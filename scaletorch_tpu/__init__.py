@@ -31,13 +31,7 @@ specs; see docs/inference.md).
 
 __version__ = "0.1.0"
 
-# Order matters: compat backfills jax.shard_map / jax.lax.pvary /
-# jax.typeof on pre-VMA jax builds before any other module (or the test
-# suite) touches them. Tolerate a jax-less interpreter: the pure-AST
-# analysis package (jaxlint, run by the dep-less CI lint job) imports
-# this package but never needs jax.
-try:
-    from scaletorch_tpu import compat  # noqa: F401
-except ImportError:
-    pass
+# Importing the package must not import jax: the pure-AST analysis
+# package (jaxlint, run by the dep-less CI lint job), the wire-protocol
+# clients and chip_smoke.py's parent process all import it without one.
 from scaletorch_tpu import env  # noqa: F401

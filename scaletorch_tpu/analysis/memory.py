@@ -152,26 +152,22 @@ def _var_shape_dtype(v) -> Tuple[Tuple[int, ...], str]:
 def _eqn_site(eqn) -> str:
     """``file:line (function)`` of the closest user frame, for the top-k
     attribution and the ST1003 message."""
-    try:
-        from jax._src import source_info_util
+    # jax 0.9.0 has no public accessor for an equation's provenance; its
+    # private one takes the traceback (None-tolerant). No try/except: a
+    # jax that moves it again should fail here, not print "<unknown>".
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return (f"{frame.file_name}:{frame.start_line} "
-                    f"({frame.function_name})")
-    except Exception:
-        pass
-    return "<unknown>"
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "<unknown>"
+    return f"{frame.file_name}:{frame.start_line} ({frame.function_name})"
 
 
 def _eqn_frame_names(eqn) -> List[str]:
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        return [f.function_name
-                for f in source_info_util.user_frames(eqn.source_info)]
-    except Exception:
-        return []
+    return [f.function_name for f in
+            source_info_util.user_frames(eqn.source_info.traceback)]
 
 
 def _is_literal(v) -> bool:
@@ -348,11 +344,14 @@ def _check_precision(entry: dict, jaxpr) -> List[Finding]:
                 elems *= d
             if elems < min_elems:
                 continue
-            # synthetic frames ("<lambda>", "<module>") carry no
-            # semantic name — they must not satisfy the allowlist
-            # ("lamb" would match every "<lambda>")
-            frames = [f.lower() for f in _eqn_frame_names(eqn)
-                      if not f.startswith("<")]
+            # frame names are qualified ("f.<locals>.<lambda>"); the
+            # synthetic components ("<lambda>", "<locals>", "<module>")
+            # carry no semantic name — they must not satisfy the
+            # allowlist ("lamb" would match every "<lambda>")
+            frames = [part.lower()
+                      for name in _eqn_frame_names(eqn)
+                      for part in name.split(".")
+                      if not part.startswith("<")]
             if any(a in f for f in frames for a in allow):
                 continue
             site = _eqn_site(eqn)

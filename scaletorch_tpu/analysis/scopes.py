@@ -521,7 +521,7 @@ class TaintTracker:
         # `self` in methods is config, not a tracer
         self.tainted.discard("self")
         # names bound to lambdas that map tracers to static facts
-        # (vma_of = lambda x: getattr(jax.typeof(x), "vma", ()) …)
+        # (vma_of = lambda x: jax.typeof(x).vma …)
         self.sanitizer_names: Set[str] = set()
 
     # -- expression tainting --------------------------------------------------

@@ -9,10 +9,16 @@ tokens/s / tokens/s/chip / MFU / final loss / device memory.
 Hermetic: synthetic data, random init — identical math/comms to real
 training (the reference benchmarks with a real dataset but the step work
 is the same; synthetic keeps the harness self-contained on any chip).
+
+A measurement path: ``benchmark_config`` refuses to run without a TPU
+(``utils.device.require_tpu``) — a rate or an MFU from a CPU run is the
+speed of XLA's CPU backend, which nobody deploys — and every result
+names the device it came from.
 """
 
 from __future__ import annotations
 
+import math
 import time
 from typing import Any, Dict, Optional
 
@@ -23,16 +29,18 @@ def benchmark_config(
     """Run one timed benchmark for a ScaleTorchTPUArguments config.
 
     Returns {tokens_per_second, tokens_per_second_per_chip, mfu, loss,
-    step_time_s, memory_gb, num_params, num_chips}. ``progress`` is an
-    optional callback taking a stage name ("trainer_built", "compiled",
-    "timed") — bench.py's hang classifier.
+    step_time_s, memory_gb, num_params, num_chips, platform, device_kind,
+    attention_backend}. Raises ``NoTpuError`` off a TPU. ``progress`` is
+    an optional callback taking a stage name ("trainer_built",
+    "compiled", "timed").
     """
     import jax
 
     from scaletorch_tpu.trainer.trainer import Trainer
-    from scaletorch_tpu.utils.device import device_memory_stats
+    from scaletorch_tpu.utils.device import device_memory_stats, require_tpu
     from scaletorch_tpu.utils.misc import get_mfu, get_num_params
 
+    require_tpu("benchmark_config")
     progress = progress or (lambda stage: None)
     trainer = Trainer(cfg)
     progress("trainer_built")
@@ -49,14 +57,22 @@ def benchmark_config(
         t0 = time.perf_counter()
         for _ in range(steps):
             m = trainer.step()
-        # Completion barrier: a host readback of the final loss (which
-        # data-depends on every step's param update) cannot return before
-        # the work is done, unlike block_until_ready on some remote-tunnel
-        # backends.
+        # Completion barrier: the final loss data-depends on every
+        # step's param update, so its host readback ends the window.
         final_loss = float(m["loss"])
         jax.block_until_ready(trainer.params)
         elapsed = time.perf_counter() - t0
         progress("timed")
+        # The in-step non-finite guard freezes the params and carries on
+        # (divergence_policy="skip"): a window whose last update was
+        # skipped timed something, but not training.
+        skipped = float(m.get("update_skipped", 0))
+        if not math.isfinite(final_loss) or skipped:
+            raise FloatingPointError(
+                f"timed window ended on loss {final_loss} with "
+                f"update_skipped={skipped}: the step produced non-finite "
+                "values on this device"
+            )
 
         tok_s = trainer.loader.tokens_per_step * steps / elapsed
         num_chips = len(jax.devices())
@@ -85,6 +101,9 @@ def benchmark_config(
             else None,
             "num_params": n_params,
             "num_chips": num_chips,
+            "platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "attention_backend": trainer.attention_backend,
         }
     finally:
         trainer.close()

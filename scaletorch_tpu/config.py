@@ -1280,9 +1280,17 @@ class ScaleTorchTPUArguments(
     def validate_world_size(self, num_devices: int) -> None:
         """Parity: reference config.py:444-460."""
         if self.world_size != num_devices:
+            from scaletorch_tpu.env import one_chip_env
+
+            one_chip = " ".join(
+                f"{k}={v}" for k, v in one_chip_env().items())
             raise ValueError(
-                f"parallel dims product {self.world_size} != available device "
-                f"count {num_devices}"
+                f"parallel dims dp*pp*cp*ep*tp = {self.world_size} but jax "
+                f"sees {num_devices} device(s). The mesh spans every "
+                f"visible device: set the *_parallel_size flags to "
+                f"multiply to {num_devices}, or start the process with "
+                f"fewer chips visible (one chip of a TPU host: "
+                f"{one_chip})"
             )
 
     def mesh_kwargs(self) -> dict:

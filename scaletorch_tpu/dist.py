@@ -99,14 +99,9 @@ def init_distributed(
     # Detect an externally-initialised runtime WITHOUT touching the XLA
     # backend (jax.process_count() would initialise it and make a
     # subsequent distributed.initialize impossible).
-    try:
-        from jax._src.distributed import global_state as _jax_dist_state
-
-        if _jax_dist_state.client is not None:
-            _initialized = True
-            return jax.process_count() > 1
-    except Exception:
-        pass
+    if jax.distributed.is_initialized():
+        _initialized = True
+        return jax.process_count() > 1
 
     if launcher == "auto":
         launcher = infer_launcher()

@@ -281,8 +281,8 @@ def make_paged_decode_step(
     Identical contract to ``make_decode_step`` with the cache reads
     routed through the page table: the K/V append is a scatter into the
     slot's current page and attention is a gather over its table (the
-    Pallas paged-decode kernel on TPU, the lax gather fallback on
-    CPU/interpret/old-jax — ops/pallas/paged_attention.py). Page-table
+    Pallas paged-decode kernel on TPU, the lax gather fallback on other
+    platforms — ops/pallas/paged_attention.py). Page-table
     contents are DATA: admissions, prefix hits, quarantine clears, and
     frees all mutate tables host-side and this one compile serves them
     all.

@@ -45,6 +45,7 @@ serving process's tail is diagnosable exactly like a training run's.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from collections import deque
@@ -594,6 +595,28 @@ class InferenceEngine:
     @property
     def draining(self) -> bool:
         return self._draining
+
+    # ---- placement --------------------------------------------------------
+    @property
+    def devices(self) -> List[Any]:
+        """The devices the KV cache lives on, by id — the engine's
+        placement: the jitted steps follow their committed operands."""
+        return sorted(self.cache.k.sharding.device_set, key=lambda d: d.id)
+
+    def on_device(self):
+        """Context for the thread that ticks a ONE-device engine: the
+        host-built step inputs (``jnp.asarray``) land on that device
+        directly instead of on ``jax.devices()[0]`` and then hopping."""
+        devices = self.devices
+        if len(devices) != 1:
+            return contextlib.nullcontext()
+        return jax.default_device(devices[0])
+
+    def device_report(self) -> List[Dict[str, Any]]:
+        """utils/device.device_report for this engine's devices."""
+        from scaletorch_tpu.utils.device import device_report
+
+        return device_report(self.devices)
 
     # ---- request lifecycle ----------------------------------------------
     def submit(

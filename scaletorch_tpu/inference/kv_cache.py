@@ -142,14 +142,10 @@ def init_kv_cache(
     of them) the buffers are created directly on their shards."""
     shape = kv_cache_shape(cfg, batch, max_seq)
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
-    k = jnp.zeros(shape, dt)
-    v = jnp.zeros(shape, dt)
-    if sharding is not None:
-        sk, sv = (sharding.k, sharding.v) if isinstance(sharding, KVCache) \
-            else (sharding, sharding)
-        k = jax.device_put(k, sk)
-        v = jax.device_put(v, sv)
-    return KVCache(k=k, v=v)
+    sk, sv = (sharding.k, sharding.v) if isinstance(sharding, KVCache) \
+        else (sharding, sharding)
+    return KVCache(k=jnp.zeros(shape, dt, device=sk),
+                   v=jnp.zeros(shape, dt, device=sv))
 
 
 def kv_cache_specs(
@@ -232,14 +228,11 @@ def init_paged_kv_cache(
     the pools are created directly on their shards."""
     shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
-    k = jnp.zeros(shape, dt)
-    v = jnp.zeros(shape, dt)
-    if sharding is not None:
-        sk, sv = (sharding.k, sharding.v) \
-            if isinstance(sharding, PagedKVCache) else (sharding, sharding)
-        k = jax.device_put(k, sk)
-        v = jax.device_put(v, sv)
-    return PagedKVCache(k=k, v=v)
+    sk, sv = (sharding.k, sharding.v) \
+        if isinstance(sharding, PagedKVCache) else (sharding, sharding)
+    # device=: allocated on the shards, never whole on the default device
+    return PagedKVCache(k=jnp.zeros(shape, dt, device=sk),
+                        v=jnp.zeros(shape, dt, device=sv))
 
 
 def paged_kv_cache_specs(

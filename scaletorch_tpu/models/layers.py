@@ -119,16 +119,10 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     custom VJP must return cotangents typed exactly like its primal
     inputs, so both are aligned to their vma union here, OUTSIDE the VJP
     — the pvary's psum transpose is then autodiff's job, not ours.
-
-    On jax builds without the VMA machinery (``jax.typeof``/``pvary``
-    absent), the alignment is a no-op — single-device and GSPMD-jit
-    semantics are unchanged.
+    Outside shard_map both sets are empty and this is a no-op.
     """
-    try:
-        vma_x = frozenset(getattr(jax.typeof(x), "vma", frozenset()))
-        vma_w = frozenset(getattr(jax.typeof(weight), "vma", frozenset()))
-    except AttributeError:  # jax without typeof/vma (pre-0.6)
-        return _rms_norm_p(float(eps), x, weight)
+    vma_x = jax.typeof(x).vma
+    vma_w = jax.typeof(weight).vma
     if vma_x != vma_w:
         x = jax.lax.pvary(x, tuple(vma_w - vma_x))
         weight = jax.lax.pvary(weight, tuple(vma_x - vma_w))

@@ -24,20 +24,17 @@ from scaletorch_tpu.models.registry import register_attention_backend
 
 
 def _pallas_available() -> bool:
+    """The one kernel-vs-XLA predicate (flash, ring, ulysses, grouped
+    MLP, paged decode): Pallas iff the platform is ``tpu``. An error
+    from the backend propagates — it must never read as "no TPU" and
+    silently select the score-materialising SDPA path."""
     if get_env("SCALETORCH_TPU_DISABLE_PALLAS"):
         return False
-    if get_env("SCALETORCH_TPU_FORCE_PALLAS"):
+    if get_env("SCALETORCH_TPU_FORCE_PALLAS"):  # AOT sessions only (env.py)
         return True
-    # is_tpu() recognises chips behind remote-execution PJRT plugins too —
-    # a bare ``platform == "tpu"`` check would silently drop REAL TPU
-    # hardware to the score-materialising SDPA fallback (34.6 GB of
-    # [L,B,H,S,S] scores at 0.6B/seq2048/bs2 per tools/aot_memory.py).
     from scaletorch_tpu.utils.device import is_tpu
 
-    try:
-        return is_tpu()
-    except Exception:  # AOT compile-only session: no local devices
-        return False
+    return is_tpu()
 
 
 def flash_attention(

@@ -55,12 +55,9 @@ _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
 
 
 def _struct(shape, dtype, like):
-    """ShapeDtypeStruct carrying ``like``'s varying-mesh-axes (vma) when
-    traced inside shard_map; plain struct otherwise."""
-    vma = getattr(jax.typeof(like), "vma", None)
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    """ShapeDtypeStruct carrying ``like``'s varying-mesh-axes (vma), so
+    the kernel's outputs type-check inside shard_map."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _pick_block(seq: int, preferred: int) -> int:
@@ -125,11 +122,9 @@ def _semantics(*dims):
     sequential reduction dims that carry scratch accumulators). Declaring
     them lets Mosaic schedule DMAs/compute across iterations instead of
     assuming every dim may carry state."""
-    from scaletorch_tpu.compat import pallas_tpu_compiler_params
-
     m = {"p": pltpu.PARALLEL, "a": pltpu.ARBITRARY}
-    return pallas_tpu_compiler_params(
-        pltpu, dimension_semantics=tuple(m[d] for d in dims))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(m[d] for d in dims))
 
 
 def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):

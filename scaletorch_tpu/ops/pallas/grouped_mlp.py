@@ -31,25 +31,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-# Guarded so `import scaletorch_tpu.ops` (and through it the inference
-# package, whose kv_cache pulls the paged-cache primitives) works on jax
-# builds whose pallas-TPU import fails; `masked_grouped_mlp` is the
-# non-TPU path and needs no pallas.
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exercised on pallas-less builds
-    pl = pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from scaletorch_tpu.models.layers import swiglu
 
 
 def _struct(shape, dtype, like):
-    vma = getattr(jax.typeof(like), "vma", None)
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _pick_block(n: int, preferred: int) -> int:
@@ -62,11 +51,9 @@ def _pick_block(n: int, preferred: int) -> int:
 def _semantics(*dims):
     """'p' = parallel grid dim, 'a' = arbitrary (sequential reduction dim
     carrying a scratch accumulator) — see ops/pallas/flash.py."""
-    from scaletorch_tpu.compat import pallas_tpu_compiler_params
-
     m = {"p": pltpu.PARALLEL, "a": pltpu.ARBITRARY}
-    return pallas_tpu_compiler_params(
-        pltpu, dimension_semantics=tuple(m[d] for d in dims))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(m[d] for d in dims))
 
 
 def _kernel(count_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_sc,

@@ -22,16 +22,14 @@ implementations live here:
     are the rows of a single ``[n_rep, page_size]`` score tile.
   * **Pure-lax fallback** (``paged_gather_kv`` + the models' shared
     ``cached_sdpa_attention``): a whole-table gather that reconstructs
-    the dense cache view. This is the CPU / interpret-mode / old-jax
-    path (``compat.py`` backfills the pallas CompilerParams naming) and
-    the *bit-parity oracle* for the kernel — it performs the identical
-    reduction the dense engine's attention performs, which is what makes
-    the paged engine's greedy outputs bit-identical to the dense
-    engine's.
+    the dense cache view. This is the off-TPU path and the reference
+    the kernel is compared against (tests in interpret mode,
+    ``chip_smoke.py`` on the chip) — it performs the same reduction
+    the dense engine's attention performs.
 
 ``paged_attention`` dispatches between them: the kernel serves
-single-token decode on a real TPU backend (toggle:
-``SCALETORCH_TPU_PAGED_KERNEL``); prefill (S > 1) and non-TPU backends
+single-token decode when the platform is ``tpu`` (toggle:
+``SCALETORCH_TPU_PAGED_KERNEL``); prefill (S > 1) and other platforms
 take the gather fallback.
 
 Writes (``paged_write_kv``) are a batched scatter: token at absolute
@@ -51,16 +49,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-# The cache primitives (TRASH_PAGE, paged_write_kv, paged_gather_kv) are
-# pure lax and imported at module level by inference/kv_cache.py — only
-# the decode kernel itself needs pallas, so a jax build whose pallas-TPU
-# import fails still serves the gather-fallback (and dense) paths.
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exercised on pallas-less builds
-    pl = pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Page 0 is reserved: never allocated, present in page tables only as
 # the sentinel for "no page here" (table padding, masked-off writes).
@@ -72,14 +62,11 @@ _NEG_INF = -1e30  # large-negative, not -inf: keeps masked rows NaN-free
 
 
 def _semantics(*dims):
-    """Mosaic grid dimension semantics ('p' parallel / 'a' arbitrary),
-    via the compat CompilerParams naming guard (same helper shape as
-    ops/pallas/flash.py)."""
-    from scaletorch_tpu.compat import pallas_tpu_compiler_params
-
+    """Mosaic grid dimension semantics ('p' parallel / 'a' arbitrary) —
+    see ops/pallas/flash.py."""
     m = {"p": pltpu.PARALLEL, "a": pltpu.ARBITRARY}
-    return pallas_tpu_compiler_params(
-        pltpu, dimension_semantics=tuple(m[d] for d in dims))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(m[d] for d in dims))
 
 
 # ---------------------------------------------------------------------------
@@ -197,12 +184,6 @@ def pallas_paged_decode_attention(
     live pages are fetched, and the per-page flash accumulation keeps
     everything after the HBM page read in VMEM.
     """
-    if pl is None:
-        raise RuntimeError(
-            "the Pallas paged-decode kernel needs jax.experimental.pallas; "
-            "this jax build lacks it — use the gather fallback "
-            "(paged_attention with kernel=False)"
-        )
     b, hq, d = q.shape
     n_pages, hkv, page_size, _ = pool_k.shape
     if hq % hkv:
@@ -270,13 +251,13 @@ def paged_attention(
 
     q: [B, Hq, S, D] (S = tail length at prefill, 1 at decode);
     q_positions: [B, S] absolute positions. ``kernel=None`` auto-selects:
-    the Pallas kernel for single-token decode on the TPU backend
-    (``SCALETORCH_TPU_PAGED_KERNEL`` gates it), the lax gather +
-    ``cached_sdpa_attention`` everywhere else — CPU, interpret mode,
-    prefill, and jax builds without working Mosaic. ``seq_limit`` crops
-    the gathered view to the engine's ``max_seq`` so the fallback's
-    reduction has *exactly* the dense layout's operand shapes — the
-    bit-identity contract with the dense engine.
+    the Pallas kernel for single-token decode when the platform is
+    ``tpu`` (the same predicate the flash backend uses;
+    ``SCALETORCH_TPU_PAGED_KERNEL`` gates it), the lax gather +
+    ``cached_sdpa_attention`` everywhere else — other platforms and
+    prefill. ``seq_limit`` crops the gathered view to the engine's
+    ``max_seq`` so the fallback's reduction has the dense layout's
+    operand shapes.
     """
     from scaletorch_tpu.models.layers import cached_sdpa_attention
 
@@ -284,10 +265,11 @@ def paged_attention(
     use_kernel = kernel
     if use_kernel is None:
         from scaletorch_tpu.env import get_env
+        from scaletorch_tpu.ops.flash_attention import _pallas_available
 
         use_kernel = (
             s == 1
-            and jax.default_backend() == "tpu"
+            and _pallas_available()
             and bool(get_env("SCALETORCH_TPU_PAGED_KERNEL"))
         )
     if use_kernel:

@@ -29,8 +29,7 @@ bit-identical across runs and across host/process layouts of the same
 logical mesh.
 
 Everything is built from ``shard_map``-level collectives
-(``all_to_all``/``all_gather``) available on every jax this repo
-supports (the 0.4.37 compat surface — scaletorch_tpu/compat.py); the
+(``all_to_all``/``all_gather``); the
 per-axis selectability lives one level up: parallel/spmd.py keeps the
 ICI-cheap axes (cp/ep/tp) in fp32 and routes only the configured
 bandwidth-bound axis (default ``dp``) through here.
@@ -42,6 +41,13 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+
+# The Varying -> Invariant all-gather. jax 0.9.0 has it only under
+# ``jax._src``: the public ``jax.lax.all_gather`` offers to="varying" or
+# to="reduced", and "reduced" values have no reshape rule. No other
+# collective both moves all-gather bytes and TYPES its result as
+# identical on every member of the axis.
+from jax._src.lax.parallel import all_gather_invariant
 
 DEFAULT_BLOCK_SIZE = 256
 _QMAX = 127.0  # symmetric int8
@@ -99,11 +105,9 @@ def quantized_pmean(
     padded = _padded_len(flat.shape[0], n, block_size)
     if padded != flat.shape[0]:
         pad = jnp.zeros(padded - flat.shape[0], jnp.float32)
-        # On VMA builds fresh zeros are axis-invariant while ``x`` varies
-        # over the mesh — align them or the concatenate is ill-typed.
-        vma = getattr(jax.typeof(flat), "vma", ())
-        if vma:
-            pad = jax.lax.pvary(pad, tuple(vma))
+        # fresh zeros are axis-invariant while ``x`` varies over the
+        # mesh — align them or the concatenate is ill-typed
+        pad = jax.lax.pvary(pad, tuple(jax.typeof(flat).vma))
         flat = jnp.concatenate([flat, pad])
     chunk = padded // n  # per-rank owned chunk, a multiple of block_size
 
@@ -122,12 +126,13 @@ def quantized_pmean(
         owned = owned / n
 
     # leg 2 — all-gather in int8: requantize the reduced chunk once,
-    # circulate, dequantize. all_gather's output is replicated over
-    # ``axis`` (identical on every member), which is exactly what the
+    # circulate, dequantize. The gathered value is identical on every
+    # member of ``axis`` and the invariant gather says so in the VMA
+    # type (a plain all_gather is typed varying), which is what the
     # surrounding step's out_specs expect of a reduced gradient.
     q2, s2 = quantize_blockwise(owned, block_size)
-    q2 = jax.lax.all_gather(q2, axis, axis=0, tiled=True)
-    s2 = jax.lax.all_gather(s2, axis, axis=0, tiled=True)
+    q2 = all_gather_invariant(q2, axis, axis=0, tiled=True)
+    s2 = all_gather_invariant(s2, axis, axis=0, tiled=True)
     out = dequantize_blockwise(q2, s2)
     return out[: _size(orig_shape)].reshape(orig_shape)
 
@@ -197,10 +202,8 @@ def quantized_pmean_tree(
         rem = -v.shape[0] % block_size
         if not rem:
             return v
-        pad = jnp.zeros(rem, jnp.float32)
-        vma = getattr(jax.typeof(v), "vma", ())
-        if vma:
-            pad = jax.lax.pvary(pad, tuple(vma))
+        pad = jax.lax.pvary(
+            jnp.zeros(rem, jnp.float32), tuple(jax.typeof(v).vma))
         return jnp.concatenate([v, pad])
 
     segs = [_pad_to_block(g.astype(jnp.float32).ravel()) for g in leaves]

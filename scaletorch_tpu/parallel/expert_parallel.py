@@ -40,7 +40,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from scaletorch_tpu.compat import psum_replicated_ct
 from scaletorch_tpu.parallel.tensor_parallel import pvary_missing
 
 
@@ -747,7 +746,7 @@ def moe_mlp(
         u = jnp.einsum("eth,ehi->eti", x_grouped, up_w)
         out = jnp.einsum("eti,eih->eth", swiglu(g, u), down_w)
     if tp_axis is not None and reduce == "sum":
-        out = psum_replicated_ct(out, tp_axis)
+        out = jax.lax.psum(out, tp_axis)
     return out
 
 

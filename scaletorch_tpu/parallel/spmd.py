@@ -77,8 +77,7 @@ def leaf_spec_list(params: Any, p_specs: Any) -> list:
     """Per-leaf PartitionSpec, aligned with ``tree_leaves(params)``.
 
     Static (spec-derived) leaf metadata rather than ``jax.typeof(...).vma``
-    reflection: the specs are ground truth for how each leaf is sharded,
-    and — unlike vma — they exist on pre-VMA jax builds too (compat.py).
+    reflection: the specs are ground truth for how each leaf is sharded.
 
     Unlike shard_map's in_specs, which also accepts pytree PREFIXES,
     this alignment needs one PartitionSpec per param leaf — a prefix (or
@@ -109,13 +108,13 @@ def _leaf_sqsum_partitioned(
     Each leaf's partial square-sum is psum'd over exactly the shard axes it
     varies over, so every element is counted once. ``leaf_axes`` (aligned
     with tree_leaves) supplies each leaf's sharded axes statically; when
-    omitted they are read from the VMA type (new-jax only)."""
+    omitted they are read from the VMA type."""
     groups: Dict[Tuple[str, ...], jax.Array] = {}
     leaves = jax.tree_util.tree_leaves(grads)
     if leaf_axes is None:
         leaf_axes = [
             tuple(a for a in shard_axes
-                  if a in getattr(jax.typeof(g), "vma", ()))
+                  if a in jax.typeof(g).vma)
             for g in leaves
         ]
     for g, axes in zip(leaves, leaf_axes):
@@ -575,7 +574,6 @@ def make_spmd_train_step(
         # is purely local and the reduction below runs ONCE per step —
         # the no_sync + single-bucket-flush contract
         # (reference data_parallel.py:46-68, bucket.py:58-77).
-        vma_of = lambda x: getattr(jax.typeof(x), "vma", ())  # noqa: E731
         from scaletorch_tpu.parallel.tensor_parallel import pvary_missing
 
         p_v = jax.tree.map(lambda x: pvary_missing(x, all_axes), p)
@@ -583,7 +581,7 @@ def make_spmd_train_step(
         zeros = jax.tree.map(
             lambda x: jax.lax.pvary(
                 jnp.zeros(x.shape, jnp.float32),
-                tuple(vma_of(x)),
+                tuple(jax.typeof(x).vma),
             ),
             p_v,
         )

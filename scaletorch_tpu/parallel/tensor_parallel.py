@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from scaletorch_tpu.compat import psum_replicated_ct
-
 
 def axis_rank(axis: str) -> jax.Array:
     return jax.lax.axis_index(axis)
@@ -51,10 +49,7 @@ def pvary_missing(x: jax.Array, axes) -> jax.Array:
     operands used inside a shard_map get correctly summed gradients."""
     if isinstance(axes, str):
         axes = (axes,)
-    try:
-        vma = jax.typeof(x).vma
-    except AttributeError:  # outside shard_map / non-VMA trace
-        return x
+    vma = jax.typeof(x).vma
     missing = tuple(a for a in axes if a not in vma)
     return jax.lax.pvary(x, missing) if missing else x
 
@@ -70,13 +65,10 @@ def copy_to_tensor_parallel_region(x: jax.Array, axis: str = "tp") -> jax.Array:
 
 
 def reduce_from_tensor_parallel_region(x: jax.Array, axis: str = "tp") -> jax.Array:
-    """All-reduce forward / identity backward (reference tp_comms.py:117-166).
-
-    ``psum_replicated_ct`` rather than raw ``psum``: on pre-VMA jax the
-    identity backward must be stated as a custom_vjp or the in-body
-    transpose inflates upstream gradients by the axis size
-    (compat.py docstring); on VMA builds it IS ``jax.lax.psum``."""
-    return psum_replicated_ct(x, axis)
+    """All-reduce forward / identity backward (reference tp_comms.py:117-166):
+    shard_map's VMA typing gives ``psum`` the replicated-cotangent
+    (collective-free) backward."""
+    return jax.lax.psum(x, axis)
 
 
 def gather_from_tensor_parallel_region(x: jax.Array, axis: str = "tp") -> jax.Array:
@@ -143,7 +135,7 @@ def vocab_parallel_embedding(
     emb = jnp.where(in_shard[..., None], emb, 0)
     if reduce == "none":
         return emb
-    return psum_replicated_ct(emb, axis)
+    return jax.lax.psum(emb, axis)
 
 
 def _vocab_parallel_token_stats(
@@ -169,7 +161,7 @@ def _vocab_parallel_token_stats(
     global_max = jax.lax.pmax(local_max, axis) if axis else local_max
     sumexp = jnp.sum(jnp.exp(logits32 - global_max[..., None]), axis=-1)
     if axis:
-        sumexp = psum_replicated_ct(sumexp, axis)
+        sumexp = jax.lax.psum(sumexp, axis)
     logz = global_max + jnp.log(sumexp)
 
     mask = targets != ignore_index
@@ -179,7 +171,7 @@ def _vocab_parallel_token_stats(
     gold = jnp.take_along_axis(logits32, local_t[..., None], axis=-1)[..., 0]
     gold = jnp.where(in_shard, gold, 0.0)
     if axis:
-        gold = psum_replicated_ct(gold, axis)
+        gold = jax.lax.psum(gold, axis)
     nll = (logz - gold) * mask
     return jnp.sum(nll), jnp.sum(mask).astype(jnp.float32)
 

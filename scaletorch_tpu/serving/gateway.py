@@ -381,6 +381,10 @@ class EngineWorker:
                 pass
 
     def _loop(self) -> None:
+        with self.engine.on_device():
+            self._tick_loop()
+
+    def _tick_loop(self) -> None:
         engine = self.engine
         drain_ticks = 0
         try:
@@ -1501,6 +1505,11 @@ class ServingGateway:
                 "prefix_pages": snap.get("prefix_pages"),
                 "warm_pages": snap.get("warm_pages_total"),
             }
+            if getattr(worker, "engine", None) is not None:
+                # in-process replica: which device(s) it sits on and what
+                # their allocators hold (a remote replica's engine lives
+                # in its child; its /healthz says it there)
+                replicas[rid]["devices"] = worker.engine.device_report()
             if "prefill_slice_devices" in snap:
                 # disaggregated replica: per-slice health (the decode
                 # slice's pool rides the base pages_in_use /

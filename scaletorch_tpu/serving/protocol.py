@@ -319,6 +319,22 @@ def stream_tokens(events: List[Tuple[str, Dict[str, Any]]]) -> List[int]:
     return out
 
 
+def parse_metrics_text(text: str) -> Dict[str, float]:
+    """Flat ``{series-with-labels: value}`` out of a ``/metrics``
+    exposition page — the client-side read of the gateway's counters
+    (both smoke scripts assert the request ledger from it)."""
+    out: Dict[str, float] = {}
+    for line in text.splitlines():
+        if line.startswith("#") or not line.strip():
+            continue
+        name, _, value = line.rpartition(" ")
+        try:
+            out[name] = float(value)
+        except ValueError:
+            continue
+    return out
+
+
 # --------------------------------------------------------------------------
 # Warm-transfer framing (POST /warm response stream)
 # --------------------------------------------------------------------------

@@ -4,7 +4,9 @@ Parity with reference scaletorch/trainer/metrics.py:23-114
 (log_training_metrics): one line per logging step on the designated
 process with loss / LR / grad-norm / tokens-per-second (global and
 per-chip) / MFU / device memory. MFU uses the same analytic formula as
-the reference (utils/misc.get_mfu) against the TPU FLOPS registry.
+the reference (utils/misc.get_mfu) against the peak-FLOPS table. Rates
+and MFU are device metrics: off a TPU the records carry the host's
+``step_time`` and none of them.
 
 Async-dispatch aware: on non-logging steps nothing is materialised — no
 ``float(loss)`` host sync, no memory-stats poll — so the host keeps
@@ -21,7 +23,11 @@ from typing import Optional
 
 import jax
 
-from scaletorch_tpu.utils.device import device_memory_stats, get_theoretical_flops
+from scaletorch_tpu.utils.device import (
+    device_memory_stats,
+    get_theoretical_flops,
+    is_tpu,
+)
 from scaletorch_tpu.utils.logger import get_logger
 from scaletorch_tpu.utils.misc import get_mfu, to_readable_format
 
@@ -45,6 +51,8 @@ class MetricsLogger:
     tokens_per_step: int           # global tokens consumed per optimizer step
     num_chips: int = 1
     log_frequency: int = 1
+    # peak FLOP/s of one chip; None = look the TPU up in the table, and
+    # off a TPU stay None — no tokens/s and no MFU are recorded then
     peak_flops: Optional[float] = None
     collect_system: bool = True   # host CPU/mem + accel env per logged step
     # optional telemetry.TelemetryExporter: every logged record also
@@ -57,7 +65,7 @@ class MetricsLogger:
     _monitor: Optional[object] = None
 
     def __post_init__(self) -> None:
-        if self.peak_flops is None:
+        if self.peak_flops is None and is_tpu():
             self.peak_flops = get_theoretical_flops()
         if self.collect_system:
             # reference PerformanceMonitor role (utils/monitor.py:69-162):
@@ -99,22 +107,23 @@ class MetricsLogger:
             elapsed = now - self._window_start_time
             steps_in_window = step - self._window_start_step
             if elapsed > 0 and steps_in_window > 0:
-                tok_s = self.tokens_per_step * steps_in_window / elapsed
-                record.update(
-                    step_time=elapsed / steps_in_window,
-                    tokens_per_second=tok_s,
-                    tokens_per_second_per_chip=tok_s / self.num_chips,
-                    mfu=get_mfu(
-                        tok_s,
-                        self.num_params,
-                        self.num_layers,
-                        self.num_heads,
-                        self.head_dim,
-                        self.seq_len,
-                        num_chips=self.num_chips,
-                        peak_flops=self.peak_flops,
-                    ),
-                )
+                record["step_time"] = elapsed / steps_in_window
+                if self.peak_flops is not None:
+                    tok_s = self.tokens_per_step * steps_in_window / elapsed
+                    record.update(
+                        tokens_per_second=tok_s,
+                        tokens_per_second_per_chip=tok_s / self.num_chips,
+                        mfu=get_mfu(
+                            tok_s,
+                            self.num_params,
+                            self.num_layers,
+                            self.num_heads,
+                            self.head_dim,
+                            self.seq_len,
+                            num_chips=self.num_chips,
+                            peak_flops=self.peak_flops,
+                        ),
+                    )
         # restart the window *after* materialisation so the sync cost isn't
         # attributed to the next window
         self._window_start_time = time.perf_counter()
@@ -183,9 +192,10 @@ class MetricsLogger:
             return []
         return self._monitor.tail(last_n)
 
-    def save_json(self, path: str) -> str:
+    def save_json(self, path: str, extra: Optional[dict] = None) -> str:
         """Dump the full metrics history as JSON (reference
-        PerformanceMonitor.save_stats, monitor.py:220-250)."""
+        PerformanceMonitor.save_stats, monitor.py:220-250); ``extra``
+        top-level keys ride along (the trainer's per-device reports)."""
         import json
         import os
 
@@ -210,6 +220,7 @@ class MetricsLogger:
                     "peak_flops": self.peak_flops,
                     "summary": summary,
                     "records": self.history,
+                    **(extra or {}),
                 },
                 f,
                 indent=1,

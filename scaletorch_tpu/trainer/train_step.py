@@ -78,7 +78,7 @@ def accumulate_gradients(
     ``pvary_axes``: when running inside a ``shard_map`` over those mesh
     axes (the quantized-allreduce step), params and the scan carry are
     marked varying first so the VMA bookkeeping lines up; identity
-    outside shard_map and on pre-VMA jax (compat.py)."""
+    outside shard_map."""
     accum = jax.tree_util.tree_leaves(batch)[0].shape[0]
     if pvary_axes:
         from scaletorch_tpu.parallel.tensor_parallel import pvary_missing

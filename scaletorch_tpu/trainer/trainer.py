@@ -24,6 +24,7 @@ from scaletorch_tpu.parallel.mesh import MeshManager, setup_mesh_manager
 from scaletorch_tpu.telemetry.spans import NOOP_SPAN
 from scaletorch_tpu.trainer.metrics import MetricsLogger
 from scaletorch_tpu.trainer.optimizer import create_optimizer
+from scaletorch_tpu.utils.device import device_report
 from scaletorch_tpu.utils.logger import get_logger
 from scaletorch_tpu.utils.misc import get_num_params, set_all_seed, to_readable_format
 
@@ -563,6 +564,12 @@ class Trainer:
             f"mesh={self.mm} backend={self.attention_backend} "
             f"dtype={cfg.dtype} gc={cfg.gradient_checkpointing}"
         )
+        # Per-device memory BEFORE step 1, while the unsharded init copy
+        # (params_host + its optimizer state, built on the first local
+        # device above) is still alive: that device's peak against the
+        # others is what the init path costs. Rides the performance log.
+        self._device_report_init = device_report(
+            arrays=(self.params, self.opt_state))
         self.global_step = 0
         self.tokens_seen = 0
         self.preempted = False
@@ -943,11 +950,20 @@ class Trainer:
             # performance_logs_<rank>_<ts>.json per rank, train.py:439-443)
             import os
 
-            path = self.metrics.save_json(os.path.join(
-                self.cfg.performance_log_dir,
-                f"performance_log_proc{jax.process_index()}"
-                f"_step{self.global_step}.json",
-            ))
+            path = self.metrics.save_json(
+                os.path.join(
+                    self.cfg.performance_log_dir,
+                    f"performance_log_proc{jax.process_index()}"
+                    f"_step{self.global_step}.json",
+                ),
+                extra={
+                    "attention_backend": self.attention_backend,
+                    "mesh": dict(self.mm.mesh.shape),
+                    "devices_before_first_step": self._device_report_init,
+                    "devices_after_last_step": device_report(
+                        arrays=(self.params, self.opt_state)),
+                },
+            )
             self.logger.info(f"performance log written to {path}")
         return last
 

@@ -3,7 +3,6 @@
 from scaletorch_tpu.utils.device import (  # noqa: F401
     get_device_kind,
     get_theoretical_flops,
-    register_device_flops,
     device_memory_stats,
 )
 from scaletorch_tpu.utils.misc import (  # noqa: F401

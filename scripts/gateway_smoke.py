@@ -1,6 +1,13 @@
 #!/usr/bin/env python
 """Gateway smoke: boot scripts/serve.py, stream one SSE request, verify.
 
+CPU ONLY, by its shape: the server is a child process (pinned to
+``JAX_PLATFORMS=cpu`` below) and THIS process rebuilds the same engine
+as the bit-parity oracle — on a TPU a chip belongs to one process, so
+the parent could never get the device its child holds. The on-chip
+counterpart is ``chip_smoke.py`` at the repo root, whose client never
+imports jax.
+
 The CI ``gateway-smoke`` step (tier1.yml) runs this end to end on a CPU
 mesh:
 
@@ -81,6 +88,8 @@ import urllib.request
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "scripts"))
+
+from scaletorch_tpu.serving.protocol import parse_metrics_text  # noqa: E402
 
 PROMPT = [1, 2, 3, 5, 8]
 MAX_NEW = 12
@@ -233,20 +242,6 @@ def stream_generate(base: str, *, timeout: float = 120.0):
     return events, stream_tokens(events), dones, echo
 
 
-def parse_prom(text: str) -> dict:
-    """Flat ``{series-with-labels: value}`` out of an exposition page."""
-    out = {}
-    for line in text.splitlines():
-        if line.startswith("#") or not line.strip():
-            continue
-        name, _, value = line.rpartition(" ")
-        try:
-            out[name] = float(value)
-        except ValueError:
-            continue
-    return out
-
-
 def main_mp(procs: int) -> int:
     """The process-fleet drill: kill -9 mid-stream, survive, heal."""
     if os.path.isdir(TELEMETRY_DIR):
@@ -321,7 +316,7 @@ def main_mp(procs: int) -> int:
         # 4. the ledger balances THROUGH the crash
         metrics = urllib.request.urlopen(
             f"{base}/metrics", timeout=30).read().decode()
-        prom = parse_prom(metrics)
+        prom = parse_metrics_text(metrics)
         received = prom["scaletorch_http_requests_received"]
         outcome_sum = sum(
             v for k, v in prom.items()
@@ -397,7 +392,7 @@ def main_mp(procs: int) -> int:
         #    metric families are live, and neither engine retraced
         metrics = urllib.request.urlopen(
             f"{base}/metrics", timeout=30).read().decode()
-        prom = parse_prom(metrics)
+        prom = parse_metrics_text(metrics)
         received = prom["scaletorch_http_requests_received"]
         assert received == 3.0, received
         assert prom["scaletorch_http_aborted"] == 1.0, prom
@@ -410,7 +405,7 @@ def main_mp(procs: int) -> int:
         deadline = time.monotonic() + 60
         while time.monotonic() < deadline:
             compiles = [
-                v for k, v in parse_prom(urllib.request.urlopen(
+                v for k, v in parse_metrics_text(urllib.request.urlopen(
                     f"{base}/metrics", timeout=30).read().decode()
                 ).items()
                 if k.startswith("scaletorch_engine_decode_compile_count")]

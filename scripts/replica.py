@@ -107,6 +107,9 @@ async def _serve(args, worker) -> None:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    from scaletorch_tpu.env import configure_compile_cache
+
+    configure_compile_cache()
     from scaletorch_tpu.inference.resilience import make_serving_watchdog
     from scaletorch_tpu.serving.gateway import EngineWorker
 

@@ -73,7 +73,11 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "(scripts/replica.py each) behind the replica "
                         "supervisor — independent failure domains with "
                         "auto-restart — instead of --serve_replicas "
-                        "in-process worker threads.")
+                        "in-process worker threads. CPU only so far: "
+                        "the children inherit this environment, and a "
+                        "TPU chip belongs to one process, so on a chip "
+                        "every child after the first fails to acquire "
+                        "it. Use --serve_replicas there.")
     p.add_argument("--replica_watchdog_timeout_s", type=float,
                    default=120.0,
                    help="Each replica child's serving stall watchdog "
@@ -145,8 +149,11 @@ def build_model(args):
 
     known = {f.name for f in dataclasses.fields(llama.LlamaConfig)}
     kwargs = {k: v for k, v in preset(args.preset).items() if k in known}
+    # serving holds the weights in the compute dtype: the fp32 master
+    # copy is a training concern, and decode reads every weight per token
     cfg = llama.LlamaConfig(
-        qk_norm=preset(args.preset).get("model_type") == "qwen3", **kwargs)
+        qk_norm=preset(args.preset).get("model_type") == "qwen3",
+        param_dtype=jnp.bfloat16, **kwargs)
     if args.model_name_or_path:
         from scaletorch_tpu.utils.hf_interop import load_hf_params
 
@@ -154,7 +161,10 @@ def build_model(args):
     return cfg, llama.init_params(jax.random.PRNGKey(args.param_seed), cfg)
 
 
-def build_engine(args, cfg, params, tracer=None):
+def build_engine(args, cfg, params, tracer=None, device=None):
+    """One engine; with ``device`` its params, KV pool and jitted steps
+    live on that device alone (a one-device mesh) — how N in-process
+    replicas take N chips instead of stacking on the first."""
     from scaletorch_tpu.inference import (
         DisaggregatedEngine,
         InferenceEngine,
@@ -175,6 +185,15 @@ def build_engine(args, cfg, params, tracer=None):
         return DisaggregatedEngine(
             params, cfg, disagg_split=parse_disagg_spec(args.disagg),
             **kw)
+    if device is not None:
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        mesh = Mesh(np.array([device]), ("tp",))
+        params = jax.device_put(
+            params, NamedSharding(mesh, PartitionSpec()))
+        kw["mesh"] = mesh
     return InferenceEngine(params, cfg, **kw)
 
 
@@ -282,11 +301,18 @@ def build_gateway(args):
     if args.serve_replica_procs > 0:
         engines, supervisor = build_replica_fleet(args, exporter=exporter)
     else:
+        import jax
+
         cfg, params = build_model(args)
+        # replica i on device i (round-robin past the device count);
+        # a disaggregated engine splits the devices itself
+        devices = [None] if args.disagg else jax.devices()
         engines = {
-            f"r{i}": build_engine(args, cfg, params, tracer=tracer)
+            f"r{i}": build_engine(args, cfg, params, tracer=tracer,
+                                  device=devices[i % len(devices)])
             for i in range(args.serve_replicas)
         }
+        del params  # each engine holds its own placed copy
     injector = ServingFaultInjector.from_config(args)
     return ServingGateway(
         engines,
@@ -388,6 +414,8 @@ def _configure_disagg_devices(args) -> None:
 
 
 def main(argv=None) -> int:
+    from scaletorch_tpu.env import configure_compile_cache
+
     args = parse_args(argv)
     if args.disagg:
         if args.cache_layout != "paged":
@@ -399,6 +427,8 @@ def main(argv=None) -> int:
                 "--disagg runs in-process replicas only; drop "
                 "--serve_replica_procs")
         _configure_disagg_devices(args)
+    # after the disagg device flags: this imports jax, they must precede it
+    configure_compile_cache()
     return asyncio.run(_main(args))
 
 

@@ -193,7 +193,11 @@ class TestSyntheticJaxprChecks:
                 # hoisted out of the accumulation loop
                 return carry + jax.lax.psum(xi, "dp"), None
 
-            out, _ = jax.lax.scan(step, jnp.zeros_like(x[0]), x)
+            # fresh zeros, not zeros_like(x[0]): the carry is a psum
+            # result (invariant over dp) and zeros_like would inherit
+            # x's varying type — ill-typed under shard_map's VMA check
+            out, _ = jax.lax.scan(
+                step, jnp.zeros(x.shape[1:], x.dtype), x)
             return out
 
         fn = jax.jit(jax.shard_map(

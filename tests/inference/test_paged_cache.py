@@ -277,7 +277,8 @@ class TestPagedPrimitives:
         dense = cached_sdpa_attention(
             q, paged_gather_kv(pool_k, tables)[:, :, : self.S_MAX],
             paged_gather_kv(pool_v, tables)[:, :, : self.S_MAX], pos)
-        assert jnp.array_equal(out, dense)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                                   rtol=1e-6, atol=1e-6)
 
     def test_pallas_kernel_interpret_matches_fallback(self):
         pool_k, tables = self._pool_and_tables(0)
@@ -309,10 +310,21 @@ class TestPagedPrimitives:
                 q, pool_k, pool_k, tables, jnp.zeros((self.B,), jnp.int32))
 
 
+# fp32 logits of the paged and the dense path: both sum the same terms
+# over the same operand shapes (seq_limit crop), but they are two
+# compiled programs (gather/scatter vs dynamic-update-slice) and XLA is
+# free to fuse and order the fp32 reductions of each differently — a few
+# ulps per layer (6e-7 on |logit| ~ 1.7 with the XLA in jax 0.9.0; bit
+# equality held on an older XLA and cannot hold between a Pallas kernel
+# and a lax gather on hardware). 1e-5 is ~50 fp32 ulps at this
+# magnitude: far above reduction-order noise, far below any wrong page,
+# position or mask (those move logits by >= 1e-2).
+PAGED_DENSE_LOGIT_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
 class TestTeacherForcedPagedParity:
     """The paged read/write path reproduces the dense cache's logits
-    bit-for-bit under teacher forcing — same operand shapes (seq_limit
-    crop), same values, same reduction."""
+    under teacher forcing to reduction-order tolerance."""
 
     def _check(self, cfg, init, page_size):
         params = init(jax.random.PRNGKey(0), cfg)
@@ -323,7 +335,8 @@ class TestTeacherForcedPagedParity:
         paged = teacher_forced_decode_paged(
             params, cfg, ids, page_size=page_size, max_seq=16,
             prefill_len=5)
-        assert jnp.array_equal(dense, paged)
+        np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
+                                   **PAGED_DENSE_LOGIT_TOL)
 
     @pytest.mark.parametrize("page_size", [4, 5, 16])
     def test_llama_gqa(self, page_size):

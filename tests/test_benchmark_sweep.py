@@ -37,8 +37,9 @@ def test_sweep_config_runs_ok(label):
     cfg = next(c for c in bc.CORRECTNESS_CONFIGS if c[0] == label)
     r = bc.run_config(cfg, steps=4, device="cpu", timeout=600)
     assert r["status"] == "OK", r
-    assert r["loss"] > 0 and r["tokens_per_sec"] > 0
-    assert "mfu" not in r  # meaningless vs TPU peak on the CPU tier
+    assert r["loss"] > 0
+    # rates and MFU are device metrics: a CPU-tier row has neither
+    assert "tokens_per_sec" not in r and "mfu" not in r
 
 
 def test_artifact_is_committed_and_green():

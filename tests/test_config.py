@@ -137,7 +137,10 @@ class TestComposedArguments:
         )
         assert cfg.world_size == 8
         cfg.validate_world_size(8)
-        with pytest.raises(ValueError, match="device count"):
+        # the error says what to do: match the device count, or show
+        # the process fewer chips
+        with pytest.raises(ValueError, match=r"sees 4 device.*multiply "
+                                             r"to 4.*TPU_VISIBLE_CHIPS=0"):
             cfg.validate_world_size(4)
 
     def test_num_microbatches_default(self):

@@ -1,11 +1,13 @@
-"""MFU math, formatting, device registry."""
+"""MFU math, formatting, peak-FLOPS table."""
+
+from types import SimpleNamespace
 
 import jax.numpy as jnp
 import pytest
 
 from scaletorch_tpu.utils.device import (
+    PEAK_BF16_FLOPS,
     get_theoretical_flops,
-    register_device_flops,
 )
 from scaletorch_tpu.utils.misc import (
     get_flops_per_token,
@@ -31,17 +33,27 @@ class TestMfu:
         n, l, h, d, s = 600e6, 28, 16, 128, 4096
         assert get_flops_per_token(n, l, h, d, s) == 6 * n + 12 * l * h * d * s
 
-    def test_mfu_env_override(self, monkeypatch):
-        monkeypatch.setenv("SCALETORCH_TPU_DEVICE_FLOPS", "1e12")
+    def test_mfu_against_a_stated_peak(self):
         # 1 param model, no attention: 6 flops/token; 1e11 tok/s -> 6e11 flops
-        mfu = get_mfu(1e11, 1, 0, 0, 0, 1)
+        mfu = get_mfu(1e11, 1, 0, 0, 0, 1, peak_flops=1e12)
         assert mfu == pytest.approx(60.0)
 
-    def test_register_device_flops(self, monkeypatch):
-        monkeypatch.delenv("SCALETORCH_TPU_DEVICE_FLOPS", raising=False)
-        register_device_flops("cpu", 5e12)
-        assert get_theoretical_flops() == 5e12
-        register_device_flops("cpu", 1e12)  # restore
+    def test_peak_table_is_keyed_by_device_kind(self):
+        v5e = SimpleNamespace(device_kind="TPU v5 lite")
+        assert get_theoretical_flops(v5e) == 197e12 \
+            == PEAK_BF16_FLOPS["TPU v5 lite"]
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v9 mega", "NVIDIA H100"])
+    def test_unknown_device_kind_raises(self, kind):
+        """A device the table does not list is an error, not a default:
+        no 'cpu: 1e12' row for an MFU to be printed against."""
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            get_theoretical_flops(SimpleNamespace(device_kind=kind))
+
+    def test_mfu_on_this_cpu_raises(self):
+        # the tests run on the CPU platform: no peak, no MFU
+        with pytest.raises(ValueError, match="device_kind 'cpu'"):
+            get_mfu(1e11, 1, 0, 0, 0, 1)
 
 
 class TestNumParams:

@@ -83,7 +83,6 @@ def _compile_point(cp: int, hq: int, hkv: int, seq: int,
     import jax.numpy as jnp
     import optax
 
-    import scaletorch_tpu  # noqa: F401 — compat backfill on old jax
     from scaletorch_tpu.analysis.hlo import collective_wire_bytes
     from scaletorch_tpu.config import ScaleTorchTPUArguments
     from scaletorch_tpu.models import llama

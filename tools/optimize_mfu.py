@@ -27,32 +27,36 @@ sys.path.insert(0, REPO)
 _OOM = ("RESOURCE_EXHAUSTED", "Out of memory", "out of memory")
 
 
+# jax.Device.device_kind -> the generation names tools/aot_memory.py takes
+_GEN_BY_KIND = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e", "TPU v5e": "v5e",
+    "TPU v5": "v5p", "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e", "TPU v6e": "v6e",
+}
+
+
 def _detect_gen(explicit: str | None) -> str:
     """Chip generation for the prefilter's HBM budget — the fit verdict
     must be judged against the chip the sweep will RUN on (dots_saveable
-    at seq 16384 overflows a 16GB v5e but fits a 32GB v6e)."""
+    at seq 16384 overflows a 16GB v5e but fits a 32GB v6e). A device it
+    does not know is an error, never a default generation."""
     if explicit:
         return explicit
-    try:
-        import jax
+    import jax
 
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no device: default budget
-        return "v5e"
-    if "v6" in kind:
-        return "v6e"
-    if "v5" in kind and "lite" in kind:
-        return "v5e"
-    if "v5" in kind:
-        return "v5p"
-    if "v4" in kind:
-        return "v4"
-    return "v5e"
+    kind = jax.local_devices()[0].device_kind
+    if kind not in _GEN_BY_KIND:
+        raise SystemExit(
+            f"cannot tell the TPU generation of device_kind {kind!r}; "
+            "pass --aot-gen")
+    return _GEN_BY_KIND[kind]
 
 
 def _aot_prefilter(args, variants):
-    """Compile-time HBM verdict per variant via tools/aot_memory.py (its
-    own scrubbed-env subprocess — works with or without a chip). One
+    """Compile-time HBM verdict per variant via tools/aot_memory.py. The
+    children only compile, so they are pinned to JAX_PLATFORMS=cpu: this
+    parent holds the chip, and a chip belongs to one process. One
     subprocess per (micro_bs, gc) group: aot_memory takes every remat
     policy in a single invocation, so the JAX-import/lowering startup is
     paid per group, not per variant. Returns (kept_variants,
@@ -95,8 +99,9 @@ def _aot_prefilter(args, variants):
             cmd += ["--gc", "--policies", *policies]
         fit_by_policy: dict = {}
         try:
-            proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  timeout=2400, cwd=REPO)
+            proc = subprocess.run(
+                cmd, capture_output=True, text=True, timeout=2400,
+                cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"))
             for line in proc.stdout.splitlines():
                 line = line.strip()
                 if not line.startswith("{"):
@@ -161,7 +166,9 @@ def main() -> None:
         flash_blocks.append((bq, bkv))
 
     from scaletorch_tpu.benchmark import benchmark_config, make_bench_args
+    from scaletorch_tpu.utils.device import require_tpu
 
+    require_tpu("tools/optimize_mfu.py")
     variants = []
     if args.try_no_gc:
         for bs in args.batch_sizes:

@@ -59,8 +59,13 @@ def main() -> None:
 
     from scaletorch_tpu.benchmark import benchmark_config, make_bench_args
     from scaletorch_tpu.models.presets import preset
-    from scaletorch_tpu.utils.device import get_device_kind, get_theoretical_flops
+    from scaletorch_tpu.utils.device import (
+        get_device_kind,
+        get_theoretical_flops,
+        require_tpu,
+    )
 
+    require_tpu("tools/profile_mfu.py")
     p = preset(args.model)
     br = flops_breakdown(p, args.seq)
     print(f"model={args.model} seq={args.seq} bs={args.bs}")
