@@ -1,0 +1,265 @@
+"""Runner of the training cells: ``Trainer.step`` back to back.
+
+The system under test is the program's own ``Trainer`` (the object
+``train.py`` builds), driven through ``Trainer.step(batch)``, its public
+per-step entry point. The benchmark owns the batches (from ``--seed``),
+the clock, the reference check and the trace.
+"""
+
+from __future__ import annotations
+
+import math
+import time
+from typing import Any, Dict, List
+
+import numpy as np
+
+from benchmarks.lib import traffic as traffic_lib
+
+from benchmarks.lib.spec import MODEL_SHAPE_KEYS
+
+# config.json keys the trainer's arguments take under the same name
+MODEL_KEYS = ("model_type",) + MODEL_SHAPE_KEYS
+
+
+def trainer_arguments(config: Dict[str, Any], workload: Dict[str, Any],
+                      traffic: Dict[str, Any], seed: int):
+    from scaletorch_tpu.config import ScaleTorchTPUArguments
+
+    kwargs = {k: config[k] for k in MODEL_KEYS if k in config}
+    kwargs.update(config.get("train", {}))
+    kwargs.update(workload.get("launch", {}))
+    dp = int(kwargs.get("data_parallel_size", 1))
+    rows = int(traffic["sequences_per_step"])
+    if rows % dp:
+        raise ValueError(f"{rows} sequences per step over dp={dp}")
+    kwargs.update(
+        sequence_length=int(traffic["sequence_length"]),
+        micro_batch_size=rows // dp,
+        gradient_accumulation_steps=1,
+        synthetic_data=True,
+        seed=traffic_lib.fold_seed(seed),
+        log_frequency=10_000_000,
+        total_train_steps=10_000_000,
+    )
+    return ScaleTorchTPUArguments(**kwargs)
+
+
+def _single_device(tree, device):
+    """Each leaf's copy on ``device``, without moving anything: the
+    state is replicated over cp/dp, so the first device's shard is the
+    whole array."""
+    import jax
+
+    def pick(leaf):
+        for shard in leaf.addressable_shards:
+            if shard.device == device:
+                if shard.data.shape != leaf.shape:
+                    raise ValueError(
+                        "the reference check needs parameters replicated "
+                        f"on one device; got a {shard.data.shape} shard of "
+                        f"{leaf.shape}")
+                return shard.data
+        raise ValueError(f"no shard on {device}")
+
+    return jax.tree.map(pick, tree)
+
+
+def reference_first_step(trainer, config, check: Dict[str, Any],
+                         batch: Dict[str, np.ndarray],
+                         wrong=None) -> Dict[str, Any]:
+    """Loss (and, where the cell's check asks for gradients, the global
+    gradient norm and the gradient of every norm gain) of the plain
+    reference on the trainer's current parameters and the batch's rows,
+    the loss averaged over rows as the system averages."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.reference import qwen3 as reference
+
+    device = jax.local_devices()[0]
+    params = _single_device(trainer.params, device)
+    gradients = bool(check.get("gradients", False))
+    fn = reference.make_loss_fn(
+        config, q_block=int(check.get("q_block", 512)),
+        loss_chunk=int(check.get("loss_chunk", 1024)), wrong=wrong,
+        with_gradients=gradients)
+    positions = jnp.asarray(batch["position_ids"][0])
+    rows = batch["input_ids"][0]
+    if gradients and len(rows) != 1:
+        raise ValueError("the gradient check takes one row per step")
+    losses, out = [], {"grad_norm": None, "gain_grads": None}
+    with jax.default_device(device):
+        for r in range(len(rows)):
+            got = fn(params, jnp.asarray(rows[r]),
+                     jnp.asarray(batch["target_ids"][0][r]), positions)
+            if gradients:
+                losses.append(float(got[0]))
+                out["grad_norm"] = float(got[1])
+                out["gain_grads"] = dict(
+                    jax.device_get(got[2]["layers"]),
+                    norm=jax.device_get(got[2]["norm"]))
+            else:
+                losses.append(float(got))
+    return dict(out, loss=float(np.mean(losses)))
+
+
+def step_values(metrics: Dict[str, Any]) -> Dict[str, float]:
+    """Host copies of one step's loss, gradient norm and skip flag (the
+    readback waits for the step)."""
+    return {"loss": float(metrics["loss"]),
+            "grad_norm": float(metrics["grad_norm"]),
+            "update_skipped": float(metrics.get("update_skipped", 0))}
+
+
+def first_step_gain_gradients(trainer, args, grad_norm: float,
+                              ) -> Dict[str, np.ndarray]:
+    """The gradient the system's first step computed for every norm gain,
+    read from where the step left it: after one update from zero
+    moments, Adam's first moment is ``(1 - b1) x`` the gradient the
+    optimizer was handed, and the step hands it the gradient scaled by
+    ``min(1, max_grad_norm / grad_norm)`` (``parallel/spmd.py``
+    ``clip_by_global_norm``). 0.07 M numbers instead of two."""
+    import jax
+    import optax
+
+    from benchmarks.reference.qwen3 import GAIN_KEYS
+
+    mu = optax.tree_utils.tree_get(trainer.opt_state, "mu")
+    clip = 1.0
+    if args.max_grad_norm and args.max_grad_norm > 0:
+        clip = min(1.0, args.max_grad_norm / max(grad_norm, 1e-12))
+    unscale = 1.0 / ((1.0 - args.adam_beta1) * clip)
+    picked = {k: mu["layers"][k] for k in GAIN_KEYS if k in mu["layers"]}
+    picked["norm"] = mu["norm"]
+    return {k: np.asarray(jax.device_get(v), np.float32) * unscale
+            for k, v in picked.items()}
+
+
+def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
+    """One run of a train cell. ``ctx``: spec pieces, seed, seconds,
+    trace flag, process start time, compile counter, log function."""
+    import jax
+
+    from benchmarks.reference import check as check_lib
+    from scaletorch_tpu.trainer.trainer import Trainer
+
+    config, workload, traffic = ctx["config"], ctx["workload"], ctx["traffic"]
+    log = ctx["log"]
+    check = workload.get("check", {})
+    batches = traffic_lib.train_batches(
+        traffic, int(config["vocab_size"]), ctx["seed"])
+    args = trainer_arguments(config, workload, traffic, ctx["seed"])
+    trainer = Trainer(args)
+    log(f"trainer built: mesh {dict(trainer.mm.mesh.shape)}, attention "
+        f"{trainer.attention_backend}")
+    try:
+        problems: List[str] = []
+        want = workload.get("expect", {}).get("attention_backend")
+        if want and trainer.attention_backend != want:
+            problems.append(f"attention backend is "
+                            f"{trainer.attention_backend!r}, not {want!r}")
+
+        t0 = time.monotonic()
+        reference = reference_first_step(trainer, config, check, batches[0])
+        log(f"reference first step: loss {reference['loss']}, gradient "
+            f"norm {reference['grad_norm']} ({time.monotonic() - t0:.1f}s)")
+        # a cell measured at another size states its own tolerances in
+        # its file; the defaults are the 8k cell's (reference/check.py)
+        tolerances = {k: float(check[k]) for k in (
+            "loss_rtol", "grad_norm_rtol", "gain_grad_rtol") if k in check}
+        wrong = {}
+        for variant in workload.get("wrong_variants", []):
+            # how far a deliberately wrong computation lands from the
+            # reference, and whether the tolerance rejects it
+            off = reference_first_step(trainer, config, check, batches[0],
+                                       wrong=variant)
+            wrong[variant] = check_lib.judge_train(off, reference,
+                                                   **tolerances)
+            log(f"wrong variant {variant}: {wrong[variant]}")
+        first = step_values(trainer.step(batches[0]))
+        if check.get("gradients"):
+            first["gain_grads"] = first_step_gain_gradients(
+                trainer, args, first["grad_norm"])
+        verdict = check_lib.judge_train(first, reference, **tolerances)
+        if wrong:
+            verdict["wrong_variants"] = wrong
+        log(f"first step: {verdict}")
+        if not verdict["ok"]:
+            problems.append("first step disagrees with the reference")
+        for i in range(int(workload.get("warmup_steps", 2))):
+            step_values(trainer.step(batches[(i + 1) % len(batches)]))
+        jax.block_until_ready(trainer.params)
+        log("warm")
+
+        tokens_per_step = (int(traffic["sequence_length"])
+                           * int(traffic["sequences_per_step"]))
+        chips = len(jax.devices())
+        tracing = ctx["tracer"]
+        seconds = (float(workload.get("trace_seconds", ctx["seconds"]))
+                   if tracing.enabled else ctx["seconds"])
+        compiles_before = ctx["compiles"].snapshot()["backend_compiles"]
+        setup_s = time.monotonic() - ctx["process_start"]
+        tracing.start()
+        window_start = time.monotonic()
+        steps, seen, pending = 0, [], None
+        while True:
+            with tracing.step("train_step", steps):
+                metrics = trainer.step(batches[steps % len(batches)])
+            steps += 1
+            if pending is not None:
+                # the step before: its readback bounds the run-ahead to
+                # one step and keeps the device fed
+                with tracing.span("loss_readback"):
+                    seen.append(step_values(pending))
+            pending = metrics
+            if time.monotonic() - window_start >= seconds:
+                break
+        with tracing.span("loss_readback"):
+            seen.append(step_values(pending))
+        jax.block_until_ready(trainer.params)
+        window_s = time.monotonic() - window_start
+        tracing.stop()
+        compiled = (ctx["compiles"].snapshot()["backend_compiles"]
+                    - compiles_before)
+
+        if compiled:
+            problems.append(f"{compiled} programs compiled in the window")
+        bad = [s for s in seen
+               if not math.isfinite(s["loss"]) or s["update_skipped"]]
+        if bad:
+            problems.append(f"{len(bad)} steps non-finite or skipped")
+        rate = steps * tokens_per_step / window_s / chips
+        log(f"window: {steps} steps in {window_s:.3f}s, "
+            f"{rate:.1f} tokens/s/chip, last loss {seen[-1]['loss']:.4f}")
+        return {
+            "problems": problems,
+            "attempted": steps, "failed": len(bad),
+            "setup_s": setup_s, "window_s": window_s,
+            "values": {"train_tokens_per_s_per_chip": rate},
+            "counters": {"steps": steps},
+            "records": {},
+            "check": verdict,
+        }
+    finally:
+        trainer.close()
+
+
+def notes(ctx: Dict[str, Any], result: Dict[str, Any], peaks) -> List[str]:
+    """Lines printed before the result: MFU by the benchmark's causal
+    count and by the repo's ``6N + 12LHDS`` convention."""
+    from benchmarks.lib import costs
+
+    if not peaks:
+        return []
+    seq = int(ctx["traffic"]["sequence_length"])
+    rate = result["values"]["train_tokens_per_s_per_chip"]
+    peak = float(peaks["bf16_flops_per_s"])
+    causal = costs.train_flops_per_token(ctx["config"], seq)
+    square = costs.train_flops_per_token_full_square(ctx["config"], seq)
+    return [
+        f"mfu_causal_required={100 * rate * causal / peak:.2f}% "
+        f"({causal / 1e9:.3f} GFLOP/token, causal half, no recomputation)",
+        f"mfu_repo_convention={100 * rate * square / peak:.2f}% "
+        f"({square / 1e9:.3f} GFLOP/token, 6N + 12LHDS)",
+    ]
