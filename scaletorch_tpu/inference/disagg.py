@@ -147,6 +147,7 @@ from scaletorch_tpu.inference.kv_cache import (  # noqa: E402
     init_paged_kv_cache,
 )
 from scaletorch_tpu.telemetry.histogram import LogHistogram  # noqa: E402
+from scaletorch_tpu.telemetry.spans import span  # noqa: E402
 from scaletorch_tpu.utils.logger import get_logger  # noqa: E402
 
 logger = get_logger()
@@ -430,14 +431,14 @@ class DisaggregatedEngine(InferenceEngine):
 
     # ---- phase scheduler ---------------------------------------------
     def _admit(self) -> None:
-        with self._span("handoff", pending=len(self._handoff)):
+        with span("handoff", self.tracer, pending=len(self._handoff)):
             self._handoff_sweep(time.monotonic())
         self._prefill_admit()
         if self._handoff:
             # same-tick pipeline: a request prefilled above reaches a
             # decode slot before this tick's decode step, exactly the
             # colocated admit-then-decode cadence
-            with self._span("handoff", pending=len(self._handoff)):
+            with span("handoff", self.tracer, pending=len(self._handoff)):
                 self._handoff_sweep(time.monotonic())
 
     def _expire(self, now: float) -> None:
@@ -530,16 +531,16 @@ class DisaggregatedEngine(InferenceEngine):
         t0 = time.monotonic()
         for _, req, _ in admitted:
             self._req_event("b", req, "req.prefill", slice="prefill")
-        with self._span("prefill", admitted=len(admitted),
-                        slice="prefill"):
+        with self._phase("engine.tick.prefill"):
             first, _logits, finite, self.prefill_cache = self._prefill(
                 self._params_prefill, jnp.asarray(tokens),
                 jnp.asarray(tail_lens), jnp.asarray(starts),
                 jnp.asarray(write_mask), jnp.asarray(tables),
                 self.prefill_cache, jnp.asarray(self._prefill_keys))
         self.metrics.prefill_calls += 1
-        first = np.asarray(first)
-        finite = np.asarray(finite)
+        with self._phase("engine.tick.prefill_wait"):
+            first = np.asarray(first)
+            finite = np.asarray(finite)
         now = time.monotonic()
         prefill_s = now - t0
         self.metrics.prefill_busy_s += prefill_s

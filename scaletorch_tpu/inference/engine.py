@@ -79,10 +79,29 @@ from scaletorch_tpu.inference.resilience import (
 )
 from scaletorch_tpu.inference.sampling import SamplingParams
 from scaletorch_tpu.telemetry.histogram import LogHistogram
-from scaletorch_tpu.telemetry.spans import NOOP_SPAN
+from scaletorch_tpu.telemetry.spans import span
 from scaletorch_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
+
+# The engine's three phase clocks. Every instant of the engine thread is
+# booked to exactly one of them: STALL while a tick sweeps deadlines,
+# admits and prefills (every stream already resident stands still for
+# someone else's admission), DEVICE_WAIT while the host is blocked on
+# the decode program's result, HOST for everything else: the rest of
+# the tick and the time between ticks.
+STALL, DEVICE_WAIT, HOST = 0, 1, 2
+_PHASE_CLOCK = {
+    "engine.tick.sweep": STALL,
+    "engine.tick.admit": STALL,
+    "engine.tick.prefill": STALL,
+    "engine.tick.prefill_wait": STALL,
+    "engine.tick.decode_wait": DEVICE_WAIT,
+}
+# A tick that took longer than this, the time since the previous tick
+# ended included, is reported with its phases. A chat tick with a
+# prefill is 0.85 s on a v5e; the stalls this is for were 7 and 13 s.
+SLOW_TICK_S = 2.0
 
 
 @dataclass
@@ -128,6 +147,12 @@ class RequestResult:
     # access records and per-tenant histograms read these):
     queue_wait_s: Optional[float] = None   # submit -> slot admission
     prefill_s: Optional[float] = None      # its admission's prefill wall
+    # first token -> retirement (the last token of an ``ok`` request),
+    # split by the engine's phase clocks; the three sum to that span.
+    # None for a request that never emitted a token from a slot.
+    stall_s: Optional[float] = None        # others' sweeps/admissions
+    device_wait_s: Optional[float] = None  # host blocked on decode
+    host_s: Optional[float] = None         # the rest, gaps between ticks too
     prefix_hit: bool = False               # radix prefix pages shared
     trace_id: Optional[str] = None
 
@@ -148,6 +173,7 @@ class EngineMetrics:
     tokens_generated: int = 0
     prefill_calls: int = 0
     decode_steps: int = 0
+    slow_ticks: int = 0             # ticks over SLOW_TICK_S (gap included)
     queue_depth: int = 0
     active_slots: int = 0
     num_slots: int = 0
@@ -213,6 +239,7 @@ class EngineMetrics:
             "tokens_generated": self.tokens_generated,
             "prefill_calls": self.prefill_calls,
             "decode_steps": self.decode_steps,
+            "slow_ticks": self.slow_ticks,
             "queue_depth": self.queue_depth,
             "num_slots": self.num_slots,
             "slot_occupancy": (
@@ -247,7 +274,8 @@ class _Slot:
     """Host-side state of one decode slot."""
 
     __slots__ = ("request", "tokens", "position", "generated",
-                 "first_token_t", "last_token_t", "prefill_s", "prefix_hit")
+                 "first_token_t", "last_token_t", "prefill_s", "prefix_hit",
+                 "clocks_at_first")
 
     def __init__(self) -> None:
         self.request: Optional[Request] = None
@@ -258,10 +286,41 @@ class _Slot:
         self.last_token_t: Optional[float] = None  # TPOT inter-arrival
         self.prefill_s: Optional[float] = None     # its admission's prefill
         self.prefix_hit = False                    # radix pages shared
+        # the engine's phase clocks when the first token was emitted
+        self.clocks_at_first: Optional[Tuple[float, float, float]] = None
 
     @property
     def active(self) -> bool:
         return self.request is not None
+
+
+class _Phase:
+    """One phase of a tick: a span (telemetry/spans.py) whose two ends
+    are also boundaries of the engine's phase clocks. Phases follow one
+    another inside ``engine.tick``; they do not nest."""
+
+    __slots__ = ("_engine", "_name", "_t0", "_span")
+
+    def __init__(self, engine: "InferenceEngine", name: str) -> None:
+        self._engine = engine
+        self._name = name
+
+    def __enter__(self) -> None:
+        engine = self._engine
+        self._t0 = time.monotonic()
+        engine._advance(self._t0)
+        engine._open_clock = _PHASE_CLOCK.get(self._name, HOST)
+        self._span = span(self._name, engine.tracer)
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        engine = self._engine
+        now = time.monotonic()
+        engine._advance(now)
+        engine._open_clock = HOST
+        seconds = engine._tick_phase_s
+        seconds[self._name] = seconds.get(self._name, 0.0) + now - self._t0
 
 
 class InferenceEngine:
@@ -302,10 +361,12 @@ class InferenceEngine:
         is dense-only — pages are not slot-aligned).
     monitor : optional SystemMonitor; ``step()`` samples the metrics
         snapshot into its ring buffer every ``monitor_every`` steps.
-    tracer : optional ``telemetry.SpanTracer``; each tick records
-        ``tick`` / ``admission`` / ``prefill`` / ``decode`` spans (host
-        dispatch time — never a device sync; the vocabulary matches the
-        serving watchdog's beat phases). None = one branch per site.
+    tracer : optional ``telemetry.SpanTracer``. A tick is cut into the
+        ``engine.tick.*`` phases (docs/observability.md) as profiler
+        annotations whether or not a tracer is attached; with one, each
+        phase is a Chrome trace event too. The same boundaries feed the
+        always-on phase clocks behind ``RequestResult.stall_s`` /
+        ``device_wait_s`` / ``host_s`` and the ``slow_tick`` report.
     exporter : optional ``telemetry.TelemetryExporter``; metrics
         snapshots ride the same schema-versioned JSONL stream the
         trainer's step records use (kind ``engine_metrics``) on the
@@ -515,6 +576,17 @@ class InferenceEngine:
         self._base_keys = np.zeros((max_slots, 2), np.uint32)
         self._draining = False
         self.metrics = EngineMetrics(num_slots=max_slots)
+        # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
+        # the clock that is open, the last boundary; this tick's seconds
+        # by phase name; when the previous tick ended, and whether it
+        # left work behind (only then is the time until the next tick
+        # part of that tick: an idle engine is not a slow one)
+        self._clocks = [0.0, 0.0, 0.0]
+        self._open_clock = HOST
+        self._clock_t = time.monotonic()
+        self._tick_phase_s: Dict[str, float] = {}
+        self._tick_end_t = self._clock_t
+        self._tick_left_work = False
         if self._paged:
             self._update_page_gauges()
         # progress fingerprint of the last JSONL export: an idle engine
@@ -544,13 +616,18 @@ class InferenceEngine:
         total = min(prompt_len + max_new_tokens, self.max_seq)
         return ceil_div(total, self.page_size)
 
-    def _span(self, name: str, **args):
-        """Telemetry span when a tracer is attached, shared no-op
-        otherwise (one branch; spans time HOST dispatch, never a device
-        sync — the telemetry/spans.py contract)."""
-        if self.tracer is None:
-            return NOOP_SPAN
-        return self.tracer.span(name, **args)
+    def _phase(self, name: str) -> _Phase:
+        """One ``engine.tick.*`` phase: a span and two clock boundaries
+        (spans time HOST work, never a device sync — the
+        telemetry/spans.py contract)."""
+        return _Phase(self, name)
+
+    def _advance(self, now: float) -> None:
+        """Book the time since the last boundary to the open clock.
+        Called at every phase boundary and wherever a request reads the
+        clocks (first token, retirement), which may be mid-phase."""
+        self._clocks[self._open_clock] += now - self._clock_t
+        self._clock_t = now
 
     def _req_event(self, ph: str, req: Request, name: str, **args) -> None:
         """Request-scoped async span event (``ph`` in 'b'/'e'/'n') on
@@ -709,6 +786,7 @@ class InferenceEngine:
         ttft_t: Optional[float] = None,
         prefill_s: Optional[float] = None,
         prefix_hit: bool = False,
+        decode_clocks: Tuple[Optional[float], ...] = (None, None, None),
         now: float,
     ) -> None:
         """Record the single terminal result of ``req``. Every request
@@ -731,6 +809,9 @@ class InferenceEngine:
             queue_wait_s=queue_wait,
             prefill_s=prefill_s,
             prefix_hit=prefix_hit,
+            stall_s=decode_clocks[STALL],
+            device_wait_s=decode_clocks[DEVICE_WAIT],
+            host_s=decode_clocks[HOST],
             trace_id=req.trace_id,
         )
         if req.admit_time is not None and outcome in ("ok", "timeout"):
@@ -768,13 +849,20 @@ class InferenceEngine:
         and free the slot."""
         slot = self._slots[i]
         req = slot.request
+        decode_clocks: Tuple[Optional[float], ...] = (None, None, None)
+        if slot.clocks_at_first is not None:
+            self._advance(now)
+            decode_clocks = tuple(
+                c - c0 for c, c0 in zip(self._clocks, slot.clocks_at_first))
         self._finalize(
             req, outcome, tokens=slot.tokens[len(req.prompt):],
             reason=reason, detail=detail, ttft_t=slot.first_token_t,
-            prefill_s=slot.prefill_s, prefix_hit=slot.prefix_hit, now=now,
+            prefill_s=slot.prefill_s, prefix_hit=slot.prefix_hit,
+            decode_clocks=decode_clocks, now=now,
         )
         slot.request = None
         slot.tokens = []
+        slot.clocks_at_first = None
         if self._paged:
             # drop the slot's references; pages shared with live slots or
             # pinned by the radix tree survive (refcount > 1), the rest
@@ -1063,6 +1151,7 @@ class InferenceEngine:
         slot.last_token_t = None
         slot.prefill_s = None
         slot.prefix_hit = False
+        slot.clocks_at_first = None
         req.admit_time = time.monotonic()
         self.metrics.hist["queue_wait"].observe(
             req.admit_time - req.submit_time)
@@ -1073,43 +1162,47 @@ class InferenceEngine:
         self.metrics.requests_admitted += 1
 
     def _admit_dense(self) -> None:
-        free = [i for i, s in enumerate(self._slots) if not s.active]
-        if not free or not self._queue:
-            return
-        admitted: List[int] = []
-        tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
-        lengths = np.ones(self.max_slots, np.int32)
-        write_mask = np.zeros(self.max_slots, bool)
-        for i in free:
-            if not self._queue:
-                break
-            req = self._queue.popleft()
-            self._bind_slot(i, req)
-            tokens[i, : len(req.prompt)] = req.prompt
-            lengths[i] = len(req.prompt)
-            write_mask[i] = True
-            admitted.append(i)
+        with self._phase("engine.tick.admit"):
+            free = [i for i, s in enumerate(self._slots) if not s.active]
+            if not free or not self._queue:
+                return
+            admitted: List[int] = []
+            tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
+            lengths = np.ones(self.max_slots, np.int32)
+            write_mask = np.zeros(self.max_slots, bool)
+            for i in free:
+                if not self._queue:
+                    break
+                req = self._queue.popleft()
+                self._bind_slot(i, req)
+                tokens[i, : len(req.prompt)] = req.prompt
+                lengths[i] = len(req.prompt)
+                write_mask[i] = True
+                admitted.append(i)
         t0 = time.monotonic()
         for i in admitted:
             self._req_event("b", self._slots[i].request, "req.prefill")
-        with self._span("prefill", admitted=len(admitted)):
+        with self._phase("engine.tick.prefill"):
             first, _logits, finite, self.cache = self._prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(lengths),
                 jnp.asarray(write_mask), self.cache,
                 jnp.asarray(self._base_keys),
             )
         self.metrics.prefill_calls += 1
-        first = np.asarray(first)
-        finite = np.asarray(finite)
-        now = time.monotonic()
-        self._note_prefill(admitted, now - t0)
-        poisoned = [i for i in admitted if not finite[i]]
-        if poisoned:
-            self._quarantine(poisoned, now, where="prefill")
-        for i in admitted:
-            if finite[i]:
-                self._emit(i, int(first[i]), now)
-        self.metrics.queue_depth = len(self._queue)
+        with self._phase("engine.tick.prefill_wait"):
+            first = np.asarray(first)
+            finite = np.asarray(finite)
+        with self._phase("engine.tick.emit"):
+            now = time.monotonic()
+            self._note_prefill(admitted, now - t0)
+            poisoned = [i for i in admitted if not finite[i]]
+            if poisoned:
+                self._quarantine(poisoned, now, where="prefill")
+            for i in admitted:
+                if finite[i]:
+                    self._emit(i, int(first[i]), now)
+            self.metrics.queue_depth = len(self._queue)
+            del _logits  # freed inside the phase, as in step()
 
     def _note_prefill(self, admitted: List[int], prefill_s: float) -> None:
         """Attribute one batched prefill's wall time to every request it
@@ -1156,45 +1249,46 @@ class InferenceEngine:
         return shared, shared_pages + own
 
     def _admit_paged(self) -> None:
-        free = [i for i, s in enumerate(self._slots) if not s.active]
-        if not free or not self._queue:
-            return
-        admitted: List[int] = []
-        tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
-        tail_lens = np.ones(self.max_slots, np.int32)
-        starts = np.zeros(self.max_slots, np.int32)
-        write_mask = np.zeros(self.max_slots, bool)
-        for i in free:
-            if not self._queue:
-                break
-            reserved = self._reserve_pages(self._queue[0])
-            if reserved is None:
-                break  # page budget exhausted: head of the line waits
-            req = self._queue.popleft()
-            shared, pages = reserved
-            self._bind_slot(i, req)
-            self._slot_pages[i] = pages
-            self._slot_frozen[i] = shared // self.page_size
-            self._tables[i, :] = TRASH_PAGE
-            self._tables[i, : len(pages)] = pages
-            self._tables_dev = None
-            tail = req.prompt[shared:]
-            tokens[i, : len(tail)] = tail
-            tail_lens[i] = len(tail)
-            starts[i] = shared
-            write_mask[i] = True
-            if shared:
-                self.metrics.prefix_hits += 1
-                self.metrics.prefill_tokens_saved += shared
-                self._slots[i].prefix_hit = True
-            admitted.append(i)
-        if not admitted:
-            return
+        with self._phase("engine.tick.admit"):
+            free = [i for i, s in enumerate(self._slots) if not s.active]
+            if not free or not self._queue:
+                return
+            admitted: List[int] = []
+            tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
+            tail_lens = np.ones(self.max_slots, np.int32)
+            starts = np.zeros(self.max_slots, np.int32)
+            write_mask = np.zeros(self.max_slots, bool)
+            for i in free:
+                if not self._queue:
+                    break
+                reserved = self._reserve_pages(self._queue[0])
+                if reserved is None:
+                    break  # page budget exhausted: head of the line waits
+                req = self._queue.popleft()
+                shared, pages = reserved
+                self._bind_slot(i, req)
+                self._slot_pages[i] = pages
+                self._slot_frozen[i] = shared // self.page_size
+                self._tables[i, :] = TRASH_PAGE
+                self._tables[i, : len(pages)] = pages
+                self._tables_dev = None
+                tail = req.prompt[shared:]
+                tokens[i, : len(tail)] = tail
+                tail_lens[i] = len(tail)
+                starts[i] = shared
+                write_mask[i] = True
+                if shared:
+                    self.metrics.prefix_hits += 1
+                    self.metrics.prefill_tokens_saved += shared
+                    self._slots[i].prefix_hit = True
+                admitted.append(i)
+            if not admitted:
+                return
         t0 = time.monotonic()
         for i in admitted:
             self._req_event("b", self._slots[i].request, "req.prefill",
                             prefix_hit=self._slots[i].prefix_hit)
-        with self._span("prefill", admitted=len(admitted)):
+        with self._phase("engine.tick.prefill"):
             first, _logits, finite, self.cache = self._prefill(
                 self.params, jnp.asarray(tokens), jnp.asarray(tail_lens),
                 jnp.asarray(starts), jnp.asarray(write_mask),
@@ -1202,35 +1296,38 @@ class InferenceEngine:
                 jnp.asarray(self._base_keys),
             )
         self.metrics.prefill_calls += 1
-        first = np.asarray(first)
-        finite = np.asarray(finite)
-        now = time.monotonic()
-        self._note_prefill(admitted, now - t0)
-        poisoned = [i for i in admitted if not finite[i]]
-        if poisoned:
-            # skip radix registration for poison prompts — their pages
-            # hold non-finite K/V and must never be shared
-            self._quarantine(poisoned, now, where="prefill")
-        for i in admitted:
-            if not finite[i]:
-                continue
-            if self.radix is not None:
-                slot = self._slots[i]
-                plen = len(slot.request.prompt)
-                frozen = (plen // self.page_size) * self.page_size
-                if frozen:
-                    n = frozen // self.page_size
-                    self.radix.insert(
-                        slot.request.prompt[:frozen],
-                        [int(p) for p in self._tables[i, :n]],
-                    )
-                    # the fully-written prompt pages are immutable from
-                    # here on — exempt from quarantine clears and
-                    # shareable by later admissions
-                    self._slot_frozen[i] = n
-            self._emit(i, int(first[i]), now)
-        self._update_page_gauges()
-        self.metrics.queue_depth = len(self._queue)
+        with self._phase("engine.tick.prefill_wait"):
+            first = np.asarray(first)
+            finite = np.asarray(finite)
+        with self._phase("engine.tick.emit"):
+            now = time.monotonic()
+            self._note_prefill(admitted, now - t0)
+            poisoned = [i for i in admitted if not finite[i]]
+            if poisoned:
+                # skip radix registration for poison prompts — their
+                # pages hold non-finite K/V and must never be shared
+                self._quarantine(poisoned, now, where="prefill")
+            for i in admitted:
+                if not finite[i]:
+                    continue
+                if self.radix is not None:
+                    slot = self._slots[i]
+                    plen = len(slot.request.prompt)
+                    frozen = (plen // self.page_size) * self.page_size
+                    if frozen:
+                        n = frozen // self.page_size
+                        self.radix.insert(
+                            slot.request.prompt[:frozen],
+                            [int(p) for p in self._tables[i, :n]],
+                        )
+                        # the fully-written prompt pages are immutable
+                        # from here on — exempt from quarantine clears
+                        # and shareable by later admissions
+                        self._slot_frozen[i] = n
+                self._emit(i, int(first[i]), now)
+            self._update_page_gauges()
+            self.metrics.queue_depth = len(self._queue)
+            del _logits  # freed inside the phase, as in step()
 
     def _emit(self, i: int, token: int, now: float) -> None:
         """Record one generated token for slot i; retire the slot when a
@@ -1243,6 +1340,8 @@ class InferenceEngine:
         self.metrics._window_tokens += 1
         if slot.first_token_t is None:
             slot.first_token_t = now
+            self._advance(now)
+            slot.clocks_at_first = tuple(self._clocks)
             self.metrics.record_ttft(now - req.submit_time)
         else:
             # per-token inter-arrival (TPOT): decode cadence as the
@@ -1282,10 +1381,12 @@ class InferenceEngine:
         since the PREVIOUS ``step()`` returned — including requests
         finalized between ticks (a ``shed``/``rejected`` recorded inside
         ``submit()``, a ``cancel()``), so a push-delivery bridge sees
-        each terminal result exactly once. With a tracer attached the
-        tick records ``tick`` / ``admission`` / ``prefill`` / ``decode``
-        spans."""
+        each terminal result exactly once. The tick is one
+        ``engine.tick`` span cut into ``engine.tick.*`` phases
+        (docs/observability.md), each also a boundary of the phase
+        clocks."""
         tick = self.metrics.decode_steps + 1  # the decode step this tick runs
+        tick_t0 = time.monotonic()
         if self.watchdog is not None:
             self.watchdog.beat(step=self.metrics.decode_steps,
                                phase="serve-step")
@@ -1301,67 +1402,102 @@ class InferenceEngine:
                 for s in self._slots:
                     if s.active:
                         s.request.deadline = past
-        with self._span("tick", tick=tick):
-            with self._span("admission"):
+        with span("engine.tick", self.tracer, tick=tick):
+            with self._phase("engine.tick.sweep"):
                 self._expire(time.monotonic())
-                self._admit()
+            self._admit()
             active_idx = [i for i, s in enumerate(self._slots) if s.active]
             if active_idx:
+                stall = 0.0
                 if inj is not None:
                     poison = inj.take_nan_logits(tick)
                     if poison is not None:
                         self._poison_slot(poison)
                     stall = inj.take_slow_decode(tick)
-                    if stall > 0:
-                        time.sleep(stall)
-                tokens = np.zeros(self.max_slots, np.int32)
-                positions = np.zeros(self.max_slots, np.int32)
-                active = np.zeros(self.max_slots, bool)
-                for i in active_idx:
-                    slot = self._slots[i]
-                    # feed the last emitted token at its absolute position:
-                    # the prompt occupies [0, len), generated token g sits at
-                    # len + g - 1
-                    tokens[i] = slot.tokens[-1]
-                    positions[i] = slot.position + slot.generated - 1
-                    active[i] = True
-                # the paged step takes the page tables between the slot
-                # mask and the cache; the dense signature is otherwise
-                # identical
-                tables = (
-                    (self._tables_device(),) if self._paged else ())
-                with self._span("decode", active=len(active_idx)):
+                with self._phase("engine.tick.feed"):
+                    tokens = np.zeros(self.max_slots, np.int32)
+                    positions = np.zeros(self.max_slots, np.int32)
+                    active = np.zeros(self.max_slots, bool)
+                    for i in active_idx:
+                        slot = self._slots[i]
+                        # feed the last emitted token at its absolute
+                        # position: the prompt occupies [0, len),
+                        # generated token g sits at len + g - 1
+                        tokens[i] = slot.tokens[-1]
+                        positions[i] = slot.position + slot.generated - 1
+                        active[i] = True
+                    # the paged step takes the page tables between the
+                    # slot mask and the cache; the dense signature is
+                    # otherwise identical
+                    tables = (
+                        (self._tables_device(),) if self._paged else ())
+                    feed = (jnp.asarray(tokens), jnp.asarray(positions),
+                            jnp.asarray(active), *tables)
+                    base_keys = jnp.asarray(self._base_keys)
+                with self._phase("engine.tick.decode"):
                     nxt, _logits, finite, self.cache = self._decode(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(active),
-                        *tables, self.cache,
-                        jnp.asarray(self._base_keys),
-                    )
+                        self.params, *feed, self.cache, base_keys)
                 self.metrics.decode_steps += 1
-                nxt = np.asarray(nxt)
-                finite = np.asarray(finite)
-                now = time.monotonic()
-                poisoned = [i for i in active_idx if not finite[i]]
-                if poisoned:
-                    self._quarantine(poisoned, now, where="decode")
-                for i in active_idx:
-                    if finite[i]:
-                        self._emit(i, int(nxt[i]), now)
-        self.metrics.active_slots = sum(s.active for s in self._slots)
-        self.metrics.queue_depth = len(self._queue)
-        if (
-            (self.monitor is not None or self.exporter is not None)
-            and self.metrics.decode_steps % self.monitor_every == 0
-        ):
-            if self.monitor is not None:
-                self.monitor.sample(counters=self.metrics.snapshot())
-            if self.exporter is not None:
-                # idle ticks keep the progress fingerprint unchanged —
-                # only movement appends to the durable stream (the ring
-                # buffer above is bounded, the file is not)
-                self._export_snapshot()
+                with self._phase("engine.tick.decode_wait"):
+                    if stall > 0:
+                        # an injected slow decode is booked where a real
+                        # one would be: the host waiting on the step
+                        time.sleep(stall)
+                    nxt = np.asarray(nxt)
+                    finite = np.asarray(finite)
+                with self._phase("engine.tick.emit"):
+                    now = time.monotonic()
+                    poisoned = [i for i in active_idx if not finite[i]]
+                    if poisoned:
+                        self._quarantine(poisoned, now, where="decode")
+                    for i in active_idx:
+                        if finite[i]:
+                            self._emit(i, int(nxt[i]), now)
+                    # release the step's device arrays inside a phase:
+                    # freeing the logits costs 0.2 ms on a v5e, which
+                    # would otherwise fall after the tick's last phase,
+                    # when step() returns
+                    del _logits, feed, base_keys
+            with self._phase("engine.tick.export"):
+                self.metrics.active_slots = sum(
+                    s.active for s in self._slots)
+                self.metrics.queue_depth = len(self._queue)
+                if (
+                    (self.monitor is not None or self.exporter is not None)
+                    and self.metrics.decode_steps % self.monitor_every == 0
+                ):
+                    if self.monitor is not None:
+                        self.monitor.sample(
+                            counters=self.metrics.snapshot())
+                    if self.exporter is not None:
+                        # idle ticks keep the progress fingerprint
+                        # unchanged — only movement appends to the
+                        # durable stream (the ring buffer above is
+                        # bounded, the file is not)
+                        self._export_snapshot()
+        self._close_tick(tick, tick_t0)
         finished, self._finished_tick = self._finished_tick, []
         return finished
+
+    def _close_tick(self, tick: int, tick_t0: float) -> None:
+        """A tick that took over ``SLOW_TICK_S``, the time since the
+        previous tick ended included when that tick left work behind,
+        is counted, logged with every phase's seconds and exported as
+        one ``slow_tick`` record."""
+        now = time.monotonic()
+        phases, self._tick_phase_s = self._tick_phase_s, {}
+        gap = tick_t0 - self._tick_end_t if self._tick_left_work else 0.0
+        self._tick_end_t = now
+        self._tick_left_work = self.pending > 0
+        wall = now - tick_t0 + gap
+        if wall <= SLOW_TICK_S:
+            return
+        self.metrics.slow_ticks += 1
+        record = {"tick": tick, "wall_s": wall, "gap_before_s": gap,
+                  "phases_s": phases}
+        logger.warning("slow tick: %s", record)
+        if self.exporter is not None:
+            self.exporter.emit("slow_tick", record)
 
     def tick(self) -> List[RequestResult]:
         """Single-step driving alias for ``step()`` — the vocabulary the

@@ -214,6 +214,7 @@ def tp_region_helpers(
     return pv, enter_full_seq, col, row
 
 
+@jax.named_scope("attn")
 def attention_block(
     x: jax.Array,
     layer: Params,
@@ -279,14 +280,17 @@ def _decoder_layer(
     x = attention_block(x, layer, cos, sin, cfg, attn_fn, helpers)
 
     # ---- SwiGLU MLP (reference llama.py:207-249) ----------------------------
-    h = rms_norm(x, pv(layer["post_attention_layernorm"]), cfg.rms_norm_eps)
-    h = enter_full_seq(h)
-    gate = col(h, layer["gate_proj"])
-    up = col(h, layer["up_proj"])
-    x = x + row(swiglu(gate, up), layer["down_proj"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, pv(layer["post_attention_layernorm"]),
+                     cfg.rms_norm_eps)
+        h = enter_full_seq(h)
+        gate = col(h, layer["gate_proj"])
+        up = col(h, layer["up_proj"])
+        x = x + row(swiglu(gate, up), layer["down_proj"])
     return x
 
 
+@jax.named_scope("embed")
 def embed(
     params: Params,
     input_ids: jax.Array,
@@ -502,6 +506,7 @@ def lm_head_weight(
 # and XLA partitions the plain einsums; no shard_map/tp_axis threading.
 
 
+@jax.named_scope("attn")
 def attention_block_cached(
     x: jax.Array,
     layer: Params,
@@ -560,6 +565,7 @@ def attention_block_cached(
     return x + attn @ layer["o_proj"].astype(cdt), cache_k, cache_v
 
 
+@jax.named_scope("mlp")
 def _mlp_block(x: jax.Array, layer: Params, cfg: LlamaConfig) -> jax.Array:
     """Dense SwiGLU MLP sub-block with residual (single-device form; the
     TP/SP training path stays in ``_decoder_layer``)."""

@@ -166,6 +166,7 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -298,6 +299,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
         out_shape=_struct((b, hq, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, g, lse4, delta4)
 
     def clamp_i(jj, i):
@@ -338,6 +340,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, g, lse4, delta4)
     return dq, dk, dv
 

@@ -226,6 +226,7 @@ def pallas_paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
         compiler_params=_semantics("p", "p", "a"),
         interpret=interpret,
+        name="paged_decode",
     )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
       q_r, pool_k, pool_v)
     return out.reshape(b, hq, d)

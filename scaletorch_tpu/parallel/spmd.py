@@ -222,11 +222,12 @@ def _build_losses(
             hidden, aux, extras = out, 0.0, {}
         # Head + CE fused over sequence chunks: full [B, S, V] logits never
         # materialise (vocab-parallel over tp AND chunk-rematerialised).
-        head = head_weight_fn(p, model_cfg, "tp")
-        ce = fused_vocab_parallel_cross_entropy(
-            hidden, head, mb["target_ids"], axis="tp",
-            chunk_size=int(get_env("SCALETORCH_TPU_CE_CHUNK") or 1024),
-        )
+        with jax.named_scope("lm_head_loss"):
+            head = head_weight_fn(p, model_cfg, "tp")
+            ce = fused_vocab_parallel_cross_entropy(
+                hidden, head, mb["target_ids"], axis="tp",
+                chunk_size=int(get_env("SCALETORCH_TPU_CE_CHUNK") or 1024),
+            )
         return ce + aux, extras
 
     if mm.pp == 1:
@@ -785,17 +786,18 @@ def make_spmd_train_step(
         # mu/nu to fp32 on the first update and break buffer donation.
         grads = jax.tree.map(lambda g, w: g.astype(w.dtype), grads, p)
         metrics = {"loss": loss, "grad_norm": grad_norm, **extras}
-        if nonfinite_guard:
-            from scaletorch_tpu.trainer.train_step import guarded_update
+        with jax.named_scope("optimizer"):
+            if nonfinite_guard:
+                from scaletorch_tpu.trainer.train_step import guarded_update
 
-            ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-            p, opt_state, skipped = guarded_update(
-                tx, p, opt_state, grads, ok
-            )
-            metrics["update_skipped"] = skipped
-        else:
-            updates, opt_state = tx.update(grads, opt_state, p)
-            p = optax.apply_updates(p, updates)
+                ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+                p, opt_state, skipped = guarded_update(
+                    tx, p, opt_state, grads, ok
+                )
+                metrics["update_skipped"] = skipped
+            else:
+                updates, opt_state = tx.update(grads, opt_state, p)
+                p = optax.apply_updates(p, updates)
         return p, opt_state, metrics
 
     sharded = jax.shard_map(

@@ -81,6 +81,7 @@ from scaletorch_tpu.serving.admission import (
     TenantConfig,
 )
 from scaletorch_tpu.serving.protocol import (
+    DECODE_CLOCK_FIELDS,
     GenerateRequest,
     ProtocolError,
 )
@@ -91,7 +92,7 @@ from scaletorch_tpu.serving.router import (
 from scaletorch_tpu.serving.slo import LATENCY_OUTCOMES, evaluate_slo
 from scaletorch_tpu.telemetry.export import render_families
 from scaletorch_tpu.telemetry.histogram import LogHistogram, TenantHistograms
-from scaletorch_tpu.telemetry.spans import NOOP_SPAN
+from scaletorch_tpu.telemetry.spans import span
 from scaletorch_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -389,7 +390,8 @@ class EngineWorker:
         drain_ticks = 0
         try:
             while True:
-                self._drain_inbox()
+                with span("engine.tick_loop.inbox", engine.tracer):
+                    self._drain_inbox()
                 if self._stop:
                     if not engine.pending:
                         break
@@ -399,9 +401,10 @@ class EngineWorker:
                         break
                 if engine.pending:
                     finished = engine.tick()
-                    for result in finished:
-                        self._deliver(result)
-                    self._notify_tick()
+                    with span("engine.tick_loop.deliver", engine.tracer):
+                        for result in finished:
+                            self._deliver(result)
+                        self._notify_tick()
                 elif not self._stop:
                     # an idle engine runs no step() and so beats no
                     # watchdog — beat it here, or an armed serving
@@ -411,10 +414,11 @@ class EngineWorker:
                     if watchdog is not None:
                         watchdog.beat(step=engine.metrics.decode_steps,
                                       phase="idle")
-                    try:
-                        fn = self._inbox.get(timeout=self.idle_wait_s)
-                    except queue.Empty:
-                        continue
+                    with span("engine.tick_loop.idle", engine.tracer):
+                        try:
+                            fn = self._inbox.get(timeout=self.idle_wait_s)
+                        except queue.Empty:
+                            continue
                     fn()
         except Exception:
             logger.exception(
@@ -512,6 +516,23 @@ class GatewayMetrics:
 # --------------------------------------------------------------------------
 # The gateway
 # --------------------------------------------------------------------------
+
+
+def _decode_clock_fields(
+        result: Optional[RequestResult]) -> Dict[str, Optional[float]]:
+    """The access record's share of the engine's phase clocks: where a
+    stream's time went between its first and its last token (frozen
+    behind others' admissions, waiting on the chip, waiting on Python),
+    in all and per decode step. Null when the engine reported none, and
+    per token under two tokens."""
+    fields: Dict[str, Optional[float]] = {}
+    steps = len(result.tokens) - 1 if result is not None else 0
+    for name in DECODE_CLOCK_FIELDS:
+        total = getattr(result, name) if result is not None else None
+        fields[name] = total
+        fields[f"{name}_per_token"] = (
+            total / steps if total is not None and steps > 0 else None)
+    return fields
 
 
 class _Pending:
@@ -722,13 +743,6 @@ class ServingGateway:
         return out
 
     # -- tracing -----------------------------------------------------------
-    def _span(self, name: str, **args):
-        """Complete-event span on the gateway (asyncio) thread; shared
-        no-op when untraced — the engine's one-branch contract."""
-        if self.tracer is None:
-            return NOOP_SPAN
-        return self.tracer.span(name, **args)
-
     def _req_event(self, ph: str, trace_id: Optional[str], name: str,
                    **args) -> None:
         """Request-scoped async event on the trace_id track (same
@@ -1068,7 +1082,7 @@ class ServingGateway:
                         "deadline exceeded in the gateway queue")
                     continue
                 try:
-                    with self._span("gw.route"):
+                    with span("gw.route", self.tracer):
                         replica_id = self.router.route(
                             pending.req.prompt, headroom=headroom)
                 except NoReplicaAvailable:
@@ -1227,6 +1241,7 @@ class ServingGateway:
                     result.queue_wait_s if result is not None else None),
                 "prefill_s": (
                     result.prefill_s if result is not None else None),
+                **_decode_clock_fields(result),
                 "ttft_s": ttft,
                 "e2e_s": e2e,
                 "prefix_hit": (
@@ -1624,7 +1639,7 @@ class ServingGateway:
         self._req_event("b", trace_id, "gw.request",
                         parent_span=parent[1] if parent else None)
         try:
-            with self._span("gw.parse", bytes=len(body)):
+            with span("gw.parse", self.tracer, bytes=len(body)):
                 req = protocol.parse_generate_request(
                     body, header_tenant=headers.get("x-tenant"))
         except ProtocolError as exc:

@@ -66,6 +66,12 @@ BAD_REQUEST_STATUS = 400
 
 DEFAULT_TENANT = "default"
 
+# A request's life from its first token to its retirement, split by the
+# engine's three phase clocks (``inference/engine.py``): the names of
+# the ``RequestResult`` fields, of the additive fields on the replica
+# wire's ``done`` event and of the gateway's ``access`` record.
+DECODE_CLOCK_FIELDS = ("stall_s", "device_wait_s", "host_s")
+
 
 # --------------------------------------------------------------------------
 # W3C trace context (traceparent)

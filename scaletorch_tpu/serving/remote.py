@@ -30,7 +30,8 @@ Wire schema (all JSON bodies carry ``v: 1``; the SSE framing is
                          internal hop's correlation key) -> SSE stream:
                          ``submitted`` (request_id), ``token``*, exactly
                          one ``done`` (result payload + additive
-                         ``queue_wait_s``/``prefill_s``/``prefix_hit``).
+                         ``queue_wait_s``/``prefill_s``/``prefix_hit``
+                         and ``stall_s``/``device_wait_s``/``host_s``).
                          The server watches the socket: a gateway that
                          dies mid-stream has its request cancelled and
                          its pages released, same as a dropped SSE
@@ -102,7 +103,11 @@ from typing import (
 )
 
 from scaletorch_tpu.serving import protocol
-from scaletorch_tpu.serving.protocol import GenerateRequest, ProtocolError
+from scaletorch_tpu.serving.protocol import (
+    DECODE_CLOCK_FIELDS,
+    GenerateRequest,
+    ProtocolError,
+)
 from scaletorch_tpu.serving.router import page_chunk_hashes
 from scaletorch_tpu.utils.logger import get_logger
 
@@ -563,6 +568,8 @@ def _done_payload(req: GenerateRequest, result: Any) -> Dict[str, Any]:
     payload["queue_wait_s"] = result.queue_wait_s
     payload["prefill_s"] = result.prefill_s
     payload["prefix_hit"] = bool(result.prefix_hit)
+    for clock in DECODE_CLOCK_FIELDS:
+        payload[clock] = getattr(result, clock)
     return payload
 
 
@@ -1020,7 +1027,8 @@ class RemoteEngineWorker:
                      detail: Optional[str],
                      queue_wait_s: Optional[float] = None,
                      prefill_s: Optional[float] = None,
-                     prefix_hit: bool = False) -> Any:
+                     prefix_hit: bool = False,
+                     **decode_clocks: Optional[float]) -> Any:
         from scaletorch_tpu.inference.engine import RequestResult
 
         return RequestResult(
@@ -1028,7 +1036,7 @@ class RemoteEngineWorker:
             tokens=list(tokens), finish_reason=finish_reason,
             outcome=outcome, detail=detail, queue_wait_s=queue_wait_s,
             prefill_s=prefill_s, prefix_hit=prefix_hit,
-            trace_id=req.trace_id)
+            trace_id=req.trace_id, **decode_clocks)
 
     def _stream_request(self, req: GenerateRequest,
                         ttl_s: Optional[float],
@@ -1093,7 +1101,9 @@ class RemoteEngineWorker:
                         detail=payload.get("detail"),
                         queue_wait_s=payload.get("queue_wait_s"),
                         prefill_s=payload.get("prefill_s"),
-                        prefix_hit=bool(payload.get("prefix_hit"))))
+                        prefix_hit=bool(payload.get("prefix_hit")),
+                        **{clock: payload.get(clock)
+                           for clock in DECODE_CLOCK_FIELDS}))
                     self._fire_tick()
                     return
         except (OSError, http.client.HTTPException, ValueError,

@@ -2,8 +2,10 @@
 
 One layer shared by the trainer and the inference engine:
 
-  * ``spans``      — host-side span tracing (Chrome trace events +
-                     crash-report tail; never forces a device sync)
+  * ``spans``      — host-side span tracing: one ``span()``, always a
+                     profiler annotation, plus Chrome trace events and
+                     a crash-report tail when a tracer is attached;
+                     never forces a device sync
   * ``profiling``  — slow-step-triggered + manual ``jax.profiler``
                      windows, SIGUSR1 live snapshots
   * ``stragglers`` — per-host step/data-fetch times riding the
@@ -15,7 +17,7 @@ One layer shared by the trainer and the inference engine:
 ``--telemetry_dir`` / ``SCALETORCH_TPU_TELEMETRY_DIR``), it owns the
 tracer/exporter/profiler/snapshotter lifecycle so the trainer and
 serving loops wire one object, not four. Disabled, every component is
-``None`` and each instrumentation site costs one branch.
+``None``; a span site then costs its profiler annotation alone.
 
 See docs/observability.md for the span vocabulary, the JSONL schema and
 its version policy, profiler triggers and the Perfetto how-to.
@@ -45,13 +47,13 @@ from scaletorch_tpu.telemetry.profiling import (
     SlowStepDetector,
     parse_profile_steps,
 )
-from scaletorch_tpu.telemetry.spans import NOOP_SPAN, SpanTracer, load_trace
+from scaletorch_tpu.telemetry.spans import SpanTracer, load_trace, span
 from scaletorch_tpu.telemetry.stragglers import StragglerDetector
 
 __all__ = [
     "Telemetry",
     "SpanTracer",
-    "NOOP_SPAN",
+    "span",
     "load_trace",
     "TelemetryExporter",
     "PrometheusEndpoint",

@@ -57,12 +57,15 @@ SCHEMA_VERSION = 1
 # disaggregated engine's per-slice state alongside each
 # ``engine_metrics`` snapshot (inference/disagg.py: slice device
 # counts, handoff counters/bytes, prefill-pool occupancy, per-slice
-# busy fractions). Free-form kinds are allowed;
+# busy fractions). ``slow_tick`` records one engine tick that took
+# over ``inference.engine.SLOW_TICK_S``, the wait since the previous
+# tick included: tick, wall_s, gap_before_s and phases_s, the seconds of
+# every ``engine.tick.*`` phase in it. Free-form kinds are allowed;
 # these are the ones consumers can rely on. Adding a kind is additive —
 # v stays 1.
 KNOWN_KINDS = ("train_step", "engine_metrics", "gateway_metrics",
                "access", "latency_histograms", "supervisor", "warmup",
-               "membership", "disagg")
+               "membership", "disagg", "slow_tick")
 
 
 class TelemetryExporter:

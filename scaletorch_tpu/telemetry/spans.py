@@ -1,19 +1,27 @@
-"""Host-side span tracing: Chrome-trace-event JSON + a crash-report tail.
+"""Host-side span tracing: one span API, two sinks.
 
 The timeline half of the observability layer: where a MetricsLogger line
 says *how fast* a step was, the span stream says *where the time went*
-— data fetch vs step dispatch vs checkpoint save on the trainer,
-admission vs prefill vs decode on the inference engine. Spans are
-written as Chrome trace events (the ``traceEvents`` JSON array format),
-so a run's timeline loads directly in Perfetto (ui.perfetto.dev) or
-``chrome://tracing``.
+— data fetch vs step dispatch vs checkpoint save on the trainer, the
+phases of a tick on the inference engine. ``span(name, tracer)`` is the
+one way the program opens a span:
+
+  * it always enters a ``jax.profiler.TraceAnnotation``: with a
+    profiler session open (a benchmark's ``--trace 1``, an
+    ``AnomalyProfiler`` window, a SIGUSR1 snapshot) the span lands on
+    the ``/host:CPU`` plane, on the device events' clock, with nothing
+    configured; with no session it is a flag test;
+  * with a ``SpanTracer`` attached it also records a Chrome trace event
+    (the ``traceEvents`` JSON array format), so a run's timeline loads
+    directly in Perfetto (ui.perfetto.dev) or ``chrome://tracing``.
 
 Two contracts every instrumentation site relies on:
 
-  * **disabled is free** — a disabled tracer costs exactly one branch
-    per call site (``if tracer is not None`` at the caller, or the
-    ``self.enabled`` check inside every method). No event dicts, no
-    clock reads, no locks.
+  * **off is cheap, not free** — with no session and no tracer a span
+    costs one ``TraceAnnotation`` (about 0.3 us on a CPU core); a
+    disabled tracer adds one branch. No event dicts, no locks. Hot-path
+    spans pass no keyword arguments (each builds a string when a
+    session is open).
   * **spans never force a device sync** — span boundaries measure HOST
     time only: the time to *dispatch* work to the accelerator, not to
     complete it. JAX's async dispatch means a ``step_dispatch`` span
@@ -43,19 +51,47 @@ from collections import deque
 from typing import IO, Any, Dict, List, Optional
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager for disabled tracers."""
+_trace_annotation = None
 
-    __slots__ = ()
 
-    def __enter__(self) -> "_NoopSpan":
+def _annotation(name: str, args: Dict[str, Any]):
+    """A ``jax.profiler.TraceAnnotation`` (imported on first use: this
+    package stays importable by tooling that runs without jax). The
+    annotation starts when it is built, so build it at the ``with``."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name, **args)
+
+
+class _BothSinks:
+    """One span in the profiler's trace and in a ``SpanTracer``."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, chrome_span: "_Span") -> None:
+        self._annotation = annotation
+        self._span = chrome_span
+
+    def __enter__(self) -> "_BothSinks":
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
-        return None
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
 
 
-NOOP_SPAN = _NoopSpan()
+def span(name: str, tracer: Optional["SpanTracer"] = None, **args: Any):
+    """Context manager timing one host-side region (dispatch, not
+    device completion — see the module contract): always a profiler
+    annotation, and a Chrome event too when ``tracer`` is enabled."""
+    annotation = _annotation(name, args)
+    if tracer is None or not tracer.enabled:
+        return annotation
+    return _BothSinks(annotation, _Span(tracer, name, args or None))
 
 
 class _Span:
@@ -129,6 +165,7 @@ class SpanTracer:
         self._phase_name: Optional[str] = None
         self._phase_t0 = 0
         self._phase_args: Optional[Dict[str, Any]] = None
+        self._phase_annotation: Any = None
 
     # ---- clock -----------------------------------------------------------
     def _now_us(self) -> int:
@@ -136,36 +173,38 @@ class SpanTracer:
 
     # ---- public API ------------------------------------------------------
     def span(self, name: str, **args: Any):
-        """Context manager timing one host-side region (dispatch, not
-        device completion — see the module contract)."""
-        if not self.enabled:
-            return NOOP_SPAN
-        return _Span(self, name, args or None)
+        """``span(name, self, **args)``: the module's one span."""
+        return span(name, self, **args)
 
     def phase(self, name: str, step: Optional[int] = None) -> None:
-        """Close the open phase span (if any) and start ``name``. The
-        trainer's ``_beat`` sites call this, so the span vocabulary IS
-        the watchdog phase vocabulary."""
+        """Close the open phase span (if any) and start ``name``, in
+        both sinks. The trainer's ``_beat`` sites call this, so the
+        span vocabulary IS the watchdog phase vocabulary. Call it from
+        one thread, outside any ``with span(...)``: the profiler's
+        annotations of a thread nest."""
         if not self.enabled:
             return
-        now = self._now_us()
-        if self._phase_name is not None:
-            self._emit(self._complete_event(
-                self._phase_name, self._phase_t0, now - self._phase_t0,
-                self._phase_args))
+        self._close_phase()
         self._phase_name = name
-        self._phase_t0 = now
+        self._phase_t0 = self._now_us()
         self._phase_args = {"step": step} if step is not None else None
+        self._phase_annotation = _annotation(name, {})
+        self._phase_annotation.__enter__()
 
     def end_phase(self) -> None:
         """Close the open phase span without starting another (loop
         exit)."""
-        if not self.enabled or self._phase_name is None:
+        if self.enabled:
+            self._close_phase()
+
+    def _close_phase(self) -> None:
+        if self._phase_name is None:
             return
-        now = self._now_us()
+        self._phase_annotation.__exit__(None, None, None)
+        self._phase_annotation = None
         self._emit(self._complete_event(
-            self._phase_name, self._phase_t0, now - self._phase_t0,
-            self._phase_args))
+            self._phase_name, self._phase_t0,
+            self._now_us() - self._phase_t0, self._phase_args))
         self._phase_name = None
         self._phase_args = None
 
@@ -211,18 +250,6 @@ class SpanTracer:
         if ph not in ("b", "e", "n"):
             raise ValueError(f"async ph must be 'b'/'e'/'n', got {ph!r}")
         self._emit(self._async_event(ph, name, trace_id, args))
-
-    def async_begin(self, name: str, trace_id: str, **args: Any) -> None:
-        """Open one async span (``ph: "b"``) on the ``trace_id`` track."""
-        self.async_event("b", name, trace_id, **args)
-
-    def async_end(self, name: str, trace_id: str, **args: Any) -> None:
-        """Close the matching ``async_begin``."""
-        self.async_event("e", name, trace_id, **args)
-
-    def async_instant(self, name: str, trace_id: str, **args: Any) -> None:
-        """Point event on the ``trace_id`` track (``ph: "n"``)."""
-        self.async_event("n", name, trace_id, **args)
 
     def _async_event(self, ph: str, name: str, trace_id: str,
                      args: Dict[str, Any]) -> dict:

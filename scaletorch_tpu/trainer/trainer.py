@@ -21,7 +21,7 @@ from scaletorch_tpu.config import ScaleTorchTPUArguments
 from scaletorch_tpu.models import llama, qwen3
 from scaletorch_tpu.models.registry import resolve_attention_backend
 from scaletorch_tpu.parallel.mesh import MeshManager, setup_mesh_manager
-from scaletorch_tpu.telemetry.spans import NOOP_SPAN
+from scaletorch_tpu.telemetry.spans import span
 from scaletorch_tpu.trainer.metrics import MetricsLogger
 from scaletorch_tpu.trainer.optimizer import create_optimizer
 from scaletorch_tpu.utils.device import device_report
@@ -736,11 +736,13 @@ class Trainer:
             # host-side fetch time: rides the coordination gather so the
             # straggler detector can tell input starvation from compute
             self._last_data_fetch_s = time.perf_counter() - t_fetch
-        dev_batch = self._device_batch(batch)
+        with span("train_step.place", self._tracer):
+            dev_batch = self._device_batch(batch)
         self._beat("step_dispatch")
-        self.params, self.opt_state, m = self.step_fn(
-            self.params, self.opt_state, dev_batch
-        )
+        with span("train_step.dispatch", self._tracer):
+            self.params, self.opt_state, m = self.step_fn(
+                self.params, self.opt_state, dev_batch
+            )
         self.global_step += 1
         # count the batch actually trained on (a caller-supplied batch may
         # differ from the loader's nominal shape), and the HOST-GLOBAL
@@ -995,18 +997,13 @@ class Trainer:
         liveness and tracing share one phase vocabulary (step_boundary /
         data_fetch / step_dispatch / checkpoint / emergency_checkpoint),
         so a watchdog crash report and a Perfetto timeline name the same
-        sites. No-op (one branch each) when neither is armed."""
+        sites; the tracer's phase is a profiler annotation too, so an
+        ``AnomalyProfiler`` window shows it. No-op (one branch each)
+        when neither is armed."""
         if self._watchdog is not None:
             self._watchdog.beat(self.global_step, phase)
         if self._tracer is not None:
             self._tracer.phase(phase, step=self.global_step)
-
-    def _span(self, name: str, **args):
-        """Telemetry span when a tracer is attached, shared no-op
-        otherwise (one branch — the telemetry/spans.py contract)."""
-        if self._tracer is None:
-            return NOOP_SPAN
-        return self._tracer.span(name, **args)
 
     def _agree_all(self, flag: bool) -> bool:
         """True iff every host holds True (identity single-process). Any
@@ -1079,7 +1076,7 @@ class Trainer:
     def save_checkpoint(self) -> bool:
         self._beat("checkpoint")
         position = self._stream_position()
-        with self._span("checkpoint_save", step=self.global_step):
+        with span("checkpoint_save", self._tracer, step=self.global_step):
             saved = self.checkpoint_manager.save(
                 step=self.global_step,
                 params=self.params,

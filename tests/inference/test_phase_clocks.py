@@ -1,0 +1,249 @@
+"""The engine's phase clocks and the spans that share their boundaries.
+
+Quick tier, CPU. Every instant of the engine thread is booked to one of
+three clocks (stall / device wait / host); a request's share of each
+between its first token and its retirement rides its terminal result;
+a tick over ``SLOW_TICK_S`` is reported with its phases; and the same
+boundaries are ``engine.tick.*`` spans in any profiler trace, with no
+tracer and no telemetry directory configured.
+"""
+
+import glob
+import os
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from scaletorch_tpu.inference import (
+    InferenceEngine,
+    SamplingParams,
+    ServingFaultInjector,
+)
+from scaletorch_tpu.inference import engine as engine_module
+from scaletorch_tpu.models import llama
+
+TINY = dict(
+    vocab_size=64, hidden_size=32, intermediate_size=64,
+    num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+    dtype=jnp.float32,
+)
+
+TICK_PHASES = (
+    "engine.tick.sweep", "engine.tick.admit", "engine.tick.prefill",
+    "engine.tick.prefill_wait", "engine.tick.feed", "engine.tick.decode",
+    "engine.tick.decode_wait", "engine.tick.emit", "engine.tick.export",
+)
+LOOP_PHASES = (
+    "engine.tick_loop.inbox", "engine.tick_loop.deliver",
+    "engine.tick_loop.idle",
+)
+
+
+@pytest.fixture(scope="module")
+def tiny_llama():
+    cfg = llama.LlamaConfig(**TINY)
+    return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def make_engine(tiny_llama, **kw):
+    cfg, params = tiny_llama
+    kw.setdefault("max_slots", 2)
+    kw.setdefault("max_seq", 32)
+    kw.setdefault("prefill_len", 8)
+    kw.setdefault("sampling", SamplingParams(temperature=0.0))
+    if kw.get("cache_layout") == "paged":
+        kw.setdefault("page_size", 4)
+    return InferenceEngine(params, cfg, **kw)
+
+
+class Collected:
+    """An exporter that keeps its records."""
+
+    def __init__(self):
+        self.records = []
+
+    def emit(self, kind, record):
+        self.records.append((kind, record))
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_the_three_clocks_sum_to_the_decode_life(tiny_llama, layout):
+    """Two overlapping requests, the second admitted while the first
+    decodes: for each, stall + device wait + host is its first token to
+    its last, and the first stood still for the second's admission."""
+    token_times = {}
+
+    def on_tokens(slot, request_id, tokens):
+        token_times.setdefault(request_id, []).append(time.monotonic())
+
+    eng = make_engine(tiny_llama, cache_layout=layout, on_tokens=on_tokens)
+    first = eng.submit([1, 2, 3], max_new_tokens=12)
+    for _ in range(4):
+        eng.step()
+    second = eng.submit([4, 5, 6, 7], max_new_tokens=6)
+    results = eng.run()
+    for rid in (first, second):
+        r = results[rid]
+        assert r.outcome == "ok"
+        life = r.latency_s - r.ttft_s       # first token -> last token
+        assert r.stall_s + r.device_wait_s + r.host_s == pytest.approx(
+            life, abs=1e-9)
+        assert min(r.stall_s, r.device_wait_s, r.host_s) >= 0
+        assert r.device_wait_s > 0
+        # the engine's token times are the ones the stream saw, to the
+        # few microseconds between the clock read and the hook
+        seen = token_times[rid]
+        assert life == pytest.approx(seen[-1] - seen[0], abs=5e-3)
+    # the first stream stood still while the second was admitted; the
+    # second's own admission came before its first token
+    assert results[first].stall_s > 0
+    assert results[second].prefill_s > 0
+    assert results[first].stall_s >= results[second].prefill_s
+    assert results[second].stall_s < results[second].prefill_s
+
+
+def test_a_request_that_never_reached_a_slot_has_no_clocks(tiny_llama):
+    eng = make_engine(tiny_llama, strict_submit=False)
+    rid = eng.submit([], max_new_tokens=4)
+    r = eng.result(rid)
+    assert r.outcome == "rejected"
+    assert (r.stall_s, r.device_wait_s, r.host_s) == (None, None, None)
+
+
+def test_an_aborted_stream_is_booked_up_to_its_retirement(tiny_llama):
+    eng = make_engine(tiny_llama)
+    rid = eng.submit([1, 2, 3], max_new_tokens=20)
+    for _ in range(3):
+        eng.step()
+    time.sleep(0.05)
+    assert eng.cancel(rid)
+    r = eng.result(rid)
+    assert r.outcome == "aborted" and len(r.tokens) >= 2
+    assert r.stall_s + r.device_wait_s + r.host_s == pytest.approx(
+        r.latency_s - r.ttft_s, abs=1e-9)
+    # the 50 ms between the last tick and the cancel are host time
+    assert r.host_s >= 0.05
+
+
+def test_slow_tick_reports_its_phases_once(tiny_llama, monkeypatch):
+    """The injector's slow decode stalls one tick past the constant:
+    one ``slow_tick`` record with the stall under ``decode_wait``.
+    An ordinary prefill tick, and a tick after an idle pause, are not
+    slow ticks."""
+    records = Collected()
+    eng = make_engine(
+        tiny_llama, cache_layout="paged", exporter=records,
+        monitor_every=10_000,
+        injector=ServingFaultInjector(
+            slow_decode_at_step=9, slow_decode_seconds=0.4))
+    eng.submit([1, 2, 3], max_new_tokens=4)   # compiles both steps
+    eng.run()
+    assert eng.metrics.decode_steps < 9
+    monkeypatch.setattr(engine_module, "SLOW_TICK_S", 0.2)
+    warm = eng.metrics.slow_ticks
+    seen = len(records.records)
+
+    time.sleep(0.3)                           # idle: nothing was pending
+    eng.submit([4, 5, 6, 7], max_new_tokens=3)
+    eng.run()                                 # a prefill tick + decodes
+    assert eng.metrics.decode_steps < 9
+    assert eng.metrics.slow_ticks == warm
+
+    eng.submit([1, 2, 3, 4], max_new_tokens=8)
+    eng.run()                                 # decode step 9 stalls
+    assert eng.metrics.decode_steps > 9
+    assert eng.metrics.slow_ticks == warm + 1
+    assert eng.metrics.snapshot()["slow_ticks"] == warm + 1
+    slow = [r for kind, r in records.records[seen:] if kind == "slow_tick"]
+    assert len(slow) == 1
+    record = slow[0]
+    assert record["tick"] == 9
+    assert record["phases_s"]["engine.tick.decode_wait"] >= 0.4
+    assert record["wall_s"] >= 0.4
+    assert record["gap_before_s"] < 0.2
+    assert set(record["phases_s"]) <= set(TICK_PHASES)
+
+
+def test_the_gap_before_a_tick_counts_when_work_was_waiting(
+        tiny_llama, monkeypatch):
+    eng = make_engine(tiny_llama)
+    eng.submit([1, 2, 3], max_new_tokens=16)
+    for _ in range(4):
+        eng.step()                            # compiled, still decoding
+    monkeypatch.setattr(engine_module, "SLOW_TICK_S", 0.2)
+    warm = eng.metrics.slow_ticks
+    time.sleep(0.3)                           # the driver of step() stalls
+    eng.step()
+    assert eng.metrics.slow_ticks == warm + 1
+    eng.step()
+    assert eng.metrics.slow_ticks == warm + 1
+
+
+def load_host_events(log_dir):
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("engine."):
+                    events.append({
+                        "plane": plane.name, "line": line.name,
+                        "name": event.name, "start": event.start_ns,
+                        "end": event.start_ns + event.duration_ns,
+                        "stats": dict(event.stats)})
+    return events
+
+
+def test_a_profiler_trace_holds_every_phase_with_nothing_configured(
+        tiny_llama, tmp_path):
+    """No tracer, no telemetry directory: a ``jax.profiler`` session
+    around a few ticks of the worker loop still shows the whole
+    vocabulary on a host plane, the tick's phases inside
+    ``engine.tick``."""
+    from scaletorch_tpu.serving.gateway import EngineWorker
+    from scaletorch_tpu.serving.protocol import GenerateRequest
+
+    eng = make_engine(tiny_llama, cache_layout="paged")
+    assert eng.tracer is None and eng.exporter is None
+    eng.submit([1, 2, 3], max_new_tokens=2)
+    eng.run()                                 # compile outside the trace
+    worker = EngineWorker(eng, replica_id="r0", idle_wait_s=0.01)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        worker.start()
+        done = threading.Event()
+        worker.submit(GenerateRequest(prompt=[5, 6, 7], max_new_tokens=4),
+                      lambda rid, toks: None, lambda result: done.set())
+        assert done.wait(timeout=60)
+        time.sleep(0.05)                      # a few idle waits
+        worker.shutdown(drain=True)
+        worker.join(timeout=60)
+        assert not worker.alive
+    finally:
+        jax.profiler.stop_trace()
+
+    events = load_host_events(str(tmp_path))
+    assert events and not any(
+        e["plane"].startswith("/device:") for e in events)
+    names = {e["name"] for e in events}
+    assert set(TICK_PHASES) | set(LOOP_PHASES) | {"engine.tick"} <= names
+    ticks = [e for e in events if e["name"] == "engine.tick"]
+    # the outer span alone carries an argument: the tick number
+    assert all("tick" in e["stats"] for e in ticks)
+    for e in events:
+        if e["name"] in TICK_PHASES:
+            assert not e["stats"]
+            assert any(t["line"] == e["line"] and t["start"] <= e["start"]
+                       and e["end"] <= t["end"] for t in ticks), e
+        elif e["name"] in LOOP_PHASES:
+            assert not any(t["line"] == e["line"] and t["start"] < e["end"]
+                           and e["start"] < t["end"] for t in ticks), e

@@ -238,6 +238,7 @@ class FakeEngineWorker:
             finish_reason=reason, outcome=outcome, detail=detail,
             ttft_s=None, latency_s=None, queue_wait_s=0.0,
             prefill_s=0.0, prefix_hit=self._has_warm_prefix(req.prompt),
+            stall_s=0.25, device_wait_s=0.5, host_s=0.125,
             trace_id=req.trace_id))
 
 

@@ -208,8 +208,40 @@ class TestObservability:
         assert rec["ttft_s"] > 0 and rec["e2e_s"] >= rec["ttft_s"]
         assert rec["queue_wait_s"] >= 0
         assert rec["prefix_hit"] is False
+        # where the stream's time went after its first token: the three
+        # engine clocks, in all and per decode step (one step here)
+        clocks = [rec[k] for k in ("stall_s", "device_wait_s", "host_s")]
+        assert all(c >= 0 for c in clocks) and rec["device_wait_s"] > 0
+        assert sum(clocks) <= rec["e2e_s"]
+        for key in ("stall_s", "device_wait_s", "host_s"):
+            assert rec[f"{key}_per_token"] == rec[key]
         # the mergeable per-tenant histogram state rode the same stream
         assert "latency_histograms" in by_kind
+
+    @pytest.mark.parametrize("tokens, per_token", [
+        (5, {"stall_s_per_token": 0.05, "device_wait_s_per_token": 0.1,
+             "host_s_per_token": 0.025}),
+        (1, None),    # one token: no decode step to divide by
+        (0, None),
+    ])
+    def test_access_record_clock_fields(self, tokens, per_token):
+        from scaletorch_tpu.inference.engine import RequestResult
+        from scaletorch_tpu.serving.gateway import _decode_clock_fields
+
+        totals = {"stall_s": 0.2, "device_wait_s": 0.4, "host_s": 0.1}
+        fields = _decode_clock_fields(RequestResult(
+            request_id=1, prompt=[1], tokens=list(range(tokens)),
+            finish_reason="length", **totals))
+        assert {k: fields[k] for k in totals} == totals
+        for key in totals:
+            want = per_token[f"{key}_per_token"] if per_token else None
+            assert fields[f"{key}_per_token"] == want
+        # never dispatched, or retired before its first token: all null
+        unserved = RequestResult(request_id=2, prompt=[1], tokens=[],
+                                 finish_reason="shed", outcome="shed")
+        for result in (None, unserved):
+            nulls = _decode_clock_fields(result)
+            assert len(nulls) == 6 and set(nulls.values()) == {None}
 
     def test_404_and_405(self, tiny_llama):
         gw = ServingGateway(make_engine(tiny_llama),
@@ -474,12 +506,14 @@ def sse_disconnect_after_first_token(port, body):
 
 class TestDisconnectReleasesPages:
     def test_mid_stream_disconnect_aborts_and_releases(self, tiny_llama):
-        engine = make_engine(tiny_llama, max_slots=1)
+        # room for a thousand tokens: on the tiny model a 25-token
+        # stream could end ``ok`` before the disconnect was seen
+        engine = make_engine(tiny_llama, max_slots=1, max_seq=1024)
         gw = ServingGateway(engine, port=0).start_in_thread()
         try:
             sse_disconnect_after_first_token(
                 gw.port, {"prompt": [1, 2, 3, 4, 5],
-                          "max_new_tokens": 25, "stream": True})
+                          "max_new_tokens": 1000, "stream": True})
             deadline = time.monotonic() + 30
             while time.monotonic() < deadline:
                 if gw.metrics.outcomes["aborted"] == 1 \

@@ -131,6 +131,8 @@ class TestWireInProcess:
             assert res.queue_wait_s == 0.0
             assert res.prefill_s == 0.0
             assert res.prefix_hit is False
+            assert (res.stall_s, res.device_wait_s, res.host_s) == (
+                0.25, 0.5, 0.125)
             assert remote.inflight == 0
         finally:
             remote.stop_polling()

@@ -597,7 +597,7 @@ def _bind_real_trainer_methods():
     for name in (
         "train", "save_checkpoint", "load_checkpoint",
         "_rollback_to_last_good", "_emergency_checkpoint", "_layer_storage",
-        "_beat", "_span", "_stream_position", "_write_crash_report",
+        "_beat", "_stream_position", "_write_crash_report",
         "_watchdog_crash_report", "_watchdog_exit", "_live_snapshot",
         "_agree_all", "_agree_any",
         # elastic continuation (no "_elastic_rebuild_topology": its
