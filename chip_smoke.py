@@ -301,43 +301,54 @@ def leg_kernels(dry_run: bool) -> dict:
     log(f"flash parity: {json.dumps(flash)}")
 
     # ---- paged decode vs gather + cached SDPA --------------------------
-    page, slots = 16, 8
+    page = 16
+
+    def paged_case(slots, max_pages, positions):
+        n_pages = slots * max_pages + 1
+        pool_k, pool_v = normal((n_pages, hkv, page, d)), \
+            normal((n_pages, hkv, page, d))
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, n_pages)).reshape(slots, max_pages),
+            jnp.int32)
+        positions = jnp.asarray(positions, jnp.int32)
+        qd = normal((slots, hq, d))
+
+        def gather_ref(dtype):
+            return cached_sdpa_attention(
+                qd.astype(dtype)[:, :, None],
+                paged_gather_kv(pool_k, tables).astype(dtype),
+                paged_gather_kv(pool_v, tables).astype(dtype),
+                positions[:, None])[:, :, 0]
+
+        out_k = jax.jit(lambda *a: pallas_paged_decode_attention(
+            *a, interpret=interpret))(qd, pool_k, pool_v, tables, positions)
+        with jax.default_matmul_precision("highest"):
+            out_t = gather_ref(jnp.float32)
+        paged = {
+            "shape": f"B{slots} Hq{hq} Hkv{hkv} D{d} page{page} "
+                     f"max_pages{max_pages} bf16",
+            "max_abs_err_kernel": max_abs(out_k - out_t),
+            "max_abs_err_xla_bf16": max_abs(gather_ref(jnp.bfloat16) - out_t),
+        }
+        check(bool(jnp.all(jnp.isfinite(out_k.astype(jnp.float32)))),
+              f"paged decode output is not finite ({paged['shape']})")
+        check(paged["max_abs_err_kernel"] <= FWD_ATOL,
+              f"paged decode off by {paged['max_abs_err_kernel']:.3g} "
+              f"> {FWD_ATOL} ({paged['shape']})")
+        log(f"paged-decode parity: {json.dumps(paged)}")
+        return paged, (qd, pool_k, pool_v, tables, positions)
+
     max_pages = 8 if dry_run else 128          # max_seq 2048
-    n_pages = slots * max_pages + 1
-    pool_k, pool_v = normal((n_pages, hkv, page, d)), \
-        normal((n_pages, hkv, page, d))
-    tables = jnp.asarray(
-        rng.permutation(np.arange(1, n_pages)).reshape(slots, max_pages),
-        jnp.int32)
     last = max_pages * page - 1
-    positions = jnp.asarray(
-        [0, page - 1, page, 5 * page + 3, last // 4, last // 2,
-         last - 1, last], jnp.int32)
-    qd = normal((slots, hq, d))
-
-    def gather_ref(dtype):
-        return cached_sdpa_attention(
-            qd.astype(dtype)[:, :, None],
-            paged_gather_kv(pool_k, tables).astype(dtype),
-            paged_gather_kv(pool_v, tables).astype(dtype),
-            positions[:, None])[:, :, 0]
-
-    out_k = jax.jit(lambda *a: pallas_paged_decode_attention(
-        *a, interpret=interpret))(qd, pool_k, pool_v, tables, positions)
-    with jax.default_matmul_precision("highest"):
-        out_t = gather_ref(jnp.float32)
-    paged = {
-        "shape": f"B{slots} Hq{hq} Hkv{hkv} D{d} page{page} "
-                 f"max_pages{max_pages} bf16",
-        "max_abs_err_kernel": max_abs(out_k - out_t),
-        "max_abs_err_xla_bf16": max_abs(gather_ref(jnp.bfloat16) - out_t),
-    }
-    check(bool(jnp.all(jnp.isfinite(out_k.astype(jnp.float32)))),
-          "paged decode output is not finite")
-    check(paged["max_abs_err_kernel"] <= FWD_ATOL,
-          f"paged decode off by {paged['max_abs_err_kernel']:.3g} "
-          f"> {FWD_ATOL}")
-    log(f"paged-decode parity: {json.dumps(paged)}")
+    paged, (qd, pool_k, pool_v, tables, positions) = paged_case(
+        8, max_pages, [0, page - 1, page, 5 * page + 3, last // 4,
+                       last // 2, last - 1, last])
+    # the serving cell's shape (qwen3-1.7b-serve: 16 slots x 96 pages),
+    # ragged from 31 to the last position a slot can hold
+    serve_pages = 6 if dry_run else 96
+    paged_serving, _ = paged_case(
+        16, serve_pages,
+        np.linspace(31, serve_pages * page - 1, 16).astype(np.int32))
 
     # ---- the dispatchers pick the kernels iff the platform is tpu ------
     lowered = {
@@ -354,6 +365,7 @@ def leg_kernels(dry_run: bool) -> dict:
               f"{'no ' if on_tpu else 'a '}Mosaic call on "
               f"platform {device['platform']}")
     return {"device": device, "flash": flash, "paged_decode": paged,
+            "paged_decode_serving": paged_serving,
             "memory_stats": {str(d.id): d.memory_stats()
                              for d in jax.devices()}}
 

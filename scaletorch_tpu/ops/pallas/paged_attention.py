@@ -8,18 +8,27 @@ Attention reads therefore become gathers over the page table. Two
 implementations live here:
 
   * **Pallas decode kernel** (``pallas_paged_decode_attention``): one
-    query token per slot against its paged cache. The grid is
-    ``(B, Hkv, max_pages)`` and the page table + positions ride the
-    TPU scalar-prefetch path (``pltpu.PrefetchScalarGridSpec``), so the
-    K/V *index maps themselves* chase the page table: page ``j``'s
-    physical block is DMA'd HBM→VMEM directly — the gathered reads stay
-    in VMEM and the dense ``[B, Hkv, S_max, D]`` view is never
-    materialised in HBM. Pages past the slot's live length are skipped
-    flash-style: compute predicated off with ``pl.when`` and the index
-    map clamped to an already-resident page so no DMA is issued
-    (the causal block-skip idiom from ops/pallas/flash.py). GQA reads
-    grouped K/V unexpanded — the ``n_rep`` query heads of one KV head
-    are the rows of a single ``[n_rep, page_size]`` score tile.
+    query token per slot against its paged cache. The grid is ``(B,)``,
+    one step per slot; the pools stay in HBM (``memory_space=pl.ANY``)
+    and the page table + positions ride the TPU scalar-prefetch path
+    (``pltpu.PrefetchScalarGridSpec``). A step walks the slot's *live*
+    pages only, a block of ``_pages_per_block`` at a time: one async
+    copy per page brings ``[Hkv, page_size, D]`` (all KV heads of a
+    page, contiguous in the pool) into a double-buffered VMEM landing
+    zone ``[2, Hkv, block, D]`` while the block before it is reduced
+    flash-style, so the dense ``[B, Hkv, S_max, D]`` view is never
+    materialised in HBM and a page past the slot's length costs
+    nothing — no grid step, no DMA (the last block's dead pages
+    re-read the last live page and are masked). GQA reads grouped K/V
+    unexpanded — one batched dot over the KV heads, the ``n_rep`` query
+    heads of a KV head the rows of its ``[n_rep, block]`` score tile.
+    ``_pages_per_block`` follows from shapes alone: enough pages for a
+    128-lane score tile (8 at page 16), under a fixed VMEM budget.
+    Mosaic can slice an HBM ref only along whole 128-lane tiles, so the
+    kernel serves a head_dim that is a multiple of 128
+    (``kernel_serves``); narrower heads take the fallback. What it
+    takes on the chip, and what the one-page-of-one-head grid it
+    replaced took, is in PERF.md (PR 25).
   * **Pure-lax fallback** (``paged_gather_kv`` + the models' shared
     ``cached_sdpa_attention``): a whole-table gather that reconstructs
     the dense cache view. This is the off-TPU path and the reference
@@ -28,9 +37,9 @@ implementations live here:
     the dense engine's attention performs.
 
 ``paged_attention`` dispatches between them: the kernel serves
-single-token decode when the platform is ``tpu`` (toggle:
-``SCALETORCH_TPU_PAGED_KERNEL``); prefill (S > 1) and other platforms
-take the gather fallback.
+single-token decode when the platform is ``tpu`` and the head_dim fills
+the lanes (toggle: ``SCALETORCH_TPU_PAGED_KERNEL``); prefill (S > 1),
+narrow heads and other platforms take the gather fallback.
 
 Writes (``paged_write_kv``) are a batched scatter: token at absolute
 position ``t`` lands at ``(table[b, t // page_size], t % page_size)``.
@@ -59,14 +68,6 @@ from jax.experimental.pallas import tpu as pltpu
 TRASH_PAGE = 0
 
 _NEG_INF = -1e30  # large-negative, not -inf: keeps masked rows NaN-free
-
-
-def _semantics(*dims):
-    """Mosaic grid dimension semantics ('p' parallel / 'a' arbitrary) —
-    see ops/pallas/flash.py."""
-    m = {"p": pltpu.PARALLEL, "a": pltpu.ARBITRARY}
-    return pltpu.CompilerParams(
-        dimension_semantics=tuple(m[d] for d in dims))
 
 
 # ---------------------------------------------------------------------------
@@ -115,52 +116,106 @@ def paged_write_kv(
 # ---------------------------------------------------------------------------
 # the decode kernel
 # ---------------------------------------------------------------------------
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_sc, m_sc, l_sc, *, scale, page_size):
+# VMEM the kernel may spend on its K/V landing buffers (2 buffers x 2
+# pools x one block of pages). A fraction of the 16 MiB scoped default,
+# so the score tiles and Mosaic's own temporaries always fit beside it.
+_KV_VMEM_BUDGET = 1 << 20
+_LANES = 128
+
+
+def _pages_per_block(page_size: int, hkv: int, d: int, dtype,
+                     max_pages: int) -> int:
+    """Pages one compute block covers: enough for a score tile whose
+    last dimension fills the 128 lanes (8 pages at page 16), capped by
+    the VMEM budget of the double-buffered landing zone and by the
+    table's length. Shapes in, one integer out — nothing to configure.
+    """
+    page_bytes = hkv * page_size * d * jnp.dtype(dtype).itemsize
+    fill_lanes = -(-_LANES // page_size)
+    fit_budget = _KV_VMEM_BUDGET // (4 * page_bytes)
+    return max(1, min(fill_lanes, fit_budget, max_pages))
+
+
+def kernel_serves(head_dim: int) -> bool:
+    """Whether Mosaic can compile the kernel for this head_dim: an HBM
+    ref is padded to whole 128-lane tiles and may only be sliced along
+    them, so a page of a narrower pool cannot be copied on its own."""
+    return head_dim % _LANES == 0
+
+
+def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, *, scale, page_size,
+                         pages_per_block, max_pages):
     b = pl.program_id(0)   # slot
-    j = pl.program_id(2)   # logical page
-    nj = pl.num_programs(2)
+    bk = pages_per_block * page_size
+    pos = pos_ref[b]
+    # live pages of this slot (0 for a negative position, never past the
+    # table) and the blocks that hold them: a dead block costs nothing
+    n_live = jnp.clip(pos // page_size + 1, 0, max_pages)
+    n_blocks = (n_live + pages_per_block - 1) // pages_per_block
 
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+    def block_copies(i, buf):
+        """One async copy per page of block ``i`` and pool: page
+        ``[Hkv, page_size, D]`` (contiguous in HBM) into its rows of
+        landing buffer ``buf``. Pages of the last block past the live
+        length re-read the last live page: what reaches VMEM is always
+        this slot's own data, never TRASH or an unallocated page."""
+        out = []
+        for p in range(pages_per_block):
+            j = jnp.minimum(i * pages_per_block + p, n_live - 1)
+            page = pt_ref[b * max_pages + j]
+            rows = pl.ds(p * page_size, page_size)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[buf, :, rows, :], sems.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[buf, :, rows, :], sems.at[1, buf]))
+        return out
 
-    # pages past the slot's live length carry no visible keys: skip their
-    # compute; their DMA was already clamped to a resident page.
-    n_live = pos_ref[b] // page_size + 1
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in block_copies(0, 0):
+            c.start()
 
-    @pl.when(j < n_live)
-    def _page():
-        q = q_ref[0, 0]   # [n_rep, D]
-        k = k_ref[0, 0]   # [page_size, D]
-        v = v_ref[0, 0]
+    q = q_ref[0]   # [Hkv, n_rep, D]
+    hkv, nrep, d = q.shape
+    key_in_block = jax.lax.broadcasted_iota(jnp.int32, (hkv, nrep, bk), 2)
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        buf = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for c in block_copies(i + 1, 1 - buf):
+                c.start()
+
+        for c in block_copies(i, buf):
+            c.wait()
+        k = k_buf[buf]   # [Hkv, bk, D]
+        v = v_buf[buf]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale  # [n_rep, page_size]
+        ) * scale  # [Hkv, n_rep, bk]
         # causal-over-the-cache mask at logical positions: key o of
-        # logical page j sits at absolute position j*page_size + o
-        nrep = q.shape[0]
-        key_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (nrep, page_size), 1)
-        s = jnp.where(key_pos <= pos_ref[b], s, _NEG_INF)
-        m_prev, l_prev = m_sc[:], l_sc[:]
+        # block i sits at absolute position i*bk + o
+        s = jnp.where(i * bk + key_in_block <= pos, s, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_sc[:] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_sc[:] = m_new
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
+        return m_new, l_new, acc
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.maximum(l_sc[:], 1e-30)
-        o_ref[0, 0] = (acc_sc[:] / l).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full((hkv, nrep, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((hkv, nrep, 1), jnp.float32),
+        jnp.zeros((hkv, nrep, d), jnp.float32),
+    ))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def pallas_paged_decode_attention(
@@ -179,10 +234,11 @@ def pallas_paged_decode_attention(
     [B, max_pages] int32; positions: [B] int32 absolute position of the
     query token (attends keys j <= position). Returns [B, Hq, D].
 
-    The page table and positions are scalar-prefetched so the K/V block
-    index maps resolve physical pages before each grid step's DMA; only
-    live pages are fetched, and the per-page flash accumulation keeps
-    everything after the HBM page read in VMEM.
+    The pools stay in HBM; the page table and positions are
+    scalar-prefetched, and each slot's step copies its live pages, a
+    block of ``_pages_per_block`` at a time and all KV heads of a page
+    at once, into a double-buffered VMEM landing zone while the block
+    before it is reduced flash-style.
     """
     b, hq, d = q.shape
     n_pages, hkv, page_size, _ = pool_k.shape
@@ -190,45 +246,47 @@ def pallas_paged_decode_attention(
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     n_rep = hq // hkv
     max_pages = page_tables.shape[1]
+    if not interpret and not kernel_serves(d):
+        raise ValueError(
+            f"the paged-decode kernel copies whole pages out of HBM, which "
+            f"Mosaic allows only for a head_dim that fills the {_LANES} "
+            f"lanes; got {d} (paged_attention() sends it to the gather "
+            f"fallback)")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    ppb = _pages_per_block(page_size, hkv, d, pool_k.dtype, max_pages)
 
-    q_r = q.reshape(b, hkv, n_rep, d)
-
-    def q_idx(b_, h, j, pt_ref, pos_ref):
-        return (b_, h, 0, 0)
-
-    def kv_idx(b_, h, j, pt_ref, pos_ref):
-        # clamp dead pages to the last live one (already resident — no
-        # DMA is spent on pages the mask would zero anyway)
-        n_live = pos_ref[b_] // page_size + 1
-        return (pt_ref[b_, jnp.minimum(j, n_live - 1)], h, 0, 0)
+    def q_idx(b_, pt_ref, pos_ref):
+        return (b_, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, max_pages),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, n_rep, d), q_idx),
-            pl.BlockSpec((1, 1, page_size, d), kv_idx),
-            pl.BlockSpec((1, 1, page_size, d), kv_idx),
+            pl.BlockSpec((1, hkv, n_rep, d), q_idx),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, n_rep, d), q_idx),
+        out_specs=pl.BlockSpec((1, hkv, n_rep, d), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((n_rep, d), jnp.float32),
-            pltpu.VMEM((n_rep, 1), jnp.float32),
-            pltpu.VMEM((n_rep, 1), jnp.float32),
+            pltpu.VMEM((2, hkv, ppb * page_size, d), pool_k.dtype),
+            pltpu.VMEM((2, hkv, ppb * page_size, d), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale,
-                          page_size=page_size),
+                          page_size=page_size, pages_per_block=ppb,
+                          max_pages=max_pages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
-        compiler_params=_semantics("p", "p", "a"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL,)),  # slots share no state
         interpret=interpret,
         name="paged_decode",
-    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q_r, pool_k, pool_v)
+    )(page_tables.astype(jnp.int32).reshape(-1),
+      positions.astype(jnp.int32), q.reshape(b, hkv, n_rep, d),
+      pool_k, pool_v)
     return out.reshape(b, hq, d)
 
 
@@ -254,11 +312,11 @@ def paged_attention(
     q_positions: [B, S] absolute positions. ``kernel=None`` auto-selects:
     the Pallas kernel for single-token decode when the platform is
     ``tpu`` (the same predicate the flash backend uses;
-    ``SCALETORCH_TPU_PAGED_KERNEL`` gates it), the lax gather +
-    ``cached_sdpa_attention`` everywhere else — other platforms and
-    prefill. ``seq_limit`` crops the gathered view to the engine's
-    ``max_seq`` so the fallback's reduction has the dense layout's
-    operand shapes.
+    ``SCALETORCH_TPU_PAGED_KERNEL`` gates it) and ``kernel_serves`` the
+    head_dim, the lax gather + ``cached_sdpa_attention`` everywhere
+    else — other platforms, narrow heads and prefill. ``seq_limit``
+    crops the gathered view to the engine's ``max_seq`` so the
+    fallback's reduction has the dense layout's operand shapes.
     """
     from scaletorch_tpu.models.layers import cached_sdpa_attention
 
@@ -270,6 +328,7 @@ def paged_attention(
 
         use_kernel = (
             s == 1
+            and kernel_serves(q.shape[3])
             and _pallas_available()
             and bool(get_env("SCALETORCH_TPU_PAGED_KERNEL"))
         )

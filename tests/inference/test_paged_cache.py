@@ -29,6 +29,7 @@ from scaletorch_tpu.models import llama, qwen3
 from scaletorch_tpu.models.layers import cached_sdpa_attention, write_kv_cache
 from scaletorch_tpu.ops.pallas.paged_attention import (
     TRASH_PAGE,
+    _pages_per_block,
     paged_attention,
     paged_gather_kv,
     paged_write_kv,
@@ -308,6 +309,103 @@ class TestPagedPrimitives:
         with pytest.raises(ValueError, match="not a multiple"):
             pallas_paged_decode_attention(
                 q, pool_k, pool_k, tables, jnp.zeros((self.B,), jnp.int32))
+
+
+class TestPagedDecodeKernelBlocks:
+    """The kernel walks a slot's live pages a block of
+    ``_pages_per_block`` at a time (all KV heads of a page in one copy):
+    parity with the gather fallback where blocks begin, end and are
+    ragged, over the head layouts and page sizes the models use."""
+
+    HKV = 2
+
+    def _case(self, n_rep, d, page_size, even, seed=0):
+        """Six slots, one at each edge of the block walk, over a pool
+        whose TRASH page and unallocated pages are all NaN. Returns the
+        kernel's inputs, the fallback's answer from the same pool with
+        the NaN zeroed, and the pages no live key sits on."""
+        rng = np.random.default_rng(seed)
+        ppb = _pages_per_block(page_size, self.HKV, d, jnp.float32, 10 ** 6)
+        max_pages = 2 * ppb if even else 2 * ppb - 3
+        bk, top = ppb * page_size, max_pages * page_size - 1
+        pos = np.asarray([0, page_size - 1, page_size, bk - 1, bk, top])
+        n_live = pos // page_size + 1
+        b = len(pos)
+        n_pages = b * max_pages + 1
+        # a random permutation; slots 4 and 5 share their first two pages
+        tables = rng.permutation(np.arange(1, n_pages)).reshape(b, max_pages)
+        tables[5, :2] = tables[4, :2]
+        live = np.zeros(n_pages, bool)
+        for row, n in zip(tables, n_live):
+            live[row[:n]] = True
+        # past the live length a table holds TRASH or an unallocated page
+        for i, n in enumerate(n_live):
+            tables[i, n:] = np.where(
+                rng.random(max_pages - n) < 0.5, TRASH_PAGE,
+                rng.choice(np.flatnonzero(~live), max_pages - n))
+        shape = (n_pages, self.HKV, page_size, d)
+        pool_k = rng.standard_normal(shape, np.float32)
+        pool_v = rng.standard_normal(shape, np.float32)
+        q = jnp.asarray(rng.standard_normal(
+            (b, self.HKV * n_rep, d), np.float32))
+        tables = jnp.asarray(tables, jnp.int32)
+        pos = jnp.asarray(pos, jnp.int32)
+        want = cached_sdpa_attention(
+            q[:, :, None], paged_gather_kv(jnp.asarray(pool_k), tables),
+            paged_gather_kv(jnp.asarray(pool_v), tables),
+            pos[:, None])[:, :, 0]
+        # poisoned copies: jnp.asarray may alias the numpy buffer the
+        # oracle above is still reading
+        dead = ~live[:, None, None, None]
+        return (q, jnp.asarray(np.where(dead, np.nan, pool_k)),
+                jnp.asarray(np.where(dead, np.nan, pool_v)), tables, pos), \
+            np.asarray(want), max_pages % ppb
+
+    @pytest.mark.parametrize("even", [True, False],
+                             ids=["whole-blocks", "short-last-block"])
+    @pytest.mark.parametrize("page_size", [8, 16])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("n_rep", [1, 2, 4, 8])
+    def test_block_edges_match_fallback(self, n_rep, d, page_size, even):
+        args, want, remainder = self._case(n_rep, d, page_size, even)
+        assert (remainder == 0) == even
+        out = np.asarray(pallas_paged_decode_attention(*args, interpret=True))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, want, atol=5e-6)
+
+    def test_dead_pages_never_reach_the_result(self):
+        """Every page no live key sits on is NaN (TRASH included): one
+        fetch of any of them, or one unmasked dead key, and the output
+        is NaN. The fallback itself cannot take this pool (0 x NaN in
+        its value product), which is why the oracle reads it zeroed."""
+        (q, pool_k, pool_v, tables, pos), want, _ = self._case(
+            2, 128, 16, False, seed=1)
+        assert bool(jnp.isnan(pool_k[TRASH_PAGE]).all())
+        assert bool(jnp.isnan(pool_v).any(axis=(1, 2, 3)).sum() > len(pos))
+        out = np.asarray(pallas_paged_decode_attention(
+            q, pool_k, pool_v, tables, pos, interpret=True))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, want, atol=5e-6)
+
+    @pytest.mark.parametrize("page_size,hkv,d,dtype,max_pages,want", [
+        (16, 8, 128, jnp.bfloat16, 96, 8),    # the serving cell: 1 MiB
+        (8, 8, 128, jnp.bfloat16, 96, 16),    # 128 lanes at page 8
+        (32, 8, 128, jnp.bfloat16, 48, 4),
+        (16, 1, 128, jnp.bfloat16, 96, 8),    # one KV head of a tp shard
+        (16, 8, 128, jnp.bfloat16, 5, 5),     # a table shorter than a block
+        (16, 32, 256, jnp.float32, 96, 1),    # 512 KiB a page: the budget caps
+        (4, 2, 8, jnp.float32, 4, 4),
+    ])
+    def test_pages_per_block_follows_shapes(self, page_size, hkv, d, dtype,
+                                            max_pages, want):
+        assert _pages_per_block(page_size, hkv, d, dtype, max_pages) == want
+
+    def test_negative_position_reads_nothing(self):
+        # a slot with no key at all (position -1) walks zero blocks
+        (q, pool_k, pool_v, tables, pos), _, _ = self._case(2, 128, 16, True)
+        out = pallas_paged_decode_attention(
+            q, pool_k, pool_v, tables, jnp.full_like(pos, -1), interpret=True)
+        assert bool((out == 0).all())
 
 
 # fp32 logits of the paged and the dense path: both sum the same terms

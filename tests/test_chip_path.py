@@ -226,19 +226,41 @@ class TestKernelDispatch:
         monkeypatch.setattr(
             pa, "pallas_paged_decode_attention",
             lambda q, *a, **kw: calls.append("kernel") or q)
-        pool = jnp.zeros((3, 2, 4, 8))
+        pool = jnp.zeros((3, 2, 4, 128))
         tables = jnp.ones((1, 2), jnp.int32)
         out = pa.paged_attention(
-            jnp.zeros((1, 2, 1, 8)), pool, pool, tables,
+            jnp.zeros((1, 2, 1, 128)), pool, pool, tables,
             jnp.zeros((1, 1), jnp.int32), page_size=4)
-        assert out.shape == (1, 2, 1, 8)
+        assert out.shape == (1, 2, 1, 128)
         assert calls == (["kernel"] if platform == "tpu" else [])
         # prefill (S > 1) is the gather path on every platform
         calls.clear()
         pa.paged_attention(
-            jnp.zeros((1, 2, 3, 8)), pool, pool, tables,
+            jnp.zeros((1, 2, 3, 128)), pool, pool, tables,
             jnp.zeros((1, 3), jnp.int32), page_size=4)
         assert calls == []
+
+    @pytest.mark.parametrize("head_dim", [8, 64, 192])
+    def test_paged_decode_narrow_heads_take_the_gather_on_tpu(
+            self, monkeypatch, head_dim):
+        """Mosaic cannot copy a page out of an HBM pool whose head_dim
+        does not fill the 128 lanes: the dispatcher must not pick a
+        kernel that cannot compile, and the kernel says so itself."""
+        from scaletorch_tpu.ops.pallas import paged_attention as pa
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        pool = jnp.zeros((3, 2, 4, head_dim))
+        tables = jnp.ones((1, 2), jnp.int32)
+        q = jnp.zeros((1, 2, 1, head_dim))
+        pos = jnp.zeros((1, 1), jnp.int32)
+        with monkeypatch.context() as m:
+            m.setattr(pa, "pallas_paged_decode_attention",
+                      lambda *a, **kw: pytest.fail("kernel picked"))
+            out = pa.paged_attention(q, pool, pool, tables, pos, page_size=4)
+        assert out.shape == q.shape
+        with pytest.raises(ValueError, match="head_dim"):
+            pa.paged_attention(q, pool, pool, tables, pos, page_size=4,
+                               kernel=True)
 
 
 # --------------------------------------------------------------------------
