@@ -8,7 +8,8 @@ nothing to read (the harness then leaves the metric out of the line).
 Context keys: ``events`` (trace events), ``window`` (ns interval),
 ``records`` (name -> list of dicts: ``access`` from the gateway,
 ``loadgen`` from the client), ``counters`` (name -> number), ``config``,
-``traffic``, ``workload``, ``peaks``.
+``traffic``, ``workload``, ``peaks``, ``spec`` (finds a named
+``cost_module``; not needed for the default one).
 """
 
 from __future__ import annotations
@@ -16,7 +17,7 @@ from __future__ import annotations
 import statistics
 from typing import Any, Callable, Dict, List, Optional
 
-from benchmarks.lib import costs, trace
+from benchmarks.lib import modules, trace
 
 Context = Dict[str, Any]
 
@@ -122,16 +123,18 @@ def _cost_args(ctx: Context, names: List[str]) -> Dict[str, Any]:
 
 
 def roofline_share(ctx: Context, params: Dict[str, Any]) -> Optional[float]:
-    """% of a peak: what the matching kernel calls had to do (a named
-    function of ``costs`` per call) over their device time over the
-    peak. ``terms`` lists, per kernel pattern, which entry of the cost
+    """% of a peak: what the matching kernel calls had to do (per call,
+    ``cost_function`` of ``cost_module``: a path, by default
+    ``benchmarks/lib/costs.py``) over their device time over the peak.
+    ``terms`` lists, per kernel pattern, which entry of the cost
     function's result one call is charged and how many trace events
     make up one call."""
     planes = trace.device_planes(ctx.get("events") or [])
     if not planes:
         return None
-    cost = getattr(costs, params["cost_function"])(
-        **_cost_args(ctx, params["cost_args"]))
+    cost = modules.cost_function(
+        ctx.get("spec"), params["cost_function"], params.get("cost_module"))(
+            **_cost_args(ctx, params["cost_args"]))
     peak = float(ctx["peaks"][params["peak"]])
 
     def one(plane):

@@ -4,8 +4,9 @@ engine on one chip, load from a client process over HTTP.
 The system under test is built by the program's own pieces
 (``scripts/serve.py``'s ``parse_args``/``build_engine`` and
 ``ServingGateway``, as ``build_gateway`` wires them). The benchmark
-owns: the weights (one jitted call of the program's init from
-``--seed``, in the serving dtype), the reference check through the
+owns: the weights (one jitted call of the program's own initialiser
+for the model it built from the configuration, from ``--seed``, in the
+serving dtype), the reference check through the
 engine's own paged prefill and decode steps (which is also the
 warm-up of both shapes), the client process, the clocks and the trace.
 """
@@ -24,9 +25,13 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from benchmarks.lib import modules, program
 from benchmarks.lib import trace as trace_lib
 from benchmarks.lib import traffic as traffic_lib
-from benchmarks.lib.spec import MODEL_SHAPE_KEYS
+
+# keys of a cell's ``check`` that are the runner's own; every other key
+# is a size of the reference and goes to its factory as it is
+RUNNER_CHECK_KEYS = ("prompts", "decode_positions", "rtol_of_max")
 
 # from the client process's launch to its first request: time for it to
 # import, read its job and open its sockets
@@ -72,43 +77,33 @@ def load_serve_module(root: str):
     return module
 
 
-def model_config(config: Dict[str, Any], dtype_name: str):
-    import jax.numpy as jnp
-
-    from scaletorch_tpu.models import llama
-
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[dtype_name]
-    kwargs = {k: config[k] for k in MODEL_SHAPE_KEYS if k in config}
-    return llama.LlamaConfig(
-        qk_norm=config.get("model_type") == "qwen3",
-        dtype=dtype, param_dtype=dtype, **kwargs)
-
-
-def make_params(cfg, seed: int):
-    """The program's own init, as one jitted call on the device, in the
-    dtype the weights are served in."""
+def make_params(init, cfg, seed: int):
+    """The program's own initialiser, as one jitted call on the device,
+    in the dtype the weights are served in."""
     import jax
 
-    from scaletorch_tpu.models import llama
-
-    init = jax.jit(llama.init_params, static_argnums=1)
-    return init(jax.random.PRNGKey(traffic_lib.fold_seed(seed)), cfg)
+    return jax.jit(init, static_argnums=1)(
+        jax.random.PRNGKey(traffic_lib.fold_seed(seed)), cfg)
 
 
-def reference_logits(config, params, tokens: np.ndarray, lens: np.ndarray,
-                     decode_positions: int, q_block: int, wrong=None):
+def reference_logits(reference, config, check: Dict[str, Any], params,
+                     tokens: np.ndarray, lens: np.ndarray,
+                     decode_positions: int, wrong=None):
     """Reference logits [n, decode_positions + 1, V] at rows
-    ``len - 1 .. len - 1 + decode_positions`` of each sequence."""
+    ``len - 1 .. len - 1 + decode_positions`` of each sequence. The
+    prompt buffer is padded to a multiple of the check's ``q_block``
+    (the one reference size the runner reads too); every size of the
+    check goes to the reference as it is."""
     import jax.numpy as jnp
 
-    from benchmarks.reference import qwen3 as reference
-
+    q_block = int(check.get("q_block", 1))
     width = tokens.shape[1]
     padded = -(-width // q_block) * q_block
     tokens = np.pad(tokens, ((0, 0), (0, padded - width)))
     rows = (lens[:, None] - 1
             + np.arange(decode_positions + 1)[None, :]).astype(np.int32)
-    fn = reference.make_logits_fn(config, q_block=q_block, wrong=wrong)
+    fn = reference.make_logits_fn(
+        config, wrong=wrong, **modules.check_sizes(check, RUNNER_CHECK_KEYS))
     return fn(params, jnp.asarray(tokens), jnp.asarray(rows))
 
 
@@ -273,8 +268,17 @@ def client_metrics(records: List[Dict[str, Any]], t0: float, t1: float,
         out["serve_ttft_p90_ms"] = 1e3 * trace_lib.percentile(ttft, 90)
         out["serve_ttft_p50_ms"] = 1e3 * trace_lib.percentile(ttft, 50)
     if gaps:
+        median = trace_lib.percentile(gaps, 50)
         out["serve_itl_p95_ms"] = 1e3 * trace_lib.percentile(gaps, 95)
-        out["serve_itl_p50_ms"] = 1e3 * trace_lib.percentile(gaps, 50)
+        out["serve_itl_p50_ms"] = 1e3 * median
+        # a gap that holds an admission is a prefill call long, any
+        # other a tick; a percentile reads one kind or the other by
+        # which side of it the admission gaps' share lies (PERF.md, PR
+        # 26): the 99th stands beside the 95th, and the share is printed
+        out["serve_itl_p99_ms"] = 1e3 * trace_lib.percentile(gaps, 99)
+        out["itl_p90_ms"] = 1e3 * trace_lib.percentile(gaps, 90)
+        out["itl_over_3x_median_share_pct"] = 100.0 * sum(
+            g > 3 * median for g in gaps) / len(gaps)
     return out
 
 
@@ -284,7 +288,7 @@ def judge_requests(records: List[Dict[str, Any]],
     Closed loop: streams dropped when the window closed are not
     attempts. Open loop: every request sent is one."""
     attempted = failed = 0
-    reasons: Dict[str, int] = {}
+    reasons: Dict[str, Any] = {}
     for r in records:
         if closed_loop and r["outcome"] == "dropped_at_stop":
             continue
@@ -295,6 +299,9 @@ def judge_requests(records: List[Dict[str, Any]],
             why = (r["outcome"] if r["outcome"] != "ok"
                    else "wrong_token_count")
             reasons[str(why)] = reasons.get(str(why), 0) + 1
+            if r.get("error"):
+                reasons.setdefault("first_error", f"{r.get('status')} "
+                                   f"{str(r['error'])[-120:]}")
     return {"attempted": attempted, "failed": failed, "reasons": reasons}
 
 
@@ -346,10 +353,11 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     shape = config["serve"]
     vocab = int(config["vocab_size"])
     problems: List[str] = []
+    reference = modules.reference_of(ctx["spec"], config)
 
     t = time.monotonic()
-    cfg = model_config(config, shape.get("dtype", "bfloat16"))
-    params = jax.block_until_ready(make_params(cfg, ctx["seed"]))
+    cfg, init = program.serving_model(config, shape.get("dtype", "bfloat16"))
+    params = jax.block_until_ready(make_params(init, cfg, ctx["seed"]))
     log(f"weights on device ({time.monotonic() - t:.1f}s)")
 
     t = time.monotonic()
@@ -357,14 +365,14 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     tokens, lens = traffic_lib.check_prompts(
         traffic, vocab, ctx["seed"], int(check.get("prompts", 8)), depth)
     ref = jax.block_until_ready(reference_logits(
-        config, params, tokens, lens, depth, int(check.get("q_block", 256))))
+        reference, config, check, params, tokens, lens, depth))
     log(f"reference logits {ref.shape} ({time.monotonic() - t:.1f}s)")
     tolerances = ({"rtol_of_max": float(check["rtol_of_max"])}
                   if "rtol_of_max" in check else {})
     wrong = {}
     for variant in workload.get("wrong_variants", []):
-        off = reference_logits(config, params, tokens, lens, depth,
-                               int(check.get("q_block", 256)), wrong=variant)
+        off = reference_logits(reference, config, check, params, tokens,
+                               lens, depth, wrong=variant)
         wrong[variant] = check_lib.judge_logits(
             float(jnp.max(jnp.abs(off - ref))), float(jnp.max(jnp.abs(ref))),
             **tolerances)
@@ -424,6 +432,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
 
         time.sleep(max(0.0, t0 - time.monotonic()))
         compiles_before = ctx["compiles"].snapshot()["backend_compiles"]
+        engine_before = engine.metrics.snapshot()
         tracer.start()
         if tracer.enabled:
             time.sleep(max(0.0, min(t1, t0 + float(workload.get(
@@ -432,6 +441,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         time.sleep(max(0.0, t1 - time.monotonic()))
         compiled = (ctx["compiles"].snapshot()["backend_compiles"]
                     - compiles_before)
+        engine_after = engine.metrics.snapshot()
         pipe_thread.join(timeout=give_up + 30.0)
         if pipe_thread.is_alive() or "out" not in box:
             raise RuntimeError("the load generator did not end")
@@ -472,6 +482,11 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         "decode_steps": engine.metrics.decode_steps,
         "prefill_calls": engine.metrics.prefill_calls,
     }
+    # the program's own counters: every number of the engine's snapshot,
+    # as its change over the window
+    for name, value in engine_after.items():
+        if isinstance(value, (int, float)):
+            counters[f"engine.{name}"] = value - engine_before.get(name, 0)
     return {
         "problems": problems,
         "attempted": judged["attempted"], "failed": judged["failed"],
@@ -496,6 +511,8 @@ def notes(ctx: Dict[str, Any], result: Dict[str, Any], peaks) -> List[str]:
             if r.get("late_s") is not None]
     if late:
         waits["loadgen_late_p95_ms"] = 1e3 * trace_lib.percentile(late, 95)
+    own = {k: v for k, v in result["counters"].items()
+           if not k.startswith("engine.")}
     return [f"client view: {json.dumps(result['values'])}",
             f"waits: {json.dumps(waits)}",
-            f"engine: {result['counters']}"]
+            f"engine: {own}"]

@@ -9,8 +9,33 @@ or one per-layer metric is a file of its own:
     benchmarks/workloads/<cell>.json       runner kind, launch flags, check
     benchmarks/metrics/<metric>.json       the reader of one per-layer metric
 
-A later PR adds a cell by adding files and entries; nothing here names
-a cell, a configuration or a metric.
+A later PR adds a cell by adding files and entries; nothing under
+``benchmarks/lib`` names a cell, a configuration, a metric or a model
+family. Three kinds of code are named by data files, by path, and
+loaded by ``benchmarks/lib/modules.py``:
+
+- a configuration's ``reference``: a module with ``make_loss_fn(config,
+  *, wrong, with_gradients, **check_sizes)``, ``make_logits_fn(config,
+  *, wrong, **check_sizes)`` and ``GAIN_KEYS``; every ``wrong=`` name a
+  cell lists under ``wrong_variants`` is one it must offer, so that the
+  cell's tolerance is shown to reject something;
+- a ``roofline_share`` reader's ``cost_module`` (default
+  ``benchmarks/lib/costs.py``) with the ``cost_function`` it names, and
+  the functions a configuration's ``cost_inputs`` name for the MFU line;
+- nothing else: the model itself is built by the program from the
+  configuration's keys (``benchmarks/lib/program.py``).
+
+A path in a data file is looked up in the tree given by ``--root`` and
+then in the checkout (``Spec.find``). The one change to an existing
+line that a new cell needs: its name appended to the ``workloads`` list
+of each metric of ``BENCHMARK.json`` that it reports.
+
+Counter names a ``counter`` reader may give as ``key``: the runner's own
+(``steps``; ``decode_steps``, ``prefill_calls``, ``live_tokens_mean``),
+every end-to-end value and ``setup_s``, ``memory_peak_bytes``, and the
+program's own: ``step.<name>`` for every scalar of the last training
+step's metrics, ``engine.<name>`` for every number of the engine's
+``metrics.snapshot()``, as its change over the window.
 """
 
 from __future__ import annotations
@@ -21,13 +46,10 @@ from typing import Any, Dict, List
 
 BENCH_DIR = "benchmarks"
 
-# config.json keys that the program's model configurations
-# (``LlamaConfig``, the trainer's arguments) take under the same name
-MODEL_SHAPE_KEYS = (
-    "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
-    "num_attention_heads", "num_key_value_heads", "head_dim", "rope_theta",
-    "rms_norm_eps", "max_position_embeddings", "tie_word_embeddings",
-)
+
+class Refused(Exception):
+    """The run cannot be made as its files stand (exit code 2, no
+    result line): the message names what is missing."""
 
 
 def default_root() -> str:
@@ -53,6 +75,16 @@ class Spec:
 
     def path(self, *parts: str) -> str:
         return os.path.join(self.root, *parts)
+
+    def find(self, relative: str) -> str | None:
+        """The file a data file names by its path: in this tree, else in
+        the checkout that holds the harness; ``None`` where neither has
+        it."""
+        for root in (self.root, default_root()):
+            candidate = os.path.join(root, relative)
+            if os.path.isfile(candidate):
+                return candidate
+        return None
 
     def _entry(self, section: str, name: str) -> Dict[str, Any]:
         for entry in self.index[section]:

@@ -14,20 +14,18 @@ from typing import Any, Dict, List
 
 import numpy as np
 
+from benchmarks.lib import modules, program
 from benchmarks.lib import traffic as traffic_lib
 
-from benchmarks.lib.spec import MODEL_SHAPE_KEYS
-
-# config.json keys the trainer's arguments take under the same name
-MODEL_KEYS = ("model_type",) + MODEL_SHAPE_KEYS
+# keys of a cell's ``check`` that are the runner's own; every other key
+# is a size of the reference and goes to its factory as it is
+RUNNER_CHECK_KEYS = ("gradients", "loss_rtol", "grad_norm_rtol",
+                     "gain_grad_rtol")
 
 
 def trainer_arguments(config: Dict[str, Any], workload: Dict[str, Any],
                       traffic: Dict[str, Any], seed: int):
-    from scaletorch_tpu.config import ScaleTorchTPUArguments
-
-    kwargs = {k: config[k] for k in MODEL_KEYS if k in config}
-    kwargs.update(config.get("train", {}))
+    kwargs = dict(config.get("train", {}))
     kwargs.update(workload.get("launch", {}))
     dp = int(kwargs.get("data_parallel_size", 1))
     rows = int(traffic["sequences_per_step"])
@@ -42,30 +40,26 @@ def trainer_arguments(config: Dict[str, Any], workload: Dict[str, Any],
         log_frequency=10_000_000,
         total_train_steps=10_000_000,
     )
-    return ScaleTorchTPUArguments(**kwargs)
+    return program.launch_arguments(config, **kwargs)
 
 
 def _single_device(tree, device):
-    """Each leaf's copy on ``device``, without moving anything: the
-    state is replicated over cp/dp, so the first device's shard is the
-    whole array."""
+    """Each leaf whole on ``device``. Where the state is replicated
+    (over cp/dp) the device's own shard is the whole array and nothing
+    moves; a leaf sharded over the mesh (experts over ep, anything over
+    tp) is assembled from its shards and put there."""
     import jax
 
     def pick(leaf):
         for shard in leaf.addressable_shards:
-            if shard.device == device:
-                if shard.data.shape != leaf.shape:
-                    raise ValueError(
-                        "the reference check needs parameters replicated "
-                        f"on one device; got a {shard.data.shape} shard of "
-                        f"{leaf.shape}")
+            if shard.device == device and shard.data.shape == leaf.shape:
                 return shard.data
-        raise ValueError(f"no shard on {device}")
+        return jax.device_put(jax.device_get(leaf), device)
 
     return jax.tree.map(pick, tree)
 
 
-def reference_first_step(trainer, config, check: Dict[str, Any],
+def reference_first_step(trainer, reference, config, check: Dict[str, Any],
                          batch: Dict[str, np.ndarray],
                          wrong=None) -> Dict[str, Any]:
     """Loss (and, where the cell's check asks for gradients, the global
@@ -75,15 +69,13 @@ def reference_first_step(trainer, config, check: Dict[str, Any],
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.reference import qwen3 as reference
-
     device = jax.local_devices()[0]
     params = _single_device(trainer.params, device)
     gradients = bool(check.get("gradients", False))
-    fn = reference.make_loss_fn(
-        config, q_block=int(check.get("q_block", 512)),
-        loss_chunk=int(check.get("loss_chunk", 1024)), wrong=wrong,
-        with_gradients=gradients)
+    fn = reference.make_loss_fn(config, wrong=wrong,
+                                with_gradients=gradients,
+                                **modules.check_sizes(
+                                    check, RUNNER_CHECK_KEYS))
     positions = jnp.asarray(batch["position_ids"][0])
     rows = batch["input_ids"][0]
     if gradients and len(rows) != 1:
@@ -113,7 +105,7 @@ def step_values(metrics: Dict[str, Any]) -> Dict[str, float]:
 
 
 def first_step_gain_gradients(trainer, args, grad_norm: float,
-                              ) -> Dict[str, np.ndarray]:
+                              gain_keys) -> Dict[str, np.ndarray]:
     """The gradient the system's first step computed for every norm gain,
     read from where the step left it: after one update from zero
     moments, Adam's first moment is ``(1 - b1) x`` the gradient the
@@ -123,14 +115,12 @@ def first_step_gain_gradients(trainer, args, grad_norm: float,
     import jax
     import optax
 
-    from benchmarks.reference.qwen3 import GAIN_KEYS
-
     mu = optax.tree_utils.tree_get(trainer.opt_state, "mu")
     clip = 1.0
     if args.max_grad_norm and args.max_grad_norm > 0:
         clip = min(1.0, args.max_grad_norm / max(grad_norm, 1e-12))
     unscale = 1.0 / ((1.0 - args.adam_beta1) * clip)
-    picked = {k: mu["layers"][k] for k in GAIN_KEYS if k in mu["layers"]}
+    picked = {k: mu["layers"][k] for k in gain_keys if k in mu["layers"]}
     picked["norm"] = mu["norm"]
     return {k: np.asarray(jax.device_get(v), np.float32) * unscale
             for k, v in picked.items()}
@@ -147,6 +137,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     config, workload, traffic = ctx["config"], ctx["workload"], ctx["traffic"]
     log = ctx["log"]
     check = workload.get("check", {})
+    reference_module = modules.reference_of(ctx["spec"], config)
     batches = traffic_lib.train_batches(
         traffic, int(config["vocab_size"]), ctx["seed"])
     args = trainer_arguments(config, workload, traffic, ctx["seed"])
@@ -161,7 +152,8 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
                             f"{trainer.attention_backend!r}, not {want!r}")
 
         t0 = time.monotonic()
-        reference = reference_first_step(trainer, config, check, batches[0])
+        reference = reference_first_step(trainer, reference_module, config,
+                                         check, batches[0])
         log(f"reference first step: loss {reference['loss']}, gradient "
             f"norm {reference['grad_norm']} ({time.monotonic() - t0:.1f}s)")
         # a cell measured at another size states its own tolerances in
@@ -172,15 +164,16 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         for variant in workload.get("wrong_variants", []):
             # how far a deliberately wrong computation lands from the
             # reference, and whether the tolerance rejects it
-            off = reference_first_step(trainer, config, check, batches[0],
-                                       wrong=variant)
+            off = reference_first_step(trainer, reference_module, config,
+                                       check, batches[0], wrong=variant)
             wrong[variant] = check_lib.judge_train(off, reference,
                                                    **tolerances)
             log(f"wrong variant {variant}: {wrong[variant]}")
         first = step_values(trainer.step(batches[0]))
         if check.get("gradients"):
             first["gain_grads"] = first_step_gain_gradients(
-                trainer, args, first["grad_norm"])
+                trainer, args, first["grad_norm"],
+                reference_module.GAIN_KEYS)
         verdict = check_lib.judge_train(first, reference, **tolerances)
         if wrong:
             verdict["wrong_variants"] = wrong
@@ -220,6 +213,12 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         jax.block_until_ready(trainer.params)
         window_s = time.monotonic() - window_start
         tracing.stop()
+        # the program's own counters: every scalar of the last step's
+        # metrics, one readback outside the timed window
+        counters = {"steps": steps}
+        for name, value in jax.device_get(pending).items():
+            if np.ndim(value) == 0:
+                counters[f"step.{name}"] = float(value)
         compiled = (ctx["compiles"].snapshot()["backend_compiles"]
                     - compiles_before)
 
@@ -237,7 +236,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
             "attempted": steps, "failed": len(bad),
             "setup_s": setup_s, "window_s": window_s,
             "values": {"train_tokens_per_s_per_chip": rate},
-            "counters": {"steps": steps},
+            "counters": counters,
             "records": {},
             "check": verdict,
         }
@@ -246,20 +245,26 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def notes(ctx: Dict[str, Any], result: Dict[str, Any], peaks) -> List[str]:
-    """Lines printed before the result: MFU by the benchmark's causal
-    count and by the repo's ``6N + 12LHDS`` convention."""
-    from benchmarks.lib import costs
-
-    if not peaks:
+    """Lines printed before the result: MFU by every count of operations
+    per token that the configuration's ``cost_inputs`` names as
+    ``{"function": ..., "module": ...}`` (called with the configuration
+    and the sequence length), against the peak it names. A configuration
+    that names none gets no line."""
+    inputs = ctx["config"].get("cost_inputs", {})
+    if not peaks or inputs.get("peak") not in peaks:
         return []
     seq = int(ctx["traffic"]["sequence_length"])
     rate = result["values"]["train_tokens_per_s_per_chip"]
-    peak = float(peaks["bf16_flops_per_s"])
-    causal = costs.train_flops_per_token(ctx["config"], seq)
-    square = costs.train_flops_per_token_full_square(ctx["config"], seq)
-    return [
-        f"mfu_causal_required={100 * rate * causal / peak:.2f}% "
-        f"({causal / 1e9:.3f} GFLOP/token, causal half, no recomputation)",
-        f"mfu_repo_convention={100 * rate * square / peak:.2f}% "
-        f"({square / 1e9:.3f} GFLOP/token, 6N + 12LHDS)",
-    ]
+    peak = float(peaks[inputs["peak"]])
+    lines = []
+    for name, named in inputs.items():
+        if not (isinstance(named, dict) and "function" in named):
+            continue
+        per_token = modules.cost_function(
+            ctx["spec"], named["function"], named.get("module"))(
+                ctx["config"], seq)
+        lines.append(
+            f"mfu.{name}={100 * rate * per_token / peak:.2f}% "
+            f"({per_token / 1e9:.3f} GFLOP/token: {named['function']}, "
+            f"{named.get('what', '')})")
+    return lines

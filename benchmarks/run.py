@@ -82,7 +82,7 @@ def report_per_layer(line, tracer, spec, args, result, counters, config,
     reduce_ctx = {
         "events": events, "window": window, "records": result["records"],
         "counters": counters, "config": config, "traffic": traffic,
-        "workload": workload, "peaks": peaks or {},
+        "workload": workload, "peaks": peaks or {}, "spec": spec,
     }
     for metric in spec.per_layer(args.workload):
         value = reducers.read_metric(reduce_ctx, metric)
@@ -106,7 +106,7 @@ def report_per_layer(line, tracer, spec, args, result, counters, config,
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    from benchmarks.lib.spec import Spec
+    from benchmarks.lib.spec import Refused, Spec
 
     spec = Spec(args.root)
     workload = spec.workload(args.workload)
@@ -203,6 +203,8 @@ def main(argv=None) -> int:
         else:
             report_per_layer(line, tracer, spec, args, result, counters,
                              config, traffic, workload, peaks)
+    except Refused as exc:
+        return refuse(str(exc))
     finally:
         tracer.stop()
         tracer.cleanup()

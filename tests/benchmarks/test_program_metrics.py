@@ -39,7 +39,7 @@ def test_the_spec_finds_the_metric_for_its_cells_only(name):
         "percentile": 50, "scale": 1000.0}
     assert (metric["unit"], metric["better"], metric["source"],
             metric["moves"]) == ("ms", "lower", "program_counter",
-                                 "serve_itl_p95_ms")
+                                 "serve_itl_p99_ms")
     # an inside view of a layer the benchmark already names
     outside = {m["layer"] for m in SPEC.index["per_layer"]
                if m["name"] not in PROGRAM_METRICS}

@@ -3,7 +3,12 @@
 The real harness (``benchmarks/run.py`` and ``benchmarks/lib``) runs it
 through ``--root``; nothing in the harness knows these names. That is
 the proof that a later PR can add a configuration, a traffic mix, a
-cell and a per-layer metric as new files and new entries.
+cell and a per-layer metric as new files and new entries. With
+``second_family`` the tree also holds a configuration of another model
+family (``model_type: "llama"``: no q/k norm, a head of its own, MHA)
+with its own plain reference, ``toy_llama_reference.py`` copied into
+the tree, and a train and a serve cell on it: the family too is files
+and entries only.
 """
 
 from __future__ import annotations
@@ -22,7 +27,21 @@ TOY_MODEL = {
     "max_position_embeddings": 4096, "rms_norm_eps": 1e-06,
     "rope_theta": 1000000, "tie_word_embeddings": True,
     "reduced": [], "assumed": {},
+    "reference": "benchmarks/reference/qwen3.py",
 }
+
+TOY_LLAMA_REFERENCE = "benchmarks/reference/toy_llama.py"
+TOY_LLAMA = {
+    "model_type": "llama", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 4,
+    "max_position_embeddings": 4096, "rms_norm_eps": 1e-05,
+    "rope_theta": 10000.0, "tie_word_embeddings": False,
+    "reduced": [], "assumed": {},
+    "reference": TOY_LLAMA_REFERENCE,
+}
+# family -> (configuration keys, prefix of its configurations and cells)
+FAMILIES = {"qwen3": (TOY_MODEL, "toy"), "llama": (TOY_LLAMA, "toy-llama")}
 
 
 CLIENT_SIDE_READERS = (
@@ -39,21 +58,37 @@ def _write(path: str, obj) -> None:
 
 
 def make_toy_root(root: str, *, cp: int = 1, serve_kind: str = "closed_loop",
-                  extra_metric: bool = False) -> str:
+                  extra_metric: bool = False, second_family: bool = False,
+                  references: dict = None, serve_rtol_of_max: float = None,
+                  ) -> str:
     """Writes the tree and returns ``root``. Cells: ``toy-train`` (cp
-    chips), ``toy-serve`` (one chip)."""
+    chips), ``toy-serve`` (one chip); with ``second_family`` also
+    ``toy-llama-train`` and ``toy-llama-serve``. ``references`` points a
+    family's configurations at another reference file;
+    ``serve_rtol_of_max`` states the serve cells' own tolerance (float32
+    at toy size lands four orders under the 1.7B cell's bf16 one)."""
     bench = os.path.join(root, "benchmarks")
     shutil.copytree(os.path.join(REPO, "benchmarks", "metrics"),
                     os.path.join(bench, "metrics"))
     shutil.copy(os.path.join(REPO, "benchmarks", "peaks.json"), bench)
-    _write(os.path.join(bench, "configs", "toy-train.json"), dict(
-        TOY_MODEL, name="toy-train", source="made up for the tests",
-        train={"dtype": "bfloat16", "param_dtype": "float32",
-               "gradient_checkpointing": True}))
-    _write(os.path.join(bench, "configs", "toy-serve.json"), dict(
-        TOY_MODEL, name="toy-serve", source="made up for the tests",
-        serve={"max_slots": 4, "max_seq": 128, "prefill_len": 64,
-               "page_size": 16, "dtype": "float32"}))
+    families = ["qwen3"] + (["llama"] if second_family else [])
+    if second_family:
+        os.makedirs(os.path.join(bench, "reference"))
+        shutil.copy(os.path.join(REPO, "tests", "benchmarks",
+                                 "toy_llama_reference.py"),
+                    os.path.join(root, TOY_LLAMA_REFERENCE))
+    for family in families:
+        model, prefix = FAMILIES[family]
+        model = dict(model, reference=(references or {}).get(
+            family, model["reference"]))
+        _write(os.path.join(bench, "configs", f"{prefix}-train.json"), dict(
+            model, name=f"{prefix}-train", source="made up for the tests",
+            train={"dtype": "bfloat16", "param_dtype": "float32",
+                   "gradient_checkpointing": True}))
+        _write(os.path.join(bench, "configs", f"{prefix}-serve.json"), dict(
+            model, name=f"{prefix}-serve", source="made up for the tests",
+            serve={"max_slots": 4, "max_seq": 128, "prefill_len": 64,
+                   "page_size": 16, "dtype": "float32"}))
     _write(os.path.join(bench, "traffic", "toy-steps.json"), {
         "kind": "train_steps", "sequence_length": 64 * cp,
         "sequences_per_step": 1, "distinct_batches": 2})
@@ -72,27 +107,40 @@ def make_toy_root(root: str, *, cp: int = 1, serve_kind: str = "closed_loop",
     if cp > 1:
         launch = {"context_parallel_size": cp, "attention_backend": "ring",
                   "cp_layout": "zigzag"}
-    _write(os.path.join(bench, "workloads", "toy-train.json"), {
-        "name": "toy-train", "kind": "train", "config": "toy-train",
-        "traffic": "toy-steps", "chips": cp, "launch": launch,
-        # 64 positions over a 512-word vocabulary average bf16 rounding
-        # far less than 8192 over 151,936 do: the toy cell's own tolerance
-        "check": {"gradients": True, "q_block": 32, "loss_chunk": 32,
-                  "loss_rtol": 5e-4, "grad_norm_rtol": 8e-3,
-                  "gain_grad_rtol": 5e-2},
-        "warmup_steps": 1, "trace_seconds": 0.5})
-    _write(os.path.join(bench, "workloads", "toy-serve.json"), {
-        "name": "toy-serve", "kind": "serve", "config": "toy-serve",
-        "traffic": "toy-requests", "chips": 1,
-        "check": {"prompts": 4, "decode_positions": 8, "q_block": 8},
-        "trace_seconds": 0.5})
+    serve_check = {"prompts": 4, "decode_positions": 8, "q_block": 8}
+    if serve_rtol_of_max is not None:
+        serve_check["rtol_of_max"] = serve_rtol_of_max
+    prefixes = [FAMILIES[f][1] for f in families]
+    for prefix in prefixes:
+        _write(os.path.join(bench, "workloads", f"{prefix}-train.json"), {
+            "name": f"{prefix}-train", "kind": "train",
+            "config": f"{prefix}-train", "traffic": "toy-steps",
+            "chips": cp, "launch": launch,
+            # 64 positions over a 512-word vocabulary average bf16
+            # rounding far less than 8192 over 151,936 do: the toy
+            # cell's own tolerance
+            "check": {"gradients": True, "q_block": 32, "loss_chunk": 32,
+                      "loss_rtol": 5e-4, "grad_norm_rtol": 8e-3,
+                      "gain_grad_rtol": 5e-2},
+            "warmup_steps": 1, "trace_seconds": 0.5})
+        _write(os.path.join(bench, "workloads", f"{prefix}-serve.json"), {
+            "name": f"{prefix}-serve", "kind": "serve",
+            "config": f"{prefix}-serve", "traffic": "toy-requests",
+            "chips": 1, "check": serve_check, "trace_seconds": 0.5})
+
+    def toy_cells(real_cells):
+        """The toy cells that stand for these cells of BENCHMARK.json:
+        the one change to an existing line that a new cell needs is its
+        name in the ``workloads`` list of each metric it reports."""
+        kinds = {"train" if c.startswith("train") else "serve"
+                 for c in real_cells}
+        return sorted(f"{p}-{k}" for p in prefixes for k in kinds)
+
     per_layer = []
     index = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     for metric in index["per_layer"]:
-        cells = metric.get("workloads", [])
-        kinds = {("toy-train" if c.startswith("train") else "toy-serve")
-                 for c in cells}
-        per_layer.append(dict(metric, workloads=sorted(kinds)))
+        per_layer.append(dict(
+            metric, workloads=toy_cells(metric.get("workloads", []))))
     # readers that are files under benchmarks/metrics and in no cell of
     # BENCHMARK.json yet (client-side quantities waiting for an
     # end-to-end metric they move): the toy tree adds them as entries
@@ -102,36 +150,44 @@ def make_toy_root(root: str, *, cp: int = 1, serve_kind: str = "closed_loop",
             "name": name, "unit": unit,
             "better": "higher" if unit == "tokens/s" else "lower",
             "source": "host_clock", "layer": "client",
-            "moves": "serve_itl_p95_ms", "workloads": ["toy-serve"]})
+            "moves": "serve_itl_p99_ms",
+            "workloads": [f"{p}-serve" for p in prefixes]})
     if extra_metric:
-        _write(os.path.join(bench, "metrics", "toy_steps_counted.json"), {
-            "name": "toy_steps_counted",
-            "reducer": {"kind": "counter", "key": "steps"}})
-        per_layer.append({
-            "name": "toy_steps_counted", "unit": "steps",
-            "better": "higher", "source": "program_counter",
-            "layer": "train step",
-            "moves": "train_tokens_per_s_per_chip",
-            "workloads": ["toy-train"]})
+        # counters as files: the runner's own count, and two of the
+        # program's own (a scalar of the last step's metrics, a number
+        # of the engine's snapshot as its change over the window)
+        for name, key, unit, kind, moves in (
+                ("toy_steps_counted", "steps", "steps", "train",
+                 "train_tokens_per_s_per_chip"),
+                ("toy_step_loss", "step.loss", "nats", "train",
+                 "train_tokens_per_s_per_chip"),
+                ("toy_engine_decode_steps", "engine.decode_steps", "steps",
+                 "serve", "serve_itl_p99_ms")):
+            _write(os.path.join(bench, "metrics", f"{name}.json"), {
+                "name": name, "reducer": {"kind": "counter", "key": key}})
+            per_layer.append({
+                "name": name, "unit": unit, "better": "higher",
+                "source": "program_counter", "layer": f"{kind} step",
+                "moves": moves,
+                "workloads": [f"{p}-{kind}" for p in prefixes]})
     end_to_end = []
     for metric in index["end_to_end"]:
         if "workloads" in metric:
-            kinds = {("toy-train" if c.startswith("train") else "toy-serve")
-                     for c in metric["workloads"]}
-            metric = dict(metric, workloads=sorted(kinds))
+            metric = dict(metric, workloads=toy_cells(metric["workloads"]))
         end_to_end.append(metric)
     _write(os.path.join(root, "BENCHMARK.json"), {
         "command": index["command"], "paths": index["paths"],
         "run_seconds": 1,
         "configs": [
-            {"name": n, "source": "made up for the tests",
-             "file": f"benchmarks/configs/{n}.json", "reduced": [],
-             "why": "toy"} for n in ("toy-train", "toy-serve")],
+            {"name": f"{p}-{k}", "source": "made up for the tests",
+             "file": f"benchmarks/configs/{p}-{k}.json", "reduced": [],
+             "why": "toy"} for p in prefixes for k in ("train", "serve")],
         "workloads": [
-            {"name": "toy-train", "config": "toy-train",
-             "traffic": "toy-steps", "chips": cp, "why": "toy"},
-            {"name": "toy-serve", "config": "toy-serve",
-             "traffic": "toy-requests", "chips": 1, "why": "toy"}],
+            cell for p in prefixes for cell in (
+                {"name": f"{p}-train", "config": f"{p}-train",
+                 "traffic": "toy-steps", "chips": cp, "why": "toy"},
+                {"name": f"{p}-serve", "config": f"{p}-serve",
+                 "traffic": "toy-requests", "chips": 1, "why": "toy"})],
         "end_to_end": end_to_end, "per_layer": per_layer,
     })
     return root
