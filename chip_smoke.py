@@ -240,7 +240,9 @@ def leg_kernels(dry_run: bool) -> dict:
     from scaletorch_tpu.ops.pallas.paged_attention import (
         paged_attention,
         paged_gather_kv,
+        paged_write_kv,
         pallas_paged_decode_attention,
+        pallas_paged_write,
     )
 
     interpret = dry_run  # never True on the chip
@@ -350,6 +352,49 @@ def leg_kernels(dry_run: bool) -> dict:
         16, serve_pages,
         np.linspace(31, serve_pages * page - 1, 16).astype(np.int32))
 
+    # ---- the page write, in place, vs the scatter: bit for bit ----------
+    def write_case(slots, max_pages, rows, layers=3, layer=1):
+        n_pages = slots * max_pages + 1
+        pool = normal((layers, n_pages, hkv, page, d))
+        tables_w = jnp.asarray(
+            rng.permutation(np.arange(1, n_pages)).reshape(slots, max_pages),
+            jnp.int32)
+        if rows == 1:   # decode: any offset, one position past the table
+            first = rng.integers(0, max_pages * page, slots)
+            first[-1] = max_pages * page
+        else:           # prefill: page-aligned starts
+            first = rng.integers(0, max_pages // 2, slots) * page
+        positions_w = jnp.asarray(first[:, None] + np.arange(rows), jnp.int32)
+        mask = jnp.asarray(np.arange(slots) != 1)   # slot 1 -> TRASH
+        new = normal((slots, hkv, rows, d))
+        want = jax.jit(lambda *a: paged_write_kv(*a[:-1], page, a[-1],
+                                                 layer=layer))(
+            pool, new, positions_w, tables_w, mask)
+        got = jax.jit(lambda *a: pallas_paged_write(
+            *a, layer=layer, interpret=interpret))(
+                pool, new, positions_w, tables_w, mask)
+        same = bool(jnp.all(want[:, 1:] == got[:, 1:]))   # all but TRASH
+        check(same, f"paged_write differs from the scatter "
+                    f"(slots {slots}, rows {rows})")
+        check(bool(jnp.all(jnp.isfinite(got[:, 0].astype(jnp.float32)))),
+              "paged_write left TRASH not finite")
+        read = jax.jit(lambda *a: pallas_paged_decode_attention(
+            *a, layer=layer, interpret=interpret))(
+                qd[:slots], got, got, tables_w, positions_w[:, 0] % page)
+        read_one = jax.jit(lambda *a: pallas_paged_decode_attention(
+            *a, interpret=interpret))(
+                qd[:slots], got[layer], got[layer], tables_w,
+                positions_w[:, 0] % page)
+        check(bool(jnp.all(read == read_one)),
+              "the decode kernel at a layer index differs from the kernel "
+              "on that layer's pool")
+        return {"slots": slots, "rows": rows, "bit_identical": same}
+
+    write_pages = 6 if dry_run else 96
+    paged_write = [write_case(8, write_pages, 1),
+                   write_case(8, write_pages, (write_pages // 2) * page)]
+    log(f"paged-write parity: {json.dumps(paged_write)}")
+
     # ---- the dispatchers pick the kernels iff the platform is tpu ------
     lowered = {
         "flash": jax.jit(flash_attention).lower(q, k, v).as_text(),
@@ -366,6 +411,7 @@ def leg_kernels(dry_run: bool) -> dict:
               f"platform {device['platform']}")
     return {"device": device, "flash": flash, "paged_decode": paged,
             "paged_decode_serving": paged_serving,
+            "paged_write": paged_write,
             "memory_stats": {str(d.id): d.memory_stats()
                              for d in jax.devices()}}
 

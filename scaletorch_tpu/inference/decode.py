@@ -11,8 +11,10 @@ per run, however many requests flow through it:
     buffer, flip its mask bit" — no new trace.
   * ``decode``: one token per slot at per-slot absolute positions,
     RoPE at the absolute position, ``lax.dynamic_update_slice`` cache
-    append, sample. Cache buffers are DONATED — XLA appends in place
-    instead of copying the whole cache every token.
+    append, sample. Cache buffers are DONATED and carried whole through
+    the forwards' layer loop (``llama.scan_layers_cached``) — the
+    append happens in place instead of copying the whole cache every
+    token.
 
 Both lower onto the models' cache-aware forwards
 (models/llama.py forward_cached & family), resolved per config by
@@ -305,11 +307,12 @@ def make_paged_decode_step(
           new_pool)
 
     Identical contract to ``make_decode_step`` with the cache reads
-    routed through the page table: the K/V append is a scatter into the
-    slot's current page and attention is a gather over its table (the
-    Pallas paged-decode kernel on TPU, the lax gather fallback on other
-    platforms — ops/pallas/paged_attention.py). Page-table
-    contents are DATA: admissions, prefix hits, quarantine clears, and
+    routed through the page table: the K/V append writes one row of the
+    slot's current page and attention walks its table, the donated pool
+    carried whole through the layer loop (the Mosaic pair on TPU, in
+    place: ``paged_write`` + the paged-decode kernel at a layer index;
+    the lax scatter + gather on other platforms —
+    ops/pallas/paged_attention.py). Page-table contents are DATA: admissions, prefix hits, quarantine clears, and
     frees all mutate tables host-side and this one compile serves them
     all. ``routing_counts`` as in ``make_paged_prefill_step``; the rows
     that exist are the active slots'.

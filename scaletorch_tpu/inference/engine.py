@@ -19,9 +19,11 @@ TPU execution discipline:
     admission becomes page-budget-aware (HBM scales with tokens cached,
     not B x S_max), a radix tree shares page-aligned prompt prefixes
     across requests (refcounted, copy-on-write at the page boundary),
-    and decode attention gathers through the table (the Pallas kernel
-    in ops/pallas/paged_attention.py on TPU, the lax fallback
-    elsewhere) — greedy outputs stay bit-identical to the dense layout
+    and the steps touch the pool through the table (the Mosaic pair of
+    ops/pallas/paged_attention.py on TPU: ``paged_write`` in place and
+    the decode kernel, the pool carried whole through the layer loop;
+    the lax scatter + gather elsewhere; the snapshot's
+    ``paged_pool_in_place`` says which) — greedy outputs stay bit-identical to the dense layout
     and the tables are data, so the one-compile discipline survives
     admissions, prefix hits, quarantine page-clears, and frees.
 
@@ -83,6 +85,7 @@ from scaletorch_tpu.inference.resilience import (
     ServingFaultInjector,
 )
 from scaletorch_tpu.inference.sampling import SamplingParams
+from scaletorch_tpu.ops.pallas.paged_attention import in_place_pair
 from scaletorch_tpu.telemetry.histogram import LogHistogram
 from scaletorch_tpu.telemetry.spans import span
 from scaletorch_tpu.utils.logger import get_logger
@@ -196,6 +199,11 @@ class EngineMetrics:
     # imported from peers since boot
     prefix_pages: int = 0
     warm_pages_total: int = 0
+    # which pair the two paged step programs were built with: 1 = the
+    # Mosaic pair (``paged_write`` in place + the decode kernel at a
+    # layer index; a TPU whose head_dim the kernels serve), 0 = the lax
+    # scatter + gather (and on the dense layout)
+    paged_pool_in_place: int = 0
     ttft_sum_s: float = 0.0
     ttft_count: int = 0
     outcomes: Dict[str, int] = field(
@@ -272,6 +280,7 @@ class EngineMetrics:
             "prefill_tokens_saved": self.prefill_tokens_saved,
             "prefix_pages": self.prefix_pages,
             "warm_pages_total": self.warm_pages_total,
+            "paged_pool_in_place": self.paged_pool_in_place,
         }
         for outcome, count in self.outcomes.items():
             snap[f"requests_{outcome}"] = count
@@ -616,7 +625,10 @@ class InferenceEngine:
         self._base_keys = np.zeros((max_slots, 2), np.uint32)
         self._base_keys_dev = None
         self._draining = False
-        self.metrics = EngineMetrics(num_slots=max_slots, routing=routing)
+        self.metrics = EngineMetrics(
+            num_slots=max_slots, routing=routing,
+            paged_pool_in_place=int(
+                self._paged and in_place_pair(self.cache.k.shape[-1])))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
         # the clock that is open, the last boundary; this tick's seconds
         # by phase name; when the previous tick ended, and whether it

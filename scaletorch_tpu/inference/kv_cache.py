@@ -28,8 +28,11 @@ not ``B × S_max`` — and requests sharing a token prefix share pages:
 ``PageAllocator`` (host-side free list + refcounts) and
 ``RadixPrefixCache`` (page-granular radix tree over token chunks) keep
 the bookkeeping; ``PagedKVIO`` adapts the models' cache-aware forwards
-to the paged pool (ops/pallas/paged_attention.py holds the gather /
-scatter primitives and the Pallas decode kernel). Sharding mirrors the
+to the paged pool: they carry it whole through their layer loop and the
+adapter writes and reads it at a layer index
+(ops/pallas/paged_attention.py holds the two pairs: the Mosaic page
+write + decode kernel, in place, and the lax scatter + gather). Sharding
+mirrors the
 dense layout: the KV-head axis over the same ``tp`` mesh axis
 (``paged_kv_cache_specs``).
 """
@@ -46,7 +49,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from scaletorch_tpu.ops.pallas.paged_attention import (
     TRASH_PAGE,
     paged_attention,
-    paged_write_kv,
+    paged_write,
 )
 
 
@@ -514,15 +517,21 @@ class RadixPrefixCache:
 class PagedKVIO:
     """Paged-cache adapter for the models' cache-aware forwards.
 
-    The dense path writes with ``write_kv_cache`` and attends with
-    ``cached_sdpa_attention`` against ``[B, Hkv, S_max, D]`` buffers;
-    with a ``kv_io`` the same forwards write/attend through this object
-    against the page pool — constructed INSIDE the jitted step from the
-    traced page tables, so tables are data and the step compiles once.
+    The forwards carry the whole pool pair [L, n_pages, Hkv, page_size,
+    D] through their layer loop (``llama.scan_layers_cached``) and touch
+    it only through ``write`` and ``attend`` at a layer index, as they
+    touch the dense cache through ``layers.DenseKVIO`` — this object is
+    constructed INSIDE the jitted step from the traced page tables, so
+    tables are data and the step compiles once. Neither method slices a
+    layer out: the write is ``paged_write`` (the Mosaic page write, in
+    place, on a TPU whose head_dim the kernels serve; a scatter at
+    ``pool.at[layer, ...]`` elsewhere) and the read ``paged_attention``
+    (the decode kernel at ``pool.at[layer, page]``; a gather of whole
+    pages ``pool[layer, page_tables]`` for prefill and the fallback).
     ``seq_limit`` crops the fallback's gathered view to the engine's
     ``max_seq`` (bit-identical operand shapes vs the dense engine);
-    ``kernel`` forwards to ``paged_attention``'s dispatcher (None =
-    auto: Pallas decode kernel on TPU, lax gather elsewhere).
+    ``kernel`` forces the pair for both (None = auto, one predicate:
+    ``paged_attention.in_place_pair``).
     """
 
     def __init__(self, page_tables: jax.Array, page_size: int, *,
@@ -535,15 +544,19 @@ class PagedKVIO:
         self.kernel = kernel
         self.interpret = interpret
 
-    def write(self, pool: jax.Array, new: jax.Array, positions: jax.Array,
+    def write(self, pool: jax.Array, layer: jax.Array, new: jax.Array,
+              positions: jax.Array,
               write_mask: Optional[jax.Array]) -> jax.Array:
-        return paged_write_kv(pool, new, positions, self.page_tables,
-                              self.page_size, write_mask)
+        return paged_write(
+            pool, new, positions, self.page_tables, write_mask,
+            layer=layer, kernel=self.kernel, interpret=self.interpret)
 
     def attend(self, q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-               q_positions: jax.Array) -> jax.Array:
+               layer: jax.Array, q_positions: jax.Array) -> jax.Array:
         return paged_attention(
             q, pool_k, pool_v, self.page_tables, q_positions,
-            page_size=self.page_size, seq_limit=self.seq_limit,
-            kernel=self.kernel, interpret=self.interpret,
+            page_size=self.page_size, layer=layer, seq_limit=self.seq_limit,
+            kernel=None if self.kernel is None
+            else self.kernel and q.shape[2] == 1,
+            interpret=self.interpret,
         )

@@ -24,11 +24,11 @@ import jax
 import jax.numpy as jnp
 
 from scaletorch_tpu.models.layers import (
-    cached_sdpa_attention,
+    DenseKVIO,
     normal_init,
     sdpa_attention,
-    write_kv_cache,
 )
+from scaletorch_tpu.models.llama import scan_layers_cached
 from scaletorch_tpu.parallel.expert_parallel import (
     combine_routed,
     dispatch_routed,
@@ -238,16 +238,16 @@ def forward_cached(
     -> (logits [B, S, V], new cache). Positional signal is the learned
     ``wpe`` table looked up at the absolute positions (no RoPE). Routing
     is deterministic (no noise) — matching ``generate``'s eval-mode
-    forward. ``kv_io`` swaps the cache layout (paged pool) exactly as in
-    ``llama.attention_block_cached``.
+    forward. ``kv_io`` is the cache layout (None: dense; the paged pool's
+    adapter) and the layer loop ``llama.scan_layers_cached``, exactly as
+    in ``llama.forward_cached``.
     """
-    cache_k, cache_v = cache
     b, s = input_ids.shape
     cdt = cfg.dtype
+    kv_io = kv_io or DenseKVIO()
     x = (params["wte"][input_ids] + params["wpe"][positions]).astype(cdt)
 
-    def layer_body(h, xs):
-        layer, ck, cv = xs
+    def layer_fn(h, layer, index, ck, cv):
         a = _layer_norm(h, layer["ln1"])
         qkv = a @ layer["attn_qkv"].astype(cdt)
         q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -255,14 +255,9 @@ def forward_cached(
         def heads(t):
             return t.reshape(b, s, cfg.n_head, cfg.head_dim).transpose(0, 2, 1, 3)
 
-        if kv_io is None:
-            ck = write_kv_cache(ck, heads(k), positions[:, 0], write_mask)
-            cv = write_kv_cache(cv, heads(v), positions[:, 0], write_mask)
-            o = cached_sdpa_attention(heads(q), ck, cv, positions)
-        else:
-            ck = kv_io.write(ck, heads(k), positions, write_mask)
-            cv = kv_io.write(cv, heads(v), positions, write_mask)
-            o = kv_io.attend(heads(q), ck, cv, positions)
+        ck = kv_io.write(ck, index, heads(k), positions, write_mask)
+        cv = kv_io.write(cv, index, heads(v), positions, write_mask)
+        o = kv_io.attend(heads(q), ck, cv, index, positions)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_embd)
         h = h + o @ layer["attn_proj"].astype(cdt)
 
@@ -272,13 +267,11 @@ def forward_cached(
         else:
             y = jax.nn.gelu(m @ layer["mlp_fc"].astype(cdt))
             y = y @ layer["mlp_proj"].astype(cdt)
-        return h + y.astype(cdt), (ck, cv)
+        return h + y.astype(cdt), ck, cv, None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer_body, x, (params["layers"], cache_k, cache_v)
-    )
+    x, cache, _ = scan_layers_cached(layer_fn, x, cache, params["layers"])
     x = _layer_norm(x, params["ln_f"])
-    return x @ params["wte"].astype(cdt).T, (k_new, v_new)
+    return x @ params["wte"].astype(cdt).T, cache
 
 
 def generate(

@@ -313,6 +313,32 @@ def write_kv_cache(
     return updated
 
 
+class DenseKVIO:
+    """K/V adapter of the dense layout: what ``PagedKVIO``
+    (inference/kv_cache.py) is to the page pool. The cache-aware forwards
+    carry the whole stacked cache [L, B, Hkv, S_max, D] through their
+    layer loop and touch it only through an adapter's ``write`` and
+    ``attend`` at a layer index; this one is ``write_kv_cache`` +
+    ``cached_sdpa_attention`` on that layer."""
+
+    def write(self, cache: jax.Array, layer: jax.Array, new: jax.Array,
+              positions: jax.Array,
+              write_mask: Optional[jax.Array]) -> jax.Array:
+        updated = write_kv_cache(
+            jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False),
+            new, positions[:, 0], write_mask)
+        return jax.lax.dynamic_update_index_in_dim(cache, updated, layer, 0)
+
+    def attend(self, q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
+               layer: jax.Array, q_positions: jax.Array) -> jax.Array:
+        def at_layer(cache):
+            return jax.lax.dynamic_index_in_dim(
+                cache, layer, 0, keepdims=False)
+
+        return cached_sdpa_attention(
+            q, at_layer(cache_k), at_layer(cache_v), q_positions)
+
+
 # ---- losses -----------------------------------------------------------------
 def cross_entropy_loss(
     logits: jax.Array,

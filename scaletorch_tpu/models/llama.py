@@ -32,14 +32,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from scaletorch_tpu.models.layers import (
+    DenseKVIO,
     apply_rotary_pos_emb,
-    cached_sdpa_attention,
     fan_in_uniform,
     get_cos_sin,
     rms_norm,
     sdpa_attention,
     swiglu,
-    write_kv_cache,
 )
 from scaletorch_tpu.models.registry import (
     get_attention_backend,
@@ -553,6 +552,7 @@ def lm_head_weight(
 def attention_block_cached(
     x: jax.Array,
     layer: Params,
+    index: jax.Array,
     cache_k: jax.Array,
     cache_v: jax.Array,
     cos: jax.Array,
@@ -565,25 +565,28 @@ def attention_block_cached(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Cache-aware pre-norm attention sub-block with residual.
 
-    x: [B, S, H]; cache_k/cache_v: [B, Hkv, S_max, D]; cos/sin:
-    [B, S, Dh] per-slot RoPE tables; positions: [B, S] absolute token
-    positions (contiguous per slot — prefill passes [0..S), decode a
-    single column p). K/V are computed with RoPE at the absolute
-    positions, appended into the cache at ``positions[:, 0]`` (see
-    ``write_kv_cache``; ``write_mask`` [B] protects live slots during a
-    mixed admit-prefill), and attention runs q-against-cache with the
-    j <= p mask. Returns (out, new_cache_k, new_cache_v).
+    x: [B, S, H]; cache_k/cache_v: the WHOLE stacked cache, all layers
+    ([L, B, Hkv, S_max, D] dense), with ``index`` the layer this block
+    is; cos/sin: [B, S, Dh] per-slot RoPE tables; positions: [B, S]
+    absolute token positions (contiguous per slot — prefill passes
+    [0..S), decode a single column p). K/V are computed with RoPE at the
+    absolute positions, appended into the layer's cache at
+    ``positions[:, 0]`` (see ``write_kv_cache``; ``write_mask`` [B]
+    protects live slots during a mixed admit-prefill), and attention
+    runs q-against-cache with the j <= p mask. Returns (out,
+    new_cache_k, new_cache_v), the caches whole again.
 
-    ``kv_io`` swaps the cache layout: an adapter with
-    ``write(cache, kv, positions, write_mask)`` and
-    ``attend(q, cache_k, cache_v, positions)`` (e.g. the paged pool's
-    ``inference.kv_cache.PagedKVIO``) replaces the dense
-    ``write_kv_cache`` + ``cached_sdpa_attention`` pair; the cache
-    arrays then carry the adapter's layout instead of
-    [B, Hkv, S_max, D].
+    ``kv_io`` is the cache layout: an adapter with
+    ``write(cache, layer, kv, positions, write_mask)`` and
+    ``attend(q, cache_k, cache_v, layer, positions)``. None is the dense
+    layout's ``layers.DenseKVIO``; the paged pool's
+    ``inference.kv_cache.PagedKVIO`` carries [L, n_pages, Hkv, page, D]
+    instead. The block never slices a layer out of the cache itself:
+    whether that costs anything is the adapter's business.
     """
     cdt = cfg.dtype
     dh = cfg.actual_head_dim
+    kv_io = kv_io or DenseKVIO()
     h = rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
     b, s, _ = h.shape
     q, k = split_heads_qk_normed(
@@ -594,14 +597,9 @@ def attention_block_cached(
     k = k.transpose(0, 2, 1, 3)
     v = v.transpose(0, 2, 1, 3)
     q, k = apply_rotary_pos_emb(q, k, cos, sin)
-    if kv_io is None:
-        cache_k = write_kv_cache(cache_k, k, positions[:, 0], write_mask)
-        cache_v = write_kv_cache(cache_v, v, positions[:, 0], write_mask)
-        attn = cached_sdpa_attention(q, cache_k, cache_v, positions)
-    else:
-        cache_k = kv_io.write(cache_k, k, positions, write_mask)
-        cache_v = kv_io.write(cache_v, v, positions, write_mask)
-        attn = kv_io.attend(q, cache_k, cache_v, positions)
+    cache_k = kv_io.write(cache_k, index, k, positions, write_mask)
+    cache_v = kv_io.write(cache_v, index, v, positions, write_mask)
+    attn = kv_io.attend(q, cache_k, cache_v, index, positions)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return x + attn @ layer["o_proj"].astype(cdt), cache_k, cache_v
 
@@ -615,6 +613,44 @@ def _mlp_block(x: jax.Array, layer: Params, cfg: LlamaConfig) -> jax.Array:
     gate = h @ layer["gate_proj"].astype(cdt)
     up = h @ layer["up_proj"].astype(cdt)
     return x + swiglu(gate, up) @ layer["down_proj"].astype(cdt)
+
+
+def scan_layers_cached(
+    layer_fn: Callable,
+    x: jax.Array,
+    cache: Tuple[jax.Array, jax.Array],
+    layers: Params,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array], Any]:
+    """The one layer loop of the cache-aware forwards (Llama / Qwen3,
+    Qwen3-MoE / OLMoE, GPT-MoE): a ``lax.scan`` whose CARRY is
+    ``(h, cache_k, cache_v)`` and whose scanned operands are the stacked
+    layer parameters and the layer index.
+
+    ``layer_fn(h, layer, index, cache_k, cache_v) -> (h, cache_k,
+    cache_v, out)`` sees the whole cache and the index of its layer;
+    ``out`` is stacked over the layers (None for nothing). Returns
+    ``(h, (cache_k, cache_v), outs)``.
+
+    The cache is carried, never scanned: as ``xs`` / ``ys`` XLA slices a
+    layer out, re-lays it for the write and re-stacks the layers into a
+    new cache that the donated input cannot alias — 22.6 GB of copies a
+    Qwen3-1.7B decode step to append 1.8 MB (PERF.md, PR 28). Carried
+    whole, and touched only through the ``kv_io`` adapter at a layer
+    index, the donated buffer is the loop's buffer from the first layer
+    to the last.
+    """
+    cache_k, cache_v = cache
+
+    def body(carry, xs):
+        h, ck, cv = carry
+        layer, index = xs
+        h, ck, cv, out = layer_fn(h, layer, index, ck, cv)
+        return (h, ck, cv), out
+
+    (x, cache_k, cache_v), outs = jax.lax.scan(
+        body, (x, cache_k, cache_v),
+        (layers, jnp.arange(cache_k.shape[0], dtype=jnp.int32)))
+    return x, (cache_k, cache_v), outs
 
 
 def forward_cached(
@@ -631,38 +667,34 @@ def forward_cached(
     [B, S] -> (logits [B, S, V], new (cache_k, cache_v)).
 
     ``cache`` is a pair of [L, B, Hkv, S_max, D] stacked per-layer
-    buffers in the models' scan layout (inference/kv_cache.py builds and
-    shards them). One trace serves both engine steps: prefill (S = P,
-    positions [0..P), ``write_mask`` selecting the admitted slots) and
-    decode (S = 1, positions = current length per slot). The layer loop
-    is the same ``lax.scan`` shape as the training forward — the cache
-    rides the scan as per-layer xs/ys — so compile time stays O(1) in
-    depth. With ``kv_io`` the cache pair is the adapter's layout instead
-    (the paged pool's [L, n_pages, Hkv, page_size, D]); the scan slices
-    its leading layer axis the same way.
+    buffers (inference/kv_cache.py builds and shards them). One trace
+    serves both engine steps: prefill (S = P, positions [0..P),
+    ``write_mask`` selecting the admitted slots) and decode (S = 1,
+    positions = current length per slot). The layer loop is
+    ``scan_layers_cached``: the same ``lax.scan`` over the stacked
+    parameters as the training forward, so compile time stays O(1) in
+    depth, with the cache pair carried whole beside the hidden state and
+    each layer writing and reading its own index of it. With ``kv_io``
+    the cache pair is the adapter's layout instead (the paged pool's
+    [L, n_pages, Hkv, page_size, D]), carried the same way.
     """
-    cache_k, cache_v = cache
     x = embed(params, input_ids, cfg)
     cos, sin = get_cos_sin(
         input_ids.shape[1], cfg.actual_head_dim, cfg.rope_theta,
         positions=positions,
     )
 
-    def layer_body(h, xs):
-        layer, ck, cv = xs
+    def layer_fn(h, layer, index, ck, cv):
         h, ck, cv = attention_block_cached(
-            h, layer, ck, cv, cos, sin, positions, cfg,
+            h, layer, index, ck, cv, cos, sin, positions, cfg,
             write_mask=write_mask, kv_io=kv_io,
         )
-        h = _mlp_block(h, layer, cfg)
-        return h, (ck, cv)
+        return _mlp_block(h, layer, cfg), ck, cv, None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer_body, x, (params["layers"], cache_k, cache_v)
-    )
+    x, cache, _ = scan_layers_cached(layer_fn, x, cache, params["layers"])
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
     logits = x @ lm_head_weight(params, cfg)
-    return logits, (k_new, v_new)
+    return logits, cache
 
 
 class Llama:

@@ -685,9 +685,11 @@ def forward_cached(
     that DROP tokens in full-sequence routing (capacity < S·k/E worst
     case) can emit slightly different logits at decode than teacher
     forcing; with a dropless capacity_factor (>= E/top_k) prefill and
-    decode match the training forward exactly. Uniform-sparse layouts
-    only — interleaved dense/sparse configs have per-kind layer stacks
-    that do not align with one scanned cache.
+    decode match the training forward exactly. The layer loop is
+    ``llama.scan_layers_cached``: the cache pair carried whole, each
+    layer at its own index of it. Uniform-sparse layouts only —
+    interleaved dense/sparse configs have per-kind layer stacks that do
+    not align with one cache indexed by layer.
     """
     if not cfg.is_uniform_sparse:
         raise NotImplementedError(
@@ -696,7 +698,6 @@ def forward_cached(
             "(mlp_only_layers/decoder_sparse_step) — serve it with the "
             "dense Qwen3 family or extend the cache to per-kind stacks"
         )
-    cache_k, cache_v = cache
     x = _llama.embed(params, input_ids, cfg)
     cos, sin = get_cos_sin(
         input_ids.shape[1], cfg.actual_head_dim, cfg.rope_theta,
@@ -711,10 +712,9 @@ def forward_cached(
         stacked = {k: layers[k] for k in _EXPERT_KEYS}
         layers = {k: v for k, v in layers.items() if k not in _EXPERT_KEYS}
 
-    def layer_body(h, xs):
-        layer, index, ck, cv = xs
+    def layer_fn(h, layer, index, ck, cv):
         h, ck, cv = _llama.attention_block_cached(
-            h, layer, ck, cv, cos, sin, positions, cfg,
+            h, layer, index, ck, cv, cos, sin, positions, cfg,
             write_mask=write_mask, kv_io=kv_io,
         )
         h, _aux, _stats, routing = moe_block_with_load(
@@ -724,18 +724,14 @@ def forward_cached(
         counts = {"routed": jnp.sum(rows), "dropped": routing["dropped"],
                   "expert_visits": jnp.sum(rows > 0, dtype=jnp.int32),
                   "peak_load_rows": jnp.max(rows)}
-        return h, (ck, cv, counts)
+        return h, ck, cv, counts
 
-    x, (k_new, v_new, counts) = jax.lax.scan(
-        layer_body, x,
-        (layers, jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32),
-         cache_k, cache_v)
-    )
+    x, cache, counts = _llama.scan_layers_cached(layer_fn, x, cache, layers)
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
     logits = x @ _llama.lm_head_weight(params, cfg)
     if return_routing:
-        return logits, (k_new, v_new), jax.tree.map(jnp.sum, counts)
-    return logits, (k_new, v_new)
+        return logits, cache, jax.tree.map(jnp.sum, counts)
+    return logits, cache
 
 
 def qwen3_moe_param_specs(

@@ -1,21 +1,35 @@
-"""Pallas TPU paged-decode attention + the paged-cache KV primitives.
+"""Pallas TPU kernels for the paged KV cache + the lax pair they replace.
 
 The paged KV cache (inference/kv_cache.py ``PagedKVCache``) keeps one
-global pool of fixed-size pages ``[n_pages, Hkv, page_size, D]`` per
-layer; each decode slot owns a per-slot *page table* ``[max_pages]``
-int32 mapping logical page ``t // page_size`` to a physical pool page.
-Attention reads therefore become gathers over the page table. Two
-implementations live here:
+global pool of fixed-size pages per K and V, all layers in one array
+``[L, n_pages, Hkv, page_size, D]``; each decode slot owns a per-slot
+*page table* ``[max_pages]`` int32 mapping logical page
+``t // page_size`` to a physical pool page. The models' cache-aware
+forwards carry the pool WHOLE through their layer loop
+(``llama.scan_layers_cached``) and touch it at a layer index through
+one of two pairs of a write and a read:
 
-  * **Pallas decode kernel** (``pallas_paged_decode_attention``): one
-    query token per slot against its paged cache. The grid is ``(B,)``,
-    one step per slot; the pools stay in HBM (``memory_space=pl.ANY``)
-    and the page table + positions ride the TPU scalar-prefetch path
-    (``pltpu.PrefetchScalarGridSpec``). A step walks the slot's *live*
-    pages only, a block of ``_pages_per_block`` at a time: one async
-    copy per page brings ``[Hkv, page_size, D]`` (all KV heads of a
-    page, contiguous in the pool) into a double-buffered VMEM landing
-    zone ``[2, Hkv, block, D]`` while the block before it is reduced
+  * **The Mosaic pair** (``pallas_paged_write`` +
+    ``pallas_paged_decode_attention``). The pool is never an operand
+    XLA may slice, re-lay or re-stack: both calls take it in HBM
+    (``memory_space=pl.ANY``) with the layer, the pages and the
+    positions on the TPU scalar-prefetch path
+    (``pltpu.PrefetchScalarGridSpec``).
+    The write aliases the pool to its only result and moves nothing
+    but the pages it writes: at decode (one row a slot) a chunk of
+    slots' pages come to VMEM, take their row and go back; at prefill
+    (a run of rows from a page boundary) whole pages go from the
+    blocked operand straight to their place. It serves both step
+    programs, because a pool that XLA writes anywhere is given the
+    layout XLA's scatter likes and converted back, whole, for every
+    Mosaic read (PERF.md, PR 28).
+    The decode kernel reads one query token per slot against its paged
+    cache. The grid is ``(B,)``, one step per slot. A step walks the
+    slot's *live* pages only, a block of ``_pages_per_block`` at a
+    time: one async copy per page brings ``pool.at[layer, page]``,
+    ``[Hkv, page_size, D]`` (all KV heads of a page, contiguous in the
+    pool), into a double-buffered VMEM landing zone
+    ``[2, Hkv, block, D]`` while the block before it is reduced
     flash-style, so the dense ``[B, Hkv, S_max, D]`` view is never
     materialised in HBM and a page past the slot's length costs
     nothing — no grid step, no DMA (the last block's dead pages
@@ -25,29 +39,34 @@ implementations live here:
     ``_pages_per_block`` follows from shapes alone: enough pages for a
     128-lane score tile (8 at page 16), under a fixed VMEM budget.
     Mosaic can slice an HBM ref only along whole 128-lane tiles, so the
-    kernel serves a head_dim that is a multiple of 128
-    (``kernel_serves``); narrower heads take the fallback. What it
-    takes on the chip, and what the one-page-of-one-head grid it
+    pair serves a head_dim that is a multiple of 128
+    (``kernel_serves``); narrower heads take the lax pair. What the
+    kernel takes on the chip, and what the one-page-of-one-head grid it
     replaced took, is in PERF.md (PR 25).
-  * **Pure-lax fallback** (``paged_gather_kv`` + the models' shared
-    ``cached_sdpa_attention``): a whole-table gather that reconstructs
-    the dense cache view. This is the off-TPU path and the reference
-    the kernel is compared against (tests in interpret mode,
-    ``chip_smoke.py`` on the chip) — it performs the same reduction
-    the dense engine's attention performs.
+  * **The lax pair** (``paged_write_kv`` + ``paged_gather_kv`` and the
+    models' shared ``cached_sdpa_attention``): a batched scatter at
+    ``pool.at[layer, pages, :, offsets, :]`` and a whole-table gather
+    ``pool[layer, page_tables]`` that reconstructs the dense cache
+    view. This is the off-TPU path and the reference the kernels are
+    compared against (tests in interpret mode, ``chip_smoke.py`` on
+    the chip) — it performs the same reduction the dense engine's
+    attention performs. Prefill (S > 1) reads through the gather on
+    every platform.
 
-``paged_attention`` dispatches between them: the kernel serves
-single-token decode when the platform is ``tpu`` and the head_dim fills
-the lanes (toggle: ``SCALETORCH_TPU_PAGED_KERNEL``); prefill (S > 1),
-narrow heads and other platforms take the gather fallback.
+``paged_write`` and ``paged_attention`` dispatch between them on ONE
+predicate, ``in_place_pair``: the Mosaic pair when the platform is
+``tpu`` and the head_dim fills the lanes (toggle:
+``SCALETORCH_TPU_PAGED_KERNEL``); the lax pair for narrow heads and on
+other platforms. The engine's snapshot says which
+(``paged_pool_in_place``).
 
-Writes (``paged_write_kv``) are a batched scatter: token at absolute
-position ``t`` lands at ``(table[b, t // page_size], t % page_size)``.
-Masked-off slots and positions beyond the table are redirected to the
-reserved TRASH page (page 0 — never allocated, read only through masked
-attention lanes), which keeps the write unconditional — data changes,
-shapes never do, so the engine's one-compile discipline survives
-admissions, prefix hits, and frees.
+A write puts the token at absolute position ``t`` at
+``(table[b, t // page_size], t % page_size)``. Masked-off slots and
+positions beyond the table are redirected to the reserved TRASH page
+(page 0 — never allocated, read only through masked attention lanes),
+which keeps the write unconditional — data changes, shapes never do, so
+the engine's one-compile discipline survives admissions, prefix hits,
+and frees.
 """
 
 from __future__ import annotations
@@ -71,18 +90,35 @@ _NEG_INF = -1e30  # large-negative, not -inf: keeps masked rows NaN-free
 
 
 # ---------------------------------------------------------------------------
-# paged cache primitives (pure lax — shared by fallback and engine steps)
+# paged cache primitives (pure lax — the fallback pair and its oracle)
 # ---------------------------------------------------------------------------
-def paged_gather_kv(pool: jax.Array, page_tables: jax.Array) -> jax.Array:
+def paged_gather_kv(pool: jax.Array, page_tables: jax.Array,
+                    layer: Optional[jax.Array] = None) -> jax.Array:
     """Reconstruct the dense cache view from the page pool.
 
-    pool: [n_pages, Hkv, page_size, D]; page_tables: [B, max_pages]
+    pool: [n_pages, Hkv, page_size, D], or the whole [L, n_pages, Hkv,
+    page_size, D] pool with the ``layer`` to read (one gather of whole
+    pages, the layer never sliced out); page_tables: [B, max_pages]
     -> [B, Hkv, max_pages * page_size, D], logical position ``t`` of slot
     ``b`` at sequence index ``t`` exactly as the dense layout stores it.
     """
-    view = pool[page_tables]  # [B, max_pages, Hkv, page_size, D]
-    b, mp, h, p, d = view.shape
+    view = pool[page_tables] if layer is None else pool[layer, page_tables]
+    b, mp, h, p, d = view.shape  # [B, max_pages, Hkv, page_size, D]
     return view.transpose(0, 2, 1, 3, 4).reshape(b, h, mp * p, d)
+
+
+def _write_targets(positions, page_tables, page_size, write_mask):
+    """(page, offset) each of ``positions`` [B, S] lands on: the slot's
+    own page through its table, TRASH for a masked-off slot or a position
+    past the table's reach."""
+    max_pages = page_tables.shape[1]
+    logical = positions // page_size
+    valid = logical < max_pages
+    pages = jnp.take_along_axis(
+        page_tables, jnp.minimum(logical, max_pages - 1), axis=1)
+    if write_mask is not None:
+        valid = valid & write_mask[:, None]
+    return jnp.where(valid, pages, TRASH_PAGE), positions % page_size
 
 
 def paged_write_kv(
@@ -92,43 +128,221 @@ def paged_write_kv(
     page_tables: jax.Array,
     page_size: int,
     write_mask: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Scatter ``new`` [B, H, S, D] into ``pool`` [n_pages, H, page_size,
-    D] at per-token absolute ``positions`` [B, S] through ``page_tables``
-    [B, max_pages]. ``write_mask`` [B] bool redirects unlisted slots'
-    writes to the TRASH page (their own pages stay byte-identical —
-    continuous batching admits new requests without perturbing live
-    ones); positions past the table's reach go to TRASH too.
+    D] (or into ``layer`` of the whole [L, n_pages, H, page_size, D]
+    pool) at per-token absolute ``positions`` [B, S] through
+    ``page_tables`` [B, max_pages]. ``write_mask`` [B] bool redirects
+    unlisted slots' writes to the TRASH page (their own pages stay
+    byte-identical — continuous batching admits new requests without
+    perturbing live ones); positions past the table's reach go to TRASH
+    too.
     """
-    max_pages = page_tables.shape[1]
-    logical = positions // page_size                       # [B, S]
-    offsets = positions % page_size
-    valid = logical < max_pages
-    pages = jnp.take_along_axis(
-        page_tables, jnp.minimum(logical, max_pages - 1), axis=1)
-    if write_mask is not None:
-        valid = valid & write_mask[:, None]
-    pages = jnp.where(valid, pages, TRASH_PAGE)
+    pages, offsets = _write_targets(
+        positions, page_tables, page_size, write_mask)
     vals = new.astype(pool.dtype).transpose(0, 2, 1, 3)    # [B, S, H, D]
-    return pool.at[pages, :, offsets, :].set(vals)
+    if layer is None:
+        return pool.at[pages, :, offsets, :].set(vals)
+    return pool.at[layer, pages, :, offsets, :].set(vals)
+
+
+# ---------------------------------------------------------------------------
+# the page-write kernel
+# ---------------------------------------------------------------------------
+# VMEM a kernel may spend on pages in flight (the write's landing zone,
+# the decode kernel's double-buffered K/V blocks). A fraction of the
+# 16 MiB scoped default, so the blocked operands, the score tiles and
+# Mosaic's own temporaries always fit beside it.
+_KV_VMEM_BUDGET = 1 << 20
+_LANES = 128
+
+
+def kernel_serves(head_dim: int) -> bool:
+    """Whether Mosaic can compile the kernels for this head_dim: an HBM
+    ref is padded to whole 128-lane tiles and may only be sliced along
+    them, so a page of a narrower pool cannot be copied on its own."""
+    return head_dim % _LANES == 0
+
+
+def in_place_pair(head_dim: int) -> bool:
+    """Which pair touches the pool: the Mosaic pair (``paged_write`` +
+    the decode kernel at a layer index) when the platform is ``tpu`` (the
+    repo's one kernel-vs-XLA predicate; ``SCALETORCH_TPU_PAGED_KERNEL``
+    gates it) and ``kernel_serves`` the head_dim, the lax pair (scatter
+    + gather) everywhere else. One predicate for the write and the read:
+    a pool that XLA writes and Mosaic reads is re-laid whole between the
+    two, every layer (PERF.md, PR 28)."""
+    from scaletorch_tpu.env import get_env
+    from scaletorch_tpu.ops.flash_attention import _pallas_available
+
+    return (kernel_serves(head_dim) and _pallas_available()
+            and bool(get_env("SCALETORCH_TPU_PAGED_KERNEL")))
+
+
+def _slots_per_step(n_slots: int, page_bytes: int) -> int:
+    """Slots whose pages one step of the row write holds in VMEM at once:
+    the largest divisor of ``n_slots`` that fits the budget."""
+    cap = max(1, min(n_slots, _KV_VMEM_BUDGET // page_bytes))
+    return max(c for c in range(1, cap + 1) if n_slots % c == 0)
+
+
+def _row_in_page(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _paged_write_row_kernel(pages_ref, offs_ref, layer_ref, new_ref, _pool,
+                            pool_ref, buf, sems, *, chunk):
+    """One token per slot (decode): bring each slot's page to VMEM, put
+    its row in, send the page back; a chunk of slots in flight at once.
+    Masked slots all name TRASH and race on it: garbage by contract."""
+    first = pl.program_id(0) * chunk
+    layer = layer_ref[0]
+
+    def page_copy(i, back):
+        hbm = pool_ref.at[layer, pages_ref[first + i]]
+        src, dst = (buf.at[i], hbm) if back else (hbm, buf.at[i])
+        return pltpu.make_async_copy(src, dst, sems.at[int(back)])
+
+    for i in range(chunk):
+        page_copy(i, False).start()
+    for i in range(chunk):   # one semaphore: all in before any is touched
+        page_copy(i, False).wait()
+    row = _row_in_page(buf.shape[1:])
+    for i in range(chunk):
+        buf[i] = jnp.where(row == offs_ref[first + i], new_ref[i], buf[i])
+        page_copy(i, True).start()
+    for i in range(chunk):
+        page_copy(i, True).wait()
+
+
+def _paged_write_pages_kernel(pages_ref, layer_ref, new_ref, _pool, pool_ref,
+                              buf, sems, *, page_size, pages_per_block,
+                              n_rows):
+    """A run of rows per slot that starts on a page boundary (prefill):
+    every whole page of this block of rows goes from the blocked operand
+    straight to its place; the run's last, partly filled page is read,
+    merged and written back."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
+    n_whole, rest = divmod(n_rows, page_size)
+    slot_pages = n_whole + bool(rest)
+
+    def rows_and_page(p):
+        """Page ``p`` of this block: its rows of the operand, its place."""
+        g = jnp.minimum(j * pages_per_block + p, slot_pages - 1)
+        return (new_ref.at[0, :, pl.ds(p * page_size, page_size), :],
+                pool_ref.at[layer, pages_ref[b * slot_pages + g]])
+
+    whole = [(j * pages_per_block + p < n_whole,
+              pltpu.make_async_copy(*rows_and_page(p), sems.at[0]))
+             for p in range(pages_per_block)]
+    for live, copy in whole:
+        pl.when(live)(copy.start)
+    if rest:
+        # the partly filled page: a fixed page of one block of the run
+        rows, hbm = rows_and_page(n_whole % pages_per_block)
+
+        @pl.when(j == n_whole // pages_per_block)
+        def _merge():
+            fetch = pltpu.make_async_copy(hbm, buf, sems.at[1])
+            fetch.start()
+            fetch.wait()
+            buf[...] = jnp.where(
+                _row_in_page(buf.shape) < rest, rows[...], buf[...])
+            store = pltpu.make_async_copy(buf, hbm, sems.at[1])
+            store.start()
+            store.wait()
+    for live, copy in whole:
+        pl.when(live)(copy.wait)
+
+
+def pallas_paged_write(
+    pool: jax.Array,
+    new: jax.Array,
+    positions: jax.Array,
+    page_tables: jax.Array,
+    write_mask: Optional[jax.Array] = None,
+    *,
+    layer: jax.Array,
+    interpret: bool = False,
+) -> jax.Array:
+    """``paged_write_kv`` into ``layer`` of the whole pool, in place.
+
+    pool: [L, n_pages, Hkv, page_size, D], aliased to the result and left
+    in HBM (``pl.ANY``): nothing but the pages written moves, and XLA
+    never sees an operation on the pool whose layout it could choose.
+    new: [B, Hkv, S, D]; positions: [B, S], contiguous per slot. S = 1
+    (decode) writes one row of one page per slot at any offset; S > 1
+    (prefill) needs each slot's first position on a page boundary, which
+    the engine's page-aligned ``starts`` give. Pages and offsets are
+    computed as ``paged_write_kv`` computes them and scalar-prefetched
+    with the layer. Every page but TRASH ends bit-identical to the
+    scatter's; TRASH holds some writer's rows.
+    """
+    _, _, hkv, page_size, d = pool.shape
+    b, _, s, _ = new.shape
+    if not interpret and not kernel_serves(d):
+        raise ValueError(
+            f"the page write copies whole pages in and out of HBM, which "
+            f"Mosaic allows only for a head_dim that fills the {_LANES} "
+            f"lanes; got {d} (paged_write() sends it to the scatter)")
+    page_bytes = hkv * page_size * d * jnp.dtype(pool.dtype).itemsize
+    new = new.astype(pool.dtype)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    if s == 1:
+        pages, offsets = _write_targets(
+            positions, page_tables, page_size, write_mask)
+        chunk = _slots_per_step(b, page_bytes)
+        scalars = (pages.reshape(-1), offsets.reshape(-1), layer)
+        kernel = functools.partial(_paged_write_row_kernel, chunk=chunk)
+        grid = (b // chunk,)
+        new_spec = pl.BlockSpec(
+            (chunk, hkv, 1, d), lambda c, *_: (c, 0, 0, 0))
+        scratch = [pltpu.VMEM((chunk, hkv, page_size, d), pool.dtype)]
+    else:
+        pages, _ = _write_targets(
+            positions[:, ::page_size], page_tables, page_size, write_mask)
+        ppb = max(1, min(_KV_VMEM_BUDGET // (2 * page_bytes),
+                         pages.shape[1]))
+        scalars = (pages.reshape(-1), layer)
+        kernel = functools.partial(
+            _paged_write_pages_kernel, page_size=page_size,
+            pages_per_block=ppb, n_rows=s)
+        grid = (b, -(-pages.shape[1] // ppb))
+        new_spec = pl.BlockSpec(
+            (1, hkv, ppb * page_size, d), lambda b_, j, *_: (b_, 0, j, 0))
+        scratch = [pltpu.VMEM((hkv, page_size, d), pool.dtype)]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
+            in_specs=[new_spec, any_space],
+            out_specs=any_space,
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={len(scalars) + 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            # sequential: masked slots share TRASH
+            dimension_semantics=(pltpu.ARBITRARY,) * len(grid)),
+        interpret=interpret,
+        name="paged_write",
+    )(*scalars, new, pool)
 
 
 # ---------------------------------------------------------------------------
 # the decode kernel
 # ---------------------------------------------------------------------------
-# VMEM the kernel may spend on its K/V landing buffers (2 buffers x 2
-# pools x one block of pages). A fraction of the 16 MiB scoped default,
-# so the score tiles and Mosaic's own temporaries always fit beside it.
-_KV_VMEM_BUDGET = 1 << 20
-_LANES = 128
-
-
 def _pages_per_block(page_size: int, hkv: int, d: int, dtype,
                      max_pages: int) -> int:
     """Pages one compute block covers: enough for a score tile whose
     last dimension fills the 128 lanes (8 pages at page 16), capped by
-    the VMEM budget of the double-buffered landing zone and by the
-    table's length. Shapes in, one integer out — nothing to configure.
+    the VMEM budget of the double-buffered landing zone (2 buffers x 2
+    pools x one block of pages) and by the table's length. Shapes in,
+    one integer out — nothing to configure.
     """
     page_bytes = hkv * page_size * d * jnp.dtype(dtype).itemsize
     fill_lanes = -(-_LANES // page_size)
@@ -136,17 +350,11 @@ def _pages_per_block(page_size: int, hkv: int, d: int, dtype,
     return max(1, min(fill_lanes, fit_budget, max_pages))
 
 
-def kernel_serves(head_dim: int) -> bool:
-    """Whether Mosaic can compile the kernel for this head_dim: an HBM
-    ref is padded to whole 128-lane tiles and may only be sliced along
-    them, so a page of a narrower pool cannot be copied on its own."""
-    return head_dim % _LANES == 0
-
-
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, *, scale, page_size,
+def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, *, scale, page_size,
                          pages_per_block, max_pages):
     b = pl.program_id(0)   # slot
+    layer = layer_ref[0]
     bk = pages_per_block * page_size
     pos = pos_ref[b]
     # live pages of this slot (0 for a negative position, never past the
@@ -166,9 +374,11 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             page = pt_ref[b * max_pages + j]
             rows = pl.ds(p * page_size, page_size)
             out.append(pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[buf, :, rows, :], sems.at[0, buf]))
+                k_hbm.at[layer, page], k_buf.at[buf, :, rows, :],
+                sems.at[0, buf]))
             out.append(pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[buf, :, rows, :], sems.at[1, buf]))
+                v_hbm.at[layer, page], v_buf.at[buf, :, rows, :],
+                sems.at[1, buf]))
         return out
 
     @pl.when(n_blocks > 0)
@@ -225,14 +435,18 @@ def pallas_paged_decode_attention(
     page_tables: jax.Array,
     positions: jax.Array,
     *,
+    layer: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """One-token paged attention: q [B, Hq, D] against the page pool.
 
-    pool_k/pool_v: [n_pages, Hkv, page_size, D]; page_tables:
-    [B, max_pages] int32; positions: [B] int32 absolute position of the
-    query token (attends keys j <= position). Returns [B, Hq, D].
+    pool_k/pool_v: the whole [L, n_pages, Hkv, page_size, D] pools with
+    the ``layer`` to read (a third scalar-prefetch operand: the kernel
+    copies ``pool.at[layer, page]``, nothing slices a layer out), or one
+    layer's [n_pages, Hkv, page_size, D]; page_tables: [B, max_pages]
+    int32; positions: [B] int32 absolute position of the query token
+    (attends keys j <= position). Returns [B, Hq, D].
 
     The pools stay in HBM; the page table and positions are
     scalar-prefetched, and each slot's step copies its live pages, a
@@ -240,8 +454,10 @@ def pallas_paged_decode_attention(
     at once, into a double-buffered VMEM landing zone while the block
     before it is reduced flash-style.
     """
+    if layer is None:   # one layer's pool is a pool of one layer
+        pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
     b, hq, d = q.shape
-    n_pages, hkv, page_size, _ = pool_k.shape
+    _, n_pages, hkv, page_size, _ = pool_k.shape
     if hq % hkv:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     n_rep = hq // hkv
@@ -256,11 +472,11 @@ def pallas_paged_decode_attention(
         scale = 1.0 / math.sqrt(d)
     ppb = _pages_per_block(page_size, hkv, d, pool_k.dtype, max_pages)
 
-    def q_idx(b_, pt_ref, pos_ref):
+    def q_idx(b_, *_):
         return (b_, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, hkv, n_rep, d), q_idx),
@@ -285,14 +501,39 @@ def pallas_paged_decode_attention(
         interpret=interpret,
         name="paged_decode",
     )(page_tables.astype(jnp.int32).reshape(-1),
-      positions.astype(jnp.int32), q.reshape(b, hkv, n_rep, d),
-      pool_k, pool_v)
+      positions.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(b, hkv, n_rep, d), pool_k, pool_v)
     return out.reshape(b, hq, d)
 
 
 # ---------------------------------------------------------------------------
-# dispatcher
+# dispatchers: one predicate picks the pair
 # ---------------------------------------------------------------------------
+def paged_write(
+    pool: jax.Array,
+    new: jax.Array,
+    positions: jax.Array,
+    page_tables: jax.Array,
+    write_mask: Optional[jax.Array] = None,
+    *,
+    layer: jax.Array,
+    kernel: Optional[bool] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Write ``new`` [B, Hkv, S, D] into ``layer`` of the whole pool
+    [L, n_pages, Hkv, page_size, D], kernel or scatter: the Mosaic page
+    write when ``in_place_pair`` says so (``kernel=None``), the lax
+    scatter at ``pool.at[layer, ...]`` elsewhere."""
+    if kernel is None:
+        kernel = in_place_pair(pool.shape[-1])
+    if kernel:
+        return pallas_paged_write(pool, new, positions, page_tables,
+                                  write_mask, layer=layer,
+                                  interpret=interpret)
+    return paged_write_kv(pool, new, positions, page_tables, pool.shape[-2],
+                          write_mask, layer=layer)
+
+
 def paged_attention(
     q: jax.Array,
     pool_k: jax.Array,
@@ -301,6 +542,7 @@ def paged_attention(
     q_positions: jax.Array,
     *,
     page_size: int,
+    layer: Optional[jax.Array] = None,
     seq_limit: Optional[int] = None,
     scale: Optional[float] = None,
     kernel: Optional[bool] = None,
@@ -309,29 +551,21 @@ def paged_attention(
     """Attention against the paged cache, kernel or fallback.
 
     q: [B, Hq, S, D] (S = tail length at prefill, 1 at decode);
-    q_positions: [B, S] absolute positions. ``kernel=None`` auto-selects:
-    the Pallas kernel for single-token decode when the platform is
-    ``tpu`` (the same predicate the flash backend uses;
-    ``SCALETORCH_TPU_PAGED_KERNEL`` gates it) and ``kernel_serves`` the
-    head_dim, the lax gather + ``cached_sdpa_attention`` everywhere
-    else — other platforms, narrow heads and prefill. ``seq_limit``
-    crops the gathered view to the engine's ``max_seq`` so the
-    fallback's reduction has the dense layout's operand shapes.
+    q_positions: [B, S] absolute positions; the pools whole with the
+    ``layer`` to read, or one layer's. ``kernel=None`` auto-selects: the
+    Pallas kernel for single-token decode when ``in_place_pair`` (the
+    platform is ``tpu`` and ``kernel_serves`` the head_dim), the lax
+    gather + ``cached_sdpa_attention`` everywhere else — other
+    platforms, narrow heads and prefill. ``seq_limit`` crops the
+    gathered view to the engine's ``max_seq`` so the fallback's
+    reduction has the dense layout's operand shapes.
     """
     from scaletorch_tpu.models.layers import cached_sdpa_attention
 
     s = q.shape[2]
     use_kernel = kernel
     if use_kernel is None:
-        from scaletorch_tpu.env import get_env
-        from scaletorch_tpu.ops.flash_attention import _pallas_available
-
-        use_kernel = (
-            s == 1
-            and kernel_serves(q.shape[3])
-            and _pallas_available()
-            and bool(get_env("SCALETORCH_TPU_PAGED_KERNEL"))
-        )
+        use_kernel = s == 1 and in_place_pair(q.shape[3])
     if use_kernel:
         if s != 1:
             raise ValueError(
@@ -340,11 +574,11 @@ def paged_attention(
             )
         out = pallas_paged_decode_attention(
             q[:, :, 0, :], pool_k, pool_v, page_tables, q_positions[:, 0],
-            scale=scale, interpret=interpret,
+            layer=layer, scale=scale, interpret=interpret,
         )
         return out[:, :, None, :]
-    k = paged_gather_kv(pool_k, page_tables)
-    v = paged_gather_kv(pool_v, page_tables)
+    k = paged_gather_kv(pool_k, page_tables, layer)
+    v = paged_gather_kv(pool_v, page_tables, layer)
     if seq_limit is not None and k.shape[2] > seq_limit:
         k = k[:, :, :seq_limit, :]
         v = v[:, :, :seq_limit, :]

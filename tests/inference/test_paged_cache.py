@@ -32,8 +32,10 @@ from scaletorch_tpu.ops.pallas.paged_attention import (
     _pages_per_block,
     paged_attention,
     paged_gather_kv,
+    paged_write,
     paged_write_kv,
     pallas_paged_decode_attention,
+    pallas_paged_write,
 )
 
 TINY = dict(
@@ -408,6 +410,108 @@ class TestPagedDecodeKernelBlocks:
         assert bool((out == 0).all())
 
 
+class TestPageWriteInPlace:
+    """``paged_write`` (the Mosaic page write, interpret mode) against
+    ``paged_write_kv`` into one layer of the whole pool, bit for bit."""
+
+    PS, MP, D = 4, 4, 8
+
+    def _case(self, rows, heads, starts, mask, layers, layer, seed=0):
+        rng = np.random.default_rng(seed)
+        slots = len(starts)
+        pool = jnp.asarray(rng.standard_normal(
+            (layers, slots * self.MP + 1, heads, self.PS, self.D)),
+            jnp.float32)
+        tables = jnp.asarray(rng.permutation(
+            np.arange(1, slots * self.MP + 1)).reshape(slots, self.MP),
+            jnp.int32)
+        new = jnp.asarray(rng.standard_normal(
+            (slots, heads, rows, self.D)), jnp.float32)
+        positions = jnp.asarray(
+            np.asarray(starts)[:, None] + np.arange(rows), jnp.int32)
+        mask = None if mask is None else jnp.asarray(mask)
+        return pool, new, positions, tables, mask, layer
+
+    @pytest.mark.parametrize("rows,heads,starts,mask,layers,layer,trash", [
+        # S = 1 (decode): any offset in the page
+        (1, 2, [0, 5, 11, 7], None, 1, 0, "same"),
+        (1, 4, [3, 14, 9], None, 3, 2, "same"),         # MHA-sized, layer 2
+        (1, 2, [0, 5, 11, 7], [True, False, True, True], 3, 1, "same"),
+        (1, 2, [0, 5, 11, 7], [True, False, True, False], 3, 1, "shared"),
+        (1, 2, [0, 5, 16, 40], None, 2, 1, "shared"),   # past the table
+        # S = several pages (prefill): page-aligned starts
+        (12, 2, [0, 4, 0], None, 1, 0, "same"),
+        (12, 4, [0, 4, 0], None, 3, 1, "same"),         # layer 1 of 3
+        (10, 2, [0, 4, 0], None, 2, 1, "same"),         # a partly filled page
+        (3, 2, [0, 8, 12], None, 2, 0, "same"),         # less than one page
+        (10, 2, [0, 4, 0], [True, False, True], 2, 1, "shared"),
+        (10, 2, [0, 4, 0], [False, False, True], 2, 1, "shared"),
+        (12, 2, [0, 8, 12], None, 2, 1, "shared"),      # runs off the table
+    ], ids=["row", "row-mha-layer2", "row-one-masked", "row-two-masked",
+            "row-past-table", "pages", "pages-mha-layer1", "pages-ragged",
+            "pages-short", "pages-one-masked", "pages-two-masked",
+            "pages-past-table"])
+    def test_bit_identical_to_the_scatter(self, rows, heads, starts, mask,
+                                          layers, layer, trash):
+        pool, new, positions, tables, mask, layer = self._case(
+            rows, heads, starts, mask, layers, layer)
+        want = np.asarray(paged_write_kv(
+            pool, new, positions, tables, self.PS, mask, layer=layer))
+        got = np.asarray(pallas_paged_write(
+            pool, new, positions, tables, mask, layer=layer, interpret=True))
+        # every page a slot owns, in every layer: the written layer equal
+        # to the scatter's, every other layer untouched
+        assert np.array_equal(got[:, 1:], want[:, 1:])
+        others = [i for i in range(layers) if i != layer]
+        assert np.array_equal(got[others], np.asarray(pool)[others])
+        if trash == "same":     # at most one row for TRASH: same bytes
+            assert np.array_equal(got[:, TRASH_PAGE], want[:, TRASH_PAGE])
+        else:   # TRASH holds some writer's rows: garbage by contract
+            assert np.isfinite(got[:, TRASH_PAGE]).all()
+            assert not np.array_equal(got[layer, TRASH_PAGE],
+                                      np.asarray(pool)[layer, TRASH_PAGE])
+
+    def test_dispatcher_takes_the_scatter_off_the_chip(self):
+        pool, new, positions, tables, mask, layer = self._case(
+            1, 2, [0, 5, 11, 7], [True, False, True, True], 3, 1)
+        want = paged_write_kv(pool, new, positions, tables, self.PS, mask,
+                              layer=layer)
+        got = paged_write(pool, new, positions, tables, mask, layer=layer)
+        assert jnp.array_equal(got, want)
+        forced = paged_write(pool, new, positions, tables, mask, layer=layer,
+                             kernel=True, interpret=True)
+        assert jnp.array_equal(forced[:, 1:], want[:, 1:])
+
+    @pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+    def test_kernel_reads_its_layer_of_the_whole_pool(self, hq, hkv):
+        """The 5-D decode kernel at a non-zero layer against the gather
+        fallback on that layer, and against the kernel on the layer
+        sliced out."""
+        rng = np.random.default_rng(3)
+        slots, layers, layer = 3, 3, 2
+        shape = (layers, slots * self.MP + 1, hkv, self.PS, self.D)
+        pool_k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        pool_v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        tables = jnp.asarray(rng.permutation(
+            np.arange(1, slots * self.MP + 1)).reshape(slots, self.MP),
+            jnp.int32)
+        q = jnp.asarray(rng.standard_normal((slots, hq, self.D)), jnp.float32)
+        pos = jnp.asarray([2, 15, 9], jnp.int32)
+        out = pallas_paged_decode_attention(
+            q, pool_k, pool_v, tables, pos, layer=layer, interpret=True)
+        fallback = paged_attention(
+            q[:, :, None], pool_k, pool_v, tables, pos[:, None],
+            page_size=self.PS, layer=layer, kernel=False)[:, :, 0]
+        np.testing.assert_allclose(np.asarray(out), np.asarray(fallback),
+                                   atol=2e-6)
+        sliced = pallas_paged_decode_attention(
+            q, pool_k[layer], pool_v[layer], tables, pos, interpret=True)
+        assert jnp.array_equal(out, sliced)
+        assert jnp.array_equal(
+            paged_gather_kv(pool_k, tables, layer),
+            paged_gather_kv(pool_k[layer], tables))
+
+
 # fp32 logits of the paged and the dense path: both sum the same terms
 # over the same operand shapes (seq_limit crop), but they are two
 # compiled programs (gather/scatter vs dynamic-update-slice) and XLA is
@@ -443,6 +547,95 @@ class TestTeacherForcedPagedParity:
     def test_qwen3(self):
         self._check(qwen3.Qwen3Config(**{**TINY, "head_dim": 16}),
                     qwen3.init_params, 4)
+
+
+class TestKernelPairThroughTheForwards:
+    """The carried layer loop with the Mosaic pair (interpret mode) in
+    place of the lax pair: prefill writes page by page, decode row by
+    row and reads through the kernel at the layer index."""
+
+    @pytest.mark.parametrize("family", ["llama", "qwen3_moe", "gpt_moe"])
+    def test_logits_match_the_lax_pair(self, family):
+        from scaletorch_tpu.inference.kv_cache import (
+            PagedKVIO,
+            init_paged_kv_cache,
+        )
+        from scaletorch_tpu.models import gpt_moe, qwen3_moe
+
+        if family == "llama":
+            cfg, mod = llama.LlamaConfig(**TINY), llama
+        elif family == "qwen3_moe":
+            cfg = qwen3_moe.Qwen3MoEConfig(
+                **{**TINY, "head_dim": 16}, num_experts=4,
+                num_experts_per_tok=2, moe_intermediate_size=32,
+                capacity_factor=2.0)
+            mod = qwen3_moe
+        else:
+            cfg = gpt_moe.GPTMoEConfig(
+                vocab_size=64, block_size=16, n_layer=2, n_head=2,
+                n_embd=32, use_moe=True, num_experts=2, top_k=1)
+            mod = gpt_moe
+        params = mod.init_params(jax.random.PRNGKey(0), cfg)
+        ids = jax.random.randint(jax.random.PRNGKey(1), (2, 9), 0,
+                                 cfg.vocab_size)
+        page, max_pages = 4, 4
+        tables = jnp.asarray(
+            (np.arange(2 * max_pages, dtype=np.int32) + 1).reshape(
+                2, max_pages))
+
+        def run(kernel):
+            io = PagedKVIO(tables, page, seq_limit=16, kernel=kernel,
+                           interpret=True)
+            pool = tuple(init_paged_kv_cache(
+                cfg, 2 * max_pages + 1, page, dtype=jnp.float32))
+            positions = jnp.broadcast_to(jnp.arange(6, dtype=jnp.int32),
+                                         (2, 6))
+            out, pool = mod.forward_cached(
+                params, ids[:, :6], cfg, pool, positions=positions,
+                kv_io=io)
+            chunks = [out]
+            for t in range(6, 9):
+                out, pool = mod.forward_cached(
+                    params, ids[:, t:t + 1], cfg, pool,
+                    positions=jnp.full((2, 1), t, jnp.int32), kv_io=io)
+                chunks.append(out)
+            return jnp.concatenate(chunks, axis=1), pool
+
+        lax_logits, lax_pool = run(False)
+        kernel_logits, kernel_pool = run(True)
+        np.testing.assert_allclose(
+            np.asarray(kernel_logits), np.asarray(lax_logits),
+            **PAGED_DENSE_LOGIT_TOL)
+        for got, want in zip(kernel_pool, lax_pool):   # the writes: copies
+            np.testing.assert_allclose(
+                np.asarray(got[:, 1:]), np.asarray(want[:, 1:]),
+                **PAGED_DENSE_LOGIT_TOL)
+
+
+class TestEngineSaysWhichPair:
+    """The snapshot's ``paged_pool_in_place`` gauge: the pair the two
+    paged step programs are built with, decided by the platform and the
+    head_dim alone (the AOT switch stands in for the platform here)."""
+
+    @pytest.mark.parametrize("tpu,head_dim,layout,want", [
+        (False, 128, "paged", 0),   # no chip: scatter + gather
+        (True, 128, "paged", 1),    # the Mosaic pair
+        (True, 16, "paged", 0),     # no kernel serves a narrow head
+        (True, 128, "dense", 0),    # no pool at all
+    ], ids=["cpu", "tpu-wide", "tpu-narrow", "tpu-dense"])
+    def test_gauge(self, monkeypatch, tpu, head_dim, layout, want):
+        from scaletorch_tpu.inference import InferenceEngine, SamplingParams
+
+        monkeypatch.setenv("SCALETORCH_TPU_FORCE_PALLAS", "1" if tpu else "0")
+        cfg = llama.LlamaConfig(**{**TINY, "num_hidden_layers": 1},
+                                head_dim=head_dim)
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        engine = InferenceEngine(
+            params, cfg, sampling=SamplingParams(temperature=0.0),
+            max_slots=2, max_seq=16,
+            **(dict(cache_layout="paged", page_size=4)
+               if layout == "paged" else {}))
+        assert engine.metrics.snapshot()["paged_pool_in_place"] == want
 
 
 class TestCacheBytesLayouts:

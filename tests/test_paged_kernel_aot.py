@@ -1,10 +1,16 @@
-"""The paged-decode kernel compiled for the v5e at real widths, with no
-chip attached: Mosaic refuses here what it would refuse there (a slice
-off the tiling, too much VMEM), which interpret mode cannot see. One
-file, one fixture, nothing at import time: only the worker that runs
-this file loads the TPU compiler. Quick tier, ~1 s a case.
+"""The paged kernels, and the two paged step programs that call them,
+compiled for the v5e at real widths with no chip attached: Mosaic
+refuses here what it would refuse there (a slice off the tiling, too
+much VMEM), and the compiled step shows what XLA made of the pool (a
+copy per layer, a re-laid stack: PR 27's expert stack, PR 28's page
+pool), neither of which interpret mode can see. One file, one fixture,
+nothing at import time: only the worker that runs this file loads the
+TPU compiler. Quick tier, ~1 s a kernel case, 2-20 s a step program.
 """
 
+import json
+import math
+import os
 import re
 
 import jax
@@ -14,7 +20,10 @@ from jax.sharding import SingleDeviceSharding
 
 from scaletorch_tpu.ops.pallas.paged_attention import (
     pallas_paged_decode_attention,
+    pallas_paged_write,
 )
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -25,6 +34,9 @@ def one_chip():
         env.setenv("TPU_SKIP_MDS_QUERY", "1")
         env.setenv("TPU_WORKER_HOSTNAMES", "localhost")
         env.setenv("TPU_LOG_DIR", "disabled")
+        # the target is a topology, so the platform test cannot see it:
+        # the step programs pick their pair as they would on the chip
+        env.setenv("SCALETORCH_TPU_FORCE_PALLAS", "1")
         try:
             topo = topologies.get_topology_desc(
                 platform="tpu", topology_name="v5e:2x2")
@@ -47,6 +59,11 @@ def _compiled_text(one_chip, b, hq, hkv, d, page, max_pages, dtype):
 def _mosaic_calls(text):
     return [line for line in text.splitlines()
             if "custom-call(" in line and "tpu_custom_call" in line]
+
+
+def _named(calls, name):
+    """The calls that ARE ``name`` (its result), not those that read it."""
+    return [c for c in calls if re.match(rf"\s*(ROOT )?%{name}\S* = ", c)]
 
 
 def test_serving_shape_is_one_call_the_benchmark_can_find(one_chip):
@@ -74,3 +91,183 @@ def test_kernel_compiles_for_the_layouts_the_models_use(
     calls = _mosaic_calls(_compiled_text(
         one_chip, 4, hq, hkv, 128, page, max_pages, dtype))
     assert len(calls) == 1, calls
+
+
+@pytest.mark.parametrize("layers,slots,hkv,rows,page,max_pages,dtype", [
+    (28, 16, 8, 1, 16, 96, jnp.bfloat16),      # qwen3-1.7b-serve, decode
+    (28, 16, 8, 1024, 16, 96, jnp.bfloat16),   # ... and its prefill
+    (8, 16, 16, 1, 16, 96, jnp.bfloat16),      # olmoe-1b-7b-serve (MHA)
+    (8, 16, 16, 1024, 16, 96, jnp.bfloat16),
+    (2, 3, 8, 1000, 16, 96, jnp.bfloat16),     # a partly filled last page
+    (2, 4, 1, 50, 16, 13, jnp.bfloat16),       # one KV head of a tp shard
+    (2, 4, 8, 100, 8, 13, jnp.bfloat16),       # a page of half a bf16 tile
+    (2, 4, 8, 1, 32, 13, jnp.bfloat16),
+    (2, 4, 8, 20, 8, 13, jnp.float32),         # fp32 pools
+], ids=["qwen-decode", "qwen-prefill", "olmoe-decode", "olmoe-prefill",
+        "ragged-rows", "hkv1", "page8", "page32", "fp32"])
+def test_page_write_compiles_and_aliases_the_pool(
+        one_chip, layers, slots, hkv, rows, page, max_pages, dtype):
+    """``paged_write``: one Mosaic call whose only result is the donated
+    pool itself, with nothing of the pool's size beside it."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = arg((layers, slots * max_pages + 1, hkv, page, 128), dtype)
+    compiled = jax.jit(
+        lambda pool, *a: pallas_paged_write(pool, *a[:-1], layer=a[-1]),
+        donate_argnums=0,
+    ).lower(
+        pool, arg((slots, hkv, rows, 128), dtype),
+        arg((slots, rows), jnp.int32), arg((slots, max_pages), jnp.int32),
+        arg((slots,), jnp.bool_), arg((), jnp.int32),
+    ).compile()
+    calls = _mosaic_calls(compiled.as_text())
+    assert len(calls) == 1 and _named(calls, "paged_write"), calls
+    memory = compiled.memory_analysis()
+    pool_bytes = math.prod(pool.shape) * jnp.dtype(dtype).itemsize
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 10
+
+
+# ---------------------------------------------------------------------------
+# the whole step programs
+# ---------------------------------------------------------------------------
+def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
+                   page_size):
+    """(decode, prefill) compiled from the engine's own step builders,
+    donated, on abstract arguments; and the pool's shape."""
+    from scaletorch_tpu.inference.decode import (
+        counts_routing,
+        make_paged_decode_step,
+        make_paged_prefill_step,
+    )
+    from scaletorch_tpu.inference.kv_cache import init_paged_kv_cache
+    from scaletorch_tpu.inference.routing_counters import ROUTING_COUNTERS
+    from scaletorch_tpu.inference.sampling import SamplingParams
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: arg(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), cfg)))
+    max_pages = -(-max_seq // page_size)
+    pool = on_chip(jax.eval_shape(lambda: init_paged_kv_cache(
+        cfg, slots * max_pages + 1, page_size, dtype=cfg.dtype)))
+    counted = counts_routing(cfg)
+    build = dict(page_size=page_size, seq_limit=max_seq, donate_cache=True,
+                 routing_counts=counted)
+    sampling = SamplingParams(temperature=0.0)
+    ints, tables = arg((slots,), jnp.int32), arg((slots, max_pages), jnp.int32)
+    tail = (pool, arg((slots, 2), jnp.uint32)) + (
+        (arg((len(ROUTING_COUNTERS),), jnp.uint32),) if counted else ())
+    decode = make_paged_decode_step(cfg, sampling, **build).lower(
+        params, ints, ints, arg((slots,), jnp.bool_), tables, *tail)
+    prefill = make_paged_prefill_step(cfg, sampling, **build).lower(
+        params, arg((slots, prefill_len), jnp.int32), ints, ints,
+        arg((slots,), jnp.bool_), tables, *tail)
+    return decode.compile(), prefill.compile(), pool.k.shape
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (?P<type>\(.*?\)|\S+) (?P<op>[\w-]+)\(")
+# what may carry the pool: the program's and the loop's parameters, the
+# tuples they travel in, the loop itself
+_PLUMBING = {"parameter", "tuple", "get-tuple-element", "while"}
+
+
+def _pool_shaped(text, pool_shape):
+    """{op: count} of the instructions, fused ones included, whose result
+    is the pool or one layer of it, Mosaic calls and plumbing apart."""
+    dims = ",".join(map(str, pool_shape[1:]))
+    shapes = [f"[{pool_shape[0]},{dims}]", f"[1,{dims}]", f"[{dims}]"]
+    found = {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or not any(s in m["type"] for s in shapes):
+            continue
+        if m["op"] in _PLUMBING or "tpu_custom_call" in line:
+            continue
+        found[m["op"]] = found.get(m["op"], 0) + 1
+    return found
+
+
+@pytest.fixture(scope="module", params=["qwen3-1.7b-serve",
+                                        "olmoe-1b-7b-serve"])
+def serving_programs(request, one_chip):
+    from benchmarks.lib.program import serving_model
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           request.param + ".json")) as f:
+        config = json.load(f)
+    serve = config["serve"]
+    cfg, init = serving_model(config, serve["dtype"])
+    return _serving_steps(
+        one_chip, cfg, init, slots=serve["max_slots"],
+        max_seq=serve["max_seq"], prefill_len=serve["prefill_len"],
+        page_size=serve["page_size"])
+
+
+def test_no_step_program_moves_the_pool(serving_programs):
+    """The guard that would have caught, with no chip, 22 GB of pool
+    copies a decode step (PR 28) and the expert stack's copy per layer
+    (PR 27): in the compiled step nothing but parameters, tuple
+    plumbing, the layer loop and Mosaic calls has a result of the pool's
+    shape or of one layer of it."""
+    decode, prefill, pool_shape = serving_programs
+    for name, program in (("decode", decode), ("prefill", prefill)):
+        assert _pool_shaped(program.as_text(), pool_shape) == {}, name
+        writes = _named(_mosaic_calls(program.as_text()), "paged_write")
+        assert len(writes) == 2, (name, writes)    # K and V, in the loop
+
+
+def test_decode_program_reserves_no_second_pool(serving_programs):
+    decode, _prefill, pool_shape = serving_programs
+    one_pool = 2 * math.prod(pool_shape)
+    assert decode.memory_analysis().temp_size_in_bytes < one_pool // 10
+    assert decode.memory_analysis().alias_size_in_bytes >= 2 * one_pool
+
+
+def test_decode_kernel_is_still_the_one_4d_call(serving_programs):
+    """`serve_paged_attn_roofline` finds the kernel by its 4-D bf16
+    result: the write's result is the 5-D pool, an expert matmul's 2-D."""
+    decode, prefill, _ = serving_programs
+    four_d = re.compile(r"= bf16\[\d+,\d+,\d+,\d+\]\S* custom-call")
+    calls = [c for c in _mosaic_calls(decode.as_text()) if four_d.search(c)]
+    assert len(calls) == 1 and _named(calls, "paged_decode"), calls
+    assert not [c for c in _mosaic_calls(prefill.as_text())
+                if four_d.search(c)]
+
+
+def test_narrow_heads_take_the_lax_pair_in_the_same_loop(one_chip):
+    """head_dim 64: no kernel serves it, so the carried loop scatters
+    and gathers. What a compile found (PERF.md, PR 28): no layer is
+    sliced out of the pool or stacked back (the parent did both, eight
+    layer-sized operations a layer), the scatter updates the carried
+    pool in place, and what is left are four whole-pool copies at the
+    program's edge, because the device keeps a 64-wide pool pages-minor
+    and the scatter wants rows: the pool pair once more in the scatter's
+    padded layout, 2.0 times its bytes of temp (the parent: 1.4)."""
+    from scaletorch_tpu.models.llama import LlamaConfig, init_params
+
+    cfg = LlamaConfig(
+        vocab_size=32000, hidden_size=1024, intermediate_size=2816,
+        num_hidden_layers=16, num_attention_heads=16, num_key_value_heads=8,
+        head_dim=64, max_position_embeddings=4096, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    decode, prefill, pool_shape = _serving_steps(
+        one_chip, cfg, init_params, slots=16, max_seq=1536,
+        prefill_len=1024, page_size=16)
+    pool_pair = 2 * 2 * math.prod(pool_shape)
+    for program in (decode, prefill):
+        text = program.as_text()
+        assert not _mosaic_calls(text)
+        found = _pool_shaped(text, pool_shape)
+        assert set(found) <= {"copy", "fusion", "scatter", "bitcast"}, found
+        assert found.get("copy", 0) <= 4, found
+        layer = ",".join(map(str, pool_shape[1:]))
+        assert not re.search(
+            rf"= bf16\[(1,)?{layer}\]\S* (?!parameter)", text)
+    assert decode.memory_analysis().temp_size_in_bytes < 2.1 * pool_pair
