@@ -161,7 +161,7 @@ def serve_argv(replicas: int, dry_run: bool) -> list:
               "--prefill_len", "64"] if dry_run else
              ["--preset", "qwen3-0.6b", "--max_slots", "8",
               "--max_seq", "2048", "--prefill_len", "512"])
-    return shape + ["--param_seed", "0", "--cache_layout", "paged",
+    return shape + ["--param_seed", "0",
                     "--page_size", "16", "--serve_port", "0",
                     "--serve_replicas", str(replicas)]
 

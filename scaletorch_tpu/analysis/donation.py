@@ -9,9 +9,9 @@ caches are exactly this hazard.
 ST401  a name passed in a donated position of a jitted call is read
        again later in the same scope without being reassigned first
 
-The resolver follows the factory idiom (``step = make_decode_step(…)``)
-across modules, so donated positions declared in ``decode.py`` protect
-call sites in ``engine.py``.
+The resolver follows the factory idiom
+(``step = make_paged_decode_step(…)``) across modules, so donated
+positions declared in ``decode.py`` protect call sites in ``engine.py``.
 """
 
 from __future__ import annotations

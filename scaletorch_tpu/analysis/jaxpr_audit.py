@@ -29,8 +29,8 @@ ST704  a single collective result exceeds the entry's replication cap
 
 Each entry point's builder lives NEXT TO the entry point it audits
 (``parallel/spmd.audit_entry``, ``trainer/train_step.audit_entry``,
-``inference/decode.audit_entry_prefill``/``_decode``/
-``_paged_decode``) and returns a
+``inference/decode.audit_entry_paged_prefill``/``_paged_decode``)
+and returns a
 plain dict — the runtime modules never import the analyzer. This module
 imports jax and is only pulled in by the ``--tier deep`` CLI path and
 its tests; the pure-AST tier stays jax-free.
@@ -52,10 +52,8 @@ MANIFEST: Tuple[Tuple[str, str, str], ...] = (
     ("spmd_train_step", "scaletorch_tpu.parallel.spmd", "audit_entry"),
     ("declarative_train_step", "scaletorch_tpu.trainer.train_step",
      "audit_entry"),
-    ("prefill_step", "scaletorch_tpu.inference.decode",
-     "audit_entry_prefill"),
-    ("decode_step", "scaletorch_tpu.inference.decode",
-     "audit_entry_decode"),
+    ("paged_prefill_step", "scaletorch_tpu.inference.decode",
+     "audit_entry_paged_prefill"),
     ("paged_decode_step", "scaletorch_tpu.inference.decode",
      "audit_entry_paged_decode"),
     ("disagg_prefill_slice", "scaletorch_tpu.inference.disagg",

@@ -24,7 +24,7 @@ ST1004  remat violation: a configured checkpoint policy whose scan-body
         residuals still survive to the backward at full-activation
         scale
 ST1005  pool-sizing mismatch: the engine's ``kv_cache_bytes`` for the
-        audited layout disagrees with the compiled cache/pool buffer
+        audited page pool disagrees with the compiled pool buffer
         bytes — admission math and XLA must share one source of truth
 
 The XLA numbers are exact compiled facts (buffer assignment, donation
@@ -416,10 +416,7 @@ def _check_pool_sizing(entry: dict) -> List[Finding]:
     from scaletorch_tpu.inference.kv_cache import cache_nbytes, kv_cache_bytes
 
     expected = kv_cache_bytes(
-        kc["cfg"], kc["batch"], kc["max_seq"], kc.get("dtype"),
-        layout=kc.get("layout", "dense"), page_size=kc.get("page_size"),
-        num_pages=kc.get("num_pages"),
-    )
+        kc["cfg"], kc["num_pages"], kc["page_size"], kc.get("dtype"))
     actual = cache_nbytes(entry["args"][kc["arg_index"]])
     if actual == expected:
         return []
@@ -427,11 +424,10 @@ def _check_pool_sizing(entry: dict) -> List[Finding]:
         file=entry["file"], line=1, code="ST1005", severity="error",
         message=(
             f"entry {entry['name']!r}: engine kv_cache_bytes sizes the "
-            f"{kc.get('layout', 'dense')} cache at {expected} bytes but "
-            f"the compiled entry's cache/pool buffers are {actual} bytes "
-            "— admission math and the compiled program have drifted "
-            "apart (bench_decode's HBM column and page-budget shedding "
-            "are computed from the former, XLA allocates the latter)"
+            f"page pool at {expected} bytes but the compiled entry's "
+            f"pool buffers are {actual} bytes — admission math and the "
+            "compiled program have drifted apart (page-budget shedding "
+            "is computed from the former, XLA allocates the latter)"
         ),
     )]
 

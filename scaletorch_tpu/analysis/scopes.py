@@ -12,15 +12,15 @@ Two questions every pass keeps asking are answered here, once:
    resolves this across the whole analyzed file set, including the
    factory idiom this codebase uses everywhere::
 
-       def make_decode_step(...):
+       def make_paged_decode_step(...):
            def decode(params, tokens, ...):
                ...
-           return jax.jit(decode, donate_argnums=(4,))
+           return jax.jit(decode, donate_argnums=(5,))
 
-   — ``decode`` is a jit scope, ``make_decode_step`` is a *jit factory*
-   and names bound from its call sites are jitted callables carrying
-   the factory's static/donate argnums (imports followed module to
-   module, best effort).
+   — ``decode`` is a jit scope, ``make_paged_decode_step`` is a *jit
+   factory* and names bound from its call sites are jitted callables
+   carrying the factory's static/donate argnums (imports followed
+   module to module, best effort).
 
 2. **Which values are tracers?** ``TaintTracker`` runs a linear,
    order-sensitive walk over a traced function body: parameters start
@@ -471,7 +471,7 @@ def collect_jitted_callables(
     name call sites use (``step``, ``self._decode`` …).
 
     Covers direct wrapping (``g = jax.jit(f, …)``) and the factory
-    idiom (``g = make_decode_step(…)`` where the factory — local or
+    idiom (``g = make_paged_decode_step(…)`` where the factory — local or
     imported — returns a ``jax.jit``-wrapped function), so the donation
     and retrace passes see the same callables the runtime does.
     """

@@ -4,18 +4,17 @@ The serving half of the framework (ROADMAP north star: "serves heavy
 traffic from millions of users"), reusing the training stack's mesh, TP
 sharding specs, and attention math:
 
-  * ``kv_cache``  — per-layer KV caches in the models' scan layout:
-    the dense per-slot ``[L, B, Hkv, S_max, D]`` buffers, the MLA
-    latent-only cache, and the PAGED layout — a global pool of
-    fixed-size pages ``[L, n_pages, Hkv, page_size, D]`` with per-slot
-    page tables, a host-side ``PageAllocator`` (free list + refcounts)
-    and a ``RadixPrefixCache`` sharing page-aligned prompt prefixes
-    across requests; all head-sharded with the existing TP
-    NamedSharding specs.
-  * ``decode``    — the jitted steps (full-prompt prefill, single-
-    token decode, dense and paged variants) over the models'
-    cache-aware forwards; static shapes, donated cache buffers, two
-    compiles total per layout.
+  * ``kv_cache``  — KV caches in the models' scan layout: the page
+    pool the engine serves from — a global pool of fixed-size pages
+    ``[L, n_pages, Hkv, page_size, D]`` with per-slot page tables, a
+    host-side ``PageAllocator`` (free list + refcounts) and a
+    ``RadixPrefixCache`` sharing page-aligned prompt prefixes across
+    requests, head-sharded with the existing TP NamedSharding specs —
+    plus the contiguous ``[L, B, Hkv, S_max, D]`` reference cache and
+    the MLA latent-only cache.
+  * ``decode``    — the jitted steps (prompt-tail prefill, single-
+    token decode) over the models' cache-aware forwards; static
+    shapes, donated pool, two compiles total.
   * ``sampling``  — greedy / temperature / top-k / top-p with per-slot
     PRNG keys.
   * ``engine``    — continuous batching over a fixed-slot batch: admit
@@ -46,8 +45,6 @@ from scaletorch_tpu.inference.kv_cache import (  # noqa: F401
     init_paged_kv_cache,
     kv_cache_bytes,
     kv_cache_shape,
-    kv_cache_shardings,
-    kv_cache_specs,
     paged_kv_cache_shape,
     paged_kv_cache_shardings,
     paged_kv_cache_specs,
@@ -58,11 +55,9 @@ from scaletorch_tpu.inference.sampling import (  # noqa: F401
     sample_one,
 )
 from scaletorch_tpu.inference.decode import (  # noqa: F401
-    make_decode_step,
     make_fill_slots_step,
     make_paged_decode_step,
     make_paged_prefill_step,
-    make_prefill_step,
     resolve_forward_cached,
 )
 from scaletorch_tpu.inference.resilience import (  # noqa: F401

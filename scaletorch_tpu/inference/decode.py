@@ -1,24 +1,27 @@
-"""The two jitted engine steps: full-prompt prefill and one-token decode.
+"""The two jitted engine steps: prompt-tail prefill and one-token decode,
+both over the page pool (kv_cache.PagedKVCache).
 
 Static shapes everywhere — the engine compiles each step exactly once
 per run, however many requests flow through it:
 
-  * ``prefill``: a full-sequence causal forward over the fixed
-    ``[B, P_max]`` prompt buffer that also writes cache positions
-    [0, P_max) for the slots named by ``write_mask`` (live slots'
-    cache bytes are untouched), returns the first sampled token per
-    slot. Admitting a request into a freed slot is "set its row of the
-    buffer, flip its mask bit" — no new trace.
+  * ``prefill``: a causal forward over the fixed ``[B, P_max]`` prompt
+    buffer (each admitted slot's non-shared prompt tail) that writes
+    the slots named by ``write_mask`` into their own pages (live slots'
+    pages are untouched) and returns the first sampled token per slot.
+    Admitting a request into a freed slot is "set its row of the
+    buffer and of the page table, flip its mask bit" — no new trace.
   * ``decode``: one token per slot at per-slot absolute positions,
-    RoPE at the absolute position, ``lax.dynamic_update_slice`` cache
-    append, sample. Cache buffers are DONATED and carried whole through
-    the forwards' layer loop (``llama.scan_layers_cached``) — the
-    append happens in place instead of copying the whole cache every
-    token.
+    RoPE at the absolute position, one row appended to the slot's
+    current page, attention over its page table, sample. The pool is
+    DONATED and carried whole through the forwards' layer loop
+    (``llama.scan_layers_cached``) — the append happens in place
+    instead of copying the whole pool every token.
 
 Both lower onto the models' cache-aware forwards
 (models/llama.py forward_cached & family), resolved per config by
-``resolve_forward_cached``.
+``resolve_forward_cached``, with ``kv_cache.PagedKVIO`` as their cache
+adapter. ``teacher_forced_decode`` (contiguous cache) and
+``teacher_forced_decode_paged`` are the parity harnesses.
 """
 
 from __future__ import annotations
@@ -28,7 +31,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from scaletorch_tpu.inference.kv_cache import KVCache
 from scaletorch_tpu.inference.routing_counters import step_counts
 from scaletorch_tpu.inference.sampling import (
     SamplingParams,
@@ -79,110 +81,18 @@ def counts_routing(cfg) -> bool:
     return isinstance(cfg, Qwen3MoEConfig)
 
 
-def make_prefill_step(
-    cfg,
-    sampling: SamplingParams,
-    *,
-    forward_fn: Optional[Callable] = None,
-    donate_cache: Optional[bool] = None,
-) -> Callable:
-    """Build the jitted prefill step.
-
-    prefill(params, tokens [B, P], lengths [B], write_mask [B] bool,
-            cache, base_keys [B, 2])
-      -> (first_token [B] i32, last_logits [B, V] f32, finite [B] bool,
-          new_cache)
-
-    Runs the full causal forward over the whole fixed buffer (positions
-    [0, P) for every slot), writes cache [0, P) for masked slots only,
-    reads each slot's logits at ``lengths - 1`` and samples its first
-    token. ``finite`` flags the slots whose sampled-from logits are all
-    finite (``sampling.finite_mask``) — the engine quarantines a False
-    slot instead of emitting its garbage sample. Anything the buffer
-    holds beyond a slot's length writes garbage K/V above the slot's
-    live region — invisible, because the j <= p attention mask never
-    reaches past the current position and decode overwrites position p
-    before attending to it.
-    """
-    fwd = forward_fn or resolve_forward_cached(cfg)
-
-    def prefill(params, tokens, lengths, write_mask, cache, base_keys):
-        b, p = tokens.shape
-        positions = jnp.broadcast_to(
-            jnp.arange(p, dtype=jnp.int32), (b, p))
-        logits, new_cache = fwd(
-            params, tokens, cfg, tuple(cache),
-            positions=positions, write_mask=write_mask,
-        )
-        last = jnp.take_along_axis(
-            logits, (lengths - 1)[:, None, None], axis=1
-        )[:, 0, :]
-        keys = slot_keys(base_keys, lengths - 1)
-        first = sample(last, keys, sampling)
-        return (first, last.astype(jnp.float32), finite_mask(last),
-                KVCache(*new_cache))
-
-    return jax.jit(
-        prefill, donate_argnums=(4,) if _resolve_donate(donate_cache) else ()
-    )
-
-
-def make_decode_step(
-    cfg,
-    sampling: SamplingParams,
-    *,
-    forward_fn: Optional[Callable] = None,
-    donate_cache: Optional[bool] = None,
-) -> Callable:
-    """Build the jitted single-token decode step.
-
-    decode(params, tokens [B] i32, positions [B] i32, active [B] bool,
-           cache, base_keys [B, 2])
-      -> (next_token [B] i32, logits [B, V] f32, finite [B] bool,
-          new_cache)
-
-    Feeds each slot's current token at its absolute position (RoPE at
-    that position), appends K/V at the position for ACTIVE slots only,
-    and samples the next token with the slot's (seed, position) key.
-    ``finite`` is the in-step non-finite guard (``sampling.finite_mask``
-    over the step logits): a False slot carries NaN/Inf numerics — the
-    engine retires it as ``quarantined`` and never emits its sample.
-    Inactive slots compute garbage that goes nowhere — their mask bit
-    keeps their cache bytes intact and the engine ignores their sample.
-    """
-    fwd = forward_fn or resolve_forward_cached(cfg)
-
-    def decode(params, tokens, positions, active, cache, base_keys):
-        logits, new_cache = fwd(
-            params, tokens[:, None], cfg, tuple(cache),
-            positions=positions[:, None], write_mask=active,
-        )
-        step_logits = logits[:, 0, :]
-        keys = slot_keys(base_keys, positions)
-        nxt = sample(step_logits, keys, sampling)
-        return (nxt, step_logits.astype(jnp.float32),
-                finite_mask(step_logits), KVCache(*new_cache))
-
-    return jax.jit(
-        decode, donate_argnums=(4,) if _resolve_donate(donate_cache) else ()
-    )
-
-
 def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
-    """Build the jitted masked fill over axis 1 of the stacked cache.
+    """Build the jitted masked fill over axis 1 of the stacked cache:
+    the PAGE axis of the [L, n_pages, Hkv, page_size, D] pools.
 
     fill_slots(cache, mask bool, value scalar) -> cache with every
-    masked index's lines along axis 1 set to ``value``; unmasked bytes
-    pass through bit-identical. Axis 1 is the SLOT axis of the dense
-    [L, B, Hkv, S_max, D] buffers and the PAGE axis of the paged
-    [L, n_pages, Hkv, page_size, D] pools — the same compiled step
-    serves both layouts (the engine clears whole slots dense, whole
-    pages paged).
+    masked page set to ``value``; unmasked bytes pass through
+    bit-identical.
 
     One compile serves the scalar consumers — quarantine hygiene
     (value 0: a retired poison slot's NaN K/V must not outlive the
-    request) and fault injection (value NaN: poison a slot's cache
-    lines so its next decode step goes non-finite) — because the mask
+    request) and fault injection (value NaN: poison a slot's mutable
+    pages so its next decode step goes non-finite) — because the mask
     and the fill value are data, never shapes. ``value`` may also be a
     cache-shaped tuple (one buffer per cache field): the warm-rejoin
     import writes transferred page CONTENTS through this same step —
@@ -210,9 +120,6 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
     )
 
 
-# ---------------------------------------------------------------------------
-# paged-cache steps (ISSUE 10)
-# ---------------------------------------------------------------------------
 def make_paged_prefill_step(
     cfg,
     sampling: SamplingParams,
@@ -223,7 +130,7 @@ def make_paged_prefill_step(
     donate_cache: Optional[bool] = None,
     routing_counts: bool = False,
 ) -> Callable:
-    """Build the jitted paged prefill step.
+    """Build the jitted prefill step.
 
     prefill(params, tokens [B, P], tail_lens [B], starts [B],
             write_mask [B] bool, page_tables [B, max_pages] i32,
@@ -231,8 +138,8 @@ def make_paged_prefill_step(
       -> (first_token [B] i32, last_logits [B, V] f32, finite [B] bool,
           new_pool)
 
-    The paged twist on ``make_prefill_step``: each admitted slot
-    prefills only its NON-SHARED prompt tail. ``starts`` is the
+    Each admitted slot prefills only its NON-SHARED prompt tail, for
+    the slots named by ``write_mask``. ``starts`` is the
     page-aligned count of tokens already cached via a radix prefix hit
     (0 without one); the tail tokens sit at buffer rows [0, tail_len)
     and run at absolute positions ``starts + row`` — their attention
@@ -240,11 +147,14 @@ def make_paged_prefill_step(
     page table, so the shared positions cost ZERO forward compute.
     Writes land in the slot's own pages only (prefix sharing is
     page-aligned and shared pages are frozen); rows past ``tail_len``
-    write garbage into the slot's own later pages or the TRASH page,
-    invisible for the same reason the dense buffer's garbage is. The
-    first token samples from the logits at row ``tail_len - 1`` with
-    the slot's (seed, prompt_len - 1) key — bit-identical to the dense
-    engine's first sample.
+    write garbage into the slot's own later pages or the TRASH page —
+    invisible, because the j <= p attention mask never reaches past the
+    current position and decode overwrites position p before attending
+    to it. The first token samples from the logits at row
+    ``tail_len - 1`` with the slot's (seed, prompt_len - 1) key.
+    ``finite`` flags the slots whose sampled-from logits are all finite
+    (``sampling.finite_mask``) — the engine quarantines a False slot
+    instead of emitting its garbage sample.
 
     ``routing_counts`` (a model whose cached forward takes
     ``return_routing``: the MoE families) adds a last argument and a
@@ -298,7 +208,7 @@ def make_paged_decode_step(
     donate_cache: Optional[bool] = None,
     routing_counts: bool = False,
 ) -> Callable:
-    """Build the jitted paged single-token decode step.
+    """Build the jitted single-token decode step.
 
     decode(params, tokens [B] i32, positions [B] i32, active [B] bool,
            page_tables [B, max_pages] i32, pool (PagedKVCache),
@@ -306,16 +216,23 @@ def make_paged_decode_step(
       -> (next_token [B] i32, logits [B, V] f32, finite [B] bool,
           new_pool)
 
-    Identical contract to ``make_decode_step`` with the cache reads
-    routed through the page table: the K/V append writes one row of the
-    slot's current page and attention walks its table, the donated pool
-    carried whole through the layer loop (the Mosaic pair on TPU, in
-    place: ``paged_write`` + the paged-decode kernel at a layer index;
-    the lax scatter + gather on other platforms —
-    ops/pallas/paged_attention.py). Page-table contents are DATA: admissions, prefix hits, quarantine clears, and
-    frees all mutate tables host-side and this one compile serves them
-    all. ``routing_counts`` as in ``make_paged_prefill_step``; the rows
-    that exist are the active slots'.
+    Feeds each slot's current token at its absolute position (RoPE at
+    that position) and samples the next token with the slot's (seed,
+    position) key. For ACTIVE slots only, the K/V append writes one row
+    of the slot's current page and attention walks its table, the
+    donated pool carried whole through the layer loop (the Mosaic pair
+    on TPU, in place: ``paged_write`` + the paged-decode kernel at a
+    layer index; the lax scatter + gather on other platforms —
+    ops/pallas/paged_attention.py). ``finite`` is the in-step
+    non-finite guard (``sampling.finite_mask`` over the step logits): a
+    False slot carries NaN/Inf numerics — the engine retires it as
+    ``quarantined`` and never emits its sample. Inactive slots compute
+    garbage that goes nowhere — their mask bit keeps their pages intact
+    and the engine ignores their sample. Page-table contents are DATA:
+    admissions, prefix hits, quarantine clears, and frees all mutate
+    tables host-side and this one compile serves them all.
+    ``routing_counts`` as in ``make_paged_prefill_step``; the rows that
+    exist are the active slots'.
     """
     fwd = forward_fn or resolve_forward_cached(cfg)
 
@@ -361,7 +278,7 @@ def teacher_forced_decode_paged(
     identity page table (slot ``b`` owns pages ``b*max_pages+1 ..``,
     page 0 reserved as TRASH). Returns [B, S, V] logits — the parity
     oracle proving the paged read/write path is positionally identical
-    to the dense cache, layer by layer, token by token."""
+    to the contiguous reference cache, layer by layer, token by token."""
     import numpy as np
 
     from scaletorch_tpu.inference.kv_cache import (
@@ -394,13 +311,41 @@ def teacher_forced_decode_paged(
     return jnp.concatenate(chunks, axis=1)
 
 
-def _audit_cfg_and_cache(compute_dtype: str = "fp32"):
-    """Shared tiny setup for the inference audit targets below.
-    ``compute_dtype`` selects the activation/cache dtype so the memory
-    tier's ST1003 injection tests can build a bf16-contracted entry;
-    the manifest default stays fp32 (the CPU-mesh numerics the parity
-    oracles attest)."""
-    from scaletorch_tpu.inference.kv_cache import init_kv_cache
+# the audit targets' geometry: slots, positions a slot, tokens a page
+_AUDIT_SLOTS, _AUDIT_SEQ, _AUDIT_PAGE = 2, 32, 8
+
+
+def _audit_entry(name, make_step, lead, *, pool_pages=None,
+                 compute_dtype="fp32", forward_fn=None):
+    """One inference audit target (analysis/jaxpr_audit.py): the step
+    ``make_step`` builds, greedy and donating, on one device at the tiny
+    geometry above, called as ``step(params, *lead, page_tables, pool,
+    base_keys)``.
+
+    Contract: donation of the PAGE POOL survives lowering
+    (``donate_cache=True`` — the CPU default skips donation, which is
+    exactly what the audit must not silently accept; the pool is the
+    whole serving cache, so losing the alias doubles serving HBM per
+    step), and the single-device step compiles to ZERO collectives — any
+    collective that appears is unbudgeted by definition
+    (tools/comm_budget.json records an empty set for these entries).
+
+    Memory-tier contract (analysis/memory.py): the donated pool's bytes
+    show up as input/output alias savings (``donated_min_mb`` — ST1002),
+    and the engine's ``kv_cache_bytes`` matches the compiled pool
+    buffers (``kv_cache`` — ST1005). Both are pinned to the DEFAULT pool
+    (every slot full, plus the trash page), NOT derived from the built
+    objects, so a sizing drift fails the gate instead of relaxing it:
+    ``pool_pages`` exists so the ST1005 tests can build a shrunken pool
+    and prove the gate catches the engine/compiled-bytes drift.
+    ``compute_dtype`` selects the activation/pool dtype so the ST1003
+    injection tests can build a bf16-contracted entry; the manifest
+    default stays fp32 (the CPU-mesh numerics the parity oracles
+    attest)."""
+    from scaletorch_tpu.inference.kv_cache import (
+        init_paged_kv_cache,
+        kv_cache_bytes,
+    )
     from scaletorch_tpu.models.llama import LlamaConfig, init_params
 
     dt = jnp.bfloat16 if compute_dtype in ("bf16", "bfloat16") \
@@ -411,108 +356,28 @@ def _audit_cfg_and_cache(compute_dtype: str = "fp32"):
         head_dim=16, max_position_embeddings=256,
         dtype=dt, param_dtype=jnp.float32,
     )
-    b, s_max = 2, 32
+    b, page_size = _AUDIT_SLOTS, _AUDIT_PAGE
+    max_pages = _AUDIT_SEQ // page_size
+    num_pages = b * max_pages + 1
     params = jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(
-        lambda: init_kv_cache(cfg, b, s_max, dtype=dt))
-    base_keys = jax.ShapeDtypeStruct((b, 2), jnp.uint32)
-    return cfg, params, cache, base_keys, b, s_max
-
-
-def audit_entry_prefill():
-    """Deep-tier audit target (analysis/jaxpr_audit.py): the jitted
-    prefill step on one device. Contract: cache donation survives
-    lowering (``donate_cache=True`` — the CPU default skips donation,
-    which is exactly what the audit must not silently accept), and the
-    single-device step compiles to ZERO collectives — any collective
-    that appears is unbudgeted by definition (tools/comm_budget.json
-    records an empty set for this entry).
-
-    Memory-tier contract (analysis/memory.py): the donated cache's
-    bytes show up as input/output alias savings (``donated_min_mb`` —
-    ST1002), and the engine's ``kv_cache_bytes`` for the dense layout
-    matches the compiled cache buffers (``kv_cache`` — ST1005). Pinned
-    here, NOT derived from the built objects, so a sizing drift fails
-    the gate instead of relaxing it."""
-    from scaletorch_tpu.inference.kv_cache import kv_cache_bytes
-
-    cfg, params, cache, base_keys, b, s_max = _audit_cfg_and_cache()
-    fn = make_prefill_step(
-        cfg, SamplingParams(temperature=0.0), donate_cache=True)
+    pool = jax.eval_shape(
+        lambda: init_paged_kv_cache(
+            cfg, pool_pages if pool_pages is not None else num_pages,
+            page_size, dtype=dt))
+    fn = make_step(
+        cfg, SamplingParams(temperature=0.0), page_size=page_size,
+        seq_limit=_AUDIT_SEQ, forward_fn=forward_fn, donate_cache=True)
     args = (
         params,
-        jax.ShapeDtypeStruct((b, s_max), jnp.int32),   # tokens
-        jax.ShapeDtypeStruct((b,), jnp.int32),         # lengths
-        jax.ShapeDtypeStruct((b,), jnp.bool_),         # write_mask
-        cache,
-        base_keys,
+        *lead,
+        jax.ShapeDtypeStruct((b, max_pages), jnp.int32),   # page tables
+        pool,
+        jax.ShapeDtypeStruct((b, 2), jnp.uint32),          # base_keys
     )
-    cache_mb = kv_cache_bytes(cfg, b, s_max, jnp.float32) / 1e6
+    pool_mb = kv_cache_bytes(cfg, num_pages, page_size, dt) / 1e6
     return {
-        "name": "prefill_step",
-        "file": "scaletorch_tpu/inference/decode.py",
-        "fn": fn,
-        "args": args,
-        "min_devices": 1,
-        "quantized_axis": None,
-        "expect_donation": True,
-        "hoisted_axes": (),
-        "max_collective_result_mb": 1.0,
-        "compute_dtype": "fp32",
-        "donated_min_mb": round(0.9 * cache_mb, 4),
-        "kv_cache": {
-            "cfg": cfg, "layout": "dense", "batch": b, "max_seq": s_max,
-            "dtype": jnp.float32, "arg_index": 4,
-        },
-    }
-
-
-def audit_entry_decode(
-    compute_dtype: str = "fp32", fp32_residual: bool = False
-):
-    """Deep-tier audit target: the jitted one-token decode step on one
-    device (same contract as ``audit_entry_prefill``).
-
-    The kwargs exist so the memory-tier tests can inject exactly the
-    ST1003 regression: ``compute_dtype="bf16"`` builds the
-    bf16-contracted entry, ``fp32_residual=True`` routes the cache
-    through a large fp32 round-trip in the forward — the accidental
-    upcast the precision-leak check must attribute to its source line.
-    The manifest build stays fp32 (check inert, like the train steps).
-    """
-    from scaletorch_tpu.inference.kv_cache import kv_cache_bytes
-
-    cfg, params, cache, base_keys, b, s_max = \
-        _audit_cfg_and_cache(compute_dtype)
-    forward_fn = None
-    if fp32_residual:
-        base_fwd = resolve_forward_cached(cfg)
-
-        def forward_fn(p, tokens, c, kv, **kw):
-            logits, new_kv = base_fwd(p, tokens, c, kv, **kw)
-            # the injected leak: a full-cache fp32 round trip
-            new_kv = jax.tree.map(
-                lambda x: (x.astype(jnp.float32) + 0.0).astype(x.dtype),
-                new_kv,
-            )
-            return logits, new_kv
-
-    fn = make_decode_step(
-        cfg, SamplingParams(temperature=0.0), forward_fn=forward_fn,
-        donate_cache=True)
-    args = (
-        params,
-        jax.ShapeDtypeStruct((b,), jnp.int32),         # tokens
-        jax.ShapeDtypeStruct((b,), jnp.int32),         # positions
-        jax.ShapeDtypeStruct((b,), jnp.bool_),         # active
-        cache,
-        base_keys,
-    )
-    cache_dt = cache.k.dtype
-    cache_mb = kv_cache_bytes(cfg, b, s_max, cache_dt) / 1e6
-    return {
-        "name": "decode_step",
+        "name": name,
         "file": "scaletorch_tpu/inference/decode.py",
         "fn": fn,
         "args": args,
@@ -522,77 +387,71 @@ def audit_entry_decode(
         "hoisted_axes": (),
         "max_collective_result_mb": 1.0,
         "compute_dtype": compute_dtype,
-        # one cache buffer (k or v) counts as "large" — the smallest
-        # fp32 intermediate the leak injection materialises
-        "fp32_large_elems": 2048,
-        "donated_min_mb": round(0.9 * cache_mb, 4),
-        "kv_cache": {
-            "cfg": cfg, "layout": "dense", "batch": b, "max_seq": s_max,
-            "dtype": cache_dt, "arg_index": 4,
-        },
-    }
-
-
-def audit_entry_paged_decode(pool_pages: Optional[int] = None):
-    """Deep-tier audit target: the jitted paged one-token decode step on
-    one device. Contract: donation of the PAGE POOL survives lowering
-    (the pool is the whole serving cache — losing the alias doubles
-    serving HBM per step) and the single-device step compiles to ZERO
-    collectives (empty budget row in tools/comm_budget.json, like the
-    dense steps).
-
-    Memory-tier contract: the ``kv_cache`` sizing is pinned to the
-    DEFAULT pool (``b * max_pages + 1`` pages, the dense-equivalent +
-    trash page) regardless of ``pool_pages`` — the kwarg exists so the
-    ST1005 tests can build a shrunken pool and prove the gate catches
-    the engine/compiled-bytes drift, exactly the PR 6 injection style.
-    """
-    from scaletorch_tpu.inference.kv_cache import (
-        init_paged_kv_cache,
-        kv_cache_bytes,
-    )
-
-    cfg, params, _, base_keys, b, s_max = _audit_cfg_and_cache()
-    page_size = 8
-    max_pages = s_max // page_size
-    num_pages = b * max_pages + 1
-    pool = jax.eval_shape(
-        lambda: init_paged_kv_cache(
-            cfg, pool_pages if pool_pages is not None else num_pages,
-            page_size, dtype=jnp.float32))
-    fn = make_paged_decode_step(
-        cfg, SamplingParams(temperature=0.0), page_size=page_size,
-        seq_limit=s_max, donate_cache=True)
-    args = (
-        params,
-        jax.ShapeDtypeStruct((b,), jnp.int32),             # tokens
-        jax.ShapeDtypeStruct((b,), jnp.int32),             # positions
-        jax.ShapeDtypeStruct((b,), jnp.bool_),             # active
-        jax.ShapeDtypeStruct((b, max_pages), jnp.int32),   # page tables
-        pool,
-        base_keys,
-    )
-    pool_mb = kv_cache_bytes(
-        cfg, b, s_max, jnp.float32, layout="paged", page_size=page_size,
-        num_pages=num_pages) / 1e6
-    return {
-        "name": "paged_decode_step",
-        "file": "scaletorch_tpu/inference/decode.py",
-        "fn": fn,
-        "args": args,
-        "min_devices": 1,
-        "quantized_axis": None,
-        "expect_donation": True,
-        "hoisted_axes": (),
-        "max_collective_result_mb": 1.0,
-        "compute_dtype": "fp32",
         "donated_min_mb": round(0.9 * pool_mb, 4),
         "kv_cache": {
-            "cfg": cfg, "layout": "paged", "batch": b, "max_seq": s_max,
-            "dtype": jnp.float32, "page_size": page_size,
-            "num_pages": num_pages, "arg_index": 5,
+            "cfg": cfg, "dtype": dt, "page_size": page_size,
+            "num_pages": num_pages, "arg_index": len(lead) + 2,
         },
     }
+
+
+def audit_entry_paged_prefill(pool_pages: Optional[int] = None):
+    """Deep-tier audit target: the jitted prefill step, the program that
+    holds the memory peak of a serving engine (``_audit_entry`` for the
+    contract)."""
+    b = _AUDIT_SLOTS
+    return _audit_entry(
+        "paged_prefill_step", make_paged_prefill_step,
+        (
+            jax.ShapeDtypeStruct((b, _AUDIT_SEQ), jnp.int32),  # tokens
+            jax.ShapeDtypeStruct((b,), jnp.int32),             # tail_lens
+            jax.ShapeDtypeStruct((b,), jnp.int32),             # starts
+            jax.ShapeDtypeStruct((b,), jnp.bool_),             # write_mask
+        ),
+        pool_pages=pool_pages)
+
+
+def audit_entry_paged_decode(
+    pool_pages: Optional[int] = None,
+    compute_dtype: str = "fp32",
+    fp32_residual: bool = False,
+):
+    """Deep-tier audit target: the jitted one-token decode step
+    (``_audit_entry`` for the contract).
+
+    ``fp32_residual=True`` routes the pool through a large fp32
+    round-trip in the forward — the accidental upcast the memory tier's
+    precision-leak check (ST1003) must attribute to its source line in
+    a ``compute_dtype="bf16"`` entry. The manifest build stays fp32
+    (check inert, like the train steps).
+    """
+    forward_fn = None
+    if fp32_residual:
+        from scaletorch_tpu.models.llama import forward_cached as base_fwd
+
+        def forward_fn(p, tokens, c, kv, **kw):
+            logits, new_kv = base_fwd(p, tokens, c, kv, **kw)
+            # the injected leak: a full-pool fp32 round trip
+            new_kv = jax.tree.map(
+                lambda x: (x.astype(jnp.float32) + 0.0).astype(x.dtype),
+                new_kv,
+            )
+            return logits, new_kv
+
+    b = _AUDIT_SLOTS
+    entry = _audit_entry(
+        "paged_decode_step", make_paged_decode_step,
+        (
+            jax.ShapeDtypeStruct((b,), jnp.int32),             # tokens
+            jax.ShapeDtypeStruct((b,), jnp.int32),             # positions
+            jax.ShapeDtypeStruct((b,), jnp.bool_),             # active
+        ),
+        pool_pages=pool_pages, compute_dtype=compute_dtype,
+        forward_fn=forward_fn)
+    # one pool buffer (k or v) counts as "large" — the smallest fp32
+    # intermediate the leak injection materialises
+    entry["fp32_large_elems"] = 2048
+    return entry
 
 
 def teacher_forced_decode(
@@ -605,12 +464,13 @@ def teacher_forced_decode(
     forward_fn: Optional[Callable] = None,
     dtype=None,
 ) -> jax.Array:
-    """Reference harness: prefill the first ``prefill_len`` tokens, then
-    decode the rest one at a time with the GROUND-TRUTH token at each
-    step (no sampling). Returns [B, S, V] logits position-aligned with
-    the full-sequence training forward — the parity oracle the engine
-    tests assert against (ISSUE 4 acceptance: prefill+decode logit
-    parity under teacher forcing).
+    """Reference harness on the contiguous cache (``init_kv_cache``, the
+    cached forwards' ``kv_io=None`` default): prefill the first
+    ``prefill_len`` tokens, then decode the rest one at a time with the
+    GROUND-TRUTH token at each step (no sampling). Returns [B, S, V]
+    logits position-aligned with the full-sequence training forward —
+    the parity oracle of the cached forwards (ISSUE 4 acceptance:
+    prefill+decode logit parity under teacher forcing).
     """
     from scaletorch_tpu.inference.kv_cache import init_kv_cache
 

@@ -39,7 +39,8 @@ engine is therefore the standing parity oracle (tests, bench row,
 gateway smoke).
 
 Slice sizing reads the per-program HBM rows the memory tier pins in
-``tools/hbm_budget.json`` (``prefill_step`` vs ``paged_decode_step``):
+``tools/hbm_budget.json`` (``paged_prefill_step`` vs
+``paged_decode_step``):
 ``plan_slice_split`` splits the fleet proportional to per-phase peak
 memory, which on the 8-virtual-device CPU mesh lands on 4+4. An
 explicit ``"prefill:decode"`` spec overrides.
@@ -103,8 +104,8 @@ def plan_slice_split(
     """Size the two slices from the CI-attested per-phase HBM rows:
     devices split proportional to ``peak_mb`` of the prefill-slice vs
     decode-slice programs (the ``disagg_*`` rows the manifest entries
-    below pin; the colocated ``prefill_step``/``paged_decode_step``
-    rows are the fallback), each slice getting at least one device. A
+    below pin; the colocated ``paged_prefill_step``/
+    ``paged_decode_step`` rows are the fallback), each slice getting at least one device. A
     missing or unreadable budget falls back to an even split — sizing
     degrades, correctness doesn't."""
     if num_devices < 2:
@@ -119,7 +120,7 @@ def plan_slice_split(
     except (OSError, ValueError):
         entries = {}
     w_p = _budget_peak(entries, "disagg_prefill_slice",
-                       "prefill_step") or 1.0
+                       "paged_prefill_step") or 1.0
     w_d = _budget_peak(entries, "disagg_decode_slice",
                        "paged_decode_step") or 1.0
     n_p = int(round(num_devices * w_p / (w_p + w_d)))
@@ -312,11 +313,6 @@ class DisaggregatedEngine(InferenceEngine):
                  prefill_pool_pages: Optional[int] = None,
                  channel: Optional[PageHandoffChannel] = None,
                  **kw) -> None:
-        layout = kw.setdefault("cache_layout", "paged")
-        if layout != "paged":
-            raise ValueError(
-                "DisaggregatedEngine requires cache_layout='paged' — "
-                "the page is the handoff unit")
         if kw.get("mesh") is not None:
             raise ValueError(
                 "DisaggregatedEngine owns its slice meshes; pass "
@@ -734,64 +730,23 @@ class DisaggregatedEngine(InferenceEngine):
 
 def audit_entry_prefill_slice():
     """Deep-tier audit target: the PREFILL slice's single program — the
-    jitted paged prefill step exactly as the disaggregated engine calls
-    it (full-prompt prefill into a prompt-pages pool). Contract: pool
-    donation survives lowering (``donate_cache=True`` — ST702/ST1002)
-    and the single-device program compiles to ZERO collectives (the
-    comm budget pins an empty row; slice-internal TP would add axes
-    here, cross-slice traffic rides the handoff channel, never a
-    collective). Memory tier: the pinned ``kv_cache`` geometry must
-    match the compiled pool buffer (ST1005) — the per-phase ``peak_mb``
-    row this writes into ``tools/hbm_budget.json`` is what
-    ``plan_slice_split`` sizes the prefill slice by."""
-    from scaletorch_tpu.inference.decode import (
-        _audit_cfg_and_cache,
-        make_paged_prefill_step,
-    )
-    from scaletorch_tpu.inference.kv_cache import kv_cache_bytes
-    from scaletorch_tpu.inference.sampling import SamplingParams
+    same jitted paged prefill step the colocated engine runs, as the
+    disaggregated engine calls it (full-prompt prefill into a
+    prompt-pages pool), attested under the disagg name. Contract: pool
+    donation survives lowering (ST702/ST1002), the single-device
+    program compiles to ZERO collectives (the comm budget pins an empty
+    row; slice-internal TP would add axes here, cross-slice traffic
+    rides the handoff channel, never a collective), and the pinned
+    ``kv_cache`` geometry matches the compiled pool buffer (ST1005) —
+    the per-phase ``peak_mb`` row this writes into
+    ``tools/hbm_budget.json`` is what ``plan_slice_split`` sizes the
+    prefill slice by."""
+    from scaletorch_tpu.inference.decode import audit_entry_paged_prefill
 
-    cfg, params, _, base_keys, b, s_max = _audit_cfg_and_cache()
-    page_size = 8
-    max_pages = s_max // page_size
-    num_pages = b * max_pages + 1
-    pool = jax.eval_shape(
-        lambda: init_paged_kv_cache(
-            cfg, num_pages, page_size, dtype=jnp.float32))
-    fn = make_paged_prefill_step(
-        cfg, SamplingParams(temperature=0.0), page_size=page_size,
-        seq_limit=s_max, donate_cache=True)
-    args = (
-        params,
-        jax.ShapeDtypeStruct((b, s_max), jnp.int32),       # tokens
-        jax.ShapeDtypeStruct((b,), jnp.int32),             # tail_lens
-        jax.ShapeDtypeStruct((b,), jnp.int32),             # starts
-        jax.ShapeDtypeStruct((b,), jnp.bool_),             # write_mask
-        jax.ShapeDtypeStruct((b, max_pages), jnp.int32),   # page tables
-        pool,
-        base_keys,
-    )
-    pool_mb = kv_cache_bytes(
-        cfg, b, s_max, jnp.float32, layout="paged", page_size=page_size,
-        num_pages=num_pages) / 1e6
-    return {
-        "name": "disagg_prefill_slice",
-        "file": "scaletorch_tpu/inference/disagg.py",
-        "fn": fn,
-        "args": args,
-        "min_devices": 1,
-        "quantized_axis": None,
-        "expect_donation": True,
-        "hoisted_axes": (),
-        "max_collective_result_mb": 1.0,
-        "compute_dtype": "fp32",
-        "donated_min_mb": round(0.9 * pool_mb, 4),
-        "kv_cache": {
-            "cfg": cfg, "layout": "paged", "batch": b, "max_seq": s_max,
-            "dtype": jnp.float32, "page_size": page_size,
-            "num_pages": num_pages, "arg_index": 6,
-        },
-    }
+    entry = audit_entry_paged_prefill()
+    entry["name"] = "disagg_prefill_slice"
+    entry["file"] = "scaletorch_tpu/inference/disagg.py"
+    return entry
 
 
 def audit_entry_decode_slice():

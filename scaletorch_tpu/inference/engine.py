@@ -11,21 +11,20 @@ TPU execution discipline:
     steps the engine admits queued requests into freed slots by writing
     their row of the prompt buffer and flipping their ``write_mask``
     bit — data changes, shapes don't, nothing retraces;
-  * the KV cache is donated through every step (XLA appends in place);
-    with a mesh it is head-sharded over ``tp`` via the same specs the
-    training params use (kv_cache_specs), and the steps run GSPMD;
-  * ``cache_layout="paged"`` swaps the dense per-slot buffers for a
-    global page pool + per-slot page tables (kv_cache.PagedKVCache):
-    admission becomes page-budget-aware (HBM scales with tokens cached,
-    not B x S_max), a radix tree shares page-aligned prompt prefixes
-    across requests (refcounted, copy-on-write at the page boundary),
-    and the steps touch the pool through the table (the Mosaic pair of
-    ops/pallas/paged_attention.py on TPU: ``paged_write`` in place and
-    the decode kernel, the pool carried whole through the layer loop;
-    the lax scatter + gather elsewhere; the snapshot's
-    ``paged_pool_in_place`` says which) — greedy outputs stay bit-identical to the dense layout
-    and the tables are data, so the one-compile discipline survives
-    admissions, prefix hits, quarantine page-clears, and frees.
+  * K/V lives in ONE layout: a global page pool + per-slot page tables
+    (kv_cache.PagedKVCache). Admission is page-budget-aware (HBM scales
+    with tokens cached, not B x S_max), a radix tree shares
+    page-aligned prompt prefixes across requests (refcounted,
+    copy-on-write at the page boundary), and the steps touch the pool
+    through the table (the Mosaic pair of ops/pallas/paged_attention.py
+    on TPU: ``paged_write`` in place and the decode kernel, the pool
+    carried whole through the layer loop; the lax scatter + gather
+    elsewhere; the snapshot's ``paged_pool_in_place`` says which). The
+    tables are data, so the one-compile discipline survives admissions,
+    prefix hits, quarantine page-clears, and frees;
+  * the pool is donated through every step (written in place); with a
+    mesh it is head-sharded over ``tp`` via the same axis the training
+    params use (paged_kv_cache_specs), and the steps run GSPMD.
 
 Serving-grade fault tolerance (inference/resilience.py) rides the same
 discipline: every submitted request ends in exactly one terminal
@@ -60,11 +59,9 @@ import numpy as np
 
 from scaletorch_tpu.inference.decode import (
     counts_routing,
-    make_decode_step,
     make_fill_slots_step,
     make_paged_decode_step,
     make_paged_prefill_step,
-    make_prefill_step,
 )
 from scaletorch_tpu.inference.routing_counters import (
     CountedStep,
@@ -75,9 +72,9 @@ from scaletorch_tpu.inference.kv_cache import (
     RadixPrefixCache,
     TRASH_PAGE,
     ceil_div,
-    init_kv_cache,
     init_paged_kv_cache,
     kv_cache_bytes,
+    paged_kv_cache_shardings,
 )
 from scaletorch_tpu.inference.resilience import (
     TERMINAL_OUTCOMES,
@@ -185,7 +182,7 @@ class EngineMetrics:
     queue_depth: int = 0
     active_slots: int = 0
     num_slots: int = 0
-    # paged-cache gauges/counters (zero on the dense layout): pool
+    # page-pool gauges/counters: pool
     # occupancy plus the radix prefix-cache's yield — an admission whose
     # prompt head was already cached is a ``prefix_hit`` and its shared
     # tokens (never re-prefilled) accumulate in ``prefill_tokens_saved``
@@ -202,7 +199,7 @@ class EngineMetrics:
     # which pair the two paged step programs were built with: 1 = the
     # Mosaic pair (``paged_write`` in place + the decode kernel at a
     # layer index; a TPU whose head_dim the kernels serve), 0 = the lax
-    # scatter + gather (and on the dense layout)
+    # scatter + gather
     paged_pool_in_place: int = 0
     ttft_sum_s: float = 0.0
     ttft_count: int = 0
@@ -353,31 +350,29 @@ class InferenceEngine:
         NamedShardings (utils/hf_interop.load_hf_params(shardings=...)
         feeds this directly).
     max_slots : decode batch size B (fixed).
-    max_seq : cache length S_max (prompt + generation cap per slot).
+    max_seq : prompt + generation cap per slot (S_max).
     prefill_len : static prompt-buffer length P_max (default
         ``max_seq``); prompts longer than this are rejected.
     sampling : engine-wide sampling knobs (static, baked into the
         compiled steps).
-    cache_layout : ``"dense"`` (default, per-slot [L,B,Hkv,S_max,D]
-        buffers) or ``"paged"`` — a global pool of fixed-size pages
-        [L,n_pages,Hkv,page_size,D] plus per-slot page tables. Paged,
-        admission is PAGE-BUDGET-aware: a request is admitted when the
-        pool can cover ``min(prompt + max_new_tokens, max_seq)`` tokens
-        of pages (minus any radix prefix hit), not when a slot index
-        frees up — HBM scales with tokens actually cached, and
-        concurrency with the pool, not with ``B × S_max``.
-    page_size : tokens per page (paged layout only).
+    page_size : tokens per page. The cache is a global pool of
+        fixed-size pages [L,n_pages,Hkv,page_size,D] plus per-slot page
+        tables, and admission is PAGE-BUDGET-aware: a request is
+        admitted when the pool can cover ``min(prompt + max_new_tokens,
+        max_seq)`` tokens of pages (minus any radix prefix hit), not
+        when a slot index frees up — HBM scales with tokens actually
+        cached, and concurrency with the pool, not with ``B × S_max``.
     num_pages : pool size including the reserved TRASH page. None sizes
-        the dense-equivalent pool (``max_slots * ceil(max_seq /
-        page_size) + 1``); smaller pools trade concurrency for HBM.
-    prefix_cache : paged only — keep a radix tree over page-aligned
-        token prefixes so a request whose prompt head is already cached
-        shares those pages (refcounted, copy-on-write at the page
-        boundary) and prefills only its tail.
-    mesh / tp_axis / batch_axis : optional — shard the cache over the
-        mesh (KV heads over ``tp_axis``, slots over ``batch_axis``;
-        the paged pool shards KV heads the same way, ``batch_axis``
-        is dense-only — pages are not slot-aligned).
+        the pool every slot can fill to ``max_seq`` (``max_slots *
+        ceil(max_seq / page_size) + 1``); smaller pools trade
+        concurrency for HBM.
+    prefix_cache : keep a radix tree over page-aligned token prefixes
+        so a request whose prompt head is already cached shares those
+        pages (refcounted, copy-on-write at the page boundary) and
+        prefills only its tail.
+    mesh / tp_axis : optional — shard the pool's KV heads over
+        ``tp_axis`` of the mesh (the page axis stays unsharded: pages
+        are not slot-aligned).
     monitor : optional SystemMonitor; ``step()`` samples the metrics
         snapshot into its ring buffer every ``monitor_every`` steps.
     tracer : optional ``telemetry.SpanTracer``. A tick is cut into the
@@ -444,13 +439,11 @@ class InferenceEngine:
         prefill_len: Optional[int] = None,
         sampling: SamplingParams = SamplingParams(),
         cache_dtype: Any = None,
-        cache_layout: str = "dense",
         page_size: int = 16,
         num_pages: Optional[int] = None,
         prefix_cache: bool = True,
         mesh: Any = None,
         tp_axis: str = "tp",
-        batch_axis: Optional[str] = None,
         donate_cache: Optional[bool] = None,
         monitor: Any = None,
         monitor_every: int = 16,
@@ -505,116 +498,71 @@ class InferenceEngine:
         self._held_tokens: List[Tuple[int, int, List[int]]] = []
         self.on_dispatched: Optional[Callable[[], None]] = None
 
-        if cache_layout not in ("dense", "paged"):
-            raise ValueError(
-                f"cache_layout must be 'dense' or 'paged', "
-                f"got {cache_layout!r}"
-            )
-        self.cache_layout = cache_layout
-        self._paged = cache_layout == "paged"
-        if self._paged and page_size < 1:
+        if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.page_size = page_size
-        self._pages_per_slot = (
-            ceil_div(max_seq, page_size) if self._paged else 0)
-        if num_pages is None and self._paged:
+        self._pages_per_slot = ceil_div(max_seq, page_size)
+        if num_pages is None:
             num_pages = max_slots * self._pages_per_slot + 1
         self.num_pages = num_pages
 
-        if self._paged:
-            from scaletorch_tpu.inference.kv_cache import (
-                paged_kv_cache_shardings,
-            )
+        sharding = (
+            paged_kv_cache_shardings(mesh, tp_axis=tp_axis)
+            if mesh is not None else None
+        )
+        self.cache = init_paged_kv_cache(
+            cfg, num_pages, page_size, dtype=cache_dtype, sharding=sharding)
+        self.allocator = PageAllocator(num_pages)
+        self.radix = (
+            RadixPrefixCache(
+                page_size, self.allocator.retain, self.allocator.release,
+                self.allocator.refcount,
+            ) if prefix_cache else None
+        )
+        # per-slot page table (host copy; reaches the device as data
+        # every step), the pages each slot holds a reference on
+        # (shared prefix pages first, own pages after), and how many
+        # leading table entries are FROZEN — shared or
+        # radix-registered, so exempt from quarantine clears/pokes
+        self._tables = np.full(
+            (max_slots, self._pages_per_slot), TRASH_PAGE, np.int32)
+        # device copy of the tables, re-uploaded only after a host
+        # write (admission/retire) — the decode hot loop reads it
+        # every tick and must not pay a H2D transfer per token
+        self._tables_dev = None
+        self._slot_pages: List[List[int]] = [[] for _ in range(max_slots)]
+        self._slot_frozen = [0] * max_slots
+        logger.info(
+            "inference engine: %d slots over %d pages x %d tokens, "
+            "pool %.1f MiB%s%s",
+            max_slots, num_pages, page_size,
+            kv_cache_bytes(cfg, num_pages, page_size,
+                           dtype=cache_dtype) / 2**20,
+            ", prefix cache on" if prefix_cache else "",
+            f", sharded over {mesh.axis_names}" if mesh is not None
+            else "",
+        )
 
-            sharding = (
-                paged_kv_cache_shardings(mesh, tp_axis=tp_axis)
-                if mesh is not None else None
-            )
-            self.cache = init_paged_kv_cache(
-                cfg, num_pages, page_size, dtype=cache_dtype,
-                sharding=sharding)
-            self.allocator = PageAllocator(num_pages)
-            self.radix = (
-                RadixPrefixCache(
-                    page_size, self.allocator.retain, self.allocator.release,
-                    self.allocator.refcount,
-                ) if prefix_cache else None
-            )
-            # per-slot page table (host copy; reaches the device as data
-            # every step), the pages each slot holds a reference on
-            # (shared prefix pages first, own pages after), and how many
-            # leading table entries are FROZEN — shared or
-            # radix-registered, so exempt from quarantine clears/pokes
-            self._tables = np.full(
-                (max_slots, self._pages_per_slot), TRASH_PAGE, np.int32)
-            # device copy of the tables, re-uploaded only after a host
-            # write (admission/retire) — the decode hot loop reads it
-            # every tick and must not pay a H2D transfer per token
-            self._tables_dev = None
-            self._slot_pages: List[List[int]] = [[] for _ in range(max_slots)]
-            self._slot_frozen = [0] * max_slots
-            cache_mib = kv_cache_bytes(
-                cfg, max_slots, max_seq, dtype=cache_dtype, layout="paged",
-                page_size=page_size, num_pages=num_pages) / 2**20
-            logger.info(
-                "inference engine: %d slots over %d pages x %d tokens, "
-                "pool %.1f MiB%s%s",
-                max_slots, num_pages, page_size, cache_mib,
-                ", prefix cache on" if prefix_cache else "",
-                f", sharded over {mesh.axis_names}" if mesh is not None
-                else "",
-            )
-        else:
-            sharding = None
-            if mesh is not None:
-                from scaletorch_tpu.inference.kv_cache import (
-                    kv_cache_shardings,
-                )
-
-                sharding = kv_cache_shardings(
-                    mesh, tp_axis=tp_axis, batch_axis=batch_axis)
-            self.cache = init_kv_cache(
-                cfg, max_slots, max_seq, dtype=cache_dtype, sharding=sharding)
-            self.allocator = None
-            self.radix = None
-            logger.info(
-                "inference engine: %d slots x %d positions, cache %.1f "
-                "MiB%s",
-                max_slots, max_seq,
-                kv_cache_bytes(cfg, max_slots, max_seq,
-                               dtype=cache_dtype) / 2**20,
-                f", sharded over {mesh.axis_names}" if mesh is not None
-                else "",
-            )
-
+        # a model that routes tokens to experts: its steps count what
+        # they route (inference/routing_counters.py)
         routing = None
-        if self._paged:
-            # a model that routes tokens to experts: its paged steps
-            # count what they route (inference/routing_counters.py)
-            counts = forward_fn is None and counts_routing(cfg)
-            steps = dict(page_size=page_size, seq_limit=max_seq,
-                         forward_fn=forward_fn, donate_cache=donate_cache,
-                         routing_counts=counts)
-            self._prefill = make_paged_prefill_step(cfg, sampling, **steps)
-            self._decode = make_paged_decode_step(cfg, sampling, **steps)
-            if counts:
-                replicated = None
-                if mesh is not None:
-                    from jax.sharding import NamedSharding, PartitionSpec
+        counts = forward_fn is None and counts_routing(cfg)
+        steps = dict(page_size=page_size, seq_limit=max_seq,
+                     forward_fn=forward_fn, donate_cache=donate_cache,
+                     routing_counts=counts)
+        self._prefill = make_paged_prefill_step(cfg, sampling, **steps)
+        self._decode = make_paged_decode_step(cfg, sampling, **steps)
+        if counts:
+            replicated = None
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
 
-                    replicated = NamedSharding(mesh, PartitionSpec())
-                routing = RoutingCounters(
-                    cfg.num_experts, len(cfg.sparse_layer_ids()),
-                    sharding=replicated)
-                self._prefill = CountedStep(self._prefill, routing)
-                self._decode = CountedStep(self._decode, routing)
-        else:
-            self._prefill = make_prefill_step(
-                cfg, sampling, forward_fn=forward_fn,
-                donate_cache=donate_cache)
-            self._decode = make_decode_step(
-                cfg, sampling, forward_fn=forward_fn,
-                donate_cache=donate_cache)
+                replicated = NamedSharding(mesh, PartitionSpec())
+            routing = RoutingCounters(
+                cfg.num_experts, len(cfg.sparse_layer_ids()),
+                sharding=replicated)
+            self._prefill = CountedStep(self._prefill, routing)
+            self._decode = CountedStep(self._decode, routing)
         self._fill_slots = make_fill_slots_step(donate_cache=donate_cache)
 
         self._slots = [_Slot() for _ in range(max_slots)]
@@ -627,8 +575,7 @@ class InferenceEngine:
         self._draining = False
         self.metrics = EngineMetrics(
             num_slots=max_slots, routing=routing,
-            paged_pool_in_place=int(
-                self._paged and in_place_pair(self.cache.k.shape[-1])))
+            paged_pool_in_place=int(in_place_pair(self.cache.k.shape[-1])))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
         # the clock that is open, the last boundary; this tick's seconds
         # by phase name; when the previous tick ended, and whether it
@@ -640,8 +587,7 @@ class InferenceEngine:
         self._tick_phase_s: Dict[str, float] = {}
         self._tick_end_t = self._clock_t
         self._tick_left_work = False
-        if self._paged:
-            self._update_page_gauges()
+        self._update_page_gauges()
         # progress fingerprint of the last JSONL export: an idle engine
         # polled at a cadence multiple (or a drain() straight after
         # run()) must not append duplicate records — but any outcome
@@ -798,7 +744,7 @@ class InferenceEngine:
                 f"prompt length {len(prompt)} leaves no room to generate "
                 f"within max_seq {self.max_seq}"
             )
-        elif (self._paged and self._request_pages(len(prompt), max_new_tokens)
+        elif (self._request_pages(len(prompt), max_new_tokens)
                 > self.allocator.capacity):
             err = (
                 f"request needs {self._request_pages(len(prompt), max_new_tokens)} "
@@ -926,17 +872,16 @@ class InferenceEngine:
         slot.request = None
         slot.tokens = []
         slot.clocks_at_first = None
-        if self._paged:
-            # drop the slot's references; pages shared with live slots or
-            # pinned by the radix tree survive (refcount > 1), the rest
-            # return to the free list
-            for p in self._slot_pages[i]:
-                self.allocator.release(p)
-            self._slot_pages[i] = []
-            self._slot_frozen[i] = 0
-            self._tables[i, :] = TRASH_PAGE
-            self._tables_dev = None
-            self._update_page_gauges()
+        # drop the slot's references; pages shared with live slots or
+        # pinned by the radix tree survive (refcount > 1), the rest
+        # return to the free list
+        for p in self._slot_pages[i]:
+            self.allocator.release(p)
+        self._slot_pages[i] = []
+        self._slot_frozen[i] = 0
+        self._tables[i, :] = TRASH_PAGE
+        self._tables_dev = None
+        self._update_page_gauges()
 
     def _expire(self, now: float) -> None:
         """Deadline sweep: retire queued and mid-decode requests whose
@@ -963,33 +908,25 @@ class InferenceEngine:
 
     def _quarantine(self, indices: List[int], now: float, where: str) -> None:
         """Retire poisoned slots (non-finite logits) and mask-clear their
-        cache lines so the NaN K/V cannot outlive the request. The clear
-        is one jitted masked fill over the whole cache — data-only, so
-        the decode step's single compile survives the fault. Paged, the
-        mask covers the slot's MUTABLE pages only (own pages past the
-        frozen prefix): frozen pages are immutable since registration —
+        pages so the NaN K/V cannot outlive the request. The clear is
+        one jitted masked fill over the whole pool — data-only, so the
+        decode step's single compile survives the fault. The mask
+        covers the slot's MUTABLE pages only (own pages past the frozen
+        prefix): frozen pages are immutable since registration —
         written once by a healthy prefill — so the NaN cannot live there,
         and clearing them would corrupt the slots sharing them."""
-        if self._paged:
-            mask = np.zeros(self.num_pages, bool)
-            for i in indices:
-                mutable = self._slot_pages[i][self._slot_frozen[i]:]
-                mask[mutable] = True
-                self._retire_slot(
-                    i, "quarantined",
-                    detail=f"non-finite logits at {where}", now=now)
-        else:
-            mask = np.zeros(self.max_slots, bool)
-            for i in indices:
-                self._retire_slot(
-                    i, "quarantined",
-                    detail=f"non-finite logits at {where}", now=now)
-                mask[i] = True
+        mask = np.zeros(self.num_pages, bool)
+        for i in indices:
+            mutable = self._slot_pages[i][self._slot_frozen[i]:]
+            mask[mutable] = True
+            self._retire_slot(
+                i, "quarantined",
+                detail=f"non-finite logits at {where}", now=now)
         self.cache = self._fill_slots(
             self.cache, jnp.asarray(mask), jnp.asarray(0.0, jnp.float32))
 
     def _poison_slot(self, slot_idx: int) -> None:
-        """Fault injection: NaN-fill one slot's cache lines so its next
+        """Fault injection: NaN-fill one slot's pages so its next
         decode step produces non-finite logits (same masked fill the
         quarantine clear uses — one compile serves both)."""
         active = [i for i, s in enumerate(self._slots) if s.active]
@@ -999,18 +936,14 @@ class InferenceEngine:
             return
         if slot_idx not in active:
             slot_idx = active[0]
-        if self._paged:
-            # NaN the slot's mutable pages only — frozen prefix pages may
-            # be shared, and poisoning them would fault the neighbours
-            # the drill asserts are unaffected. (With a page-aligned
-            # prompt the poke surfaces from the second decode on: until
-            # then the only mutable lane is overwritten fresh each step.)
-            mask = np.zeros(self.num_pages, bool)
-            mutable = self._slot_pages[slot_idx][self._slot_frozen[slot_idx]:]
-            mask[mutable] = True
-        else:
-            mask = np.zeros(self.max_slots, bool)
-            mask[slot_idx] = True
+        # NaN the slot's mutable pages only — frozen prefix pages may
+        # be shared, and poisoning them would fault the neighbours
+        # the drill asserts are unaffected. (With a page-aligned
+        # prompt the poke surfaces from the second decode on: until
+        # then the only mutable lane is overwritten fresh each step.)
+        mask = np.zeros(self.num_pages, bool)
+        mutable = self._slot_pages[slot_idx][self._slot_frozen[slot_idx]:]
+        mask[mutable] = True
         self.cache = self._fill_slots(
             self.cache, jnp.asarray(mask),
             jnp.asarray(float("nan"), jnp.float32))
@@ -1037,9 +970,8 @@ class InferenceEngine:
         """Snapshot the radix tree for a warming peer: root-to-leaf
         token chains with their page ids, plus per-page refcount/frozen
         state. Engine-thread only (worker inbox)."""
-        if not self._paged or self.radix is None:
-            return {"page_size": self.page_size if self._paged else None,
-                    "chains": [], "pages": {}}
+        if self.radix is None:
+            return {"page_size": self.page_size, "chains": [], "pages": {}}
         return {
             "page_size": self.page_size,
             "dtype": str(self.cache.k.dtype),
@@ -1069,14 +1001,13 @@ class InferenceEngine:
         {page: (k_bytes, v_bytes)})``; requested pages no longer frozen
         are simply absent (the wire sends a zero-content frame)."""
         meta: Dict[str, Any] = {
-            "dtype": str(self.cache.k.dtype) if self._paged else None,
+            "dtype": str(self.cache.k.dtype),
             "page_shape": ([int(self.cache.k.shape[0])]
-                           + [int(d) for d in self.cache.k.shape[2:]])
-            if self._paged else [],
-            "page_size": self.page_size if self._paged else None,
+                           + [int(d) for d in self.cache.k.shape[2:]]),
+            "page_size": self.page_size,
         }
         contents: Dict[int, Tuple[bytes, bytes]] = {}
-        if not self._paged or self.radix is None:
+        if self.radix is None:
             return meta, contents
         frozen = set(self.radix.registered_pages())
         valid = [int(p) for p in pages if int(p) in frozen]
@@ -1112,7 +1043,7 @@ class InferenceEngine:
         transfer still warms what arrived intact. Returns ``{"pages":
         new_radix_pages, "chains": [registered token lists]}``."""
         result: Dict[str, Any] = {"pages": 0, "chains": []}
-        if not self._paged or self.radix is None:
+        if self.radix is None:
             return result
         expected_shape = tuple(
             [int(self.cache.k.shape[0])]
@@ -1194,16 +1125,6 @@ class InferenceEngine:
             self.cache, jnp.asarray(mask),
             type(self.cache)(jnp.asarray(vk), jnp.asarray(vv)))
 
-    def _admit(self) -> None:
-        """Move queued requests into free slots and prefill them — ONE
-        batched prefill call regardless of how many were admitted. A
-        slot whose prefill logits are non-finite (poison prompt) is
-        quarantined immediately; the other admitted slots proceed."""
-        if self._paged:
-            self._admit_paged()
-        else:
-            self._admit_dense()
-
     def _bind_slot(self, i: int, req: Request) -> None:
         slot = self._slots[i]
         slot.request = req
@@ -1224,50 +1145,6 @@ class InferenceEngine:
             jax.random.PRNGKey(req.seed), np.uint32)
         self._base_keys_dev = None
         self.metrics.requests_admitted += 1
-
-    def _admit_dense(self) -> None:
-        with self._phase("engine.tick.admit"):
-            free = [i for i, s in enumerate(self._slots) if not s.active]
-            if not free or not self._queue:
-                return
-            self._release_tokens()
-            admitted: List[int] = []
-            tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
-            lengths = np.ones(self.max_slots, np.int32)
-            write_mask = np.zeros(self.max_slots, bool)
-            for i in free:
-                if not self._queue:
-                    break
-                req = self._queue.popleft()
-                self._bind_slot(i, req)
-                tokens[i, : len(req.prompt)] = req.prompt
-                lengths[i] = len(req.prompt)
-                write_mask[i] = True
-                admitted.append(i)
-        t0 = time.monotonic()
-        for i in admitted:
-            self._req_event("b", self._slots[i].request, "req.prefill")
-        with self._phase("engine.tick.prefill"):
-            first, _logits, finite, self.cache = self._prefill(
-                self.params, jnp.asarray(tokens), jnp.asarray(lengths),
-                jnp.asarray(write_mask), self.cache,
-                self._base_keys_device(),
-            )
-        self.metrics.prefill_calls += 1
-        with self._phase("engine.tick.prefill_wait"):
-            first = np.asarray(first)
-            finite = np.asarray(finite)
-        with self._phase("engine.tick.emit"):
-            now = time.monotonic()
-            self._note_prefill(admitted, now - t0)
-            poisoned = [i for i in admitted if not finite[i]]
-            if poisoned:
-                self._quarantine(poisoned, now, where="prefill")
-            for i in admitted:
-                if finite[i]:
-                    self._emit(i, int(first[i]), now)
-            self.metrics.queue_depth = len(self._queue)
-            del _logits  # freed inside the phase, as in step()
 
     def _note_prefill(self, admitted: List[int], prefill_s: float) -> None:
         """Attribute one batched prefill's wall time to every request it
@@ -1313,7 +1190,12 @@ class InferenceEngine:
             return None
         return shared, shared_pages + own
 
-    def _admit_paged(self) -> None:
+    def _admit(self) -> None:
+        """Move queued requests into free slots while the page pool can
+        cover them, and prefill them — ONE batched prefill call
+        regardless of how many were admitted. A slot whose prefill
+        logits are non-finite (poison prompt) is quarantined
+        immediately; the other admitted slots proceed."""
         with self._phase("engine.tick.admit"):
             free = [i for i, s in enumerate(self._slots) if not s.active]
             if not free or not self._queue:
@@ -1514,15 +1396,11 @@ class InferenceEngine:
                         tokens[i] = slot.tokens[-1]
                         positions[i] = slot.position + slot.generated - 1
                         active[i] = True
-                    # the paged step takes the page tables between the
-                    # slot mask and the cache; the dense signature is
-                    # otherwise identical
-                    tables = (
-                        (self._tables_device(),) if self._paged else ())
                     # numpy as it is: the jitted call uploads its host
                     # operands itself, without the 0.25 ms of Python a
                     # jnp.asarray each costs on a v5e's host
-                    feed = (tokens, positions, active, *tables)
+                    feed = (tokens, positions, active,
+                            self._tables_device())
                     base_keys = self._base_keys_device()
                 with self._phase("engine.tick.decode"):
                     nxt, _logits, finite, self.cache = self._decode(

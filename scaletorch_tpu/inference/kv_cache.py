@@ -1,40 +1,38 @@
 """KV-cache containers in the models' stacked-scan layout.
 
-The decode engine keeps one cache buffer pair per model: keys and values
-``[L, B, Hkv, S_max, D]`` with the layer axis leading — the same stacked
-layout the training params use, so the cached forward scans layers and
-cache slices together (models/llama.py forward_cached) and compile time
-stays O(1) in depth.
+The serving engine keeps ONE cache layout, the page pool:
+``PagedKVCache`` is a global pool of fixed-size pages
+``[L, n_pages, Hkv, page_size, D]`` with the layer axis leading — the
+same stacked layout the training params use, so the cached forward
+carries the pool through its layer loop (models/llama.py
+forward_cached) and compile time stays O(1) in depth — plus per-slot
+page tables (``[B, max_pages]`` int32, TRASH_PAGE-padded). Slots
+reserve only the pages their request can actually touch — HBM scales
+with tokens cached, not ``B × S_max`` — and requests sharing a token
+prefix share pages: ``PageAllocator`` (host-side free list + refcounts)
+and ``RadixPrefixCache`` (page-granular radix tree over token chunks)
+keep the bookkeeping; ``PagedKVIO`` adapts the models' cache-aware
+forwards to the pool: they carry it whole through their layer loop and
+the adapter writes and reads it at a layer index
+(ops/pallas/paged_attention.py holds the two pairs: the Mosaic page
+write + decode kernel, in place, and the lax scatter + gather).
 
 Sharding reuses the training stack's TP placement: K/V projections are
 column-parallel over ``tp`` (tensor_parallel.llama_param_specs), so the
-cache shards its KV-head axis over the same ``tp`` mesh axis —
-``kv_cache_specs`` is the cache-side counterpart of llama_param_specs.
-Slots (the engine's batch axis) can additionally shard over ``dp`` for
-throughput serving. Placement is declarative (NamedSharding +
-device_put); the jitted steps run GSPMD — no shard_map needed, so the
-serving path works on any jax new enough for NamedSharding.
+pool shards its KV-head axis over the same ``tp`` mesh axis
+(``paged_kv_cache_specs``). Placement is declarative (NamedSharding +
+device_put); the jitted steps run GSPMD — no shard_map needed.
+
+The contiguous ``KVCache`` (``[L, B, Hkv, S_max, D]``, one row of
+positions per sequence) is the REFERENCE: what the cached forwards read
+and write by default (``layers.DenseKVIO``), what
+``decode.teacher_forced_decode`` and ``gpt_moe.generate`` run on, and
+what the parity tests hold the paged path to. The engine never builds
+one.
 
 MLA models cache only the low-rank latent (``MLACache``,
 [B, S_max, kv_rank]) and re-expand K/V per step — the trade the variant
 documents (models/attention/variants.py MultiHeadLatentAttention).
-
-Paged layout (ISSUE 10): ``PagedKVCache`` replaces the dense per-slot
-buffers with a global pool of fixed-size pages
-``[L, n_pages, Hkv, page_size, D]`` plus per-slot page tables
-(``[B, max_pages]`` int32, TRASH_PAGE-padded). Slots reserve only the
-pages their request can actually touch — HBM scales with tokens cached,
-not ``B × S_max`` — and requests sharing a token prefix share pages:
-``PageAllocator`` (host-side free list + refcounts) and
-``RadixPrefixCache`` (page-granular radix tree over token chunks) keep
-the bookkeeping; ``PagedKVIO`` adapts the models' cache-aware forwards
-to the paged pool: they carry it whole through their layer loop and the
-adapter writes and reads it at a layer index
-(ops/pallas/paged_attention.py holds the two pairs: the Mosaic page
-write + decode kernel, in place, and the lax scatter + gather). Sharding
-mirrors the
-dense layout: the KV-head axis over the same ``tp`` mesh axis
-(``paged_kv_cache_specs``).
 """
 
 from __future__ import annotations
@@ -54,7 +52,8 @@ from scaletorch_tpu.ops.pallas.paged_attention import (
 
 
 class KVCache(NamedTuple):
-    """Stacked per-layer cache buffers, each [L, B, Hkv, S_max, D].
+    """Stacked per-layer contiguous cache buffers, each
+    [L, B, Hkv, S_max, D]: the reference layout (module docstring).
 
     A NamedTuple so it is a pytree (jit/donate/scan-friendly) and
     unpacks as the plain ``(k, v)`` pair the models' cache-aware
@@ -83,36 +82,15 @@ def kv_cache_shape(cfg, batch: int, max_seq: int) -> Tuple[int, ...]:
 
 
 def kv_cache_bytes(
-    cfg,
-    batch: int,
-    max_seq: int,
-    dtype: Any = None,
-    *,
-    layout: str = "dense",
-    page_size: Optional[int] = None,
-    num_pages: Optional[int] = None,
+    cfg, num_pages: int, page_size: int, dtype: Any = None
 ) -> int:
-    """Total cache footprint (both buffers) — the capacity-planning number
-    the engine logs at startup and the bench HBM column reports.
-
-    Layout-aware: ``dense`` is the per-slot ``[L, B, Hkv, S_max, D]``
-    pair (``batch × max_seq`` positions reserved whether used or not);
-    ``paged`` is the page pool ``[L, n_pages, Hkv, page_size, D]`` pair —
-    pass ``page_size`` and ``num_pages`` (``batch``/``max_seq`` then only
-    size the default pool when ``num_pages`` is None: the
-    dense-equivalent ``batch * ceil(max_seq / page_size)`` + trash).
-    """
-    if layout == "paged":
-        if not page_size or page_size < 1:
-            raise ValueError(
-                f"paged layout needs page_size >= 1, got {page_size}")
-        if num_pages is None:
-            num_pages = batch * ceil_div(max_seq, page_size) + 1
-        shape = paged_kv_cache_shape(cfg, num_pages, page_size)
-    elif layout == "dense":
-        shape = kv_cache_shape(cfg, batch, max_seq)
-    else:
-        raise ValueError(f"unknown cache layout {layout!r}")
+    """Total footprint of the page pool (both buffers,
+    ``[L, num_pages, Hkv, page_size, D]`` each) — the capacity-planning
+    number the engine logs at startup and the memory audit holds the
+    compiled programs to (ST1005)."""
+    if page_size < 1:
+        raise ValueError(f"page_size must be >= 1, got {page_size}")
+    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     dt = jnp.dtype(dtype or getattr(cfg, "dtype", jnp.bfloat16))
     n = 1
     for d in shape:
@@ -124,9 +102,8 @@ def cache_nbytes(cache: Any) -> int:
     """Actual bytes of a cache pytree (arrays OR ShapeDtypeStructs) —
     the measured twin of :func:`kv_cache_bytes`. The jaxlint memory
     tier's ST1005 check (analysis/memory.py) and the quick-tier
-    cross-check tests compare the two so bench_decode's HBM column and
-    the engine's page-budget admission math can never drift from what
-    XLA actually allocates."""
+    cross-check tests compare the two so the engine's page-budget
+    admission math can never drift from what XLA actually allocates."""
     from scaletorch_tpu.utils.misc import tree_bytes
 
     return tree_bytes(cache)
@@ -138,44 +115,13 @@ def init_kv_cache(
     max_seq: int,
     *,
     dtype: Any = None,
-    sharding: Optional[Any] = None,
 ) -> KVCache:
-    """Zeroed cache in the model's compute dtype (bf16 on TPU). With
-    ``sharding`` (a NamedSharding, applied to both buffers, or a KVCache
-    of them) the buffers are created directly on their shards."""
+    """Zeroed contiguous cache in the model's compute dtype (bf16 on
+    TPU): the reference layout, for parity harnesses and
+    single-sequence sampling (``gpt_moe.generate``), never the engine."""
     shape = kv_cache_shape(cfg, batch, max_seq)
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
-    sk, sv = (sharding.k, sharding.v) if isinstance(sharding, KVCache) \
-        else (sharding, sharding)
-    return KVCache(k=jnp.zeros(shape, dt, device=sk),
-                   v=jnp.zeros(shape, dt, device=sv))
-
-
-def kv_cache_specs(
-    *, tp_axis: Optional[str] = "tp", batch_axis: Optional[str] = None
-) -> KVCache:
-    """PartitionSpec pair for the cache buffers — the cache-side
-    counterpart of ``llama_param_specs``: KV heads over ``tp`` (matching
-    the column-parallel k/v projections, so the decode matmuls never
-    re-shard), slots optionally over ``batch_axis`` (dp) for throughput
-    serving. Layer / sequence / head_dim axes stay unsharded — the
-    sequence axis is appended to in place every step.
-    """
-    spec = P(None, batch_axis, tp_axis, None, None)
-    return KVCache(k=spec, v=spec)
-
-
-def kv_cache_shardings(
-    mesh,
-    *,
-    tp_axis: Optional[str] = "tp",
-    batch_axis: Optional[str] = None,
-) -> KVCache:
-    """NamedShardings over ``mesh`` for the cache pair."""
-    specs = kv_cache_specs(tp_axis=tp_axis, batch_axis=batch_axis)
-    return KVCache(
-        k=NamedSharding(mesh, specs.k), v=NamedSharding(mesh, specs.v)
-    )
+    return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
 
 
 def init_mla_cache(attn_cfg, batch: int, max_seq: int,
@@ -187,7 +133,7 @@ def init_mla_cache(attn_cfg, batch: int, max_seq: int,
 
 
 # ---------------------------------------------------------------------------
-# paged layout (ISSUE 10)
+# the page pool
 # ---------------------------------------------------------------------------
 def ceil_div(a: int, b: int) -> int:
     """Page-count rounding, shared by every pages-for-N-tokens site
@@ -241,9 +187,10 @@ def init_paged_kv_cache(
 def paged_kv_cache_specs(
     *, tp_axis: Optional[str] = "tp"
 ) -> PagedKVCache:
-    """PartitionSpec pair for the page pools — the same TP placement as
-    the dense ``kv_cache_specs``: KV heads over ``tp`` (matching the
-    column-parallel k/v projections). The page axis stays unsharded —
+    """PartitionSpec pair for the page pools — the pool-side
+    counterpart of ``llama_param_specs``: KV heads over ``tp`` (matching
+    the column-parallel k/v projections, so the decode matmuls never
+    re-shard). The page axis stays unsharded —
     pages are the unit of host-side ownership and any page must be
     reachable from any slot's table."""
     spec = P(None, None, tp_axis, None, None)
@@ -520,7 +467,7 @@ class PagedKVIO:
     The forwards carry the whole pool pair [L, n_pages, Hkv, page_size,
     D] through their layer loop (``llama.scan_layers_cached``) and touch
     it only through ``write`` and ``attend`` at a layer index, as they
-    touch the dense cache through ``layers.DenseKVIO`` — this object is
+    touch the reference cache through ``layers.DenseKVIO`` — this object is
     constructed INSIDE the jitted step from the traced page tables, so
     tables are data and the step compiles once. Neither method slices a
     layer out: the write is ``paged_write`` (the Mosaic page write, in
@@ -529,7 +476,7 @@ class PagedKVIO:
     (the decode kernel at ``pool.at[layer, page]``; a gather of whole
     pages ``pool[layer, page_tables]`` for prefill and the fallback).
     ``seq_limit`` crops the fallback's gathered view to the engine's
-    ``max_seq`` (bit-identical operand shapes vs the dense engine);
+    ``max_seq`` (the operand shapes of the contiguous reference);
     ``kernel`` forces the pair for both (None = auto, one predicate:
     ``paged_attention.in_place_pair``).
     """

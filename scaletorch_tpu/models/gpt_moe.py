@@ -287,8 +287,8 @@ def generate(
     KV-cached: one full prefill over the prompt, then a ``lax.scan`` of
     single-token decode steps against the cache — O(S·S_max) attention
     per emitted token instead of the old recompute path's O(S_max²·L)
-    full forward per token (retained as ``generate_recompute`` for the
-    tools/bench_decode.py A/B). Static shapes throughout — prefill + one
+    full forward per token (retained as ``generate_recompute``, the
+    greedy parity oracle). Static shapes throughout — prefill + one
     decode-scan compile. prompt: [B, P]. Greedy when temperature == 0.
 
     Sampled continuations draw per-step keys from ``key`` exactly like
@@ -358,7 +358,8 @@ def generate_recompute(
 ) -> jax.Array:
     """The original cache-less sampler: reruns the full O(S²·L) forward
     over the whole block buffer for every emitted token. Kept ONLY as the
-    baseline arm of ``tools/bench_decode.py`` — use ``generate``.
+    oracle ``generate`` is held to (tests/inference/test_decode_parity.py)
+    — use ``generate``.
     """
     b, p = prompt.shape
     total = min(cfg.block_size, p + max_new_tokens)

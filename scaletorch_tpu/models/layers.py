@@ -314,12 +314,14 @@ def write_kv_cache(
 
 
 class DenseKVIO:
-    """K/V adapter of the dense layout: what ``PagedKVIO``
+    """K/V adapter of the contiguous reference cache: what ``PagedKVIO``
     (inference/kv_cache.py) is to the page pool. The cache-aware forwards
     carry the whole stacked cache [L, B, Hkv, S_max, D] through their
     layer loop and touch it only through an adapter's ``write`` and
     ``attend`` at a layer index; this one is ``write_kv_cache`` +
-    ``cached_sdpa_attention`` on that layer."""
+    ``cached_sdpa_attention`` on that layer. Reference and
+    single-sequence sampling (``teacher_forced_decode``,
+    ``gpt_moe.generate``), never the engine: it serves from the pool."""
 
     def write(self, cache: jax.Array, layer: jax.Array, new: jax.Array,
               positions: jax.Array,

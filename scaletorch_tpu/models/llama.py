@@ -544,8 +544,8 @@ def lm_head_weight(
 # inference/decode.py) both lower onto ``forward_cached``: a full-sequence
 # call with positions [B, 0..P) is prefill, a one-token call with positions
 # [B, 1] = p is decode. TP runs via GSPMD — params and cache arrive as
-# NamedSharding-placed global arrays (llama_param_specs + kv_cache_specs)
-# and XLA partitions the plain einsums; no shard_map/tp_axis threading.
+# NamedSharding-placed global arrays (llama_param_specs +
+# paged_kv_cache_specs) and XLA partitions the plain einsums; no shard_map/tp_axis threading.
 
 
 @jax.named_scope("attn")
@@ -578,8 +578,8 @@ def attention_block_cached(
 
     ``kv_io`` is the cache layout: an adapter with
     ``write(cache, layer, kv, positions, write_mask)`` and
-    ``attend(q, cache_k, cache_v, layer, positions)``. None is the dense
-    layout's ``layers.DenseKVIO``; the paged pool's
+    ``attend(q, cache_k, cache_v, layer, positions)``. None is the
+    contiguous reference's ``layers.DenseKVIO``; the serving pool's
     ``inference.kv_cache.PagedKVIO`` carries [L, n_pages, Hkv, page, D]
     instead. The block never slices a layer out of the cache itself:
     whether that costs anything is the adapter's business.

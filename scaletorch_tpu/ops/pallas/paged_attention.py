@@ -46,11 +46,11 @@ one of two pairs of a write and a read:
   * **The lax pair** (``paged_write_kv`` + ``paged_gather_kv`` and the
     models' shared ``cached_sdpa_attention``): a batched scatter at
     ``pool.at[layer, pages, :, offsets, :]`` and a whole-table gather
-    ``pool[layer, page_tables]`` that reconstructs the dense cache
+    ``pool[layer, page_tables]`` that reconstructs the contiguous
     view. This is the off-TPU path and the reference the kernels are
     compared against (tests in interpret mode, ``chip_smoke.py`` on
-    the chip) — it performs the same reduction the dense engine's
-    attention performs. Prefill (S > 1) reads through the gather on
+    the chip) — it performs the same reduction the contiguous
+    reference cache's attention performs. Prefill (S > 1) reads through the gather on
     every platform.
 
 ``paged_write`` and ``paged_attention`` dispatch between them on ONE
@@ -100,7 +100,8 @@ def paged_gather_kv(pool: jax.Array, page_tables: jax.Array,
     page_size, D] pool with the ``layer`` to read (one gather of whole
     pages, the layer never sliced out); page_tables: [B, max_pages]
     -> [B, Hkv, max_pages * page_size, D], logical position ``t`` of slot
-    ``b`` at sequence index ``t`` exactly as the dense layout stores it.
+    ``b`` at sequence index ``t`` exactly as the contiguous reference
+    cache stores it.
     """
     view = pool[page_tables] if layer is None else pool[layer, page_tables]
     b, mp, h, p, d = view.shape  # [B, max_pages, Hkv, page_size, D]
@@ -558,7 +559,7 @@ def paged_attention(
     gather + ``cached_sdpa_attention`` everywhere else — other
     platforms, narrow heads and prefill. ``seq_limit`` crops the
     gathered view to the engine's ``max_seq`` so the fallback's
-    reduction has the dense layout's operand shapes.
+    reduction has the contiguous reference's operand shapes.
     """
     from scaletorch_tpu.models.layers import cached_sdpa_attention
 

@@ -429,7 +429,7 @@ class AdmissionController:
         free = float(snap.get("page_pool_free", 0.0))
         used = float(snap.get("pages_in_use", 0.0))
         total = free + used
-        if total <= 0:  # dense layout: no pool gauge, no pool gate
+        if total <= 0:  # no gauges read yet: no pool gate
             return False
         return free / total < self.free_page_watermark
 

@@ -101,7 +101,7 @@ PARENT_SPAN = "b7ad6b7169203331"
 SERVE_ARGS = [
     "--preset", "tiny", "--param_seed", str(SEED),
     "--max_slots", "2", "--max_seq", "64", "--prefill_len", "16",
-    "--cache_layout", "paged", "--page_size", "4",
+    "--page_size", "4",
     "--serve_port", "0",
     "--telemetry_dir", TELEMETRY_DIR,
     "--slo_path", os.path.join(REPO, "tools", "slo.json"),
@@ -181,7 +181,8 @@ def check_trace_correlation(trace_path: str, *,
         f"{gw_tids}, engine tids {engine_tids}")
     tick_spans = {e["name"] for e in events
                   if e.get("ph") == "X" and e.get("tid") in engine_tids}
-    want_ticks = {"tick", "decode", "prefill"}
+    want_ticks = {"engine.tick", "engine.tick.decode",
+                  "engine.tick.prefill"}
     if disagg:
         want_ticks = want_ticks | {"handoff"}
     assert want_ticks <= tick_spans, (

@@ -50,8 +50,6 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--max_slots", type=int, default=4)
     p.add_argument("--max_seq", type=int, default=128)
     p.add_argument("--prefill_len", type=int, default=64)
-    p.add_argument("--cache_layout", default="paged",
-                   choices=("dense", "paged"))
     p.add_argument("--page_size", type=int, default=16)
     p.add_argument("--replica_id", default="r0")
     p.add_argument("--host", default="127.0.0.1")

@@ -54,8 +54,10 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--max_slots", type=int, default=4)
     p.add_argument("--max_seq", type=int, default=128)
     p.add_argument("--prefill_len", type=int, default=64)
-    p.add_argument("--cache_layout", default="paged",
-                   choices=("dense", "paged"))
+    # does nothing: the engine has one layout. Kept because
+    # benchmarks/lib/serve_cell.py passes it; goes when the harness
+    # stops (ROADMAP D14)
+    p.add_argument("--cache_layout", default="paged", choices=("paged",))
     p.add_argument("--page_size", type=int, default=16)
     p.add_argument("--disagg", default="",
                    help="Disaggregated prefill/decode serving "
@@ -63,8 +65,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "visible devices into a P-device prefill slice "
                         "and a D-device decode slice; 'auto' sizes the "
                         "split from tools/hbm_budget.json's per-phase "
-                        "rows. Paged layout only; in-process replicas "
-                        "only (not --serve_replica_procs).")
+                        "rows. In-process replicas only (not "
+                        "--serve_replica_procs).")
     p.add_argument("--serve_host", default="127.0.0.1")
     p.add_argument("--serve_port", type=int, default=8000)
     p.add_argument("--serve_replicas", type=int, default=1)
@@ -183,7 +185,7 @@ def build_engine(args, cfg, params, tracer=None, device=None):
         max_slots=args.max_slots, max_seq=args.max_seq,
         prefill_len=args.prefill_len,
         sampling=SamplingParams(temperature=0.0),
-        cache_layout=args.cache_layout, page_size=args.page_size,
+        page_size=args.page_size,
         strict_submit=False,
         tracer=tracer,
     )
@@ -222,7 +224,6 @@ def make_replica_spawner(args):
                "--max_slots", str(args.max_slots),
                "--max_seq", str(args.max_seq),
                "--prefill_len", str(args.prefill_len),
-               "--cache_layout", args.cache_layout,
                "--page_size", str(args.page_size),
                "--replica_id", replica_id,
                "--port", "0",
@@ -426,10 +427,6 @@ def main(argv=None) -> int:
 
     args = parse_args(argv)
     if args.disagg:
-        if args.cache_layout != "paged":
-            raise SystemExit(
-                "--disagg requires --cache_layout paged (the page is "
-                "the handoff unit)")
         if args.serve_replica_procs > 0:
             raise SystemExit(
                 "--disagg runs in-process replicas only; drop "

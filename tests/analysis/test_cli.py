@@ -418,7 +418,7 @@ class TestMalformedBaseline:
 
     def test_deep_flags_need_deep_tier(self, capsys):
         for flag in (["--write-budget"], ["--no-budget"],
-                     ["--budget", "x.json"], ["--entries", "decode_step"]):
+                     ["--budget", "x.json"], ["--entries", "paged_decode_step"]):
             rc, _, err = run_cli(
                 capsys, str(FIXTURES / "clean.py"), *flag
             )

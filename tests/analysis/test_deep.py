@@ -39,10 +39,10 @@ class TestManifestAuditsClean:
         _, reports = full_audit
         assert set(reports) == {
             "spmd_train_step", "declarative_train_step",
-            "prefill_step", "decode_step", "paged_decode_step",
+            "paged_prefill_step", "paged_decode_step",
             "disagg_prefill_slice", "disagg_decode_slice",
         }
-        assert len(MANIFEST) == 7
+        assert len(MANIFEST) == 6
 
     def test_entries_filter_skips_unselected_builders(self):
         """A scoped run builds ONLY the selected entries (an unrelated
@@ -50,8 +50,8 @@ class TestManifestAuditsClean:
         ST700, reported against the static manifest."""
         from scaletorch_tpu.analysis.jaxpr_audit import load_entries
 
-        entries, errors = load_entries(["decode_step"])
-        assert [e["name"] for e in entries] == ["decode_step"]
+        entries, errors = load_entries(["paged_decode_step"])
+        assert [e["name"] for e in entries] == ["paged_decode_step"]
         assert errors == []
         entries, errors = load_entries(["nope"])
         assert entries == []
@@ -73,7 +73,7 @@ class TestManifestAuditsClean:
         ANY collective a future change introduces is unbudgeted by
         construction and fails the gate."""
         _, reports = full_audit
-        for name in ("prefill_step", "decode_step"):
+        for name in ("paged_prefill_step", "paged_decode_step"):
             assert reports[name]["hlo"] == {}, reports[name]
             assert reports[name]["total_wire_mb"] == 0.0
 
@@ -134,7 +134,7 @@ class TestBudgetGate:
         rc = main([
             str(REPO / "tests" / "analysis" / "fixtures" / "clean.py"),
             "--no-baseline", "--tier", "deep",
-            "--entries", "decode_step", "--write-budget",
+            "--entries", "paged_decode_step", "--write-budget",
             "--budget", str(path),
         ])
         assert rc == 0

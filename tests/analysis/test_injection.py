@@ -59,10 +59,10 @@ class TestInjectedAxisTypo:
             f.code == "ST102" and "'q_porj'" in f.message for f in findings
         ), [f.render() for f in findings]
 
-    def test_kv_cache_specs_axis_typo_detected(self, tmp_path):
+    def test_paged_kv_cache_specs_axis_typo_detected(self, tmp_path):
         src = (PKG / "inference" / "kv_cache.py").read_text()
         needle = 'tp_axis: Optional[str] = "tp"'
-        assert needle in src, "kv_cache_specs signature moved; update test"
+        assert needle in src, "paged_kv_cache_specs signature moved; update test"
         findings = _analyze_with(
             tmp_path, "kv_cache.py",
             src.replace(needle, 'tp_axis: Optional[str] = "tb"', 1),
@@ -202,8 +202,8 @@ class TestInjectedRetireLeak:
     COMPANIONS = ["inference/kv_cache.py"]
     SRC = PKG / "inference" / "engine.py"
     NEEDLE = (
-        "            for p in self._slot_pages[i]:\n"
-        "                self.allocator.release(p)\n"
+        "        for p in self._slot_pages[i]:\n"
+        "            self.allocator.release(p)\n"
     )
 
     def _ownership(self, tmp_path, src):

@@ -39,7 +39,7 @@ class TestManifestMemoryClean:
         _, reports, _ = full_memory_audit
         assert set(reports) == {
             "spmd_train_step", "declarative_train_step",
-            "prefill_step", "decode_step", "paged_decode_step",
+            "paged_prefill_step", "paged_decode_step",
             "disagg_prefill_slice", "disagg_decode_slice",
         }
 
@@ -51,22 +51,26 @@ class TestManifestMemoryClean:
             assert rep["source"] == "xla", (name, rep)
             assert rep["peak_mb"] > 0, (name, rep)
 
+    @pytest.mark.parametrize("builder", [
+        "audit_entry_paged_prefill", "audit_entry_paged_decode"])
     def test_donated_cache_shows_up_as_alias_savings(
-        self, full_memory_audit
+        self, full_memory_audit, builder
     ):
-        """The decode entries donate their KV cache; the compiled alias
+        """Both engine steps donate the page pool; the compiled alias
         bytes must cover it — the standing form of the ST702 one-shot."""
-        from scaletorch_tpu.inference.decode import audit_entry_decode
+        from scaletorch_tpu.inference import decode
 
         _, reports, _ = full_memory_audit
-        want = audit_entry_decode()["donated_min_mb"]
-        assert reports["decode_step"]["alias_mb"] >= want
+        entry = getattr(decode, builder)()
+        want = entry["donated_min_mb"]
+        assert want > 0
+        assert reports[entry["name"]]["alias_mb"] >= want
 
     def test_top_attribution_has_source_sites(self, full_memory_audit):
         """The liveness walk attributes live-at-peak buffers to source
         lines via eqn provenance — the thing XLA's stats can't do."""
         _, _, tops = full_memory_audit
-        top = tops["prefill_step"]
+        top = tops["paged_prefill_step"]
         assert top, "no top allocations recorded"
         sites = [t.site for t in top]
         assert any(".py:" in s for s in sites), sites
@@ -99,7 +103,7 @@ class TestHbmBudgetGate:
     def test_lost_alias_savings_trip_st1001(self, full_memory_audit):
         _, reports, _ = full_memory_audit
         doc = json.loads(HBM_BUDGET.read_text())
-        doc["entries"]["decode_step"]["alias_mb"] = 5.0
+        doc["entries"]["paged_decode_step"]["alias_mb"] = 5.0
         findings = memory_mod.check_hbm_budget(reports, doc)
         assert any(
             f.code == "ST1001" and "alias" in f.message for f in findings
@@ -124,7 +128,7 @@ class TestHbmBudgetGate:
         doc = json.loads(HBM_BUDGET.read_text())
         doc["entries"]["spmd_train_step"]["jax"] = "0.0.0-not-this-jax"
         doc["entries"]["spmd_train_step"]["peak_mb"] /= 4.0
-        doc["entries"]["decode_step"]["peak_mb"] /= 4.0
+        doc["entries"]["paged_decode_step"]["peak_mb"] /= 4.0
         findings = memory_mod.check_hbm_budget(reports, doc)
         by_entry = {
             ("spmd" if "spmd" in f.message else "decode"): f.severity
@@ -185,7 +189,7 @@ class TestHbmBudgetGate:
         rc = main([
             str(REPO / "tests" / "analysis" / "fixtures" / "clean.py"),
             "--no-baseline", "--tier", "memory",
-            "--entries", "decode_step", "--write-hbm-budget",
+            "--entries", "paged_decode_step", "--write-hbm-budget",
             "--hbm-budget", str(path),
         ])
         assert rc == 0
@@ -196,7 +200,8 @@ class TestHbmBudgetGate:
         # launder stale rows into same-version comparisons
         import jax
 
-        assert merged["entries"]["decode_step"]["jax"] == jax.__version__
+        assert merged["entries"]["paged_decode_step"]["jax"] == \
+            jax.__version__
         assert merged["entries"]["spmd_train_step"]["jax"] == \
             "0.0.0-older-jax"
 
@@ -213,18 +218,18 @@ class TestInjectedRegressions:
         ]
 
     def test_bf16_entry_without_injection_is_clean(self):
-        from scaletorch_tpu.inference.decode import audit_entry_decode
+        from scaletorch_tpu.inference.decode import audit_entry_paged_decode
 
-        findings, _, _ = _audit_one(audit_entry_decode(
+        findings, _, _ = _audit_one(audit_entry_paged_decode(
             compute_dtype="bf16"))
         assert findings == [], [f.render() for f in findings]
 
     def test_fp32_cast_in_bf16_entry_trips_st1003(self):
-        """The motivating precision leak: a full-cache fp32 round trip
+        """The motivating precision leak: a full-pool fp32 round trip
         inside a bf16-configured decode — attributed to its source line."""
-        from scaletorch_tpu.inference.decode import audit_entry_decode
+        from scaletorch_tpu.inference.decode import audit_entry_paged_decode
 
-        findings, _, _ = _audit_one(audit_entry_decode(
+        findings, _, _ = _audit_one(audit_entry_paged_decode(
             compute_dtype="bf16", fp32_residual=True))
         leaks = [f for f in findings if f.code == "ST1003"]
         assert leaks, [f.render() for f in findings]
@@ -232,15 +237,20 @@ class TestInjectedRegressions:
             f.render() for f in leaks
         ]
 
-    def test_shrunken_pool_trips_st1005(self):
+    @pytest.mark.parametrize("builder", [
+        "audit_entry_paged_prefill", "audit_entry_paged_decode"])
+    def test_shrunken_pool_trips_st1005(self, builder):
         """The engine's kv_cache_bytes says N pages, the compiled pool
-        holds fewer — admission math and XLA have drifted apart."""
-        from scaletorch_tpu.inference.decode import audit_entry_paged_decode
+        holds fewer — admission math and XLA have drifted apart. The
+        whole pool at its pinned size is clean (the byte equality)."""
+        from scaletorch_tpu.inference import decode
 
-        findings, _, _ = _audit_one(audit_entry_paged_decode(pool_pages=5))
+        findings, _, _ = _audit_one(getattr(decode, builder)(pool_pages=5))
         assert any(f.code == "ST1005" for f in findings), [
             f.render() for f in findings
         ]
+        findings, _, _ = _audit_one(getattr(decode, builder)())
+        assert findings == [], [f.render() for f in findings]
 
 
 class TestSyntheticRematCheck:
@@ -363,9 +373,8 @@ class TestLivenessEstimator:
 class TestKvCacheBytesCrossCheck:
     """Satellite fix: the engine's capacity math (`kv_cache_bytes`) and
     the buffers the compiled program actually allocates
-    (`cache_nbytes` over the eval_shape tree) must agree exactly, for
-    both layouts — bench_decode's HBM column and page-budget admission
-    depend on it."""
+    (`cache_nbytes` over the eval_shape tree) must agree exactly —
+    page-budget admission depends on it."""
 
     def _cfg(self):
         import jax.numpy as jnp
@@ -379,22 +388,6 @@ class TestKvCacheBytesCrossCheck:
             max_position_embeddings=128,
             dtype=jnp.float32, param_dtype=jnp.float32,
         )
-
-    def test_dense_layout_matches(self):
-        import jax
-        import jax.numpy as jnp
-
-        from scaletorch_tpu.inference.kv_cache import (
-            cache_nbytes,
-            init_kv_cache,
-            kv_cache_bytes,
-        )
-
-        cfg = self._cfg()
-        cache = jax.eval_shape(
-            lambda: init_kv_cache(cfg, 4, 64, dtype=jnp.float32))
-        assert cache_nbytes(cache) == kv_cache_bytes(
-            cfg, 4, 64, jnp.float32)
 
     def test_paged_layout_matches(self):
         import jax
@@ -410,8 +403,7 @@ class TestKvCacheBytesCrossCheck:
         pool = jax.eval_shape(
             lambda: init_paged_kv_cache(cfg, 17, 8, dtype=jnp.float32))
         assert cache_nbytes(pool) == kv_cache_bytes(
-            cfg, 1, 1, jnp.float32, layout="paged", page_size=8,
-            num_pages=17)
+            cfg, 17, 8, jnp.float32)
 
     def test_bf16_halves_both_sides(self):
         import jax
@@ -419,14 +411,14 @@ class TestKvCacheBytesCrossCheck:
 
         from scaletorch_tpu.inference.kv_cache import (
             cache_nbytes,
-            init_kv_cache,
+            init_paged_kv_cache,
             kv_cache_bytes,
         )
 
         cfg = self._cfg()
-        cache = jax.eval_shape(
-            lambda: init_kv_cache(cfg, 2, 32, dtype=jnp.bfloat16))
-        assert cache_nbytes(cache) == kv_cache_bytes(
-            cfg, 2, 32, jnp.bfloat16)
-        assert cache_nbytes(cache) * 2 == kv_cache_bytes(
-            cfg, 2, 32, jnp.float32)
+        pool = jax.eval_shape(
+            lambda: init_paged_kv_cache(cfg, 9, 8, dtype=jnp.bfloat16))
+        assert cache_nbytes(pool) == kv_cache_bytes(
+            cfg, 9, 8, jnp.bfloat16)
+        assert cache_nbytes(pool) * 2 == kv_cache_bytes(
+            cfg, 9, 8, jnp.float32)

@@ -54,7 +54,6 @@ def make_colocated(params, cfg, **kw):
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_len", 8)
     kw.setdefault("sampling", GREEDY)
-    kw.setdefault("cache_layout", "paged")
     kw.setdefault("page_size", 4)
     return InferenceEngine(params, cfg, **kw)
 
@@ -393,8 +392,6 @@ class TestPlanningAndValidation:
 
     def test_constructor_validation(self, tiny_llama):
         cfg, params = tiny_llama
-        with pytest.raises(ValueError, match="paged"):
-            make_disagg(params, cfg, cache_layout="dense")
         with pytest.raises(ValueError, match="slice meshes"):
             make_disagg(params, cfg, mesh=object())
         with pytest.raises(ValueError, match="devices"):

@@ -16,6 +16,7 @@ from scaletorch_tpu.inference import (
     SamplingParams,
 )
 from scaletorch_tpu.models import llama, qwen3_moe
+from tests.inference.oracle import greedy_by_forward
 
 TINY = dict(
     vocab_size=64, hidden_size=32, intermediate_size=64,
@@ -31,35 +32,34 @@ def tiny_llama():
     return cfg, params
 
 
-def ref_greedy(params, cfg, prompt, n):
-    """Oracle: repeated full-sequence forward + argmax."""
-    toks = list(prompt)
-    for _ in range(n):
-        logits = llama.forward(params, jnp.asarray([toks], jnp.int32), cfg)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
+def make_engine(params, cfg, **kw):
+    """Pages of 4 tokens, so that page boundaries fall inside the
+    prompts and generations these tests use."""
+    kw.setdefault("page_size", 4)
+    return InferenceEngine(params, cfg, **kw)
 
 
 class TestEngineCorrectness:
     def test_greedy_matches_full_forward_oracle(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=2, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=2, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         prompts = [[1, 2, 3], [7, 8, 9, 10]]
         ids = [eng.submit(p, max_new_tokens=6) for p in prompts]
         results = eng.run()
         for rid, prompt in zip(ids, prompts):
-            assert results[rid].tokens == ref_greedy(params, cfg, prompt, 6)
+            assert results[rid].tokens == greedy_by_forward(
+                params, cfg, prompt, 6)
             assert results[rid].finish_reason == "length"
             assert results[rid].ttft_s >= 0
 
     def test_eos_stops_early(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
-        expected = ref_greedy(params, cfg, [1, 2, 3], 6)
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
+        expected = greedy_by_forward(params, cfg, [1, 2, 3], 6)
         eos = expected[2]  # generation must stop at eos's FIRST occurrence
         rid = eng.submit([1, 2, 3], max_new_tokens=6, eos_id=eos)
         results = eng.run()
@@ -68,9 +68,9 @@ class TestEngineCorrectness:
 
     def test_max_seq_caps_generation(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=8,
-                              prefill_len=4,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=8,
+                          prefill_len=4,
+                          sampling=SamplingParams(temperature=0.0))
         rid = eng.submit([1, 2, 3], max_new_tokens=100)
         results = eng.run()
         assert results[rid].finish_reason == "max_seq"
@@ -80,7 +80,7 @@ class TestEngineCorrectness:
         cfg, params = tiny_llama
 
         def run_once():
-            eng = InferenceEngine(
+            eng = make_engine(
                 params, cfg, max_slots=2, max_seq=24, prefill_len=8,
                 sampling=SamplingParams(temperature=1.0, top_k=8),
             )
@@ -91,8 +91,8 @@ class TestEngineCorrectness:
 
     def test_submit_validation(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=4,
-                              prefill_len=4)
+        eng = make_engine(params, cfg, max_slots=1, max_seq=4,
+                          prefill_len=4)
         with pytest.raises(ValueError, match="at least one token"):
             eng.submit([])
         with pytest.raises(ValueError, match="prefill buffer"):
@@ -108,9 +108,9 @@ class TestContinuousBatching:
         freed slots mid-run; the decode step must have compiled exactly
         once by the end — the jitted step never retraces."""
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=2, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=2, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         prompts = [[1, 2, 3], [9, 8], [4, 5, 6, 7], [11], [20, 21]]
         lens = [3, 5, 2, 6, 4]
         ids = [eng.submit(p, max_new_tokens=n)
@@ -120,25 +120,27 @@ class TestContinuousBatching:
         assert eng.prefill_compile_count == 1
         assert eng.metrics.prefill_calls >= 2  # admissions happened mid-run
         for rid, prompt, n in zip(ids, prompts, lens):
-            assert results[rid].tokens == ref_greedy(params, cfg, prompt, n)
+            assert results[rid].tokens == greedy_by_forward(
+                params, cfg, prompt, n)
 
     def test_slot_reuse_does_not_leak_state(self, tiny_llama):
         """A request admitted into a reused slot sees none of the
         previous occupant's cache: its output equals a fresh engine's."""
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         eng.submit([1, 2, 3], max_new_tokens=4)
         second = eng.submit([9, 8, 7], max_new_tokens=4)
         results = eng.run()
-        assert results[second].tokens == ref_greedy(params, cfg, [9, 8, 7], 4)
+        assert results[second].tokens == greedy_by_forward(
+            params, cfg, [9, 8, 7], 4)
 
     def test_metrics_accounting(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=2, max_seq=24,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=2, max_seq=24,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         eng.submit([1, 2], max_new_tokens=3)
         eng.submit([3, 4], max_new_tokens=5)
         eng.run()
@@ -154,9 +156,9 @@ class TestContinuousBatching:
 
         cfg, params = tiny_llama
         mon = SystemMonitor(max_records=16)
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=24,
-                              prefill_len=8, monitor=mon, monitor_every=1,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=24,
+                          prefill_len=8, monitor=mon, monitor_every=1,
+                          sampling=SamplingParams(temperature=0.0))
         eng.submit([1, 2], max_new_tokens=4)
         eng.run()
         assert mon.records
@@ -175,10 +177,10 @@ class TestTokenHookAndCancel:
         def hook(slot, request_id, token_ids):
             streamed.setdefault(request_id, []).extend(token_ids)
 
-        eng = InferenceEngine(params, cfg, max_slots=2, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0),
-                              on_tokens=hook)
+        eng = make_engine(params, cfg, max_slots=2, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0),
+                          on_tokens=hook)
         prompts = [[1, 2, 3], [9, 8], [4, 5, 6, 7], [11]]
         ids = [eng.submit(p, max_new_tokens=n)
                for p, n in zip(prompts, [6, 3, 5, 4])]
@@ -192,7 +194,7 @@ class TestTokenHookAndCancel:
         end: drive tick-by-tick and watch tokens arrive incrementally."""
         cfg, params = tiny_llama
         seen = []
-        eng = InferenceEngine(
+        eng = make_engine(
             params, cfg, max_slots=1, max_seq=32, prefill_len=8,
             sampling=SamplingParams(temperature=0.0),
             on_tokens=lambda s, r, t: seen.extend(t))
@@ -210,10 +212,10 @@ class TestTokenHookAndCancel:
         def bad_hook(slot, request_id, token_ids):
             raise RuntimeError("consumer bug")
 
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0),
-                              on_tokens=bad_hook)
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0),
+                          on_tokens=bad_hook)
         rid = eng.submit([1, 2, 3], max_new_tokens=4)
         results = eng.run()
         assert results[rid].outcome == "ok"
@@ -221,9 +223,9 @@ class TestTokenHookAndCancel:
 
     def test_cancel_queued_and_mid_decode(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         active = eng.submit([1, 2, 3], max_new_tokens=10)
         queued = eng.submit([4, 5], max_new_tokens=10)
         eng.step()                      # admit + first decode of `active`
@@ -241,10 +243,9 @@ class TestTokenHookAndCancel:
 
     def test_cancel_releases_pages(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8, cache_layout="paged",
-                              page_size=4,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         rid = eng.submit([1, 2, 3, 4, 5], max_new_tokens=20)
         eng.step()
         assert eng.metrics.pages_in_use > 0
@@ -257,9 +258,9 @@ class TestTokenHookAndCancel:
         """The bridge-owned drain: stop_admissions() blocks submits but
         the owner keeps ticking in-flight work to completion."""
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8, strict_submit=False,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8, strict_submit=False,
+                          sampling=SamplingParams(temperature=0.0))
         rid = eng.submit([1, 2, 3], max_new_tokens=4)
         eng.stop_admissions()
         late = eng.submit([7], max_new_tokens=2)
@@ -279,9 +280,9 @@ class TestShardedServing:
         from scaletorch_tpu.parallel.tensor_parallel import llama_param_specs
 
         cfg, params = tiny_llama
-        e0 = InferenceEngine(params, cfg, max_slots=2, max_seq=24,
-                             prefill_len=8,
-                             sampling=SamplingParams(temperature=0.0))
+        e0 = make_engine(params, cfg, max_slots=2, max_seq=24,
+                         prefill_len=8,
+                         sampling=SamplingParams(temperature=0.0))
         r0 = e0.submit([1, 2, 3], max_new_tokens=6)
         expected = e0.run()[r0].tokens
 
@@ -292,9 +293,9 @@ class TestShardedServing:
             is_leaf=lambda x: isinstance(x, P),
         )
         params_sh = jax.tree.map(jax.device_put, params, shardings)
-        eng = InferenceEngine(params_sh, cfg, max_slots=2, max_seq=24,
-                              prefill_len=8, mesh=mm.mesh, tp_axis="tp",
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params_sh, cfg, max_slots=2, max_seq=24,
+                          prefill_len=8, mesh=mm.mesh, tp_axis="tp",
+                          sampling=SamplingParams(temperature=0.0))
         assert eng.cache.k.sharding.spec[2] == "tp"
         rid = eng.submit([1, 2, 3], max_new_tokens=6)
         results = eng.run()
@@ -309,19 +310,14 @@ class TestShardedServing:
             tie_word_embeddings=False,
         )
         params = qwen3_moe.init_params(jax.random.PRNGKey(0), cfg)
-        eng = InferenceEngine(params, cfg, max_slots=2, max_seq=24,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=2, max_seq=24,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         rid = eng.submit([1, 2, 3], max_new_tokens=5)
         results = eng.run()
         assert len(results[rid].tokens) == 5
-        # oracle: repeated full forward
-        toks = [1, 2, 3]
-        for _ in range(5):
-            logits = qwen3_moe.forward(
-                params, jnp.asarray([toks], jnp.int32), cfg)
-            toks.append(int(jnp.argmax(logits[0, -1])))
-        assert results[rid].tokens == toks[3:]
+        assert results[rid].tokens == greedy_by_forward(
+            params, cfg, [1, 2, 3], 5)
 
 
 class TestRequestScopedObservability:
@@ -334,9 +330,9 @@ class TestRequestScopedObservability:
 
     def test_result_latency_attribution_and_histograms(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=2, max_seq=32,
-                              prefill_len=8,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=2, max_seq=32,
+                          prefill_len=8,
+                          sampling=SamplingParams(temperature=0.0))
         rid = eng.submit([1, 2, 3], max_new_tokens=6)
         result = eng.run()[rid]
         assert result.queue_wait_s is not None and result.queue_wait_s >= 0
@@ -360,9 +356,9 @@ class TestRequestScopedObservability:
         cfg, params = tiny_llama
 
         def run(tracer, trace_id):
-            eng = InferenceEngine(params, cfg, max_slots=2, max_seq=32,
-                                  prefill_len=8, tracer=tracer,
-                                  sampling=SamplingParams(temperature=0.0))
+            eng = make_engine(params, cfg, max_slots=2, max_seq=32,
+                              prefill_len=8, tracer=tracer,
+                              sampling=SamplingParams(temperature=0.0))
             rid = eng.submit([1, 2, 3], max_new_tokens=6,
                              trace_id=trace_id)
             result = eng.run()[rid]
@@ -392,10 +388,10 @@ class TestRequestScopedObservability:
 
         cfg, params = tiny_llama
         tracer = SpanTracer(path=None, role="serve")
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=16,
-                              prefill_len=8, tracer=tracer,
-                              strict_submit=False,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=16,
+                          prefill_len=8, tracer=tracer,
+                          strict_submit=False,
+                          sampling=SamplingParams(temperature=0.0))
         # rejected at submit: request + queued both close immediately
         bad = eng.submit([], trace_id="11" * 16)
         assert eng.result(bad).outcome == "rejected"
@@ -414,9 +410,9 @@ class TestRequestScopedObservability:
         not feed the e2e tail estimate — only served (ok/timeout)
         requests do."""
         cfg, params = tiny_llama
-        eng = InferenceEngine(params, cfg, max_slots=1, max_seq=32,
-                              prefill_len=8, strict_submit=False,
-                              sampling=SamplingParams(temperature=0.0))
+        eng = make_engine(params, cfg, max_slots=1, max_seq=32,
+                          prefill_len=8, strict_submit=False,
+                          sampling=SamplingParams(temperature=0.0))
         eng.submit([])  # rejected at submit
         rid = eng.submit([1, 2], max_new_tokens=20)
         eng.step()      # admitted, first token

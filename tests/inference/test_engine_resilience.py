@@ -28,11 +28,12 @@ from scaletorch_tpu.inference import (
     InferenceEngine,
     SamplingParams,
     ServingFaultInjector,
-    make_prefill_step,
+    make_paged_prefill_step,
     make_serving_watchdog,
 )
 from scaletorch_tpu.models import llama
 from scaletorch_tpu.resilience import PreemptionHandler
+from tests.inference.oracle import greedy_by_forward
 
 TINY = dict(
     vocab_size=64, hidden_size=32, intermediate_size=64,
@@ -55,16 +56,9 @@ def make_engine(tiny_llama, **kw):
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_len", 8)
     kw.setdefault("sampling", GREEDY)
+    # pages of 4 tokens: page boundaries fall inside these prompts
+    kw.setdefault("page_size", 4)
     return InferenceEngine(params, cfg, **kw)
-
-
-def ref_greedy(params, cfg, prompt, n):
-    """Oracle: repeated full-sequence forward + argmax."""
-    toks = list(prompt)
-    for _ in range(n):
-        logits = llama.forward(params, jnp.asarray([toks], jnp.int32), cfg)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
 
 
 def assert_conserved(eng):
@@ -92,7 +86,8 @@ class TestOutcomeTaxonomy:
         results = eng.run(max_steps=6)
         cfg, params = tiny_llama
         assert results[done].outcome == "ok"
-        assert results[done].tokens == ref_greedy(params, cfg, [1, 2, 3], 2)
+        assert results[done].tokens == greedy_by_forward(
+            params, cfg, [1, 2, 3], 2)
         assert results[hung].outcome == "aborted"
         assert results[hung].finish_reason == "aborted"
         assert len(results[hung].tokens) > 0     # partials attached
@@ -206,9 +201,9 @@ class TestQuarantine:
         assert_conserved(eng)
 
     def test_slot_reuse_after_quarantine_is_clean(self, tiny_llama):
-        """The quarantined slot's cache lines are mask-cleared: the next
-        occupant's output equals a fresh engine's, and the decode step
-        still never retraced."""
+        """The quarantined slot's mutable pages are mask-cleared: the
+        next occupant's output equals the plain forward's, and the decode
+        step still never retraced."""
         cfg, params = tiny_llama
         inj = ServingFaultInjector(nan_logits_at_step=2, nan_logits_slot=0)
         eng = make_engine(tiny_llama, max_slots=1, injector=inj)
@@ -217,7 +212,8 @@ class TestQuarantine:
         results = eng.run()
         assert results[poisoned].outcome == "quarantined"
         assert results[reused].outcome == "ok"
-        assert results[reused].tokens == ref_greedy(params, cfg, [9, 8, 7], 4)
+        assert results[reused].tokens == greedy_by_forward(
+            params, cfg, [9, 8, 7], 4)
         assert eng.decode_compile_count == 1
         assert_conserved(eng)
 
@@ -227,25 +223,26 @@ class TestQuarantine:
         cfg, params = tiny_llama
         base = llama.forward_cached
 
-        def poisoned_forward(params, tokens, cfg, cache, *, positions,
-                             write_mask=None):
-            logits, new_cache = base(params, tokens, cfg, cache,
-                                     positions=positions,
-                                     write_mask=write_mask)
+        def poisoned_forward(params, tokens, cfg, cache, **kw):
+            logits, new_cache = base(params, tokens, cfg, cache, **kw)
             bad = jnp.any(tokens == 63, axis=-1)  # magic poison token
             logits = jnp.where(bad[:, None, None], jnp.nan, logits)
             return logits, new_cache
 
-        prefill = make_prefill_step(cfg, GREEDY, forward_fn=poisoned_forward)
-        from scaletorch_tpu.inference.kv_cache import init_kv_cache
-        cache = init_kv_cache(cfg, 2, 16, dtype=jnp.float32)
+        prefill = make_paged_prefill_step(
+            cfg, GREEDY, page_size=4, seq_limit=16,
+            forward_fn=poisoned_forward)
+        from scaletorch_tpu.inference.kv_cache import init_paged_kv_cache
+        # two slots x four pages of 4 tokens, page 0 the trash page
+        pool = init_paged_kv_cache(cfg, 9, 4, dtype=jnp.float32)
+        tables = np.arange(1, 9, dtype=np.int32).reshape(2, 4)
         tokens = np.zeros((2, 8), np.int32)
         tokens[0, :3] = [1, 2, 63]      # poisoned prompt
         tokens[1, :3] = [1, 2, 3]
         _, _, finite, _ = prefill(
             params, jnp.asarray(tokens), jnp.asarray([3, 3], jnp.int32),
-            jnp.asarray([True, True]), cache,
-            jnp.zeros((2, 2), jnp.uint32),
+            jnp.zeros(2, jnp.int32), jnp.asarray([True, True]),
+            jnp.asarray(tables), pool, jnp.zeros((2, 2), jnp.uint32),
         )
         assert list(np.asarray(finite)) == [False, True]
 
@@ -256,11 +253,8 @@ class TestQuarantine:
         cfg, params = tiny_llama
         base = llama.forward_cached
 
-        def poisoned_forward(params, tokens, cfg, cache, *, positions,
-                             write_mask=None):
-            logits, new_cache = base(params, tokens, cfg, cache,
-                                     positions=positions,
-                                     write_mask=write_mask)
+        def poisoned_forward(params, tokens, cfg, cache, **kw):
+            logits, new_cache = base(params, tokens, cfg, cache, **kw)
             bad = jnp.any(tokens == 63, axis=-1)
             logits = jnp.where(bad[:, None, None], jnp.nan, logits)
             return logits, new_cache
@@ -273,7 +267,8 @@ class TestQuarantine:
         assert results[poison].tokens == []
         assert "prefill" in results[poison].detail
         assert results[normal].outcome == "ok"
-        assert results[normal].tokens == ref_greedy(params, cfg, [7, 8, 9], 4)
+        assert results[normal].tokens == greedy_by_forward(
+            params, cfg, [7, 8, 9], 4)
         assert_conserved(eng)
 
 
@@ -323,7 +318,8 @@ class TestStorms:
         rid = eng.submit([4, 5, 6], max_new_tokens=4)
         results = eng.run()
         assert results[rid].outcome == "ok"
-        assert results[rid].tokens == ref_greedy(params, cfg, [4, 5, 6], 4)
+        assert results[rid].tokens == greedy_by_forward(
+            params, cfg, [4, 5, 6], 4)
         assert eng.decode_compile_count == 1
         assert_conserved(eng)
 

@@ -1,6 +1,6 @@
-"""KV-cache containers: shapes, dtypes, sharding specs, write semantics."""
+"""KV-cache containers: shapes, dtypes, write semantics of the
+contiguous reference cache (the page pool: test_paged_cache.py)."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,8 +11,6 @@ from scaletorch_tpu.inference.kv_cache import (
     init_mla_cache,
     kv_cache_bytes,
     kv_cache_shape,
-    kv_cache_shardings,
-    kv_cache_specs,
 )
 from scaletorch_tpu.models.attention.base import AttentionConfig
 from scaletorch_tpu.models.gpt_moe import GPTMoEConfig
@@ -46,34 +44,16 @@ class TestShapes:
         assert not np.any(np.asarray(cache.v))
 
     def test_bytes_accounting(self):
-        assert kv_cache_bytes(TINY, 2, 16) == 2 * 3 * 2 * 2 * 16 * 8 * 4
-        assert kv_cache_bytes(TINY, 2, 16, dtype=jnp.bfloat16) == \
-            kv_cache_bytes(TINY, 2, 16) // 2
+        """Nine pages of four tokens: k and v, three layers, two KV
+        heads of eight lanes, four bytes each."""
+        assert kv_cache_bytes(TINY, 9, 4) == 2 * 3 * 9 * 2 * 4 * 8 * 4
+        assert kv_cache_bytes(TINY, 9, 4, dtype=jnp.bfloat16) == \
+            kv_cache_bytes(TINY, 9, 4) // 2
 
     def test_mla_latent_only(self):
         acfg = AttentionConfig(embed_dim=64, num_heads=8, kv_lora_rank=16)
         cache = init_mla_cache(acfg, 2, 12)
         assert cache.latent.shape == (2, 12, 16)
-
-
-class TestSharding:
-    def test_specs_head_axis_over_tp(self):
-        specs = kv_cache_specs(tp_axis="tp")
-        assert specs.k == jax.sharding.PartitionSpec(None, None, "tp", None, None)
-        assert specs.k == specs.v
-
-    def test_sharded_init_on_virtual_mesh(self, mm_factory):
-        mm = mm_factory(tp=2, dp=4)
-        shardings = kv_cache_shardings(mm.mesh, tp_axis="tp")
-        cache = init_kv_cache(TINY, 2, 16, sharding=shardings)
-        # KV-head axis (2) split over tp=2
-        assert cache.k.sharding.spec[2] == "tp"
-
-    def test_batch_axis_sharding(self, mm_factory):
-        mm = mm_factory(tp=2, dp=4)
-        shardings = kv_cache_shardings(mm.mesh, tp_axis="tp", batch_axis="dp")
-        cache = init_kv_cache(TINY, 4, 16, sharding=shardings)
-        assert cache.k.sharding.spec[1] == "dp"
 
 
 class TestWriteKvCache:

@@ -38,8 +38,7 @@ LAYERS, TOP_K = TINY["num_hidden_layers"], TINY["num_experts_per_tok"]
 def make_engine(cfg, params, **kw):
     return InferenceEngine(
         params, cfg, max_slots=4, max_seq=64, prefill_len=32,
-        sampling=SamplingParams(temperature=0.0), cache_layout="paged",
-        page_size=8, strict_submit=False, **kw)
+        sampling=SamplingParams(temperature=0.0), page_size=8, strict_submit=False, **kw)
 
 
 @pytest.fixture(scope="module")
@@ -176,14 +175,14 @@ def _http(port, path, payload=None):
 
 
 def test_serve_cli_serves_olmoe_tiny_and_shows_the_moe_numbers():
-    """``scripts/serve.py --preset olmoe-tiny --cache_layout paged``:
+    """``scripts/serve.py --preset olmoe-tiny``:
     one completion through the gateway, then the engine's routing
     counters on ``/metrics``."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "scripts", "serve.py"),
-         "--preset", "olmoe-tiny", "--cache_layout", "paged",
+         "--preset", "olmoe-tiny",
          "--page_size", "8", "--max_slots", "2", "--max_seq", "64",
          "--prefill_len", "16", "--serve_port", "0"],
         cwd=REPO, env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,

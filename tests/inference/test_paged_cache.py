@@ -4,7 +4,7 @@ correctness (longest match, page-boundary splits, refcount-gated
 eviction), the page scatter/gather pair against the dense cache ops,
 the Pallas paged-decode kernel in interpret mode against the lax
 fallback oracle, the paged teacher-forced parity harness, and the
-layout-aware ``kv_cache_bytes`` fix. Quick tier, CPU.
+pool's ``kv_cache_bytes``. Quick tier, CPU.
 """
 
 import random
@@ -22,7 +22,6 @@ from scaletorch_tpu.inference.kv_cache import (
     PageAllocator,
     RadixPrefixCache,
     kv_cache_bytes,
-    kv_cache_shape,
     paged_kv_cache_shape,
 )
 from scaletorch_tpu.models import llama, qwen3
@@ -617,13 +616,12 @@ class TestEngineSaysWhichPair:
     paged step programs are built with, decided by the platform and the
     head_dim alone (the AOT switch stands in for the platform here)."""
 
-    @pytest.mark.parametrize("tpu,head_dim,layout,want", [
-        (False, 128, "paged", 0),   # no chip: scatter + gather
-        (True, 128, "paged", 1),    # the Mosaic pair
-        (True, 16, "paged", 0),     # no kernel serves a narrow head
-        (True, 128, "dense", 0),    # no pool at all
-    ], ids=["cpu", "tpu-wide", "tpu-narrow", "tpu-dense"])
-    def test_gauge(self, monkeypatch, tpu, head_dim, layout, want):
+    @pytest.mark.parametrize("tpu,head_dim,want", [
+        (False, 128, 0),   # no chip: scatter + gather
+        (True, 128, 1),    # the Mosaic pair
+        (True, 16, 0),     # no kernel serves a narrow head
+    ], ids=["cpu", "tpu-wide", "tpu-narrow"])
+    def test_gauge(self, monkeypatch, tpu, head_dim, want):
         from scaletorch_tpu.inference import InferenceEngine, SamplingParams
 
         monkeypatch.setenv("SCALETORCH_TPU_FORCE_PALLAS", "1" if tpu else "0")
@@ -632,68 +630,63 @@ class TestEngineSaysWhichPair:
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
         engine = InferenceEngine(
             params, cfg, sampling=SamplingParams(temperature=0.0),
-            max_slots=2, max_seq=16,
-            **(dict(cache_layout="paged", page_size=4)
-               if layout == "paged" else {}))
+            max_slots=2, max_seq=16, page_size=4)
         assert engine.metrics.snapshot()["paged_pool_in_place"] == want
 
 
-class TestCacheBytesLayouts:
-    """Satellite fix: ``kv_cache_bytes`` reports the layout actually
-    deployed, not always the dense one."""
+class TestPoolBytes:
+    """``kv_cache_bytes`` is the page pool's footprint: what the engine
+    logs, what admission reasons about, what XLA allocates."""
 
-    def test_dense_unchanged(self):
+    def test_defaults_to_the_config_dtype(self):
         cfg = llama.LlamaConfig(**TINY)
-        shape = kv_cache_shape(cfg, 4, 128)
-        n = int(np.prod(shape))
-        assert kv_cache_bytes(cfg, 4, 128, jnp.float32) == 2 * n * 4
+        assert cfg.dtype == jnp.float32
+        assert kv_cache_bytes(cfg, 33, 16) == \
+            kv_cache_bytes(cfg, 33, 16, jnp.float32)
+        assert kv_cache_bytes(cfg, 33, 16, jnp.bfloat16) * 2 == \
+            kv_cache_bytes(cfg, 33, 16)
 
     def test_paged_pool_bytes(self):
         cfg = llama.LlamaConfig(**TINY)
         shape = paged_kv_cache_shape(cfg, 33, 16)
         n = int(np.prod(shape))
-        got = kv_cache_bytes(cfg, 4, 128, jnp.float32, layout="paged",
-                             page_size=16, num_pages=33)
-        assert got == 2 * n * 4
+        assert kv_cache_bytes(cfg, 33, 16, jnp.float32) == 2 * n * 4
 
-    def test_paged_defaults_to_dense_equivalent_pool(self):
-        cfg = llama.LlamaConfig(**TINY)
-        # batch * ceil(max_seq / page_size) + 1 trash page
-        auto = kv_cache_bytes(cfg, 4, 120, jnp.float32, layout="paged",
-                              page_size=16)
-        explicit = kv_cache_bytes(cfg, 4, 120, jnp.float32, layout="paged",
-                                  page_size=16, num_pages=4 * 8 + 1)
-        assert auto == explicit
+    def test_engine_default_pool_holds_every_slot_full(self):
+        """``num_pages=None``: max_slots * ceil(max_seq / page_size)
+        pages + 1 trash page, whatever max_seq leaves of the last."""
+        from scaletorch_tpu.inference import InferenceEngine
+        from scaletorch_tpu.inference.kv_cache import cache_nbytes
 
-    def test_invalid_layouts_raise(self):
         cfg = llama.LlamaConfig(**TINY)
-        with pytest.raises(ValueError, match="unknown cache layout"):
-            kv_cache_bytes(cfg, 1, 8, layout="ragged")
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        engine = InferenceEngine(params, cfg, max_slots=4, max_seq=120,
+                                 page_size=16)
+        assert engine.num_pages == 4 * 8 + 1
+        assert cache_nbytes(engine.cache) == kv_cache_bytes(
+            cfg, 4 * 8 + 1, 16)
+
+    def test_invalid_page_size_raises(self):
+        cfg = llama.LlamaConfig(**TINY)
         with pytest.raises(ValueError, match="page_size"):
-            kv_cache_bytes(cfg, 1, 8, layout="paged")
+            kv_cache_bytes(cfg, 8, 0)
 
     def test_engine_pool_matches_admission_math(self):
         """The ISSUE 15 unification: the bytes the engine's admission /
         shedding math reasons about (``kv_cache_bytes``) and the bytes
         the engine actually allocated (``cache_nbytes`` over the live
-        pool/cache) must agree exactly, for both layouts — the jaxlint
-        memory tier's ST1005 pins the same identity over the COMPILED
-        audit entries, so bench_decode's HBM column can never drift."""
+        pool) must agree exactly, for the default pool and for one cut
+        below it — the jaxlint memory tier's ST1005 pins the same
+        identity over the COMPILED audit entries."""
         from scaletorch_tpu.inference import InferenceEngine, SamplingParams
         from scaletorch_tpu.inference.kv_cache import cache_nbytes
 
         cfg = llama.LlamaConfig(**TINY)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)
-        paged = InferenceEngine(
-            params, cfg, sampling=SamplingParams(temperature=0.0),
-            max_slots=2, max_seq=16, cache_layout="paged", page_size=4,
-        )
-        assert cache_nbytes(paged.cache) == kv_cache_bytes(
-            cfg, 2, 16, cfg.dtype, layout="paged", page_size=4,
-            num_pages=paged.num_pages)
-        dense = InferenceEngine(
-            params, cfg, sampling=SamplingParams(temperature=0.0),
-            max_slots=2, max_seq=16,
-        )
-        assert cache_nbytes(dense.cache) == kv_cache_bytes(
-            cfg, 2, 16, cfg.dtype)
+        for num_pages in (None, 5):
+            engine = InferenceEngine(
+                params, cfg, sampling=SamplingParams(temperature=0.0),
+                max_slots=2, max_seq=16, page_size=4, num_pages=num_pages,
+            )
+            assert cache_nbytes(engine.cache) == kv_cache_bytes(
+                cfg, engine.num_pages, 4, cfg.dtype)

@@ -54,8 +54,7 @@ def make_engine(tiny_llama, **kw):
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_len", 8)
     kw.setdefault("sampling", SamplingParams(temperature=0.0))
-    if kw.get("cache_layout") == "paged":
-        kw.setdefault("page_size", 4)
+    kw.setdefault("page_size", 4)
     return InferenceEngine(params, cfg, **kw)
 
 
@@ -69,8 +68,7 @@ class Collected:
         self.records.append((kind, record))
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_the_three_clocks_sum_to_the_decode_life(tiny_llama, layout):
+def test_the_three_clocks_sum_to_the_decode_life(tiny_llama):
     """Two overlapping requests, the second admitted while the first
     decodes: for each, stall + device wait + host is its first token to
     its last, and the first stood still for the second's admission."""
@@ -79,7 +77,7 @@ def test_the_three_clocks_sum_to_the_decode_life(tiny_llama, layout):
     def on_tokens(slot, request_id, tokens):
         token_times.setdefault(request_id, []).append(time.monotonic())
 
-    eng = make_engine(tiny_llama, cache_layout=layout, on_tokens=on_tokens)
+    eng = make_engine(tiny_llama, on_tokens=on_tokens)
     # both steps compiled before anything is timed: a token reaches the
     # hook once the next step is dispatched, and a first dispatch compiles
     eng.submit([9, 9], max_new_tokens=3)
@@ -140,7 +138,7 @@ def test_slow_tick_reports_its_phases_once(tiny_llama, monkeypatch):
     slow ticks."""
     records = Collected()
     eng = make_engine(
-        tiny_llama, cache_layout="paged", exporter=records,
+        tiny_llama, exporter=records,
         monitor_every=10_000,
         injector=ServingFaultInjector(
             slow_decode_at_step=9, slow_decode_seconds=0.4))
@@ -214,7 +212,7 @@ def test_a_profiler_trace_holds_every_phase_with_nothing_configured(
     from scaletorch_tpu.serving.gateway import EngineWorker
     from scaletorch_tpu.serving.protocol import GenerateRequest
 
-    eng = make_engine(tiny_llama, cache_layout="paged")
+    eng = make_engine(tiny_llama)
     assert eng.tracer is None and eng.exporter is None
     eng.submit([1, 2, 3], max_new_tokens=2)
     eng.run()                                 # compile outside the trace

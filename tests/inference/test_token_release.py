@@ -23,13 +23,12 @@ def tiny_llama():
     return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def make_engine(tiny_llama, layout, **kw):
+def make_engine(tiny_llama, **kw):
     cfg, params = tiny_llama
-    if layout == "paged":
-        kw.setdefault("page_size", 4)
+    kw.setdefault("page_size", 4)
     return InferenceEngine(
         params, cfg, max_slots=2, max_seq=32, prefill_len=8,
-        sampling=SamplingParams(temperature=0.0), cache_layout=layout, **kw)
+        sampling=SamplingParams(temperature=0.0), **kw)
 
 
 class Recorder:
@@ -49,12 +48,11 @@ class Recorder:
                          set(self.engine._tick_phase_s)))
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
 class TestHeldTokens:
     def test_a_decode_steps_tokens_wait_for_the_next_dispatch(
-            self, tiny_llama, layout):
+            self, tiny_llama):
         rec = Recorder()
-        eng = make_engine(tiny_llama, layout, on_tokens=rec.tokens)
+        eng = make_engine(tiny_llama, on_tokens=rec.tokens)
         rec.engine = eng
         rid = eng.submit([1, 2, 3], max_new_tokens=6)
         eng.step()
@@ -74,12 +72,12 @@ class TestHeldTokens:
         assert eng._held_tokens == []
 
     def test_a_requests_last_token_comes_with_its_result(
-            self, tiny_llama, layout):
+            self, tiny_llama):
         """The stream sees every token, then the terminal result: the
         retiring request's held tokens are handed over when its result
         is recorded, the other slot's stay held."""
         rec = Recorder()
-        eng = make_engine(tiny_llama, layout, on_tokens=rec.tokens)
+        eng = make_engine(tiny_llama, on_tokens=rec.tokens)
         rec.engine = eng
         short = eng.submit([1, 2, 3], max_new_tokens=2)
         long = eng.submit([4, 5, 6], max_new_tokens=8)
@@ -90,10 +88,10 @@ class TestHeldTokens:
         assert [h[1] for h in eng._held_tokens] == [long]
 
     def test_an_admission_hands_the_held_tokens_over_first(
-            self, tiny_llama, layout):
+            self, tiny_llama):
         """No token waits for a prefill call."""
         rec = Recorder()
-        eng = make_engine(tiny_llama, layout, on_tokens=rec.tokens)
+        eng = make_engine(tiny_llama, on_tokens=rec.tokens)
         rec.engine = eng
         first = eng.submit([1, 2, 3], max_new_tokens=12)
         eng.step()
@@ -108,9 +106,9 @@ class TestHeldTokens:
         assert "engine.tick.prefill" not in handed[3]  # before the call
 
     def test_on_dispatched_fires_once_per_decode_step_after_the_tokens(
-            self, tiny_llama, layout):
+            self, tiny_llama):
         rec = Recorder()
-        eng = make_engine(tiny_llama, layout, on_tokens=rec.tokens)
+        eng = make_engine(tiny_llama, on_tokens=rec.tokens)
         rec.engine = eng
         eng.on_dispatched = rec.dispatched
         eng.submit([1, 2, 3], max_new_tokens=5)
@@ -125,9 +123,9 @@ class TestHeldTokens:
                 assert "engine.tick.decode" in e[3]
 
     def test_cancel_hands_over_before_the_aborted_result(
-            self, tiny_llama, layout):
+            self, tiny_llama):
         rec = Recorder()
-        eng = make_engine(tiny_llama, layout, on_tokens=rec.tokens)
+        eng = make_engine(tiny_llama, on_tokens=rec.tokens)
         rec.engine = eng
         rid = eng.submit([1, 2, 3], max_new_tokens=12)
         eng.step()
@@ -140,14 +138,14 @@ class TestHeldTokens:
 
 
 def test_without_a_hook_nothing_is_held(tiny_llama):
-    eng = make_engine(tiny_llama, "paged")
+    eng = make_engine(tiny_llama)
     eng.submit([1, 2, 3], max_new_tokens=4)
     eng.step()
     assert eng._held_tokens == []
 
 
 def test_base_keys_are_uploaded_once_per_admission(tiny_llama):
-    eng = make_engine(tiny_llama, "paged")
+    eng = make_engine(tiny_llama)
     eng.submit([1, 2, 3], max_new_tokens=6, seed=5)
     eng.step()
     keys = eng._base_keys_device()

@@ -39,7 +39,6 @@ def make_engine(params, cfg, **kw):
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_len", 12)
     kw.setdefault("sampling", GREEDY)
-    kw.setdefault("cache_layout", "paged")
     kw.setdefault("page_size", 4)
     return InferenceEngine(params, cfg, **kw)
 
@@ -95,11 +94,15 @@ class TestExport:
         for k_bytes, v_bytes in contents.values():
             assert len(k_bytes) == nbytes and len(v_bytes) == nbytes
 
-    def test_dense_engine_has_no_map(self, tiny_llama):
+    def test_engine_without_a_prefix_cache_has_no_map(self, tiny_llama):
         cfg, params = tiny_llama
-        eng = make_engine(params, cfg, cache_layout="dense")
+        eng = make_engine(params, cfg, prefix_cache=False)
+        eng.submit(SYS + [1], max_new_tokens=3)
+        eng.run()
         pmap = eng.export_prefix_map()
         assert pmap["chains"] == [] and pmap["pages"] == {}
+        assert pmap["page_size"] == 4
+        assert eng.export_prefix_pages([1, 2])[1] == {}
 
 
 class TestImportParity:

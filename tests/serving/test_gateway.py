@@ -53,7 +53,6 @@ def make_engine(tiny_llama, **kw):
     kw.setdefault("max_seq", 32)
     kw.setdefault("prefill_len", 16)
     kw.setdefault("sampling", SamplingParams(temperature=0.0))
-    kw.setdefault("cache_layout", "paged")
     kw.setdefault("page_size", PAGE)
     kw.setdefault("strict_submit", False)
     return InferenceEngine(params, cfg, **kw)

@@ -59,7 +59,7 @@ def ref_tokens(tiny_llama, prompt, n):
     architecture split, not disagg-vs-itself."""
     cfg, params = tiny_llama
     eng = InferenceEngine(
-        params, cfg, cache_layout="paged", **engine_kw())
+        params, cfg, **engine_kw())
     rid = eng.submit(prompt, max_new_tokens=n)
     return eng.run()[rid].tokens
 
@@ -142,7 +142,7 @@ class TestDisaggGateway:
     def test_colocated_healthz_has_no_disagg_block(self, tiny_llama):
         cfg, params = tiny_llama
         engine = InferenceEngine(
-            params, cfg, cache_layout="paged", **engine_kw())
+            params, cfg, **engine_kw())
         gw = ServingGateway(engine, port=0).start_in_thread()
         try:
             _, raw = get(gw.port, "/healthz")

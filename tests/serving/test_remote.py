@@ -346,7 +346,7 @@ class TestEngineParity:
         return InferenceEngine(
             params, cfg, max_slots=2, max_seq=32, prefill_len=16,
             sampling=SamplingParams(temperature=0.0),
-            cache_layout="paged", page_size=4, strict_submit=False)
+            page_size=4, strict_submit=False)
 
     def test_remote_bit_identical_one_compile(self, tiny):
         from scaletorch_tpu.serving.gateway import EngineWorker

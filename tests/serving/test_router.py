@@ -226,7 +226,7 @@ def _run_schedule(tiny_llama, prefix_aware: bool, schedule):
         rid: InferenceEngine(
             params, cfg, max_slots=2, max_seq=32, prefill_len=16,
             sampling=SamplingParams(temperature=0.0),
-            cache_layout="paged", page_size=PAGE, num_pages=64)
+            page_size=PAGE, num_pages=64)
         for rid in ("r0", "r1")
     }
     router = PrefixAwareRouter(list(engines), PAGE,
@@ -292,7 +292,7 @@ class TestPrefixRoutingBeatsConsistentHash:
                 rid: InferenceEngine(
                     params, cfg, max_slots=2, max_seq=32, prefill_len=16,
                     sampling=SamplingParams(temperature=0.0),
-                    cache_layout="paged", page_size=PAGE, num_pages=64)
+                    page_size=PAGE, num_pages=64)
                 for rid in ("r0", "r1")
             }
             router = PrefixAwareRouter(list(engines), PAGE,

@@ -802,7 +802,7 @@ class TestEndToEndTelemetry:
         exporter = TelemetryExporter(str(tmp_path / "serve.jsonl"))
         eng = InferenceEngine(
             params, cfg, max_slots=2, max_seq=16, prefill_len=8,
-            sampling=SamplingParams(temperature=0.0),
+            page_size=4, sampling=SamplingParams(temperature=0.0),
             tracer=tracer, exporter=exporter, monitor_every=4,
         )
         eng.submit([1, 2, 3], max_new_tokens=5)
@@ -844,7 +844,14 @@ class TestEndToEndTelemetry:
         no profiler session and no tracer, everything a tick adds (the
         outer span with its tick number, every phase with its two clock
         boundaries, the end-of-tick check, the worker loop's spans)
-        costs under 25 us, against a tick of about 100 ms on the chip."""
+        costs under 1 % of a tick. The shortest tick the ledger shows is
+        8.9 ms (serve-1.7b-longgen, PR 28), so the bound is 89 us; the
+        loop measures 17 us on an idle core of the test host and 33 us
+        with twelve busy processes on its eight cores (the 25 us of wall
+        clock this used to assert failed there). The quietest of ten
+        short bursts is taken: the other xdist workers share the cores,
+        and a burst of 10 ms can fall between their slices where one of
+        50 ms could not."""
         import timeit
 
         import jax
@@ -860,7 +867,7 @@ class TestEndToEndTelemetry:
         )
         eng = InferenceEngine(
             llama.init_params(jax.random.PRNGKey(0), cfg), cfg,
-            max_slots=2, max_seq=16, prefill_len=8,
+            max_slots=2, max_seq=16, prefill_len=8, page_size=4,
             sampling=SamplingParams(temperature=0.0))
         assert eng.tracer is None
         phases = ("sweep", "admit", "prefill", "prefill_wait", "emit",
@@ -881,8 +888,8 @@ class TestEndToEndTelemetry:
                     pass
 
         one_tick()
-        per_tick = min(timeit.repeat(one_tick, number=2_000, repeat=5)) / 2_000
-        assert per_tick < 25e-6, f"{per_tick * 1e6:.1f} us per tick"
+        per_tick = min(timeit.repeat(one_tick, number=500, repeat=10)) / 500
+        assert per_tick < 0.01 * 8.9e-3, f"{per_tick * 1e6:.1f} us per tick"
         assert eng.metrics.slow_ticks == 0
 
     def test_disabled_overhead_within_noise(self, tmp_path):

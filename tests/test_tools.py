@@ -91,7 +91,7 @@ def test_optimize_mfu_gen_detection():
 
 
 TIMING_TOOLS = [
-    "bench_single.py", "bench_decode.py", "bench_cp_compare.py",
+    "bench_single.py", "bench_cp_compare.py",
     "bench_moe_dispatch.py", "pp_schedule_compare.py", "optimize_mfu.py",
     "profile_mfu.py",
 ]
