@@ -350,6 +350,7 @@ class DisaggregatedEngine(InferenceEngine):
         # its slice and never again.
         self.params = jax.device_put(self.params, self._decode_place)
         self.cache = jax.device_put(self.cache, self._decode_place)
+        self._token_home = self._decode_place if n_d > 1 else None
         self._params_prefill = jax.device_put(params, self._prefill_place)
 
         # prefill-side scratch pool: PROMPT pages only — a request's
@@ -426,6 +427,10 @@ class DisaggregatedEngine(InferenceEngine):
             self.metrics.prefill_pool_free = alloc.free_count
 
     # ---- phase scheduler ---------------------------------------------
+    def _admission_due(self) -> bool:
+        # either phase may have work; the sweeps decide what fits
+        return bool(self._queue or self._handoff)
+
     def _admit(self) -> None:
         with span("handoff", self.tracer, pending=len(self._handoff)):
             self._handoff_sweep(time.monotonic())

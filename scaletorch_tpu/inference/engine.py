@@ -11,6 +11,19 @@ TPU execution discipline:
     steps the engine admits queued requests into freed slots by writing
     their row of the prompt buffer and flipping their ``write_mask``
     bit — data changes, shapes don't, nothing retraces;
+  * the decode loop runs ONE STEP AHEAD of the host: with step n on the
+    device a tick dispatches step n+1, fed n's sampled tokens as the
+    device array they are, and only then reads n back and emits it —
+    the device never waits for the host between two steps, and the
+    copy-back, the emit loop, the serving thread's inbox and the next
+    dispatch all run beside a step. Lengths are known ahead, so a slot
+    that ends by ``max_new_tokens`` / ``max_seq`` is switched off
+    exactly; an ``eos``, a non-finite row, a cancel or a TTL is learnt
+    one step late and costs one discarded row
+    (``decode_slot_steps_discarded``), matched to the request that was
+    bound to the slot when the step was dispatched, never to the slot's
+    next tenant. A tick that admits reads the step in flight first
+    (``InferenceEngine.step``);
   * K/V lives in ONE layout: a global page pool + per-slot page tables
     (kv_cache.PagedKVCache). Admission is page-budget-aware (HBM scales
     with tokens cached, not B x S_max), a radix tree shares
@@ -51,11 +64,22 @@ import itertools
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.array import ArrayImpl  # see _tokens_operand
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from scaletorch_tpu.inference.decode import (
     counts_routing,
@@ -177,7 +201,14 @@ class EngineMetrics:
     requests_admitted: int = 0      # entered a slot (prefilled)
     tokens_generated: int = 0
     prefill_calls: int = 0
-    decode_steps: int = 0
+    decode_steps: int = 0           # decode steps dispatched
+    # ... of them, dispatched while the step before had not been read
+    # back (the decode loop running one step ahead), and slot-steps
+    # computed for a request that had ended by the time they were read
+    # (an eos, a non-finite row, a cancel or a TTL is learnt one step
+    # late; an end by length never is)
+    decode_steps_ahead: int = 0
+    decode_slot_steps_discarded: int = 0
     slow_ticks: int = 0             # ticks over SLOW_TICK_S (gap included)
     queue_depth: int = 0
     active_slots: int = 0
@@ -252,6 +283,8 @@ class EngineMetrics:
             "tokens_generated": self.tokens_generated,
             "prefill_calls": self.prefill_calls,
             "decode_steps": self.decode_steps,
+            "decode_steps_ahead": self.decode_steps_ahead,
+            "decode_slot_steps_discarded": self.decode_slot_steps_discarded,
             "slow_ticks": self.slow_ticks,
             "queue_depth": self.queue_depth,
             "num_slots": self.num_slots,
@@ -308,6 +341,22 @@ class _Slot:
     @property
     def active(self) -> bool:
         return self.request is not None
+
+
+class _InFlight(NamedTuple):
+    """A decode step on the device whose result the host has not read:
+    its number (1-based, over the engine's life), its sampled tokens
+    and finite mask as the device arrays they are, the positions it
+    fed, and the request each of its slots ran for: a result belongs
+    to that request, never to the slot's next tenant. ``stall`` is an
+    injected slow decode, slept where the host waits for this step."""
+
+    number: int
+    nxt: Any
+    finite: Any
+    positions: np.ndarray
+    bound: List[Tuple[int, Request]]
+    stall: float
 
 
 class _Phase:
@@ -409,24 +458,24 @@ class InferenceEngine:
         invoked from ``step()`` with each slot's newly sampled tokens
         — PUSH, not poll, so a streaming bridge (serving/gateway.py)
         never waits on terminal results to forward tokens. The tokens
-        of a step are handed over once the NEXT decode step is on the
-        device (or before an admission builds its prefill call), so
-        that whatever the consumer wakes (the gateway's event loop
-        shares this interpreter) works while the device does and not
-        between two steps; a request that reaches its terminal result
-        has its tokens handed over first. Host-side only: the hook
-        sees tokens
-        after the device->host transfer the engine already performs, so
-        attaching it adds zero retraces (``decode_compile_count`` stays
-        1). Concatenating every ``token_ids`` delivered for a request
+        a tick emits are handed over right after the NEXT dispatch of a
+        decode step (or before an admission builds its prefill call):
+        the moment the engine thread is about to block on the device,
+        so that whatever the consumer wakes (the gateway's event loop
+        shares this interpreter) gets the interpreter then and not
+        while the thread dispatches; a request that reaches its
+        terminal result has its tokens handed over first. Host-side
+        only: the hook sees tokens after the device->host transfer the
+        engine already performs, so attaching it adds zero retraces
+        (``decode_compile_count`` stays 1). Concatenating every ``token_ids`` delivered for a request
         reproduces its final ``RequestResult.tokens`` bit-exactly. A
         raising hook is logged and disarmed, never fatal to serving.
 
     ``on_dispatched`` (an attribute, None by default) is called with no
-    argument on the ticking thread once a decode step is on the device,
-    after the held tokens were handed over: the place for host work that
-    should run beside the step rather than between two steps (the
-    serving bridge delivers the last tick's terminal results there).
+    argument on the ticking thread each time a decode step has been put
+    on the device, after the held tokens were handed over: the place
+    for host work that should run beside a step (the serving bridge
+    delivers the last tick's terminal results there).
     """
 
     def __init__(
@@ -506,10 +555,14 @@ class InferenceEngine:
             num_pages = max_slots * self._pages_per_slot + 1
         self.num_pages = num_pages
 
-        sharding = (
-            paged_kv_cache_shardings(mesh, tp_axis=tp_axis)
-            if mesh is not None else None
-        )
+        sharding = replicated = None
+        if mesh is not None:
+            sharding = paged_kv_cache_shardings(mesh, tp_axis=tp_axis)
+            replicated = NamedSharding(mesh, PartitionSpec())
+        # where the decode step's ``tokens`` operand goes when the pool
+        # spans several devices (None: one device, ``_tokens_operand``)
+        self._token_home = (
+            replicated if mesh is not None and mesh.size > 1 else None)
         self.cache = init_paged_kv_cache(
             cfg, num_pages, page_size, dtype=cache_dtype, sharding=sharding)
         self.allocator = PageAllocator(num_pages)
@@ -553,11 +606,6 @@ class InferenceEngine:
         self._prefill = make_paged_prefill_step(cfg, sampling, **steps)
         self._decode = make_paged_decode_step(cfg, sampling, **steps)
         if counts:
-            replicated = None
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                replicated = NamedSharding(mesh, PartitionSpec())
             routing = RoutingCounters(
                 cfg.num_experts, len(cfg.sparse_layer_ids()),
                 sharding=replicated)
@@ -566,7 +614,14 @@ class InferenceEngine:
         self._fill_slots = make_fill_slots_step(donate_cache=donate_cache)
 
         self._slots = [_Slot() for _ in range(max_slots)]
+        # the decode step that is on the device, its result not yet
+        # read: the loop runs one step ahead (``step()``)
+        self._in_flight: Optional[_InFlight] = None
         self._queue: deque[Request] = deque()
+        # the queued request the pool could not cover when it was last
+        # at the head of the line: no admission is due for it until a
+        # slot retires (pages come back from nowhere else)
+        self._page_starved: Optional[int] = None
         self._results: Dict[int, RequestResult] = {}
         self._finished_tick: List[RequestResult] = []
         self._ids = itertools.count()
@@ -614,6 +669,33 @@ class InferenceEngine:
         if self._base_keys_dev is None:
             self._base_keys_dev = jnp.asarray(self._base_keys)
         return self._base_keys_dev
+
+    def _tokens_operand(self, tokens):
+        """The decode step's ``tokens`` operand, from the host (numpy)
+        or from the step before (its sampled tokens, on the device), in
+        ONE form: a step fed either way is then one call signature
+        (``decode_compile_count``) of one compiled program.
+
+        On one device that form is what a host-built operand is to
+        jit, an array on the device that is not committed to it: the
+        program the steps then run is the one every other caller of
+        ``_decode`` with host-built operands runs (a serving check
+        before the first request, say), and nothing compiles when the
+        first step is fed on the device. A jitted step's results are
+        committed whenever its parameters are, and jax has no public
+        way to take that mark off without a copy through the host, so
+        the same buffer is wrapped again (``ArrayImpl``, internal to
+        the one jax this repo runs on; tests/inference/test_run_ahead.py
+        holds what is relied on). Over several devices the operand is
+        committed, replicated over the pool's mesh, both ways."""
+        if self._token_home is not None:
+            return (tokens if isinstance(tokens, jax.Array)
+                    else jax.device_put(tokens, self._token_home))
+        device = next(iter(self.cache.k.sharding.device_set))
+        if not isinstance(tokens, jax.Array):
+            tokens = jax.device_put(tokens, device)
+        return ArrayImpl(tokens.aval, SingleDeviceSharding(device),
+                         tokens._arrays, committed=False, _skip_checks=True)
 
     def _request_pages(self, prompt_len: int, max_new_tokens: int) -> int:
         """Worst-case pages a request reserves: every position it can
@@ -881,6 +963,7 @@ class InferenceEngine:
         self._slot_frozen[i] = 0
         self._tables[i, :] = TRASH_PAGE
         self._tables_dev = None
+        self._page_starved = None
         self._update_page_gauges()
 
     def _expire(self, now: float) -> None:
@@ -1190,6 +1273,15 @@ class InferenceEngine:
             return None
         return shared, shared_pages + own
 
+    def _admission_due(self) -> bool:
+        """A queued request, a free slot for it, and no word yet that
+        the pool cannot cover it. What ``step()`` asks before it lets
+        the decode loop run ahead: an admission reads the step in
+        flight first."""
+        return (bool(self._queue)
+                and self._queue[0].request_id != self._page_starved
+                and not all(s.active for s in self._slots))
+
     def _admit(self) -> None:
         """Move queued requests into free slots while the page pool can
         cover them, and prefill them — ONE batched prefill call
@@ -1197,9 +1289,9 @@ class InferenceEngine:
         logits are non-finite (poison prompt) is quarantined
         immediately; the other admitted slots proceed."""
         with self._phase("engine.tick.admit"):
-            free = [i for i, s in enumerate(self._slots) if not s.active]
-            if not free or not self._queue:
+            if not self._admission_due():
                 return
+            free = [i for i, s in enumerate(self._slots) if not s.active]
             self._release_tokens()
             admitted: List[int] = []
             tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
@@ -1211,7 +1303,9 @@ class InferenceEngine:
                     break
                 reserved = self._reserve_pages(self._queue[0])
                 if reserved is None:
-                    break  # page budget exhausted: head of the line waits
+                    # page budget exhausted: head of the line waits
+                    self._page_starved = self._queue[0].request_id
+                    break
                 req = self._queue.popleft()
                 shared, pages = reserved
                 self._bind_slot(i, req)
@@ -1345,17 +1439,36 @@ class InferenceEngine:
 
     def step(self) -> List[RequestResult]:
         """One engine tick: deadline sweep, admit into freed slots
-        (prefill), then one decode step for the active slots — with the
-        slots whose logits went non-finite quarantined instead of
-        emitting. Returns every result that reached its terminal outcome
-        since the PREVIOUS ``step()`` returned — including requests
-        finalized between ticks (a ``shed``/``rejected`` recorded inside
-        ``submit()``, a ``cancel()``), so a push-delivery bridge sees
-        each terminal result exactly once. The tick is one
-        ``engine.tick`` span cut into ``engine.tick.*`` phases
-        (docs/observability.md), each also a boundary of the phase
-        clocks."""
-        tick = self.metrics.decode_steps + 1  # the decode step this tick runs
+        (prefill), then one decode step's tokens for the active slots —
+        with the slots whose logits went non-finite quarantined instead
+        of emitting. Returns every result that reached its terminal
+        outcome since the PREVIOUS ``step()`` returned — including
+        requests finalized between ticks (a ``shed``/``rejected``
+        recorded inside ``submit()``, a ``cancel()``), so a
+        push-delivery bridge sees each terminal result exactly once.
+
+        The decode loop runs one step ahead: a tick that finds step n
+        on the device dispatches step n+1, fed n's sampled tokens as
+        the device array they are, and only then reads n back and
+        emits it, so the device never waits for the host between two
+        steps. What the host knows without the tokens it decides
+        exactly (positions; a slot that ends at n by ``max_new_tokens``
+        or ``max_seq`` is off in n+1); an ``eos``, a non-finite row, a
+        cancel or a TTL is learnt late, and that slot's row of n+1 is
+        thrown away (``decode_slot_steps_discarded``). A tick that
+        admits reads the step in flight first, prefills, and leaves the
+        next step, fed from the host, on the device for the following
+        tick. Whether a step goes ahead is decided here from what the
+        engine sees: a step in flight, slots that continue, no
+        admission due.
+
+        The tick is one ``engine.tick`` span cut into ``engine.tick.*``
+        phases (docs/observability.md), each also a boundary of the
+        phase clocks."""
+        flight, self._in_flight = self._in_flight, None
+        # the decode step this tick reads
+        tick = (flight.number if flight is not None
+                else self.metrics.decode_steps + 1)
         tick_t0 = time.monotonic()
         if self.watchdog is not None:
             self.watchdog.beat(step=self.metrics.decode_steps,
@@ -1375,61 +1488,20 @@ class InferenceEngine:
         with span("engine.tick", self.tracer, tick=tick):
             with self._phase("engine.tick.sweep"):
                 self._expire(time.monotonic())
+            read = False
+            if flight is not None and self._admission_due():
+                # no token of a running stream waits for a prefill call
+                self._read(flight)
+                flight, read = None, True
             self._admit()
-            active_idx = [i for i, s in enumerate(self._slots) if s.active]
-            if active_idx:
-                stall = 0.0
-                if inj is not None:
-                    poison = inj.take_nan_logits(tick)
-                    if poison is not None:
-                        self._poison_slot(poison)
-                    stall = inj.take_slow_decode(tick)
-                with self._phase("engine.tick.feed"):
-                    tokens = np.zeros(self.max_slots, np.int32)
-                    positions = np.zeros(self.max_slots, np.int32)
-                    active = np.zeros(self.max_slots, bool)
-                    for i in active_idx:
-                        slot = self._slots[i]
-                        # feed the last emitted token at its absolute
-                        # position: the prompt occupies [0, len),
-                        # generated token g sits at len + g - 1
-                        tokens[i] = slot.tokens[-1]
-                        positions[i] = slot.position + slot.generated - 1
-                        active[i] = True
-                    # numpy as it is: the jitted call uploads its host
-                    # operands itself, without the 0.25 ms of Python a
-                    # jnp.asarray each costs on a v5e's host
-                    feed = (tokens, positions, active,
-                            self._tables_device())
-                    base_keys = self._base_keys_device()
-                with self._phase("engine.tick.decode"):
-                    nxt, _logits, finite, self.cache = self._decode(
-                        self.params, *feed, self.cache, base_keys)
-                self.metrics.decode_steps += 1
-                with self._phase("engine.tick.decode_wait"):
-                    self._release_tokens()
-                    if self.on_dispatched is not None:
-                        self.on_dispatched()
-                    if stall > 0:
-                        # an injected slow decode is booked where a real
-                        # one would be: the host waiting on the step
-                        time.sleep(stall)
-                    # both copies started, then both awaited: one
-                    # round trip to the device, not two
-                    nxt, finite = jax.device_get((nxt, finite))
-                with self._phase("engine.tick.emit"):
-                    now = time.monotonic()
-                    poisoned = [i for i in active_idx if not finite[i]]
-                    if poisoned:
-                        self._quarantine(poisoned, now, where="decode")
-                    for i in active_idx:
-                        if finite[i]:
-                            self._emit(i, int(nxt[i]), now)
-                    # release the step's device arrays inside a phase:
-                    # freeing the logits costs 0.2 ms on a v5e, which
-                    # would otherwise fall after the tick's last phase,
-                    # when step() returns
-                    del _logits, feed, base_keys
+            if flight is None:
+                flight = self._dispatch(None)
+            if flight is not None and not read:
+                self._in_flight = self._dispatch(flight)
+                self._read(flight)
+                self._drop_dead_flight()  # its streams may have ended here
+            else:
+                self._in_flight = flight
             with self._phase("engine.tick.export"):
                 self.metrics.active_slots = sum(
                     s.active for s in self._slots)
@@ -1450,6 +1522,120 @@ class InferenceEngine:
         self._close_tick(tick, tick_t0)
         finished, self._finished_tick = self._finished_tick, []
         return finished
+
+    def _live(self, flight: _InFlight) -> List[Tuple[int, Request]]:
+        """The slots of a step whose request is the one the step ran
+        for: not ended since, not the slot's next tenant."""
+        return [(i, req) for i, req in flight.bound
+                if self._slots[i].request is req]
+
+    def _drop_dead_flight(self) -> None:
+        """Forget the step in flight if every request it runs for has
+        ended: nobody waits for it, and its rows count as discarded."""
+        flight = self._in_flight
+        if flight is not None and not self._live(flight):
+            self._read(flight)
+            self._in_flight = None
+
+    def _dispatch(self, before: Optional[_InFlight]) -> Optional[_InFlight]:
+        """Put the next decode step on the device, then hand over what
+        the host held back for that moment. With ``before`` None the
+        step is fed from the host: every active slot's last token at
+        its position. With ``before`` still on the device it is the
+        step after it: the slots of ``before`` that cannot end at it
+        by length, one position on, fed ITS sampled tokens without
+        their leaving the device. None when no slot has a step to
+        run."""
+        with self._phase("engine.tick.feed"):
+            positions = np.zeros(self.max_slots, np.int32)
+            active = np.zeros(self.max_slots, bool)
+            if before is None:
+                tokens = np.zeros(self.max_slots, np.int32)
+                bound = [(i, s.request)
+                         for i, s in enumerate(self._slots) if s.active]
+                for i, _ in bound:
+                    slot = self._slots[i]
+                    # feed the last emitted token at its absolute
+                    # position: the prompt occupies [0, len),
+                    # generated token g sits at len + g - 1
+                    tokens[i] = slot.tokens[-1]
+                    positions[i] = slot.position + slot.generated - 1
+            else:
+                tokens = before.nxt
+                bound = []
+                for i, req in self._live(before):
+                    slot = self._slots[i]
+                    # the token ``before`` samples is the slot's n-th:
+                    # the conditions ``_emit`` will end it by, ahead
+                    n = slot.generated + 1
+                    if (n < req.max_new_tokens
+                            and slot.position + n < self.max_seq):
+                        bound.append((i, req))
+                        positions[i] = before.positions[i] + 1
+            if not bound:
+                return None
+            active[[i for i, _ in bound]] = True
+            # positions and active stay numpy: the jitted call uploads
+            # host operands itself, without the 0.25 ms of Python a
+            # jnp.asarray each costs on a v5e's host
+            feed = (self._tokens_operand(tokens), positions, active,
+                    self._tables_device())
+            base_keys = self._base_keys_device()
+        number = self.metrics.decode_steps + 1
+        stall = 0.0
+        if self.injector is not None:
+            poison = self.injector.take_nan_logits(number)
+            if poison is not None:
+                self._poison_slot(poison)
+            stall = self.injector.take_slow_decode(number)
+        with self._phase("engine.tick.decode"):
+            nxt, _logits, finite, self.cache = self._decode(
+                self.params, *feed, self.cache, base_keys)
+            # dropped inside a phase: freeing the logits costs 0.2 ms
+            # on a v5e
+            del _logits, feed, base_keys
+        self.metrics.decode_steps = number
+        if before is not None:
+            self.metrics.decode_steps_ahead += 1
+        with self._phase("engine.tick.decode_wait"):
+            # the device has work: now whatever the consumers of the
+            # held tokens and results wake runs beside it
+            self._release_tokens()
+            if self.on_dispatched is not None:
+                self.on_dispatched()
+        return _InFlight(number, nxt, finite, positions, bound, stall)
+
+    def _read(self, flight: _InFlight) -> None:
+        """Read a dispatched step back and emit it: one token for each
+        of its slots whose request is still the one it ran for,
+        quarantine for a non-finite row. The rows of requests that
+        ended meanwhile are thrown away, and a step that has only such
+        rows is not waited for."""
+        live = self._live(flight)
+        self.metrics.decode_slot_steps_discarded += (
+            len(flight.bound) - len(live))
+        if not live:
+            return
+        with self._phase("engine.tick.decode_wait"):
+            # about to block: nothing that was emitted waits for it (a
+            # tick that admits reads with no dispatch, and so no
+            # hand-over, before it)
+            self._release_tokens()
+            if flight.stall > 0:
+                # an injected slow decode is booked where a real one
+                # would be: the host waiting on the step
+                time.sleep(flight.stall)
+            # both copies started, then both awaited: one round trip
+            # to the device, not two
+            nxt, finite = jax.device_get((flight.nxt, flight.finite))
+        with self._phase("engine.tick.emit"):
+            now = time.monotonic()
+            poisoned = [i for i, _ in live if not finite[i]]
+            if poisoned:
+                self._quarantine(poisoned, now, where="decode")
+            for i, _ in live:
+                if finite[i]:
+                    self._emit(i, int(nxt[i]), now)
 
     def _close_tick(self, tick: int, tick_t0: float) -> None:
         """A tick that took over ``SLOW_TICK_S``, the time since the
@@ -1502,6 +1688,7 @@ class InferenceEngine:
                 self._retire_slot(i, "aborted", detail=detail, now=now)
                 self.metrics.active_slots = sum(
                     s.active for s in self._slots)
+                self._drop_dead_flight()
                 return True
         return False
 
@@ -1527,6 +1714,7 @@ class InferenceEngine:
         for i, slot in enumerate(self._slots):
             if slot.active:
                 self._retire_slot(i, "aborted", detail=detail, now=now)
+        self._drop_dead_flight()
         self.metrics.queue_depth = 0
         self.metrics.active_slots = 0
 

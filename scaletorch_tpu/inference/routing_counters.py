@@ -56,12 +56,14 @@ def step_counts(counts: Dict[str, "jax.Array"], *, prefill: bool):
 
 class RoutingCounters:
     """Host totals plus the accumulators that are on the device now:
-    ``accumulator``, the result of the newest call, and ``_settled``,
-    the one before it, whose step has ended (the engine read that
-    step's tokens before it dispatched the next). A reader never waits
-    for a step in flight: the gateway reads the engine's snapshot on
-    its event loop for every request it routes, and a wait there stalls
-    every open stream.
+    ``accumulator``, the result of the newest call, ``_previous``, the
+    one before it, and ``_settled``, two calls back, whose step has
+    ended: the engine's decode loop runs one step ahead, so it has read
+    the tokens of the step two back before it dispatches (the one
+    before may still be on the device). A reader never waits for a step
+    in flight: the gateway reads the engine's snapshot on its event
+    loop for every request it routes, and a wait there stalls every
+    open stream.
 
     ``sharding``: where the steps' other operands are committed (the
     engine's mesh, replicated), so that the first call's accumulator is
@@ -77,7 +79,7 @@ class RoutingCounters:
         self._lock = threading.Lock()
         self._calls = 0
         zeros = np.zeros(len(ROUTING_COUNTERS), np.uint32)
-        self.accumulator = self._settled = (
+        self.accumulator = self._previous = self._settled = (
             jax.device_put(zeros, sharding) if sharding is not None
             else jax.numpy.asarray(zeros))
 
@@ -90,8 +92,8 @@ class RoutingCounters:
         """One call of a counting step: the accumulator goes in last
         and its result takes its place (engine thread)."""
         with self._lock:
-            self._settled = self.accumulator
-            *results, self.accumulator = step(*args, self._settled)
+            self._settled, self._previous = self._previous, self.accumulator
+            *results, self.accumulator = step(*args, self._previous)
             self._calls += 1
             if self._calls % READ_EVERY == 0:
                 self._read_device(self._settled)
@@ -101,12 +103,12 @@ class RoutingCounters:
         """The cumulative counters and the per-step means they give:
         experts touched per decode step and layer, and the fullest
         expert's load over the mean load in the prefill calls. Counts
-        every call that has ended: the newest one too once its result
-        is ready, as it is whenever the engine is between ticks."""
+        every call that has ended: the newest two as well once their
+        results are ready, as they are whenever the engine is idle."""
         with self._lock:
-            self._read_device(self.accumulator
-                              if self.accumulator.is_ready()
-                              else self._settled)
+            self._read_device(next(
+                (acc for acc in (self.accumulator, self._previous)
+                 if acc.is_ready()), self._settled))
             totals = dict(zip(ROUTING_COUNTERS, map(int, self._totals)))
         steps = decode_steps * self.moe_layers
         mean_load = totals["moe_prefill_assignments"] / self.num_experts

@@ -162,14 +162,14 @@ class EngineWorker:
     WORKER THREAD and must trampoline themselves onto the event loop.
 
     What a tick leaves for the event loop (its terminal results, the
-    listeners' wake-up) is handed over once the NEXT decode step is on
-    the device (``engine.on_dispatched``), as the engine does with the
-    tokens: the loop shares this interpreter, and whatever it does
-    between two steps (answering a ``done``, parsing the client's next
-    request) the device waits for. A tick that leaves no slot decoding
-    hands over at once, and a tick that freed a slot or a place in the
-    engine's queue wakes the listeners at once (the dispatcher's
-    backlog does not wait).
+    listeners' wake-up) is handed over right after the NEXT dispatch of
+    a decode step (``engine.on_dispatched``), as the engine does with
+    the tokens: the loop shares this interpreter, and it gets it when
+    this thread is about to block on the device, with a step (the
+    engine's decode loop runs one ahead) to work beside. A tick that
+    leaves no slot decoding hands over at once, and a tick that freed a
+    slot or a place in the engine's queue wakes the listeners at once
+    (the dispatcher's backlog does not wait).
     """
 
     def __init__(self, engine: InferenceEngine, *, replica_id: str = "r0",

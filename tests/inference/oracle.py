@@ -64,6 +64,22 @@ def greedy_by_forward(params, cfg, prompt, n):
     return seq[len(prompt):]
 
 
+def sampled_by_forward(params, cfg, prompt, n, *, seed, sampling):
+    """``n`` sampled tokens after ``prompt``: the plain forward on the
+    whole sequence each step, and the engine's rule for the key of a
+    token: the request's seed folded with the position of the token fed
+    last (``sampling.slot_keys``)."""
+    from scaletorch_tpu.inference.sampling import sample_one
+
+    base = jax.random.PRNGKey(seed)
+    seq = list(prompt)
+    for _ in range(n):
+        key = jax.random.fold_in(base, len(seq) - 1)
+        logits = jnp.asarray(last_logits(params, cfg, seq))
+        seq.append(int(sample_one(logits, key, sampling)))
+    return seq[len(prompt):]
+
+
 def assert_greedy(params, cfg, prompt, tokens):
     """``tokens`` is the greedy continuation of ``prompt`` by the plain
     forward (module docstring for what a tie may do)."""

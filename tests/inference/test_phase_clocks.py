@@ -108,6 +108,44 @@ def test_the_three_clocks_sum_to_the_decode_life(tiny_llama):
     assert results[second].stall_s < results[second].prefill_s
 
 
+def test_the_clocks_partition_the_wall_time_with_a_step_in_flight(
+        tiny_llama, monkeypatch):
+    """The loop one step ahead: every instant of the ticking thread is
+    still booked to one clock, the wait for the step in flight is
+    device wait, and a plain tick's phases come in the order
+    docs/observability.md gives: the dispatch of the step ahead, the
+    hand-over, then the read of the step before it."""
+    eng = make_engine(tiny_llama)
+    eng.submit([1, 2, 3], max_new_tokens=24)
+    eng.step()
+    eng.step()                                # compiled, a step in flight
+    names = []
+    real_span = engine_module.span
+
+    def recording_span(name, *args, **kw):
+        names.append(name)
+        return real_span(name, *args, **kw)
+
+    monkeypatch.setattr(engine_module, "span", recording_span)
+    t0 = time.monotonic()
+    eng._advance(t0)
+    before = list(eng._clocks)
+    for _ in range(6):
+        assert eng._in_flight is not None
+        eng.step()
+    t1 = time.monotonic()
+    eng._advance(t1)
+    spent = [c - c0 for c, c0 in zip(eng._clocks, before)]
+    assert sum(spent) == pytest.approx(t1 - t0, abs=1e-9)
+    assert min(spent) >= 0 and spent[engine_module.DEVICE_WAIT] > 0
+    assert spent[engine_module.STALL] < spent[engine_module.HOST]
+    tick = ["engine.tick", "engine.tick.sweep", "engine.tick.admit",
+            "engine.tick.feed", "engine.tick.decode",
+            "engine.tick.decode_wait", "engine.tick.decode_wait",
+            "engine.tick.emit", "engine.tick.export"]
+    assert names == tick * 6
+
+
 def test_a_request_that_never_reached_a_slot_has_no_clocks(tiny_llama):
     eng = make_engine(tiny_llama, strict_submit=False)
     rid = eng.submit([], max_new_tokens=4)
