@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Any, Dict, List, Optional
 
 
 @dataclass
@@ -85,8 +85,8 @@ class ModelArguments:
     )
     model_type: str = field(
         default="llama",
-        metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | gpt_moe | "
-                          "lenet | mingpt"},
+        metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
+                          "olmo_hybrid | gpt_moe | lenet | mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -100,6 +100,22 @@ class ModelArguments:
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
+    # olmo_hybrid, by the published config.json names: the kind of each
+    # layer (linear_attention | full_attention; omitted = three linear,
+    # one full, repeated), the linear-attention layers' heads and widths
+    layer_types: Optional[List[str]] = None
+    linear_num_key_heads: int = 30
+    linear_num_value_heads: int = 30
+    linear_key_head_dim: int = 96
+    linear_value_head_dim: int = 192
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    rope_parameters: Optional[Dict[str, Any]] = field(
+        default=None,
+        metadata={"help": "HF rope_parameters (olmo_hybrid). Its "
+                          "rope_theta null = no rotary embedding; "
+                          "omitted = --rope_theta."},
+    )
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "

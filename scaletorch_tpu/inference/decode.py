@@ -31,6 +31,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from scaletorch_tpu.inference.kv_cache import carries_state
 from scaletorch_tpu.inference.routing_counters import step_counts
 from scaletorch_tpu.inference.sampling import (
     SamplingParams,
@@ -50,12 +51,17 @@ def _resolve_donate(donate_cache: Optional[bool]) -> bool:
 
 def resolve_forward_cached(cfg) -> Callable:
     """The cache-aware forward for a model config: Qwen3-MoE and GPT-MoE
-    have their own cached forwards; every other LlamaConfig subclass
-    (Llama, Qwen3) shares the Llama one."""
+    and Olmo-Hybrid have their own cached forwards; every other
+    LlamaConfig subclass (Llama, Qwen3) shares the Llama one."""
     from scaletorch_tpu.models.gpt_moe import GPTMoEConfig
     from scaletorch_tpu.models.llama import LlamaConfig
+    from scaletorch_tpu.models.olmo_hybrid import OlmoHybridConfig
     from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
 
+    if isinstance(cfg, OlmoHybridConfig):
+        from scaletorch_tpu.models import olmo_hybrid
+
+        return olmo_hybrid.forward_cached
     if isinstance(cfg, Qwen3MoEConfig):
         from scaletorch_tpu.models import qwen3_moe
 
@@ -87,7 +93,10 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
 
     fill_slots(cache, mask bool, value scalar) -> cache with every
     masked page set to ``value``; unmasked bytes pass through
-    bit-identical.
+    bit-identical. A cache with slot-indexed buffers
+    (``kv_cache.HybridCache``: recurrent state, convolution tail) takes
+    a second mask, ``slot_mask`` [slots] bool, for those: one call
+    clears (or poisons) a slot's pages and its state together.
 
     One compile serves the scalar consumers — quarantine hygiene
     (value 0: a retired poison slot's NaN K/V must not outlive the
@@ -103,16 +112,21 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
     the engine steps, so XLA rewrites the masked lanes in place.
     """
 
-    def fill_slots(cache, mask, value):
+    def fill_slots(cache, mask, value, slot_mask=None):
+        from scaletorch_tpu.inference.kv_cache import SLOT_FIELDS
+
         vals = tuple(value) if isinstance(value, tuple) \
             else (value,) * len(cache)
+        by_slot = [name in SLOT_FIELDS
+                   for name in getattr(cache, "_fields", ())]
 
-        def fill(buf, val):
-            m = mask.reshape((1, mask.shape[0]) + (1,) * (buf.ndim - 2))
+        def fill(buf, val, slots):
+            m = slot_mask if slots else mask
+            m = m.reshape((1, m.shape[0]) + (1,) * (buf.ndim - 2))
             return jnp.where(m, jnp.asarray(val, buf.dtype), buf)
 
-        return type(cache)(*(fill(buf, val)
-                             for buf, val in zip(cache, vals)))
+        return type(cache)(*(fill(buf, val, slots) for buf, val, slots
+                             in zip(cache, vals, by_slot)))
 
     return jax.jit(
         fill_slots,
@@ -160,23 +174,34 @@ def make_paged_prefill_step(
     ``return_routing``: the MoE families) adds a last argument and a
     fifth result, the uint32 accumulator of
     ``inference/routing_counters.py``, and tells the forward which rows
-    exist: those of admitted slots below their ``tail_len``.
+    exist: those of admitted slots below their ``tail_len``. A model
+    with state-carrying layers (``kv_cache.carries_state``) is told the same: for
+    K/V a row past ``tail_len`` is harmless garbage, for a recurrence it
+    is a wrong answer, so such rows and every slot outside
+    ``write_mask`` leave state and convolution tail untouched, and the
+    pool the step donates and returns is that model's whole cache
+    (``kv_cache.HybridCache``).
     """
     fwd = forward_fn or resolve_forward_cached(cfg)
+    # a row that is no token would otherwise enter a recurrent state:
+    # told to the model's own forward and to a ``forward_fn`` in its
+    # place alike (one that cannot take ``row_mask`` fails at the trace)
+    row_masked = carries_state(cfg)
 
     def prefill(params, tokens, tail_lens, starts, write_mask,
                 page_tables, pool, base_keys, *routing):
-        from scaletorch_tpu.inference.kv_cache import PagedKVCache, PagedKVIO
+        from scaletorch_tpu.inference.kv_cache import PagedKVIO
 
         b, p = tokens.shape
         rows = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
         positions = starts[:, None] + rows
         kv_io = PagedKVIO(page_tables, page_size, seq_limit=seq_limit)
         counted = {}
-        if routing_counts:
+        if routing_counts or row_masked:
             counted = dict(
-                row_mask=write_mask[:, None] & (rows < tail_lens[:, None]),
-                return_routing=True)
+                row_mask=write_mask[:, None] & (rows < tail_lens[:, None]))
+        if routing_counts:
+            counted["return_routing"] = True
         logits, new_pool, *counts = fwd(
             params, tokens, cfg, tuple(pool),
             positions=positions, write_mask=write_mask, kv_io=kv_io,
@@ -188,7 +213,7 @@ def make_paged_prefill_step(
         keys = slot_keys(base_keys, starts + tail_lens - 1)
         first = sample(last, keys, sampling)
         out = (first, last.astype(jnp.float32), finite_mask(last),
-               PagedKVCache(*new_pool))
+               type(pool)(*new_pool))
         if routing_counts:
             out += (routing[0] + step_counts(counts[0], prefill=True),)
         return out
@@ -235,14 +260,21 @@ def make_paged_decode_step(
     exist are the active slots'.
     """
     fwd = forward_fn or resolve_forward_cached(cfg)
+    # a row that is no token would otherwise enter a recurrent state:
+    # told to the model's own forward and to a ``forward_fn`` in its
+    # place alike (one that cannot take ``row_mask`` fails at the trace)
+    row_masked = carries_state(cfg)
 
     def decode(params, tokens, positions, active, page_tables, pool,
                base_keys, *routing):
-        from scaletorch_tpu.inference.kv_cache import PagedKVCache, PagedKVIO
+        from scaletorch_tpu.inference.kv_cache import PagedKVIO
 
         kv_io = PagedKVIO(page_tables, page_size, seq_limit=seq_limit)
-        counted = (dict(row_mask=active[:, None], return_routing=True)
-                   if routing_counts else {})
+        counted = {}
+        if routing_counts or row_masked:
+            counted["row_mask"] = active[:, None]
+        if routing_counts:
+            counted["return_routing"] = True
         logits, new_pool, *counts = fwd(
             params, tokens[:, None], cfg, tuple(pool),
             positions=positions[:, None], write_mask=active, kv_io=kv_io,
@@ -252,7 +284,7 @@ def make_paged_decode_step(
         keys = slot_keys(base_keys, positions)
         nxt = sample(step_logits, keys, sampling)
         out = (nxt, step_logits.astype(jnp.float32),
-               finite_mask(step_logits), PagedKVCache(*new_pool))
+               finite_mask(step_logits), type(pool)(*new_pool))
         if routing_counts:
             out += (routing[0] + step_counts(counts[0], prefill=False),)
         return out
@@ -293,7 +325,7 @@ def teacher_forced_decode_paged(
     max_pages = ceil_div(s_max, page_size)
     pool = init_paged_kv_cache(
         cfg, b * max_pages + 1, page_size,
-        dtype=dtype or getattr(cfg, "dtype", None))
+        dtype=dtype or getattr(cfg, "dtype", None), slots=b)
     tables = (np.arange(b * max_pages, dtype=np.int32) + 1).reshape(
         b, max_pages)
     kv_io = PagedKVIO(jnp.asarray(tables), page_size, seq_limit=s_max)

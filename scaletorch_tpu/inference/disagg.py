@@ -144,6 +144,7 @@ from scaletorch_tpu.inference.engine import (  # noqa: E402
 from scaletorch_tpu.inference.kv_cache import (  # noqa: E402
     TRASH_PAGE,
     PageAllocator,
+    carries_state,
     ceil_div,
     init_paged_kv_cache,
 )
@@ -317,6 +318,14 @@ class DisaggregatedEngine(InferenceEngine):
             raise ValueError(
                 "DisaggregatedEngine owns its slice meshes; pass "
                 "devices/disagg_split instead of mesh")
+        if carries_state(cfg):
+            raise NotImplementedError(
+                f"DisaggregatedEngine: {type(cfg).__name__} has "
+                "state-carrying layers, and what is missing is the "
+                "hand-off of a request's recurrent state from the "
+                "prefill slice to the decode slice (the channel moves "
+                "pages; a state has none, and no snapshots at page "
+                "boundaries exist to move instead)")
         devs = list(devices) if devices is not None else list(jax.devices())
         if isinstance(disagg_split, str):
             disagg_split = parse_disagg_spec(disagg_split)

@@ -95,10 +95,12 @@ from scaletorch_tpu.inference.kv_cache import (
     PageAllocator,
     RadixPrefixCache,
     TRASH_PAGE,
+    carries_state,
     ceil_div,
     init_paged_kv_cache,
     kv_cache_bytes,
     paged_kv_cache_shardings,
+    recurrent_state_bytes,
 )
 from scaletorch_tpu.inference.resilience import (
     TERMINAL_OUTCOMES,
@@ -248,6 +250,14 @@ class EngineMetrics:
     # an MoE model's routing counters (inference/routing_counters.py):
     # on the device between snapshots, read when one is taken
     routing: Optional[RoutingCounters] = None
+    # a model with state-carrying layers (kv_cache.HybridCache): the
+    # bytes of its slot-indexed buffers (0: no such model, and none of
+    # these three is in the snapshot), slots a prefill call started from
+    # an empty state, and decode slot-steps dispatched on a slot whose
+    # state was last started by another request (0 or a fault)
+    recurrent_state_bytes: int = 0
+    recurrent_state_resets: int = 0
+    recurrent_state_owner_mismatches: int = 0
     _window_start: float = field(default_factory=time.monotonic)
     _window_tokens: int = 0
 
@@ -316,6 +326,11 @@ class EngineMetrics:
             snap[f"requests_{outcome}"] = count
         if self.routing is not None:
             snap.update(self.routing.snapshot(self.decode_steps))
+        if self.recurrent_state_bytes:
+            snap["recurrent_state_bytes"] = self.recurrent_state_bytes
+            snap["recurrent_state_resets"] = self.recurrent_state_resets
+            snap["recurrent_state_owner_mismatches"] = (
+                self.recurrent_state_owner_mismatches)
         return snap
 
 
@@ -418,7 +433,12 @@ class InferenceEngine:
     prefix_cache : keep a radix tree over page-aligned token prefixes
         so a request whose prompt head is already cached shares those
         pages (refcounted, copy-on-write at the page boundary) and
-        prefills only its tail.
+        prefills only its tail. A model with state-carrying layers
+        (``kv_cache.carries_state``) is served without it whatever this
+        says: a shared page holds K/V for its tokens, and nothing holds
+        the recurrent state after them (no snapshots at page
+        boundaries), so the export / import of prefix pages refuses
+        such a model by name.
     mesh / tp_axis : optional — shard the pool's KV heads over
         ``tp_axis`` of the mesh (the page axis stays unsharded: pages
         are not slot-aligned).
@@ -563,8 +583,13 @@ class InferenceEngine:
         # spans several devices (None: one device, ``_tokens_operand``)
         self._token_home = (
             replicated if mesh is not None and mesh.size > 1 else None)
+        # state-carrying layers: a recurrent state per slot beside the
+        # pool, no prefix sharing (class docstring)
+        self._stateful = carries_state(cfg)
+        prefix_cache = prefix_cache and not self._stateful
         self.cache = init_paged_kv_cache(
-            cfg, num_pages, page_size, dtype=cache_dtype, sharding=sharding)
+            cfg, num_pages, page_size, dtype=cache_dtype, sharding=sharding,
+            slots=max_slots)
         self.allocator = PageAllocator(num_pages)
         self.radix = (
             RadixPrefixCache(
@@ -572,6 +597,8 @@ class InferenceEngine:
                 self.allocator.refcount,
             ) if prefix_cache else None
         )
+        # the request whose prefill last started each slot's state
+        self._state_owner: List[Optional[int]] = [None] * max_slots
         # per-slot page table (host copy; reaches the device as data
         # every step), the pages each slot holds a reference on
         # (shared prefix pages first, own pages after), and how many
@@ -591,7 +618,9 @@ class InferenceEngine:
             max_slots, num_pages, page_size,
             kv_cache_bytes(cfg, num_pages, page_size,
                            dtype=cache_dtype) / 2**20,
-            ", prefix cache on" if prefix_cache else "",
+            (f" + {recurrent_state_bytes(self.cache) / 2**20:.1f} MiB of "
+             "recurrent state by slot" if self._stateful else "")
+            + (", prefix cache on" if prefix_cache else ""),
             f", sharded over {mesh.axis_names}" if mesh is not None
             else "",
         )
@@ -630,7 +659,8 @@ class InferenceEngine:
         self._draining = False
         self.metrics = EngineMetrics(
             num_slots=max_slots, routing=routing,
-            paged_pool_in_place=int(in_place_pair(self.cache.k.shape[-1])))
+            paged_pool_in_place=int(in_place_pair(self.cache.k.shape[-1])),
+            recurrent_state_bytes=recurrent_state_bytes(self.cache))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
         # the clock that is open, the last boundary; this tick's seconds
         # by phase name; when the previous tick ended, and whether it
@@ -991,7 +1021,8 @@ class InferenceEngine:
 
     def _quarantine(self, indices: List[int], now: float, where: str) -> None:
         """Retire poisoned slots (non-finite logits) and mask-clear their
-        pages so the NaN K/V cannot outlive the request. The clear is
+        pages (and their recurrent state, where the model has one) so
+        the NaN K/V cannot outlive the request. The clear is
         one jitted masked fill over the whole pool — data-only, so the
         decode step's single compile survives the fault. The mask
         covers the slot's MUTABLE pages only (own pages past the frozen
@@ -1005,8 +1036,21 @@ class InferenceEngine:
             self._retire_slot(
                 i, "quarantined",
                 detail=f"non-finite logits at {where}", now=now)
+        self._fill(mask, indices, 0.0)
+
+    def _fill(self, page_mask: np.ndarray, slots: List[int],
+              value: float) -> None:
+        """The masked fill of the cache: ``value`` into the masked pages
+        and, for a model with state-carrying layers, into the recurrent
+        state and convolution tail of ``slots``."""
+        by_slot = ()
+        if self._stateful:
+            slot_mask = np.zeros(self.max_slots, bool)
+            slot_mask[slots] = True
+            by_slot = (jnp.asarray(slot_mask),)
         self.cache = self._fill_slots(
-            self.cache, jnp.asarray(mask), jnp.asarray(0.0, jnp.float32))
+            self.cache, jnp.asarray(page_mask),
+            jnp.asarray(value, jnp.float32), *by_slot)
 
     def _poison_slot(self, slot_idx: int) -> None:
         """Fault injection: NaN-fill one slot's pages so its next
@@ -1027,9 +1071,7 @@ class InferenceEngine:
         mask = np.zeros(self.num_pages, bool)
         mutable = self._slot_pages[slot_idx][self._slot_frozen[slot_idx]:]
         mask[mutable] = True
-        self.cache = self._fill_slots(
-            self.cache, jnp.asarray(mask),
-            jnp.asarray(float("nan"), jnp.float32))
+        self._fill(mask, [slot_idx], float("nan"))
 
     # ---- warm rejoin: peer-to-peer prefix state exchange -----------------
     #
@@ -1049,10 +1091,23 @@ class InferenceEngine:
     # so an interrupted import leaves the allocator conservation oracle
     # green.
 
+    def _refuse_prefix_exchange(self, what: str) -> None:
+        """A model with state-carrying layers has no prefix to share:
+        its pages hold the full-attention layers' K/V, and the recurrent
+        state after those tokens was never kept."""
+        if self._stateful:
+            raise NotImplementedError(
+                f"{what}: {type(self.cfg).__name__} has state-carrying "
+                "layers, and what is missing is snapshots of the "
+                "recurrent state at page boundaries; without them a "
+                "shared or transferred prefix page has no state to "
+                "continue from")
+
     def export_prefix_map(self) -> Dict[str, Any]:
         """Snapshot the radix tree for a warming peer: root-to-leaf
         token chains with their page ids, plus per-page refcount/frozen
         state. Engine-thread only (worker inbox)."""
+        self._refuse_prefix_exchange("export_prefix_map")
         if self.radix is None:
             return {"page_size": self.page_size, "chains": [], "pages": {}}
         return {
@@ -1083,6 +1138,7 @@ class InferenceEngine:
         its conservation invariant never moves. Returns ``(meta,
         {page: (k_bytes, v_bytes)})``; requested pages no longer frozen
         are simply absent (the wire sends a zero-content frame)."""
+        self._refuse_prefix_exchange("export_prefix_pages")
         meta: Dict[str, Any] = {
             "dtype": str(self.cache.k.dtype),
             "page_shape": ([int(self.cache.k.shape[0])]
@@ -1125,6 +1181,7 @@ class InferenceEngine:
         stream) keeps its valid PREFIX and sheds the tail, so a partial
         transfer still warms what arrived intact. Returns ``{"pages":
         new_radix_pages, "chains": [registered token lists]}``."""
+        self._refuse_prefix_exchange("import_prefix_pages")
         result: Dict[str, Any] = {"pages": 0, "chains": []}
         if self.radix is None:
             return result
@@ -1338,6 +1395,11 @@ class InferenceEngine:
                 self._base_keys_device(),
             )
         self.metrics.prefill_calls += 1
+        if self._stateful:
+            # the call started every admitted slot's state from zero
+            for i in admitted:
+                self._state_owner[i] = self._slots[i].request.request_id
+            self.metrics.recurrent_state_resets += len(admitted)
         with self._phase("engine.tick.prefill_wait"):
             first = np.asarray(first)
             finite = np.asarray(finite)
@@ -1574,6 +1636,10 @@ class InferenceEngine:
                         positions[i] = before.positions[i] + 1
             if not bound:
                 return None
+            if self._stateful:
+                self.metrics.recurrent_state_owner_mismatches += sum(
+                    self._state_owner[i] != req.request_id
+                    for i, req in bound)
             active[[i for i, _ in bound]] = True
             # positions and active stay numpy: the jitted call uploads
             # host operands itself, without the 0.25 ms of Python a

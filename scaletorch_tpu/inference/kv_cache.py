@@ -30,6 +30,15 @@ and write by default (``layers.DenseKVIO``), what
 what the parity tests hold the paged path to. The engine never builds
 one.
 
+A model with state-carrying layers (Olmo-Hybrid: gated delta-rule layers
+between its full-attention layers) keeps two kinds of memory in ONE
+cache pytree, ``HybridCache``: the page pool over its full-attention
+layers only, and beside it, indexed by SLOT, the linear layers'
+recurrent state ``f32[linear layers, slots, H, d_k, d_v]`` and their
+convolution tail ``[linear layers, slots, kernel - 1, channels]``. A
+slot's state has no pages, no table and no snapshots: it is whatever the
+slot's request has read so far, started from zero by its prefill.
+
 MLA models cache only the low-rank latent (``MLACache``,
 [B, S_max, kv_rank]) and re-expand K/V per step — the trade the variant
 documents (models/attention/variants.py MultiHeadLatentAttention).
@@ -74,7 +83,9 @@ def kv_cache_shape(cfg, batch: int, max_seq: int) -> Tuple[int, ...]:
     """[L, B, Hkv, S_max, D] for a Llama-family config, or
     [L, B, H, S_max, D] for GPT-MoE (full per-head K/V)."""
     if hasattr(cfg, "num_key_value_heads"):  # Llama / Qwen3 / Qwen3-MoE
-        return (cfg.num_hidden_layers, batch, cfg.num_key_value_heads,
+        # a hybrid model keeps K/V for its full-attention layers only
+        layers = getattr(cfg, "num_kv_cache_layers", cfg.num_hidden_layers)
+        return (layers, batch, cfg.num_key_value_heads,
                 max_seq, cfg.actual_head_dim)
     if hasattr(cfg, "n_layer"):  # GPTMoEConfig
         return (cfg.n_layer, batch, cfg.n_head, max_seq, cfg.head_dim)
@@ -115,13 +126,18 @@ def init_kv_cache(
     max_seq: int,
     *,
     dtype: Any = None,
-) -> KVCache:
+) -> Any:
     """Zeroed contiguous cache in the model's compute dtype (bf16 on
     TPU): the reference layout, for parity harnesses and
-    single-sequence sampling (``gpt_moe.generate``), never the engine."""
+    single-sequence sampling (``gpt_moe.generate``), never the engine.
+    A model with state-carrying layers gets a ``HybridCache`` whose K/V
+    are contiguous and whose slots are the ``batch`` sequences."""
     shape = kv_cache_shape(cfg, batch, max_seq)
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
-    return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
+    k, v = jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+    if carries_state(cfg):
+        return HybridCache(k, v, *_zero_recurrent_state(cfg, batch, dt))
+    return KVCache(k=k, v=v)
 
 
 def init_mla_cache(attn_cfg, batch: int, max_seq: int,
@@ -156,6 +172,46 @@ class PagedKVCache(NamedTuple):
     v: jax.Array
 
 
+class HybridCache(NamedTuple):
+    """The cache of a model with full-attention AND state-carrying
+    layers, one pytree that the step programs donate and return: the
+    page pools ``k`` / ``v`` ``[full layers, n_pages, Hkv, page_size,
+    D]`` and, indexed by slot and not by page, the recurrent ``state``
+    ``f32[linear layers, slots, H, d_k, d_v]`` and the convolution tail
+    ``conv`` ``[linear layers, slots, kernel - 1, channels]``."""
+
+    k: jax.Array
+    v: jax.Array
+    state: jax.Array
+    conv: jax.Array
+
+
+# the fields of a cache whose axis 1 counts SLOTS (every other field's
+# counts pages): what a masked fill over slots touches
+SLOT_FIELDS = ("state", "conv")
+
+
+def carries_state(cfg) -> bool:
+    """Whether the model has state-carrying layers: its cache holds a
+    per-slot recurrent state beside the page pool."""
+    return hasattr(cfg, "recurrent_state_shapes")
+
+
+def _zero_recurrent_state(cfg, slots: int, dtype: Any,
+                          sharding: Optional[Any] = None):
+    state, conv = cfg.recurrent_state_shapes(slots)
+    if isinstance(sharding, NamedSharding):   # the pool's mesh, replicated
+        sharding = NamedSharding(sharding.mesh, P())
+    return (jnp.zeros(state, jnp.float32, device=sharding),
+            jnp.zeros(conv, dtype, device=sharding))
+
+
+def recurrent_state_bytes(cache: Any) -> int:
+    """Bytes of the slot-indexed buffers of a cache; 0 without any."""
+    return sum(getattr(cache, name).nbytes for name in SLOT_FIELDS
+               if hasattr(cache, name))
+
+
 def paged_kv_cache_shape(cfg, num_pages: int, page_size: int
                          ) -> Tuple[int, ...]:
     """[L, n_pages, Hkv, page_size, D] for any config ``kv_cache_shape``
@@ -171,17 +227,33 @@ def init_paged_kv_cache(
     *,
     dtype: Any = None,
     sharding: Optional[Any] = None,
-) -> PagedKVCache:
+    slots: Optional[int] = None,
+) -> Any:
     """Zeroed page pool in the model's compute dtype; with ``sharding``
     (a NamedSharding applied to both pools, or a PagedKVCache of them)
-    the pools are created directly on their shards."""
+    the pools are created directly on their shards. A model with
+    state-carrying layers gets a ``HybridCache``: the pool over its
+    full-attention layers plus the zeroed state and convolution tail of
+    ``slots`` slots (on one device, or replicated: sharding a recurrent
+    state over heads is not written)."""
     shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
     sk, sv = (sharding.k, sharding.v) \
         if isinstance(sharding, PagedKVCache) else (sharding, sharding)
     # device=: allocated on the shards, never whole on the default device
-    return PagedKVCache(k=jnp.zeros(shape, dt, device=sk),
-                        v=jnp.zeros(shape, dt, device=sv))
+    k, v = jnp.zeros(shape, dt, device=sk), jnp.zeros(shape, dt, device=sv)
+    if not carries_state(cfg):
+        return PagedKVCache(k=k, v=v)
+    if slots is None:
+        raise ValueError(
+            f"{type(cfg).__name__} has state-carrying layers: its cache "
+            "needs the number of slots beside the number of pages")
+    if sk is not None and len(sk.device_set) > 1:
+        raise NotImplementedError(
+            "a recurrent state over several devices (tensor parallelism "
+            "over state-carrying layers) is not written: serve this "
+            "model on one device")
+    return HybridCache(k, v, *_zero_recurrent_state(cfg, slots, dt, sk))
 
 
 def paged_kv_cache_specs(

@@ -14,6 +14,10 @@ from scaletorch_tpu.models.llama import Llama, LlamaConfig  # noqa: F401
 from scaletorch_tpu.models.qwen3 import Qwen3, Qwen3Config  # noqa: F401
 from scaletorch_tpu.models.qwen3_moe import Qwen3MoE, Qwen3MoEConfig  # noqa: F401
 from scaletorch_tpu.models.olmoe import Olmoe, OlmoeConfig  # noqa: F401
+from scaletorch_tpu.models.olmo_hybrid import (  # noqa: F401
+    OlmoHybrid,
+    OlmoHybridConfig,
+)
 from scaletorch_tpu.models.gpt_moe import GPTMoE, GPTMoEConfig  # noqa: F401
 from scaletorch_tpu.models.lenet import LeNet, LeNetConfig  # noqa: F401
 from scaletorch_tpu.models.resnet import ResNetConfig  # noqa: F401

@@ -247,7 +247,8 @@ def forward_cached(
     kv_io = kv_io or DenseKVIO()
     x = (params["wte"][input_ids] + params["wpe"][positions]).astype(cdt)
 
-    def layer_fn(h, layer, index, ck, cv):
+    def layer_fn(h, layer, index, kv):
+        ck, cv = kv
         a = _layer_norm(h, layer["ln1"])
         qkv = a @ layer["attn_qkv"].astype(cdt)
         q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -267,7 +268,7 @@ def forward_cached(
         else:
             y = jax.nn.gelu(m @ layer["mlp_fc"].astype(cdt))
             y = y @ layer["mlp_proj"].astype(cdt)
-        return h + y.astype(cdt), ck, cv, None
+        return h + y.astype(cdt), (ck, cv), None
 
     x, cache, _ = scan_layers_cached(layer_fn, x, cache, params["layers"])
     x = _layer_norm(x, params["ln_f"])

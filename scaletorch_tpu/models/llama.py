@@ -548,6 +548,48 @@ def lm_head_weight(
 # paged_kv_cache_specs) and XLA partitions the plain einsums; no shard_map/tp_axis threading.
 
 
+def attention_mix_cached(
+    h: jax.Array,
+    layer: Params,
+    index: jax.Array,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    cos: Optional[jax.Array],
+    sin: Optional[jax.Array],
+    positions: jax.Array,
+    cfg: LlamaConfig,
+    *,
+    write_mask: Optional[jax.Array] = None,
+    kv_io: Optional[Any] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The cache-aware attention mixer without its norm and residual:
+    ``h`` [B, S, H] is what the projections multiply (the normed hidden
+    state of a pre-norm block, the residual stream itself of a block
+    that norms the mixer's output). q/k/v with the configuration's q/k
+    norm, RoPE at the absolute positions (``cos`` None: no rotary
+    embedding), K/V appended at ``index`` of the whole cache through
+    ``kv_io``, attention against the cache, ``o_proj``. Returns (the
+    residual's increment before any output norm, cache_k, cache_v)."""
+    cdt = cfg.dtype
+    dh = cfg.actual_head_dim
+    kv_io = kv_io or DenseKVIO()
+    b, s, _ = h.shape
+    q, k = split_heads_qk_normed(
+        h @ layer["q_proj"].astype(cdt), h @ layer["k_proj"].astype(cdt),
+        layer, cfg)
+    v = (h @ layer["v_proj"].astype(cdt)).reshape(b, s, -1, dh)
+    q = q.transpose(0, 2, 1, 3)  # [B, Hq, S, D]
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
+    if cos is not None:
+        q, k = apply_rotary_pos_emb(q, k, cos, sin)
+    cache_k = kv_io.write(cache_k, index, k, positions, write_mask)
+    cache_v = kv_io.write(cache_v, index, v, positions, write_mask)
+    attn = kv_io.attend(q, cache_k, cache_v, index, positions)
+    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
+    return attn @ layer["o_proj"].astype(cdt), cache_k, cache_v
+
+
 @jax.named_scope("attn")
 def attention_block_cached(
     x: jax.Array,
@@ -582,54 +624,47 @@ def attention_block_cached(
     contiguous reference's ``layers.DenseKVIO``; the serving pool's
     ``inference.kv_cache.PagedKVIO`` carries [L, n_pages, Hkv, page, D]
     instead. The block never slices a layer out of the cache itself:
-    whether that costs anything is the adapter's business.
+    whether that costs anything is the adapter's business. The mixer
+    itself is ``attention_mix_cached``.
     """
-    cdt = cfg.dtype
-    dh = cfg.actual_head_dim
-    kv_io = kv_io or DenseKVIO()
     h = rms_norm(x, layer["input_layernorm"], cfg.rms_norm_eps)
-    b, s, _ = h.shape
-    q, k = split_heads_qk_normed(
-        h @ layer["q_proj"].astype(cdt), h @ layer["k_proj"].astype(cdt),
-        layer, cfg)
-    v = (h @ layer["v_proj"].astype(cdt)).reshape(b, s, -1, dh)
-    q = q.transpose(0, 2, 1, 3)  # [B, Hq, S, D]
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q, k = apply_rotary_pos_emb(q, k, cos, sin)
-    cache_k = kv_io.write(cache_k, index, k, positions, write_mask)
-    cache_v = kv_io.write(cache_v, index, v, positions, write_mask)
-    attn = kv_io.attend(q, cache_k, cache_v, index, positions)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
-    return x + attn @ layer["o_proj"].astype(cdt), cache_k, cache_v
+    out, cache_k, cache_v = attention_mix_cached(
+        h, layer, index, cache_k, cache_v, cos, sin, positions, cfg,
+        write_mask=write_mask, kv_io=kv_io)
+    return x + out, cache_k, cache_v
+
+
+def swiglu_mlp(h: jax.Array, layer: Params, cfg: LlamaConfig) -> jax.Array:
+    """The dense SwiGLU MLP of ``h`` without norm or residual."""
+    cdt = cfg.dtype
+    gate = h @ layer["gate_proj"].astype(cdt)
+    up = h @ layer["up_proj"].astype(cdt)
+    return swiglu(gate, up) @ layer["down_proj"].astype(cdt)
 
 
 @jax.named_scope("mlp")
 def _mlp_block(x: jax.Array, layer: Params, cfg: LlamaConfig) -> jax.Array:
     """Dense SwiGLU MLP sub-block with residual (single-device form; the
     TP/SP training path stays in ``_decoder_layer``)."""
-    cdt = cfg.dtype
     h = rms_norm(x, layer["post_attention_layernorm"], cfg.rms_norm_eps)
-    gate = h @ layer["gate_proj"].astype(cdt)
-    up = h @ layer["up_proj"].astype(cdt)
-    return x + swiglu(gate, up) @ layer["down_proj"].astype(cdt)
+    return x + swiglu_mlp(h, layer, cfg)
 
 
 def scan_layers_cached(
     layer_fn: Callable,
     x: jax.Array,
-    cache: Tuple[jax.Array, jax.Array],
+    cache: Any,
     layers: Params,
-) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array], Any]:
-    """The one layer loop of the cache-aware forwards (Llama / Qwen3,
-    Qwen3-MoE / OLMoE, GPT-MoE): a ``lax.scan`` whose CARRY is
-    ``(h, cache_k, cache_v)`` and whose scanned operands are the stacked
+) -> Tuple[jax.Array, Any, Any]:
+    """The one layer loop of the cache-aware forwards of one layer kind
+    (Llama / Qwen3, Qwen3-MoE / OLMoE, GPT-MoE): a ``lax.scan`` whose
+    CARRY is ``(h, cache)`` and whose scanned operands are the stacked
     layer parameters and the layer index.
 
-    ``layer_fn(h, layer, index, cache_k, cache_v) -> (h, cache_k,
-    cache_v, out)`` sees the whole cache and the index of its layer;
-    ``out`` is stacked over the layers (None for nothing). Returns
-    ``(h, (cache_k, cache_v), outs)``.
+    ``cache`` is any pytree of whole buffers (the ``(k, v)`` pair).
+    ``layer_fn(h, layer, index, cache) -> (h, cache, out)`` sees the
+    whole cache and the index of its layer; ``out`` is stacked over the
+    layers (None for nothing). Returns ``(h, cache, outs)``.
 
     The cache is carried, never scanned: as ``xs`` / ``ys`` XLA slices a
     layer out, re-lays it for the write and re-stacks the layers into a
@@ -637,20 +672,20 @@ def scan_layers_cached(
     Qwen3-1.7B decode step to append 1.8 MB (PERF.md, PR 28). Carried
     whole, and touched only through the ``kv_io`` adapter at a layer
     index, the donated buffer is the loop's buffer from the first layer
-    to the last.
+    to the last. (``olmo_hybrid.forward_cached`` loops over periods of
+    two layer kinds with the same carry, and scans no parameters.)
     """
-    cache_k, cache_v = cache
+    steps = jax.tree_util.tree_leaves(layers)[0].shape[0]
 
     def body(carry, xs):
-        h, ck, cv = carry
+        h, held = carry
         layer, index = xs
-        h, ck, cv, out = layer_fn(h, layer, index, ck, cv)
-        return (h, ck, cv), out
+        h, held, out = layer_fn(h, layer, index, held)
+        return (h, held), out
 
-    (x, cache_k, cache_v), outs = jax.lax.scan(
-        body, (x, cache_k, cache_v),
-        (layers, jnp.arange(cache_k.shape[0], dtype=jnp.int32)))
-    return x, (cache_k, cache_v), outs
+    (x, cache), outs = jax.lax.scan(
+        body, (x, cache), (layers, jnp.arange(steps, dtype=jnp.int32)))
+    return x, cache, outs
 
 
 def forward_cached(
@@ -684,12 +719,12 @@ def forward_cached(
         positions=positions,
     )
 
-    def layer_fn(h, layer, index, ck, cv):
+    def layer_fn(h, layer, index, kv):
         h, ck, cv = attention_block_cached(
-            h, layer, index, ck, cv, cos, sin, positions, cfg,
+            h, layer, index, *kv, cos, sin, positions, cfg,
             write_mask=write_mask, kv_io=kv_io,
         )
-        return _mlp_block(h, layer, cfg), ck, cv, None
+        return _mlp_block(h, layer, cfg), (ck, cv), None
 
     x, cache, _ = scan_layers_cached(layer_fn, x, cache, params["layers"])
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)

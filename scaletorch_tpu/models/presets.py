@@ -169,6 +169,52 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         norm_topk_prob=False,
         router_aux_loss_coef=0.01,
     ),
+    # Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json): gated
+    # delta-rule layers, three in four, between full-attention layers
+    # without rotary embedding; 7.43B parameters at the published 32
+    # layers, 14.9 GB in bf16, so one v5e chip serves a stage of a
+    # two-chip pipeline: the first 16 layers (four whole periods) with
+    # the embedding and the head, 4.10B parameters. ``layer_types``
+    # omitted: three linear_attention, one full_attention, repeated.
+    "olmo-hybrid-7b": dict(
+        model_type="olmo_hybrid",
+        vocab_size=100352,
+        hidden_size=3840,
+        intermediate_size=11008,
+        num_hidden_layers=16,
+        num_attention_heads=30,
+        num_key_value_heads=30,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=65536,
+        tie_word_embeddings=False,
+        linear_num_key_heads=30,
+        linear_num_value_heads=30,
+        linear_key_head_dim=96,
+        linear_value_head_dim=192,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+        rope_parameters={"rope_theta": None},
+    ),
+    # The same family at a size the CPU tests serve: two periods.
+    "olmo-hybrid-tiny": dict(
+        model_type="olmo_hybrid",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=8,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=4096,
+        tie_word_embeddings=False,
+        linear_num_key_heads=4,
+        linear_num_value_heads=4,
+        linear_key_head_dim=8,
+        linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+        rope_parameters={"rope_theta": None},
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

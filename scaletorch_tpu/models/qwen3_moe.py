@@ -712,9 +712,9 @@ def forward_cached(
         stacked = {k: layers[k] for k in _EXPERT_KEYS}
         layers = {k: v for k, v in layers.items() if k not in _EXPERT_KEYS}
 
-    def layer_fn(h, layer, index, ck, cv):
+    def layer_fn(h, layer, index, kv):
         h, ck, cv = _llama.attention_block_cached(
-            h, layer, index, ck, cv, cos, sin, positions, cfg,
+            h, layer, index, *kv, cos, sin, positions, cfg,
             write_mask=write_mask, kv_io=kv_io,
         )
         h, _aux, _stats, routing = moe_block_with_load(
@@ -724,7 +724,7 @@ def forward_cached(
         counts = {"routed": jnp.sum(rows), "dropped": routing["dropped"],
                   "expert_visits": jnp.sum(rows > 0, dtype=jnp.int32),
                   "peak_load_rows": jnp.max(rows)}
-        return h, ck, cv, counts
+        return h, (ck, cv), counts
 
     x, cache, counts = _llama.scan_layers_cached(layer_fn, x, cache, layers)
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)

@@ -123,6 +123,23 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
             **moe_arch,
             **common,
         )
+    if cfg.model_type == "olmo_hybrid":
+        from scaletorch_tpu.models import olmo_hybrid
+
+        # the published config.json names; ``rope_parameters`` carries
+        # the family's rope_theta (null: no rotary embedding), the
+        # reordered norm and the whole-width q/k norm are the family's
+        # (models/olmo_hybrid.py)
+        rope = ({} if cfg.rope_parameters is None
+                else {"rope_theta": cfg.rope_parameters.get("rope_theta")})
+        return olmo_hybrid.OlmoHybridConfig(**{
+            **common, **rope,
+            "layer_types": (None if cfg.layer_types is None
+                            else tuple(cfg.layer_types)),
+            **{name: getattr(cfg, name) for name in (
+                "linear_num_key_heads", "linear_num_value_heads",
+                "linear_key_head_dim", "linear_value_head_dim",
+                "linear_conv_kernel_dim", "linear_allow_neg_eigval")}})
     if cfg.model_type == "qwen3":
         return qwen3.Qwen3Config(qk_norm=True, **common)
     if cfg.model_type == "llama":

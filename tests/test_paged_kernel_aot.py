@@ -155,7 +155,8 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
         lambda: init(jax.random.PRNGKey(0), cfg)))
     max_pages = -(-max_seq // page_size)
     pool = on_chip(jax.eval_shape(lambda: init_paged_kv_cache(
-        cfg, slots * max_pages + 1, page_size, dtype=cfg.dtype)))
+        cfg, slots * max_pages + 1, page_size, dtype=cfg.dtype,
+        slots=slots)))
     counted = counts_routing(cfg)
     build = dict(page_size=page_size, seq_limit=max_seq, donate_cache=True,
                  routing_counts=counted)
@@ -194,13 +195,11 @@ def _pool_shaped(text, pool_shape):
     return found
 
 
-@pytest.fixture(scope="module", params=["qwen3-1.7b-serve",
-                                        "olmoe-1b-7b-serve"])
-def serving_programs(request, one_chip):
+def _programs_of(one_chip, name):
     from benchmarks.lib.program import serving_model
 
     with open(os.path.join(REPO, "benchmarks", "configs",
-                           request.param + ".json")) as f:
+                           name + ".json")) as f:
         config = json.load(f)
     serve = config["serve"]
     cfg, init = serving_model(config, serve["dtype"])
@@ -208,6 +207,13 @@ def serving_programs(request, one_chip):
         one_chip, cfg, init, slots=serve["max_slots"],
         max_seq=serve["max_seq"], prefill_len=serve["prefill_len"],
         page_size=serve["page_size"])
+
+
+@pytest.fixture(scope="module", params=["qwen3-1.7b-serve",
+                                        "olmoe-1b-7b-serve",
+                                        "olmo-hybrid-7b-serve"])
+def serving_programs(request, one_chip):
+    return _programs_of(one_chip, request.param)
 
 
 def test_no_step_program_moves_the_pool(serving_programs):
@@ -239,6 +245,58 @@ def test_decode_kernel_is_still_the_one_4d_call(serving_programs):
     assert len(calls) == 1 and _named(calls, "paged_decode"), calls
     assert not [c for c in _mosaic_calls(prefill.as_text())
                 if four_d.search(c)]
+
+
+def _top_level(text, wanted):
+    """Instructions outside fused computations (what is scheduled as an
+    operation of its own: a fusion, a copy, a call) whose result type
+    holds ``wanted``."""
+    found, fused = [], False
+    for line in text.splitlines():
+        if line.startswith(("ENTRY", "%")) and line.rstrip().endswith("{"):
+            fused = line.startswith("%fused_computation")
+        m = _INSTRUCTION.match(line)
+        if m and not fused and wanted in m["type"]:
+            found.append((m["op"], line.strip()[:160]))
+    return found
+
+
+def test_the_recurrent_state_is_updated_in_place_and_no_weight_is_moved(
+        one_chip):
+    """Olmo-Hybrid's decode step at the cell's shapes. The state
+    ``f32[12,16,30,96,192]`` is the layer loop's carry: beside plumbing,
+    the only operations that return it are the select +
+    dynamic-update-slice fusions that write one layer of it in place
+    (one per linear layer of a period), and no operation returns a copy
+    of one layer. No operation returns a whole weight stack or a
+    period's slice of one: indexed ``[period][j]`` out of scanned
+    operands, XLA copied every linear layer's weights out once more a
+    step (6 GB written and read back), and split into heads the gate's
+    projection was re-laid whole (0.53 GB), both found here before the
+    first chip call (PERF.md, PR 32). What is left of scratch is a
+    hundredth of the state."""
+    decode, prefill, _ = _programs_of(one_chip, "olmo-hybrid-7b-serve")
+    text = decode.as_text()
+    state = [op for op, _ in _top_level(text, "f32[12,16,30,96,192]")
+             if op not in _PLUMBING]
+    assert state == ["fusion"] * 3, state
+    assert not [x for x in _top_level(text, "f32[16,30,96,192]")
+                if x[0] not in _PLUMBING], "a layer of the state is copied"
+    # a layer's matrices are 22 to 85 MB; the gates' [3840, 30] columns
+    # (0.2 MB a layer) may be fetched ahead as XLA likes
+    for width in (2880, 5760, 11008, 3840):
+        for stack in (f"bf16[4,3,3840,{width}]", f"bf16[3,3840,{width}]",
+                      f"bf16[4,3,{width},3840]", f"bf16[3,{width},3840]"):
+            moved = [x for x in _top_level(text, stack)
+                     if x[0] not in _PLUMBING]
+            assert not moved, moved[:3]
+    state_bytes = 12 * 16 * 30 * 96 * 192 * 4
+    memory = decode.memory_analysis()
+    assert memory.temp_size_in_bytes < state_bytes // 10
+    assert memory.alias_size_in_bytes >= state_bytes
+    # the prefill call's scan inverts its triangular systems by matrix
+    # products: the solver's custom call took a quarter of the call
+    assert "InvertDiagBlocks" not in prefill.as_text()
 
 
 def test_narrow_heads_take_the_lax_pair_in_the_same_loop(one_chip):
