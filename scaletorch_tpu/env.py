@@ -82,10 +82,12 @@ register_env("SCALETORCH_TPU_CE_CHUNK", "1024", int)
 # Default OFF until measured faster than the batched einsum on real
 # chips (the einsum is already MXU-dense; the win is the padding skip).
 register_env("SCALETORCH_TPU_GROUPED_MLP_KERNEL", "0", _as_bool)
-# Flash-kernel tile sizes (ops/pallas/flash.py). The defaults are sound
-# for d=64..128 on v5e VMEM; tools/optimize_mfu.py --flash-blocks sweeps
-# these on the actual chip (block choice is a measured property, not a
-# host-side heuristic).
+# Flash-kernel tile sizes (ops/pallas/flash.py), halved until they divide
+# the sequence. The defaults are sound for d=64..128 on v5e VMEM and are
+# what every chip number of PERF.md was taken at; the blocks a causal call
+# skips follow from them and the two lengths alone
+# (flash.causal_block_plan), so they are no lever for that.
+# tools/optimize_mfu.py --flash-blocks can sweep them; no chip run has.
 register_env("SCALETORCH_TPU_FLASH_BLOCK_Q", "512", int)
 register_env("SCALETORCH_TPU_FLASH_BLOCK_KV", "512", int)
 # Paged-decode attention (ops/pallas/paged_attention.py): 1 (default)

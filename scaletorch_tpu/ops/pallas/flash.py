@@ -11,36 +11,44 @@ Design points:
     head by ``n_rep``, so grouped K/V heads are read directly from their
     unexpanded [B, Hkv, S, D] layout (the reference expands via zero-copy
     ``expand``, llama.py:176-192; here the "expansion" is pure indexing).
-  * **Causal block skip** — for query block i, key blocks j > i are
-    skipped: their compute is predicated off with ``pl.when`` and their
-    index maps are clamped to an already-resident block so no DMA is
-    issued for them. This is the reference ring-attention causal-skip
-    idea (context_parallel.py:154-171) applied at tile granularity.
+  * **Causal block skip, on the grid** — a causal call's grid is
+    ``(batch, head, step)`` and a step is one entry of a small table of
+    the LIVE ``(query block, key block)`` pairs (``causal_block_plan``,
+    built on the host from the four static sizes, read on the scalar
+    prefetch path): blocks above the diagonal are no grid step at all
+    (120 of a head's 256 at 8192 / 512 / 512), so nothing is predicated
+    off and no DMA is clamped. Every live block builds the triangle
+    mask: on the chip it costs nothing a second, mask-free body for the
+    blocks wholly under the diagonal would win back (PERF.md, PR 33).
+    ``causal=False`` keeps the rectangular grid and no mask (ring
+    attention's off-diagonal hops).
+    This is the reference ring-attention causal-skip idea
+    (context_parallel.py:154-171) applied at tile granularity.
   * **vma-aware** — output ShapeDtypeStructs carry the varying-mesh-axes
     of their inputs, so the kernel composes with ``jax.shard_map``'s
     vma checking (the spmd train step runs everything inside shard_map).
   * fp32 accumulators and LSE; bf16 MXU feeds.
 
 Backward follows FlashAttention-2: delta = rowsum(dO * O) precomputed in
-XLA, then a dq kernel (grid over query blocks, reducing key blocks) and a
-dkv kernel (grid over key blocks, reducing query blocks AND the n_rep
-grouped query heads).
+XLA, then a dq kernel (a query block at a time, reducing its key blocks)
+and a dkv kernel (a key block at a time, reducing its query blocks and, at
+each, the n_rep grouped query heads).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 def _resolve_blocks(block_q, block_kv):
-    """None -> the SCALETORCH_TPU_FLASH_BLOCK_Q/KV env registry values
-    (tools/optimize_mfu.py --flash-blocks sweeps these on the real chip).
+    """None -> the SCALETORCH_TPU_FLASH_BLOCK_Q/KV env registry values.
     Resolved HERE so every entry point — the attention backend, the ring
     attention's forward/backward composition — honours the tuned tiles."""
     if block_q is None or block_kv is None:
@@ -68,52 +76,132 @@ def _pick_block(seq: int, preferred: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# forward
+# the causal structure, on the grid
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
-                *, scale, causal, bq, bkv):
-    i = pl.program_id(2)  # query block
-    j = pl.program_id(3)  # key block
-    nj = pl.num_programs(3)
+# what a step of a causal walk does to its accumulation (the plan's ``flags``)
+_FIRST, _LAST = 1, 2
 
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+# A walk's three tables lie in SMEM whole (1 MiB on the v5e), 12 bytes a
+# step: 2**16 steps are 768 KiB, 361 blocks a side, 184,832 tokens in
+# 512-wide blocks (tests/test_paged_kernel_aot.py compiles that walk).
+# Past it a call fails here, by name, and not inside Mosaic.
+MAX_CAUSAL_STEPS = 2 ** 16
 
-    # query block i attends key block j iff j*bkv <= i*bq + bq - 1
-    needed = (j * bkv <= i * bq + bq - 1) if causal else (j >= 0)
 
-    @pl.when(needed)
-    def _block():
-        q = q_ref[0, 0]  # [bq, D]
-        k = k_ref[0, 0]  # [bkv, D]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bkv]
+class CausalBlockPlan(NamedTuple):
+    """Which blocks of a causal score matrix the kernels visit, in the
+    order they visit them: two walks, each three read-only int32
+    vectors ``(outer block, inner block, flags)``, one entry a grid
+    step.
+
+    ``by_query`` = ``(q_blk, k_blk, flags)``: query block by query
+    block, each one's visible key blocks in order (``flash_fwd``,
+    ``flash_dq``). ``by_key`` = ``(k_blk, q_blk, flags)``: key block by
+    key block, each one's contributing query blocks in order
+    (``flash_dkv``, which visits a pair once per grouped query head).
+    ``flags`` says whether the step opens (``_FIRST``) or closes
+    (``_LAST``) its accumulation. ``live`` counts the blocks that hold
+    a visible element; ``dead`` the key blocks that no query row
+    reaches (``skv > sq`` only): each still gets one step, against
+    query block 0, where the mask leaves nothing, so its dk / dv come
+    out zeros.
+    """
+    by_query: tuple
+    by_key: tuple
+    live: int
+    dead: int
+
+
+@functools.lru_cache(maxsize=None)
+def causal_block_plan(sq: int, skv: int, bq: int, bkv: int) -> CausalBlockPlan:
+    """The plan of a causal ``[sq, skv]`` score matrix (row >= column is
+    visible) cut into ``bq x bkv`` blocks. Static by shape, so it is
+    built on the host, once, and reaches the kernels as scalar-prefetch
+    tables. Block ``(i, j)`` is live iff its first key column is at or
+    before its last query row: 8192 / 8192 / 512 / 512 is 136 live of
+    256."""
+    nq, nkv = sq // bq, skv // bkv
+
+    def live(i, j):
+        return j * bkv <= i * bq + bq - 1
+
+    def walk(accumulations):
+        """[(outer, inner)] per accumulation -> the flagged tables."""
+        steps = []
+        for acc in accumulations:
+            acc = [[*step, 0] for step in acc]
+            acc[0][-1] |= _FIRST
+            acc[-1][-1] |= _LAST
+            steps += acc
+        if len(steps) > MAX_CAUSAL_STEPS:
+            raise ValueError(
+                f"causal flash attention at {sq} x {skv} in {bq} x {bkv} "
+                f"blocks walks {len(steps)} live blocks; its tables hold "
+                f"at most {MAX_CAUSAL_STEPS}. Use larger blocks "
+                "(SCALETORCH_TPU_FLASH_BLOCK_Q / _KV) or shard the "
+                "sequence (context parallel).")
+        tables = tuple(np.ascontiguousarray(col)
+                       for col in np.asarray(steps, np.int32).T)
+        for table in tables:  # the cache hands every caller the same arrays
+            table.setflags(write=False)
+        return tables
+
+    by_query = walk(
+        [(i, j) for j in range(nkv) if live(i, j)]
+        for i in range(nq))  # key block 0 is live for every query block
+    by_key = walk(
+        [(j, i) for i in range(nq) if live(i, j)] or [(j, 0)]
+        for j in range(nkv))
+    n_live = len(by_query[0])
+    return CausalBlockPlan(by_query, by_key, n_live,
+                           dead=len(by_key[0]) - n_live)
+
+
+def _grid_step(causal, refs, n_blocks):
+    """Where this grid step is: ``(blocks, first, last, refs)``. The
+    grid past (batch, head) is the ``n_blocks`` block coordinates,
+    outermost first, reduced over all but the first. Causal: ONE walked
+    dimension stands for the first two, an index into a walk's tables,
+    the kernel's leading refs, which are dropped from ``refs``.
+    """
+    if causal:
+        outer, inner, flags = (ref[pl.program_id(2)] for ref in refs[:3])
+        refs, inner_dims = refs[3:], range(3, n_blocks + 1)
+        first, last = flags & _FIRST != 0, flags & _LAST != 0
+    else:
+        outer, inner = pl.program_id(2), pl.program_id(3)
+        inner_dims = range(4, n_blocks + 2)
+        first, last = inner == 0, inner == pl.num_programs(3) - 1
+    blocks = [outer, inner]
+    for a in inner_dims:
+        blocks.append(pl.program_id(a))
+        first &= pl.program_id(a) == 0
+        last &= pl.program_id(a) == pl.num_programs(a) - 1
+    return blocks, first, last, refs
+
+
+def _blocks_of(causal, n_blocks):
+    """``_grid_step``'s blocks for an index map, from its arguments past
+    (batch, head): the grid's, then (causal) the three tables."""
+    def blocks(*g):
         if causal:
-            # only the blocks straddling the diagonal need the triangle mask
-            row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-            col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
-        m_prev, l_prev = m_sc[:], l_sc[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[:] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_sc[:] = m_new
-        acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            t, (outer, inner, _) = g[0], g[n_blocks - 1:]
+            return [outer[t], inner[t], *g[1:n_blocks - 1]]
+        return g
+    return blocks
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.maximum(l_sc[:], 1e-30)
-        o_ref[0, 0] = (acc_sc[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_sc[:, 0] + jnp.log(l[:, 0]))[None, :]
+
+def _scores(q, k, i, j, *, scale, masked, bq, bkv):
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # [bq, bkv]
+    if masked:
+        # every live causal block: the select is the identity on a block
+        # wholly under the diagonal, and measured free there
+        row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
+        col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+        s = jnp.where(row >= col, s, _NEG_INF)
+    return s
 
 
 def _semantics(*dims):
@@ -127,34 +215,87 @@ def _semantics(*dims):
         dimension_semantics=tuple(m[d] for d in dims))
 
 
+def _call(kernel, name, tables, grid, interpret, out_shape, **specs):
+    """The ``pallas_call`` of one kernel. ``grid`` is the rectangular
+    grid: (batch, head, outer block) are parallel, the rest carry the
+    accumulators. With ``tables`` (a causal walk) the two outermost
+    block dimensions fold into one sequential one, a step a table
+    entry."""
+    parallel = 3
+    if tables:
+        grid, parallel = grid[:2] + (len(tables[0]),) + grid[4:], 2
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=grid, **specs),
+        compiler_params=_semantics(
+            *["p"] * parallel, *["a"] * (len(grid) - parallel)),
+        out_shape=out_shape,
+        interpret=interpret,
+        name=name,
+    )
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+def _fwd_kernel(*refs, scale, causal, bq, bkv):
+    (i, j), first, last, refs = _grid_step(causal, refs, 2)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
+
+    @pl.when(first)
+    def _init():
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    q = q_ref[0, 0]  # [bq, D]
+    k = k_ref[0, 0]  # [bkv, D]
+    v = v_ref[0, 0]
+    s = _scores(q, k, i, j, scale=scale, masked=causal, bq=bq, bkv=bkv)
+    m_prev, l_prev = m_sc[:], l_sc[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[:] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_sc[:] = m_new
+    acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+    @pl.when(last)
+    def _finalize():
+        l = jnp.maximum(l_sc[:], 1e-30)
+        o_ref[0, 0] = (acc_sc[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_sc[:, 0] + jnp.log(l[:, 0]))[None, :]
+
+
 def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     n_rep = hq // hkv
-    nq, nkv = sq // bq, skv // bkv
+    tables = causal_block_plan(sq, skv, bq, bkv).by_query if causal else ()
+    blocks = _blocks_of(causal, 2)
 
-    def clamp_j(i, j):
-        # causal: key blocks beyond the last one visible to query block i
-        # are skipped; point their DMA at the last visible block (already
-        # resident) so no bandwidth is spent on them. The bound is in KEY
-        # block units: last visible key row is i*bq + bq - 1.
-        return jnp.minimum(j, (i * bq + bq - 1) // bkv) if causal else j
+    def q_rows(b_, h, *g):
+        return b_, h, blocks(*g)[0], 0
 
-    grid = (b, hq, nq, nkv)
-    out, lse = pl.pallas_call(
+    def kv_rows(b_, h, *g):
+        return b_, h // n_rep, blocks(*g)[1], 0
+
+    out, lse = _call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv),
-        grid=grid,
-        compiler_params=_semantics("p", "p", "p", "a"),
+        "flash_fwd", tables, (b, hq, sq // bq, skv // bkv), interpret,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda b_, h, i, j: (b_, h // n_rep, clamp_j(i, j), 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda b_, h, i, j: (b_, h // n_rep, clamp_j(i, j), 0)),
+            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b_, h, i, j: (b_, h, 0, i)),
+            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, 1, bq),
+                         lambda b_, h, *g: (b_, h, 0, blocks(*g)[0])),
         ],
         out_shape=[
             _struct((b, hq, sq, d), q.dtype, q),
@@ -165,103 +306,77 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
-        name="flash_fwd",
-    )(q, k, v)
+    )(*tables, q, k, v)
     return out, lse[:, :, 0, :]
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc,
-               *, scale, causal, bq, bkv):
-    i = pl.program_id(2)
-    j = pl.program_id(3)
-    nj = pl.num_programs(3)
+def _dq_kernel(*refs, scale, causal, bq, bkv):
+    (i, j), first, last, refs = _grid_step(causal, refs, 2)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc = refs
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    needed = (j * bkv <= i * bq + bq - 1) if causal else (j >= 0)
+    q = q_ref[0, 0]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0]      # [1, bq]
+    delta = delta_ref[0, 0]  # [1, bq]
+    s = _scores(q, k, i, j, scale=scale, masked=causal, bq=bq, bkv=bkv)
+    p = jnp.exp(s - lse[0][:, None])  # [bq, bkv]
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - delta[0][:, None]) * scale
+    dq_sc[:] += jax.lax.dot_general(
+        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(needed)
-    def _block():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]      # [1, bq]
-        delta = delta_ref[0, 0]  # [1, bq]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-            col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
-        p = jnp.exp(s - lse[0][:, None])  # [bq, bkv]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[0][:, None]) * scale
-        dq_sc[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == nj - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_sc[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_sc, dv_sc, *, scale, causal, bq, bkv):
-    jj = pl.program_id(2)  # key block
-    r = pl.program_id(3)   # grouped query head within this kv head
-    i = pl.program_id(4)   # query block
-    nr = pl.num_programs(3)
-    ni = pl.num_programs(4)
+def _dkv_kernel(*refs, scale, causal, bq, bkv):
+    # key block jj, query block i, grouped query head r of the kv head
+    (jj, i, r), first, last, refs = _grid_step(causal, refs, 3)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+     dk_ref, dv_ref, dk_sc, dv_sc) = refs
 
-    @pl.when((r == 0) & (i == 0))
+    @pl.when(first)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    # key block jj receives gradient from query blocks i >= jj
-    needed = (i * bq + bq - 1 >= jj * bkv) if causal else (i >= 0)
+    q = q_ref[0, 0]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
+    # on a key block no row reaches, s is _NEG_INF throughout and p 0
+    s = _scores(q, k, i, jj, scale=scale, masked=causal, bq=bq, bkv=bkv)
+    p = jnp.exp(s - lse[0][:, None])
+    dv_sc[:] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - delta[0][:, None]) * scale
+    dk_sc[:] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(needed)
-    def _block():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-            col = jj * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-            s = jnp.where(row >= col, s, _NEG_INF)
-        p = jnp.exp(s - lse[0][:, None])
-        dv_sc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[0][:, None]) * scale
-        dk_sc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when((r == nr - 1) & (i == ni - 1))
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
@@ -272,64 +387,71 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
     _, hkv, skv, _ = k.shape
     n_rep = hq // hkv
     nq, nkv = sq // bq, skv // bkv
+    plan = causal_block_plan(sq, skv, bq, bkv) if causal else None
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse4 = lse[:, :, None, :]      # [B, Hq, 1, S]
     delta4 = delta[:, :, None, :]
 
-    def clamp_j(i, j):
-        # same key-block-unit bound as the forward
-        return jnp.minimum(j, (i * bq + bq - 1) // bkv) if causal else j
+    # dq: a query block at a time, over its key blocks
+    tables = plan.by_query if causal else ()
+    blocks = _blocks_of(causal, 2)
 
-    dq = pl.pallas_call(
+    def q_rows(b_, h, *g_):
+        return b_, h, blocks(*g_)[0], 0
+
+    def kv_rows(b_, h, *g_):
+        return b_, h // n_rep, blocks(*g_)[1], 0
+
+    def q_stats(b_, h, *g_):
+        return b_, h, 0, blocks(*g_)[0]
+
+    dq = _call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv),
-        grid=(b, hq, nq, nkv),
-        compiler_params=_semantics("p", "p", "p", "a"),
+        "flash_dq", tables, (b, hq, nq, nkv), interpret,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda b_, h, i, j: (b_, h // n_rep, clamp_j(i, j), 0)),
-            pl.BlockSpec((1, 1, bkv, d),
-                         lambda b_, h, i, j: (b_, h // n_rep, clamp_j(i, j), 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b_, h, i, j: (b_, h, 0, i)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b_, h, i, j: (b_, h, 0, i)),
+            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, 1, bq), q_stats),
+            pl.BlockSpec((1, 1, 1, bq), q_stats),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, d), q_rows),
         out_shape=_struct((b, hq, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_dq",
-    )(q, k, v, g, lse4, delta4)
+    )(*tables, q, k, v, g, lse4, delta4)
 
-    def clamp_i(jj, i):
-        # key block jj only receives gradient from query blocks whose last
-        # row reaches its first key row jj*bkv — bound in QUERY block units
-        return jnp.maximum(i, (jj * bkv) // bq) if causal else i
+    # dk/dv: a key block at a time, over its query blocks and, at each,
+    # the n_rep query heads of the kv head
+    tables = plan.by_key if causal else ()
+    blocks = _blocks_of(causal, 3)
 
-    dk, dv = pl.pallas_call(
+    def q_rows(b_, hk, *g_):
+        _, i, r = blocks(*g_)
+        return b_, hk * n_rep + r, i, 0
+
+    def q_stats(b_, hk, *g_):
+        _, i, r = blocks(*g_)
+        return b_, hk * n_rep + r, 0, i
+
+    def kv_rows(b_, hk, *g_):
+        return b_, hk, blocks(*g_)[0], 0
+
+    dk, dv = _call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv),
-        grid=(b, hkv, nkv, n_rep, nq),
-        compiler_params=_semantics("p", "p", "p", "a", "a"),
+        "flash_dkv", tables, (b, hkv, nkv, nq, n_rep), interpret,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda b_, hk, jj, r, i: (b_, hk * n_rep + r,
-                                                   clamp_i(jj, i), 0)),
-            pl.BlockSpec((1, 1, bkv, d), lambda b_, hk, jj, r, i: (b_, hk, jj, 0)),
-            pl.BlockSpec((1, 1, bkv, d), lambda b_, hk, jj, r, i: (b_, hk, jj, 0)),
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda b_, hk, jj, r, i: (b_, hk * n_rep + r,
-                                                   clamp_i(jj, i), 0)),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda b_, hk, jj, r, i: (b_, hk * n_rep + r, 0,
-                                                   clamp_i(jj, i))),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda b_, hk, jj, r, i: (b_, hk * n_rep + r, 0,
-                                                   clamp_i(jj, i))),
+            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, 1, bq), q_stats),
+            pl.BlockSpec((1, 1, 1, bq), q_stats),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bkv, d), lambda b_, hk, jj, r, i: (b_, hk, jj, 0)),
-            pl.BlockSpec((1, 1, bkv, d), lambda b_, hk, jj, r, i: (b_, hk, jj, 0)),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bkv, d), kv_rows),
         ],
         out_shape=[
             _struct((b, hkv, skv, d), k.dtype, k),
@@ -339,9 +461,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
             pltpu.VMEM((bkv, d), jnp.float32),
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
-        interpret=interpret,
-        name="flash_dkv",
-    )(q, k, v, g, lse4, delta4)
+    )(*tables, q, k, v, g, lse4, delta4)
     return dq, dk, dv
 
 

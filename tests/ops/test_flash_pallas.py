@@ -5,10 +5,19 @@ tests/parallel/test_context_parallel.py:72-106)."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from scaletorch_tpu.models.layers import sdpa_attention
-from scaletorch_tpu.ops.pallas.flash import pallas_flash_attention
+from scaletorch_tpu.models.layers import sdpa_attention, sdpa_attention_with_lse
+from scaletorch_tpu.ops.pallas.flash import (
+    _FIRST,
+    _LAST,
+    MAX_CAUSAL_STEPS,
+    causal_block_plan,
+    flash_block_backward,
+    flash_forward_with_lse,
+    pallas_flash_attention,
+)
 
 
 def _qkv(b=2, hq=4, hkv=2, s=256, d=64, dtype=jnp.float32):
@@ -106,3 +115,191 @@ def test_flash_jax_rejects_nondivisible_gqa_heads():
     q, k, v = _qkv(hq=4, hkv=3, s=64, d=32)
     with pytest.raises(ValueError, match="multiple of key/value heads"):
         flash_attention_jax(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# the causal structure on the grid (PR 33): the plan, then the kernels on it
+# ---------------------------------------------------------------------------
+PLAN_SHAPES = [
+    # sq, skv, bq, bkv -> live blocks, key blocks no row reaches
+    ((8192, 8192, 512, 512), (136, 0)),   # train-0.6b-seq8k
+    ((128, 128, 64, 32), (6, 0)),
+    ((128, 128, 32, 64), (6, 0)),
+    ((64, 64, 64, 64), (1, 0)),           # one block
+    ((256, 256, 64, 64), (10, 0)),
+    ((192, 192, 64, 64), (6, 0)),
+    ((128, 64, 32, 32), (7, 0)),          # more rows than keys
+    ((64, 128, 32, 32), (3, 2)),          # keys no row reaches
+]
+PLAN_IDS = ["x".join(map(str, shape)) for shape, _ in PLAN_SHAPES]
+
+
+def _visible(shape, i, j):
+    """Block (i, j) of the triangle the kernels' mask draws."""
+    sq, skv, bq, bkv = shape
+    tril = np.arange(sq)[:, None] >= np.arange(skv)[None, :]
+    return tril[i * bq:(i + 1) * bq, j * bkv:(j + 1) * bkv]
+
+
+@pytest.mark.parametrize("shape,counts", PLAN_SHAPES, ids=PLAN_IDS)
+def test_plan_counts(shape, counts):
+    plan = causal_block_plan(*shape)
+    assert (plan.live, plan.dead) == counts
+    sq, skv, bq, bkv = shape
+    # no grid step but a live block's (and one for a key block that has
+    # none, to write its zeros): the rectangle had nq * nkv of them
+    assert len(plan.by_query[0]) == plan.live
+    assert len(plan.by_key[0]) == plan.live + plan.dead
+    assert plan.live + plan.dead <= (sq // bq) * (skv // bkv)
+    for table in plan.by_query + plan.by_key:  # the cache shares them
+        assert table.dtype == np.int32 and not table.flags.writeable
+
+
+@pytest.mark.parametrize("shape", [s for s, _ in PLAN_SHAPES], ids=PLAN_IDS)
+def test_plan_visits_the_blocks_tril_has_something_in(shape):
+    """Every live pair once, in the forward's walk, and no other: a block
+    is walked iff the triangle has a visible element inside it."""
+    sq, skv, bq, bkv = shape
+    q_blk, k_blk, _ = causal_block_plan(*shape).by_query
+    pairs = list(zip(q_blk.tolist(), k_blk.tolist()))
+    assert len(set(pairs)) == len(pairs)
+    for i in range(sq // bq):
+        for j in range(skv // bkv):
+            assert ((i, j) in pairs) == bool(_visible(shape, i, j).any())
+
+
+@pytest.mark.parametrize("shape", [s for s, _ in PLAN_SHAPES], ids=PLAN_IDS)
+def test_plan_walks_open_and_close_each_accumulation_once(shape):
+    """Both walks: an outer block at a time, its inner blocks ascending,
+    flagged where its accumulation opens and closes. The dk/dv walk
+    holds the forward's pairs, and for a key block with none, one block
+    the mask empties (so dk = dv = 0 is computed, not special-cased)."""
+    sq, skv, bq, bkv = shape
+    plan = causal_block_plan(*shape)
+    live = set(zip(*(t.tolist() for t in plan.by_query[:2])))
+    for tables, n_outer in ((plan.by_query, sq // bq),
+                            (plan.by_key, skv // bkv)):
+        outer, inner, flags = (t.tolist() for t in tables)
+        assert outer == sorted(outer) and set(outer) == set(range(n_outer))
+        for o in range(n_outer):
+            steps = [n for n, b in enumerate(outer) if b == o]
+            assert [inner[n] for n in steps] == sorted(
+                inner[n] for n in steps)
+            assert [flags[n] for n in steps] == [
+                _FIRST * (n == steps[0]) | _LAST * (n == steps[-1])
+                for n in steps]
+    k_blk, q_blk, _ = (t.tolist() for t in plan.by_key)
+    walked = set(zip(q_blk, k_blk))
+    assert live <= walked and len(walked) == len(k_blk)
+    for i, j in walked - live:
+        assert i == 0 and not _visible(shape, i, j).any()
+
+
+def test_plan_refuses_more_blocks_than_its_tables_hold():
+    """The tables lie in SMEM whole: past ``MAX_CAUSAL_STEPS`` a call
+    says so, with the way out, before Mosaic is asked. 361 blocks a
+    side is the last that fits, whatever the heads."""
+    assert causal_block_plan(
+        128 * 361, 128 * 361, 128, 128).live == 65341 <= MAX_CAUSAL_STEPS
+    with pytest.raises(ValueError, match="larger blocks"):
+        causal_block_plan(128 * 362, 128 * 362, 128, 128)
+    q = jax.ShapeDtypeStruct((1, 8, 128 * 362, 32), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 1, 128 * 362, 32), jnp.float32)
+    with pytest.raises(ValueError, match="walks 65703 live blocks"):
+        jax.eval_shape(lambda q, k, v: pallas_flash_attention(
+            q, k, v, block_q=128, block_kv=128, interpret=True), q, kv, kv)
+    jax.eval_shape(lambda q, k, v: pallas_flash_attention(
+        q, k, v, block_q=256, block_kv=256, interpret=True), q, kv, kv)
+
+
+def _sq_loss(fn):
+    return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 1), (2, 2)],
+                         ids=["gqa2", "mqa", "mha"])
+def test_four_by_four_blocks_forward_and_gradients(hq, hkv, causal):
+    """4 x 4 blocks: causal has dead, interior and diagonal blocks at
+    once (6 + 6 + 4); ``causal=False`` keeps the rectangle, unmasked."""
+    q, k, v = _qkv(b=1, hq=hq, hkv=hkv, s=128, d=32)
+
+    def flash(q, k, v):
+        return pallas_flash_attention(
+            q, k, v, causal=causal, block_q=32, block_kv=32, interpret=True)
+
+    def ref(q, k, v):
+        return sdpa_attention(q, k, v, causal=causal)
+
+    assert jnp.max(jnp.abs(flash(q, k, v) - ref(q, k, v))) < 1e-5
+    gp = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(_sq_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        assert jnp.max(jnp.abs(a - b)) < 1e-4
+
+
+@pytest.mark.parametrize("bq,bkv", [(64, 32), (32, 64), (16, 64)])
+def test_mismatched_blocks_gradients_gqa(bq, bkv):
+    """bq != bkv: a diagonal block is then not square and a query block
+    has more than one (or a key block several query blocks') of them."""
+    q, k, v = _qkv(b=1, hq=4, hkv=2, s=128, d=32)
+
+    def flash(q, k, v):
+        return pallas_flash_attention(
+            q, k, v, causal=True, block_q=bq, block_kv=bkv, interpret=True)
+
+    gp = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(_sq_loss(sdpa_attention), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        assert jnp.max(jnp.abs(a - b)) < 1e-4
+
+
+@pytest.mark.parametrize("sq,skv", [(64, 128), (128, 64)],
+                         ids=["keys-past-the-rows", "rows-past-the-keys"])
+def test_causal_rectangle_row_ge_column(sq, skv):
+    """The kernels' causal is row >= column from the top left, whatever
+    the two lengths. Keys that no row reaches get dk = dv = 0 (their
+    blocks have no live pair, so the plan walks one the mask empties)."""
+    kq, kk, kv = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(kq, (1, 4, sq, 32))
+    k = jax.random.normal(kk, (1, 2, skv, 32))
+    v = jax.random.normal(kv, (1, 2, skv, 32))
+
+    def ref(q, k, v):
+        k, v = (jnp.repeat(x, 2, axis=1) for x in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(32.0)
+        mask = jnp.arange(sq)[:, None] >= jnp.arange(skv)[None, :]
+        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+    def flash(q, k, v):
+        return pallas_flash_attention(
+            q, k, v, causal=True, block_q=32, block_kv=32, interpret=True)
+
+    assert jnp.max(jnp.abs(flash(q, k, v) - ref(q, k, v))) < 1e-5
+    gp = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(_sq_loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        assert jnp.max(jnp.abs(a - b)) < 1e-4
+    if skv > sq:
+        assert not jnp.any(gp[1][:, :, sq:]) and not jnp.any(gp[2][:, :, sq:])
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_raw_entries_match_the_differentiable_op(causal):
+    """Ring attention's pair: ``flash_forward_with_lse`` (causal on the
+    diagonal hop, full on the others) and ``flash_block_backward`` give
+    what ``pallas_flash_attention`` and its VJP give, and the lse is the
+    dense one."""
+    q, k, v = _qkv(b=1, hq=4, hkv=2, s=128, d=32)
+    kw = dict(causal=causal, block_q=32, block_kv=32, interpret=True)
+    out, lse = flash_forward_with_lse(q, k, v, **kw)
+    want, vjp = jax.vjp(
+        lambda q, k, v: pallas_flash_attention(q, k, v, **kw), q, k, v)
+    assert jnp.array_equal(out, want)
+    _, ref_lse = sdpa_attention_with_lse(q, k, v, causal=causal)
+    assert jnp.max(jnp.abs(lse - ref_lse)) < 1e-5
+    dout = jnp.cos(out)
+    for a, b in zip(flash_block_backward(q, k, v, out, lse, dout, **kw),
+                    vjp(dout)):
+        assert jnp.array_equal(a, b)

@@ -1,11 +1,13 @@
-"""The paged kernels, and the two paged step programs that call them,
-compiled for the v5e at real widths with no chip attached: Mosaic
-refuses here what it would refuse there (a slice off the tiling, too
-much VMEM), and the compiled step shows what XLA made of the pool (a
-copy per layer, a re-laid stack: PR 27's expert stack, PR 28's page
-pool), neither of which interpret mode can see. One file, one fixture,
-nothing at import time: only the worker that runs this file loads the
-TPU compiler. Quick tier, ~1 s a kernel case, 2-20 s a step program.
+"""The paged kernels, the two paged step programs that call them, and
+(last section) the training flash kernels, compiled for the v5e at real
+widths with no chip attached: Mosaic refuses here what it would refuse
+there (a slice off the tiling, too much VMEM or SMEM), and the compiled
+step shows what XLA made of the pool (a copy per layer, a re-laid
+stack: PR 27's expert stack, PR 28's page pool), neither of which
+interpret mode can see. One file, one fixture, nothing at import time:
+only the worker that runs this file loads the TPU compiler, and a
+second file that did would skip wherever two workers may not both hold
+libtpu. Quick tier, ~1 s a kernel case, 2-20 s a step program.
 """
 
 import json
@@ -18,6 +20,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from benchmarks.lib.trace import short_name
+from scaletorch_tpu.ops.pallas.flash import (
+    MAX_CAUSAL_STEPS,
+    causal_block_plan,
+    pallas_flash_attention,
+)
 from scaletorch_tpu.ops.pallas.paged_attention import (
     pallas_paged_decode_attention,
     pallas_paged_write,
@@ -329,3 +337,103 @@ def test_narrow_heads_take_the_lax_pair_in_the_same_loop(one_chip):
         assert not re.search(
             rf"= bf16\[(1,)?{layer}\]\S* (?!parameter)", text)
     assert decode.memory_analysis().temp_size_in_bytes < 2.1 * pool_pair
+
+
+# ---------------------------------------------------------------------------
+# the flash kernels (training): what the compiled text shows is what the
+# benchmark's readers will find in a trace. They tell the three kernels
+# by what each RETURNS, so a fourth kind of Mosaic call, a fused backward
+# or a result of another shape would leave `train_attn_roofline` with
+# nothing, or the wrong thing, to read.
+# ---------------------------------------------------------------------------
+def _flash_calls(one_chip, fn, hq, hkv, s, d):
+    """The Mosaic calls of ``fn(q, k, v)`` at batch 1, bf16, as the trace
+    reader names them: ``instr | opcode | target | result``."""
+    def arg(heads):
+        return jax.ShapeDtypeStruct((1, heads, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(fn).lower(arg(hq), arg(hkv), arg(hkv)).compile().as_text()
+    return [short_name(re.sub(r"^\s*(ROOT )?", "", line))
+            for line in _mosaic_calls(text)]
+
+
+def _flash_grad(**kw):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(pallas_flash_attention(
+            q, k, v, **kw).astype(jnp.float32)),
+        argnums=(0, 1, 2))
+
+
+def _flash_kernel(name):
+    """``jvp_flash_fwd_.1 | custom-call | ...`` -> ``flash_fwd``: the
+    instruction carries the kernel's name inside its autodiff scope."""
+    found = re.search(r"flash_(fwd|dq|dkv)", name.split(" | ")[0])
+    return found.group(0) if found else name
+
+
+def _flash_kinds(calls):
+    return sorted({_flash_kernel(name) for name in calls})
+
+
+def test_training_shape_is_three_kernels_the_roofline_can_find(one_chip):
+    """qwen3-0.6b-train at seq 8192: 1 x 16 / 8 heads x 8192 x 128, bf16,
+    causal. Forward and gradient compile; the program holds the three
+    kernels and no other, and the patterns of
+    ``benchmarks/metrics/train_attn_roofline.json`` charge one call as
+    a forward and two as one backward."""
+    calls = _flash_calls(one_chip, _flash_grad(), 16, 8, 8192, 128)
+    assert _flash_kinds(calls) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert len(calls) == 3, calls
+
+    with open(os.path.join(
+            REPO, "benchmarks", "metrics", "train_attn_roofline.json")) as f:
+        terms = json.load(f)["reducer"]["terms"]
+    found = {term["charge"]: [
+        name for name in calls
+        if any(re.search(p, name) for p in term["patterns"])]
+        for term in terms}
+    assert [_flash_kernel(n) for n in found["forward"]] == ["flash_fwd"], found
+    assert _flash_kinds(found["backward"]) == ["flash_dkv", "flash_dq"], found
+    assert len(found["backward"]) == 2 == terms[1]["events_per_call"], found
+    assert found["forward"][0].endswith(
+        "(bf16[1,16,8192,128], f32[1,16,1,8192])"), found
+    # and the other reader of these calls counts exactly the same three
+    with open(os.path.join(
+            REPO, "benchmarks", "metrics",
+            "train_attn_kernel_share.json")) as f:
+        share = json.load(f)["reducer"]["patterns"]
+    assert [n for n in calls if any(re.search(p, n) for p in share)] == calls
+
+    plan = causal_block_plan(8192, 8192, 512, 512)
+    assert (plan.live, plan.dead) == (136, 0)  # of 256: no dead grid step
+    assert len(plan.by_query[0]) == len(plan.by_key[0]) == 136
+
+
+def test_flash_forward_alone_is_one_call(one_chip):
+    calls = _flash_calls(
+        one_chip, lambda q, k, v: pallas_flash_attention(q, k, v),
+        16, 8, 8192, 128)
+    assert _flash_kinds(calls) == ["flash_fwd"] and len(calls) == 1, calls
+
+
+@pytest.mark.parametrize("hq,hkv,s,d,kw", [
+    (16, 8, 8192, 128, dict(causal=False)),  # ring's off-diagonal hops
+    (16, 8, 2048, 128, {}),     # its diagonal hop at cp 4
+    (8, 1, 4096, 128, {}),      # MQA: eight query heads a key block
+    (16, 16, 4096, 64, {}),     # MHA at head_dim 64
+    (4, 2, 1536, 128, {}),      # three blocks a side
+    (32, 8, 32768, 128, {}),    # 64 x 64 blocks, 2,080 live
+    (4, 2, 131072, 128, {}),    # Ulysses at cp 4: the whole 128k sequence
+    # the longest walk the tables take (flash.MAX_CAUSAL_STEPS), in SMEM
+    (8, 1, 361 * 128, 128, dict(block_q=128, block_kv=128)),
+], ids=["rect", "seq2k", "mqa", "mha-d64", "seq1536", "seq32k-nrep4",
+        "seq128k", "longest-walk"])
+def test_flash_compiles_for_its_other_callers_shapes(
+        one_chip, hq, hkv, s, d, kw):
+    calls = _flash_calls(one_chip, _flash_grad(**kw), hq, hkv, s, d)
+    assert _flash_kinds(calls) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert len(calls) == 3, calls
+    if kw.get("block_q"):
+        assert causal_block_plan(s, s, 128, 128).live == 65341
+        assert 65341 <= MAX_CAUSAL_STEPS < 362 * 363 // 2
