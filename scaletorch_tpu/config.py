@@ -86,7 +86,8 @@ class ModelArguments:
     model_type: str = field(
         default="llama",
         metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
-                          "olmo_hybrid | gpt_moe | lenet | mingpt"},
+                          "olmo_hybrid | qwen3_next | gpt_moe | lenet | "
+                          "mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -116,6 +117,22 @@ class ModelArguments:
                           "rope_theta null = no rotary embedding; "
                           "omitted = --rope_theta."},
     )
+    # qwen3_next, by the published config.json names: every
+    # full_attention_interval-th layer is full attention (the others
+    # linear attention), rotary embedding on the first
+    # partial_rotary_factor of each head, a shared expert of this width
+    # beside the routed ones (0: none)
+    full_attention_interval: int = 4
+    partial_rotary_factor: float = 1.0
+    shared_expert_intermediate_size: int = 0
+    embed_init_std: Optional[float] = field(
+        default=None,
+        metadata={"help": "Standard deviation the random initialiser "
+                          "draws the token embedding at (qwen3_next "
+                          "only; unset: 0.02, HF's initializer_range). "
+                          "A property of random weights, not of the "
+                          "model."},
+    )
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "
@@ -132,6 +149,17 @@ class ModelArguments:
     num_experts: int = 8
     num_experts_per_tok: int = 2
     moe_intermediate_size: Optional[int] = None
+    # a chip's share of an expert layer (dropless routing): the router's
+    # width where num_experts counts only the experts HELD here, and the
+    # id of the first of them; the held experts are
+    # [first_expert_id, first_expert_id + num_experts)
+    num_routed_experts: Optional[int] = field(
+        default=None,
+        metadata={"help": "Width of the router where --num_experts is a "
+                          "chip's share of the expert layer (unset: "
+                          "every expert is held)."},
+    )
+    first_expert_id: int = 0
     moe_capacity_factor: float = 1.25
     norm_topk_prob: Optional[bool] = field(
         default=None,

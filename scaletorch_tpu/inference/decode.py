@@ -50,14 +50,20 @@ def _resolve_donate(donate_cache: Optional[bool]) -> bool:
 
 
 def resolve_forward_cached(cfg) -> Callable:
-    """The cache-aware forward for a model config: Qwen3-MoE and GPT-MoE
-    and Olmo-Hybrid have their own cached forwards; every other
-    LlamaConfig subclass (Llama, Qwen3) shares the Llama one."""
+    """The cache-aware forward for a model config: Qwen3-MoE, GPT-MoE,
+    Olmo-Hybrid and Qwen3-Next (a subclass of the hybrid's: asked
+    first) have their own cached forwards; every other LlamaConfig
+    subclass (Llama, Qwen3) shares the Llama one."""
     from scaletorch_tpu.models.gpt_moe import GPTMoEConfig
     from scaletorch_tpu.models.llama import LlamaConfig
     from scaletorch_tpu.models.olmo_hybrid import OlmoHybridConfig
     from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
+    from scaletorch_tpu.models.qwen3_next import Qwen3NextConfig
 
+    if isinstance(cfg, Qwen3NextConfig):
+        from scaletorch_tpu.models import qwen3_next
+
+        return qwen3_next.forward_cached
     if isinstance(cfg, OlmoHybridConfig):
         from scaletorch_tpu.models import olmo_hybrid
 
@@ -81,10 +87,13 @@ def resolve_forward_cached(cfg) -> Callable:
 
 def counts_routing(cfg) -> bool:
     """Whether the config's cached forward counts what it routes
-    (``return_routing``): the Qwen3-MoE family, OLMoE included."""
+    (``return_routing``): the Qwen3-MoE family, OLMoE included, and
+    Qwen3-Next, whose steps take the row mask of a state-carrying model
+    and the routing accumulator side by side."""
     from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
+    from scaletorch_tpu.models.qwen3_next import Qwen3NextConfig
 
-    return isinstance(cfg, Qwen3MoEConfig)
+    return isinstance(cfg, (Qwen3MoEConfig, Qwen3NextConfig))
 
 
 def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:

@@ -21,6 +21,15 @@ that exist: inactive slots and padding positions count nowhere):
     moe_peak_load_rows       prefill calls only: the fullest expert's
                              rows, summed over calls and layers
     moe_prefill_assignments  the prefill calls' share of the first
+    moe_assignments_elsewhere  rows whose expert another share of the
+                             layer holds (``qwen3_moe.ExpertShare``):
+                             no work here and not dropped; 0 where every
+                             expert is held
+
+``snapshot`` adds ``moe_assignments_held`` (the first counter under the
+name that pairs with the last: under uniform routing their ratio is
+held : absent experts, and it is the first thing to look at when a
+step of a share is slow or fast) and the gauge ``moe_experts_held``.
 """
 
 from __future__ import annotations
@@ -32,7 +41,8 @@ import numpy as np
 
 ROUTING_COUNTERS = (
     "moe_routed_assignments", "moe_dropped_assignments",
-    "moe_expert_visits", "moe_peak_load_rows", "moe_prefill_assignments")
+    "moe_expert_visits", "moe_peak_load_rows", "moe_prefill_assignments",
+    "moe_assignments_elsewhere")
 
 # the accumulator is uint32 and wraps; the host adds the change since
 # its last reading modulo 2**32, which is exact while fewer than 2**32
@@ -51,7 +61,8 @@ def step_counts(counts: Dict[str, "jax.Array"], *, prefill: bool):
         counts["routed"], counts["dropped"],
         zero if prefill else counts["expert_visits"],
         counts["peak_load_rows"] if prefill else zero,
-        counts["routed"] if prefill else zero]).astype(jnp.uint32)
+        counts["routed"] if prefill else zero,
+        counts["elsewhere"]]).astype(jnp.uint32)
 
 
 class RoutingCounters:
@@ -114,6 +125,8 @@ class RoutingCounters:
         mean_load = totals["moe_prefill_assignments"] / self.num_experts
         return dict(
             totals,
+            moe_assignments_held=totals["moe_routed_assignments"],
+            moe_experts_held=self.num_experts,
             moe_experts_touched_per_step=(
                 totals["moe_expert_visits"] / steps if steps else 0.0),
             moe_peak_over_mean_load=(

@@ -129,6 +129,13 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     return _rms_norm_p(float(eps), x, weight)
 
 
+def rms_norm_zero_centered(x: jax.Array, weight: jax.Array,
+                           eps: float = 1e-6) -> jax.Array:
+    """RMSNorm under the zero-centred gain ``1 + weight`` (Qwen3-Next's
+    layer, q/k and final norms: a gain initialised at 0)."""
+    return rms_norm(x, 1.0 + weight.astype(jnp.float32), eps)
+
+
 # ---- RoPE -------------------------------------------------------------------
 def get_cos_sin(
     seq_len: int,
@@ -168,7 +175,15 @@ def apply_rotary_pos_emb(
 ) -> Tuple[jax.Array, jax.Array]:
     """Apply RoPE. q/k: [B, H, S, Dh]; cos/sin: [S, Dh] (broadcast over
     B, H) or per-batch [B, S, Dh] (decode's per-slot positions; broadcast
-    over H only)."""
+    over H only). Tables narrower than the head (HF
+    ``partial_rotary_factor``): the first ``cos.shape[-1]`` dims of each
+    head rotate among themselves, the others pass through."""
+    rotary = cos.shape[-1]
+    if rotary < q.shape[-1]:
+        q_rot, k_rot = apply_rotary_pos_emb(
+            q[..., :rotary], k[..., :rotary], cos, sin)
+        return (jnp.concatenate([q_rot, q[..., rotary:]], axis=-1),
+                jnp.concatenate([k_rot, k[..., rotary:]], axis=-1))
     if cos.ndim == 3:
         cos = cos[:, None, :, :].astype(q.dtype)
         sin = sin[:, None, :, :].astype(q.dtype)

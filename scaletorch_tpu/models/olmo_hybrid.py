@@ -14,7 +14,9 @@ and NO rotary embedding (``rope_parameters.rope_theta`` is null). It is
 ``llama.attention_mix_cached``: the paged pool, the Mosaic pair.
 
 *Linear-attention layer* (gated delta rule). With ``u`` the layer's
-input, ``H`` heads of key width ``d_k`` and value width ``d_v``:
+input, ``H`` heads of key width ``d_k`` and value width ``d_v``
+(``linear_num_key_heads`` < ``H``: q and k have that many heads, each
+repeated over ``H / key heads`` consecutive value heads):
 
     q~ = u Wq   k~ = u Wk   v~ = u Wv   z = u Wg
     (q', k', v') = silu(conv(q~, k~, v~))     depthwise, causal, width 4
@@ -105,12 +107,11 @@ class OlmoHybridConfig(LlamaConfig):
     linear_allow_neg_eigval: bool = True
 
     def __post_init__(self) -> None:
-        if self.linear_num_key_heads != self.linear_num_value_heads:
-            raise NotImplementedError(
-                "linear-attention layers with fewer key heads than value "
-                f"heads ({self.linear_num_key_heads} / "
-                f"{self.linear_num_value_heads}): the key heads' repeat "
-                "over value-head groups is not written")
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                f"linear_num_value_heads {self.linear_num_value_heads} is "
+                f"no multiple of the {self.linear_num_key_heads} key heads "
+                "that are repeated over them")
         kinds = self.layer_kinds
         if len(kinds) != self.num_hidden_layers:
             raise ValueError(
@@ -454,7 +455,7 @@ def linear_attention_mix(
     b, s, _ = u.shape
     heads, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                      cfg.linear_value_head_dim)
-    kq = cfg.linear_key_size
+    key_heads, kq = cfg.linear_num_key_heads, cfg.linear_key_size
 
     with jax.named_scope("gdn.conv"):
         qkv = jnp.concatenate(
@@ -471,8 +472,13 @@ def linear_attention_mix(
             rows, keep[:, :, None], axis=1).astype(tail.dtype)
 
     with jax.named_scope("gdn.recurrence"):
-        q = l2norm(mixed[..., :kq].reshape(b, s, heads, dk)) * dk ** -0.5
-        k = l2norm(mixed[..., kq:2 * kq].reshape(b, s, heads, dk))
+        q = l2norm(mixed[..., :kq].reshape(b, s, key_heads, dk)) * dk ** -0.5
+        k = l2norm(mixed[..., kq:2 * kq].reshape(b, s, key_heads, dk))
+        if key_heads != heads:
+            # a key head serves ``heads // key_heads`` consecutive value
+            # heads, each with a state of its own
+            q, k = (jnp.repeat(a, heads // key_heads, axis=2)
+                    for a in (q, k))
         v = mixed[..., 2 * kq:].reshape(b, s, heads, dv)
         # the two gates' projections (one column a head) leave their
         # matmul in float32: a decay is a product over every token
@@ -524,7 +530,7 @@ def _rope_tables(cfg: OlmoHybridConfig, seq: int, positions):
                        positions=positions)
 
 
-def _layer_of(stack: Params, period: jax.Array, j: int) -> Params:
+def layer_of(stack: Params, period: jax.Array, j: int) -> Params:
     """Layer ``j`` of period ``period`` out of the whole stacks
     ``[periods, layers of the kind in a period, ...]``, each matrix by
     ONE dynamic slice that its consumer fuses. (Scanned over periods
@@ -539,7 +545,7 @@ def _layer_of(stack: Params, period: jax.Array, j: int) -> Params:
     return {name: one(a) for name, a in stack.items()}
 
 
-def _period_layers(pattern: Tuple[str, ...]):
+def period_layers(pattern: Tuple[str, ...]):
     """The layers of one period in order, statically: (kind, the name of
     its parameter stack, its place among the period's layers of that
     kind)."""
@@ -559,7 +565,7 @@ def _close_block(h: jax.Array, mixed: jax.Array, layer: Params,
     return _mlp_block(h, layer, cfg)
 
 
-class _SelfKV:
+class SelfKV:
     """K/V of the call itself (``forward``): no cache to write or read."""
 
     def write(self, cache, layer, new, positions, write_mask):
@@ -590,7 +596,7 @@ def forward_cached(
     prefix of each sequence; None: all). A sequence whose first row is
     at position 0 starts from an empty state."""
     pattern = cfg.period_pattern
-    layers = _period_layers(pattern)
+    layers = period_layers(pattern)
     n_lin, n_full = pattern.count(LINEAR), pattern.count(FULL)
     kv_io = kv_io or DenseKVIO()
     x = _llama.embed(params, input_ids, cfg)
@@ -602,7 +608,7 @@ def forward_cached(
     def period_fn(carry, index):
         h, (ck, cv, state, conv) = carry
         for kind, stack, j in layers:
-            layer = _layer_of(params["layers"][stack], index, j)
+            layer = layer_of(params["layers"][stack], index, j)
             if kind == LINEAR:
                 at = index * n_lin + j
                 old_s = jax.lax.dynamic_index_in_dim(state, at, 0, False)
@@ -648,7 +654,7 @@ def forward(
     the sequence itself, the delta rule from an empty state in its
     chunked form, or row after row with ``sequential`` (the oracle the
     tests hold the chunked form and the cache to)."""
-    layers = _period_layers(cfg.period_pattern)
+    layers = period_layers(cfg.period_pattern)
     b, s = input_ids.shape
     x = _llama.embed(params, input_ids, cfg)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
@@ -659,7 +665,7 @@ def forward(
 
     def period_fn(h, index):
         for kind, stack, j in layers:
-            layer = _layer_of(params["layers"][stack], index, j)
+            layer = layer_of(params["layers"][stack], index, j)
             if kind == LINEAR:
                 out, _, _ = linear_attention_mix(
                     h, layer, cfg, state0, tail0, sequential=sequential)
@@ -667,7 +673,7 @@ def forward(
                 with jax.named_scope("attn"):
                     out, _, _ = _llama.attention_mix_cached(
                         h, layer, 0, None, None, cos, sin, positions, cfg,
-                        kv_io=_SelfKV())
+                        kv_io=SelfKV())
             h = _close_block(h, out, layer, cfg)
         return h, None
 

@@ -215,6 +215,72 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         linear_allow_neg_eigval=True,
         rope_parameters={"rope_theta": None},
     ),
+    # Qwen3-Next-80B-A3B-Instruct (Qwen/Qwen3-Next-80B-A3B-Instruct
+    # config.json), as published: 48 layers (three gated delta-rule
+    # layers, one gated full-attention layer, repeated), each with 512
+    # routed experts of width 512 (top 10) and a gated shared expert;
+    # 80 B parameters = 160 GB in bf16, sixteen v5e chips at the least.
+    # One chip serves a share (benchmarks/configs/
+    # qwen3-next-80b-a3b-serve.json): --num_hidden_layers 12,
+    # --num_experts 128 --num_routed_experts 512, a quarter of the
+    # vocabulary.
+    "qwen3-next-80b-a3b": dict(
+        model_type="qwen3_next",
+        vocab_size=151936,
+        hidden_size=2048,
+        intermediate_size=5120,
+        num_hidden_layers=48,
+        num_attention_heads=16,
+        num_key_value_heads=2,
+        head_dim=256,
+        rope_theta=1e7,
+        partial_rotary_factor=0.25,
+        full_attention_interval=4,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=262144,
+        tie_word_embeddings=False,
+        linear_num_key_heads=16,
+        linear_num_value_heads=32,
+        linear_key_head_dim=128,
+        linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+        num_experts=512,
+        num_experts_per_tok=10,
+        moe_intermediate_size=512,
+        shared_expert_intermediate_size=512,
+        norm_topk_prob=True,
+    ),
+    # The same family at a size the CPU tests serve: two periods, 2 key
+    # heads over 4 value heads, a quarter-rotary head, and a SHARE of
+    # the experts: 8 of 16 routed ones held here, from id 4.
+    "qwen3-next-tiny": dict(
+        model_type="qwen3_next",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=8,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        head_dim=32,
+        rope_theta=1e4,
+        partial_rotary_factor=0.25,
+        full_attention_interval=4,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=4096,
+        tie_word_embeddings=False,
+        linear_num_key_heads=2,
+        linear_num_value_heads=4,
+        linear_key_head_dim=8,
+        linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
+        num_experts=8,
+        num_routed_experts=16,
+        first_expert_id=4,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=48,
+        norm_topk_prob=True,
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

@@ -32,7 +32,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from scaletorch_tpu.models import llama as _llama
-from scaletorch_tpu.models.layers import fan_in_uniform, get_cos_sin, rms_norm
+from scaletorch_tpu.models.layers import (
+    fan_in_uniform,
+    get_cos_sin,
+    rms_norm,
+    swiglu,
+)
 from scaletorch_tpu.models.llama import Params
 from scaletorch_tpu.models.qwen3 import Qwen3Config
 from scaletorch_tpu.models.registry import get_attention_backend
@@ -54,8 +59,36 @@ def _grouped_mlp_env_default() -> bool:
     return bool(get_env("SCALETORCH_TPU_GROUPED_MLP_KERNEL"))
 
 
+class ExpertShare:
+    """What a configuration with the fields ``num_experts``,
+    ``num_routed_experts`` and ``first_expert_id`` says about a chip's
+    share of an expert layer: the router is ``router_width`` wide, the
+    experts held here are ``[first_expert_id, first_expert_id +
+    num_experts)`` of it (``num_routed_experts`` None: all of them)."""
+
+    @property
+    def router_width(self) -> int:
+        return self.num_routed_experts or self.num_experts
+
+    @property
+    def holds_every_expert(self) -> bool:
+        return self.router_width == self.num_experts
+
+    def check_expert_share(self) -> None:
+        last = self.first_expert_id + self.num_experts
+        if self.first_expert_id < 0 or last > self.router_width:
+            raise ValueError(
+                f"experts [{self.first_expert_id}, {last}) are not among "
+                f"the {self.router_width} the router chooses from "
+                "(first_expert_id, num_experts, num_routed_experts)")
+        if self.num_experts_per_tok > self.router_width:
+            raise ValueError(
+                f"num_experts_per_tok {self.num_experts_per_tok} of "
+                f"{self.router_width} routed experts")
+
+
 @dataclass(frozen=True)
-class Qwen3MoEConfig(Qwen3Config):
+class Qwen3MoEConfig(ExpertShare, Qwen3Config):
     # Qwen3-30B-A3B-style knobs (reference model_qwen3_moe.py + HF config)
     num_experts: int = 8
     num_experts_per_tok: int = 2
@@ -71,6 +104,19 @@ class Qwen3MoEConfig(Qwen3Config):
     # (ops/grouped_matmul.py), O(N k H) memory. ``capacity_factor`` and
     # ``moe_dispatch`` then do nothing. Single device only for now.
     dropless: bool = False
+    # A chip's share of the expert layer (``ExpertShare``; dropless
+    # only): ``num_experts`` counts the experts held here, the router is
+    # ``num_routed_experts`` wide and every token still takes its
+    # ``num_experts_per_tok`` of all of them; a choice held elsewhere
+    # costs no expert work here and adds nothing, and the weights of the
+    # held ones stay what the uncut layer gives them. No exchange with
+    # the other shares is written: the partial sum is the block's result.
+    num_routed_experts: Optional[int] = None
+    first_expert_id: int = 0
+    # A shared expert of this width (dropless only; 0: none): one SwiGLU
+    # every token takes, under a sigmoid gate of the token, added to the
+    # routed sum.
+    shared_expert_intermediate_size: int = 0
     # Interleaved dense/sparse architecture knobs (HF Qwen3MoeConfig):
     # layer i runs a dense SwiGLU MLP (intermediate_size) instead of the
     # MoE block when i is in mlp_only_layers OR (i+1) % decoder_sparse_step
@@ -115,6 +161,16 @@ class Qwen3MoEConfig(Qwen3Config):
                 f"mlp_only_layers indices {bad} out of range for "
                 f"{self.num_hidden_layers} layers"
             )
+        self.check_expert_share()
+        if not self.dropless and (
+                not self.holds_every_expert
+                or self.shared_expert_intermediate_size):
+            raise NotImplementedError(
+                "a share of the experts (num_routed_experts "
+                f"{self.num_routed_experts}) or a shared expert "
+                f"(width {self.shared_expert_intermediate_size}) under "
+                "capacity dispatch: both are written for dropless "
+                "routing only (qwen3_moe.dropless_block)")
         if not any(self.sparse_layout()):
             raise ValueError(
                 "no layer is sparse under mlp_only_layers="
@@ -188,9 +244,10 @@ class Qwen3MoEConfig(Qwen3Config):
         n_sparse = sum(self.sparse_layout())
         n_dense = self.num_hidden_layers - n_sparse
         attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
-        moe = self.num_experts * 3 * h * self.moe_intermediate_size
+        moe = (self.num_experts * 3 * h * self.moe_intermediate_size
+               + shared_expert_params(self))
         dense_mlp = 3 * h * self.intermediate_size
-        router = h * self.num_experts
+        router = h * self.router_width
         norms = 2 * h + sum(self.qk_norm_sizes)
         per_common = attn + norms
         head = 0 if self.tie_word_embeddings else v * h
@@ -209,9 +266,10 @@ class Qwen3MoEConfig(Qwen3Config):
         n_sparse = sum(self.sparse_layout())
         n_dense = self.num_hidden_layers - n_sparse
         attn = h * self.q_size + 2 * h * self.kv_size + self.q_size * h
-        moe = self.num_experts_per_tok * 3 * h * self.moe_intermediate_size
+        moe = (self.num_experts_per_tok * 3 * h * self.moe_intermediate_size
+               + shared_expert_params(self))
         dense_mlp = 3 * h * self.intermediate_size
-        router = h * self.num_experts
+        router = h * self.router_width
         norms = 2 * h + sum(self.qk_norm_sizes)
         head = 0 if self.tie_word_embeddings else v * h
         return (
@@ -220,6 +278,42 @@ class Qwen3MoEConfig(Qwen3Config):
             + n_dense * dense_mlp
             + v * h + h + head
         )
+
+
+def shared_expert_params(cfg) -> int:
+    """Parameters of one layer's shared expert and its gate."""
+    width = cfg.shared_expert_intermediate_size
+    return (3 * width + 1) * cfg.hidden_size if width else 0
+
+
+def init_moe_params(keys, cfg, lead: Tuple[int, ...]) -> Params:
+    """The sparse MLP's own parameters, stacked under ``lead``: the
+    router over ``cfg.router_width`` experts, the ``cfg.num_experts``
+    held here (``keys[:4]``) and, where the configuration has one, the
+    shared expert with its gate (``keys[4:8]``)."""
+    h, e, i = cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size
+    pd = cfg.param_dtype
+
+    def w(k, shape, fan_in):
+        # one batched draw: fan-in-uniform bounds depend only on fan_in,
+        # so [L, E, ...] in a single RNG call is distributionally identical
+        return fan_in_uniform(k, lead + shape, fan_in, pd)
+
+    out = {
+        "router": 0.02 * jax.random.normal(
+            keys[0], lead + (h, cfg.router_width), pd),
+        "expert_gate_proj": w(keys[1], (e, h, i), h),
+        "expert_up_proj": w(keys[2], (e, h, i), h),
+        "expert_down_proj": w(keys[3], (e, i, h), i),
+    }
+    width = cfg.shared_expert_intermediate_size
+    if width:
+        out.update(
+            shared_gate_proj=w(keys[4], (h, width), h),
+            shared_up_proj=w(keys[5], (h, width), h),
+            shared_down_proj=w(keys[6], (width, h), width),
+            shared_expert_gate=w(keys[7], (h, 1), h))
+    return out
 
 
 def init_params(key: jax.Array, cfg: Qwen3MoEConfig) -> Params:
@@ -233,24 +327,16 @@ def init_params(key: jax.Array, cfg: Qwen3MoEConfig) -> Params:
     the DENSE subset [L_dense, H, intermediate_size]. All-sparse configs
     (L_sparse == L, no dense keys) keep the round-1 layout unchanged.
     """
-    h, e = cfg.hidden_size, cfg.num_experts
-    i = cfg.moe_intermediate_size
+    h = cfg.hidden_size
     ls = len(cfg.sparse_layer_ids())
     ld = cfg.num_hidden_layers - ls
     pd = cfg.param_dtype
     base = _llama.init_params(key, cfg, mlp=False)
     layers = base["layers"]
     keys = jax.random.split(jax.random.fold_in(key, 7), 7)
-
-    def expert_stack(k, shape, fan_in):
-        # one batched draw: fan-in-uniform bounds depend only on fan_in,
-        # so [L, E, ...] in a single RNG call is distributionally identical
-        return fan_in_uniform(k, (ls, e) + shape, fan_in, pd)
-
-    layers["router"] = 0.02 * jax.random.normal(keys[0], (ls, h, e), pd)
-    layers["expert_gate_proj"] = expert_stack(keys[1], (h, i), h)
-    layers["expert_up_proj"] = expert_stack(keys[2], (h, i), h)
-    layers["expert_down_proj"] = expert_stack(keys[3], (i, h), i)
+    layers.update(init_moe_params(
+        tuple(keys[:4]) + tuple(jax.random.split(
+            jax.random.fold_in(key, 8), 4)), cfg, (ls,)))
     if ld:
         di = cfg.intermediate_size
         layers["gate_proj"] = fan_in_uniform(keys[4], (ld, h, di), h, pd)
@@ -259,21 +345,42 @@ def init_params(key: jax.Array, cfg: Qwen3MoEConfig) -> Params:
     return base
 
 
-def _dropless_block(
+def shared_expert(flat: jax.Array, layer: Params, cfg) -> jax.Array:
+    """The shared expert of flat [N, H]: one SwiGLU every token takes,
+    times ``sigmoid(x w_s)`` of the token (the gate's one column leaves
+    its matmul in float32)."""
+    cdt = cfg.dtype
+    h = flat.astype(cdt)
+    mid = swiglu(h @ layer["shared_gate_proj"].astype(cdt),
+                 h @ layer["shared_up_proj"].astype(cdt))
+    gate = jax.nn.sigmoid(jnp.matmul(
+        h, layer["shared_expert_gate"].astype(cdt),
+        preferred_element_type=jnp.float32))
+    out = mid @ layer["shared_down_proj"].astype(cdt)
+    return (out.astype(jnp.float32) * gate).astype(cdt)
+
+
+def dropless_block(
     x: jax.Array,
     h_full: jax.Array,
     layer: Params,
-    cfg: Qwen3MoEConfig,
+    cfg,
     row_mask: Optional[jax.Array],
     expert_stack: Optional[Tuple[Params, jax.Array]],
 ) -> Tuple[jax.Array, jax.Array, dict, dict]:
-    """``moe_block_with_load`` for ``cfg.dropless``: softmax over all
-    experts in fp32, the top k kept (renormalised only where the
-    configuration says so), every kept (token, choice) computed."""
+    """``moe_block_with_load`` for ``cfg.dropless``, given the normed
+    hidden states ``h_full``: softmax over all routed experts in fp32,
+    the top k kept (renormalised only where the configuration says so),
+    every kept (token, choice) whose expert is held here computed
+    (``ExpertShare``: all of them unless the configuration holds a
+    share), the shared expert added where there is one. ``cfg`` is any
+    configuration with the MoE fields (the Qwen3-MoE family's, or
+    ``qwen3_next.Qwen3NextConfig``)."""
     from scaletorch_tpu.ops.grouped_matmul import dropless_expert_mlp
 
     b, s, hid = h_full.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
+    first, share = cfg.first_expert_id, not cfg.holds_every_expert
     flat = h_full.reshape(b * s, hid)
     live = None if row_mask is None else row_mask.reshape(b * s)
     with jax.named_scope("moe.route"):
@@ -283,17 +390,30 @@ def _dropless_block(
         gate_w, gate_idx = jax.lax.top_k(probs, k)
         if cfg.norm_topk_prob:
             gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
+        held = None
+        if share:
+            # ids from the first expert held here; the weights stay the
+            # uncut layer's, never renormalised over the held choices
+            gate_idx = gate_idx - first
+            held = (gate_idx >= 0) & (gate_idx < e)
     experts, index = expert_stack or (layer, None)
     y, rows = dropless_expert_mlp(
         flat, gate_idx, gate_w, experts["expert_gate_proj"],
         experts["expert_up_proj"], experts["expert_down_proj"],
-        live=live, layer=index, compute_dtype=cfg.dtype)
+        live=live, held=held, layer=index, compute_dtype=cfg.dtype)
+    if cfg.shared_expert_intermediate_size:
+        with jax.named_scope("moe.shared_expert"):
+            y = y + shared_expert(flat, layer, cfg)
     weight = (jnp.ones(b * s, bool) if live is None else live)[:, None]
+    elsewhere = (jnp.sum(~held & weight, dtype=jnp.int32) if share
+                 else jnp.int32(0))
     weight = weight.astype(jnp.float32)
     tokens = jnp.maximum(jnp.sum(weight), 1.0)
     # Switch load-balance and router z losses over the live tokens
-    # (``_route_core``'s definitions, one group)
-    load = rows.astype(jnp.float32) / tokens                  # sums to k
+    # (``_route_core``'s definitions, one group), of the experts here
+    load = rows.astype(jnp.float32) / tokens     # sums to k over all shares
+    if share:
+        probs = probs[:, first:first + e]
     mean_probs = jnp.sum(probs * weight, axis=0) / tokens
     z = jnp.square(jax.nn.logsumexp(logits, axis=-1, keepdims=True))
     aux_total = (cfg.aux_loss_coef * e * jnp.sum(load * mean_probs) / k
@@ -303,9 +423,22 @@ def _dropless_block(
         "moe_load_cv": jnp.std(load) / jnp.maximum(jnp.mean(load), 1e-9),
     }
     wanted = tokens.astype(jnp.int32) * k if live is not None else b * s * k
-    routing = {"expert_rows": rows, "dropped": wanted - jnp.sum(rows)}
+    # an assignment to an expert held elsewhere is not a dropped one
+    routing = {"expert_rows": rows, "elsewhere": elsewhere,
+               "dropped": wanted - jnp.sum(rows) - elsewhere}
     return (x + y.reshape(b, s, hid).astype(x.dtype), aux_total, stats,
             routing)
+
+
+def routing_counts(routing: dict) -> dict:
+    """One layer's int32 scalars a serving step counts
+    (``forward_cached(..., return_routing=True)`` sums them over the
+    layers) out of a block's ``routing``."""
+    rows = routing["expert_rows"]
+    return {"routed": jnp.sum(rows), "dropped": routing["dropped"],
+            "elsewhere": routing["elsewhere"],
+            "expert_visits": jnp.sum(rows > 0, dtype=jnp.int32),
+            "peak_load_rows": jnp.max(rows)}
 
 
 def moe_block(x, layer, cfg, helpers, **kwargs
@@ -334,8 +467,9 @@ def moe_block_with_load(
     ``dropped_fraction`` (tokens beyond capacity) and ``load_cv``
     (coefficient of variation of expert load; 0 = perfectly balanced);
     routing is what a serving step counts: ``expert_rows`` [E] int32,
-    the (token, choice) rows each expert computed, and ``dropped``, the
-    rows that were routed and not computed.
+    the (token, choice) rows each expert computed, ``dropped``, the
+    rows that were routed and not computed, and ``elsewhere``, the rows
+    of experts that another share of the layer holds (``ExpertShare``).
 
     ``row_mask`` [B, S] bool marks the tokens that exist (a serving
     step's inactive slots and padding positions do not). The dropless
@@ -361,7 +495,7 @@ def moe_block_with_load(
                 "exchange of sorted rows between expert shards "
                 "(expert_parallel.sort_dispatch_tokens feeding the grouped "
                 "matmul) and the tp split of the expert width")
-        return _dropless_block(x, h_full, layer, cfg, row_mask, expert_stack)
+        return dropless_block(x, h_full, layer, cfg, row_mask, expert_stack)
 
     # Router in fp32 (reference router runs in fp32 for gate stability).
     # Each batch row routes as its own group (GShard-style grouping): the
@@ -438,6 +572,7 @@ def moe_block_with_load(
     # pre-capacity assignments per expert, and those past capacity
     routing = {
         "expert_rows": jnp.round(load * (b * s)).astype(jnp.int32),
+        "elsewhere": jnp.int32(0),
         "dropped": jnp.round(aux["dropped_fraction"] * (
             b * s * cfg.num_experts_per_tok)).astype(jnp.int32),
     }
@@ -512,8 +647,8 @@ _ATTN_KEYS = (
     "input_layernorm", "q_proj", "k_proj", "v_proj", "o_proj",
     "post_attention_layernorm", "q_norm", "k_norm",
 )
-_EXPERT_KEYS = ("expert_gate_proj", "expert_up_proj", "expert_down_proj")
-_MOE_KEYS = ("router",) + _EXPERT_KEYS
+EXPERT_KEYS = ("expert_gate_proj", "expert_up_proj", "expert_down_proj")
+_MOE_KEYS = ("router",) + EXPERT_KEYS
 _DENSE_KEYS = ("gate_proj", "up_proj", "down_proj")
 
 
@@ -675,7 +810,8 @@ def forward_cached(
     ``row_mask`` [B, S] bool: the tokens that exist (``moe_block_with_
     load``). ``return_routing`` appends the call's routing counts, int32
     scalars summed over the layers: ``routed`` (rows computed),
-    ``dropped``, ``expert_visits`` (experts with at least one row) and
+    ``dropped``, ``elsewhere`` (rows of experts another share holds),
+    ``expert_visits`` (experts with at least one row) and
     ``peak_load_rows`` (the fullest expert's rows).
 
     Attention is the shared cache-aware Llama block; the MoE FFN is
@@ -709,8 +845,8 @@ def forward_cached(
     if cfg.dropless:
         # the experts stay out of the scanned operands: the grouped
         # matmul reads the layer's experts out of the whole stack
-        stacked = {k: layers[k] for k in _EXPERT_KEYS}
-        layers = {k: v for k, v in layers.items() if k not in _EXPERT_KEYS}
+        stacked = {k: layers[k] for k in EXPERT_KEYS}
+        layers = {k: v for k, v in layers.items() if k not in EXPERT_KEYS}
 
     def layer_fn(h, layer, index, kv):
         h, ck, cv = _llama.attention_block_cached(
@@ -720,11 +856,7 @@ def forward_cached(
         h, _aux, _stats, routing = moe_block_with_load(
             h, layer, cfg, helpers, row_mask=row_mask,
             expert_stack=None if stacked is None else (stacked, index))
-        rows = routing["expert_rows"]
-        counts = {"routed": jnp.sum(rows), "dropped": routing["dropped"],
-                  "expert_visits": jnp.sum(rows > 0, dtype=jnp.int32),
-                  "peak_load_rows": jnp.max(rows)}
-        return h, (ck, cv), counts
+        return h, (ck, cv), routing_counts(routing)
 
     x, cache, counts = _llama.scan_layers_cached(layer_fn, x, cache, layers)
     x = rms_norm(x, params["norm"], cfg.rms_norm_eps)

@@ -89,6 +89,7 @@ def dropless_expert_mlp(
     down_proj: jax.Array,
     *,
     live: Optional[jax.Array] = None,
+    held: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,
     compute_dtype: Any = None,
     matmul=None,
@@ -100,7 +101,13 @@ def dropless_expert_mlp(
     [E, I, H]; ``live`` [N] bool marks the tokens that exist (a serving
     step's inactive slots and padding positions do not): a dead token's
     rows are sorted past every group, cost no expert work, count in no
-    load and come back as zeros. With ``layer`` (an int32 scalar) the
+    load and come back as zeros. ``held`` [N, k] bool marks the choices
+    whose expert is one of the E held here (a chip's share of an expert
+    layer: ``gate_idx`` counts from the first held expert, and an id
+    outside [0, E) of a choice not held is never read): a choice held
+    elsewhere is sorted past every group like a dead token's rows and
+    adds zeros, and the weights of the held ones stay as they are given.
+    With ``layer`` (an int32 scalar) the
     projections are whole layer stacks, [L, E, H, I] and [L, E, I, H],
     and that layer's experts are used: the stack goes to the grouped
     matmul as [L E, ...] with every other layer's groups empty, so a
@@ -117,8 +124,12 @@ def dropless_expert_mlp(
     cdt = compute_dtype or x.dtype
     with jax.named_scope("moe.sort"):
         ids = gate_idx.reshape(-1).astype(jnp.int32)
-        if live is not None:
-            ids = jnp.where(jnp.repeat(live, k), ids, e)  # e: no group
+        computed = None if live is None else jnp.repeat(live, k)
+        if held is not None:
+            computed = (held.reshape(-1) if computed is None
+                        else computed & held.reshape(-1))
+        if computed is not None:
+            ids = jnp.where(computed, ids, e)                # e: no group
         # stable: an expert's rows keep token order
         order = jnp.argsort(ids, stable=True)
         group_sizes = jnp.sum(
@@ -141,9 +152,9 @@ def dropless_expert_mlp(
         inverse = jnp.zeros(n * k, jnp.int32).at[order].set(
             jnp.arange(n * k, dtype=jnp.int32))
         back = out[inverse].reshape(n, k, hid).astype(jnp.float32)
+        if computed is not None:
+            # rows of no group: what a form left in them (NaN, perhaps)
+            # is selected away, never multiplied
+            back = jnp.where(computed.reshape(n, k, 1), back, 0)
         y = jnp.sum(back * gate_w[..., None].astype(jnp.float32), axis=1)
-        if live is not None:
-            # a dead token's rows belong to no group: what a form left
-            # in them (NaN, perhaps) is selected away, never multiplied
-            y = jnp.where(live[:, None], y, 0)
     return y.astype(cdt), group_sizes

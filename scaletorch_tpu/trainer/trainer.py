@@ -42,6 +42,19 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
     # architecture's (HF ``norm_topk_prob``); None keeps the family's
     moe_arch = ({} if cfg.norm_topk_prob is None
                 else {"norm_topk_prob": cfg.norm_topk_prob})
+    # a chip's share of the expert layer (qwen3_moe.ExpertShare)
+    moe_arch.update(num_routed_experts=cfg.num_routed_experts,
+                    first_expert_id=cfg.first_expert_id)
+    if cfg.embed_init_std is not None and cfg.model_type != "qwen3_next":
+        raise NotImplementedError(
+            f"--embed_init_std with model_type {cfg.model_type!r}: only "
+            "qwen3_next's initialiser reads it (models/qwen3_next.py)")
+    if cfg.model_type == "qwen3_next" and cfg.model_name_or_path:
+        raise NotImplementedError(
+            "qwen3_next from --model_name_or_path: HF config auto-fill "
+            "and weight loading are not written for this family; give "
+            "its sizes by their config.json names (models/presets.py "
+            "qwen3-next-80b-a3b)")
     if cfg.model_name_or_path:
         from transformers import AutoConfig
 
@@ -140,6 +153,34 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
                 "linear_num_key_heads", "linear_num_value_heads",
                 "linear_key_head_dim", "linear_value_head_dim",
                 "linear_conv_kernel_dim", "linear_allow_neg_eigval")}})
+    if cfg.model_type == "qwen3_next":
+        from scaletorch_tpu.models import qwen3_next
+
+        if cfg.mlp_only_layers or (cfg.decoder_sparse_step or 1) != 1:
+            raise NotImplementedError(
+                "qwen3_next with dense-MLP layers (mlp_only_layers "
+                f"{cfg.mlp_only_layers}, decoder_sparse_step "
+                f"{cfg.decoder_sparse_step}): every layer's MLP is the "
+                "sparse one in models/qwen3_next.py")
+        # the published config.json names (models/qwen3_next.py)
+        return qwen3_next.Qwen3NextConfig(**{
+            **common, **moe_arch,
+            "layer_types": (None if cfg.layer_types is None
+                            else tuple(cfg.layer_types)),
+            "num_experts": cfg.num_experts,
+            "num_experts_per_tok": cfg.num_experts_per_tok,
+            "moe_intermediate_size": cfg.moe_intermediate_size
+            or common["intermediate_size"],
+            "aux_loss_coef": cfg.router_aux_loss_coef,
+            "z_loss_coef": cfg.router_z_loss_coef,
+            **({} if cfg.embed_init_std is None
+               else {"embed_init_std": cfg.embed_init_std}),
+            **{name: getattr(cfg, name) for name in (
+                "full_attention_interval", "partial_rotary_factor",
+                "shared_expert_intermediate_size",
+                "linear_num_key_heads", "linear_num_value_heads",
+                "linear_key_head_dim", "linear_value_head_dim",
+                "linear_conv_kernel_dim")}})
     if cfg.model_type == "qwen3":
         return qwen3.Qwen3Config(qk_norm=True, **common)
     if cfg.model_type == "llama":
@@ -222,6 +263,12 @@ class Trainer:
     """End-to-end training driver (reference train.py main + loop)."""
 
     def __init__(self, cfg: ScaleTorchTPUArguments):
+        if cfg.model_type in ("olmo_hybrid", "qwen3_next"):
+            raise NotImplementedError(
+                f"the trainer has no step for model_type {cfg.model_type!r}"
+                ": its state-carrying layers have no sharding rules (tp / "
+                "cp / pp / ep), no loss wiring and no HF weight loading; "
+                "the family is served (scripts/serve.py --preset ...)")
         self.cfg = cfg
         self.logger = get_logger(log_file=cfg.log_file,
                                  log_format=cfg.log_format)

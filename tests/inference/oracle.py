@@ -3,8 +3,8 @@
 No cache of any kind and none of the engine's code: every token is the
 argmax of the full-sequence training forward (``llama.forward`` /
 ``qwen3_moe.forward`` / ``gpt_moe.forward`` / ``olmo_hybrid.forward``
-with the recurrence row after row) over the whole sequence so
-far. The engine's page pool, page tables, prefix sharing, cached
+and ``qwen3_next.forward`` with the recurrence row after row) over the
+whole sequence so far. The engine's page pool, page tables, prefix sharing, cached
 forwards and sampling are all on the other side of the comparison.
 
 On the CPU in float32 at the tiny presets the tokens are equal. Should
@@ -20,7 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from scaletorch_tpu.models import gpt_moe, llama, olmo_hybrid, qwen3_moe
+from scaletorch_tpu.models import (
+    gpt_moe,
+    llama,
+    olmo_hybrid,
+    qwen3_moe,
+    qwen3_next,
+)
 
 # of the largest |logit| of the step: float32 order-of-summation noise
 # measured on these presets is under 1e-6; a wrong token is off by 1e-2
@@ -30,6 +36,8 @@ TIE_RTOL = 1e-5
 def plain_forward(cfg):
     """The full-sequence forward of a config's family (the training
     forward, not the cache-aware one)."""
+    if isinstance(cfg, qwen3_next.Qwen3NextConfig):
+        return functools.partial(qwen3_next.forward, sequential=True)
     if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
         # the delta rule as its definition, not the chunked form the
         # engine's prefill runs

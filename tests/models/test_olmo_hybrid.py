@@ -174,9 +174,12 @@ def test_any_period_of_both_kinds_is_served():
     assert _err_of_max(cached, ref) < RTOL_OF_MAX
 
 
-def test_fewer_key_heads_than_value_heads_is_refused_by_name():
-    with pytest.raises(NotImplementedError, match="key heads"):
-        tiny_config(linear_num_key_heads=2)
+def test_value_heads_no_multiple_of_the_key_heads_are_refused_by_name():
+    """Fewer key heads than value heads are repeated over them
+    (tests/models/test_qwen3_next.py runs 2 over 4); 3 over 4 cannot."""
+    with pytest.raises(ValueError, match="key heads"):
+        tiny_config(linear_num_key_heads=3)
+    assert tiny_config(linear_num_key_heads=2).linear_key_size == 2 * 8
 
 
 # ---- the delta rule ----------------------------------------------------------

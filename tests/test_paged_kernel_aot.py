@@ -101,6 +101,20 @@ def test_kernel_compiles_for_the_layouts_the_models_use(
     assert len(calls) == 1, calls
 
 
+@pytest.mark.parametrize("hq,hkv", [(16, 2), (4, 2)],
+                         ids=["qwen3-next-group8", "group2"])
+def test_kernel_compiles_for_a_256_wide_head(one_chip, hq, hkv):
+    """qwen3-next-80b-a3b-serve: 16 query heads over 2 K/V heads of 256,
+    16 slots x 96 pages of 16. Two lane tiles a head, 8 query rows a
+    K/V head: still one call with the one 4-D bf16 result that
+    ``serve_paged_attn_roofline`` tells the kernel by."""
+    calls = _mosaic_calls(_compiled_text(
+        one_chip, 16, hq, hkv, 256, 16, 96, jnp.bfloat16))
+    assert len(calls) == 1, calls
+    assert re.search(
+        rf"%paged_decode\S* = bf16\[16,{hkv},{hq // hkv},256\]", calls[0]), calls
+
+
 @pytest.mark.parametrize("layers,slots,hkv,rows,page,max_pages,dtype", [
     (28, 16, 8, 1, 16, 96, jnp.bfloat16),      # qwen3-1.7b-serve, decode
     (28, 16, 8, 1024, 16, 96, jnp.bfloat16),   # ... and its prefill
@@ -117,15 +131,29 @@ def test_page_write_compiles_and_aliases_the_pool(
         one_chip, layers, slots, hkv, rows, page, max_pages, dtype):
     """``paged_write``: one Mosaic call whose only result is the donated
     pool itself, with nothing of the pool's size beside it."""
+    _page_write_aliases_the_pool(
+        one_chip, layers, slots, hkv, rows, page, max_pages, dtype, 128)
+
+
+@pytest.mark.parametrize("rows", [1, 512], ids=["decode", "prefill"])
+def test_page_write_compiles_for_a_256_wide_head(one_chip, rows):
+    """qwen3-next-80b-a3b-serve: 3 full-attention layers, 2 K/V heads of
+    256."""
+    _page_write_aliases_the_pool(
+        one_chip, 3, 16, 2, rows, 16, 96, jnp.bfloat16, 256)
+
+
+def _page_write_aliases_the_pool(one_chip, layers, slots, hkv, rows, page,
+                                 max_pages, dtype, d):
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = arg((layers, slots * max_pages + 1, hkv, page, 128), dtype)
+    pool = arg((layers, slots * max_pages + 1, hkv, page, d), dtype)
     compiled = jax.jit(
         lambda pool, *a: pallas_paged_write(pool, *a[:-1], layer=a[-1]),
         donate_argnums=0,
     ).lower(
-        pool, arg((slots, hkv, rows, 128), dtype),
+        pool, arg((slots, hkv, rows, d), dtype),
         arg((slots, rows), jnp.int32), arg((slots, max_pages), jnp.int32),
         arg((slots,), jnp.bool_), arg((), jnp.int32),
     ).compile()
@@ -219,7 +247,8 @@ def _programs_of(one_chip, name):
 
 @pytest.fixture(scope="module", params=["qwen3-1.7b-serve",
                                         "olmoe-1b-7b-serve",
-                                        "olmo-hybrid-7b-serve"])
+                                        "olmo-hybrid-7b-serve",
+                                        "qwen3-next-80b-a3b-serve"])
 def serving_programs(request, one_chip):
     return _programs_of(one_chip, request.param)
 
@@ -240,7 +269,10 @@ def test_no_step_program_moves_the_pool(serving_programs):
 def test_decode_program_reserves_no_second_pool(serving_programs):
     decode, _prefill, pool_shape = serving_programs
     one_pool = 2 * math.prod(pool_shape)
-    assert decode.memory_analysis().temp_size_in_bytes < one_pool // 10
+    # a tenth of a pool, or the 16 MB of ordinary scratch a step has
+    # (Qwen3-Next's pool of three layers x two heads is 76 MB in all)
+    assert decode.memory_analysis().temp_size_in_bytes < max(
+        one_pool // 10, 16 * 2**20)
     assert decode.memory_analysis().alias_size_in_bytes >= 2 * one_pool
 
 
@@ -304,6 +336,75 @@ def test_the_recurrent_state_is_updated_in_place_and_no_weight_is_moved(
     assert memory.alias_size_in_bytes >= state_bytes
     # the prefill call's scan inverts its triangular systems by matrix
     # products: the solver's custom call took a quarter of the call
+    assert "InvertDiagBlocks" not in prefill.as_text()
+
+
+def _reader_patterns(name):
+    with open(os.path.join(REPO, "benchmarks", "metrics",
+                           name + ".json")) as f:
+        return [p for term in json.load(f)["reducer"]["terms"]
+                for p in term["patterns"]]
+
+
+def _short_names(text):
+    """Every instruction of a compiled program as the trace reader names
+    an ``XLA Ops`` event."""
+    return [short_name(re.sub(r"^\s*(ROOT )?", "", line))
+            for line in text.splitlines() if _INSTRUCTION.match(line)]
+
+
+def test_qwen3_next_steps_are_what_the_new_readers_look_for(one_chip):
+    """Qwen3-Next's step programs at the cell's shapes (a 512-wide
+    router over 128 held experts, 16 slots). The decode step's grouped
+    matmuls are 12 Mosaic calls a period (gate, up, down of 4 layers)
+    over the WHOLE expert stack as 1,536 groups, told by their results
+    ``bf16[256, 512]`` / ``bf16[256, 2048]`` (160 sorted rows padded to
+    two row tiles), which is what
+    ``serve_qwen3_next_expert_mlp_roofline`` matches and the prefill's
+    81,920-row calls are not; no operation returns the expert stack or a
+    layer of it (a copy of 0.8 GB a layer: PR 27). The state
+    ``f32[9,16,32,128,128]`` is the loop's carry, written in place by
+    one select + dynamic-update-slice fusion a linear layer, beside the
+    fusion that returns the pair of ``[16,32,128]`` sums:
+    ``serve_qwen3_next_gdn_state_update_roofline`` matches exactly those
+    two a layer."""
+    decode, prefill, pool_shape = _programs_of(
+        one_chip, "qwen3-next-80b-a3b-serve")
+    assert pool_shape == (3, 16 * 96 + 1, 2, 16, 256)
+    text = decode.as_text()
+    names = _short_names(text)
+
+    gmm = [c for c in _mosaic_calls(text) if re.search(r"%gmm\S* = ", c)]
+    assert len(gmm) == 12, gmm
+    experts = _reader_patterns("serve_qwen3_next_expert_mlp_roofline")
+    found = [n for n in names if any(re.search(p, n) for p in experts)]
+    assert len(found) == 12 and all(n.startswith("gmm") for n in found), found
+    assert sorted(n.rsplit(" | ", 1)[1] for n in found) == (
+        ["bf16[256,2048]"] * 4 + ["bf16[256,512]"] * 8)
+    assert not [n for n in _short_names(prefill.as_text())
+                if any(re.search(p, n) for p in experts)]
+    for stack in ("bf16[12,128,2048,512]", "bf16[128,2048,512]",
+                  "bf16[1536,2048,512]", "bf16[12,128,512,2048]",
+                  "bf16[128,512,2048]", "bf16[1536,512,2048]"):
+        moved = [x for x in _top_level(text, stack)
+                 if x[0] not in _PLUMBING | {"bitcast"}
+                 and "tpu_custom_call" not in x[1]]
+        assert not moved, moved[:3]
+
+    state = [op for op, _ in _top_level(text, "f32[9,16,32,128,128]")
+             if op not in _PLUMBING]
+    assert state == ["fusion"] * 3, state
+    assert not [x for x in _top_level(text, "f32[16,32,128,128]")
+                if x[0] not in _PLUMBING], "a layer of the state is copied"
+    update = _reader_patterns("serve_qwen3_next_gdn_state_update_roofline")
+    found = [n for n in names if any(re.search(p, n) for p in update)]
+    assert len(found) == 6, found            # two a linear layer of a period
+    memory = decode.memory_analysis()
+    state_bytes = 9 * 16 * 32 * 128 * 128 * 4
+    assert memory.temp_size_in_bytes < state_bytes // 10
+    assert memory.alias_size_in_bytes >= state_bytes
+    # the prefill program's scratch beside 11.3 GB of arguments
+    assert prefill.memory_analysis().temp_size_in_bytes < 2.2e9
     assert "InvertDiagBlocks" not in prefill.as_text()
 
 
