@@ -174,7 +174,11 @@ def make_paged_prefill_step(
     invisible, because the j <= p attention mask never reaches past the
     current position and decode overwrites position p before attending
     to it. The first token samples from the logits at row
-    ``tail_len - 1`` with the slot's (seed, prompt_len - 1) key.
+    ``tail_len - 1`` with the slot's (seed, prompt_len - 1) key: the
+    forward is told that row (``logit_rows``), so its final norm and
+    head run on [B, 1, hidden] as the decode step's do and no
+    [B, P, V] logits exist in the program (``last_logits`` is that one
+    row a slot; a slot outside ``write_mask`` yields an ignored row).
     ``finite`` flags the slots whose sampled-from logits are all finite
     (``sampling.finite_mask``) — the engine quarantines a False slot
     instead of emitting its garbage sample.
@@ -194,7 +198,8 @@ def make_paged_prefill_step(
     fwd = forward_fn or resolve_forward_cached(cfg)
     # a row that is no token would otherwise enter a recurrent state:
     # told to the model's own forward and to a ``forward_fn`` in its
-    # place alike (one that cannot take ``row_mask`` fails at the trace)
+    # place alike (one that cannot take ``row_mask``, or the
+    # ``logit_rows`` every prefill names, fails at the trace)
     row_masked = carries_state(cfg)
 
     def prefill(params, tokens, tail_lens, starts, write_mask,
@@ -214,11 +219,9 @@ def make_paged_prefill_step(
         logits, new_pool, *counts = fwd(
             params, tokens, cfg, tuple(pool),
             positions=positions, write_mask=write_mask, kv_io=kv_io,
-            **counted,
+            logit_rows=tail_lens - 1, **counted,
         )
-        last = jnp.take_along_axis(
-            logits, (tail_lens - 1)[:, None, None], axis=1
-        )[:, 0, :]
+        last = logits[:, 0, :]
         keys = slot_keys(base_keys, starts + tail_lens - 1)
         first = sample(last, keys, sampling)
         out = (first, last.astype(jnp.float32), finite_mask(last),

@@ -28,7 +28,7 @@ from scaletorch_tpu.models.layers import (
     normal_init,
     sdpa_attention,
 )
-from scaletorch_tpu.models.llama import scan_layers_cached
+from scaletorch_tpu.models.llama import scan_layers_cached, select_logit_rows
 from scaletorch_tpu.parallel.expert_parallel import (
     combine_routed,
     dispatch_routed,
@@ -233,9 +233,12 @@ def forward_cached(
     positions: jax.Array,
     write_mask: Optional[jax.Array] = None,
     kv_io: Optional[Any] = None,
+    logit_rows: Optional[jax.Array] = None,
 ):
     """KV-cached forward: [B, S] tokens at absolute ``positions`` [B, S]
-    -> (logits [B, S, V], new cache). Positional signal is the learned
+    -> (logits, new cache); ``logits`` [B, S, V], or [B, 1, V] for the
+    row a sequence that ``logit_rows`` [B] names
+    (``llama.select_logit_rows``). Positional signal is the learned
     ``wpe`` table looked up at the absolute positions (no RoPE). Routing
     is deterministic (no noise) — matching ``generate``'s eval-mode
     forward. ``kv_io`` is the cache layout (None: dense; the paged pool's
@@ -271,7 +274,7 @@ def forward_cached(
         return h + y.astype(cdt), (ck, cv), None
 
     x, cache, _ = scan_layers_cached(layer_fn, x, cache, params["layers"])
-    x = _layer_norm(x, params["ln_f"])
+    x = _layer_norm(select_logit_rows(x, logit_rows), params["ln_f"])
     return x @ params["wte"].astype(cdt).T, cache
 
 

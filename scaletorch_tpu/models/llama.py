@@ -688,6 +688,21 @@ def scan_layers_cached(
     return x, cache, outs
 
 
+def select_logit_rows(
+    x: jax.Array, logit_rows: Optional[jax.Array]
+) -> jax.Array:
+    """The hidden states a cached forward's final norm and head run on:
+    ``x`` [B, S, H] whole (``logit_rows`` None: logits for every row),
+    or row ``logit_rows[b]`` of each sequence, [B, 1, H]. A prefill step
+    samples from one row a slot, so it names that row and the head
+    multiplies [B, 1, H] as the decode step's does, never [B, S, H]
+    (16 of 16,384 rows were used: PERF.md, PR 36). Shared by every
+    family's ``forward_cached``."""
+    if logit_rows is None:
+        return x
+    return jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
+
+
 def forward_cached(
     params: Params,
     input_ids: jax.Array,
@@ -697,9 +712,13 @@ def forward_cached(
     positions: jax.Array,
     write_mask: Optional[jax.Array] = None,
     kv_io: Optional[Any] = None,
+    logit_rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """KV-cached decoder forward: [B, S] tokens at absolute ``positions``
-    [B, S] -> (logits [B, S, V], new (cache_k, cache_v)).
+    [B, S] -> (logits, new (cache_k, cache_v)). ``logits`` is [B, S, V],
+    or [B, 1, V] for the one row a sequence that ``logit_rows`` [B] int32
+    names (``select_logit_rows``: the rows are taken before the final
+    norm and the head).
 
     ``cache`` is a pair of [L, B, Hkv, S_max, D] stacked per-layer
     buffers (inference/kv_cache.py builds and shards them). One trace
@@ -727,7 +746,8 @@ def forward_cached(
         return _mlp_block(h, layer, cfg), (ck, cv), None
 
     x, cache, _ = scan_layers_cached(layer_fn, x, cache, params["layers"])
-    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    x = rms_norm(select_logit_rows(x, logit_rows), params["norm"],
+                 cfg.rms_norm_eps)
     logits = x @ lm_head_weight(params, cfg)
     return logits, cache
 

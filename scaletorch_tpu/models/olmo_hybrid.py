@@ -585,9 +585,12 @@ def forward_cached(
     write_mask: Optional[jax.Array] = None,
     kv_io: Optional[Any] = None,
     row_mask: Optional[jax.Array] = None,
+    logit_rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
     """Cached forward: [B, S] tokens at absolute ``positions`` [B, S] ->
-    (logits [B, S, V], the new cache). ``cache`` is ``(k, v, state,
+    (logits, the new cache); ``logits`` [B, S, V], or [B, 1, V] for the
+    row a sequence that ``logit_rows`` [B] names
+    (``llama.select_logit_rows``). ``cache`` is ``(k, v, state,
     conv)``: K/V of the full-attention layers in ``kv_io``'s layout
     (``[full layers, ...]``: the paged pool, or the dense reference),
     the recurrent state and the convolution tail of the linear layers
@@ -637,7 +640,8 @@ def forward_cached(
     (x, cache), _ = jax.lax.scan(
         period_fn, (x, tuple(cache)),
         jnp.arange(cfg.num_periods, dtype=jnp.int32))
-    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    x = rms_norm(_llama.select_logit_rows(x, logit_rows), params["norm"],
+                 cfg.rms_norm_eps)
     return x @ _llama.lm_head_weight(params, cfg), cache
 
 

@@ -802,10 +802,13 @@ def forward_cached(
     kv_io: Optional[Any] = None,
     row_mask: Optional[jax.Array] = None,
     return_routing: bool = False,
+    logit_rows: Optional[jax.Array] = None,
 ):
     """KV-cached MoE decoder forward for the decode engine
     (inference/decode.py): [B, S] tokens at absolute ``positions`` [B, S]
-    -> (logits [B, S, V], new (cache_k, cache_v)).
+    -> (logits, new (cache_k, cache_v)); ``logits`` [B, S, V], or
+    [B, 1, V] for the row a sequence that ``logit_rows`` [B] names
+    (``llama.select_logit_rows``).
 
     ``row_mask`` [B, S] bool: the tokens that exist (``moe_block_with_
     load``). ``return_routing`` appends the call's routing counts, int32
@@ -859,7 +862,8 @@ def forward_cached(
         return h, (ck, cv), routing_counts(routing)
 
     x, cache, counts = _llama.scan_layers_cached(layer_fn, x, cache, layers)
-    x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
+    x = rms_norm(_llama.select_logit_rows(x, logit_rows), params["norm"],
+                 cfg.rms_norm_eps)
     logits = x @ _llama.lm_head_weight(params, cfg)
     if return_routing:
         return logits, cache, jax.tree.map(jnp.sum, counts)

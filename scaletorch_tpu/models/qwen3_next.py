@@ -304,13 +304,15 @@ def forward_cached(
     kv_io: Optional[Any] = None,
     row_mask: Optional[jax.Array] = None,
     return_routing: bool = False,
+    logit_rows: Optional[jax.Array] = None,
 ):
     """Cached forward: [B, S] tokens at absolute ``positions`` [B, S] ->
-    (logits [B, S, V], the new cache). The cache, ``row_mask``,
-    ``write_mask`` and the loop over periods with the cache as its carry
-    are ``olmo_hybrid.forward_cached``'s; ``return_routing`` appends the
-    call's routing counts as ``qwen3_moe.forward_cached`` does (int32
-    scalars summed over the layers)."""
+    (logits, the new cache). The cache, ``row_mask``, ``write_mask``,
+    ``logit_rows`` (logits [B, 1, V] for the named row a sequence in
+    place of [B, S, V]) and the loop over periods with the cache as its
+    carry are ``olmo_hybrid.forward_cached``'s; ``return_routing``
+    appends the call's routing counts as ``qwen3_moe.forward_cached``
+    does (int32 scalars summed over the layers)."""
     pattern = cfg.period_pattern
     layers = _hybrid.period_layers(pattern)
     n_lin, n_full = pattern.count(LINEAR), pattern.count(FULL)
@@ -361,7 +363,8 @@ def forward_cached(
     (x, cache), counts = jax.lax.scan(
         period_fn, (x, tuple(cache)),
         jnp.arange(cfg.num_periods, dtype=jnp.int32))
-    x = rms_norm_zero_centered(x, params["norm"], cfg.rms_norm_eps)
+    x = rms_norm_zero_centered(_llama.select_logit_rows(x, logit_rows),
+                               params["norm"], cfg.rms_norm_eps)
     logits = x @ _llama.lm_head_weight(params, cfg)
     if return_routing:
         return logits, cache, jax.tree.map(jnp.sum, counts)

@@ -287,6 +287,51 @@ def test_decode_kernel_is_still_the_one_4d_call(serving_programs):
                 if four_d.search(c)]
 
 
+# ``memory_analysis().temp_size_in_bytes`` of each prefill program at
+# the parent of PR 36 (8574b90), whose head multiplied every row of the
+# buffer, and the room the counter has over it (AOT, PR 36; it reads
+# 2.594e9, 2.561e9, 3.094e9 and 1.70788e9 now). OLMoE's peak never held
+# its 1.65 GB of logits (its scores do: f32[16,16,1024,1536] and their
+# bf16 copy, 2.42 GB): the buffer assignment's heap peak is the parent's
+# to 4 KB (9,622,502,448 -> 9,622,506,624 B), its allocations 126 KB
+# smaller, and this counter reads 2.0 % MORE, so it is held to 2.5 %.
+_PREFILL_TEMP_WITH_EVERY_ROW_S_LOGITS = {
+    "qwen3-1.7b-serve": (8_973_132_288, 1.0),
+    "olmoe-1b-7b-serve": (2_511_168_512, 1.025),
+    "olmo-hybrid-7b-serve": (3_301_462_528, 1.0),
+    "qwen3-next-80b-a3b-serve": (1_708_014_080, 1.0),
+}
+_ARRAY = re.compile(r"\b(?:pred|[a-z]+\d+)\[([\d,]+)\]")
+
+
+def test_prefill_program_runs_the_head_on_the_sampled_from_rows_only(
+        request, serving_programs):
+    """The prefill step names one row a slot (``logit_rows``) and the
+    forward takes it before the final norm and the head: no array of
+    ``slots x prefill_len x vocab`` elements, of any type or layout, is
+    left in the compiled program (Qwen3-1.7B's was 4.98 GB in bf16, five
+    instructions of it), the logits it does hold are the decode step's
+    ``[slots, vocab]``, and the program's scratch is smaller for it."""
+    name = request.node.callspec.params["serving_programs"]
+    _, prefill, _ = serving_programs
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    serve = config["serve"]
+    slots, vocab = serve["max_slots"], config["vocab_size"]
+    every_row = slots * serve["prefill_len"] * vocab
+    text = prefill.as_text()
+    sizes = {dims: math.prod(map(int, dims.split(",")))
+             for dims in set(_ARRAY.findall(text))}
+    assert not [d for d, n in sizes.items() if n == every_row], name
+    assert f"f32[{slots},{vocab}]" in text       # last_logits
+    temp = prefill.memory_analysis().temp_size_in_bytes
+    before, room = _PREFILL_TEMP_WITH_EVERY_ROW_S_LOGITS[name]
+    assert temp < before * room, (
+        f"{name}: prefill scratch {temp:,} B, not under the {before:,} B "
+        f"(x {room}) of the program that multiplied every row by the head")
+
+
 def _top_level(text, wanted):
     """Instructions outside fused computations (what is scheduled as an
     operation of its own: a fusion, a copy, a call) whose result type
