@@ -28,6 +28,23 @@ Design points:
     of their inputs, so the kernel composes with ``jax.shard_map``'s
     vma checking (the spmd train step runs everything inside shard_map).
   * fp32 accumulators and LSE; bf16 MXU feeds.
+  * **Softmax statistics a register wide** — the forward's running
+    maximum and sum live in ``[bq, 128]`` float32 scratch, every lane of
+    a row holding the row's value (the layout of jax's own TPU flash
+    kernel). Held as a ``[bq, 1]`` column, one lane of 128 in every
+    vector register, each grid step had to spread them across lanes for
+    ``s - m`` and ``acc * corr``: on the v5e that was 1.66 of a call's
+    4.10 ms at the training shape, and a ``[bq, 128]`` scratch read
+    through its first column costs the same, so it is the spreading and
+    not the scratch's traffic (PERF.md, PR 38). Now the step's row
+    maximum and row sum are broadcast into the width once, and
+    ``_lanes`` takes a statistic to the key block's width and to the
+    head's by whole registers, or by its leading lanes under one. Which,
+    it reads from the static shapes: one body, no option. The same
+    float32 operations on the same values: ``out`` and ``lse`` are bit
+    for bit what the column gave. The backward kernels keep their
+    ``[1, bq]`` rows of lse and delta: ``flash_dq`` fed from two such
+    scratches was slower (2.87 -> 3.00 ms, same PR).
 
 Backward follows FlashAttention-2: delta = rowsum(dO * O) precomputed in
 XLA, then a dq kernel (a query block at a time, reducing its key blocks)
@@ -60,6 +77,7 @@ def _resolve_blocks(block_q, block_kv):
 
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
+_LANES = 128  # of a vector register: the forward holds its statistics that wide
 
 
 def _struct(shape, dtype, like):
@@ -239,6 +257,14 @@ def _call(kernel, name, tables, grid, interpret, out_shape, **specs):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+def _lanes(stat, width):
+    """A lane-replicated ``[rows, _LANES]`` statistic at ``width`` lanes:
+    repeated whole registers for a multiple of a register (the training
+    shapes' 512-wide blocks and 128 / 256-wide heads), the leading lanes
+    under one (64-wide heads, the CPU tests' 16-64-wide blocks)."""
+    return jnp.tile(stat, (1, -(-width // _LANES)))[:, :width]
+
+
 def _fwd_kernel(*refs, scale, causal, bq, bkv):
     (i, j), first, last, refs = _grid_step(causal, refs, 2)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
@@ -252,14 +278,16 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv):
     q = q_ref[0, 0]  # [bq, D]
     k = k_ref[0, 0]  # [bkv, D]
     v = v_ref[0, 0]
+    d = q.shape[-1]
     s = _scores(q, k, i, j, scale=scale, masked=causal, bq=bq, bkv=bkv)
+    # m, l, corr: [bq, _LANES], every lane of a row the row's value
     m_prev, l_prev = m_sc[:], l_sc[:]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
+    p = jnp.exp(s - _lanes(m_new, bkv))
     corr = jnp.exp(m_prev - m_new)
     l_sc[:] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
     m_sc[:] = m_new
-    acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
+    acc_sc[:] = acc_sc[:] * _lanes(corr, d) + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -267,7 +295,7 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv):
     @pl.when(last)
     def _finalize():
         l = jnp.maximum(l_sc[:], 1e-30)
-        o_ref[0, 0] = (acc_sc[:] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_sc[:] / _lanes(l, d)).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_sc[:, 0] + jnp.log(l[:, 0]))[None, :]
 
 
@@ -303,8 +331,8 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((bq, _LANES), jnp.float32),  # running sum
         ],
     )(*tables, q, k, v)
     return out, lse[:, :, 0, :]

@@ -216,6 +216,20 @@ def _sq_loss(fn):
     return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
 
 
+def _softmax_reference(q, k, v, causal):
+    """Plain softmax attention and the log-sum-exp of its scores; causal
+    is row >= column from the top left, as the kernels draw it."""
+    n_rep = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, n_rep, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(float(q.shape[-1]))
+    if causal:
+        visible = (jnp.arange(q.shape[2])[:, None]
+                   >= jnp.arange(k.shape[2])[None, :])
+        s = jnp.where(visible, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v), lse
+
+
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 1), (2, 2)],
                          ids=["gqa2", "mqa", "mha"])
@@ -266,11 +280,7 @@ def test_causal_rectangle_row_ge_column(sq, skv):
     v = jax.random.normal(kv, (1, 2, skv, 32))
 
     def ref(q, k, v):
-        k, v = (jnp.repeat(x, 2, axis=1) for x in (k, v))
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(32.0)
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(skv)[None, :]
-        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+        return _softmax_reference(q, k, v, causal=True)[0]
 
     def flash(q, k, v):
         return pallas_flash_attention(
@@ -303,3 +313,64 @@ def test_raw_entries_match_the_differentiable_op(causal):
     for a, b in zip(flash_block_backward(q, k, v, out, lse, dout, **kw),
                     vjp(dout)):
         assert jnp.array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# the forward's statistics, lane-replicated (PR 38): m and l are held
+# [bq, 128]; what widens them to the key block and to the head is chosen
+# from the static shapes, so each branch gets a shape that selects it
+# ---------------------------------------------------------------------------
+STATISTICS_CASES = [
+    # hq, hkv, sq, skv, d, bq, bkv, causal
+    # key blocks under a register's width: the leading lanes
+    (4, 2, 128, 128, 64, 32, 32, True),
+    (4, 1, 128, 128, 128, 64, 64, False),
+    (2, 2, 128, 64, 256, 32, 32, True),
+    (4, 2, 64, 128, 64, 16, 64, True),
+    # one register wide: as held
+    (4, 2, 256, 256, 128, 128, 128, True),
+    (4, 1, 128, 256, 64, 64, 128, False),
+    (2, 2, 256, 256, 256, 64, 128, True),
+    # wider: repeated whole registers
+    (4, 2, 512, 512, 64, 128, 256, True),
+    (2, 2, 256, 512, 128, 128, 256, False),
+    (4, 1, 512, 512, 256, 256, 256, True),
+    (4, 2, 256, 512, 128, 64, 512, True),
+    # neither: 192 = a register and a half
+    (4, 2, 192, 384, 64, 64, 192, False),
+]
+
+
+@pytest.mark.parametrize(
+    "hq,hkv,sq,skv,d,bq,bkv,causal", STATISTICS_CASES,
+    ids=[f"h{c[0]}-{c[1]}_s{c[2]}-{c[3]}_d{c[4]}_b{c[5]}-{c[6]}_"
+         + ("causal" if c[7] else "full") for c in STATISTICS_CASES])
+def test_lane_replicated_statistics_at_every_width(
+        hq, hkv, sq, skv, d, bq, bkv, causal):
+    """``out``, ``lse`` and the three gradients against plain softmax,
+    over key blocks under, at and over 128 lanes, heads 64 / 128 / 256
+    wide, GQA / MQA / MHA, causal and full, ``sq != skv``. The lse that
+    ring attention merges across hops is finite on every row and is the
+    log-sum-exp of the reference's scores."""
+    kq, kk, kv = jax.random.split(jax.random.key(bkv + d + hkv), 3)
+    q = jax.random.normal(kq, (1, hq, sq, d))
+    k = jax.random.normal(kk, (1, hkv, skv, d))
+    v = jax.random.normal(kv, (1, hkv, skv, d))
+    kw = dict(causal=causal, block_q=bq, block_kv=bkv, interpret=True)
+
+    out, lse = flash_forward_with_lse(q, k, v, **kw)
+    want_out, want_lse = _softmax_reference(q, k, v, causal)
+    assert lse.shape == (1, hq, sq) and bool(jnp.all(jnp.isfinite(lse)))
+    assert jnp.max(jnp.abs(lse - want_lse)) < 1e-5
+    assert jnp.max(jnp.abs(out - want_out)) < 1e-5
+
+    def flash(q, k, v):
+        return pallas_flash_attention(q, k, v, **kw)
+
+    assert jnp.array_equal(flash(q, k, v), out)
+    gp = jax.grad(_sq_loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(_sq_loss(
+        lambda q, k, v: _softmax_reference(q, k, v, causal)[0]),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        assert jnp.max(jnp.abs(a - b)) < 1e-4

@@ -492,14 +492,18 @@ def test_narrow_heads_take_the_lax_pair_in_the_same_loop(one_chip):
 # or a result of another shape would leave `train_attn_roofline` with
 # nothing, or the wrong thing, to read.
 # ---------------------------------------------------------------------------
+def _flash_args(one_chip, hq, hkv, s, d):
+    """``q, k, v`` at batch 1, bf16, on the one chip."""
+    return [jax.ShapeDtypeStruct((1, heads, s, d), jnp.bfloat16,
+                                 sharding=one_chip)
+            for heads in (hq, hkv, hkv)]
+
+
 def _flash_calls(one_chip, fn, hq, hkv, s, d):
     """The Mosaic calls of ``fn(q, k, v)`` at batch 1, bf16, as the trace
     reader names them: ``instr | opcode | target | result``."""
-    def arg(heads):
-        return jax.ShapeDtypeStruct((1, heads, s, d), jnp.bfloat16,
-                                    sharding=one_chip)
-
-    text = jax.jit(fn).lower(arg(hq), arg(hkv), arg(hkv)).compile().as_text()
+    text = jax.jit(fn).lower(
+        *_flash_args(one_chip, hq, hkv, s, d)).compile().as_text()
     return [short_name(re.sub(r"^\s*(ROOT )?", "", line))
             for line in _mosaic_calls(text)]
 
@@ -561,6 +565,39 @@ def test_flash_forward_alone_is_one_call(one_chip):
         one_chip, lambda q, k, v: pallas_flash_attention(q, k, v),
         16, 8, 8192, 128)
     assert _flash_kinds(calls) == ["flash_fwd"] and len(calls) == 1, calls
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, the ones inside a
+    ``custom_vjp`` or a ``jit`` too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("d", [128, 64, 256])
+def test_flash_forward_holds_its_statistics_a_register_wide(one_chip, d):
+    """The running maximum and sum are ``(bq, 128)`` float32 in VMEM,
+    every lane of a row the row's value, whatever the head's width: a
+    ``(bq, 1)`` scratch uses one lane of 128 in every register it
+    touches and cost the forward 1.4 of its 4.0 ms on the chip (PERF.md,
+    PR 38). A later edit that narrows them fails here, on a CPU."""
+    traced = jax.jit(lambda q, k, v: pallas_flash_attention(q, k, v)).trace(
+        *_flash_args(one_chip, 16, 8, 8192, d))
+    (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+    assert call.params["name"] == "flash_fwd"
+    scratch = call.params["grid_mapping"].scratch_avals
+    assert [(str(ref.memory_space), ref.shape, ref.dtype)
+            for ref in scratch] == [
+        ("vmem", (512, d), jnp.float32),     # the output's accumulator
+        ("vmem", (512, 128), jnp.float32),   # running max
+        ("vmem", (512, 128), jnp.float32),   # running sum
+    ]
+    assert len(_mosaic_calls(traced.lower().compile().as_text())) == 1
 
 
 @pytest.mark.parametrize("hq,hkv,s,d,kw", [
