@@ -7,9 +7,14 @@ nothing to read (the harness then leaves the metric out of the line).
 
 Context keys: ``events`` (trace events), ``window`` (ns interval),
 ``records`` (name -> list of dicts: ``access`` from the gateway,
-``loadgen`` from the client), ``counters`` (name -> number), ``config``,
-``traffic``, ``workload``, ``peaks``, ``spec`` (finds a named
-``cost_module``; not needed for the default one).
+``loadgen`` from the client), ``counters`` (name -> number), ``client``
+(the run's client view: every number the runner took from the client's
+clock, the end-to-end values among them), ``config``, ``traffic``,
+``workload``, ``peaks``, ``spec`` (finds a named ``cost_module``; not
+needed for the default one).
+
+A kind in ``UNTRACED_KINDS`` reads nothing of the trace, the records or
+the counters, so ``run.py`` prints its metrics for an untraced run too.
 """
 
 from __future__ import annotations
@@ -174,6 +179,16 @@ def counter(ctx: Context, params: Dict[str, Any]) -> Optional[float]:
     return None if value is None else value * params.get("scale", 1.0)
 
 
+def client_value(ctx: Context, params: Dict[str, Any]) -> Optional[float]:
+    """One number of the run's client view by its ``key`` there (a
+    serving run's: ``serve_cell.client_metrics``), scaled: neither a
+    span nor a counter, and there with the profiler off."""
+    value = (ctx.get("client") or {}).get(params["key"])
+    return None if value is None else value * params.get("scale", 1.0)
+
+
+UNTRACED_KINDS = ("client_value",)
+
 KINDS: Dict[str, Callable[[Context, Dict[str, Any]], Optional[float]]] = {
     "share_of_window": share_of_window,
     "idle_share": idle_share,
@@ -183,6 +198,7 @@ KINDS: Dict[str, Callable[[Context, Dict[str, Any]], Optional[float]]] = {
     "roofline_share": roofline_share,
     "record_percentile": record_percentile,
     "counter": counter,
+    "client_value": client_value,
 }
 
 

@@ -68,27 +68,41 @@ def refuse(reason: str) -> int:
     return REFUSED_EXIT
 
 
+def read_per_layer(spec, cell: str, reduce_ctx,
+                   untraced_only: bool = False) -> dict:
+    """The cell's per-layer metrics, each by its own reader, that found
+    something to read: name -> value and unit. ``untraced_only`` keeps
+    the readers that need no trace (``reducers.UNTRACED_KINDS``)."""
+    from benchmarks.lib import reducers
+
+    found = {}
+    for metric in spec.per_layer(cell):
+        if untraced_only and \
+                metric["reducer"]["kind"] not in reducers.UNTRACED_KINDS:
+            continue
+        value = reducers.read_metric(reduce_ctx, metric)
+        if value is not None:
+            found[metric["name"]] = {"value": value, "unit": metric["unit"]}
+    return found
+
+
 def report_per_layer(line, tracer, spec, args, result, counters, config,
                      traffic, workload, peaks) -> None:
     """Fills the result line of a traced run: the cell's per-layer
-    metrics, each by its own reader, the device's busy and window
-    seconds, and the breakdown. Off a TPU the trace has no device plane:
-    the readers of device metrics then return nothing."""
-    from benchmarks.lib import reducers, trace
+    metrics, the device's busy and window seconds, and the breakdown.
+    Off a TPU the trace has no device plane: the readers of device
+    metrics then return nothing."""
+    from benchmarks.lib import trace
 
     events = tracer.events()
     window = (trace.device_window(events)
               if trace.device_planes(events) else None)
-    reduce_ctx = {
+    line["metrics"] = read_per_layer(spec, args.workload, {
         "events": events, "window": window, "records": result["records"],
-        "counters": counters, "config": config, "traffic": traffic,
-        "workload": workload, "peaks": peaks or {}, "spec": spec,
-    }
-    for metric in spec.per_layer(args.workload):
-        value = reducers.read_metric(reduce_ctx, metric)
-        if value is not None:
-            line["metrics"][metric["name"]] = {
-                "value": value, "unit": metric["unit"]}
+        "counters": counters, "client": result["values"], "config": config,
+        "traffic": traffic, "workload": workload, "peaks": peaks or {},
+        "spec": spec,
+    })
     line["breakdown"] = {"device_ops": [], "idle_gaps": []}
     if window is None:
         line["device"]["window_s"] = tracer.stopped_at - tracer.started_at
@@ -192,17 +206,24 @@ def main(argv=None) -> int:
         line = {"correct": not problems,
                 "attempted": int(result["attempted"]),
                 "failed": int(result["failed"]),
-                "metrics": {}, "device": out_device,
-                "check": result["check"], "problems": problems}
+                "metrics": {}, "device": out_device}
         if not args.trace:
             for metric in spec.end_to_end(args.workload):
                 line["metrics"][metric["name"]] = {
                     "value": values[metric["name"]], "unit": metric["unit"]}
+            # the per-layer metrics that need no trace, under a key of
+            # their own: an untraced run's ``metrics`` are the
+            # end-to-end ones and no other
+            line["per_layer_untraced"] = read_per_layer(
+                spec, args.workload, {"client": result["values"]},
+                untraced_only=True)
             for note in runner.notes(ctx, result, peaks):
                 log(note)
         else:
             report_per_layer(line, tracer, spec, args, result, counters,
                              config, traffic, workload, peaks)
+        # what was compared, each number beside its limit: last in the line
+        line.update(check=result["check"], problems=problems)
     except Refused as exc:
         return refuse(str(exc))
     finally:

@@ -44,13 +44,33 @@ def test_serve_cell_prints_the_contracts_line(which, request):
 
 def test_closed_loop_reports_the_gap_tail(closed_loop, traced):
     _, line, out = closed_loop
+    # the toy cell stands for every serving cell: the longgen cells'
+    # two percentiles and the chat cell's 99.5th
     assert set(line["metrics"]) == {"serve_itl_p95_ms", "serve_itl_p99_ms",
-                                    "setup_s"}, out
-    assert line["metrics"]["serve_itl_p99_ms"]["value"] >= \
+                                    "serve_itl_p995_ms", "setup_s"}, out
+    assert line["metrics"]["serve_itl_p995_ms"]["value"] >= \
+        line["metrics"]["serve_itl_p99_ms"]["value"] >= \
         line["metrics"]["serve_itl_p95_ms"]["value"] > 0
     # delivered tokens/s has a reader (a counter) and is in no cell yet
     _, line, out = traced
     assert line["metrics"]["serve_tokens_per_s"]["value"] > 0, out
+
+
+@pytest.mark.parametrize("which", ["closed_loop", "open_loop", "traced"])
+def test_long_gap_share_is_printed_with_and_without_a_trace(which, request):
+    """A ``client_value`` reader needs no trace: an untraced run prints
+    it under a key of its own (its ``metrics`` are the end-to-end ones
+    and no other), a traced run among its per-layer metrics."""
+    _, line, out = request.getfixturevalue(which)
+    where = "metrics" if which == "traced" else "per_layer_untraced"
+    found = line[where]["serve_itl_long_gap_share_pct"]
+    assert found["unit"] == "%" and 0.0 <= found["value"] < 100.0, out
+    if which != "traced":
+        assert set(line["per_layer_untraced"]) == {
+            "serve_itl_long_gap_share_pct",
+            "serve_itl_long_gap_share_pct.chat"}
+    # what was compared, beside its limit, comes last in the line
+    assert list(line)[-2:] == ["check", "problems"]
 
 
 def test_open_loop_times_from_the_due_instant(open_loop, traced):

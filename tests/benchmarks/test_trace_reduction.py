@@ -262,11 +262,11 @@ def test_chat_metric_files_read_the_recorded_trace(chat_trace):
     cell = "serve-1.7b-chat"
     ctx_ = cell_ctx(cell, events, window)
     decode = reducers.read_metric(
-        ctx_, metric_reader(cell, "serve_decode_step_device_ms"))
+        ctx_, metric_reader(cell, "serve_decode_step_device_ms.chat"))
     tick = reducers.read_metric(
-        ctx_, metric_reader(cell, "serve_tick_interval_p50_ms"))
+        ctx_, metric_reader(cell, "serve_tick_interval_p50_ms.chat"))
     prefill = reducers.read_metric(
-        ctx_, metric_reader(cell, "serve_prefill_device_share"))
+        ctx_, metric_reader(cell, "serve_prefill_device_share.chat"))
     assert decode == pytest.approx(96.0, abs=1.0)
     assert decode < tick < decode + 10     # the host's part of a tick
     assert 25 < prefill < 70               # one or two 0.64-0.75 s calls in 2 s

@@ -11,7 +11,9 @@ from benchmarks.lib.spec import Spec
 SPEC = Spec()
 CONFIG_06B = SPEC.config("qwen3-0.6b-train")
 CONFIG_17B = SPEC.config("qwen3-1.7b-serve")
-CHAT = SPEC.traffic("chat-open-loop-r0.8")
+# the chat cell's mix, under whatever rate its last sweep gave it
+CHAT_NAME = SPEC.workload("serve-1.7b-chat")["traffic"]
+CHAT = SPEC.traffic(CHAT_NAME)
 LONGGEN = SPEC.traffic("longgen-closed16")
 BIG_SEED = 2**31 + 12345
 
@@ -147,7 +149,7 @@ def test_open_loop_seed_permutes_one_fixed_multiset():
     assert "NOT a Poisson process" in CHAT["note"]
 
 
-@pytest.mark.parametrize("mix", ["chat-open-loop-r0.8", "longgen-closed16"])
+@pytest.mark.parametrize("mix", [CHAT_NAME, "longgen-closed16"])
 def test_serving_mix_names_where_its_lengths_come_from(mix):
     data = SPEC.traffic(mix)
     assert len(data["lengths_source"]) > 40
