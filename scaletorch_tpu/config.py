@@ -86,8 +86,8 @@ class ModelArguments:
     model_type: str = field(
         default="llama",
         metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
-                          "olmo_hybrid | qwen3_next | gpt_moe | lenet | "
-                          "mingpt"},
+                          "olmo_hybrid | qwen3_next | afmoe | gpt_moe | "
+                          "lenet | mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -129,10 +129,34 @@ class ModelArguments:
         default=None,
         metadata={"help": "Standard deviation the random initialiser "
                           "draws the token embedding at (qwen3_next "
-                          "only; unset: 0.02, HF's initializer_range). "
+                          "and afmoe; unset: 0.02, HF's "
+                          "initializer_range). "
                           "A property of random weights, not of the "
                           "model."},
     )
+    # afmoe, by the published config.json names (layer_types above:
+    # sliding_attention | full_attention; omitted = every
+    # global_attn_every_n_layers-th layer full): the window of the
+    # sliding_attention layers, the leading layers with a dense MLP,
+    # the sigmoid router (score_func, route_norm, route_scale; n_group /
+    # topk_group must be 1), the ungated shared experts, the embedding
+    # times sqrt(hidden) (mup_enabled). The window is
+    # ``sliding_window_size`` here: the dense families' published files
+    # carry ``sliding_window: null`` and ``rope_scaling: null``, and
+    # what reaches these arguments from them is pinned
+    # (tests/benchmarks/test_pass_through.py); a published
+    # ``rope_scaling`` other than null has no argument at all
+    # (``AfmoeConfig`` refuses one)
+    sliding_window_size: int = 2048
+    global_attn_every_n_layers: int = 4
+    num_dense_layers: int = 2
+    num_shared_experts: int = 1
+    score_func: str = "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 2.826
+    n_group: int = 1
+    topk_group: int = 1
+    mup_enabled: bool = True
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "

@@ -51,15 +51,20 @@ def _resolve_donate(donate_cache: Optional[bool]) -> bool:
 
 def resolve_forward_cached(cfg) -> Callable:
     """The cache-aware forward for a model config: Qwen3-MoE, GPT-MoE,
-    Olmo-Hybrid and Qwen3-Next (a subclass of the hybrid's: asked
+    afmoe, Olmo-Hybrid and Qwen3-Next (a subclass of the hybrid's: asked
     first) have their own cached forwards; every other LlamaConfig
     subclass (Llama, Qwen3) shares the Llama one."""
+    from scaletorch_tpu.models.afmoe import AfmoeConfig
     from scaletorch_tpu.models.gpt_moe import GPTMoEConfig
     from scaletorch_tpu.models.llama import LlamaConfig
     from scaletorch_tpu.models.olmo_hybrid import OlmoHybridConfig
     from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
     from scaletorch_tpu.models.qwen3_next import Qwen3NextConfig
 
+    if isinstance(cfg, AfmoeConfig):
+        from scaletorch_tpu.models import afmoe
+
+        return afmoe.forward_cached
     if isinstance(cfg, Qwen3NextConfig):
         from scaletorch_tpu.models import qwen3_next
 
@@ -87,13 +92,14 @@ def resolve_forward_cached(cfg) -> Callable:
 
 def counts_routing(cfg) -> bool:
     """Whether the config's cached forward counts what it routes
-    (``return_routing``): the Qwen3-MoE family, OLMoE included, and
-    Qwen3-Next, whose steps take the row mask of a state-carrying model
-    and the routing accumulator side by side."""
+    (``return_routing``): the Qwen3-MoE family, OLMoE included, afmoe,
+    and Qwen3-Next, whose steps take the row mask of a state-carrying
+    model and the routing accumulator side by side."""
+    from scaletorch_tpu.models.afmoe import AfmoeConfig
     from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
     from scaletorch_tpu.models.qwen3_next import Qwen3NextConfig
 
-    return isinstance(cfg, (Qwen3MoEConfig, Qwen3NextConfig))
+    return isinstance(cfg, (Qwen3MoEConfig, Qwen3NextConfig, AfmoeConfig))
 
 
 def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
@@ -105,7 +111,10 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
     bit-identical. A cache with slot-indexed buffers
     (``kv_cache.HybridCache``: recurrent state, convolution tail) takes
     a second mask, ``slot_mask`` [slots] bool, for those: one call
-    clears (or poisons) a slot's pages and its state together.
+    clears (or poisons) a slot's pages and its state together. The
+    rings of a ``kv_cache.WindowCache`` take the same ``slot_mask``,
+    spread over each named slot's ring pages (TRASH, page 0 of a ring
+    buffer, is never filled).
 
     One compile serves the scalar consumers — quarantine hygiene
     (value 0: a retired poison slot's NaN K/V must not outlive the
@@ -122,20 +131,31 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
     """
 
     def fill_slots(cache, mask, value, slot_mask=None):
-        from scaletorch_tpu.inference.kv_cache import SLOT_FIELDS
+        from scaletorch_tpu.inference.kv_cache import (
+            RING_FIELDS,
+            SLOT_FIELDS,
+        )
 
         vals = tuple(value) if isinstance(value, tuple) \
             else (value,) * len(cache)
-        by_slot = [name in SLOT_FIELDS
-                   for name in getattr(cache, "_fields", ())]
+        names = getattr(cache, "_fields", ("",) * len(cache))
 
-        def fill(buf, val, slots):
-            m = slot_mask if slots else mask
+        def over_rings(pages):
+            """``slot_mask`` spread over each slot's ring pages, TRASH
+            (page 0) left out."""
+            ring = (pages - 1) // slot_mask.shape[0]
+            return jnp.concatenate([
+                jnp.zeros((1,), bool), jnp.repeat(slot_mask, ring)])
+
+        def fill(buf, val, name):
+            m = (slot_mask if name in SLOT_FIELDS
+                 else over_rings(buf.shape[1]) if name in RING_FIELDS
+                 else mask)
             m = m.reshape((1, m.shape[0]) + (1,) * (buf.ndim - 2))
             return jnp.where(m, jnp.asarray(val, buf.dtype), buf)
 
-        return type(cache)(*(fill(buf, val, slots) for buf, val, slots
-                             in zip(cache, vals, by_slot)))
+        return type(cache)(*(fill(buf, val, name) for buf, val, name
+                             in zip(cache, vals, names)))
 
     return jax.jit(
         fill_slots,

@@ -147,6 +147,7 @@ from scaletorch_tpu.inference.kv_cache import (  # noqa: E402
     carries_state,
     ceil_div,
     init_paged_kv_cache,
+    window_of,
 )
 from scaletorch_tpu.telemetry.histogram import LogHistogram  # noqa: E402
 from scaletorch_tpu.telemetry.spans import span  # noqa: E402
@@ -322,6 +323,13 @@ class DisaggregatedEngine(InferenceEngine):
                 "prefill slice to the decode slice (the channel moves "
                 "pages; a state has none, and no snapshots at page "
                 "boundaries exist to move instead)")
+        if window_of(cfg) is not None:
+            raise NotImplementedError(
+                f"DisaggregatedEngine: {type(cfg).__name__} has "
+                "window-attention layers, whose K/V is kept by slot in a "
+                "ring; what is missing is the hand-off of a request's "
+                "rings from the prefill slice to the decode slice (the "
+                "channel moves the full-attention layers' pages only)")
         devs = list(devices) if devices is not None else list(jax.devices())
         if isinstance(disagg_split, str):
             disagg_split = parse_disagg_spec(disagg_split)

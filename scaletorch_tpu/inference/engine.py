@@ -99,8 +99,12 @@ from scaletorch_tpu.inference.kv_cache import (
     ceil_div,
     init_paged_kv_cache,
     kv_cache_bytes,
+    no_prefix_reason,
     paged_kv_cache_shardings,
     recurrent_state_bytes,
+    window_cache_bytes,
+    window_of,
+    window_ring_pages,
 )
 from scaletorch_tpu.inference.resilience import (
     TERMINAL_OUTCOMES,
@@ -255,6 +259,19 @@ class EngineMetrics:
     recurrent_state_bytes: int = 0
     recurrent_state_resets: int = 0
     recurrent_state_owner_mismatches: int = 0
+    # a model with window-attention layers (kv_cache.WindowCache): the
+    # bytes of its rings (0: no such model, and none of these five is
+    # in the snapshot); times a slot's write position passed its ring's
+    # end (a prompt longer than the ring has at admission); keys one
+    # window layer and one full layer attended, summed over the decode
+    # slot-steps dispatched (from the positions: min(p + 1, window) and
+    # p + 1); decode slot-steps dispatched on a slot whose ring was
+    # last started by another request (0 or a fault)
+    window_cache_bytes: int = 0
+    window_ring_wraps: int = 0
+    window_keys_attended: int = 0
+    full_keys_attended: int = 0
+    window_slot_reuse_mismatches: int = 0
 
     def record_ttft(self, ttft_s: float) -> None:
         self.hist["ttft"].observe(ttft_s)
@@ -312,6 +329,11 @@ class EngineMetrics:
             snap["recurrent_state_resets"] = self.recurrent_state_resets
             snap["recurrent_state_owner_mismatches"] = (
                 self.recurrent_state_owner_mismatches)
+        if self.window_cache_bytes:
+            for name in ("window_cache_bytes", "window_ring_wraps",
+                         "window_keys_attended", "full_keys_attended",
+                         "window_slot_reuse_mismatches"):
+                snap[name] = getattr(self, name)
         return snap
 
 
@@ -419,7 +441,10 @@ class InferenceEngine:
         says: a shared page holds K/V for its tokens, and nothing holds
         the recurrent state after them (no snapshots at page
         boundaries), so the export / import of prefix pages refuses
-        such a model by name.
+        such a model by name. So is a model with window-attention
+        layers (``kv_cache.window_of``): their K/V lives by slot in a
+        ring that holds a suffix. ``kv_cache.no_prefix_reason`` is the
+        one place that says which models these are.
     mesh / tp_axis : optional — shard the pool's KV heads over
         ``tp_axis`` of the mesh (the page axis stays unsharded: pages
         are not slot-aligned).
@@ -571,9 +596,16 @@ class InferenceEngine:
         self._token_home = (
             replicated if mesh is not None and mesh.size > 1 else None)
         # state-carrying layers: a recurrent state per slot beside the
-        # pool, no prefix sharing (class docstring)
+        # pool; window layers: a ring of pages per slot beside it.
+        # Neither shares a prefix (class docstring)
         self._stateful = carries_state(cfg)
-        prefix_cache = prefix_cache and not self._stateful
+        self._window = window_of(cfg)
+        self._by_slot = self._stateful or self._window is not None
+        # tokens one slot's ring holds in a window layer
+        self._ring_tokens = (
+            0 if self._window is None
+            else page_size * window_ring_pages(self._window, page_size))
+        prefix_cache = prefix_cache and no_prefix_reason(cfg) is None
         self.cache = init_paged_kv_cache(
             cfg, num_pages, page_size, dtype=cache_dtype, sharding=sharding,
             slots=max_slots)
@@ -584,7 +616,8 @@ class InferenceEngine:
                 self.allocator.refcount,
             ) if prefix_cache else None
         )
-        # the request whose prefill last started each slot's state
+        # the request whose prefill last started each slot's state (or
+        # its window layers' rings)
         self._state_owner: List[Optional[int]] = [None] * max_slots
         # per-slot page table (host copy; reaches the device as data
         # every step), the pages each slot holds a reference on
@@ -607,6 +640,9 @@ class InferenceEngine:
                            dtype=cache_dtype) / 2**20,
             (f" + {recurrent_state_bytes(self.cache) / 2**20:.1f} MiB of "
              "recurrent state by slot" if self._stateful else "")
+            + (f" + {window_cache_bytes(self.cache) / 2**20:.1f} MiB of "
+               "window layers' rings by slot"
+               if self._window is not None else "")
             + (", prefix cache on" if prefix_cache else ""),
             f", sharded over {mesh.axis_names}" if mesh is not None
             else "",
@@ -647,7 +683,8 @@ class InferenceEngine:
         self.metrics = EngineMetrics(
             num_slots=max_slots, routing=routing,
             paged_pool_in_place=int(in_place_pair(self.cache.k.shape[-1])),
-            recurrent_state_bytes=recurrent_state_bytes(self.cache))
+            recurrent_state_bytes=recurrent_state_bytes(self.cache),
+            window_cache_bytes=window_cache_bytes(self.cache))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
         # the clock that is open, the last boundary; this tick's seconds
         # by phase name; when the previous tick ended, and whether it
@@ -1029,9 +1066,10 @@ class InferenceEngine:
               value: float) -> None:
         """The masked fill of the cache: ``value`` into the masked pages
         and, for a model with state-carrying layers, into the recurrent
-        state and convolution tail of ``slots``."""
+        state and convolution tail of ``slots``; for one with window
+        layers, into their rings."""
         by_slot = ()
-        if self._stateful:
+        if self._by_slot:
             slot_mask = np.zeros(self.max_slots, bool)
             slot_mask[slots] = True
             by_slot = (jnp.asarray(slot_mask),)
@@ -1079,16 +1117,13 @@ class InferenceEngine:
     # green.
 
     def _refuse_prefix_exchange(self, what: str) -> None:
-        """A model with state-carrying layers has no prefix to share:
-        its pages hold the full-attention layers' K/V, and the recurrent
-        state after those tokens was never kept."""
-        if self._stateful:
+        """A model whose pages are no prefix (``no_prefix_reason``: a
+        recurrent state that was never kept, or window layers' K/V that
+        lives by slot) has none to export or import."""
+        reason = no_prefix_reason(self.cfg)
+        if reason is not None:
             raise NotImplementedError(
-                f"{what}: {type(self.cfg).__name__} has state-carrying "
-                "layers, and what is missing is snapshots of the "
-                "recurrent state at page boundaries; without them a "
-                "shared or transferred prefix page has no state to "
-                "continue from")
+                f"{what}: {type(self.cfg).__name__} {reason}")
 
     def export_prefix_map(self) -> Dict[str, Any]:
         """Snapshot the radix tree for a warming peer: root-to-leaf
@@ -1382,11 +1417,17 @@ class InferenceEngine:
                 self._base_keys_device(),
             )
         self.metrics.prefill_calls += 1
-        if self._stateful:
+        if self._by_slot:
             # the call started every admitted slot's state from zero
+            # (or its rings from the prompt)
             for i in admitted:
                 self._state_owner[i] = self._slots[i].request.request_id
+        if self._stateful:
             self.metrics.recurrent_state_resets += len(admitted)
+        if self._window is not None:
+            self.metrics.window_ring_wraps += sum(
+                (self._slots[i].position - 1) // self._ring_tokens
+                for i in admitted)
         with self._phase("engine.tick.prefill_wait"):
             first = np.asarray(first)
             finite = np.asarray(finite)
@@ -1622,11 +1663,17 @@ class InferenceEngine:
                         positions[i] = before.positions[i] + 1
             if not bound:
                 return None
-            if self._stateful:
-                self.metrics.recurrent_state_owner_mismatches += sum(
-                    self._state_owner[i] != req.request_id
-                    for i, req in bound)
-            active[[i for i, _ in bound]] = True
+            if self._by_slot:
+                strangers = sum(self._state_owner[i] != req.request_id
+                                for i, req in bound)
+                if self._stateful:
+                    self.metrics.recurrent_state_owner_mismatches += strangers
+                else:
+                    self.metrics.window_slot_reuse_mismatches += strangers
+            held = [i for i, _ in bound]
+            if self._window is not None:
+                self._count_window_keys(positions[held])
+            active[held] = True
             # positions and active stay numpy: the jitted call uploads
             # host operands itself, without the 0.25 ms of Python a
             # jnp.asarray each costs on a v5e's host
@@ -1656,6 +1703,16 @@ class InferenceEngine:
             if self.on_dispatched is not None:
                 self.on_dispatched()
         return _InFlight(number, nxt, finite, positions, bound, stall)
+
+    def _count_window_keys(self, positions: np.ndarray) -> None:
+        """What one dispatched decode step's slots attend, by kind of
+        layer (one layer of each), and the rings that wrap at it."""
+        keys = positions.astype(np.int64) + 1
+        self.metrics.full_keys_attended += int(keys.sum())
+        self.metrics.window_keys_attended += int(
+            np.minimum(keys, self._window).sum())
+        self.metrics.window_ring_wraps += int(np.sum(
+            (positions > 0) & (positions % self._ring_tokens == 0)))
 
     def _read(self, flight: _InFlight) -> None:
         """Read a dispatched step back and emit it: one token for each

@@ -39,6 +39,20 @@ convolution tail ``[linear layers, slots, kernel - 1, channels]``. A
 slot's state has no pages, no table and no snapshots: it is whatever the
 slot's request has read so far, started from zero by its prefill.
 
+A model whose layers mix window and full attention (afmoe: three
+``sliding_attention`` layers to one ``full_attention``) keeps its two
+kinds of K/V in ONE cache pytree too, ``WindowCache``: the page pool
+over its full-attention layers (pages by request, through the tables)
+and beside it, by SLOT, a ring of ``window_ring_pages`` pages a slot
+and window layer: position p lives in ring page ``(p // page_size) %
+ring_pages`` of its slot. No table reaches the device for it:
+``RingKVIO`` computes one inside the jitted step from the positions,
+whose logical page ``t // page_size`` repeats the ring, so the same
+``paged_write`` / ``paged_attention`` pair serves it, told the window
+(the mask is by position, the kernel's walk starts at the window's
+first page). What a slot's earlier request left in its ring is never
+read: a position inside a request's window was written by that request.
+
 MLA models cache only the low-rank latent (``MLACache``,
 [B, S_max, kv_rank]) and re-expand K/V per step — the trade the variant
 documents (models/attention/variants.py MultiHeadLatentAttention).
@@ -186,9 +200,63 @@ class HybridCache(NamedTuple):
     conv: jax.Array
 
 
+class WindowCache(NamedTuple):
+    """The cache of a model with window AND full-attention layers, one
+    pytree that the step programs donate and return: the page pools
+    ``k`` / ``v`` ``[full layers, n_pages, Hkv, page_size, D]`` (pages
+    by request) and the rings ``wk`` / ``wv`` ``[window layers, 1 +
+    slots * ring_pages, Hkv, page_size, D]`` (page 0 TRASH, then each
+    slot's ring; module docstring)."""
+
+    k: jax.Array
+    v: jax.Array
+    wk: jax.Array
+    wv: jax.Array
+
+
 # the fields of a cache whose axis 1 counts SLOTS (every other field's
 # counts pages): what a masked fill over slots touches
 SLOT_FIELDS = ("state", "conv")
+# the fields whose axis 1 counts a TRASH page and then every slot's
+# ring of pages: a masked fill over slots touches a slot's whole ring
+RING_FIELDS = ("wk", "wv")
+
+
+def window_of(cfg) -> Optional[int]:
+    """The window of a model's window-attention layers; None for a model
+    without any (its cache is the page pool alone)."""
+    return cfg.sliding_window if hasattr(cfg, "num_window_layers") else None
+
+
+def no_prefix_reason(cfg) -> Optional[str]:
+    """Why a model's pages are no prefix another request could share or
+    a peer import (None: they are one). The one place that says so: the
+    engine drops its radix tree and refuses the prefix exchange on it,
+    the disaggregated engine refuses the model."""
+    if carries_state(cfg):
+        return ("has state-carrying layers, and what is missing is "
+                "snapshots of the recurrent state at page boundaries; "
+                "without them a shared or transferred prefix page has no "
+                "state to continue from")
+    if window_of(cfg) is not None:
+        return ("has window-attention layers, whose K/V is kept by slot "
+                "in a ring that holds a suffix of the slot's tokens; a "
+                "shared or transferred prefix page of the full-attention "
+                "layers has no window-layer K/V to go with it")
+    return None
+
+
+def window_ring_pages(window: int, page_size: int) -> int:
+    """Pages of one slot's ring in one window layer: the window's keys
+    lie on at most ``ceil(window / page_size) + 1`` pages (129 at 2048 /
+    16), whatever the position."""
+    return ceil_div(window, page_size) + 1
+
+
+def window_cache_bytes(cache: Any) -> int:
+    """Bytes of the rings of a cache; 0 without any."""
+    return sum(getattr(cache, name).nbytes for name in RING_FIELDS
+               if hasattr(cache, name))
 
 
 def carries_state(cfg) -> bool:
@@ -242,17 +310,24 @@ def init_paged_kv_cache(
         if isinstance(sharding, PagedKVCache) else (sharding, sharding)
     # device=: allocated on the shards, never whole on the default device
     k, v = jnp.zeros(shape, dt, device=sk), jnp.zeros(shape, dt, device=sv)
-    if not carries_state(cfg):
+    window = window_of(cfg)
+    if window is None and not carries_state(cfg):
         return PagedKVCache(k=k, v=v)
     if slots is None:
         raise ValueError(
-            f"{type(cfg).__name__} has state-carrying layers: its cache "
+            f"{type(cfg).__name__} keeps memory by slot beside its pages "
+            "(a recurrent state, or window layers' rings): its cache "
             "needs the number of slots beside the number of pages")
     if sk is not None and len(sk.device_set) > 1:
         raise NotImplementedError(
-            "a recurrent state over several devices (tensor parallelism "
-            "over state-carrying layers) is not written: serve this "
-            "model on one device")
+            "a recurrent state or a window layer's ring over several "
+            "devices (tensor parallelism over such layers) is not "
+            "written: serve this model on one device")
+    if window is not None:
+        ring = (cfg.num_window_layers,
+                1 + slots * window_ring_pages(window, page_size)) + shape[2:]
+        return WindowCache(k, v, jnp.zeros(ring, dt, device=sk),
+                           jnp.zeros(ring, dt, device=sv))
     return HybridCache(k, v, *_zero_recurrent_state(cfg, slots, dt, sk))
 
 
@@ -579,3 +654,58 @@ class PagedKVIO:
             else self.kernel and q.shape[2] == 1,
             interpret=self.interpret,
         )
+
+
+class RingKVIO:
+    """``PagedKVIO``'s twin for the window layers of a ``WindowCache``:
+    the same pair of a write and a read, on the rings ``[window layers,
+    1 + slots * ring_pages, Hkv, page_size, D]``, through tables that
+    are computed here, inside the jitted step, from the call's
+    positions (nothing about a ring reaches the device as an operand).
+
+    ``tables`` ``[B, max_pages]``: logical page ``t // page_size`` of
+    slot b is ring page ``1 + b * ring_pages + (t // page_size) %
+    ring_pages``, so position t lands where ``paged_write`` and the
+    decode kernel look for it, and the newest ``window`` positions of a
+    slot are always resident (the ring holds ``window + page_size`` at
+    the least). A call's rows run from ``first`` [B] for ``live`` [B]
+    rows a slot (a prefill's prompt, a decode step's one token): of
+    their pages only the newest ``ring_pages`` are written, the others
+    go to TRASH, since an older page of a prompt longer than the ring
+    would land on a newer one's place, and so would the rows a fixed-
+    shape prefill buffer holds past the prompt's end.
+    ``attend`` serves the one-token read; a multi-row call of such a
+    family attends to itself and does not come here."""
+
+    def __init__(self, paged: "PagedKVIO", window: int, first: jax.Array,
+                 live: jax.Array) -> None:
+        self.paged, self.window = paged, window
+        slots, width = paged.page_tables.shape
+        ring = window_ring_pages(window, paged.page_size)
+        logical = jnp.arange(width, dtype=jnp.int32)[None, :]
+        own = 1 + ring * jnp.arange(slots, dtype=jnp.int32)[:, None]
+        self.tables = own + logical % ring
+        last = ((first + live - 1) // paged.page_size)[:, None]
+        self.write_tables = jnp.where(
+            (logical <= last) & (logical > last - ring), self.tables,
+            TRASH_PAGE)
+
+    def write(self, pool: jax.Array, layer: jax.Array, new: jax.Array,
+              positions: jax.Array,
+              write_mask: Optional[jax.Array]) -> jax.Array:
+        return paged_write(
+            pool, new, positions, self.write_tables, write_mask,
+            layer=layer, kernel=self.paged.kernel,
+            interpret=self.paged.interpret)
+
+    def attend(self, q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
+               layer: jax.Array, q_positions: jax.Array) -> jax.Array:
+        if q.shape[2] != 1:
+            raise ValueError(
+                "a window layer's ring serves one-token reads; a "
+                f"{q.shape[2]}-row call attends to itself")
+        return paged_attention(
+            q, pool_k, pool_v, self.tables, q_positions,
+            page_size=self.paged.page_size, layer=layer,
+            seq_limit=self.paged.seq_limit, kernel=self.paged.kernel,
+            interpret=self.paged.interpret, window=self.window)

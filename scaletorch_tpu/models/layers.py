@@ -278,6 +278,7 @@ def cached_sdpa_attention(
     q_positions: jax.Array,
     *,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """SDPA against a fixed-size KV cache with absolute-position masking.
 
@@ -290,7 +291,10 @@ def cached_sdpa_attention(
     overwrites position p before reading it).
 
     Same fp32-softmax math as ``sdpa_attention``, so prefill logits match
-    the full-sequence training forward to float tolerance.
+    the full-sequence training forward to float tolerance. With
+    ``window`` the query at p sees only the entries with ``p - j <
+    window`` (a window layer's ring, where index j holds the newest
+    position that is j modulo the ring's length).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -300,6 +304,8 @@ def cached_sdpa_attention(
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     key_idx = jnp.arange(k_cache.shape[2], dtype=jnp.int32)
     mask = key_idx[None, None, :] <= q_positions[:, :, None]  # [B, S, S_max]
+    if window is not None:
+        mask &= q_positions[:, :, None] - key_idx[None, None, :] < window
     scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)

@@ -281,6 +281,71 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         shared_expert_intermediate_size=48,
         norm_topk_prob=True,
     ),
+    # Trinity-Mini (arcee-ai/Trinity-Mini config.json, model_type
+    # afmoe), as published: 32 layers, three sliding_attention (window
+    # 2048, rotary) to one full_attention (no rotary), 2 leading dense
+    # layers, then 128 sigmoid-routed experts of width 1024 (top 8) and
+    # an ungated shared expert; 26 B parameters = 52 GB in bf16, eight
+    # v5e chips at the least. One chip serves a share
+    # (benchmarks/configs/trinity-mini-serve.json): --num_hidden_layers
+    # 16, --num_experts 32 --num_routed_experts 128, a quarter of the
+    # vocabulary.
+    "trinity-mini": dict(
+        model_type="afmoe",
+        vocab_size=200192,
+        hidden_size=2048,
+        intermediate_size=6144,
+        num_hidden_layers=32,
+        num_attention_heads=32,
+        num_key_value_heads=4,
+        head_dim=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=131072,
+        tie_word_embeddings=False,
+        global_attn_every_n_layers=4,
+        sliding_window_size=2048,
+        num_dense_layers=2,
+        num_experts=128,
+        num_experts_per_tok=8,
+        moe_intermediate_size=1024,
+        num_shared_experts=1,
+        score_func="sigmoid",
+        route_norm=True,
+        route_scale=2.826,
+        mup_enabled=True,
+    ),
+    # The same family at a size the CPU tests serve: two periods, a
+    # window of 24 tokens (a ring of 4 pages of 8), 2 leading dense
+    # layers, and a SHARE of the experts: 8 of 16 routed ones held
+    # here, from id 4.
+    "afmoe-tiny": dict(
+        model_type="afmoe",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=8,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        head_dim=32,
+        rope_theta=1e4,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=4096,
+        tie_word_embeddings=False,
+        global_attn_every_n_layers=4,
+        sliding_window_size=24,
+        num_dense_layers=2,
+        num_experts=8,
+        num_routed_experts=16,
+        first_expert_id=4,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        num_shared_experts=1,
+        score_func="sigmoid",
+        route_norm=True,
+        route_scale=2.826,
+        mup_enabled=True,
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

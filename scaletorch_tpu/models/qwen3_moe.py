@@ -64,7 +64,25 @@ class ExpertShare:
     ``num_routed_experts`` and ``first_expert_id`` says about a chip's
     share of an expert layer: the router is ``router_width`` wide, the
     experts held here are ``[first_expert_id, first_expert_id +
-    num_experts)`` of it (``num_routed_experts`` None: all of them)."""
+    num_experts)`` of it (``num_routed_experts`` None: all of them).
+
+    What ``dropless_block`` reads of the router and the shared expert
+    beside those fields has its defaults here, the softmax families'
+    (Qwen3-MoE, OLMoE, Qwen3-Next); a family that scores otherwise
+    (afmoe) states its own as fields:
+
+    ``score_func``: ``softmax`` over all routed experts, or ``sigmoid``
+    of each logit, where the top k are chosen by ``score + expert_bias``
+    (a float32 buffer of the layer) and weighted by the score WITHOUT
+    the bias. ``route_scale`` multiplies the kept weights (after
+    ``norm_topk_prob`` divides them by their sum). ``shared_expert_gated``:
+    whether the shared expert's output is multiplied by ``sigmoid(x
+    w_s)`` (Qwen3-MoE's, Qwen3-Next's: yes; afmoe's: no, it is added as
+    it is)."""
+
+    score_func = "softmax"
+    route_scale = 1.0
+    shared_expert_gated = True
 
     @property
     def router_width(self) -> int:
@@ -85,6 +103,10 @@ class ExpertShare:
             raise ValueError(
                 f"num_experts_per_tok {self.num_experts_per_tok} of "
                 f"{self.router_width} routed experts")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                "score_func must be 'softmax' or 'sigmoid', got "
+                f"{self.score_func!r}")
 
 
 @dataclass(frozen=True)
@@ -168,9 +190,11 @@ class Qwen3MoEConfig(ExpertShare, Qwen3Config):
             raise NotImplementedError(
                 "a share of the experts (num_routed_experts "
                 f"{self.num_routed_experts}) or a shared expert "
-                f"(width {self.shared_expert_intermediate_size}) under "
-                "capacity dispatch: both are written for dropless "
-                "routing only (qwen3_moe.dropless_block)")
+                f"(width {self.shared_expert_intermediate_size}; gated "
+                "by sigmoid(x w_s) in this family and in qwen3_next, "
+                "ungated in afmoe) under capacity dispatch: both are "
+                "written for dropless routing only "
+                "(qwen3_moe.dropless_mlp)")
         if not any(self.sparse_layout()):
             raise ValueError(
                 "no layer is sparse under mlp_only_layers="
@@ -281,16 +305,20 @@ class Qwen3MoEConfig(ExpertShare, Qwen3Config):
 
 
 def shared_expert_params(cfg) -> int:
-    """Parameters of one layer's shared expert and its gate."""
+    """Parameters of one layer's shared expert and, where the
+    configuration gates it, its gate."""
     width = cfg.shared_expert_intermediate_size
-    return (3 * width + 1) * cfg.hidden_size if width else 0
+    gate = 1 if cfg.shared_expert_gated else 0
+    return (3 * width + gate) * cfg.hidden_size if width else 0
 
 
 def init_moe_params(keys, cfg, lead: Tuple[int, ...]) -> Params:
     """The sparse MLP's own parameters, stacked under ``lead``: the
     router over ``cfg.router_width`` experts, the ``cfg.num_experts``
     held here (``keys[:4]``) and, where the configuration has one, the
-    shared expert with its gate (``keys[4:8]``)."""
+    shared expert (``keys[4:8]``; its gate only for a configuration
+    with ``shared_expert_gated``). A sigmoid-scored router's
+    ``expert_bias`` is the family's own to draw."""
     h, e, i = cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size
     pd = cfg.param_dtype
 
@@ -311,8 +339,9 @@ def init_moe_params(keys, cfg, lead: Tuple[int, ...]) -> Params:
         out.update(
             shared_gate_proj=w(keys[4], (h, width), h),
             shared_up_proj=w(keys[5], (h, width), h),
-            shared_down_proj=w(keys[6], (width, h), width),
-            shared_expert_gate=w(keys[7], (h, 1), h))
+            shared_down_proj=w(keys[6], (width, h), width))
+        if cfg.shared_expert_gated:
+            out["shared_expert_gate"] = w(keys[7], (h, 1), h)
     return out
 
 
@@ -346,13 +375,17 @@ def init_params(key: jax.Array, cfg: Qwen3MoEConfig) -> Params:
 
 
 def shared_expert(flat: jax.Array, layer: Params, cfg) -> jax.Array:
-    """The shared expert of flat [N, H]: one SwiGLU every token takes,
-    times ``sigmoid(x w_s)`` of the token (the gate's one column leaves
-    its matmul in float32)."""
+    """The shared expert of flat [N, H]: one SwiGLU every token takes.
+    Where the configuration gates it (``ExpertShare.shared_expert_gated``:
+    Qwen3-MoE with a shared expert, Qwen3-Next) times ``sigmoid(x w_s)``
+    of the token (the gate's one column leaves its matmul in float32);
+    ungated (afmoe) as it is."""
     cdt = cfg.dtype
     h = flat.astype(cdt)
     mid = swiglu(h @ layer["shared_gate_proj"].astype(cdt),
                  h @ layer["shared_up_proj"].astype(cdt))
+    if not cfg.shared_expert_gated:
+        return mid @ layer["shared_down_proj"].astype(cdt)
     gate = jax.nn.sigmoid(jnp.matmul(
         h, layer["shared_expert_gate"].astype(cdt),
         preferred_element_type=jnp.float32))
@@ -368,14 +401,33 @@ def dropless_block(
     row_mask: Optional[jax.Array],
     expert_stack: Optional[Tuple[Params, jax.Array]],
 ) -> Tuple[jax.Array, jax.Array, dict, dict]:
-    """``moe_block_with_load`` for ``cfg.dropless``, given the normed
-    hidden states ``h_full``: softmax over all routed experts in fp32,
-    the top k kept (renormalised only where the configuration says so),
-    every kept (token, choice) whose expert is held here computed
-    (``ExpertShare``: all of them unless the configuration holds a
-    share), the shared expert added where there is one. ``cfg`` is any
-    configuration with the MoE fields (the Qwen3-MoE family's, or
-    ``qwen3_next.Qwen3NextConfig``)."""
+    """``x + dropless_mlp(h_full, ...)``: the sparse MLP of a block that
+    adds it to the residual stream as it is (``moe_block_with_load`` for
+    ``cfg.dropless``)."""
+    y, aux_total, stats, routing = dropless_mlp(
+        h_full, layer, cfg, row_mask, expert_stack)
+    return x + y.astype(x.dtype), aux_total, stats, routing
+
+
+def dropless_mlp(
+    h_full: jax.Array,
+    layer: Params,
+    cfg,
+    row_mask: Optional[jax.Array],
+    expert_stack: Optional[Tuple[Params, jax.Array]],
+) -> Tuple[jax.Array, jax.Array, dict, dict]:
+    """The dropless sparse MLP of the normed hidden states ``h_full``
+    [B, S, H], without the residual: the router's scores over all routed
+    experts in fp32 (``cfg.score_func``: softmax, or sigmoid with a
+    bias that steers the choice and never the weight), the top k kept
+    (divided by their sum only where the configuration says so, times
+    ``cfg.route_scale``), every kept (token, choice) whose expert is
+    held here computed (``ExpertShare``: all of them unless the
+    configuration holds a share), the shared expert added where there
+    is one. ``cfg`` is any configuration with the MoE fields (the
+    Qwen3-MoE family's, ``qwen3_next.Qwen3NextConfig``,
+    ``afmoe.AfmoeConfig``). Returns (y [B, S, H] in the compute dtype,
+    the auxiliary loss, statistics, routing counts)."""
     from scaletorch_tpu.ops.grouped_matmul import dropless_expert_mlp
 
     b, s, hid = h_full.shape
@@ -386,10 +438,21 @@ def dropless_block(
     with jax.named_scope("moe.route"):
         logits = flat.astype(jnp.float32) @ layer["router"].astype(
             jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_w, gate_idx = jax.lax.top_k(probs, k)
-        if cfg.norm_topk_prob:
-            gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
+        if cfg.score_func == "sigmoid":
+            with jax.named_scope("moe.router"):
+                probs = jax.nn.sigmoid(logits)
+                _, gate_idx = jax.lax.top_k(
+                    probs + layer["expert_bias"].astype(jnp.float32), k)
+                gate_w = jnp.take_along_axis(probs, gate_idx, axis=-1)
+                if cfg.norm_topk_prob:
+                    gate_w = gate_w / (
+                        jnp.sum(gate_w, axis=-1, keepdims=True) + 1e-20)
+                gate_w = gate_w * cfg.route_scale
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate_w, gate_idx = jax.lax.top_k(probs, k)
+            if cfg.norm_topk_prob:
+                gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
         held = None
         if share:
             # ids from the first expert held here; the weights stay the
@@ -426,8 +489,7 @@ def dropless_block(
     # an assignment to an expert held elsewhere is not a dropped one
     routing = {"expert_rows": rows, "elsewhere": elsewhere,
                "dropped": wanted - jnp.sum(rows) - elsewhere}
-    return (x + y.reshape(b, s, hid).astype(x.dtype), aux_total, stats,
-            routing)
+    return y.reshape(b, s, hid), aux_total, stats, routing
 
 
 def routing_counts(routing: dict) -> dict:

@@ -17,6 +17,7 @@ import math
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from scaletorch_tpu.env import get_env
 from scaletorch_tpu.models.layers import sdpa_attention
@@ -56,6 +57,37 @@ def flash_attention(
         # the ring-attention composition path
         return pallas_flash_attention(q, k, v, causal=causal, scale=scale)
     return sdpa_attention(q, k, v, causal=causal, scale=scale)
+
+
+def prefill_self_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    window: Optional[int] = None,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """Causal attention of a prompt over itself, forward only: row i
+    sees keys j <= i, and with ``window`` only those with ``i - j <
+    window``. [B, Hq, S, D] x [B, Hkv, S, D]^2 -> [B, Hq, S, D]. What a
+    serving prefill of a sequence that starts at position 0 computes,
+    in key blocks (the flash forward: no [S, S] scores in HBM, and the
+    blocks a window cannot see are no grid step); off the TPU the plain
+    softmax with the band as a bias."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if _pallas_available():
+        from scaletorch_tpu.ops.pallas.flash import flash_forward_with_lse
+
+        return flash_forward_with_lse(
+            q, k, v, causal=True, scale=scale, window=window)[0]
+    bias = None
+    if window is not None:
+        rows = jnp.arange(q.shape[2])[:, None]
+        cols = jnp.arange(k.shape[2])[None, :]
+        bias = jnp.where(rows - cols >= window,
+                         jnp.finfo(jnp.float32).min, 0.0)
+    return sdpa_attention(q, k, v, causal=True, scale=scale, bias=bias)
 
 
 register_attention_backend("flash", flash_attention)

@@ -131,17 +131,26 @@ class CausalBlockPlan(NamedTuple):
 
 
 @functools.lru_cache(maxsize=None)
-def causal_block_plan(sq: int, skv: int, bq: int, bkv: int) -> CausalBlockPlan:
+def causal_block_plan(sq: int, skv: int, bq: int, bkv: int,
+                      window: Optional[int] = None) -> CausalBlockPlan:
     """The plan of a causal ``[sq, skv]`` score matrix (row >= column is
     visible) cut into ``bq x bkv`` blocks. Static by shape, so it is
     built on the host, once, and reaches the kernels as scalar-prefetch
     tables. Block ``(i, j)`` is live iff its first key column is at or
     before its last query row: 8192 / 8192 / 512 / 512 is 136 live of
-    256."""
+    256. With a ``window`` (a row sees the ``window`` columns that end
+    at its own: ``0 <= row - column < window``) a block is live only if
+    its last key column also reaches its first query row's window:
+    3072 / 3072 / 512 / 512 under a window of 2048 is 20 live of the
+    causal 21, so a window prunes little until the sequence is several
+    windows long."""
     nq, nkv = sq // bq, skv // bkv
 
     def live(i, j):
-        return j * bkv <= i * bq + bq - 1
+        seen = j * bkv <= i * bq + bq - 1
+        if window is not None:
+            seen = seen and j * bkv + bkv - 1 > i * bq - window
+        return seen
 
     def walk(accumulations):
         """[(outer, inner)] per accumulation -> the flagged tables."""
@@ -166,7 +175,7 @@ def causal_block_plan(sq: int, skv: int, bq: int, bkv: int) -> CausalBlockPlan:
 
     by_query = walk(
         [(i, j) for j in range(nkv) if live(i, j)]
-        for i in range(nq))  # key block 0 is live for every query block
+        for i in range(nq))  # the diagonal block is live for every query block
     by_key = walk(
         [(j, i) for i in range(nq) if live(i, j)] or [(j, 0)]
         for j in range(nkv))
@@ -209,7 +218,7 @@ def _blocks_of(causal, n_blocks):
     return blocks
 
 
-def _scores(q, k, i, j, *, scale, masked, bq, bkv):
+def _scores(q, k, i, j, *, scale, masked, bq, bkv, window=None):
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [bq, bkv]
@@ -218,7 +227,10 @@ def _scores(q, k, i, j, *, scale, masked, bq, bkv):
         # wholly under the diagonal, and measured free there
         row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
         col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-        s = jnp.where(row >= col, s, _NEG_INF)
+        seen = row >= col
+        if window is not None:
+            seen &= row - col < window
+        s = jnp.where(seen, s, _NEG_INF)
     return s
 
 
@@ -265,7 +277,7 @@ def _lanes(stat, width):
     return jnp.tile(stat, (1, -(-width // _LANES)))[:, :width]
 
 
-def _fwd_kernel(*refs, scale, causal, bq, bkv):
+def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None):
     (i, j), first, last, refs = _grid_step(causal, refs, 2)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
 
@@ -279,7 +291,8 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv):
     k = k_ref[0, 0]  # [bkv, D]
     v = v_ref[0, 0]
     d = q.shape[-1]
-    s = _scores(q, k, i, j, scale=scale, masked=causal, bq=bq, bkv=bkv)
+    s = _scores(q, k, i, j, scale=scale, masked=causal, bq=bq, bkv=bkv,
+                window=window)
     # m, l, corr: [bq, _LANES], every lane of a row the row's value
     m_prev, l_prev = m_sc[:], l_sc[:]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -299,11 +312,15 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv):
         lse_ref[0, 0] = (m_sc[:, 0] + jnp.log(l[:, 0]))[None, :]
 
 
-def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):
+def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None):
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     n_rep = hq // hkv
-    tables = causal_block_plan(sq, skv, bq, bkv).by_query if causal else ()
+    if window is not None and not causal:
+        raise ValueError("a window is a causal mask's: causal=False "
+                         f"with window={window}")
+    tables = (causal_block_plan(sq, skv, bq, bkv, window).by_query
+              if causal else ())
     blocks = _blocks_of(causal, 2)
 
     def q_rows(b_, h, *g):
@@ -313,7 +330,8 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret):
         return b_, h // n_rep, blocks(*g)[1], 0
 
     out, lse = _call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv),
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
+                          bkv=bkv, window=window),
         "flash_fwd", tables, (b, hq, sq // bq, skv // bkv), interpret,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_rows),
@@ -560,8 +578,13 @@ def flash_forward_with_lse(
     block_q: int | None = None,
     block_kv: int | None = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ):
-    """Raw kernel forward returning ``(out, lse)``.
+    """Raw kernel forward returning ``(out, lse)``; with ``window`` each
+    row sees the ``window`` keys that end at its own
+    (``causal_block_plan(window=)``: blocks wholly outside are no grid
+    step, the rest carry the band in their mask). A serving prefill's
+    entry: the backward kernels know no window.
 
     NOT differentiable — the caller owns the VJP (ring attention merges
     per-block (out, lse) partials across ``ppermute`` steps and drives the
@@ -577,7 +600,8 @@ def flash_forward_with_lse(
     block_q, block_kv = _resolve_blocks(block_q, block_kv)
     bq = _pick_block(q.shape[2], block_q)
     bkv = _pick_block(k.shape[2], block_kv)
-    return _flash_forward(q, k, v, causal, scale, bq, bkv, interpret)
+    return _flash_forward(q, k, v, causal, scale, bq, bkv, interpret,
+                          window)
 
 
 def flash_block_backward(

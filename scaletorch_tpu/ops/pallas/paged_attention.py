@@ -50,8 +50,8 @@ one of two pairs of a write and a read:
     view. This is the off-TPU path and the reference the kernels are
     compared against (tests in interpret mode, ``chip_smoke.py`` on
     the chip) — it performs the same reduction the contiguous
-    reference cache's attention performs. Prefill (S > 1) reads through the gather on
-    every platform.
+    reference cache's attention performs. A multi-row call (S > 1) that
+    reads the pool reads through the gather on every platform.
 
 ``paged_write`` and ``paged_attention`` dispatch between them on ONE
 predicate, ``in_place_pair``: the Mosaic pair when the platform is
@@ -353,7 +353,7 @@ def _pages_per_block(page_size: int, hkv: int, d: int, dtype,
 
 def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
                          o_ref, k_buf, v_buf, sems, *, scale, page_size,
-                         pages_per_block, max_pages):
+                         pages_per_block, max_pages, window=None):
     b = pl.program_id(0)   # slot
     layer = layer_ref[0]
     bk = pages_per_block * page_size
@@ -361,6 +361,11 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
     # live pages of this slot (0 for a negative position, never past the
     # table) and the blocks that hold them: a dead block costs nothing
     n_live = jnp.clip(pos // page_size + 1, 0, max_pages)
+    if window is not None:
+        # a window layer: the walk starts at the page that holds the
+        # window's first key, ``first`` logical pages into the table
+        first = jnp.maximum(pos - window + 1, 0) // page_size
+        n_live = jnp.maximum(n_live - first, 0)
     n_blocks = (n_live + pages_per_block - 1) // pages_per_block
 
     def block_copies(i, buf):
@@ -372,6 +377,8 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
         out = []
         for p in range(pages_per_block):
             j = jnp.minimum(i * pages_per_block + p, n_live - 1)
+            if window is not None:
+                j += first
             page = pt_ref[b * max_pages + j]
             rows = pl.ds(p * page_size, page_size)
             out.append(pltpu.make_async_copy(
@@ -409,8 +416,14 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
             preferred_element_type=jnp.float32,
         ) * scale  # [Hkv, n_rep, bk]
         # causal-over-the-cache mask at logical positions: key o of
-        # block i sits at absolute position i*bk + o
-        s = jnp.where(i * bk + key_in_block <= pos, s, _NEG_INF)
+        # block i sits at absolute position i*bk + o (past the window's
+        # first page, where there is a window)
+        if window is None:
+            seen = i * bk + key_in_block <= pos
+        else:
+            key = first * page_size + i * bk + key_in_block
+            seen = (key <= pos) & (pos - key < window)
+        s = jnp.where(seen, s, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
@@ -439,6 +452,7 @@ def pallas_paged_decode_attention(
     layer: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """One-token paged attention: q [B, Hq, D] against the page pool.
 
@@ -447,7 +461,12 @@ def pallas_paged_decode_attention(
     copies ``pool.at[layer, page]``, nothing slices a layer out), or one
     layer's [n_pages, Hkv, page_size, D]; page_tables: [B, max_pages]
     int32; positions: [B] int32 absolute position of the query token
-    (attends keys j <= position). Returns [B, Hq, D].
+    (attends keys j <= position; with ``window`` only those with
+    ``position - j < window``, and the walk starts at the logical page
+    of the window's first key: the pages before it cost no DMA, so a
+    table whose logical pages repeat a ring of ``ceil(window /
+    page_size) + 1`` physical ones serves a window layer).
+    Returns [B, Hq, D].
 
     The pools stay in HBM; the page table and positions are
     scalar-prefetched, and each slot's step copies its live pages, a
@@ -494,7 +513,7 @@ def pallas_paged_decode_attention(
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale,
                           page_size=page_size, pages_per_block=ppb,
-                          max_pages=max_pages),
+                          max_pages=max_pages, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -548,6 +567,7 @@ def paged_attention(
     scale: Optional[float] = None,
     kernel: Optional[bool] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention against the paged cache, kernel or fallback.
 
@@ -557,9 +577,14 @@ def paged_attention(
     Pallas kernel for single-token decode when ``in_place_pair`` (the
     platform is ``tpu`` and ``kernel_serves`` the head_dim), the lax
     gather + ``cached_sdpa_attention`` everywhere else — other
-    platforms, narrow heads and prefill. ``seq_limit`` crops the
-    gathered view to the engine's ``max_seq`` so the fallback's
-    reduction has the contiguous reference's operand shapes.
+    platforms, narrow heads and every multi-row call of a family that
+    reads its prompt's prefix out of the pool (a family that refuses
+    prefix sharing attends a prompt to itself and never comes here with
+    S > 1: ``ops/flash_attention.prefill_self_attention``).
+    ``seq_limit`` crops the gathered view to the engine's ``max_seq``
+    so the fallback's reduction has the contiguous reference's operand
+    shapes. ``window``: a window layer's mask and first page
+    (``pallas_paged_decode_attention``), by position in the fallback.
     """
     from scaletorch_tpu.models.layers import cached_sdpa_attention
 
@@ -571,11 +596,11 @@ def paged_attention(
         if s != 1:
             raise ValueError(
                 f"the paged-decode kernel serves single-token queries; "
-                f"got S={s} (prefill goes through the gather fallback)"
+                f"got S={s} (a multi-row call reads through the gather)"
             )
         out = pallas_paged_decode_attention(
             q[:, :, 0, :], pool_k, pool_v, page_tables, q_positions[:, 0],
-            layer=layer, scale=scale, interpret=interpret,
+            layer=layer, scale=scale, interpret=interpret, window=window,
         )
         return out[:, :, None, :]
     k = paged_gather_kv(pool_k, page_tables, layer)
@@ -583,4 +608,5 @@ def paged_attention(
     if seq_limit is not None and k.shape[2] > seq_limit:
         k = k[:, :, :seq_limit, :]
         v = v[:, :, :seq_limit, :]
-    return cached_sdpa_attention(q, k, v, q_positions, scale=scale)
+    return cached_sdpa_attention(q, k, v, q_positions, scale=scale,
+                                 window=window)

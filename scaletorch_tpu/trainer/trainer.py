@@ -45,10 +45,18 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
     # a chip's share of the expert layer (qwen3_moe.ExpertShare)
     moe_arch.update(num_routed_experts=cfg.num_routed_experts,
                     first_expert_id=cfg.first_expert_id)
-    if cfg.embed_init_std is not None and cfg.model_type != "qwen3_next":
+    if cfg.embed_init_std is not None and cfg.model_type not in (
+            "qwen3_next", "afmoe"):
         raise NotImplementedError(
             f"--embed_init_std with model_type {cfg.model_type!r}: only "
-            "qwen3_next's initialiser reads it (models/qwen3_next.py)")
+            "qwen3_next's and afmoe's initialisers read it "
+            "(models/qwen3_next.py, models/afmoe.py)")
+    if cfg.model_type == "afmoe" and cfg.model_name_or_path:
+        raise NotImplementedError(
+            "afmoe from --model_name_or_path: HF config auto-fill and "
+            "weight loading are not written for this family; give its "
+            "sizes by their config.json names (models/presets.py "
+            "trinity-mini)")
     if cfg.model_type == "qwen3_next" and cfg.model_name_or_path:
         raise NotImplementedError(
             "qwen3_next from --model_name_or_path: HF config auto-fill "
@@ -181,6 +189,40 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
                 "linear_num_key_heads", "linear_num_value_heads",
                 "linear_key_head_dim", "linear_value_head_dim",
                 "linear_conv_kernel_dim")}})
+    if cfg.model_type == "afmoe":
+        from scaletorch_tpu.models import afmoe
+
+        if cfg.mlp_only_layers or (cfg.decoder_sparse_step or 1) != 1:
+            raise NotImplementedError(
+                "afmoe with mlp_only_layers / decoder_sparse_step: its "
+                "dense layers are the leading num_dense_layers "
+                "(models/afmoe.py)")
+        if cfg.moe_dispatch != "auto" or cfg.moe_capacity_factor != 1.25:
+            raise NotImplementedError(
+                "afmoe under capacity dispatch (--moe_dispatch "
+                f"{cfg.moe_dispatch}, --moe_capacity_factor "
+                f"{cfg.moe_capacity_factor}): the family routes dropless "
+                "(qwen3_moe.dropless_mlp) and no capacity path is written "
+                "for a sigmoid router")
+        # the published config.json names (models/afmoe.py)
+        return afmoe.AfmoeConfig(**{
+            **common,
+            "num_routed_experts": cfg.num_routed_experts,
+            "first_expert_id": cfg.first_expert_id,
+            "layer_types": (None if cfg.layer_types is None
+                            else tuple(cfg.layer_types)),
+            "num_experts": cfg.num_experts,
+            "num_experts_per_tok": cfg.num_experts_per_tok,
+            "moe_intermediate_size": cfg.moe_intermediate_size
+            or common["intermediate_size"],
+            **({} if cfg.embed_init_std is None
+               else {"embed_init_std": cfg.embed_init_std}),
+            "sliding_window": cfg.sliding_window_size,
+            **{name: getattr(cfg, name) for name in (
+                "global_attn_every_n_layers",
+                "num_dense_layers", "num_shared_experts", "score_func",
+                "route_norm", "route_scale", "n_group", "topk_group",
+                "mup_enabled")}})
     if cfg.model_type == "qwen3":
         return qwen3.Qwen3Config(qk_norm=True, **common)
     if cfg.model_type == "llama":
@@ -269,6 +311,15 @@ class Trainer:
                 ": its state-carrying layers have no sharding rules (tp / "
                 "cp / pp / ep), no loss wiring and no HF weight loading; "
                 "the family is served (scripts/serve.py --preset ...)")
+        if cfg.model_type == "afmoe":
+            raise NotImplementedError(
+                "the trainer has no step for model_type 'afmoe': its "
+                "window layers and its sigmoid router have no sharding "
+                "rules (tp / cp / pp / ep), load_balance_coeff names a "
+                "loss and a bias update whose equations its config.json "
+                "does not give, and there is no HF weight loading; the "
+                "family is served (scripts/serve.py --preset "
+                "trinity-mini)")
         self.cfg = cfg
         self.logger = get_logger(log_file=cfg.log_file,
                                  log_format=cfg.log_format)

@@ -2,8 +2,9 @@
 
 No cache of any kind and none of the engine's code: every token is the
 argmax of the full-sequence training forward (``llama.forward`` /
-``qwen3_moe.forward`` / ``gpt_moe.forward`` / ``olmo_hybrid.forward``
-and ``qwen3_next.forward`` with the recurrence row after row) over the
+``qwen3_moe.forward`` / ``gpt_moe.forward`` / ``afmoe.forward`` /
+``olmo_hybrid.forward`` and ``qwen3_next.forward`` with the recurrence
+row after row) over the
 whole sequence so far. The engine's page pool, page tables, prefix sharing, cached
 forwards and sampling are all on the other side of the comparison.
 
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from scaletorch_tpu.models import (
+    afmoe,
     gpt_moe,
     llama,
     olmo_hybrid,
@@ -42,6 +44,8 @@ def plain_forward(cfg):
         # the delta rule as its definition, not the chunked form the
         # engine's prefill runs
         return functools.partial(olmo_hybrid.forward, sequential=True)
+    if isinstance(cfg, afmoe.AfmoeConfig):
+        return afmoe.forward
     if isinstance(cfg, qwen3_moe.Qwen3MoEConfig):   # OLMoE included
         return qwen3_moe.forward
     if isinstance(cfg, llama.LlamaConfig):          # Llama, Qwen3

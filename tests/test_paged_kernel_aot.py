@@ -453,6 +453,109 @@ def test_qwen3_next_steps_are_what_the_new_readers_look_for(one_chip):
     assert "InvertDiagBlocks" not in prefill.as_text()
 
 
+def test_decode_kernel_with_a_window_is_still_the_one_4d_call(one_chip):
+    """trinity-mini-serve's window layers: 32 query heads over 4 K/V
+    heads of 128, 8 slots, a table of 216 logical pages over a ring of
+    129, the rings' buffer ``[12, 1 + 8 x 129, ...]`` read at a layer
+    index. Told the window, the kernel is still one Mosaic call with the
+    one 4-D bf16 result ``serve_trinity_paged_attn_roofline`` tells it
+    by: the first page and the band are arithmetic on the
+    scalar-prefetched position."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rings = arg((12, 1 + 8 * 129, 4, 16, 128), jnp.bfloat16)
+    text = jax.jit(
+        lambda q, k, v, tables, pos, layer: pallas_paged_decode_attention(
+            q, k, v, tables, pos, layer=layer, window=2048)
+    ).lower(
+        arg((8, 32, 128), jnp.bfloat16), rings, rings,
+        arg((8, 216), jnp.int32), arg((8,), jnp.int32), arg((), jnp.int32),
+    ).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 1, calls
+    assert re.search(r"%paged_decode\S* = bf16\[8,4,8,128\]", calls[0]), calls
+
+
+def test_trinity_steps_are_what_the_new_readers_look_for(one_chip):
+    """Trinity-Mini's two step programs at the cell's shapes (8 slots x
+    3,072-row prompts, ``max_seq`` 3,456, 12 window + 4 full layers, a
+    128-wide router over 32 held experts). **Prefill attention runs in
+    key blocks**: no array of ``slots x heads x prefill_len x max_seq``
+    (or ``x prefill_len``) elements exists, of any type (the gather
+    path's ``f32[8,32,3072,3456]`` would be 10.9 GB); the attention is
+    the flash forward, 8 calls in the text (a period unrolled + the
+    scanned period's), told by the ``(bf16 4-D, f32)`` pair
+    ``serve_trinity_prefill_attn_roofline`` matches. The decode step:
+    8 ``paged_decode`` calls in the text with the one 4-D result (what
+    ``serve_trinity_paged_attn_roofline`` matches, and nothing else
+    does), 18 decode-shaped ``gmm`` calls ``bf16[128, 1024 | 2048]``
+    (``serve_trinity_expert_mlp_roofline``; the prefill's run 196,608
+    rows and are not matched). Neither program has an operation that
+    returns the page pool, the rings, a layer of either, or the expert
+    stack; both fit the chip: arguments + scratch under 11 GB."""
+    decode, prefill, pool_shape = _programs_of(one_chip, "trinity-mini-serve")
+    slots, heads, rows, max_seq = 8, 32, 3072, 3456
+    assert pool_shape == (4, slots * 216 + 1, 4, 16, 128)
+    ring_shape = (12, slots * 129 + 1, 4, 16, 128)
+    texts = {"decode": decode.as_text(), "prefill": prefill.as_text()}
+    for name, text in texts.items():
+        assert _pool_shaped(text, pool_shape) == {}, name
+        assert _pool_shaped(text, ring_shape) == {}, name
+        writes = _named(_mosaic_calls(text), "paged_write")
+        # K and V: three window layers and one full layer of the
+        # unrolled period, and of the scanned one
+        assert len(writes) == 2 * (3 + 1) * 2, (name, len(writes))
+        for stack in ("bf16[14,32,2048,1024]", "bf16[32,2048,1024]",
+                      "bf16[448,2048,1024]", "bf16[14,32,1024,2048]",
+                      "bf16[32,1024,2048]", "bf16[448,1024,2048]"):
+            moved = [x for x in _top_level(text, stack)
+                     if x[0] not in _PLUMBING | {"bitcast"}
+                     and "tpu_custom_call" not in x[1]]
+            assert not moved, (name, moved[:3])
+
+    sizes = {dims: math.prod(map(int, dims.split(",")))
+             for dims in set(_ARRAY.findall(texts["prefill"]))}
+    scores = {slots * heads * rows * max_seq, slots * heads * rows * rows}
+    assert not [d for d, n in sizes.items() if n in scores]
+    assert f"f32[{slots},50048]" in texts["prefill"]      # last_logits
+
+    patterns = {name: _reader_patterns(name) for name in (
+        "serve_trinity_paged_attn_roofline",
+        "serve_trinity_expert_mlp_roofline",
+        "serve_trinity_prefill_attn_roofline")}
+
+    def found(program, reader):
+        return [n for n in _short_names(texts[program])
+                if any(re.search(p, n) for p in patterns[reader])]
+
+    attn = found("decode", "serve_trinity_paged_attn_roofline")
+    assert len(attn) == 8 and all(
+        n.startswith("paged_decode") and n.endswith("bf16[8,4,8,128]")
+        for n in attn), attn
+    experts = found("decode", "serve_trinity_expert_mlp_roofline")
+    assert len(experts) == 18 and all(n.startswith("gmm") for n in experts)
+    assert sorted(n.rsplit(" | ", 1)[1] for n in experts) == (
+        ["bf16[128,1024]"] * 12 + ["bf16[128,2048]"] * 6)
+    flash = found("prefill", "serve_trinity_prefill_attn_roofline")
+    assert len(flash) == 8 and all(
+        n.startswith("flash_fwd")
+        and n.endswith("(bf16[8,32,3072,128], f32[8,32,1,3072])")
+        for n in flash), flash
+    assert not found("prefill", "serve_trinity_paged_attn_roofline")
+    assert not found("prefill", "serve_trinity_expert_mlp_roofline")
+    assert not found("decode", "serve_trinity_prefill_attn_roofline")
+
+    cache_bytes = 2 * 2 * (math.prod(pool_shape) + math.prod(ring_shape))
+    for name, program, scratch in (("decode", decode, 0.5e9),
+                                   ("prefill", prefill, 2.5e9)):
+        memory = program.memory_analysis()
+        assert memory.alias_size_in_bytes >= cache_bytes, name
+        assert memory.temp_size_in_bytes < scratch, name
+        assert (memory.argument_size_in_bytes
+                + memory.temp_size_in_bytes) < 11e9, name
+
+
 def test_narrow_heads_take_the_lax_pair_in_the_same_loop(one_chip):
     """head_dim 64: no kernel serves it, so the carried loop scatters
     and gathers. What a compile found (PERF.md, PR 28): no layer is
