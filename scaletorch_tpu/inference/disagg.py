@@ -348,6 +348,9 @@ class DisaggregatedEngine(InferenceEngine):
         decode_devs = devs[n_p:n_p + n_d]
 
         super().__init__(params, cfg, **kw)
+        # the prefill slice's own call (``_prefill_admit``) runs the one
+        # full shape, its rows in admission order
+        self.prefill_shapes = self.prefill_shapes[-1:]
 
         # two disjoint 1-D meshes; replicated placement per slice (the
         # CPU simulation shape — TP within a slice layers on via the
@@ -502,6 +505,11 @@ class DisaggregatedEngine(InferenceEngine):
             ttft_t=h.first_token_t, prefill_s=h.prefill_s, now=now)
         self._update_page_gauges()
 
+    def warm_prefill_shapes(self) -> None:
+        raise NotImplementedError(
+            "the prefill slice compiles its one shape at its first "
+            "admission")
+
     # ---- phase 1: prefill slice --------------------------------------
     def _prefill_admit(self) -> None:
         """Admit queued requests into the prefill slice by PREFILL-pool
@@ -553,6 +561,9 @@ class DisaggregatedEngine(InferenceEngine):
                 jnp.asarray(write_mask), jnp.asarray(tables),
                 self.prefill_cache, jnp.asarray(self._prefill_keys))
         self.metrics.prefill_calls += 1
+        self.metrics.prefill_positions_run += tokens.size
+        self.metrics.prefill_positions_admitted += sum(
+            len(req.prompt) for _, req, _ in admitted)
         with self._phase("engine.tick.prefill_wait"):
             first = np.asarray(first)
             finite = np.asarray(finite)

@@ -5,12 +5,19 @@ TPU execution discipline:
 
   * a FIXED number of slots (the decode batch) and a FIXED maximum
     sequence length — every device buffer keeps its shape for the whole
-    engine lifetime, so the two jitted steps (prefill / decode,
-    inference/decode.py) compile exactly once each;
+    engine lifetime, so the decode step (inference/decode.py) compiles
+    exactly once and the prefill step once a shape of
+    ``prefill_shapes``, a short list fixed at construction;
   * per-slot lengths and stop state live on the HOST; between decode
     steps the engine admits queued requests into freed slots by writing
-    their row of the prompt buffer and flipping their ``write_mask``
-    bit — data changes, shapes don't, nothing retraces;
+    a row of the prompt buffer each and flipping its ``write_mask``
+    bit — data changes, nothing retraces. Where the cache is addressed
+    by page the prefill call takes the shape of its admission: the
+    listed ``(rows, length)`` of fewest positions that holds the
+    admitted prompts' tails, so one short prompt does not pay for
+    ``max_slots x prefill_len`` positions (``warm_prefill_shapes`` runs
+    every listed shape once, before the first request: a server
+    compiles nothing under traffic);
   * the decode loop runs ONE STEP AHEAD of the host: with step n on the
     device a tick dispatches step n+1, fed n's sampled tokens as the
     device array they are, and only then reads n back and emits it —
@@ -86,6 +93,7 @@ from scaletorch_tpu.inference.decode import (
     make_fill_slots_step,
     make_paged_decode_step,
     make_paged_prefill_step,
+    prefill_shapes,
 )
 from scaletorch_tpu.inference.routing_counters import (
     CountedStep,
@@ -207,6 +215,11 @@ class EngineMetrics:
     requests_admitted: int = 0      # entered a slot (prefilled)
     tokens_generated: int = 0
     prefill_calls: int = 0
+    # rows x length of the shape each prefill call ran at, and of them
+    # the admitted prompts' tail tokens: run / admitted is what a call
+    # pads (1 is none)
+    prefill_positions_run: int = 0
+    prefill_positions_admitted: int = 0
     decode_steps: int = 0           # decode steps dispatched
     # ... of them, dispatched while the step before had not been read
     # back (the decode loop running one step ahead), and slot-steps
@@ -294,6 +307,8 @@ class EngineMetrics:
             "requests_completed": self.requests_completed,
             "tokens_generated": self.tokens_generated,
             "prefill_calls": self.prefill_calls,
+            "prefill_positions_run": self.prefill_positions_run,
+            "prefill_positions_admitted": self.prefill_positions_admitted,
             "decode_steps": self.decode_steps,
             "decode_steps_ahead": self.decode_steps_ahead,
             "decode_slot_steps_discarded": self.decode_slot_steps_discarded,
@@ -419,7 +434,9 @@ class InferenceEngine:
     max_slots : decode batch size B (fixed).
     max_seq : prompt + generation cap per slot (S_max).
     prefill_len : static prompt-buffer length P_max (default
-        ``max_seq``); prompts longer than this are rejected.
+        ``max_seq``); prompts longer than this are rejected. With
+        ``max_slots`` it is the largest of ``prefill_shapes``, the
+        ``(rows, length)`` a prefill call may take.
     sampling : engine-wide sampling knobs (static, baked into the
         compiled steps).
     page_size : tokens per page. The cache is a global pool of
@@ -655,6 +672,13 @@ class InferenceEngine:
         steps = dict(page_size=page_size, seq_limit=max_seq,
                      forward_fn=forward_fn, donate_cache=donate_cache,
                      routing_counts=counts)
+        # the (rows, length) a prefill call may take, fewest positions
+        # first. State, convolution tail and rings are indexed by the
+        # row itself: such a cache keeps the one call over every slot
+        # (a row subset there is a gather and scatter of state by slot)
+        self.prefill_shapes = (
+            ((max_slots, self.prefill_len),) if self._by_slot
+            else prefill_shapes(max_slots, self.prefill_len))
         self._prefill = make_paged_prefill_step(cfg, sampling, **steps)
         self._decode = make_paged_decode_step(cfg, sampling, **steps)
         if counts:
@@ -1361,10 +1385,49 @@ class InferenceEngine:
                 and self._queue[0].request_id != self._page_starved
                 and not all(s.active for s in self._slots))
 
+    def _call_prefill(self, tokens, tail_lens, starts, write_mask, tables,
+                      base_keys, *, counted: bool = True):
+        """One call of the prefill step on host-built operands
+        ``[rows, ...]``, the cache donated and taken back: the one place
+        an admission and ``warm_prefill_shapes`` call it from, so that
+        both reach the same compiled program of a shape (the default
+        device is part of a jitted call's signature). ``counted`` False:
+        an MoE model's routing counters are left as they were."""
+        step = self._prefill if counted else getattr(
+            self._prefill, "uncounted", self._prefill)
+        with self.on_device():
+            first, _logits, finite, self.cache = step(
+                self.params, jnp.asarray(tokens), jnp.asarray(tail_lens),
+                jnp.asarray(starts), jnp.asarray(write_mask),
+                jnp.asarray(tables), self.cache, jnp.asarray(base_keys))
+        return first, finite
+
+    def warm_prefill_shapes(self) -> None:
+        """Run the prefill step once at every shape of
+        ``prefill_shapes`` with no row admitted (every row masked, its
+        table TRASH: nothing but the TRASH page is written, no counter
+        moves, the donated cache comes back), so that each program
+        exists before the first request: ``prefill_compile_count`` is
+        ``len(prefill_shapes)`` from here on, whatever is admitted. For
+        an idle engine; ``scripts/serve.py`` calls it as it builds one.
+        An engine that is not warmed compiles a shape at the first
+        admission that takes it. Largest first and nothing waited for:
+        the device runs the full shape while the host traces the next."""
+        for rows, length in reversed(self.prefill_shapes):
+            self._call_prefill(
+                np.zeros((rows, length), np.int32), np.ones(rows, np.int32),
+                np.zeros(rows, np.int32), np.zeros(rows, bool),
+                np.full((rows, self._pages_per_slot), TRASH_PAGE, np.int32),
+                np.zeros((rows, 2), np.uint32), counted=False)
+
     def _admit(self) -> None:
         """Move queued requests into free slots while the page pool can
         cover them, and prefill them — ONE batched prefill call
-        regardless of how many were admitted. A slot whose prefill
+        regardless of how many were admitted, at the shape of
+        ``prefill_shapes`` with the fewest positions that holds them. A
+        row of the call is an admitted slot, in admission order (every
+        slot in turn where the cache is by slot); rows past them are
+        padding: masked, one token, a TRASH table. A slot whose prefill
         logits are non-finite (poison prompt) is quarantined
         immediately; the other admitted slots proceed."""
         with self._phase("engine.tick.admit"):
@@ -1372,11 +1435,8 @@ class InferenceEngine:
                 return
             free = [i for i, s in enumerate(self._slots) if not s.active]
             self._release_tokens()
-            admitted: List[int] = []
-            tokens = np.zeros((self.max_slots, self.prefill_len), np.int32)
-            tail_lens = np.ones(self.max_slots, np.int32)
-            starts = np.zeros(self.max_slots, np.int32)
-            write_mask = np.zeros(self.max_slots, bool)
+            # (slot, the prompt's tail to prefill, tokens shared before it)
+            taken: List[Tuple[int, Sequence[int], int]] = []
             for i in free:
                 if not self._queue:
                     break
@@ -1393,30 +1453,49 @@ class InferenceEngine:
                 self._tables[i, :] = TRASH_PAGE
                 self._tables[i, : len(pages)] = pages
                 self._tables_dev = None
-                tail = req.prompt[shared:]
-                tokens[i, : len(tail)] = tail
-                tail_lens[i] = len(tail)
-                starts[i] = shared
-                write_mask[i] = True
+                taken.append((i, req.prompt[shared:], shared))
                 if shared:
                     self.metrics.prefix_hits += 1
                     self.metrics.prefill_tokens_saved += shared
                     self._slots[i].prefix_hit = True
-                admitted.append(i)
-            if not admitted:
+            if not taken:
                 return
+            admitted = [i for i, _, _ in taken]
+            row_slots = (list(range(self.max_slots)) if self._by_slot
+                         else admitted)
+            row_of = {slot: row for row, slot in enumerate(row_slots)}
+            tails = [len(tail) for _, tail, _ in taken]
+            rows, length = next(
+                shape for shape in self.prefill_shapes
+                if shape[0] >= len(row_slots) and shape[1] >= max(tails))
+            tokens = np.zeros((rows, length), np.int32)
+            tail_lens = np.ones(rows, np.int32)
+            starts = np.zeros(rows, np.int32)
+            write_mask = np.zeros(rows, bool)
+            tables = np.full(
+                (rows, self._pages_per_slot), TRASH_PAGE, np.int32)
+            tables[: len(row_slots)] = self._tables[row_slots]
+            # a slot's sampling key stays its own: a request's tokens do
+            # not depend on the shape that admitted it
+            base_keys = np.zeros((rows, 2), np.uint32)
+            base_keys[: len(row_slots)] = self._base_keys[row_slots]
+            for i, tail, shared in taken:
+                row = row_of[i]
+                tokens[row, : len(tail)] = tail
+                tail_lens[row] = len(tail)
+                starts[row] = shared
+                write_mask[row] = True
         t0 = time.monotonic()
         for i in admitted:
             self._req_event("b", self._slots[i].request, "req.prefill",
-                            prefix_hit=self._slots[i].prefix_hit)
+                            prefix_hit=self._slots[i].prefix_hit,
+                            rows=rows, length=length)
         with self._phase("engine.tick.prefill"):
-            first, _logits, finite, self.cache = self._prefill(
-                self.params, jnp.asarray(tokens), jnp.asarray(tail_lens),
-                jnp.asarray(starts), jnp.asarray(write_mask),
-                self._tables_device(), self.cache,
-                self._base_keys_device(),
-            )
+            first, finite = self._call_prefill(
+                tokens, tail_lens, starts, write_mask, tables, base_keys)
         self.metrics.prefill_calls += 1
+        self.metrics.prefill_positions_run += rows * length
+        self.metrics.prefill_positions_admitted += sum(tails)
         if self._by_slot:
             # the call started every admitted slot's state from zero
             # (or its rings from the prompt)
@@ -1434,13 +1513,13 @@ class InferenceEngine:
         with self._phase("engine.tick.emit"):
             now = time.monotonic()
             self._note_prefill(admitted, now - t0)
-            poisoned = [i for i in admitted if not finite[i]]
+            poisoned = [i for i in admitted if not finite[row_of[i]]]
             if poisoned:
                 # skip radix registration for poison prompts — their
                 # pages hold non-finite K/V and must never be shared
                 self._quarantine(poisoned, now, where="prefill")
             for i in admitted:
-                if not finite[i]:
+                if not finite[row_of[i]]:
                     continue
                 if self.radix is not None:
                     slot = self._slots[i]
@@ -1456,10 +1535,9 @@ class InferenceEngine:
                         # from here on — exempt from quarantine clears
                         # and shareable by later admissions
                         self._slot_frozen[i] = n
-                self._emit(i, int(first[i]), now)
+                self._emit(i, int(first[row_of[i]]), now)
             self._update_page_gauges()
             self.metrics.queue_depth = len(self._queue)
-            del _logits  # freed inside the phase, as in step()
 
     def _release_tokens(self, request_id: Optional[int] = None) -> None:
         """Hand the tokens emitted since the last release to

@@ -347,7 +347,15 @@ def paged_kv_cache_specs(
 def paged_kv_cache_shardings(
     mesh, *, tp_axis: Optional[str] = "tp"
 ) -> PagedKVCache:
-    """NamedShardings over ``mesh`` for the page pools."""
+    """NamedShardings over ``mesh`` for the page pools. An axis of one
+    device shards nothing, and what a jitted step returns says so
+    (``PartitionSpec()``): the pool is placed as the steps return it,
+    or a step's first call after the constructor is a call signature
+    of its own (one more entry of ``prefill_compile_count`` for the
+    first shape called, and that shape traced again at its next call)."""
+    if tp_axis is not None and mesh.shape[tp_axis] == 1:
+        whole = NamedSharding(mesh, P())
+        return PagedKVCache(k=whole, v=whole)
     specs = paged_kv_cache_specs(tp_axis=tp_axis)
     return PagedKVCache(
         k=NamedSharding(mesh, specs.k), v=NamedSharding(mesh, specs.v)

@@ -145,5 +145,11 @@ class CountedStep:
     def __call__(self, *args):
         return self._counters.run(self._step, args)
 
+    def uncounted(self, *args):
+        """The same call with its counts thrown away (the accumulator
+        goes in and is not taken back): a warm-up's rows are no tokens,
+        and the expert block reckons one row where a call has none."""
+        return self._step(*args, self._counters.accumulator)[:-1]
+
     def __getattr__(self, item):
         return getattr(self._step, item)

@@ -204,7 +204,13 @@ def build_engine(args, cfg, params, tracer=None, device=None):
         params = jax.device_put(
             params, NamedSharding(mesh, PartitionSpec()))
         kw["mesh"] = mesh
-    return InferenceEngine(params, cfg, **kw)
+    engine = InferenceEngine(params, cfg, **kw)
+    if len(engine.prefill_shapes) > 1:
+        # every prefill program before the first request: none compiles
+        # under traffic. An engine with the one full shape compiles it at
+        # its first call, as it did before there was a list
+        engine.warm_prefill_shapes()
+    return engine
 
 
 def make_replica_spawner(args):

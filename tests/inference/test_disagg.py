@@ -116,7 +116,7 @@ class TestDisaggParity:
             assert d.tokens == c.tokens
             assert d.finish_reason == c.finish_reason
             assert d.outcome == "ok"
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         assert eng.decode_compile_count == 1
         assert eng.metrics.handoffs > 0
         assert_conserved_both(eng)
@@ -163,7 +163,7 @@ class TestDisaggParity:
         assert dis[0].tokens == []
         assert "prefill" in dis[0].detail
         assert dis[1].outcome == "ok"
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         assert eng.decode_compile_count == 1
         assert_conserved_both(eng)
 
@@ -253,7 +253,7 @@ class TestMidHandoffDeath:
         assert eng.metrics.handoff_failures == 1
         assert channel.failures == 1
         assert eng.metrics.handoffs == 1
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         assert eng.decode_compile_count == 1
         assert_conserved_both(eng)
 
@@ -351,7 +351,7 @@ class TestRandomizedConservation:
         assert all(i in results for i in ids)
         assert sum(eng.metrics.outcomes.values()) == len(ids)
         assert set(eng.metrics.outcomes) <= OUTCOMES
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         assert eng.decode_compile_count <= 1
         assert_conserved_both(eng)
 

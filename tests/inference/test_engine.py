@@ -117,7 +117,7 @@ class TestContinuousBatching:
                for p, n in zip(prompts, lens)]
         results = eng.run()
         assert eng.decode_compile_count == 1
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         assert eng.metrics.prefill_calls >= 2  # admissions happened mid-run
         for rid, prompt, n in zip(ids, prompts, lens):
             assert results[rid].tokens == greedy_by_forward(

@@ -72,7 +72,7 @@ def assert_conserved(eng):
     assert not any(s.active for s in eng._slots)
     assert eng.pending == 0
     assert eng.decode_compile_count <= 1
-    assert eng.prefill_compile_count <= 1
+    assert eng.prefill_compile_count <= len(eng.prefill_shapes)
 
 
 class TestOutcomeTaxonomy:
@@ -197,7 +197,7 @@ class TestQuarantine:
         assert faulty[b1].outcome == "ok"
         assert faulty[b1].tokens == clean[b0].tokens
         assert eng.decode_compile_count == 1
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         assert_conserved(eng)
 
     def test_slot_reuse_after_quarantine_is_clean(self, tiny_llama):

@@ -95,7 +95,7 @@ def test_the_check_counted_live_rows_only(checked):
         8, 3 * TOP_K)
     assert snap["moe_peak_load_rows"] >= prefill / 8
     assert engine.decode_compile_count == 1
-    assert engine.prefill_compile_count == 1
+    assert 1 <= engine.prefill_compile_count <= len(engine.prefill_shapes)
 
 
 @pytest.mark.parametrize("with_mesh", [False, True])
@@ -130,9 +130,9 @@ def test_a_short_engine_run_counts_routed_rows_and_drops_none(with_mesh):
         snap["moe_expert_visits"] / (snap["decode_steps"] * LAYERS))
     assert 1.0 <= snap["moe_peak_over_mean_load"] <= 8.0
     assert engine.decode_compile_count == 1
-    # (under a mesh the prefill step's cache holds a second entry for
-    # the pool as the engine first placed it, for a dense model alike)
-    assert engine.prefill_compile_count == 1 + with_mesh
+    # one entry a shape called, under a one-device mesh too: the pool
+    # is placed as the steps return it (``paged_kv_cache_shardings``)
+    assert 1 <= engine.prefill_compile_count <= len(engine.prefill_shapes)
     # a second snapshot reads the same totals again
     assert engine.metrics.snapshot()["moe_routed_assignments"] == \
         snap["moe_routed_assignments"]

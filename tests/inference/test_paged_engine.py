@@ -133,7 +133,7 @@ def test_engine_tokens_equal_the_plain_forwards(name, scenario):
         assert eng.metrics.prefill_calls >= admissions
     assert_oracle(params, cfg, schedule, results)
     assert eng.decode_compile_count == 1
-    assert eng.prefill_compile_count == 1
+    assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
     assert_pages_conserved(eng)
 
 
@@ -162,7 +162,7 @@ class TestEngineMatchesPlainForward:
         ep, results = serve(params, cfg, page_size=page_size)
         assert_oracle(params, cfg, SCHEDULE, results)
         assert ep.decode_compile_count == 1
-        assert ep.prefill_compile_count == 1
+        assert 1 <= ep.prefill_compile_count <= len(ep.prefill_shapes)
         assert_pages_conserved(ep)
 
     def test_llama_gqa(self, tiny_llama):
@@ -202,7 +202,7 @@ class TestEngineMatchesPlainForward:
         assert paged[1].outcome == "ok"
         assert paged[1].tokens == clean[1].tokens  # neighbour unaffected
         assert ep.decode_compile_count == 1
-        assert ep.prefill_compile_count == 1
+        assert 1 <= ep.prefill_compile_count <= len(ep.prefill_shapes)
         assert_pages_conserved(ep)
 
     def test_slot_reuse_after_quarantine_is_clean(self, tiny_llama):
@@ -255,7 +255,7 @@ class TestPrefixSharing:
         assert results[r2].tokens == greedy_by_forward(
             params, cfg, self.SYS + [2], 4)
         assert eng.decode_compile_count == 1
-        assert eng.prefill_compile_count == 1
+        assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
         snap = eng.metrics.snapshot()
         assert snap["prefix_hit_rate"] == 0.5  # 1 hit / 2 admissions
         assert snap["prefill_tokens_saved"] == len(self.SYS)

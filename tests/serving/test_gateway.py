@@ -108,7 +108,7 @@ class TestStreamingParity:
                 assert streamed == dones[0]["token_ids"]
                 assert streamed == ref_tokens(tiny_llama, prompt, 6)
             assert engine.decode_compile_count == 1
-            assert engine.prefill_compile_count == 1
+            assert 1 <= engine.prefill_compile_count <= len(engine.prefill_shapes)
         finally:
             gw.stop_sync()
         gw.metrics.check_conservation()

@@ -105,7 +105,7 @@ class TestDisaggGateway:
                 streamed = stream_tokens(events)
                 assert streamed == dones[0]["token_ids"]
                 assert streamed == ref_tokens(tiny_llama, prompt, 6)
-            assert engine.prefill_compile_count == 1
+            assert 1 <= engine.prefill_compile_count <= len(engine.prefill_shapes)
             assert engine.decode_compile_count == 1
 
             _, raw = get(gw.port, "/healthz")

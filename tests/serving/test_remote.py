@@ -372,7 +372,7 @@ class TestEngineParity:
                 assert res.tokens == expect[tuple(prompt)], prompt
                 assert out["tokens"] == expect[tuple(prompt)], prompt
             assert engine.decode_compile_count == 1
-            assert engine.prefill_compile_count == 1
+            assert 1 <= engine.prefill_compile_count <= len(engine.prefill_shapes)
             # the wire surfaces the compile count for CI to assert on
             metrics = remote._get_json("/metrics")
             assert metrics["decode_compile_count"] == 1

@@ -155,7 +155,8 @@ class TestExitCodeContract:
         assert st["restarts_total"] == 1
         assert st["last_exit_code"] == code
         assert st["pid"] != first_pid
-        assert restarts == ["r0"]
+        # the monitor thread fires on_restart AFTER it has set "up"
+        wait_for(lambda: restarts == ["r0"], msg="on_restart")
         sup.stop(drain=False)
 
     def test_backoff_escalates_and_caps(self):

@@ -169,9 +169,12 @@ def _page_write_aliases_the_pool(one_chip, layers, slots, hkv, rows, page,
 # the whole step programs
 # ---------------------------------------------------------------------------
 def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
-                   page_size):
+                   page_size, prefill_rows=None):
     """(decode, prefill) compiled from the engine's own step builders,
-    donated, on abstract arguments; and the pool's shape."""
+    donated, on abstract arguments; and the pool's shape. With
+    ``prefill_rows`` the prefill step alone, at ``[prefill_rows,
+    prefill_len]``: a row is a slot only through its page table and its
+    key, so the step takes any number of them."""
     from scaletorch_tpu.inference.decode import (
         counts_routing,
         make_paged_decode_step,
@@ -197,15 +200,22 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
     build = dict(page_size=page_size, seq_limit=max_seq, donate_cache=True,
                  routing_counts=counted)
     sampling = SamplingParams(temperature=0.0)
-    ints, tables = arg((slots,), jnp.int32), arg((slots, max_pages), jnp.int32)
-    tail = (pool, arg((slots, 2), jnp.uint32)) + (
-        (arg((len(ROUTING_COUNTERS),), jnp.uint32),) if counted else ())
-    decode = make_paged_decode_step(cfg, sampling, **build).lower(
-        params, ints, ints, arg((slots,), jnp.bool_), tables, *tail)
+
+    def operands(rows, *lead):
+        ints = arg((rows,), jnp.int32)
+        return (params, *lead, ints, ints, arg((rows,), jnp.bool_),
+                arg((rows, max_pages), jnp.int32), pool,
+                arg((rows, 2), jnp.uint32)) + (
+            (arg((len(ROUTING_COUNTERS),), jnp.uint32),) if counted else ())
+
+    rows = prefill_rows or slots
     prefill = make_paged_prefill_step(cfg, sampling, **build).lower(
-        params, arg((slots, prefill_len), jnp.int32), ints, ints,
-        arg((slots,), jnp.bool_), tables, *tail)
-    return decode.compile(), prefill.compile(), pool.k.shape
+        *operands(rows, arg((rows, prefill_len), jnp.int32))).compile()
+    if prefill_rows is not None:
+        return None, prefill, pool.k.shape
+    decode = make_paged_decode_step(cfg, sampling, **build).lower(
+        *operands(slots))
+    return decode.compile(), prefill, pool.k.shape
 
 
 _INSTRUCTION = re.compile(
@@ -231,7 +241,10 @@ def _pool_shaped(text, pool_shape):
     return found
 
 
-def _programs_of(one_chip, name):
+def _programs_of(one_chip, name, prefill_shape=None):
+    """The configuration's two step programs at its serve shapes; with
+    ``prefill_shape`` its prefill program at that ``(rows, length)``
+    alone."""
     from benchmarks.lib.program import serving_model
 
     with open(os.path.join(REPO, "benchmarks", "configs",
@@ -239,10 +252,27 @@ def _programs_of(one_chip, name):
         config = json.load(f)
     serve = config["serve"]
     cfg, init = serving_model(config, serve["dtype"])
+    rows, length = prefill_shape or (None, serve["prefill_len"])
     return _serving_steps(
         one_chip, cfg, init, slots=serve["max_slots"],
-        max_seq=serve["max_seq"], prefill_len=serve["prefill_len"],
-        page_size=serve["page_size"])
+        max_seq=serve["max_seq"], prefill_len=length,
+        page_size=serve["page_size"], prefill_rows=rows)
+
+
+@pytest.fixture(scope="module")
+def serving_cfgs():
+    """{configuration name: the model config the program builds for it}
+    of the four whose step programs ``serving_programs`` compiles."""
+    from benchmarks.lib.program import serving_model
+
+    cfgs = {}
+    for name in ("qwen3-1.7b-serve", "olmoe-1b-7b-serve",
+                 "olmo-hybrid-7b-serve", "qwen3-next-80b-a3b-serve"):
+        with open(os.path.join(REPO, "benchmarks", "configs",
+                               name + ".json")) as f:
+            config = json.load(f)
+        cfgs[name] = serving_model(config, config["serve"]["dtype"])[0]
+    return cfgs
 
 
 @pytest.fixture(scope="module", params=["qwen3-1.7b-serve",
@@ -330,6 +360,47 @@ def test_prefill_program_runs_the_head_on_the_sampled_from_rows_only(
     assert temp < before * room, (
         f"{name}: prefill scratch {temp:,} B, not under the {before:,} B "
         f"(x {room}) of the program that multiplied every row by the head")
+
+
+def test_the_listed_prefill_shapes_compile_at_their_own_size(
+        request, one_chip, serving_programs):
+    """The largest shape of an engine's list is the fixture's program.
+    Where the cache is addressed by page (Qwen3-1.7B, OLMoE) the list
+    starts with one row of half the buffer: compiled for the v5e at
+    published widths its buffer is the call's ``[1, 512]``, no operand
+    has the full buffer's shape, no array of ``16 x 1024 x vocab``
+    elements (or of ``512 x vocab``: one row is sampled from) exists,
+    and its scratch is under a sixteenth of the full program's, which
+    holds 16 x 1024 rows of every layer's activations and scores. A
+    cache by slot lists the one full shape."""
+    from scaletorch_tpu.inference.decode import prefill_shapes
+    from scaletorch_tpu.inference.kv_cache import carries_state
+
+    name = request.node.callspec.params["serving_programs"]
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    serve, vocab = config["serve"], config["vocab_size"]
+    slots, length = serve["max_slots"], serve["prefill_len"]
+    *shorter, top = prefill_shapes(slots, length)
+    assert top == (slots, length) and len(shorter) <= 6
+    _, full, _ = serving_programs
+    if carries_state(request.getfixturevalue("serving_cfgs")[name]):
+        return       # by slot: the engine lists ``top`` alone
+    assert shorter == [(1, 512)]
+    for rows, rung in shorter:
+        _, program, _ = _programs_of(one_chip, name, (rows, rung))
+        text = program.as_text()
+        assert f"s32[{rows},{rung}]" in text, (name, rows, rung)
+        assert f"s32[{slots},{length}]" not in text, (name, rows, rung)
+        sizes = {math.prod(map(int, dims.split(",")))
+                 for dims in set(_ARRAY.findall(text))}
+        assert slots * length * vocab not in sizes
+        assert rows * rung * vocab not in sizes
+        assert f"f32[{rows},{vocab}]" in text       # last_logits
+        temp = program.memory_analysis().temp_size_in_bytes
+        full_temp = full.memory_analysis().temp_size_in_bytes
+        assert temp < full_temp // 16, (name, rows, rung, temp, full_temp)
 
 
 def _top_level(text, wanted):
