@@ -223,6 +223,30 @@ def samples_beyond(n: int, q: float) -> int:
     return n - int(max(1, -(-n * q // 100)))
 
 
+# the rule of benchmarks/README.md, "Which percentile": a held
+# percentile is clear of an edge by this factor, with this many samples
+# beyond it
+CLEAR_BY = 2.5
+MIN_BEYOND = 10
+
+
+def percentile_clearance(n: int, q: float, long_share_pct: float,
+                         full_share_pct: float = 0.0) -> Dict[str, bool]:
+    """Whether the q-th percentile of ``n`` gaps reads the long
+    population and nothing else: the share of long gaps is ``CLEAR_BY``
+    times the tail beyond the percentile or more (``long_edge``), the
+    share of the gaps of the next population up, which the percentile
+    is NOT to read, is the tail over ``CLEAR_BY`` or less
+    (``full_edge``), and at least ``MIN_BEYOND`` samples lie beyond it
+    (``samples``); ``clear`` is all three."""
+    tail_pct = 100.0 - q
+    out = {"long_edge": long_share_pct >= CLEAR_BY * tail_pct,
+           "full_edge": full_share_pct * CLEAR_BY <= tail_pct,
+           "samples": samples_beyond(n, q) >= MIN_BEYOND}
+    out["clear"] = all(out.values())
+    return out
+
+
 def top_operations(events: Sequence[Event], window: Interval,
                    limit: int = 10) -> List[List[Any]]:
     """[name, seconds] of the device operations that took most time,

@@ -128,7 +128,8 @@ def first_step_gain_gradients(trainer, args, grad_norm: float,
 
 def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     """One run of a train cell. ``ctx``: spec pieces, seed, seconds,
-    trace flag, process start time, compile counter, log function."""
+    trace flag, the stamp ``setup_s`` counts from, compile counter, log
+    function."""
     import jax
 
     from benchmarks.reference import check as check_lib
@@ -192,7 +193,7 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
         seconds = (float(workload.get("trace_seconds", ctx["seconds"]))
                    if tracing.enabled else ctx["seconds"])
         compiles_before = ctx["compiles"].snapshot()["backend_compiles"]
-        setup_s = time.monotonic() - ctx["process_start"]
+        setup_s = time.monotonic() - ctx["setup_start"]
         tracing.start()
         window_start = time.monotonic()
         steps, seen, pending = 0, [], None

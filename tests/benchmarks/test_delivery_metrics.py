@@ -14,6 +14,7 @@ import pytest
 
 from benchmarks.lib import reducers
 from benchmarks.lib.spec import Spec
+from tests.benchmarks.helpers import chat_held_metric
 from tests.benchmarks.toy import REPO
 
 LONGGEN = ["serve-1.7b-longgen", "serve-olmoe-longgen",
@@ -70,25 +71,25 @@ def test_a_delivery_metric_reads_the_median_of_its_field(name):
 @pytest.mark.parametrize("name", sorted(DELIVERY))
 def test_a_delivery_metric_is_listed_with_the_longgen_cells(name):
     index, entry = index_entry(name)
+    # on the list, wherever: a later cell is appended after them
+    assert set(LONGGEN) <= set(entry.pop("workloads"))
     assert entry == {
         "name": name, "unit": "ms", "better": "lower",
         "source": "program_counter", "layer": DELIVERY[name][0],
-        "moves": "serve_itl_p95_ms", "workloads": LONGGEN}
+        "moves": "serve_itl_p95_ms"}
     # every cell it lists reports the end-to-end metric it moves
     (moved,) = [m for m in index["end_to_end"]
                 if m["name"] == entry["moves"]]
-    assert set(entry["workloads"]) <= set(moved["workloads"])
+    assert set(index_entry(name)[1]["workloads"]) <= set(
+        moved["workloads"])
 
 
-def test_the_new_entries_stand_together_after_what_was_there():
-    """Appended as one block (a later PR appends after it: PR 41's
-    long-gap share did)."""
+def test_the_new_entries_are_all_there_once():
+    """Where in the list they stand is nobody's business: a later PR
+    appends, a ``benchmark`` PR may sort."""
     index, _ = index_entry(SLOW_TICKS)
     names = [m["name"] for m in index["per_layer"]]
-    block = set(DELIVERY) | {SLOW_TICKS}
-    first = min(names.index(n) for n in block)
-    assert set(names[first:first + 6]) == block
-    assert first == 31  # behind the 31 entries of PR 35
+    assert set(DELIVERY) | {SLOW_TICKS} <= set(names)
     assert len(names) == len(set(names))
     layers = {m["layer"] for m in index["per_layer"]}
     assert "gateway" in layers and "engine worker" in layers
@@ -96,18 +97,21 @@ def test_the_new_entries_stand_together_after_what_was_there():
 
 def test_the_stall_counter_is_named_in_every_serving_cell():
     index, entry = index_entry(SLOW_TICKS)
+    assert set(LONGGEN) <= set(entry.pop("workloads"))
     assert entry == {
         "name": SLOW_TICKS, "unit": "ticks", "better": "lower",
         "source": "program_counter", "layer": "engine worker",
-        "moves": "serve_itl_p99_ms", "workloads": LONGGEN}
+        "moves": "serve_itl_p99_ms"}
     (moved,) = [m for m in index["end_to_end"]
                 if m["name"] == "serve_itl_p99_ms"]
     assert set(LONGGEN) <= set(moved["workloads"])
-    # the chat cell is held to the 99.5th percentile since PR 41: the
-    # same counter under a name of its own, which moves that metric
+    # the chat cell has had a percentile of its own since PR 41: the
+    # same counter under a name of its own, which moves the metric
+    # that cell is held to
     _, twin = index_entry(SLOW_TICKS + ".chat")
     assert twin == dict(entry, name=SLOW_TICKS + ".chat",
-                        moves="serve_itl_p995_ms", workloads=SERVE[:1])
+                        moves=chat_held_metric(Spec(REPO))["name"],
+                        workloads=SERVE[:1])
     (chat_reader,) = [m for m in Spec(REPO).per_layer(SERVE[0])
                       if m["name"] == twin["name"]]
     assert chat_reader["reducer"] == reader(SLOW_TICKS)["reducer"]

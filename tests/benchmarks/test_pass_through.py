@@ -32,10 +32,12 @@ TOY_MOE = dict(TOY_MODEL, model_type="qwen3_moe", num_experts=8,
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_the_same_twelve_keys_reach_the_programs_arguments(name):
+    """Membership, not equality: a key that a later configuration
+    brings and the program declares goes through beside the twelve."""
     config = SPEC.config(name)
     passed = program.model_arguments(config)
-    assert set(passed) == THE_TWELVE
-    assert all(passed[k] == config[k] for k in THE_TWELVE)
+    assert THE_TWELVE <= set(passed)
+    assert all(passed[k] == config[k] for k in passed)
 
 
 @pytest.mark.parametrize("name", CONFIGS)

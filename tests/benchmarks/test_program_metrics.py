@@ -7,11 +7,12 @@ import pytest
 
 from benchmarks.lib import reducers
 from benchmarks.lib.spec import Spec
-from tests.benchmarks.helpers import run_cell
+from tests.benchmarks.helpers import chat_held_metric, run_cell
 from tests.benchmarks.toy import make_toy_root
 
 SPEC = Spec()
 CHAT, LONGGEN = "serve-1.7b-chat", "serve-1.7b-longgen"
+CHAT_HELD = chat_held_metric(SPEC)["name"]
 
 # metric -> (access-record field, the cells that report it)
 PROGRAM_METRICS = {
@@ -24,9 +25,10 @@ PROGRAM_METRICS = {
 
 
 def name_in(cell, name):
-    """The chat cell is held to ``serve_itl_p995_ms`` since PR 41 and a
-    per-layer metric names one end-to-end metric: there the quantity
-    has a twin of its own, ``<name>.chat``, with the same reader."""
+    """The chat cell has had a gap percentile of its own since PR 41
+    (``CHAT_HELD``) and a per-layer metric names one end-to-end metric:
+    there the quantity has a twin of its own, ``<name>.chat``, with the
+    same reader."""
     return f"{name}.chat" if cell == CHAT else name
 
 
@@ -43,7 +45,7 @@ def test_the_spec_finds_the_metric_for_its_cells_only(name):
                  if m["name"] == name_in(cell, name)]
         assert len(found) == (cell in cells), (name, cell)
         for m in found:
-            assert m["moves"] == ("serve_itl_p995_ms" if cell == CHAT
+            assert m["moves"] == (CHAT_HELD if cell == CHAT
                                   else "serve_itl_p99_ms")
     metric = reader(name, LONGGEN if LONGGEN in cells else CHAT)
     assert metric["reducer"] == reader(name)["reducer"]
@@ -54,7 +56,7 @@ def test_the_spec_finds_the_metric_for_its_cells_only(name):
             metric["moves"]) == (
                 "ms", "lower", "program_counter",
                 "serve_itl_p99_ms" if LONGGEN in cells
-                else "serve_itl_p995_ms")
+                else CHAT_HELD)
     # an inside view of a layer the benchmark already names
     outside = {m["layer"] for m in SPEC.index["per_layer"]
                if m["name"].removesuffix(".chat") not in PROGRAM_METRICS}

@@ -141,7 +141,7 @@ def test_the_cell_reports_what_the_index_says():
             # on the list, wherever: the next cell is appended after it
             assert REAL_CELL in metric["workloads"], metric["name"]
         if metric["name"] in NEW_READERS:
-            assert metric["workloads"] == [REAL_CELL]
+            assert REAL_CELL in metric["workloads"]
             assert metric["moves"] == "serve_itl_p95_ms"
             assert set(metric) == {"name", "unit", "better", "source",
                                    "layer", "moves", "workloads"}
