@@ -86,8 +86,8 @@ class ModelArguments:
     model_type: str = field(
         default="llama",
         metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
-                          "olmo_hybrid | qwen3_next | afmoe | gpt_moe | "
-                          "lenet | mingpt"},
+                          "olmo_hybrid | qwen3_next | afmoe | jamba | "
+                          "gpt_moe | lenet | mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -128,8 +128,8 @@ class ModelArguments:
     embed_init_std: Optional[float] = field(
         default=None,
         metadata={"help": "Standard deviation the random initialiser "
-                          "draws the token embedding at (qwen3_next "
-                          "and afmoe; unset: 0.02, HF's "
+                          "draws the token embedding at (qwen3_next, "
+                          "afmoe and jamba; unset: 0.02, HF's "
                           "initializer_range). "
                           "A property of random weights, not of the "
                           "model."},
@@ -157,6 +157,20 @@ class ModelArguments:
     n_group: int = 1
     topk_group: int = 1
     mup_enabled: bool = True
+    # jamba, by the published config.json names: layer i is an attention
+    # layer where i % attn_layer_period == attn_layer_offset and a
+    # Mamba-1 layer elsewhere; the Mamba layers' state size, convolution
+    # width, expansion (inner width = mamba_expand * hidden_size), the
+    # rank of the step projection, and whether the convolution / the in
+    # and out projections carry a bias
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "

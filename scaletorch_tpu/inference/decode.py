@@ -53,11 +53,12 @@ def _resolve_donate(donate_cache: Optional[bool]) -> bool:
 
 def resolve_forward_cached(cfg) -> Callable:
     """The cache-aware forward for a model config: Qwen3-MoE, GPT-MoE,
-    afmoe, Olmo-Hybrid and Qwen3-Next (a subclass of the hybrid's: asked
-    first) have their own cached forwards; every other LlamaConfig
-    subclass (Llama, Qwen3) shares the Llama one."""
+    afmoe, Jamba, Olmo-Hybrid and Qwen3-Next (a subclass of the
+    hybrid's: asked first) have their own cached forwards; every other
+    LlamaConfig subclass (Llama, Qwen3) shares the Llama one."""
     from scaletorch_tpu.models.afmoe import AfmoeConfig
     from scaletorch_tpu.models.gpt_moe import GPTMoEConfig
+    from scaletorch_tpu.models.jamba import JambaConfig
     from scaletorch_tpu.models.llama import LlamaConfig
     from scaletorch_tpu.models.olmo_hybrid import OlmoHybridConfig
     from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
@@ -67,6 +68,10 @@ def resolve_forward_cached(cfg) -> Callable:
         from scaletorch_tpu.models import afmoe
 
         return afmoe.forward_cached
+    if isinstance(cfg, JambaConfig):
+        from scaletorch_tpu.models import jamba
+
+        return jamba.forward_cached
     if isinstance(cfg, Qwen3NextConfig):
         from scaletorch_tpu.models import qwen3_next
 

@@ -37,7 +37,11 @@ layers only, and beside it, indexed by SLOT, the linear layers'
 recurrent state ``f32[linear layers, slots, H, d_k, d_v]`` and their
 convolution tail ``[linear layers, slots, kernel - 1, channels]``. A
 slot's state has no pages, no table and no snapshots: it is whatever the
-slot's request has read so far, started from zero by its prefill.
+slot's request has read so far, started from zero by its prefill. A
+state-space model (Jamba: Mamba-1 layers between two attention layers)
+keeps the same pytree with a state of another shape, ``f32[mamba
+layers, slots, N, R, 128]``: the family's ``recurrent_state_shapes``
+says which, and only ``[layers, slots]`` is common to them.
 
 A model whose layers mix window and full attention (afmoe: three
 ``sliding_attention`` layers to one ``full_attention``) keeps its two
@@ -191,8 +195,18 @@ class HybridCache(NamedTuple):
     layers, one pytree that the step programs donate and return: the
     page pools ``k`` / ``v`` ``[full layers, n_pages, Hkv, page_size,
     D]`` and, indexed by slot and not by page, the recurrent ``state``
-    ``f32[linear layers, slots, H, d_k, d_v]`` and the convolution tail
-    ``conv`` ``[linear layers, slots, kernel - 1, channels]``."""
+    (float32) and the convolution tail ``conv`` (the serving dtype), in
+    whatever shapes the family's ``recurrent_state_shapes(slots)``
+    returns: each begins ``[state-carrying layers, slots]`` and may
+    have any rank after that. The gated delta rule's (Olmo-Hybrid,
+    Qwen3-Next): a matrix per head, ``[linear layers, slots, H, d_k,
+    d_v]``, and ``[linear layers, slots, kernel - 1, channels]``;
+    Mamba-1's (Jamba): elementwise per channel with the channels on the
+    lanes, ``[mamba layers, slots, N, R, 128]`` (``channels = R *
+    128``), and ``[mamba layers, slots, d_conv - 1, channels]``. Nothing
+    but the family's own forward reads past the first two axes: the
+    engine fills by slot (``decode.make_fill_slots_step`` masks axis 1)
+    and counts bytes."""
 
     k: jax.Array
     v: jax.Array
@@ -267,6 +281,10 @@ def carries_state(cfg) -> bool:
 
 def _zero_recurrent_state(cfg, slots: int, dtype: Any,
                           sharding: Optional[Any] = None):
+    """The zeroed ``(state, conv)`` of ``slots`` slots: float32 and
+    ``dtype``, in the two shapes ``cfg.recurrent_state_shapes(slots)``
+    gives (``[layers, slots, ...]``, any rank after that: no rank is
+    assumed here or by the masked fill over slots)."""
     state, conv = cfg.recurrent_state_shapes(slots)
     if isinstance(sharding, NamedSharding):   # the pool's mesh, replicated
         sharding = NamedSharding(sharding.mesh, P())
@@ -308,21 +326,24 @@ def init_paged_kv_cache(
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
     sk, sv = (sharding.k, sharding.v) \
         if isinstance(sharding, PagedKVCache) else (sharding, sharding)
-    # device=: allocated on the shards, never whole on the default device
-    k, v = jnp.zeros(shape, dt, device=sk), jnp.zeros(shape, dt, device=sv)
     window = window_of(cfg)
-    if window is None and not carries_state(cfg):
-        return PagedKVCache(k=k, v=v)
-    if slots is None:
+    by_slot = window is not None or carries_state(cfg)
+    if by_slot and slots is None:
         raise ValueError(
             f"{type(cfg).__name__} keeps memory by slot beside its pages "
             "(a recurrent state, or window layers' rings): its cache "
             "needs the number of slots beside the number of pages")
-    if sk is not None and len(sk.device_set) > 1:
+    if by_slot and sk is not None and len(sk.device_set) > 1:
+        # asked before the pools are made: a family with ONE K/V head
+        # (Jamba) has no head axis a second device could take
         raise NotImplementedError(
             "a recurrent state or a window layer's ring over several "
             "devices (tensor parallelism over such layers) is not "
             "written: serve this model on one device")
+    # device=: allocated on the shards, never whole on the default device
+    k, v = jnp.zeros(shape, dt, device=sk), jnp.zeros(shape, dt, device=sv)
+    if not by_slot:
+        return PagedKVCache(k=k, v=v)
     if window is not None:
         ring = (cfg.num_window_layers,
                 1 + slots * window_ring_pages(window, page_size)) + shape[2:]

@@ -23,6 +23,7 @@ from scaletorch_tpu.models.qwen3_next import (  # noqa: F401
     Qwen3NextConfig,
 )
 from scaletorch_tpu.models.afmoe import Afmoe, AfmoeConfig  # noqa: F401
+from scaletorch_tpu.models.jamba import Jamba, JambaConfig  # noqa: F401
 from scaletorch_tpu.models.gpt_moe import GPTMoE, GPTMoEConfig  # noqa: F401
 from scaletorch_tpu.models.lenet import LeNet, LeNetConfig  # noqa: F401
 from scaletorch_tpu.models.resnet import ResNetConfig  # noqa: F401

@@ -421,16 +421,37 @@ def gated_delta_chunked(q, k, v, log_alpha, beta, state, *,
     return o.reshape(b, n * chunk, h, dv)[:, :s], state
 
 
-def short_conv(x: jax.Array, weight: jax.Array, tail: jax.Array):
+def short_conv(x: jax.Array, weight: jax.Array, tail: jax.Array,
+               bias: Optional[jax.Array] = None):
     """Depthwise causal convolution over time: x [B, S, C] after the
-    ``tail`` [B, K-1, C] of rows that came before it, weight [K, C], no
-    bias. Returns (y [B, S, C] float32 with ``y_t = sum_j w_j
-    x_{t-K+1+j}``, the rows ``[tail; x]`` [B, S+K-1, C])."""
+    ``tail`` [B, K-1, C] of rows that came before it, weight [K, C],
+    ``bias`` [C] or none (the delta-rule families have none; Jamba's
+    Mamba layers have one). Returns (y [B, S, C] float32 with ``y_t =
+    sum_j w_j x_{t-K+1+j} (+ bias)``, the rows ``[tail; x]`` [B, S+K-1,
+    C])."""
     rows = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     s = x.shape[1]
     wide, w = rows.astype(F32), weight.astype(F32)
     y = sum(wide[:, j:j + s] * w[j] for j in range(weight.shape[0]))
+    if bias is not None:
+        y = y + bias.astype(F32)
     return y, rows
+
+
+def conv_tail_after(rows: jax.Array, tail: jax.Array,
+                    row_mask: Optional[jax.Array]) -> jax.Array:
+    """The tail a call leaves: of ``rows`` = ``[tail; x]`` [B, S+K-1, C]
+    (``short_conv``) the last K-1 rows before each sequence's next
+    token, where ``row_mask`` [B, S] says which rows of ``x`` are
+    tokens (a prefix of each sequence; None: all)."""
+    b, s = rows.shape[0], rows.shape[1] - tail.shape[1]
+    valid = (jnp.full((b,), s, jnp.int32) if row_mask is None
+             else jnp.sum(row_mask, axis=1, dtype=jnp.int32))
+    # rows[valid + j] is the j-th of the last K-1 rows before the next
+    # token
+    keep = valid[:, None] + jnp.arange(tail.shape[1])[None, :]
+    return jnp.take_along_axis(
+        rows, keep[:, :, None], axis=1).astype(tail.dtype)
 
 
 def linear_attention_mix(
@@ -463,13 +484,7 @@ def linear_attention_mix(
              for name in ("q_proj", "k_proj", "v_proj")], axis=-1)
         mixed, rows = short_conv(qkv, layer["conv"], tail)
         mixed = jax.nn.silu(mixed)
-        valid = (jnp.full((b,), s, jnp.int32) if row_mask is None
-                 else jnp.sum(row_mask, axis=1, dtype=jnp.int32))
-        # rows[valid + j] is the j-th of the last K-1 rows before the
-        # next token
-        keep = valid[:, None] + jnp.arange(tail.shape[1])[None, :]
-        new_tail = jnp.take_along_axis(
-            rows, keep[:, :, None], axis=1).astype(tail.dtype)
+        new_tail = conv_tail_after(rows, tail, row_mask)
 
     with jax.named_scope("gdn.recurrence"):
         q = l2norm(mixed[..., :kq].reshape(b, s, key_heads, dk)) * dk ** -0.5

@@ -346,6 +346,58 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         route_scale=2.826,
         mup_enabled=True,
     ),
+    # AI21-Jamba2-3B (ai21labs/AI21-Jamba2-3B config.json, model_type
+    # jamba), as published: 28 layers, Mamba-1 layers with an attention
+    # layer at 7 and 21 (one in 14), 20 query heads on ONE K/V head,
+    # no rotary embedding, a dense SwiGLU MLP in every layer
+    # (num_experts 1), tied embedding. 3.03 B parameters, 6.06 GB in
+    # bf16: one v5e chip serves it whole.
+    "jamba2-3b": dict(
+        model_type="jamba",
+        vocab_size=65536,
+        hidden_size=2560,
+        intermediate_size=8192,
+        num_hidden_layers=28,
+        num_attention_heads=20,
+        num_key_value_heads=1,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=262144,
+        tie_word_embeddings=True,
+        attn_layer_period=14,
+        attn_layer_offset=7,
+        num_experts=1,
+        num_experts_per_tok=1,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_dt_rank=160,
+        mamba_conv_bias=True,
+        mamba_proj_bias=False,
+    ),
+    # The same family at a size the CPU tests serve: two periods of
+    # (mamba, attention, mamba, mamba), 128 channels of 8 states.
+    "jamba-tiny": dict(
+        model_type="jamba",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=8,
+        num_attention_heads=4,
+        num_key_value_heads=1,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=4096,
+        tie_word_embeddings=True,
+        attn_layer_period=4,
+        attn_layer_offset=1,
+        num_experts=1,
+        num_experts_per_tok=1,
+        mamba_d_state=8,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_dt_rank=8,
+        mamba_conv_bias=True,
+        mamba_proj_bias=False,
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

@@ -46,11 +46,17 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
     moe_arch.update(num_routed_experts=cfg.num_routed_experts,
                     first_expert_id=cfg.first_expert_id)
     if cfg.embed_init_std is not None and cfg.model_type not in (
-            "qwen3_next", "afmoe"):
+            "qwen3_next", "afmoe", "jamba"):
         raise NotImplementedError(
             f"--embed_init_std with model_type {cfg.model_type!r}: only "
-            "qwen3_next's and afmoe's initialisers read it "
-            "(models/qwen3_next.py, models/afmoe.py)")
+            "qwen3_next's, afmoe's and jamba's initialisers read it "
+            "(models/qwen3_next.py, models/afmoe.py, models/jamba.py)")
+    if cfg.model_type == "jamba" and cfg.model_name_or_path:
+        raise NotImplementedError(
+            "jamba from --model_name_or_path: HF config auto-fill and "
+            "weight loading are not written for this family; give its "
+            "sizes by their config.json names (models/presets.py "
+            "jamba2-3b)")
     if cfg.model_type == "afmoe" and cfg.model_name_or_path:
         raise NotImplementedError(
             "afmoe from --model_name_or_path: HF config auto-fill and "
@@ -223,6 +229,26 @@ def build_model_config(cfg: ScaleTorchTPUArguments):
                 "num_dense_layers", "num_shared_experts", "score_func",
                 "route_norm", "route_scale", "n_group", "topk_group",
                 "mup_enabled")}})
+    if cfg.model_type == "jamba":
+        from scaletorch_tpu.models import jamba
+
+        if cfg.num_experts != 1 or cfg.num_experts_per_tok != 1:
+            raise NotImplementedError(
+                f"jamba with num_experts {cfg.num_experts} / "
+                f"num_experts_per_tok {cfg.num_experts_per_tok}: every "
+                "layer's feed-forward is the dense SwiGLU MLP of "
+                "models/jamba.py (Jamba2's num_experts 1); the routed "
+                "layers of the larger Jambas are not written")
+        # the published config.json names; no rotary embedding and no
+        # key for one (models/jamba.py)
+        return jamba.JambaConfig(**{
+            **common, "rope_theta": None,
+            **({} if cfg.embed_init_std is None
+               else {"embed_init_std": cfg.embed_init_std}),
+            **{name: getattr(cfg, name) for name in (
+                "attn_layer_period", "attn_layer_offset", "mamba_d_state",
+                "mamba_d_conv", "mamba_expand", "mamba_dt_rank",
+                "mamba_conv_bias", "mamba_proj_bias")}})
     if cfg.model_type == "qwen3":
         return qwen3.Qwen3Config(qk_norm=True, **common)
     if cfg.model_type == "llama":
@@ -311,6 +337,15 @@ class Trainer:
                 ": its state-carrying layers have no sharding rules (tp / "
                 "cp / pp / ep), no loss wiring and no HF weight loading; "
                 "the family is served (scripts/serve.py --preset ...)")
+        if cfg.model_type == "jamba":
+            raise NotImplementedError(
+                "the trainer has no step for model_type 'jamba': its "
+                "selective scan has no backward (the Mosaic kernel is "
+                "forward only, and the chunked XLA form under jax.grad "
+                "keeps every chunk's state), its Mamba layers have no "
+                "sharding rules (tp / cp / pp), and there is no loss "
+                "wiring and no HF weight loading; the family is served "
+                "(scripts/serve.py --preset jamba2-3b)")
         if cfg.model_type == "afmoe":
             raise NotImplementedError(
                 "the trainer has no step for model_type 'afmoe': its "

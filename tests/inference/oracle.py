@@ -3,8 +3,8 @@
 No cache of any kind and none of the engine's code: every token is the
 argmax of the full-sequence training forward (``llama.forward`` /
 ``qwen3_moe.forward`` / ``gpt_moe.forward`` / ``afmoe.forward`` /
-``olmo_hybrid.forward`` and ``qwen3_next.forward`` with the recurrence
-row after row) over the
+``olmo_hybrid.forward``, ``qwen3_next.forward`` and ``jamba.forward``
+with the recurrence row after row) over the
 whole sequence so far. The engine's page pool, page tables, prefix sharing, cached
 forwards and sampling are all on the other side of the comparison.
 
@@ -24,6 +24,7 @@ import numpy as np
 from scaletorch_tpu.models import (
     afmoe,
     gpt_moe,
+    jamba,
     llama,
     olmo_hybrid,
     qwen3_moe,
@@ -46,6 +47,9 @@ def plain_forward(cfg):
         return functools.partial(olmo_hybrid.forward, sequential=True)
     if isinstance(cfg, afmoe.AfmoeConfig):
         return afmoe.forward
+    if isinstance(cfg, jamba.JambaConfig):
+        # the selective scan as its definition, not the chunked form
+        return functools.partial(jamba.forward, scan="sequential")
     if isinstance(cfg, qwen3_moe.Qwen3MoEConfig):   # OLMoE included
         return qwen3_moe.forward
     if isinstance(cfg, llama.LlamaConfig):          # Llama, Qwen3

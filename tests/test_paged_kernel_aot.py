@@ -90,10 +90,12 @@ def test_serving_shape_is_one_call_the_benchmark_can_find(one_chip):
     (32, 8, 16, 13, jnp.bfloat16),    # n_rep 4, a short last block
     (64, 8, 16, 13, jnp.bfloat16),    # n_rep 8
     (2, 1, 16, 96, jnp.bfloat16),     # one KV head of a tp shard
+    (20, 1, 16, 216, jnp.bfloat16),   # Jamba2: 20 query rows, ONE KV head
     (16, 8, 8, 96, jnp.bfloat16),     # a page of half a bf16 tile
     (16, 8, 32, 48, jnp.bfloat16),
     (16, 8, 8, 13, jnp.float32),      # fp32 pools
-], ids=["mha", "nrep4-ragged", "nrep8", "hkv1", "page8", "page32", "fp32"])
+], ids=["mha", "nrep4-ragged", "nrep8", "hkv1", "jamba-20-on-1", "page8",
+        "page32", "fp32"])
 def test_kernel_compiles_for_the_layouts_the_models_use(
         one_chip, hq, hkv, page, max_pages, dtype):
     calls = _mosaic_calls(_compiled_text(
@@ -625,6 +627,123 @@ def test_trinity_steps_are_what_the_new_readers_look_for(one_chip):
         assert memory.temp_size_in_bytes < scratch, name
         assert (memory.argument_size_in_bytes
                 + memory.temp_size_in_bytes) < 11e9, name
+
+
+def test_jamba_steps_are_what_the_new_readers_look_for(one_chip):
+    """Jamba2-3B's two step programs at the cell's shapes (8 slots x
+    3,072-row prompts, 26 Mamba layers + 2 attention layers on ONE K/V
+    head). **The scan never materialises a state axis over the prompt**:
+    no array of ``slots x prefill_len x channels x N`` elements exists,
+    of any type or layout (``exp(dt A)`` written the obvious way is
+    ``f32[8,3072,5120,16]``, 8.05 GB a layer); the scan is the Mosaic
+    kernel, 2 calls in the text (the two runs of Mamba layers in a
+    period), named ``ssm_scan_fwd`` and returning ``(y [8, 3072, 5120],
+    the state [8, 16, 40, 128])`` with NO copy around them. Prefill attention is the flash forward on 20 query
+    heads over one K/V head; the decode step's is ``paged_decode`` with
+    the ``[8, 1, 20, 128]`` tile: the lax fallback is not taken for a
+    head of 128. The state ``f32[26,8,16,40,128]`` is the layer loops'
+    carry, written in place by one select + dynamic-update-slice fusion
+    a Mamba run, never copied. The readers of the cell's new metrics
+    find the kernel and the decode update by these names, and both
+    programs fit the chip."""
+    decode, prefill, pool_shape = _programs_of(one_chip, "jamba2-3b-serve")
+    slots, rows, channels, n = 8, 3072, 5120, 16
+    assert pool_shape == (2, slots * 216 + 1, 1, 16, 128)
+    texts = {"decode": decode.as_text(), "prefill": prefill.as_text()}
+    sizes = {dims: math.prod(map(int, dims.split(",")))
+             for dims in set(_ARRAY.findall(texts["prefill"]))}
+    assert not [d for d, count in sizes.items()
+                if count >= slots * rows * channels * n]
+    calls = _mosaic_calls(texts["prefill"])
+    scans = _named(calls, "ssm_scan_fwd")
+    assert len(scans) == 2, calls
+    for call in scans:
+        assert re.search(
+            r"= \(f32\[8,3072,5120\]\S*, f32\[8,16,40,128\]\S*\) "
+            r"custom-call\(", call), call
+    flash = _named(calls, "flash_fwd")
+    assert len(flash) == 1 and re.search(
+        r"= \(bf16\[8,20,3072,128\]\S*, f32\[8,20,1,3072\]", flash[0])
+    assert len(_named(calls, "paged_write")) == 2
+    assert not _named(calls, "paged_decode")
+    # u, dt and y go in and out as the projections hold them: nothing of
+    # their size is copied, transposed or re-laid around the call (as
+    # [.., 40, 128] views each was: 4.6 ms a layer on the chip)
+    for shape in ("f32[8,3072,5120]", "f32[3072,8,40,128]",
+                  "f32[8,3072,40,128]"):
+        assert not [x for x in _top_level(texts["prefill"], shape)
+                    if x[0] in ("copy", "transpose", "reshape")], shape
+    calls = _mosaic_calls(texts["decode"])
+    attn = _named(calls, "paged_decode")
+    assert len(attn) == 1 and re.search(
+        r"= bf16\[8,1,20,128\]", attn[0]), calls
+    assert len(_named(calls, "paged_write")) == 2
+    assert not _named(calls, "ssm_scan_fwd")
+    for name, text in texts.items():
+        state = [op for op, _ in _top_level(text, "f32[26,8,16,40,128]")
+                 if op not in _PLUMBING]
+        assert state == ["fusion"] * 2, (name, state)
+
+    with open(os.path.join(REPO, "benchmarks", "metrics",
+                           "serve_jamba_ssm_scan_share.json")) as f:
+        share = json.load(f)["reducer"]
+
+    def scan_ops(program):
+        """What the share's reader would count of a program's
+        operations: fusions and custom calls are what the trace's ``XLA
+        Ops`` line holds of them."""
+        return [name for name in _short_names(texts[program])
+                if re.search(r" \| (fusion|custom-call) \| ", name)
+                and any(re.search(p, name) for p in share["patterns"])
+                and not any(re.search(p, name) for p in share["exclude"])]
+
+    decode_ops = scan_ops("decode")
+    # a Mamba run of the decode step: the fusion that reads the state
+    # for y, and the in-place write
+    assert sum(name.endswith("| kLoop | f32[8,40,128]")
+               for name in decode_ops) == 2, decode_ops
+    assert sum(name.endswith("f32[26,8,16,40,128]")
+               for name in decode_ops) == 2, decode_ops
+    assert not [name for name in decode_ops if "bf16" in name], decode_ops
+    kernel = [name for name in _short_names(texts["prefill"])
+              if any(re.search(p, name) for p in _reader_patterns(
+                  "serve_jamba_ssm_scan_roofline"))]
+    assert len(kernel) == 2 and all(
+        name.startswith("ssm_scan_fwd") for name in kernel), kernel
+    assert set(kernel) <= set(scan_ops("prefill"))
+    assert not [name for name in _short_names(texts["decode"])
+                if any(re.search(p, name) for p in _reader_patterns(
+                    "serve_jamba_ssm_scan_roofline"))]
+
+    cache_bytes = (2 * 2 * math.prod(pool_shape) + 26 * 8 * 16 * 5120 * 4
+                   + 26 * 8 * 3 * 5120 * 2)
+    for name, program, scratch in (("decode", decode, 16 * 2**20),
+                                   ("prefill", prefill, 4e9)):
+        memory = program.memory_analysis()
+        assert memory.alias_size_in_bytes >= cache_bytes, name
+        assert memory.temp_size_in_bytes < scratch, name
+        assert (memory.argument_size_in_bytes
+                + memory.temp_size_in_bytes) < 11e9, name
+
+
+@pytest.mark.parametrize("block_t", [128, 256])
+def test_ssm_scan_kernel_compiles_at_the_cell_s_shape(one_chip, block_t):
+    """One Mamba layer's prefill call: 8 x 3,072 rows of 5,120 channels,
+    16 states held as ``[16, 40, 128]``. One Mosaic call; its scalars (B and C,
+    ``[block_t, 16]`` float32 a grid step) fit SMEM at 128 and 256 rows
+    a step (512 did not on the v5e: PERF.md, PR 47)."""
+    from scaletorch_tpu.ops.pallas.ssm_scan import ssm_scan_fwd
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(
+        lambda *xs: ssm_scan_fwd(*xs, block_t=block_t)).lower(
+        arg(8, 3072, 5120), arg(8, 3072, 5120), arg(16, 40, 128),
+        arg(8, 3072, 16), arg(8, 3072, 16), arg(8, 16, 40, 128),
+    ).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 1 and _named(calls, "ssm_scan_fwd"), calls
 
 
 def test_narrow_heads_take_the_lax_pair_in_the_same_loop(one_chip):
