@@ -172,6 +172,17 @@ def configure_compile_cache() -> str:
     return COMPILE_CACHE_DEFAULT
 
 
+def compile_cache_dir() -> "str | None":
+    """The directory jax's persistent compile cache is kept in by now
+    (``configure_compile_cache`` or ``JAX_COMPILATION_CACHE_DIR``); None
+    where no process has asked for one (the tests). What is kept BESIDE
+    the compiled programs (``inference/decode.store_orders``) asks
+    here."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir
+
+
 # ---- one chip of a multi-chip host ------------------------------------------
 def one_chip_env(chip: int = 0) -> dict[str, str]:
     """Environment that shows a NEW process one chip of a multi-chip TPU

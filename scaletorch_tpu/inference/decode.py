@@ -19,6 +19,16 @@ construction (``prefill_shapes``), however many requests flow through:
     (``llama.scan_layers_cached``) — the append happens in place
     instead of copying the whole pool every token.
 
+The weights lie where these programs read them
+(``compile_decode_for_layouts`` + ``chosen_orders`` + ``place_params``,
+called once when an engine is built): the decode step is compiled with
+the layout of every parameter left to the compiler, each leaf it reads
+in another order of dimensions is stored once, transposed into that
+order, as a new array (the caller's arrays stay as they were), and both
+steps are built to read the placed tree through a transposition back
+that costs nothing (``param_orders``). No family has code for it: the
+rule asks about each model's own decode program.
+
 Both lower onto the models' cache-aware forwards
 (models/llama.py forward_cached & family), resolved per config by
 ``resolve_forward_cached``, with ``kv_cache.PagedKVIO`` as their cache
@@ -28,10 +38,15 @@ adapter. ``teacher_forced_decode`` (contiguous cache) and
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Tuple
+import hashlib
+import json
+import os
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from scaletorch_tpu.inference.kv_cache import carries_state
 from scaletorch_tpu.inference.routing_counters import step_counts
@@ -202,6 +217,7 @@ def make_paged_prefill_step(
     forward_fn: Optional[Callable] = None,
     donate_cache: Optional[bool] = None,
     routing_counts: bool = False,
+    param_orders: Any = None,
 ) -> Callable:
     """Build the jitted prefill step.
 
@@ -248,6 +264,9 @@ def make_paged_prefill_step(
     ``write_mask`` leave state and convolution tail untouched, and the
     pool the step donates and returns is that model's whole cache
     (``kv_cache.HybridCache``).
+
+    ``param_orders`` (``chosen_orders``): the step takes the parameters
+    as ``place_params`` stored them and reads them ``in_model_order``.
     """
     fwd = forward_fn or resolve_forward_cached(cfg)
     # a row that is no token would otherwise enter a recurrent state:
@@ -260,6 +279,7 @@ def make_paged_prefill_step(
                 page_tables, pool, base_keys, *routing):
         from scaletorch_tpu.inference.kv_cache import PagedKVIO
 
+        params = in_model_order(params, param_orders)
         b, p = tokens.shape
         rows = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
         positions = starts[:, None] + rows
@@ -298,6 +318,7 @@ def make_paged_decode_step(
     forward_fn: Optional[Callable] = None,
     donate_cache: Optional[bool] = None,
     routing_counts: bool = False,
+    param_orders: Any = None,
 ) -> Callable:
     """Build the jitted single-token decode step.
 
@@ -322,8 +343,9 @@ def make_paged_decode_step(
     and the engine ignores their sample. Page-table contents are DATA:
     admissions, prefix hits, quarantine clears, and frees all mutate
     tables host-side and this one compile serves them all.
-    ``routing_counts`` as in ``make_paged_prefill_step``; the rows that
-    exist are the active slots'.
+    ``routing_counts`` and ``param_orders`` as in
+    ``make_paged_prefill_step``; the rows that exist are the active
+    slots'.
     """
     fwd = forward_fn or resolve_forward_cached(cfg)
     # a row that is no token would otherwise enter a recurrent state:
@@ -335,6 +357,7 @@ def make_paged_decode_step(
                base_keys, *routing):
         from scaletorch_tpu.inference.kv_cache import PagedKVIO
 
+        params = in_model_order(params, param_orders)
         kv_io = PagedKVIO(page_tables, page_size, seq_limit=seq_limit)
         counted = {}
         if routing_counts or row_masked:
@@ -360,6 +383,186 @@ def make_paged_decode_step(
     )
 
 
+def abstract(tree):
+    """``tree`` as shapes, each leaf where (and how sharded) it lies."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+        tree)
+
+
+def compile_decode_for_layouts(decode_step: Callable, params, operands, *,
+                               donate_cache: Optional[bool] = None):
+    """The decode program compiled with the layout of every leaf of
+    ``params`` left to the compiler (``Format(Layout.AUTO, <the leaf's
+    sharding>)``; the other ``operands`` as a call hands them over, the
+    pool donated as the step donates it): the executable, whose
+    ``input_formats`` say how the program wants each weight to lie.
+    Nothing runs and nothing is placed: ``params`` and ``operands`` may
+    be arrays or shapes with shardings (``abstract``). The jitted step
+    itself is what is compiled, inside a jit that names the layouts, so
+    where nothing is then moved its trace is the one its first call
+    finds again."""
+    free = jax.tree.map(lambda x: Format(Layout.AUTO, x.sharding), params)
+    return jax.jit(
+        decode_step,
+        in_shardings=(free,) + (None,) * len(operands),
+        donate_argnums=(5,) if _resolve_donate(donate_cache) else (),
+    ).lower(abstract(params), *operands).compile()
+
+
+def _is_order(x) -> bool:
+    return isinstance(x, tuple)
+
+
+def chosen_orders(params, executable):
+    """What ``executable`` (``compile_decode_for_layouts``) asks to be
+    moved, as a tree like ``params``: for a leaf the program reads in
+    another order of dimensions than the leaf lies in, that order
+    (``major_to_minor``, most major first); ``()`` for every other
+    leaf. None where no leaf is asked for (every CPU: the compiler
+    answers with the layouts the arrays have).
+
+    Orders are compared with dimensions of 1 left out (they lie
+    anywhere) and tilings apart (a few small vectors). A leaf asked for
+    in the row-major order stays too: nothing a transposition stores
+    differs from what the device already keeps."""
+    def asked(leaf, chosen):
+        if chosen.layout is None:       # a leaf the program does not read
+            return ()
+        own = leaf.format.layout
+        own = (tuple(range(leaf.ndim)) if own is None
+               else own.major_to_minor)
+        order = tuple(chosen.layout.major_to_minor)
+
+        def lies(o):
+            return [d for d in o if leaf.shape[d] != 1]
+
+        if lies(order) == lies(own) or lies(order) == sorted(lies(order)):
+            return ()
+        return order
+
+    orders = jax.tree.map(asked, params, executable.input_formats[0][0])
+    return orders if any(jax.tree.leaves(orders, is_leaf=_is_order)) else None
+
+
+def orders_key(*built_from) -> str:
+    """The name ``chosen_orders``' answer is kept under between
+    processes: a digest of what the decode program is built from, short
+    of tracing it. ``built_from`` is what the caller built the step
+    with (configuration, sampling, shapes and shardings of the
+    parameters and operands, as ``repr`` prints them); added here are
+    the installation (jax, jaxlib, the runtime's version, the device's
+    kind) and this package's own source under ``inference/``,
+    ``models/`` and ``ops/``. An answer found under a name that should
+    have changed and did not costs time, never a token: any orders are
+    correct, the steps read the placed tree back through them."""
+    import jaxlib
+
+    device = jax.devices()[0]
+    digest = hashlib.sha256(repr((
+        jax.__version__, jaxlib.__version__, device.device_kind,
+        device.client.platform_version, built_from)).encode())
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for part in ("inference", "models", "ops"):
+        for folder, _, files in sorted(os.walk(os.path.join(package, part))):
+            for name in sorted(files):
+                if name.endswith(".py"):
+                    with open(os.path.join(folder, name), "rb") as f:
+                        digest.update(f.read())
+    return digest.hexdigest()
+
+
+def _orders_file(key: Optional[str]) -> Optional[str]:
+    """Beside jax's persistent compile cache, which holds the programs
+    the answer is about; nowhere where no such cache is kept, or for a
+    program that has no name (``key`` None)."""
+    from scaletorch_tpu.env import compile_cache_dir
+
+    folder = compile_cache_dir()
+    return (os.path.join(folder, f"param_orders-{key}.json")
+            if key and folder else None)
+
+
+def load_orders(key: Optional[str], params) -> Tuple[bool, Any]:
+    """(found, orders) of an earlier process' ``store_orders`` under
+    ``key``; an unreadable file counts as none."""
+    path = _orders_file(key)
+    if path is None:
+        return False, None
+    try:
+        with open(path) as f:
+            moved = {leaf: tuple(order)
+                     for leaf, order in json.load(f)["moved"].items()}
+    except (OSError, ValueError, KeyError, AttributeError):
+        return False, None
+    if not moved:
+        return True, None
+    return True, jax.tree_util.tree_map_with_path(
+        lambda at, _: moved.get(jax.tree_util.keystr(at), ()), params)
+
+
+def store_orders(key: Optional[str], orders) -> None:
+    """Keep ``orders`` under ``key`` for the processes that come after
+    (written beside and renamed: a reader sees a whole file or none)."""
+    path = _orders_file(key)
+    if path is None:
+        return
+    moved = {} if orders is None else {
+        jax.tree_util.keystr(at): order for at, order in
+        jax.tree_util.tree_flatten_with_path(orders, is_leaf=_is_order)[0]
+        if order}
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    scratch = f"{path}.{os.getpid()}"
+    with open(scratch, "w") as f:
+        json.dump({"moved": moved}, f)
+    os.replace(scratch, path)
+
+
+def place_params(params, orders) -> Tuple[Any, int, int]:
+    """``params`` as the decode program reads them (``chosen_orders``):
+    a leaf that is asked for in another order of dimensions is stored
+    ONCE, transposed into that order, as a new array in the device's
+    default layout, which is the chosen layout of the leaf's own shape;
+    every other leaf is handed on as it is. The caller's arrays are not
+    donated and stay as they were. Returns the placed tree, the leaves
+    moved and their bytes.
+
+    The steps built with the same ``orders`` (``param_orders``) read the
+    placed tree through ``in_model_order``, a transposition back that
+    the compiler folds into the layout it wanted: the weight copies a
+    step made (``q_proj``'s stack re-laid contraction-minor every
+    token) are paid here, once. Stored so, and not as the same shape
+    under a custom device layout (``jax.device_put`` to a ``Format``),
+    because a program compiled against custom parameter layouts came
+    back from the persistent compile cache as the default-layout
+    program on the v5e and read the re-laid weights as garbage
+    (PERF.md, PR 48): every program that runs has default layouts."""
+    if orders is None:
+        return params, 0, 0
+    moved = []
+
+    def place(order, leaf):
+        if not order:
+            return leaf
+        moved.append(leaf.nbytes)
+        return jnp.transpose(leaf, order)
+
+    placed = jax.tree.map(place, orders, params, is_leaf=_is_order)
+    return placed, len(moved), sum(moved)
+
+
+def in_model_order(params, orders):
+    """Inside a step: the placed tree (``place_params``) as the model's
+    forward indexes it. A transposition of a program's parameter, which
+    the compiler turns into the layout of what reads it: no copy."""
+    if orders is None:
+        return params
+    return jax.tree.map(
+        lambda order, leaf: (jnp.transpose(leaf, np.argsort(order))
+                             if order else leaf),
+        orders, params, is_leaf=_is_order)
+
+
 def teacher_forced_decode_paged(
     params,
     cfg,
@@ -377,8 +580,6 @@ def teacher_forced_decode_paged(
     page 0 reserved as TRASH). Returns [B, S, V] logits — the parity
     oracle proving the paged read/write path is positionally identical
     to the contiguous reference cache, layer by layer, token by token."""
-    import numpy as np
-
     from scaletorch_tpu.inference.kv_cache import (
         PagedKVIO,
         ceil_div,

@@ -302,7 +302,17 @@ class DisaggregatedEngine(InferenceEngine):
     or None = ``plan_slice_split`` over ``budget_path``),
     ``prefill_pool_pages`` (prefill-side scratch pool; default sizes
     ``max_slots`` full prompts + trash page) and ``channel`` (a
-    ``PageHandoffChannel``, injectable for drills)."""
+    ``PageHandoffChannel``, injectable for drills).
+
+    The parameters stay as the caller laid them here: each slice places
+    its own copy of the caller's tree on its own devices below, after
+    the base class is built, so the colocated engine's placement
+    (``InferenceEngine._param_orders``: the order of dimensions the
+    compiler chooses for the decode program) is not asked for; no
+    benchmark cell runs this engine."""
+
+    def _param_orders(self, params, steps):
+        return None
 
     def __init__(self, params, cfg, *,
                  devices: Optional[List[Any]] = None,

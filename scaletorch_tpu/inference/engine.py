@@ -44,7 +44,16 @@ TPU execution discipline:
     prefix hits, quarantine page-clears, and frees;
   * the pool is donated through every step (written in place); with a
     mesh it is head-sharded over ``tp`` via the same axis the training
-    params use (paged_kv_cache_specs), and the steps run GSPMD.
+    params use (paged_kv_cache_specs), and the steps run GSPMD;
+  * the weights lie where the step programs read them: when an engine
+    is built, its decode step is compiled once with every parameter's
+    layout left to the compiler, each leaf the program reads in another
+    order of dimensions is stored once in that order as a new array
+    (``decode.place_params``; the caller's arrays are not donated and
+    stay as they were), and ``engine.params`` is the placed tree, which
+    both jitted steps are built to read (``params_relaid_leaves`` /
+    ``params_relaid_bytes`` in the snapshot; 0 / 0 on a CPU). A new
+    family needs nothing for it.
 
 Serving-grade fault tolerance (inference/resilience.py) rides the same
 discipline: every submitted request ends in exactly one terminal
@@ -89,13 +98,21 @@ from jax._src.array import ArrayImpl  # see _tokens_operand
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from scaletorch_tpu.inference.decode import (
+    abstract,
+    chosen_orders,
+    compile_decode_for_layouts,
     counts_routing,
+    load_orders,
     make_fill_slots_step,
     make_paged_decode_step,
     make_paged_prefill_step,
+    orders_key,
+    place_params,
     prefill_shapes,
+    store_orders,
 )
 from scaletorch_tpu.inference.routing_counters import (
+    ROUTING_COUNTERS,
     CountedStep,
     RoutingCounters,
 )
@@ -251,6 +268,12 @@ class EngineMetrics:
     # layer index; a TPU whose head_dim the kernels serve), 0 = the lax
     # scatter + gather
     paged_pool_in_place: int = 0
+    # what the compiler asked to be moved when the engine was built:
+    # the parameter leaves stored in the order of dimensions the decode
+    # program chose (``decode.place_params``), and their bytes; 0 / 0
+    # where the program reads them as they lie (every CPU)
+    params_relaid_leaves: int = 0
+    params_relaid_bytes: int = 0
     outcomes: Dict[str, int] = field(
         default_factory=lambda: {o: 0 for o in TERMINAL_OUTCOMES})
     # request-scoped latency distributions (telemetry/histogram.py):
@@ -334,6 +357,8 @@ class EngineMetrics:
             "prefix_pages": self.prefix_pages,
             "warm_pages_total": self.warm_pages_total,
             "paged_pool_in_place": self.paged_pool_in_place,
+            "params_relaid_leaves": self.params_relaid_leaves,
+            "params_relaid_bytes": self.params_relaid_bytes,
         }
         for outcome, count in self.outcomes.items():
             snap[f"requests_{outcome}"] = count
@@ -430,7 +455,12 @@ class InferenceEngine:
         GPT-MoE config; ``resolve_forward_cached`` picks the forward).
         For sharded serving pass params already placed with their
         NamedShardings (utils/hf_interop.load_hf_params(shardings=...)
-        feeds this directly).
+        feeds this directly). ``engine.params`` is that tree as the
+        decode program reads it: a leaf the compiler asks for in
+        another order of dimensions is a new array, TRANSPOSED into
+        that order (only the engine's own steps read such a tree),
+        every other leaf is the caller's own array, and nothing of the
+        caller's is donated or changed (``_param_orders``).
     max_slots : decode batch size B (fixed).
     max_seq : prompt + generation cap per slot (S_max).
     prefill_len : static prompt-buffer length P_max (default
@@ -679,8 +709,20 @@ class InferenceEngine:
         self.prefill_shapes = (
             ((max_slots, self.prefill_len),) if self._by_slot
             else prefill_shapes(max_slots, self.prefill_len))
-        self._prefill = make_paged_prefill_step(cfg, sampling, **steps)
         self._decode = make_paged_decode_step(cfg, sampling, **steps)
+        orders = self._param_orders(params, steps)
+        self.params, relaid_leaves, relaid_bytes = place_params(
+            params, orders)
+        if orders is not None:
+            self._decode = make_paged_decode_step(
+                cfg, sampling, param_orders=orders, **steps)
+        self._prefill = make_paged_prefill_step(
+            cfg, sampling, param_orders=orders, **steps)
+        logger.info(
+            "inference engine: params_relaid_leaves %d, "
+            "params_relaid_bytes %d (%.1f MiB): what the decode program "
+            "reads in another order of dimensions, stored so once",
+            relaid_leaves, relaid_bytes, relaid_bytes / 2**20)
         if counts:
             routing = RoutingCounters(
                 cfg.num_experts, len(cfg.sparse_layer_ids()),
@@ -707,6 +749,8 @@ class InferenceEngine:
         self.metrics = EngineMetrics(
             num_slots=max_slots, routing=routing,
             paged_pool_in_place=int(in_place_pair(self.cache.k.shape[-1])),
+            params_relaid_leaves=relaid_leaves,
+            params_relaid_bytes=relaid_bytes,
             recurrent_state_bytes=recurrent_state_bytes(self.cache),
             window_cache_bytes=window_cache_bytes(self.cache))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
@@ -727,6 +771,40 @@ class InferenceEngine:
         # movement (e.g. a queued request timing out on an idle tick)
         # still must
         self._exported_key = self._export_key()
+
+    def _param_orders(self, params, steps: Dict[str, Any]):
+        """The order of dimensions the compiler chooses for each leaf of
+        ``params`` in the decode program at this engine's shapes
+        (``decode.chosen_orders``; None: as they lie; ``steps`` is what
+        the step builders are called with). Asking is a
+        trace, a lowering and a compile of the decode step, 2-9 s on a
+        v5e's host that no compile cache shortens, so the answer is
+        kept beside the compile cache under a name made of what the
+        program is built from (``decode.orders_key``) and the next
+        process starts from it: set-up then costs what it did when the
+        weights were taken as they came. A ``forward_fn`` is code this
+        package cannot name, so its answer is not kept."""
+        slots = self.max_slots
+
+        def operand(*shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        operands = (
+            operand(slots), operand(slots), operand(slots, dtype=jnp.bool_),
+            operand(slots, self._pages_per_slot), abstract(self.cache),
+            operand(slots, 2, dtype=jnp.uint32))
+        if steps["routing_counts"]:
+            operands += (operand(len(ROUTING_COUNTERS), dtype=jnp.uint32),)
+        key = None if steps["forward_fn"] is not None else orders_key(
+            self.cfg, self.sampling, sorted(steps.items()),
+            jax.tree.map(lambda x: x.format, params), operands)
+        found, orders = load_orders(key, params)
+        if not found:
+            orders = chosen_orders(params, compile_decode_for_layouts(
+                self._decode, params, operands,
+                donate_cache=steps["donate_cache"]))
+            store_orders(key, orders)
+        return orders
 
     def _update_page_gauges(self) -> None:
         self.metrics.pages_in_use = self.allocator.used_count

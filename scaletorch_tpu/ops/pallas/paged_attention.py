@@ -491,6 +491,16 @@ def pallas_paged_decode_attention(
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     ppb = _pages_per_block(page_size, hkv, d, pool_k.dtype, max_pages)
+    if not interpret:
+        # "the pools stay in HBM", said to XLA too (inside a jitted
+        # program: the constraint is no eager operation). A Mosaic
+        # call's operand may otherwise be fetched WHOLE into fast memory
+        # ahead of the call, and was once a step's weight copies had
+        # left room there: qwen3-next's 75 MB K pool, three times a
+        # step, +0.13 ms of an 8.6 ms step for a kernel that reads the
+        # live pages of one layer (PERF.md, PR 48)
+        pool_k = pltpu.with_memory_space_constraint(pool_k, pltpu.HBM)
+        pool_v = pltpu.with_memory_space_constraint(pool_v, pltpu.HBM)
 
     def q_idx(b_, *_):
         return (b_, 0, 0, 0)

@@ -625,6 +625,10 @@ class TestEngineSaysWhichPair:
         from scaletorch_tpu.inference import InferenceEngine, SamplingParams
 
         monkeypatch.setenv("SCALETORCH_TPU_FORCE_PALLAS", "1" if tpu else "0")
+        # an engine compiles its decode step as it is built (to place
+        # its parameters), and the Mosaic pair does not lower for a CPU
+        monkeypatch.setattr(InferenceEngine, "_param_orders",
+                            lambda self, *_: None)
         cfg = llama.LlamaConfig(**{**TINY, "num_hidden_layers": 1},
                                 head_dim=head_dim)
         params = llama.init_params(jax.random.PRNGKey(0), cfg)

@@ -170,24 +170,39 @@ def _page_write_aliases_the_pool(one_chip, layers, slots, hkv, rows, page,
 # ---------------------------------------------------------------------------
 # the whole step programs
 # ---------------------------------------------------------------------------
+# {key: the orders of dimensions the decode program chose for the
+# parameters}: one compile with free layouts a configuration, whatever
+# asks for its programs
+_CHOSEN = {}
+
+
 def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
-                   page_size, prefill_rows=None):
+                   page_size, prefill_rows=None, key=None):
     """(decode, prefill) compiled from the engine's own step builders,
-    donated, on abstract arguments; and the pool's shape. With
-    ``prefill_rows`` the prefill step alone, at ``[prefill_rows,
-    prefill_len]``: a row is a slot only through its page table and its
-    key, so the step takes any number of them."""
+    donated, on abstract arguments, AS AN ENGINE RUNS THEM: the
+    parameters stored in the orders of dimensions the compiler chooses
+    for the decode program (``decode.compile_decode_for_layouts`` +
+    ``chosen_orders``, the rule ``InferenceEngine`` places its weights
+    by; kept under ``key``), both steps built to read them so; and the
+    pool's shape. With ``prefill_rows`` the prefill step alone, at
+    ``[prefill_rows, prefill_len]``: a row is a slot only through its
+    page table and its key, so the step takes any number of them."""
+    from jax.experimental.layout import Format
+
     from scaletorch_tpu.inference.decode import (
+        chosen_orders,
+        compile_decode_for_layouts,
         counts_routing,
         make_paged_decode_step,
         make_paged_prefill_step,
+        place_params,
     )
     from scaletorch_tpu.inference.kv_cache import init_paged_kv_cache
     from scaletorch_tpu.inference.routing_counters import ROUTING_COUNTERS
     from scaletorch_tpu.inference.sampling import SamplingParams
 
-    def arg(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    def arg(shape, dt, where=one_chip):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=where)
 
     def on_chip(tree):
         return jax.tree.map(lambda x: arg(x.shape, x.dtype), tree)
@@ -205,19 +220,36 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
 
     def operands(rows, *lead):
         ints = arg((rows,), jnp.int32)
-        return (params, *lead, ints, ints, arg((rows,), jnp.bool_),
+        return (*lead, ints, ints, arg((rows,), jnp.bool_),
                 arg((rows, max_pages), jnp.int32), pool,
                 arg((rows, 2), jnp.uint32)) + (
             (arg((len(ROUTING_COUNTERS),), jnp.uint32),) if counted else ())
 
+    if key is None or key not in _CHOSEN:
+        # as arrays on the chip lie: the device's own default layouts
+        # (not row-major for every shape), read off a program that
+        # hands its parameters on
+        lying = jax.tree.map(
+            lambda x, own: arg(x.shape, x.dtype, Format(own.layout, one_chip)),
+            params,
+            jax.jit(lambda tree: tree).lower(params).compile(
+            ).input_formats[0][0])
+        _CHOSEN[key] = chosen_orders(lying, compile_decode_for_layouts(
+            make_paged_decode_step(cfg, sampling, **build), lying,
+            operands(slots), donate_cache=True))
+    orders = _CHOSEN[key]
+    build["param_orders"] = orders
+    placed = on_chip(jax.eval_shape(
+        lambda tree: place_params(tree, orders)[0], params))
     rows = prefill_rows or slots
     prefill = make_paged_prefill_step(cfg, sampling, **build).lower(
-        *operands(rows, arg((rows, prefill_len), jnp.int32))).compile()
+        placed, *operands(rows, arg((rows, prefill_len), jnp.int32))
+    ).compile()
     if prefill_rows is not None:
         return None, prefill, pool.k.shape
     decode = make_paged_decode_step(cfg, sampling, **build).lower(
-        *operands(slots))
-    return decode.compile(), prefill, pool.k.shape
+        placed, *operands(slots)).compile()
+    return decode, prefill, pool.k.shape
 
 
 _INSTRUCTION = re.compile(
@@ -243,38 +275,44 @@ def _pool_shaped(text, pool_shape):
     return found
 
 
-def _programs_of(one_chip, name, prefill_shape=None):
-    """The configuration's two step programs at its serve shapes; with
-    ``prefill_shape`` its prefill program at that ``(rows, length)``
-    alone."""
+def _serving_model(name):
+    """(the configuration's file, the model config the program builds
+    for it, its initialiser)."""
     from benchmarks.lib.program import serving_model
 
     with open(os.path.join(REPO, "benchmarks", "configs",
                            name + ".json")) as f:
         config = json.load(f)
-    serve = config["serve"]
-    cfg, init = serving_model(config, serve["dtype"])
-    rows, length = prefill_shape or (None, serve["prefill_len"])
-    return _serving_steps(
-        one_chip, cfg, init, slots=serve["max_slots"],
-        max_seq=serve["max_seq"], prefill_len=length,
-        page_size=serve["page_size"], prefill_rows=rows)
+    return (config,) + tuple(serving_model(config, config["serve"]["dtype"]))
+
+
+# {(configuration, prefill shape): its programs}: compiled once a run of
+# this file, whichever test asks first
+_PROGRAMS = {}
+
+
+def _programs_of(one_chip, name, prefill_shape=None):
+    """The configuration's two step programs at its serve shapes; with
+    ``prefill_shape`` its prefill program at that ``(rows, length)``
+    alone."""
+    if (name, prefill_shape) not in _PROGRAMS:
+        config, cfg, init = _serving_model(name)
+        serve = config["serve"]
+        rows, length = prefill_shape or (None, serve["prefill_len"])
+        _PROGRAMS[name, prefill_shape] = _serving_steps(
+            one_chip, cfg, init, slots=serve["max_slots"],
+            max_seq=serve["max_seq"], prefill_len=length,
+            page_size=serve["page_size"], prefill_rows=rows, key=name)
+    return _PROGRAMS[name, prefill_shape]
 
 
 @pytest.fixture(scope="module")
 def serving_cfgs():
     """{configuration name: the model config the program builds for it}
     of the four whose step programs ``serving_programs`` compiles."""
-    from benchmarks.lib.program import serving_model
-
-    cfgs = {}
-    for name in ("qwen3-1.7b-serve", "olmoe-1b-7b-serve",
-                 "olmo-hybrid-7b-serve", "qwen3-next-80b-a3b-serve"):
-        with open(os.path.join(REPO, "benchmarks", "configs",
-                               name + ".json")) as f:
-            config = json.load(f)
-        cfgs[name] = serving_model(config, config["serve"]["dtype"])[0]
-    return cfgs
+    return {name: _serving_model(name)[1]
+            for name in ("qwen3-1.7b-serve", "olmoe-1b-7b-serve",
+                         "olmo-hybrid-7b-serve", "qwen3-next-80b-a3b-serve")}
 
 
 @pytest.fixture(scope="module", params=["qwen3-1.7b-serve",
@@ -296,6 +334,138 @@ def test_no_step_program_moves_the_pool(serving_programs):
         assert _pool_shaped(program.as_text(), pool_shape) == {}, name
         writes = _named(_mosaic_calls(program.as_text()), "paged_write")
         assert len(writes) == 2, (name, writes)    # K and V, in the loop
+
+
+_COPY = re.compile(
+    r"= bf16\[(?P<dims>[\d,]+)\](?P<layout>\S*) copy\(%(?P<operand>[^\s,)]+)")
+_RESULT = re.compile(r"^\s*(?:ROOT )?%(?P<name>\S+) = \w+\[[\d,]*\](?P<layout>\S*) ")
+
+
+def _weight_copies(text, params):
+    """The ``copy`` instructions of a compiled program that RE-LAY a
+    weight: the result a bf16 array of 1 Mi elements or more with a
+    weight's shape (a leaf of ``params`` whole, or its trailing
+    dimensions: a layer of a stack, a period's slice; in any order, a
+    copy being how a matrix is turned contraction-minor; dimensions of 1
+    apart), in another layout than its operand has. A copy that keeps
+    the layout and changes the memory space (``S(1)``: XLA fetching a
+    stack into fast memory ahead of its use) re-lays nothing."""
+    def key(dims):
+        return tuple(sorted(d for d in dims if d != 1))
+
+    def laid(layout):
+        return re.sub(r"S\(\d+\)", "", layout)
+
+    weights = {key(leaf.shape[i:]) for leaf in jax.tree.leaves(params)
+               for i in range(leaf.ndim)}
+    layouts = {m["name"]: laid(m["layout"])
+               for m in map(_RESULT.match, text.splitlines()) if m}
+    found = []
+    for line in text.splitlines():
+        m = _COPY.search(line)
+        if m is None:
+            continue
+        dims = [int(d) for d in m["dims"].split(",")]
+        if (math.prod(dims) >= 2 ** 20 and key(dims) in weights
+                and laid(m["layout"]) != layouts.get(m["operand"])):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("line,found", [
+    # the parent's decode programs (ISSUE 48: the ledger's costliest
+    # copies by name), and a copy that is no weight's
+    ("  %copy.372 = bf16[16,2048,4096]{1,2,0:T(8,128)(2,1)} copy(%p.1)", 1),
+    ("  %copy.61 = bf16[1,2048,2048]{1,2,0:T(8,128)(2,1)S(1)} copy(%f.2)", 1),
+    ("  %copy.9 = bf16[4096,2048]{0,1:T(8,128)(2,1)S(1)} copy(%b.3)", 1),
+    ("  %copy.3 = bf16[16,1024,128]{1,2,0:T(8,128)(2,1)S(1)} copy(%f.2)", 0),
+    ("  %copy.4 = bf16[1,2048,128]{1,2,0:T(8,128)(2,1)} copy(%f.2)", 0),
+    # jamba's x_proj stack, fetched into fast memory as it lies (the
+    # parent does it too): a move, not a re-laying
+    ("  %copy.95 = bf16[16,2048,4096]{2,1,0:T(8,128)(2,1)S(1)} copy(%p.1)", 0),
+], ids=["a-stack", "a-layer", "a-layer-transposed", "no-weight", "small",
+        "as-it-lies"])
+def test_the_guard_finds_the_copies_the_parent_made(line, found):
+    weights = {"q_proj": jax.ShapeDtypeStruct((16, 2048, 4096), jnp.bfloat16),
+               "o_proj": jax.ShapeDtypeStruct((28, 2048, 2048), jnp.bfloat16),
+               "norm": jax.ShapeDtypeStruct((28, 2048, 128), jnp.bfloat16)}
+    text = "\n".join([
+        "  %p.1 = bf16[16,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)",
+        "  %f.2 = bf16[1,2048,2048]{2,1,0:T(8,128)(2,1)S(1)} fusion(%p.1)",
+        "  %b.3 = bf16[4096,2048]{1,0:T(8,128)(2,1)} bitcast(%f.2)", line])
+    assert len(_weight_copies(text, weights)) == found
+
+
+# what placing the weights cannot take away, each in the parent too.
+# jamba's ``x_proj`` stack lies ``{2,3,1,0}`` by the DEVICE's default (192
+# columns would pad to 256 the other way round), the decode program
+# compiled with free layouts asks for exactly that, and compiled against
+# it the program still turns the stack into fast memory once a step (51
+# MB, ~0.06 ms of 9.3). Its prefill program reads ``out_proj``
+# contraction-minor where the decode program, which the rule asks, reads
+# it as it lies (0.68 GB, ~1.7 ms of a 1.2 s call). The hybrid's gates'
+# projections ``[4,3,3840,30]`` are stored ``[4,3,30,3840]`` as asked and
+# tiled again for the matmul (2.7 MB each)
+_STILL_RE_LAID = {
+    ("jamba2-3b-serve", "decode"): ["bf16[2,13,5120,192]"],
+    ("jamba2-3b-serve", "prefill"): ["bf16[2,13,5120,2560]"],
+    ("olmo-hybrid-7b-serve", "decode"): ["bf16[4,3,30,3840]"] * 2,
+    ("olmo-hybrid-7b-serve", "prefill"): ["bf16[4,3,30,3840]"] * 2,
+}
+
+
+@pytest.mark.parametrize("name", [
+    "qwen3-1.7b-serve", "olmoe-1b-7b-serve", "olmo-hybrid-7b-serve",
+    "qwen3-next-80b-a3b-serve", "trinity-mini-serve", "jamba2-3b-serve"])
+def test_no_step_program_copies_a_weight(one_chip, name):
+    """What an engine runs once its parameters are stored in the orders
+    of dimensions the decode program reads them in
+    (``decode.place_params``): no step re-lays a weight. Compiled against the default layouts the decode programs
+    copied, EVERY token, ``q_proj``'s whole 16-layer stack in
+    Trinity-Mini (``copy.372 bf16[16,2048,4096]``, 0.8 ms of a 9.2 ms
+    step, the costliest operation of the cell's trace) and a layer of
+    ``q_proj`` / ``k_proj`` / ``v_proj`` in Qwen3-1.7B (0.5 ms of 7.6),
+    a ``bf16[1,2048,2048]`` a layer in OLMoE, ``bf16[1,1,3840,3840]`` in
+    the hybrid, ``bf16[1,1,2048,8192]`` in qwen3-next,
+    ``bf16[1,1,2560,2560]`` in jamba (PERF.md, PR 48). Every prefill
+    shape the engine lists is compiled against the SAME placed
+    weights and has none either. One copy is left, in the parent and
+    here (``_STILL_RE_LAID``)."""
+    from scaletorch_tpu.inference.decode import prefill_shapes
+    from scaletorch_tpu.inference.kv_cache import carries_state, window_of
+
+    config, cfg, init = _serving_model(name)
+    decode, prefill, _ = _programs_of(one_chip, name)
+    programs = {"decode": decode, "prefill": prefill}
+    if not (carries_state(cfg) or window_of(cfg) is not None):
+        serve = config["serve"]
+        for shape in prefill_shapes(
+                serve["max_slots"], serve["prefill_len"])[:-1]:
+            programs[f"prefill {shape}"] = _programs_of(
+                one_chip, name, shape)[1]
+    params = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
+    for label, program in programs.items():
+        left = [re.search(r"bf16\[[\d,]+\]", line)[0]
+                for line in _weight_copies(program.as_text(), params)]
+        assert left == _STILL_RE_LAID.get((name, label), []), (name, label)
+
+
+def test_the_rule_moves_the_attention_projections_and_no_mlp_weight(
+        one_chip):
+    """Qwen3-1.7B: the decode program reads ``q_proj`` / ``k_proj`` /
+    ``v_proj`` ``[28, 2048, out]`` contraction-minor (a layer's slice
+    then lands in fast memory as the matmul reads it) and the three MLP
+    stacks, the embedding and the head as they come."""
+    name = "qwen3-1.7b-serve"
+    _programs_of(one_chip, name)
+    orders = _CHOSEN[name]
+    for leaf in ("q_proj", "k_proj", "v_proj"):
+        assert orders["layers"][leaf] == (0, 2, 1), leaf
+    moved = [order for order in jax.tree.leaves(
+        orders, is_leaf=lambda x: isinstance(x, tuple)) if order]
+    assert len(moved) == 3      # no MLP stack, not the embedding, no norm
+    for leaf in ("gate_proj", "up_proj", "down_proj", "o_proj"):
+        assert orders["layers"][leaf] == (), leaf
 
 
 def test_decode_program_reserves_no_second_pool(serving_programs):
