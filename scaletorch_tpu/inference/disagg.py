@@ -285,8 +285,8 @@ class DisaggregatedEngine(InferenceEngine):
     The base class remains the DECODE side unchanged: pool, allocator,
     radix tree, page tables, slots, the jitted decode step and the tick
     loop — ``step()`` is inherited, only the admission hooks
-    (``_admit`` / ``_expire`` / ``cancel`` / ``_abort_pending``) are
-    reinterpreted as the phase scheduler:
+    (``_tick_device`` / ``_admit`` / ``_expire`` / ``cancel`` /
+    ``_abort_pending``) are reinterpreted as the phase scheduler:
 
       1. handoff sweep — bind prefilled requests into free decode slots
          by decode-pool budget (FIFO; all-or-nothing reservation);
@@ -457,7 +457,35 @@ class DisaggregatedEngine(InferenceEngine):
         # either phase may have work; the sweeps decide what fits
         return bool(self._queue or self._handoff)
 
+    def _tick_device(self, flight) -> None:
+        """This engine keeps its own order around an admission: a tick
+        with one due reads the step in flight FIRST, runs the phase
+        scheduler (``_admit``, which reads its prefill slice's result
+        back and binds decode slots with their first token on the
+        host), and leaves the next step, fed from the host, in flight.
+        The colocated engine's order (the prefill call behind the step
+        in flight, the next step behind the call, nothing read before
+        both are dispatched) has nothing to win here: the two phases
+        run on different devices, the first token crosses slices
+        through the host, and the decode wrapper above blocks on every
+        step it dispatches."""
+        read = False
+        if flight is not None and self._admission_due():
+            self._read(flight)
+            flight, read = None, True
+        self._admit()
+        if flight is None:
+            flight = self._dispatch(None)
+        if flight is not None and not read:
+            self._in_flight = self._dispatch(flight)
+            self._read(flight)
+            self._drop_dead_flight()  # its streams may have ended here
+        else:
+            self._in_flight = flight
+
     def _admit(self) -> None:
+        """The phase scheduler: blocking, nothing left unread (see
+        ``_tick_device``)."""
         with span("handoff", self.tracer, pending=len(self._handoff)):
             self._handoff_sweep(time.monotonic())
         self._prefill_admit()

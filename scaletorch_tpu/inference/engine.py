@@ -29,7 +29,10 @@ TPU execution discipline:
     one step late and costs one discarded row
     (``decode_slot_steps_discarded``), matched to the request that was
     bound to the slot when the step was dispatched, never to the slot's
-    next tenant. A tick that admits reads the step in flight first
+    next tenant. The run-ahead crosses an admission: a tick that admits
+    dispatches the prefill call behind the step in flight and the next
+    step behind the call, the admitted slots' first tokens fed as the
+    device array the call returns, before it reads anything back
     (``InferenceEngine.step``);
   * K/V lives in ONE layout: a global page pool + per-slot page tables
     (kv_cache.PagedKVCache). Admission is page-budget-aware (HBM scales
@@ -164,6 +167,19 @@ _PHASE_CLOCK = {
 SLOW_TICK_S = 2.0
 
 
+def _host_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` of the threefry generator as two
+    uint32 words, computed on the host. The jax call is a program on
+    the device and a copy back, so it waits for whatever the device is
+    running: an admission behind a step in flight stood still for the
+    rest of that step (6.9 ms of ``engine.tick.admit`` on a v5e, and
+    the step's tokens came that much late). The seed is taken as jax
+    takes a Python int: wrapped to 32 bits unless ``jax_enable_x64``
+    (tests/inference/test_token_release.py holds the equality)."""
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
+
+
 @dataclass
 class Request:
     """One generation request. ``eos_id`` stops the slot early;
@@ -232,17 +248,21 @@ class EngineMetrics:
     requests_admitted: int = 0      # entered a slot (prefilled)
     tokens_generated: int = 0
     prefill_calls: int = 0
+    # ... of them, dispatched with a decode step still on the device
+    # (behind it, the step not read first): all but the cold starts
+    prefill_calls_behind_flight: int = 0
     # rows x length of the shape each prefill call ran at, and of them
     # the admitted prompts' tail tokens: run / admitted is what a call
     # pads (1 is none)
     prefill_positions_run: int = 0
     prefill_positions_admitted: int = 0
     decode_steps: int = 0           # decode steps dispatched
-    # ... of them, dispatched while the step before had not been read
-    # back (the decode loop running one step ahead), and slot-steps
+    # ... of them, dispatched while the step before, or the prefill
+    # call before, had not been read back (the decode loop running one
+    # step ahead, across an admission too), and slot-steps
     # computed for a request that had ended by the time they were read
-    # (an eos, a non-finite row, a cancel or a TTL is learnt one step
-    # late; an end by length never is)
+    # (an eos, a non-finite row of a step or of a prefill call, a cancel
+    # or a TTL is learnt one step late; an end by length never is)
     decode_steps_ahead: int = 0
     decode_slot_steps_discarded: int = 0
     slow_ticks: int = 0             # ticks over SLOW_TICK_S (gap included)
@@ -330,6 +350,7 @@ class EngineMetrics:
             "requests_completed": self.requests_completed,
             "tokens_generated": self.tokens_generated,
             "prefill_calls": self.prefill_calls,
+            "prefill_calls_behind_flight": self.prefill_calls_behind_flight,
             "prefill_positions_run": self.prefill_positions_run,
             "prefill_positions_admitted": self.prefill_positions_admitted,
             "decode_steps": self.decode_steps,
@@ -415,6 +436,20 @@ class _InFlight(NamedTuple):
     positions: np.ndarray
     bound: List[Tuple[int, Request]]
     stall: float
+
+
+class _Admission(NamedTuple):
+    """A prefill call on the device whose result the host has not
+    read: its first tokens ``[rows]`` and finite mask as the device
+    arrays they are, the admitted slots with the request each was bound
+    to, the row of the call each slot took, and when the call was
+    dispatched (``prefill_s`` runs from there to the readback)."""
+
+    first: Any
+    finite: Any
+    bound: List[Tuple[int, Request]]
+    row_of: Dict[int, int]
+    dispatched_t: float
 
 
 class _Phase:
@@ -730,6 +765,20 @@ class InferenceEngine:
             self._prefill = CountedStep(self._prefill, routing)
             self._decode = CountedStep(self._decode, routing)
         self._fill_slots = make_fill_slots_step(donate_cache=donate_cache)
+        # the decode step's ``tokens`` with a prefill call's first
+        # tokens written over the admitted slots' entries, on the device
+        # (``_merge_tokens``); a row that has no step to run carries
+        # ``max_slots`` and is dropped
+        self._merge = jax.jit(
+            lambda tokens, first, slot_of_row:
+            tokens.at[slot_of_row].set(first, mode="drop"),
+            out_shardings=self._token_home)
+        for rows in {rows for rows, _ in self.prefill_shapes}:
+            # a few ms a row count, before the first request: nothing
+            # compiles when the first admission goes behind a step
+            self._merge_tokens(
+                np.zeros(max_slots, np.int32), np.zeros(rows, np.int32),
+                np.full(rows, max_slots, np.int32))
 
         self._slots = [_Slot() for _ in range(max_slots)]
         # the decode step that is on the device, its result not yet
@@ -852,6 +901,19 @@ class InferenceEngine:
             tokens = jax.device_put(tokens, device)
         return ArrayImpl(tokens.aval, SingleDeviceSharding(device),
                          tokens._arrays, committed=False, _skip_checks=True)
+
+    def _merge_tokens(self, tokens, first, slot_of_row: np.ndarray):
+        """``tokens`` (a step's sampled tokens on the device, or the
+        host's) with ``first[row]`` (a prefill call's first tokens, on
+        the device) at ``slot_of_row[row]``, without either leaving the
+        device. Both go in in the form ``_tokens_operand`` gives, so
+        that the one little program a row count compiles (at
+        construction) serves every admission; ``_dispatch`` gives the
+        result that form in turn, as it does any step's tokens."""
+        with self.on_device():
+            return self._merge(
+                self._tokens_operand(tokens), self._tokens_operand(first),
+                slot_of_row)
 
     def _request_pages(self, prompt_len: int, max_new_tokens: int) -> int:
         """Worst-case pages a request reserves: every position it can
@@ -1405,8 +1467,7 @@ class InferenceEngine:
             req.admit_time - req.submit_time)
         self._req_event("e", req, "req.queued")
         self._req_event("n", req, "req.admitted", slot=i)
-        self._base_keys[i] = np.asarray(
-            jax.random.PRNGKey(req.seed), np.uint32)
+        self._base_keys[i] = _host_key(req.seed)
         self._base_keys_dev = None
         self.metrics.requests_admitted += 1
 
@@ -1456,9 +1517,9 @@ class InferenceEngine:
 
     def _admission_due(self) -> bool:
         """A queued request, a free slot for it, and no word yet that
-        the pool cannot cover it. What ``step()`` asks before it lets
-        the decode loop run ahead: an admission reads the step in
-        flight first."""
+        the pool cannot cover it: what ``step()`` asks before it
+        admits. A step in flight does not hold an admission back: the
+        prefill call goes behind it on the device."""
         return (bool(self._queue)
                 and self._queue[0].request_id != self._page_starved
                 and not all(s.active for s in self._slots))
@@ -1474,10 +1535,13 @@ class InferenceEngine:
         step = self._prefill if counted else getattr(
             self._prefill, "uncounted", self._prefill)
         with self.on_device():
+            # the operands stay numpy: the jitted call uploads them
+            # itself (``_dispatch`` says what a jnp.asarray each costs),
+            # and a call behind a step in flight has that step's time
+            # to get itself and the next step dispatched
             first, _logits, finite, self.cache = step(
-                self.params, jnp.asarray(tokens), jnp.asarray(tail_lens),
-                jnp.asarray(starts), jnp.asarray(write_mask),
-                jnp.asarray(tables), self.cache, jnp.asarray(base_keys))
+                self.params, tokens, tail_lens, starts, write_mask, tables,
+                self.cache, base_keys)
         return first, finite
 
     def warm_prefill_shapes(self) -> None:
@@ -1498,19 +1562,21 @@ class InferenceEngine:
                 np.full((rows, self._pages_per_slot), TRASH_PAGE, np.int32),
                 np.zeros((rows, 2), np.uint32), counted=False)
 
-    def _admit(self) -> None:
+    def _admit(self) -> Optional[_Admission]:
         """Move queued requests into free slots while the page pool can
-        cover them, and prefill them — ONE batched prefill call
+        cover them, and DISPATCH their prefill: ONE batched prefill call
         regardless of how many were admitted, at the shape of
         ``prefill_shapes`` with the fewest positions that holds them. A
         row of the call is an admitted slot, in admission order (every
         slot in turn where the cache is by slot); rows past them are
-        padding: masked, one token, a TRASH table. A slot whose prefill
-        logits are non-finite (poison prompt) is quarantined
-        immediately; the other admitted slots proceed."""
+        padding: masked, one token, a TRASH table. Nothing is read
+        back: the call goes on the device behind the step in flight, if
+        there is one, which is not read first, and ``_read_admission``
+        takes the call's result once the step after it has been
+        dispatched too. None when nothing was admitted."""
         with self._phase("engine.tick.admit"):
             if not self._admission_due():
-                return
+                return None
             free = [i for i, s in enumerate(self._slots) if not s.active]
             self._release_tokens()
             # (slot, the prompt's tail to prefill, tokens shared before it)
@@ -1537,7 +1603,7 @@ class InferenceEngine:
                     self.metrics.prefill_tokens_saved += shared
                     self._slots[i].prefix_hit = True
             if not taken:
-                return
+                return None
             admitted = [i for i, _, _ in taken]
             row_slots = (list(range(self.max_slots)) if self._by_slot
                          else admitted)
@@ -1585,12 +1651,27 @@ class InferenceEngine:
             self.metrics.window_ring_wraps += sum(
                 (self._slots[i].position - 1) // self._ring_tokens
                 for i in admitted)
+        return _Admission(
+            first, finite, [(i, self._slots[i].request) for i in admitted],
+            row_of, t0)
+
+    def _read_admission(self, admission: _Admission) -> None:
+        """Read a dispatched prefill call back and emit its first
+        tokens. A slot whose prefill logits are non-finite (poison
+        prompt) is quarantined here, and the row the step behind the
+        call already runs for it is thrown away when that step is read;
+        the other admitted slots proceed."""
         with self._phase("engine.tick.prefill_wait"):
-            first = np.asarray(first)
-            finite = np.asarray(finite)
+            # about to block, with work on the device: nothing that was
+            # emitted (the step in flight's tokens) waits for the call
+            self._release_tokens()
+            first, finite = jax.device_get(
+                (admission.first, admission.finite))
         with self._phase("engine.tick.emit"):
             now = time.monotonic()
-            self._note_prefill(admitted, now - t0)
+            row_of = admission.row_of
+            admitted = [i for i, _ in admission.bound]
+            self._note_prefill(admitted, now - admission.dispatched_t)
             poisoned = [i for i in admitted if not finite[row_of[i]]]
             if poisoned:
                 # skip radix registration for poison prompts — their
@@ -1700,12 +1781,23 @@ class InferenceEngine:
         exactly (positions; a slot that ends at n by ``max_new_tokens``
         or ``max_seq`` is off in n+1); an ``eos``, a non-finite row, a
         cancel or a TTL is learnt late, and that slot's row of n+1 is
-        thrown away (``decode_slot_steps_discarded``). A tick that
-        admits reads the step in flight first, prefills, and leaves the
-        next step, fed from the host, on the device for the following
-        tick. Whether a step goes ahead is decided here from what the
-        engine sees: a step in flight, slots that continue, no
-        admission due.
+        thrown away (``decode_slot_steps_discarded``).
+
+        The run-ahead crosses an admission. A tick that finds an
+        admission due admits on the host and dispatches the prefill
+        call at once, behind step n and without reading it; then step
+        n+1 behind the call, for the slots of n that continue plus
+        every slot just admitted, whose token is the call's first token
+        as the device array it is, written over n's sampled tokens at
+        the admitted slots' indices on the device (``_merge_tokens``);
+        only then does it read n and emit it, read the call and emit
+        the first tokens, and leave n+1 in flight. The device holds
+        n -> prefill -> n+1 with no host in between. A prefill row
+        that is not finite is quarantined at the readback and its row
+        of n+1 thrown away, like anything else learnt late. With no
+        step in flight (an idle engine) the order is the same less n.
+        What goes ahead is decided here from what the engine sees: a
+        step in flight, slots that continue, an admission due.
 
         The tick is one ``engine.tick`` span cut into ``engine.tick.*``
         phases (docs/observability.md), each also a boundary of the
@@ -1733,20 +1825,7 @@ class InferenceEngine:
         with span("engine.tick", self.tracer, tick=tick):
             with self._phase("engine.tick.sweep"):
                 self._expire(time.monotonic())
-            read = False
-            if flight is not None and self._admission_due():
-                # no token of a running stream waits for a prefill call
-                self._read(flight)
-                flight, read = None, True
-            self._admit()
-            if flight is None:
-                flight = self._dispatch(None)
-            if flight is not None and not read:
-                self._in_flight = self._dispatch(flight)
-                self._read(flight)
-                self._drop_dead_flight()  # its streams may have ended here
-            else:
-                self._in_flight = flight
+            self._tick_device(flight)
             with self._phase("engine.tick.export"):
                 self.metrics.active_slots = sum(
                     s.active for s in self._slots)
@@ -1768,6 +1847,26 @@ class InferenceEngine:
         finished, self._finished_tick = self._finished_tick, []
         return finished
 
+    def _tick_device(self, flight: Optional[_InFlight]) -> None:
+        """What a tick puts on the device and what it reads back, in
+        the order ``step()`` describes: everything is dispatched before
+        anything is read."""
+        admission = self._admit()
+        if admission is not None and flight is not None:
+            self.metrics.prefill_calls_behind_flight += 1
+        if admission is None and flight is None:
+            # slots that hold tokens and no step in flight: fed from
+            # the host (an admission made by hand; nothing, when idle)
+            flight = self._dispatch(None)
+            if flight is None:
+                return
+        self._in_flight = self._dispatch(flight, admission)
+        if flight is not None:
+            self._read(flight)
+        if admission is not None:
+            self._read_admission(admission)
+        self._drop_dead_flight()    # its streams may have ended here
+
     def _live(self, flight: _InFlight) -> List[Tuple[int, Request]]:
         """The slots of a step whose request is the one the step ran
         for: not ended since, not the slot's next tenant."""
@@ -1782,22 +1881,41 @@ class InferenceEngine:
             self._read(flight)
             self._in_flight = None
 
-    def _dispatch(self, before: Optional[_InFlight]) -> Optional[_InFlight]:
+    def _continues(self, i: int, req: Request) -> bool:
+        """Whether slot ``i`` has a step to run after the token that is
+        on its way to it (a step's or a prefill call's, not yet read):
+        that token is the slot's n-th, and these are the conditions
+        ``_emit`` will end it by, known ahead."""
+        slot = self._slots[i]
+        n = slot.generated + 1
+        return n < req.max_new_tokens and slot.position + n < self.max_seq
+
+    def _dispatch(self, before: Optional[_InFlight],
+                  admission: Optional[_Admission] = None
+                  ) -> Optional[_InFlight]:
         """Put the next decode step on the device, then hand over what
         the host held back for that moment. With ``before`` None the
         step is fed from the host: every active slot's last token at
         its position. With ``before`` still on the device it is the
         step after it: the slots of ``before`` that cannot end at it
         by length, one position on, fed ITS sampled tokens without
-        their leaving the device. None when no slot has a step to
+        their leaving the device. With ``admission`` (a prefill call
+        on the device, behind ``before`` if there is one) the step
+        also runs every admitted slot that has more than one token to
+        give, at its prompt's length, fed the call's first token the
+        same way (``_merge_tokens``). None when no slot has a step to
         run."""
         with self._phase("engine.tick.feed"):
             positions = np.zeros(self.max_slots, np.int32)
             active = np.zeros(self.max_slots, bool)
+            fresh = [] if admission is None else [
+                (i, req) for i, req in admission.bound
+                if self._continues(i, req)]
             if before is None:
                 tokens = np.zeros(self.max_slots, np.int32)
-                bound = [(i, s.request)
-                         for i, s in enumerate(self._slots) if s.active]
+                # (an admitted slot has no token on the host yet)
+                bound = [(i, s.request) for i, s in enumerate(self._slots)
+                         if s.active and s.generated]
                 for i, _ in bound:
                     slot = self._slots[i]
                     # feed the last emitted token at its absolute
@@ -1807,16 +1925,19 @@ class InferenceEngine:
                     positions[i] = slot.position + slot.generated - 1
             else:
                 tokens = before.nxt
-                bound = []
-                for i, req in self._live(before):
-                    slot = self._slots[i]
-                    # the token ``before`` samples is the slot's n-th:
-                    # the conditions ``_emit`` will end it by, ahead
-                    n = slot.generated + 1
-                    if (n < req.max_new_tokens
-                            and slot.position + n < self.max_seq):
-                        bound.append((i, req))
-                        positions[i] = before.positions[i] + 1
+                bound = [(i, req) for i, req in self._live(before)
+                         if self._continues(i, req)]
+                for i, _ in bound:
+                    positions[i] = before.positions[i] + 1
+            if fresh:
+                slot_of_row = np.full(
+                    admission.first.shape[0], self.max_slots, np.int32)
+                for i, _ in fresh:
+                    slot_of_row[admission.row_of[i]] = i
+                    positions[i] = self._slots[i].position
+                tokens = self._merge_tokens(
+                    tokens, admission.first, slot_of_row)
+                bound = sorted(bound + fresh, key=lambda b: b[0])
             if not bound:
                 return None
             if self._by_slot:
@@ -1850,7 +1971,7 @@ class InferenceEngine:
             # on a v5e
             del _logits, feed, base_keys
         self.metrics.decode_steps = number
-        if before is not None:
+        if before is not None or admission is not None:
             self.metrics.decode_steps_ahead += 1
         with self._phase("engine.tick.decode_wait"):
             # the device has work: now whatever the consumers of the
@@ -1883,8 +2004,8 @@ class InferenceEngine:
             return
         with self._phase("engine.tick.decode_wait"):
             # about to block: nothing that was emitted waits for it (a
-            # tick that admits reads with no dispatch, and so no
-            # hand-over, before it)
+            # tick whose streams all end at this step dispatched none
+            # after it, and so handed nothing over)
             self._release_tokens()
             if flight.stall > 0:
                 # an injected slow decode is booked where a real one

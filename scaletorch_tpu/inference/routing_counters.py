@@ -67,11 +67,13 @@ def step_counts(counts: Dict[str, "jax.Array"], *, prefill: bool):
 
 class RoutingCounters:
     """Host totals plus the accumulators that are on the device now:
-    ``accumulator``, the result of the newest call, ``_previous``, the
-    one before it, and ``_settled``, two calls back, whose step has
-    ended: the engine's decode loop runs one step ahead, so it has read
-    the tokens of the step two back before it dispatches (the one
-    before may still be on the device). A reader never waits for a step
+    ``accumulator``, the result of the newest call, ``_previous`` and
+    ``_older``, the two before it, and ``_settled``, three calls back,
+    whose step has ended: the engine's decode loop runs one step ahead,
+    across an admission too (step n unread, the prefill call behind it,
+    step n+1 behind the call), so what it has read before it dispatches
+    is the call three back at the latest (the two between may still be
+    on the device). A reader never waits for a step
     in flight: the gateway reads the engine's snapshot on its event
     loop for every request it routes, and a wait there stalls every
     open stream.
@@ -90,7 +92,7 @@ class RoutingCounters:
         self._lock = threading.Lock()
         self._calls = 0
         zeros = np.zeros(len(ROUTING_COUNTERS), np.uint32)
-        self.accumulator = self._previous = self._settled = (
+        self.accumulator = self._previous = self._older = self._settled = (
             jax.device_put(zeros, sharding) if sharding is not None
             else jax.numpy.asarray(zeros))
 
@@ -103,7 +105,8 @@ class RoutingCounters:
         """One call of a counting step: the accumulator goes in last
         and its result takes its place (engine thread)."""
         with self._lock:
-            self._settled, self._previous = self._previous, self.accumulator
+            self._settled, self._older, self._previous = (
+                self._older, self._previous, self.accumulator)
             *results, self.accumulator = step(*args, self._previous)
             self._calls += 1
             if self._calls % READ_EVERY == 0:
@@ -114,11 +117,12 @@ class RoutingCounters:
         """The cumulative counters and the per-step means they give:
         experts touched per decode step and layer, and the fullest
         expert's load over the mean load in the prefill calls. Counts
-        every call that has ended: the newest two as well once their
+        every call that has ended: the newest three as well once their
         results are ready, as they are whenever the engine is idle."""
         with self._lock:
             self._read_device(next(
-                (acc for acc in (self.accumulator, self._previous)
+                (acc for acc in (self.accumulator, self._previous,
+                                 self._older)
                  if acc.is_ready()), self._settled))
             totals = dict(zip(ROUTING_COUNTERS, map(int, self._totals)))
         steps = decode_steps * self.moe_layers

@@ -159,8 +159,9 @@ def test_an_eos_learnt_one_step_late_leaves_the_next_request_right(model):
     """The run-ahead loop has dispatched the slot's next step when the
     eos is read: that step mutates the slot's state for nobody. The
     request queued behind gets the slot, its prefill starts the state
-    from zero after that step (the step in flight is read before an
-    admission's prefill), and its tokens are the oracle's."""
+    from zero after that step (the prefill call is dispatched behind
+    the step in flight, and the device runs them in that order), and
+    its tokens are the oracle's."""
     cfg, params = model
     ending, waiting, other = prompts((11, 19, 7), seed=8)
     free = greedy_by_forward(params, cfg, ending, 12)
@@ -181,28 +182,40 @@ def test_an_eos_learnt_one_step_late_leaves_the_next_request_right(model):
     assert_conserved(eng)
 
 
-def test_a_discarded_step_is_read_before_the_admission_that_follows(model):
-    """Tick by tick: when the eos is emitted a step is in flight for
-    the same slot; the tick that admits the next request holds no
-    step in flight while it prefills."""
+def test_a_discarded_step_is_dispatched_before_the_admission_that_follows(
+        model):
+    """Call by call: when the eos is emitted a step is on the device
+    for the same slot, for nobody; the next request's prefill call is
+    dispatched after it (behind it, unread), so the state that step
+    leaves is what the call starts over from zero."""
     cfg, params = model
     ending, waiting = prompts((11, 19), seed=8)
     eos = first_occurrence(greedy_by_forward(params, cfg, ending, 12), 4)
     eng = make_engine(model, max_slots=1)
     eng.submit(ending, max_new_tokens=12, eos_id=eos)
-    eng.submit(waiting, max_new_tokens=3)
-    seen = []
-    real_prefill = eng._prefill
+    rid = eng.submit(waiting, max_new_tokens=3)
+    calls = []
+    prefill, decode = eng._prefill, eng._decode
 
-    def watched(*args):
-        seen.append(eng._in_flight)
-        return real_prefill(*args)
+    def watched(name, step):
+        def call(*args):
+            calls.append(name)
+            return step(*args)
+        return call
 
-    eng._prefill = watched
-    eng.run()
-    assert len(seen) == 2 and seen == [None, None]
-    assert eng.metrics.decode_slot_steps_discarded >= 1
-    assert eng._state_owner[0] is not None
+    eng._prefill = watched("prefill", prefill)
+    eng._decode = watched("decode", decode)
+    results = eng.run()
+    # the first request: its call, three steps for tokens two to four,
+    # a fourth for nobody; then the second's call and its two steps
+    assert calls == ["prefill"] + ["decode"] * 4 + ["prefill"] + [
+        "decode"] * 2
+    assert eng.metrics.decode_slot_steps_discarded == 1
+    # (a step that runs for nobody is forgotten when its last stream
+    # ends: the engine counts the second call as a cold start)
+    assert eng.metrics.prefill_calls_behind_flight == 0
+    assert results[rid].tokens == greedy_by_forward(params, cfg, waiting, 3)
+    assert eng._state_owner[0] == rid
 
 
 def test_owner_mismatch_counts_a_step_on_another_requests_state(model):

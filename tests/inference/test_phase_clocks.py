@@ -100,12 +100,72 @@ def test_the_three_clocks_sum_to_the_decode_life(tiny_llama):
         # is dispatched, or at once if it was the request's last
         seen = token_times[rid]
         assert life == pytest.approx(seen[-1] - seen[0], abs=5e-3)
-    # the first stream stood still while the second was admitted; the
+    # the first stream stood still while the second was admitted (the
+    # admission built, its call dispatched and waited for: what the
+    # host spent beside it on the two decode steps is not stall); the
     # second's own admission came before its first token
     assert results[first].stall_s > 0
     assert results[second].prefill_s > 0
-    assert results[first].stall_s >= results[second].prefill_s
     assert results[second].stall_s < results[second].prefill_s
+
+
+def test_a_tick_that_admits_is_partitioned_and_times_its_call(
+        tiny_llama, monkeypatch):
+    """A step in flight and an admission due: the tick's phases come in
+    the order docs/observability.md gives (everything dispatched, then
+    everything read), every instant of it is booked to one clock, the
+    wait for the call is stall and the wait for the step in flight
+    device wait, and ``prefill_s`` runs from the call's dispatch to its
+    readback: the next step's dispatch and what was left of the step in
+    flight are inside it."""
+    eng = make_engine(tiny_llama)
+    eng.submit([9, 9], max_new_tokens=3)
+    eng.run()                                 # both steps compiled
+    eng.submit([1, 2, 3], max_new_tokens=24)
+    for _ in range(3):
+        eng.step()
+    assert eng._in_flight is not None
+    rid = eng.submit([4, 5, 6, 7], max_new_tokens=2)
+    names, phases = [], {}
+    real_span, close = engine_module.span, eng._close_tick
+
+    def recording_span(name, *args, **kw):
+        names.append(name)
+        return real_span(name, *args, **kw)
+
+    def closing(*args):
+        phases.update(eng._tick_phase_s)
+        close(*args)
+
+    monkeypatch.setattr(engine_module, "span", recording_span)
+    eng._close_tick = closing
+    t0 = time.monotonic()
+    eng._advance(t0)
+    before = list(eng._clocks)
+    eng.step()
+    t1 = time.monotonic()
+    eng._advance(t1)
+    eng._close_tick = close
+    assert names == [
+        "engine.tick", "engine.tick.sweep", "engine.tick.admit",
+        "engine.tick.prefill",                          # the call, behind n
+        "engine.tick.feed", "engine.tick.decode",       # n+1, behind it
+        "engine.tick.decode_wait",                      # the hand-over
+        "engine.tick.decode_wait", "engine.tick.emit",  # n read
+        "engine.tick.prefill_wait", "engine.tick.emit",  # the call read
+        "engine.tick.export"]
+    assert eng.metrics.prefill_calls_behind_flight == 1
+    spent = [c - c0 for c, c0 in zip(eng._clocks, before)]
+    assert sum(spent) == pytest.approx(t1 - t0, abs=1e-9)
+    stall = sum(phases[f"engine.tick.{name}"] for name in (
+        "sweep", "admit", "prefill", "prefill_wait"))
+    assert spent[engine_module.STALL] == pytest.approx(stall, abs=1e-9)
+    assert spent[engine_module.DEVICE_WAIT] == pytest.approx(
+        phases["engine.tick.decode_wait"], abs=1e-9)
+    prefill_s = eng.run()[rid].prefill_s
+    inside = sum(phases[f"engine.tick.{name}"] for name in (
+        "prefill", "feed", "decode", "decode_wait", "prefill_wait"))
+    assert inside <= prefill_s <= inside + phases["engine.tick.emit"] + 1e-3
 
 
 def test_the_clocks_partition_the_wall_time_with_a_step_in_flight(

@@ -75,6 +75,13 @@ def model(name):
     return family(name)
 
 
+def admit(engine):
+    """An admission on its own, outside a tick: the prefill call
+    dispatched, then read back and its first tokens emitted (a tick
+    dispatches the next decode step between the two)."""
+    engine._read_admission(engine._admit())
+
+
 def prompt(n, seed):
     rng = np.random.default_rng(seed)
     return [int(t) for t in rng.integers(1, 60, size=n)]
@@ -197,7 +204,7 @@ def check_the_call(engine, admitted, tail, shape):
     for n in range(admitted):
         engine.submit(prompt(tail if n == 0 else 1, seed=n),
                       max_new_tokens=2, seed=100 + n)
-    engine._admit()
+    admit(engine)
     (call,) = engine._prefill.calls
     assert call["shape"] == (rows, length)
     holds = [s for s in engine.prefill_shapes
@@ -235,9 +242,9 @@ def test_a_cache_by_slot_still_makes_exactly_the_fixed_shape_call(name, kw):
     assert engine._by_slot
     assert engine.prefill_shapes == ((slots, length),)
     engine.submit(prompt(5, 0), max_new_tokens=2)
-    engine._admit()                 # slot 0 is live
+    admit(engine)                 # slot 0 is live
     engine.submit(prompt(9, 1), max_new_tokens=2)
-    engine._admit()
+    admit(engine)
     first, second = engine._prefill.calls
     assert first["shape"] == second["shape"] == (slots, length)
     assert second["write_mask"].tolist() == \
@@ -290,7 +297,7 @@ def admit_same(engines, prompts, new=3):
         calls = len(engine._prefill.calls)
         for p in prompts:
             engine.submit(p, max_new_tokens=new)
-        engine._admit()
+        admit(engine)
         assert len(engine._prefill.calls) == calls + 1
         went.append(sorted(
             {i for i, s in enumerate(engine._slots) if s.active} - before))
@@ -362,9 +369,9 @@ def test_a_prefix_hit_runs_its_tail_in_a_short_row():
     head = prompt(20, 1)                   # over half: the full shape
     shared = head[:8] + prompt(9, 2)       # two pages shared, a tail of 9
     ids = [ladder.submit(head, max_new_tokens=4)]
-    ladder._admit()
+    admit(ladder)
     ids.append(ladder.submit(shared, max_new_tokens=4))
-    ladder._admit()
+    admit(ladder)
     first, second = ladder._prefill.calls
     assert first["shape"] == (4, 32) and second["shape"] == (1, 16)
     assert second["starts"].tolist() == [8]
@@ -391,7 +398,7 @@ def test_a_sampled_request_does_not_depend_on_the_shape_that_admitted_it():
         for wave in ([7], [3, 12, 5], [30]):
             ids += [engine.submit(prompt(n, n), max_new_tokens=6, seed=40 + n)
                     for n in wave]
-            engine._admit()
+            admit(engine)
         done = engine.run()
         tokens.append([done[i].tokens for i in ids])
     assert tokens[0] == tokens[1]

@@ -2,15 +2,21 @@
 
 With step n on the device the engine dispatches step n+1, fed n's
 sampled tokens as the device array they are, and only then reads n
-back. Quick tier, CPU. What has to hold: the tokens are the plain
-forward's, request for request (tests/inference/oracle.py); what the
-host learns one step late (an eos, a cancel, a TTL, a non-finite row)
-costs one slot-step that is thrown away and never surfaces, in tokens
-or in pages another request reads; one compiled decode program serves
-the steps fed from the host and the steps fed on the device; and the two
-counters say how often the loop ran ahead and what it threw away.
+back. The run-ahead crosses an admission: the prefill call goes behind
+step n, step n+1 behind the call (the admitted slots' first tokens
+merged over n's on the device), and only then is anything read. Quick
+tier, CPU. What has to hold: the tokens are the plain forward's, request
+for request (tests/inference/oracle.py), whatever kind of cache the
+model keeps; what the host learns one step late (an eos, a cancel, a
+TTL, a non-finite row of a step or of a prefill call) costs one
+slot-step that is thrown away and never surfaces, in tokens or in pages
+another request reads; one compiled decode program serves the steps fed
+a step's tokens, a prefill call's, both, or the host's; and the
+counters say how often the loop ran ahead, how many prefill calls went
+behind a step and what was thrown away.
 """
 
+import functools
 import time
 
 import jax
@@ -65,6 +71,21 @@ def greedy(tiny_llama, prompt, n):
     return greedy_by_forward(params, cfg, prompt, n)
 
 
+def count_cold_starts(eng):
+    """A list that gets, for every prefill call the engine dispatches
+    from here on, whether no decode step was on the device then."""
+    cold, tick_device = [], eng._tick_device
+
+    def watched(flight):
+        calls = eng.metrics.prefill_calls
+        tick_device(flight)
+        if eng.metrics.prefill_calls > calls:
+            cold.append(flight is None)
+
+    eng._tick_device = watched
+    return cold
+
+
 def first_occurrence(tokens, k):
     """The k-th token (1-based) of a continuation, usable as an eos id
     only if it does not occur before."""
@@ -98,17 +119,23 @@ class TestSameTokens:
         assert eng.decode_compile_count == 1
         assert_conserved(eng)
 
-    def test_one_compile_serves_both_feeds(self, tiny_llama):
-        """Steps fed from the host (the one after each admission) and
-        steps fed on the device alternate: one call signature."""
+    def test_one_compile_serves_every_feed(self, tiny_llama):
+        """Steps fed a step's tokens, steps fed a prefill call's first
+        tokens merged over them (the one behind each admission) and the
+        step behind a cold start's call alternate: one call signature,
+        and no step is fed from the host."""
         eng = make_engine(tiny_llama, max_slots=2)
+        cold = count_cold_starts(eng)
         for prompt, n in MIXED:
             eng.submit(prompt, max_new_tokens=n)
         eng.run()
         m = eng.metrics
-        assert m.decode_steps_ahead > 0
-        assert m.decode_steps - m.decode_steps_ahead >= 3
+        assert m.decode_steps_ahead == m.decode_steps > 0
+        assert m.prefill_calls >= 4 and cold == [True] + [False] * (
+            m.prefill_calls - 1)
+        assert m.prefill_calls_behind_flight == m.prefill_calls - 1
         assert eng.decode_compile_count == 1
+        assert eng.prefill_compile_count == len(eng.prefill_shapes)
 
     def test_no_program_compiles_for_the_step_fed_on_the_device(
             self, tiny_llama):
@@ -117,9 +144,10 @@ class TestSameTokens:
         device that is not the default one. A caller with host-built
         operands compiles the decode program first, as the benchmark's
         reference check does before it opens its window; after that the
-        engine's own steps, fed from the host and fed on the device,
-        are ONE more call signature and NO backend compile: the
-        sampled tokens go back in as a host-built operand would."""
+        engine's own steps, fed a step's tokens or a prefill call's
+        merged over them on the device, are ONE more call signature and
+        NO backend compile: the sampled tokens go back in as a
+        host-built operand would."""
         cfg, params = tiny_llama
         device = jax.devices()[3]
         mesh = Mesh(np.array([device]), ("tp",))
@@ -164,27 +192,30 @@ class TestSameTokens:
         for (prompt, n), rid in zip(MIXED, ids):
             assert results[rid].tokens == greedy(tiny_llama, prompt, n)
         m = eng.metrics
-        assert m.decode_steps_ahead > 0
-        assert m.decode_steps - m.decode_steps_ahead >= 3
+        assert m.decode_steps_ahead == m.decode_steps > 0
+        assert m.prefill_calls_behind_flight >= 3
 
 
 class TestCounters:
     def test_a_lone_request_by_length(self, tiny_llama):
         """Six tokens: the prefill's and five decode steps'. The first
-        step is fed from the host, the four after it on the device; the
-        sixth token is known to be the last, so no step follows it."""
+        step goes behind the prefill call, fed its first token on the
+        device, the four after it behind one another; the sixth token
+        is known to be the last, so no step follows it."""
         eng = make_engine(tiny_llama)
         rid = eng.submit([1, 2, 3], max_new_tokens=6)
         eng.step()
-        # the tick dispatched steps 1 and 2 and read step 1 back
-        assert eng.metrics.decode_steps == 2
-        assert eng._in_flight is not None and eng._in_flight.number == 2
-        assert len(eng._slots[0].tokens) - 3 == 2
+        # the tick dispatched the call and step 1, and read the call
+        assert eng.metrics.decode_steps == 1
+        assert eng._in_flight is not None and eng._in_flight.number == 1
+        assert len(eng._slots[0].tokens) - 3 == 1
         results = eng.run()
         assert len(results[rid].tokens) == 6
         snap = eng.metrics.snapshot()
         assert snap["decode_steps"] == 5
-        assert snap["decode_steps_ahead"] == 4
+        assert snap["decode_steps_ahead"] == 5
+        assert snap["prefill_calls"] == 1
+        assert snap["prefill_calls_behind_flight"] == 0     # a cold start
         assert snap["decode_slot_steps_discarded"] == 0
         assert eng._in_flight is None
 
@@ -201,7 +232,7 @@ class TestCounters:
         snap = eng.metrics.snapshot()
         # two steps gave tokens two and three; a third ran for nobody
         assert snap["decode_steps"] == 3
-        assert snap["decode_steps_ahead"] == 2
+        assert snap["decode_steps_ahead"] == 3
         assert snap["decode_slot_steps_discarded"] == 1
         assert eng._in_flight is None and eng.pending == 0
 
@@ -273,13 +304,16 @@ class TestLearntLate:
         eng = make_engine(tiny_llama)
         gone = eng.submit([1, 2, 3], max_new_tokens=20)
         stays = eng.submit([9, 8, 7, 6], max_new_tokens=12)
-        for _ in range(3):
+        for _ in range(4):
             eng.step()
         assert {i for i, _ in eng._in_flight.bound} == {0, 1}
         assert eng.cancel(gone)
-        # the freed slot is taken at once: the tick reads the step in
-        # flight first (one row for nobody), then admits
+        # the freed slot is taken at once: the prefill call goes behind
+        # the step in flight (one row of it for nobody), which writes
+        # what it writes before the call does
         late = eng.submit([5, 5], max_new_tokens=5)
+        eng.step()
+        assert eng.metrics.prefill_calls_behind_flight == 1
         results = eng.run()
         want = greedy(tiny_llama, [1, 2, 3], 20)
         assert results[gone].outcome == "aborted"
@@ -295,7 +329,7 @@ class TestLearntLate:
         eng = make_engine(tiny_llama)
         expires = eng.submit([1, 2, 3], max_new_tokens=20, ttl_s=3600.0)
         stays = eng.submit([9, 8, 7, 6], max_new_tokens=9)
-        for _ in range(3):
+        for _ in range(4):      # the prefill call's tick, three steps'
             eng.step()
         assert eng._in_flight is not None
         eng._slots[0].request.deadline = time.monotonic() - 1.0
@@ -328,6 +362,223 @@ class TestLearntLate:
         assert_conserved(eng)
 
 
+# ---- across an admission, whatever the cache keeps by slot -------------------
+
+KINDS = ("paged", "delta_rule", "jamba", "window")
+POISON = 63     # a prompt that holds it has non-finite prefill logits
+
+
+@functools.lru_cache(maxsize=None)
+def model_of(kind):
+    """The toy model each cache kind's own engine tests build."""
+    if kind == "paged":
+        cfg = llama.LlamaConfig(**TINY)
+        return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+    if kind == "delta_rule":        # kv_cache.HybridCache, a matrix state
+        from tests.models.test_olmo_hybrid import seeded_params, tiny_config
+    elif kind == "jamba":           # HybridCache, a selective-scan state
+        from tests.models.test_jamba import seeded_params, tiny_config
+    else:                           # kv_cache.WindowCache: rings by slot
+        from tests.inference.test_afmoe_engine import seeded_params
+        from tests.models.test_afmoe import tiny_config
+    cfg = tiny_config()
+    return cfg, seeded_params(cfg)
+
+
+def engine_of(kind, *, poisonable=False, **kw):
+    """Three slots; a prompt and its tokens stay under the oracle's 32
+    rows. ``poisonable``: the family's cached forward with non-finite
+    logits for a PROMPT that holds ``POISON`` (a decode step's one
+    token never does it)."""
+    cfg, params = model_of(kind)
+    if poisonable:
+        from scaletorch_tpu.inference.decode import resolve_forward_cached
+
+        base = resolve_forward_cached(cfg)
+
+        def forward(params, tokens, cfg, cache, **more):
+            logits, *rest = base(params, tokens, cfg, cache, **more)
+            bad = (tokens.shape[1] > 1) & jnp.any(tokens == POISON, axis=-1)
+            return (jnp.where(bad[:, None, None], jnp.nan, logits), *rest)
+
+        kw["forward_fn"] = forward
+    return InferenceEngine(
+        params, cfg, max_slots=3, max_seq=48, prefill_len=24,
+        sampling=GREEDY, page_size=8, **kw)
+
+
+def ask(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(1, 60, size=n)] for n in lengths]
+
+
+def assert_oracle(kind, results, asked):
+    """``asked``: request id -> (prompt, tokens asked for)."""
+    cfg, params = model_of(kind)
+    for rid, (prompt, n) in asked.items():
+        assert results[rid].outcome == "ok", results[rid]
+        assert results[rid].tokens == greedy_by_forward(
+            params, cfg, prompt, n), (rid, prompt)
+
+
+def assert_settled(eng):
+    """What holds after any of it: one decode program, a prefill
+    program a listed shape at most, no slot that ran on a stranger's
+    state or rings, every page back."""
+    assert eng._in_flight is None and eng.pending == 0
+    assert eng.decode_compile_count == 1
+    assert 1 <= eng.prefill_compile_count <= len(eng.prefill_shapes)
+    snap = eng.metrics.snapshot()
+    assert snap.get("recurrent_state_owner_mismatches", 0) == 0
+    assert snap.get("window_slot_reuse_mismatches", 0) == 0
+    assert_conserved(eng)
+
+
+class Since:
+    """An engine's counters as their change since this was made."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.at = eng.metrics.snapshot()
+
+    def __getitem__(self, name):
+        return self.eng.metrics.snapshot()[name] - self.at[name]
+
+
+@pytest.fixture(scope="module", params=KINDS)
+def served(request):
+    """``(kind, engine)``: ONE engine a cache kind for the cases below,
+    each of which starts and leaves it drained, so that the compile
+    counts at the end of any of them hold after all of it."""
+    return request.param, engine_of(request.param)
+
+
+class TestAcrossAnAdmission:
+    def test_admitted_mid_decode_equals_the_oracle(self, served):
+        """Eight requests over three slots, ticked by hand: every later
+        one is admitted while the others are mid-decode, its prefill
+        call behind their step in flight and its first decode step fed
+        the call's first token on the device. Every request gets the
+        plain forward's tokens; only the first call found no step on
+        the device; no step was fed from the host."""
+        kind, eng = served
+        cold, since = count_cold_starts(eng), Since(eng)
+        lengths = (5, 17, 9, 3, 21, 12, 7, 14)
+        news = (9, 5, 11, 1, 6, 10, 2, 7)
+        asked = {eng.submit(p, max_new_tokens=n): (p, n)
+                 for p, n in zip(ask(lengths), news)}
+        results = {}
+        while eng.pending:
+            for r in eng.step():
+                results[r.request_id] = r
+        assert_oracle(kind, results, asked)
+        calls = since["prefill_calls"]
+        assert calls >= 4 and cold == [True] + [False] * (calls - 1)
+        assert since["prefill_calls_behind_flight"] == calls - 1
+        assert since["decode_steps_ahead"] == since["decode_steps"] > 0
+        assert since["decode_slot_steps_discarded"] == 0
+        # three at once took the full shape, one alone the short row
+        assert eng.prefill_compile_count == len(eng.prefill_shapes)
+        assert_settled(eng)
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_a_prefill_row_that_is_not_finite_behind_a_step(self, kind):
+        """Two streams mid-decode; a poison prompt is admitted into the
+        free slot: its call goes behind step n and step n+1 behind the
+        call, with a row for it, before the call is read. At the
+        readback it is quarantined, that row of n+1 is thrown away, the
+        neighbours' tokens are untouched, and the slot's next tenant
+        starts clean."""
+        eng = engine_of(kind, poisonable=True)
+        a, b, later = ask((6, 11, 8), seed=2)
+        asked = {eng.submit(a, max_new_tokens=14): (a, 14),
+                 eng.submit(b, max_new_tokens=12): (b, 12)}
+        for _ in range(3):
+            eng.step()
+        bad = eng.submit([4, POISON, 7, 9], max_new_tokens=6)
+        eng.step()
+        assert eng.result(bad).outcome == "quarantined"
+        assert eng.result(bad).tokens == []
+        assert "prefill" in eng.result(bad).detail
+        # its row of the step behind the call was dispatched all the same
+        assert {i for i, _ in eng._in_flight.bound} == {0, 1, 2}
+        assert not eng._slots[2].active
+        assert eng.metrics.prefill_calls_behind_flight == 1
+        asked[eng.submit(later, max_new_tokens=7)] = (later, 7)
+        assert_oracle(kind, eng.run(), asked)
+        assert eng.metrics.decode_slot_steps_discarded == 1
+        assert eng.metrics.requests_admitted == 4
+        for buf in eng.cache:
+            assert bool(jnp.all(jnp.isfinite(buf)))
+        assert_settled(eng)
+
+    def test_an_eos_learnt_after_the_admission_beside_it(self, served):
+        """Step n samples a stream's eos. With n unread, a request is
+        admitted into ANOTHER, free slot: its call and step n+1 (a row
+        for the ending stream too) are on the device when the eos is
+        read. That row is thrown away; the admitted request, the other
+        stream and the ended slot's next tenant get the oracle's
+        tokens."""
+        kind, eng = served
+        cfg, params = model_of(kind)
+        since = Since(eng)
+        ending, other, beside, after = ask((7, 12, 10, 5), seed=5)
+        free = greedy_by_forward(params, cfg, ending, 10)
+        k = next(k for k in range(3, 10) if free[k - 1] not in free[:k - 1])
+        a = eng.submit(ending, max_new_tokens=10, eos_id=free[k - 1])
+        asked = {eng.submit(other, max_new_tokens=13): (other, 13)}
+        for _ in range(k - 1):  # the call's tick, then a token a tick
+            eng.step()
+        assert len(eng._slots[0].tokens) - len(ending) == k - 1
+        asked[eng.submit(beside, max_new_tokens=9)] = (beside, 9)
+        (ended,) = eng.step()
+        assert (ended.request_id, ended.finish_reason) == (a, "eos")
+        assert ended.tokens == free[:k]
+        assert since["prefill_calls_behind_flight"] == 1
+        assert {i for i, _ in eng._in_flight.bound} == {0, 1, 2}
+        asked[eng.submit(after, max_new_tokens=6)] = (after, 6)
+        assert_oracle(kind, eng.run(), asked)
+        assert since["decode_slot_steps_discarded"] == 1
+        assert_settled(eng)
+
+    def test_a_request_of_one_token_has_no_row_behind_its_call(
+            self, served):
+        kind, eng = served
+        since = Since(eng)
+        stream, single = ask((9, 13), seed=7)
+        asked = {eng.submit(stream, max_new_tokens=8): (stream, 8)}
+        eng.step()
+        eng.step()
+        one = eng.submit(single, max_new_tokens=1)
+        (done,) = eng.step()
+        assert done.request_id == one
+        asked[one] = (single, 1)
+        assert {i for i, _ in eng._in_flight.bound} == {0}
+        assert_oracle(kind, {**eng.run(), one: done}, asked)
+        assert since["decode_slot_steps_discarded"] == 0
+        assert since["decode_steps_ahead"] == since["decode_steps"]
+        assert_settled(eng)
+
+    def test_two_ticks_in_a_row_that_admit(self, served):
+        kind, eng = served
+        since = Since(eng)
+        first, second, third = ask((8, 15, 4), seed=9)
+        asked = {eng.submit(first, max_new_tokens=12): (first, 12)}
+        eng.step()
+        eng.step()
+        asked[eng.submit(second, max_new_tokens=9)] = (second, 9)
+        eng.step()
+        asked[eng.submit(third, max_new_tokens=10)] = (third, 10)
+        eng.step()
+        assert since["prefill_calls"] == 3
+        assert since["prefill_calls_behind_flight"] == 2
+        assert {i for i, _ in eng._in_flight.bound} == {0, 1, 2}
+        assert_oracle(kind, eng.run(), asked)
+        assert since["decode_steps_ahead"] == since["decode_steps"]
+        assert since["decode_slot_steps_discarded"] == 0
+        assert_settled(eng)
+
+
 class TestStopping:
     def test_drain_finishes_what_is_in_flight(self, tiny_llama):
         eng = make_engine(tiny_llama)
@@ -352,7 +603,7 @@ class TestStopping:
         eng = make_engine(tiny_llama)
         a = eng.submit([1, 2, 3], max_new_tokens=20)
         b = eng.submit([9, 8], max_new_tokens=20)
-        results = eng.run(max_steps=3)
+        results = eng.run(max_steps=4)
         assert results[a].outcome == results[b].outcome == "aborted"
         assert results[a].tokens == greedy(tiny_llama, [1, 2, 3], 20)[:4]
         assert results[b].tokens == greedy(tiny_llama, [9, 8], 20)[:4]

@@ -5,6 +5,7 @@ before its terminal result (PERF.md, PR 27)."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from scaletorch_tpu.inference import InferenceEngine, SamplingParams
@@ -58,15 +59,19 @@ class TestHeldTokens:
         rec.engine = eng
         rid = eng.submit([1, 2, 3], max_new_tokens=6)
         eng.step()
-        # the tick ran a prefill (first token) and a decode step (second):
-        # the first was handed over once the decode step was dispatched,
-        # the second is held until the next one is
+        # the tick dispatched a prefill call and the step behind it and
+        # read the call: its first token is held until the next step
+        # is dispatched
+        assert rec.log == [] and len(eng._held_tokens) == 1
+        eng.step()
+        # the first was handed over once that step was dispatched, the
+        # second (the read of the step behind the call) is held in turn
         assert [e[2] for e in rec.log] == [[eng._slots[0].tokens[3]]]
         assert len(eng._held_tokens) == 1
         # ... and it was handed over after the dispatch phase, before the
-        # host waited: no emit phase of that decode step had run yet
+        # host waited: no emit phase of this tick had run yet
         assert "engine.tick.decode" in rec.log[0][3]
-        assert "engine.tick.export" not in rec.log[0][3]
+        assert "engine.tick.emit" not in rec.log[0][3]
         eng.step()
         assert len(rec.log) == 2 and len(eng._held_tokens) == 1
         results = eng.run()
@@ -83,7 +88,8 @@ class TestHeldTokens:
         rec.engine = eng
         short = eng.submit([1, 2, 3], max_new_tokens=2)
         long = eng.submit([4, 5, 6], max_new_tokens=8)
-        finished = eng.step()  # prefill + one decode: `short` is done
+        assert eng.step() == []     # the prefill call and its read
+        finished = eng.step()       # the step behind it: `short` is done
         assert [r.request_id for r in finished] == [short]
         seen_short = [t for e in rec.log if e[1] == short for t in e[2]]
         assert seen_short == finished[0].tokens
@@ -107,13 +113,15 @@ class TestHeldTokens:
         assert handed[:3] == ("tokens", first, held[0][2])
         assert "engine.tick.prefill" not in handed[3]  # before the call
 
-    def test_an_admissions_read_hands_over_before_it_blocks(
+    def test_no_token_is_held_across_the_wait_for_a_prefill_call(
             self, tiny_llama):
-        """A tick that admits reads the step in flight with no dispatch
-        before it: what the last tick emitted goes out before that wait
-        (held through it, 16 streams' tokens came a whole step late and
-        together with the next: `serve_itl_p95_ms` 13.9 where 9.3 on a
-        v5e), and the read's own tokens before the prefill call."""
+        """A tick that admits dispatches the prefill call and the step
+        behind it before it reads anything. What the last tick emitted
+        goes out before the call is built (held through the tick, 16
+        streams' tokens came a whole step late and together with the
+        next: `serve_itl_p95_ms` 13.9 where 9.3 on a v5e), and what
+        the read of the step in flight emits goes out before the host
+        blocks on the call: the work it runs beside is on the device."""
         rec = Recorder()
         eng = make_engine(tiny_llama, on_tokens=rec.tokens)
         rec.engine = eng
@@ -123,14 +131,19 @@ class TestHeldTokens:
         held = list(eng._held_tokens)
         assert eng._in_flight is not None and len(held) == 1
         before = len(rec.log)
-        eng.submit([7, 8, 9, 10], max_new_tokens=4)
+        second = eng.submit([7, 8, 9, 10], max_new_tokens=4)
         eng.step()
-        early, read = rec.log[before:before + 2]
+        early, read = rec.log[before:]
         assert early[:3] == ("tokens", first, held[0][2])
         assert early[3] == {"engine.tick.sweep"}     # nothing waited yet
+        # the step in flight's token: emitted with the call and the
+        # next step dispatched, handed over before the call is waited for
         assert read[1] == first
-        assert "engine.tick.emit" in read[3]
-        assert "engine.tick.prefill" not in read[3]
+        assert {"engine.tick.prefill", "engine.tick.decode",
+                "engine.tick.emit"} <= read[3]
+        assert "engine.tick.prefill_wait" not in read[3]
+        # the call's own first token waits for the next dispatch
+        assert [h[1] for h in eng._held_tokens] == [second]
 
     def test_on_dispatched_fires_once_per_decode_step_after_the_tokens(
             self, tiny_llama):
@@ -205,7 +218,8 @@ def test_emitted_t_rides_the_held_tuple_unchanged(tiny_llama, release):
     rec.engine = eng
     short = eng.submit([1, 2, 3], max_new_tokens=2)
     long = eng.submit([4, 5, 6], max_new_tokens=8)
-    eng.step()  # prefill + one decode: `short` ended, `long` holds one
+    eng.step()  # the prefill call, read
+    eng.step()  # the step behind it: `short` ended, `long` holds one
     (held,) = eng._held_tokens
     assert held[1] == long and held[3] == eng._slots[1].last_token_t
     # `short`'s two tokens left through its own release, stamps in order
@@ -227,6 +241,32 @@ def test_without_a_hook_nothing_is_held(tiny_llama):
     eng.submit([1, 2, 3], max_new_tokens=4)
     eng.step()
     assert eng._held_tokens == []
+
+
+@pytest.mark.parametrize("seed", [
+    0, 7, 2**31 - 1, 2**31 + 5, 2**32 - 1, 2**32 + 7, 3300486127, -1, -5,
+    2**62 + 11])
+def test_the_host_s_key_is_jax_s(seed):
+    """The words of ``jax.random.PRNGKey`` for any seed a request may
+    carry, made without the device."""
+    from scaletorch_tpu.inference.engine import _host_key
+
+    key = _host_key(seed)
+    assert key.dtype == np.uint32
+    assert (key == np.asarray(jax.random.PRNGKey(seed), np.uint32)).all()
+
+
+def test_an_admission_makes_its_key_without_the_device(tiny_llama):
+    """``_bind_slot`` must not run a program and read it back (an
+    admission behind a step in flight would wait for the step):
+    ``jax.random.PRNGKey`` is not called while a tick admits."""
+    eng = make_engine(tiny_llama)
+    eng.submit([1, 2, 3], max_new_tokens=2, seed=2**31 + 5)
+    want = np.asarray(jax.random.PRNGKey(2**31 + 5), np.uint32)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.delattr(jax.random, "PRNGKey")
+        eng.step()
+    assert (eng._base_keys[0] == want).all()
 
 
 def test_base_keys_are_uploaded_once_per_admission(tiny_llama):

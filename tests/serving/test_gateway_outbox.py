@@ -214,9 +214,10 @@ class TestWorkerHandsOverBesideTheStep:
         for rid in (0, 1):
             idx = [i for i, e in enumerate(log) if e[1] == rid]
             assert log[idx[-1]][0] == "done"
-        # listeners: once per tick, and once when the worker exits
+        # listeners: once per tick (the one that read the prefill call,
+        # then one a decode step), and once when the worker exits
         ticks = [e for e in log if e[0] == "tick"]
-        assert len(ticks) == engine.metrics.decode_steps + 1
+        assert len(ticks) == engine.metrics.decode_steps + 2
 
     def test_a_cancel_that_empties_the_engine_still_delivers(
             self, tiny_llama):
