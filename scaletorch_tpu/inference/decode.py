@@ -56,6 +56,7 @@ from scaletorch_tpu.inference.sampling import (
     sample,
     slot_keys,
 )
+from scaletorch_tpu.models.families import family_of
 
 
 def _resolve_donate(donate_cache: Optional[bool]) -> bool:
@@ -67,61 +68,16 @@ def _resolve_donate(donate_cache: Optional[bool]) -> bool:
 
 
 def resolve_forward_cached(cfg) -> Callable:
-    """The cache-aware forward for a model config: Qwen3-MoE, GPT-MoE,
-    afmoe, Jamba, Olmo-Hybrid and Qwen3-Next (a subclass of the
-    hybrid's: asked first) have their own cached forwards; every other
-    LlamaConfig subclass (Llama, Qwen3) shares the Llama one."""
-    from scaletorch_tpu.models.afmoe import AfmoeConfig
-    from scaletorch_tpu.models.gpt_moe import GPTMoEConfig
-    from scaletorch_tpu.models.jamba import JambaConfig
-    from scaletorch_tpu.models.llama import LlamaConfig
-    from scaletorch_tpu.models.olmo_hybrid import OlmoHybridConfig
-    from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
-    from scaletorch_tpu.models.qwen3_next import Qwen3NextConfig
-
-    if isinstance(cfg, AfmoeConfig):
-        from scaletorch_tpu.models import afmoe
-
-        return afmoe.forward_cached
-    if isinstance(cfg, JambaConfig):
-        from scaletorch_tpu.models import jamba
-
-        return jamba.forward_cached
-    if isinstance(cfg, Qwen3NextConfig):
-        from scaletorch_tpu.models import qwen3_next
-
-        return qwen3_next.forward_cached
-    if isinstance(cfg, OlmoHybridConfig):
-        from scaletorch_tpu.models import olmo_hybrid
-
-        return olmo_hybrid.forward_cached
-    if isinstance(cfg, Qwen3MoEConfig):
-        from scaletorch_tpu.models import qwen3_moe
-
-        return qwen3_moe.forward_cached
-    if isinstance(cfg, LlamaConfig):
-        from scaletorch_tpu.models import llama
-
-        return llama.forward_cached
-    if isinstance(cfg, GPTMoEConfig):
-        from scaletorch_tpu.models import gpt_moe
-
-        return gpt_moe.forward_cached
-    raise TypeError(
-        f"no cache-aware forward known for config {type(cfg).__name__}"
-    )
+    """The cache-aware forward of a config's family
+    (``models/families.py``, by the config's exact class)."""
+    return family_of(cfg).module.forward_cached
 
 
 def counts_routing(cfg) -> bool:
     """Whether the config's cached forward counts what it routes
-    (``return_routing``): the Qwen3-MoE family, OLMoE included, afmoe,
-    and Qwen3-Next, whose steps take the row mask of a state-carrying
-    model and the routing accumulator side by side."""
-    from scaletorch_tpu.models.afmoe import AfmoeConfig
-    from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
-    from scaletorch_tpu.models.qwen3_next import Qwen3NextConfig
-
-    return isinstance(cfg, (Qwen3MoEConfig, Qwen3NextConfig, AfmoeConfig))
+    (``return_routing``); such a step takes the row mask of a
+    state-carrying model and the routing accumulator side by side."""
+    return family_of(cfg).counts_routing
 
 
 def prefill_shapes(max_slots: int,
@@ -596,17 +552,23 @@ def teacher_forced_decode_paged(
     tables = (np.arange(b * max_pages, dtype=np.int32) + 1).reshape(
         b, max_pages)
     kv_io = PagedKVIO(jnp.asarray(tables), page_size, seq_limit=s_max)
-    p = prefill_len
-    positions = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
-    logits_p, pool = fwd(params, tokens[:, :p], cfg, tuple(pool),
-                         positions=positions, kv_io=kv_io)
-    chunks = [logits_p]
+    return _teacher_forced(fwd, params, cfg, tokens, pool, prefill_len,
+                           kv_io=kv_io)
+
+
+def _teacher_forced(fwd, params, cfg, tokens, cache, p, **io) -> jax.Array:
+    """The schedule of both harnesses: the first ``p`` tokens in one
+    call, then the rest one at a time at their positions."""
+    b, s = tokens.shape
+    logits, cache = fwd(
+        params, tokens[:, :p], cfg, tuple(cache), **io,
+        positions=jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p)))
+    chunks = [logits]
     for t in range(p, s):
-        logits_t, pool = fwd(
-            params, tokens[:, t:t + 1], cfg, tuple(pool),
-            positions=jnp.full((b, 1), t, jnp.int32), kv_io=kv_io,
-        )
-        chunks.append(logits_t)
+        logits, cache = fwd(
+            params, tokens[:, t:t + 1], cfg, tuple(cache), **io,
+            positions=jnp.full((b, 1), t, jnp.int32))
+        chunks.append(logits)
     return jnp.concatenate(chunks, axis=1)
 
 
@@ -777,15 +739,4 @@ def teacher_forced_decode(
     b, s = tokens.shape
     cache = init_kv_cache(cfg, b, max_seq or s,
                           dtype=dtype or getattr(cfg, "dtype", None))
-    p = prefill_len
-    positions = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
-    logits_p, cache = fwd(params, tokens[:, :p], cfg, tuple(cache),
-                          positions=positions)
-    chunks = [logits_p]
-    for t in range(p, s):
-        logits_t, cache = fwd(
-            params, tokens[:, t:t + 1], cfg, tuple(cache),
-            positions=jnp.full((b, 1), t, jnp.int32),
-        )
-        chunks.append(logits_t)
-    return jnp.concatenate(chunks, axis=1)
+    return _teacher_forced(fwd, params, cfg, tokens, cache, prefill_len)

@@ -220,6 +220,38 @@ class AfmoeConfig(ExpertShare, LlamaConfig):
                 + v * h + h + (0 if self.tie_word_embeddings else v * h))
 
 
+def config_from_args(args, common: dict) -> AfmoeConfig:
+    """The published config.json names; the window is
+    ``sliding_window_size`` among the launch arguments."""
+    if args.mlp_only_layers or (args.decoder_sparse_step or 1) != 1:
+        raise NotImplementedError(
+            "afmoe with mlp_only_layers / decoder_sparse_step: its "
+            "dense layers are the leading num_dense_layers "
+            "(models/afmoe.py)")
+    if args.moe_dispatch != "auto" or args.moe_capacity_factor != 1.25:
+        raise NotImplementedError(
+            "afmoe under capacity dispatch (--moe_dispatch "
+            f"{args.moe_dispatch}, --moe_capacity_factor "
+            f"{args.moe_capacity_factor}): the family routes dropless "
+            "(qwen3_moe.dropless_mlp) and no capacity path is written "
+            "for a sigmoid router")
+    return AfmoeConfig(**{
+        **common,
+        "num_routed_experts": args.num_routed_experts,
+        "first_expert_id": args.first_expert_id,
+        "layer_types": args.layer_types,
+        "num_experts": args.num_experts,
+        "num_experts_per_tok": args.num_experts_per_tok,
+        "moe_intermediate_size": args.moe_intermediate_size
+        or common["intermediate_size"],
+        "sliding_window": args.sliding_window_size,
+        **{name: getattr(args, name) for name in (
+            "global_attn_every_n_layers",
+            "num_dense_layers", "num_shared_experts", "score_func",
+            "route_norm", "route_scale", "n_group", "topk_group",
+            "mup_enabled")}})
+
+
 def init_params(key: jax.Array, cfg: AfmoeConfig) -> Params:
     """Random init: fan-in uniform projections and experts, the router
     normal(0.02), the embedding normal(``cfg.embed_init_std``) (0.02 as

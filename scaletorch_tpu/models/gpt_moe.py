@@ -74,6 +74,14 @@ class GPTMoEConfig:
         return self.n_embd // self.n_head
 
 
+def config_from_args(args, common: dict) -> GPTMoEConfig:
+    """The examples' tier (lenet, mingpt): its own main builds a config."""
+    raise ValueError(
+        f"model_type {args.model_type!r} trains via its example: "
+        "examples/mnist/train_mnist.py (lenet) or "
+        "examples/mingpt/train_mingpt.py (gpt_moe/mingpt)")
+
+
 def init_params(key: jax.Array, cfg: GPTMoEConfig) -> Params:
     l, d, v = cfg.n_layer, cfg.n_embd, cfg.vocab_size
     e, i = cfg.num_experts, 4 * cfg.n_embd

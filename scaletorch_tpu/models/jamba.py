@@ -214,6 +214,24 @@ class JambaConfig(LlamaConfig):
 QK_INIT_SCALE = 2.0
 
 
+def config_from_args(args, common: dict) -> JambaConfig:
+    """The published config.json names; no rotary embedding and no key
+    for one."""
+    if args.num_experts != 1 or args.num_experts_per_tok != 1:
+        raise NotImplementedError(
+            f"jamba with num_experts {args.num_experts} / "
+            f"num_experts_per_tok {args.num_experts_per_tok}: every "
+            "layer's feed-forward is the dense SwiGLU MLP of "
+            "models/jamba.py (Jamba2's num_experts 1); the routed "
+            "layers of the larger Jambas are not written")
+    return JambaConfig(**{
+        **common, "rope_theta": None,
+        **{name: getattr(args, name) for name in (
+            "attn_layer_period", "attn_layer_offset", "mamba_d_state",
+            "mamba_d_conv", "mamba_expand", "mamba_dt_rank",
+            "mamba_conv_bias", "mamba_proj_bias")}})
+
+
 def init_params(key: jax.Array, cfg: JambaConfig) -> Params:
     """Random init: fan-in uniform projections (the depthwise
     convolution's weight and bias at its fan-in, the kernel width),

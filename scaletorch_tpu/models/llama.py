@@ -752,6 +752,15 @@ def forward_cached(
     return logits, cache
 
 
+def config_from_args(args, common: dict) -> LlamaConfig:
+    """The family's arm of ``families.build_model_config``."""
+    return LlamaConfig(**common)
+
+
+def config_from_hf(args, hf_config, overrides: dict) -> LlamaConfig:
+    return LlamaConfig.from_hf(hf_config, **overrides)
+
+
 class Llama:
     """Thin OO veneer matching the reference's ``Llama`` class API
     (llama.py:476+) over the functional init/forward pair."""

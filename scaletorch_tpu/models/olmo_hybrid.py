@@ -198,6 +198,21 @@ class OlmoHybridConfig(LlamaConfig):
                 + v * h + h + (0 if self.tie_word_embeddings else v * h))
 
 
+def config_from_args(args, common: dict) -> OlmoHybridConfig:
+    """The published config.json names; ``rope_parameters`` carries the
+    family's rope_theta (null: no rotary embedding)."""
+    rope = ({} if args.rope_parameters is None
+            else {"rope_theta": args.rope_parameters.get("rope_theta")})
+    return OlmoHybridConfig(**{
+        **common, **rope,
+        "layer_types": (None if args.layer_types is None
+                        else tuple(args.layer_types)),
+        **{name: getattr(args, name) for name in (
+            "linear_num_key_heads", "linear_num_value_heads",
+            "linear_key_head_dim", "linear_value_head_dim",
+            "linear_conv_kernel_dim", "linear_allow_neg_eigval")}})
+
+
 def init_params(key: jax.Array, cfg: OlmoHybridConfig) -> Params:
     """Random init: fan-in uniform projections, ones for norm gains,
     normal(0.02) embedding. The decay's own parameters as the rule's

@@ -23,10 +23,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-import jax
-
 from scaletorch_tpu.models import qwen3_moe as _moe
-from scaletorch_tpu.models.llama import Params
 from scaletorch_tpu.models.qwen3_moe import Qwen3MoEConfig
 
 
@@ -66,17 +63,30 @@ class OlmoeConfig(Qwen3MoEConfig):
         return super().from_hf(hf_config, **kw)
 
 
-def init_params(key: jax.Array, cfg: OlmoeConfig) -> Params:
-    return _moe.init_params(key, cfg)
+# the Qwen3-MoE functions themselves: the differences ride the config
+init_params = _moe.init_params
+forward = _moe.forward
+forward_cached = _moe.forward_cached
 
 
-def forward(params: Params, input_ids: jax.Array, cfg: OlmoeConfig, **kw):
-    return _moe.forward(params, input_ids, cfg, **kw)
+def config_from_args(args, common: dict) -> OlmoeConfig:
+    """The published config.json names: ``intermediate_size`` is the
+    expert width (OLMoE has no dense MLP)."""
+    return OlmoeConfig(
+        moe_intermediate_size=common["intermediate_size"],
+        num_experts=args.num_experts,
+        num_experts_per_tok=args.num_experts_per_tok,
+        aux_loss_coef=args.router_aux_loss_coef,
+        z_loss_coef=args.router_z_loss_coef,
+        **_moe.expert_share_from_args(args),
+        **common,
+    )
 
 
-def forward_cached(params: Params, input_ids: jax.Array, cfg: OlmoeConfig,
-                   cache, **kw):
-    return _moe.forward_cached(params, input_ids, cfg, cache, **kw)
+def config_from_hf(args, hf_config, overrides: dict) -> OlmoeConfig:
+    return OlmoeConfig.from_hf(
+        hf_config, z_loss_coef=args.router_z_loss_coef,
+        **_moe.expert_share_from_args(args), **overrides)
 
 
 class Olmoe(_moe.Qwen3MoE):

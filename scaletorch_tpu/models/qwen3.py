@@ -34,18 +34,23 @@ class Qwen3Config(LlamaConfig):
 
 
 def init_params(key: jax.Array, cfg: Qwen3Config) -> Params:
+    # a function of THIS module: the benchmark harness takes a config's
+    # initialiser from the module of its class and holds the two to one
     return _llama.init_params(key, cfg)
 
 
-def forward(params: Params, input_ids: jax.Array, cfg: Qwen3Config, **kw):
-    return _llama.forward(params, input_ids, cfg, **kw)
+# the Llama forwards themselves (qk_norm rides the config flag): a step
+# built for either family traces one function
+forward = _llama.forward
+forward_cached = _llama.forward_cached
 
 
-def forward_cached(params: Params, input_ids: jax.Array, cfg: Qwen3Config,
-                   cache, **kw):
-    """KV-cached forward (llama.forward_cached; qk_norm rides the config
-    flag) — the decode-engine entry point for the Qwen3 family."""
-    return _llama.forward_cached(params, input_ids, cfg, cache, **kw)
+def config_from_args(args, common: dict) -> Qwen3Config:
+    return Qwen3Config(qk_norm=True, **common)
+
+
+def config_from_hf(args, hf_config, overrides: dict) -> Qwen3Config:
+    return Qwen3Config.from_hf(hf_config, **overrides)
 
 
 class Qwen3(_llama.Llama):

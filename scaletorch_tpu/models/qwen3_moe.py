@@ -304,6 +304,57 @@ class Qwen3MoEConfig(ExpertShare, Qwen3Config):
         )
 
 
+def expert_share_from_args(args) -> dict:
+    """What the launch arguments say of the router and of a chip's share
+    of the expert layer (``ExpertShare``), for every family that routes.
+    Whether the top-k weights are renormalised is the architecture's (HF
+    ``norm_topk_prob``): None keeps the family's."""
+    keys = dict(num_routed_experts=args.num_routed_experts,
+                first_expert_id=args.first_expert_id)
+    if args.norm_topk_prob is not None:
+        keys["norm_topk_prob"] = args.norm_topk_prob
+    return keys
+
+
+def _training_keys(args) -> dict:
+    """Capacity and loss coefficients: in no HF config, so they come
+    from the arguments beside either source of the architecture."""
+    return dict(capacity_factor=args.moe_capacity_factor,
+                moe_dispatch=args.moe_dispatch,
+                aux_loss_coef=args.router_aux_loss_coef,
+                z_loss_coef=args.router_z_loss_coef,
+                **expert_share_from_args(args))
+
+
+def config_from_args(args, common: dict) -> Qwen3MoEConfig:
+    return Qwen3MoEConfig(
+        qk_norm=True,
+        num_experts=args.num_experts,
+        num_experts_per_tok=args.num_experts_per_tok,
+        moe_intermediate_size=args.moe_intermediate_size
+        or common["intermediate_size"],
+        mlp_only_layers=tuple(
+            i for i in (args.mlp_only_layers or ()) if i >= 0),
+        decoder_sparse_step=args.decoder_sparse_step or 1,
+        **_training_keys(args), **common)
+
+
+def config_from_hf(args, hf_config, overrides: dict) -> Qwen3MoEConfig:
+    """The interleaved-architecture knobs: an EXPLICIT argument
+    overrides the HF config (``--decoder_sparse_step 1`` forces
+    uniform-sparse, e.g. to re-enable PP); None keeps the checkpoint's.
+    A single -1 clears ``mlp_only_layers`` (nargs='+' cannot say an
+    empty list)."""
+    arch = {}
+    if args.mlp_only_layers is not None:
+        arch["mlp_only_layers"] = tuple(
+            i for i in args.mlp_only_layers if i >= 0)
+    if args.decoder_sparse_step is not None:
+        arch["decoder_sparse_step"] = args.decoder_sparse_step
+    return Qwen3MoEConfig.from_hf(
+        hf_config, **arch, **_training_keys(args), **overrides)
+
+
 def shared_expert_params(cfg) -> int:
     """Parameters of one layer's shared expert and, where the
     configuration gates it, its gate."""

@@ -158,6 +158,32 @@ class Qwen3NextConfig(ExpertShare, OlmoHybridConfig):
                 + v * h + h + (0 if self.tie_word_embeddings else v * h))
 
 
+def config_from_args(args, common: dict) -> Qwen3NextConfig:
+    """The published config.json names."""
+    if args.mlp_only_layers or (args.decoder_sparse_step or 1) != 1:
+        raise NotImplementedError(
+            "qwen3_next with dense-MLP layers (mlp_only_layers "
+            f"{args.mlp_only_layers}, decoder_sparse_step "
+            f"{args.decoder_sparse_step}): every layer's MLP is the "
+            "sparse one in models/qwen3_next.py")
+    return Qwen3NextConfig(**{
+        **common, **_moe.expert_share_from_args(args),
+        "layer_types": (None if args.layer_types is None
+                        else tuple(args.layer_types)),
+        "num_experts": args.num_experts,
+        "num_experts_per_tok": args.num_experts_per_tok,
+        "moe_intermediate_size": args.moe_intermediate_size
+        or common["intermediate_size"],
+        "aux_loss_coef": args.router_aux_loss_coef,
+        "z_loss_coef": args.router_z_loss_coef,
+        **{name: getattr(args, name) for name in (
+            "full_attention_interval", "partial_rotary_factor",
+            "shared_expert_intermediate_size",
+            "linear_num_key_heads", "linear_num_value_heads",
+            "linear_key_head_dim", "linear_value_head_dim",
+            "linear_conv_kernel_dim")}})
+
+
 def init_params(key: jax.Array, cfg: Qwen3NextConfig) -> Params:
     """Random init: fan-in uniform projections and experts, router
     normal(0.02), the embedding normal(``cfg.embed_init_std``) (0.02 as

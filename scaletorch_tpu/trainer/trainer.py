@@ -13,12 +13,11 @@ import time
 from typing import Any, Dict, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
 from scaletorch_tpu.config import ScaleTorchTPUArguments
-from scaletorch_tpu.models import llama, qwen3
+from scaletorch_tpu.models.families import FAMILIES, build_model_config
 from scaletorch_tpu.models.registry import resolve_attention_backend
 from scaletorch_tpu.parallel.mesh import MeshManager, setup_mesh_manager
 from scaletorch_tpu.telemetry.spans import span
@@ -27,242 +26,6 @@ from scaletorch_tpu.trainer.optimizer import create_optimizer
 from scaletorch_tpu.utils.device import device_report
 from scaletorch_tpu.utils.logger import get_logger
 from scaletorch_tpu.utils.misc import get_num_params, set_all_seed, to_readable_format
-
-_DTYPE = {"bfloat16": jnp.bfloat16, "float32": jnp.float32, "float16": jnp.float16}
-
-
-def build_model_config(cfg: ScaleTorchTPUArguments):
-    """model_type dispatch (reference model_builder.py:68-74), with HF
-    AutoConfig auto-fill when model_name_or_path is set."""
-    from scaletorch_tpu.models import olmoe, qwen3_moe
-
-    dtype = _DTYPE[cfg.dtype]
-    overrides = dict(dtype=dtype, param_dtype=_DTYPE[cfg.param_dtype])
-    # whether the top-k router weights are renormalised is the
-    # architecture's (HF ``norm_topk_prob``); None keeps the family's
-    moe_arch = ({} if cfg.norm_topk_prob is None
-                else {"norm_topk_prob": cfg.norm_topk_prob})
-    # a chip's share of the expert layer (qwen3_moe.ExpertShare)
-    moe_arch.update(num_routed_experts=cfg.num_routed_experts,
-                    first_expert_id=cfg.first_expert_id)
-    if cfg.embed_init_std is not None and cfg.model_type not in (
-            "qwen3_next", "afmoe", "jamba"):
-        raise NotImplementedError(
-            f"--embed_init_std with model_type {cfg.model_type!r}: only "
-            "qwen3_next's, afmoe's and jamba's initialisers read it "
-            "(models/qwen3_next.py, models/afmoe.py, models/jamba.py)")
-    if cfg.model_type == "jamba" and cfg.model_name_or_path:
-        raise NotImplementedError(
-            "jamba from --model_name_or_path: HF config auto-fill and "
-            "weight loading are not written for this family; give its "
-            "sizes by their config.json names (models/presets.py "
-            "jamba2-3b)")
-    if cfg.model_type == "afmoe" and cfg.model_name_or_path:
-        raise NotImplementedError(
-            "afmoe from --model_name_or_path: HF config auto-fill and "
-            "weight loading are not written for this family; give its "
-            "sizes by their config.json names (models/presets.py "
-            "trinity-mini)")
-    if cfg.model_type == "qwen3_next" and cfg.model_name_or_path:
-        raise NotImplementedError(
-            "qwen3_next from --model_name_or_path: HF config auto-fill "
-            "and weight loading are not written for this family; give "
-            "its sizes by their config.json names (models/presets.py "
-            "qwen3-next-80b-a3b)")
-    if cfg.model_name_or_path:
-        from transformers import AutoConfig
-
-        hf = AutoConfig.from_pretrained(cfg.model_name_or_path)
-        if cfg.model_type == "qwen3_moe":
-            # training knobs (capacity, loss coefs) are not in HF configs —
-            # thread the CLI values through alongside the architecture fields
-            # interleaved-architecture knobs: EXPLICIT CLI values override
-            # the HF config (including --decoder_sparse_step 1 to force
-            # uniform-sparse, e.g. to re-enable PP); omitted (None) keeps
-            # the checkpoint's architecture. A single -1 clears
-            # mlp_only_layers (nargs='+' cannot express an empty list).
-            arch = {}
-            if cfg.mlp_only_layers is not None:
-                arch["mlp_only_layers"] = tuple(
-                    i for i in cfg.mlp_only_layers if i >= 0)
-            if cfg.decoder_sparse_step is not None:
-                arch["decoder_sparse_step"] = cfg.decoder_sparse_step
-            return qwen3_moe.Qwen3MoEConfig.from_hf(
-                hf,
-                capacity_factor=cfg.moe_capacity_factor,
-                moe_dispatch=cfg.moe_dispatch,
-                aux_loss_coef=cfg.router_aux_loss_coef,
-                z_loss_coef=cfg.router_z_loss_coef,
-                **arch,
-                **moe_arch,
-                **overrides,
-            )
-        if cfg.model_type == "olmoe":
-            return olmoe.OlmoeConfig.from_hf(
-                hf, z_loss_coef=cfg.router_z_loss_coef, **moe_arch,
-                **overrides)
-        if cfg.model_type == "qwen3":
-            return qwen3.Qwen3Config.from_hf(hf, **overrides)
-        return llama.LlamaConfig.from_hf(hf, **overrides)
-
-    common = dict(
-        vocab_size=cfg.vocab_size,
-        hidden_size=cfg.hidden_size,
-        intermediate_size=cfg.intermediate_size or 4 * cfg.hidden_size,
-        num_hidden_layers=cfg.num_hidden_layers,
-        num_attention_heads=cfg.num_attention_heads,
-        num_key_value_heads=cfg.num_key_value_heads or cfg.num_attention_heads,
-        head_dim=cfg.head_dim,
-        max_position_embeddings=cfg.max_position_embeddings,
-        rope_theta=cfg.rope_theta,
-        rms_norm_eps=cfg.rms_norm_eps,
-        tie_word_embeddings=cfg.tie_word_embeddings,
-        **overrides,
-    )
-    if cfg.model_type == "qwen3_moe":
-        return qwen3_moe.Qwen3MoEConfig(
-            qk_norm=True,
-            num_experts=cfg.num_experts,
-            num_experts_per_tok=cfg.num_experts_per_tok,
-            moe_intermediate_size=cfg.moe_intermediate_size
-            or (cfg.intermediate_size or 4 * cfg.hidden_size),
-            capacity_factor=cfg.moe_capacity_factor,
-            moe_dispatch=cfg.moe_dispatch,
-            mlp_only_layers=tuple(
-                i for i in (cfg.mlp_only_layers or ()) if i >= 0),
-            decoder_sparse_step=cfg.decoder_sparse_step or 1,
-            aux_loss_coef=cfg.router_aux_loss_coef,
-            z_loss_coef=cfg.router_z_loss_coef,
-            **moe_arch,
-            **common,
-        )
-    if cfg.model_type == "olmoe":
-        # the published config.json names: ``intermediate_size`` is the
-        # expert width (OLMoE has no dense MLP); the q/k norm's scope,
-        # the unnormalised top-k and the dropless routing are the
-        # family's (models/olmoe.py)
-        return olmoe.OlmoeConfig(
-            moe_intermediate_size=common["intermediate_size"],
-            num_experts=cfg.num_experts,
-            num_experts_per_tok=cfg.num_experts_per_tok,
-            aux_loss_coef=cfg.router_aux_loss_coef,
-            z_loss_coef=cfg.router_z_loss_coef,
-            **moe_arch,
-            **common,
-        )
-    if cfg.model_type == "olmo_hybrid":
-        from scaletorch_tpu.models import olmo_hybrid
-
-        # the published config.json names; ``rope_parameters`` carries
-        # the family's rope_theta (null: no rotary embedding), the
-        # reordered norm and the whole-width q/k norm are the family's
-        # (models/olmo_hybrid.py)
-        rope = ({} if cfg.rope_parameters is None
-                else {"rope_theta": cfg.rope_parameters.get("rope_theta")})
-        return olmo_hybrid.OlmoHybridConfig(**{
-            **common, **rope,
-            "layer_types": (None if cfg.layer_types is None
-                            else tuple(cfg.layer_types)),
-            **{name: getattr(cfg, name) for name in (
-                "linear_num_key_heads", "linear_num_value_heads",
-                "linear_key_head_dim", "linear_value_head_dim",
-                "linear_conv_kernel_dim", "linear_allow_neg_eigval")}})
-    if cfg.model_type == "qwen3_next":
-        from scaletorch_tpu.models import qwen3_next
-
-        if cfg.mlp_only_layers or (cfg.decoder_sparse_step or 1) != 1:
-            raise NotImplementedError(
-                "qwen3_next with dense-MLP layers (mlp_only_layers "
-                f"{cfg.mlp_only_layers}, decoder_sparse_step "
-                f"{cfg.decoder_sparse_step}): every layer's MLP is the "
-                "sparse one in models/qwen3_next.py")
-        # the published config.json names (models/qwen3_next.py)
-        return qwen3_next.Qwen3NextConfig(**{
-            **common, **moe_arch,
-            "layer_types": (None if cfg.layer_types is None
-                            else tuple(cfg.layer_types)),
-            "num_experts": cfg.num_experts,
-            "num_experts_per_tok": cfg.num_experts_per_tok,
-            "moe_intermediate_size": cfg.moe_intermediate_size
-            or common["intermediate_size"],
-            "aux_loss_coef": cfg.router_aux_loss_coef,
-            "z_loss_coef": cfg.router_z_loss_coef,
-            **({} if cfg.embed_init_std is None
-               else {"embed_init_std": cfg.embed_init_std}),
-            **{name: getattr(cfg, name) for name in (
-                "full_attention_interval", "partial_rotary_factor",
-                "shared_expert_intermediate_size",
-                "linear_num_key_heads", "linear_num_value_heads",
-                "linear_key_head_dim", "linear_value_head_dim",
-                "linear_conv_kernel_dim")}})
-    if cfg.model_type == "afmoe":
-        from scaletorch_tpu.models import afmoe
-
-        if cfg.mlp_only_layers or (cfg.decoder_sparse_step or 1) != 1:
-            raise NotImplementedError(
-                "afmoe with mlp_only_layers / decoder_sparse_step: its "
-                "dense layers are the leading num_dense_layers "
-                "(models/afmoe.py)")
-        if cfg.moe_dispatch != "auto" or cfg.moe_capacity_factor != 1.25:
-            raise NotImplementedError(
-                "afmoe under capacity dispatch (--moe_dispatch "
-                f"{cfg.moe_dispatch}, --moe_capacity_factor "
-                f"{cfg.moe_capacity_factor}): the family routes dropless "
-                "(qwen3_moe.dropless_mlp) and no capacity path is written "
-                "for a sigmoid router")
-        # the published config.json names (models/afmoe.py)
-        return afmoe.AfmoeConfig(**{
-            **common,
-            "num_routed_experts": cfg.num_routed_experts,
-            "first_expert_id": cfg.first_expert_id,
-            "layer_types": (None if cfg.layer_types is None
-                            else tuple(cfg.layer_types)),
-            "num_experts": cfg.num_experts,
-            "num_experts_per_tok": cfg.num_experts_per_tok,
-            "moe_intermediate_size": cfg.moe_intermediate_size
-            or common["intermediate_size"],
-            **({} if cfg.embed_init_std is None
-               else {"embed_init_std": cfg.embed_init_std}),
-            "sliding_window": cfg.sliding_window_size,
-            **{name: getattr(cfg, name) for name in (
-                "global_attn_every_n_layers",
-                "num_dense_layers", "num_shared_experts", "score_func",
-                "route_norm", "route_scale", "n_group", "topk_group",
-                "mup_enabled")}})
-    if cfg.model_type == "jamba":
-        from scaletorch_tpu.models import jamba
-
-        if cfg.num_experts != 1 or cfg.num_experts_per_tok != 1:
-            raise NotImplementedError(
-                f"jamba with num_experts {cfg.num_experts} / "
-                f"num_experts_per_tok {cfg.num_experts_per_tok}: every "
-                "layer's feed-forward is the dense SwiGLU MLP of "
-                "models/jamba.py (Jamba2's num_experts 1); the routed "
-                "layers of the larger Jambas are not written")
-        # the published config.json names; no rotary embedding and no
-        # key for one (models/jamba.py)
-        return jamba.JambaConfig(**{
-            **common, "rope_theta": None,
-            **({} if cfg.embed_init_std is None
-               else {"embed_init_std": cfg.embed_init_std}),
-            **{name: getattr(cfg, name) for name in (
-                "attn_layer_period", "attn_layer_offset", "mamba_d_state",
-                "mamba_d_conv", "mamba_expand", "mamba_dt_rank",
-                "mamba_conv_bias", "mamba_proj_bias")}})
-    if cfg.model_type == "qwen3":
-        return qwen3.Qwen3Config(qk_norm=True, **common)
-    if cfg.model_type == "llama":
-        return llama.LlamaConfig(**common)
-    if cfg.model_type in ("lenet", "gpt_moe", "mingpt"):
-        # These are the examples-tier models (reference
-        # examples/torch_examples/{mnist,minigpt}) — they have their own
-        # training mains rather than the LLM Trainer's seq/CE pipeline.
-        raise ValueError(
-            f"model_type {cfg.model_type!r} trains via its example: "
-            "examples/mnist/train_mnist.py (lenet) or "
-            "examples/mingpt/train_mingpt.py (gpt_moe/mingpt)"
-        )
-    raise ValueError(f"unknown model_type {cfg.model_type!r}")
 
 
 def build_dataloader(cfg: ScaleTorchTPUArguments, model_cfg,
@@ -331,30 +94,11 @@ class Trainer:
     """End-to-end training driver (reference train.py main + loop)."""
 
     def __init__(self, cfg: ScaleTorchTPUArguments):
-        if cfg.model_type in ("olmo_hybrid", "qwen3_next"):
+        row = FAMILIES.get(cfg.model_type)
+        if row is not None and row.untrained:
             raise NotImplementedError(
                 f"the trainer has no step for model_type {cfg.model_type!r}"
-                ": its state-carrying layers have no sharding rules (tp / "
-                "cp / pp / ep), no loss wiring and no HF weight loading; "
-                "the family is served (scripts/serve.py --preset ...)")
-        if cfg.model_type == "jamba":
-            raise NotImplementedError(
-                "the trainer has no step for model_type 'jamba': its "
-                "selective scan has no backward (the Mosaic kernel is "
-                "forward only, and the chunked XLA form under jax.grad "
-                "keeps every chunk's state), its Mamba layers have no "
-                "sharding rules (tp / cp / pp), and there is no loss "
-                "wiring and no HF weight loading; the family is served "
-                "(scripts/serve.py --preset jamba2-3b)")
-        if cfg.model_type == "afmoe":
-            raise NotImplementedError(
-                "the trainer has no step for model_type 'afmoe': its "
-                "window layers and its sigmoid router have no sharding "
-                "rules (tp / cp / pp / ep), load_balance_coeff names a "
-                "loss and a bias update whose equations its config.json "
-                "does not give, and there is no HF weight loading; the "
-                "family is served (scripts/serve.py --preset "
-                "trinity-mini)")
+                f": {row.untrained}")
         self.cfg = cfg
         self.logger = get_logger(log_file=cfg.log_file,
                                  log_format=cfg.log_format)
@@ -495,12 +239,19 @@ class Trainer:
         # calls outside a Trainer.
 
         from scaletorch_tpu.parallel.spmd import batch_specs, shard_params
-        from scaletorch_tpu.parallel.tensor_parallel import validate_tp_divisibility
+        from scaletorch_tpu.parallel.tensor_parallel import (
+            llama_param_specs,
+            validate_tp_divisibility,
+        )
 
         if cfg.tensor_parallel_size > 1:
             validate_tp_divisibility(self.model_cfg, cfg.tensor_parallel_size)
 
-        is_moe = cfg.model_type in ("qwen3_moe", "olmoe")
+        # the routing families that train (build_model_config and the
+        # refusal above let no other through)
+        is_moe = row.counts_routing
+        init_fn, fwd_fn = row.module.init_params, row.module.forward
+        param_specs = model_kwargs = head_weight_fn = None
         if is_moe:
             from scaletorch_tpu.models import qwen3_moe
             from scaletorch_tpu.parallel.expert_parallel import (
@@ -509,7 +260,6 @@ class Trainer:
 
             if cfg.expert_parallel_size > 1:
                 validate_ep_divisibility(self.model_cfg, cfg.expert_parallel_size)
-            init_fn, fwd_fn = qwen3_moe.init_params, qwen3_moe.forward
             param_specs = qwen3_moe.qwen3_moe_param_specs(
                 self.model_cfg,
                 tp_axis="tp",
@@ -521,11 +271,15 @@ class Trainer:
                 "return_moe_stats": True,
             }
             head_weight_fn = qwen3_moe.lm_head_weight
-        else:
-            init_fn, fwd_fn = llama.init_params, llama.forward
-            param_specs = None
-            model_kwargs = None
-            head_weight_fn = None
+
+        def layout_specs():
+            """The parameter layout, as HF loading and Adafactor read it
+            (``param_specs=None`` is the step's own Llama default)."""
+            if param_specs is not None:
+                return param_specs
+            return llama_param_specs(
+                self.model_cfg, tp_axis="tp",
+                pp_axis="pp" if cfg.pipeline_parallel_size > 1 else None)
 
         key = set_all_seed(cfg.seed)
         if cfg.load_pretrained_weights:
@@ -542,21 +296,9 @@ class Trainer:
             # at a time — host memory stays bounded by one layer even for
             # 30B-class models (reference per-stage/per-rank subset
             # loading, checkpoint.py:265-423).
-            if param_specs is not None:
-                specs_for_load = param_specs
-            else:
-                from scaletorch_tpu.parallel.tensor_parallel import (
-                    llama_param_specs,
-                )
-
-                specs_for_load = llama_param_specs(
-                    self.model_cfg,
-                    tp_axis="tp",
-                    pp_axis="pp" if cfg.pipeline_parallel_size > 1 else None,
-                )
             load_shardings = jax.tree.map(
                 lambda s: NamedSharding(self.mm.mesh, s),
-                specs_for_load,
+                layout_specs(),
                 is_leaf=lambda x: isinstance(x, PartitionSpec),
             )
             params_host = load_hf_params(
@@ -611,20 +353,8 @@ class Trainer:
         # Adafactor additionally needs the param layout + mesh sizes so its
         # factored statistics reduce across sharded dims (trainer/factored.py).
         if cfg.optimizer_name.lower() == "adafactor":
-            if param_specs is not None:
-                opt_specs_in = param_specs
-            else:
-                from scaletorch_tpu.parallel.tensor_parallel import (
-                    llama_param_specs,
-                )
-
-                opt_specs_in = llama_param_specs(
-                    self.model_cfg,
-                    tp_axis="tp",
-                    pp_axis="pp" if cfg.pipeline_parallel_size > 1 else None,
-                )
             self.tx, self.schedule = create_optimizer(
-                cfg, include_clip=False, param_specs=opt_specs_in,
+                cfg, include_clip=False, param_specs=layout_specs(),
                 axis_sizes=dict(self.mm.mesh.shape),
             )
         else:

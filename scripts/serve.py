@@ -137,11 +137,9 @@ def parse_args(argv=None) -> argparse.Namespace:
 def build_model(args):
     """(cfg, params) — deterministic for a preset + a seed. Every preset
     but 'tiny' goes through the program's one dispatch from launch
-    arguments to a model (``trainer.build_model_config``, what
+    arguments to a model (``models.families.build_model_config``, what
     ``train.py`` and the benchmark harness use), so whatever family that
     knows can be served."""
-    import sys
-
     import jax
     import jax.numpy as jnp
 
@@ -152,8 +150,8 @@ def build_model(args):
         params = llama.init_params(jax.random.PRNGKey(args.param_seed), cfg)
         return cfg, params
     from scaletorch_tpu.config import ScaleTorchTPUArguments
+    from scaletorch_tpu.models.families import build_model_config, family_of
     from scaletorch_tpu.models.presets import preset
-    from scaletorch_tpu.trainer.trainer import build_model_config
 
     # serving holds the weights in the compute dtype: the fp32 master
     # copy is a training concern, and decode reads every weight per token
@@ -163,11 +161,10 @@ def build_model(args):
         from scaletorch_tpu.utils.hf_interop import load_hf_params
 
         return cfg, load_hf_params(args.model_name_or_path, cfg)
-    # the initialiser of the module that defines the config's class, as
-    # one program: drawn piece by piece, a 4 GB expert stack is held in
-    # float32 beside its bf16 cast and the published sizes do not fit
-    init = jax.jit(sys.modules[type(cfg).__module__].init_params,
-                   static_argnums=1)
+    # the family's initialiser as one program: drawn piece by piece, a
+    # 4 GB expert stack is held in float32 beside its bf16 cast and the
+    # published sizes do not fit
+    init = jax.jit(family_of(cfg).module.init_params, static_argnums=1)
     return cfg, init(jax.random.PRNGKey(args.param_seed), cfg)
 
 

@@ -21,42 +21,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from scaletorch_tpu.models import (
-    afmoe,
-    gpt_moe,
-    jamba,
-    llama,
-    olmo_hybrid,
-    qwen3_moe,
-    qwen3_next,
-)
+from scaletorch_tpu.models import jamba, olmo_hybrid, qwen3_next
+from scaletorch_tpu.models.families import family_of
 
 # of the largest |logit| of the step: float32 order-of-summation noise
 # measured on these presets is under 1e-6; a wrong token is off by 1e-2
 TIE_RTOL = 1e-5
 
+# the oracle's own: a recurrence as its definition, row after row, not
+# the chunked form the engine's prefill runs
+AS_ITS_DEFINITION = {
+    qwen3_next: {"sequential": True},
+    olmo_hybrid: {"sequential": True},
+    jamba: {"scan": "sequential"},
+}
+
 
 def plain_forward(cfg):
     """The full-sequence forward of a config's family (the training
     forward, not the cache-aware one)."""
-    if isinstance(cfg, qwen3_next.Qwen3NextConfig):
-        return functools.partial(qwen3_next.forward, sequential=True)
-    if isinstance(cfg, olmo_hybrid.OlmoHybridConfig):
-        # the delta rule as its definition, not the chunked form the
-        # engine's prefill runs
-        return functools.partial(olmo_hybrid.forward, sequential=True)
-    if isinstance(cfg, afmoe.AfmoeConfig):
-        return afmoe.forward
-    if isinstance(cfg, jamba.JambaConfig):
-        # the selective scan as its definition, not the chunked form
-        return functools.partial(jamba.forward, scan="sequential")
-    if isinstance(cfg, qwen3_moe.Qwen3MoEConfig):   # OLMoE included
-        return qwen3_moe.forward
-    if isinstance(cfg, llama.LlamaConfig):          # Llama, Qwen3
-        return llama.forward
-    if isinstance(cfg, gpt_moe.GPTMoEConfig):
-        return gpt_moe.forward
-    raise TypeError(f"no plain forward known for {type(cfg).__name__}")
+    module = family_of(cfg).module
+    return functools.partial(module.forward,
+                             **AS_ITS_DEFINITION.get(module, {}))
 
 
 @functools.lru_cache(maxsize=None)
@@ -67,10 +53,11 @@ def _jitted(cfg):
 
 def last_logits(params, cfg, seq, *, pad_to=32):
     """float32 logits [V] after ``seq``, from one plain forward over the
-    whole sequence. It sits at the head of a zero-padded buffer (one
-    compile per config, not per length): under the causal mask the row
-    read never sees the padding."""
-    buf = np.zeros((1, max(pad_to, len(seq))), np.int32)
+    whole sequence. It sits at the head of a buffer zero-padded to a
+    multiple of ``pad_to`` (one compile per config and per 32 lengths,
+    not per length): under the causal mask the row read never sees the
+    padding."""
+    buf = np.zeros((1, -(-max(len(seq), 1) // pad_to) * pad_to), np.int32)
     buf[0, :len(seq)] = seq
     logits = _jitted(cfg)(params, jnp.asarray(buf))
     return np.asarray(logits[0, len(seq) - 1], np.float32)

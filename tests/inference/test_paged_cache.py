@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from scaletorch_tpu.inference.decode import (
+    resolve_forward_cached,
     teacher_forced_decode,
     teacher_forced_decode_paged,
 )
@@ -36,6 +37,7 @@ from scaletorch_tpu.ops.pallas.paged_attention import (
     pallas_paged_decode_attention,
     pallas_paged_write,
 )
+from tests.inference.compiled import compiled_forward_cached
 
 TINY = dict(
     vocab_size=64, hidden_size=32, intermediate_size=64,
@@ -531,11 +533,12 @@ class TestTeacherForcedPagedParity:
         params = init(jax.random.PRNGKey(0), cfg)
         ids = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
                                  cfg.vocab_size)
+        fwd = compiled_forward_cached(resolve_forward_cached(cfg), cfg)
         dense = teacher_forced_decode(params, cfg, ids, max_seq=16,
-                                      prefill_len=5)
+                                      prefill_len=5, forward_fn=fwd)
         paged = teacher_forced_decode_paged(
             params, cfg, ids, page_size=page_size, max_seq=16,
-            prefill_len=5)
+            prefill_len=5, forward_fn=fwd)
         np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                    **PAGED_DENSE_LOGIT_TOL)
 
@@ -582,6 +585,8 @@ class TestKernelPairThroughTheForwards:
             (np.arange(2 * max_pages, dtype=np.int32) + 1).reshape(
                 2, max_pages))
 
+        fwd = compiled_forward_cached(mod.forward_cached, cfg)
+
         def run(kernel):
             io = PagedKVIO(tables, page, seq_limit=16, kernel=kernel,
                            interpret=True)
@@ -589,12 +594,12 @@ class TestKernelPairThroughTheForwards:
                 cfg, 2 * max_pages + 1, page, dtype=jnp.float32))
             positions = jnp.broadcast_to(jnp.arange(6, dtype=jnp.int32),
                                          (2, 6))
-            out, pool = mod.forward_cached(
+            out, pool = fwd(
                 params, ids[:, :6], cfg, pool, positions=positions,
                 kv_io=io)
             chunks = [out]
             for t in range(6, 9):
-                out, pool = mod.forward_cached(
+                out, pool = fwd(
                     params, ids[:, t:t + 1], cfg, pool,
                     positions=jnp.full((2, 1), t, jnp.int32), kv_io=io)
                 chunks.append(out)

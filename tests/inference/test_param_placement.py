@@ -32,6 +32,7 @@ from scaletorch_tpu.inference.decode import (
     make_paged_decode_step,
     make_paged_prefill_step,
     place_params,
+    resolve_forward_cached,
     teacher_forced_decode_paged,
 )
 from scaletorch_tpu.inference.kv_cache import (
@@ -39,6 +40,7 @@ from scaletorch_tpu.inference.kv_cache import (
     init_paged_kv_cache,
 )
 from scaletorch_tpu.models import llama
+from tests.inference.compiled import compiled_forward_cached
 from tests.models.test_olmo_hybrid import seeded_params, tiny_config
 
 GREEDY = SamplingParams(temperature=0.0)
@@ -168,7 +170,9 @@ def teacher_forced_greedy(model, prompt, tokens):
     seq = jnp.asarray([list(prompt) + list(tokens[:-1])], jnp.int32)
     logits = teacher_forced_decode_paged(
         params, cfg, seq, page_size=SHAPES["page_size"],
-        max_seq=SHAPES["max_seq"], prefill_len=len(prompt), forward_fn=fwd)
+        max_seq=SHAPES["max_seq"], prefill_len=len(prompt),
+        forward_fn=compiled_forward_cached(
+            fwd or resolve_forward_cached(cfg), cfg))
     return [int(t) for t in np.argmax(
         np.asarray(logits[0, len(prompt) - 1:], np.float32), axis=-1)]
 
@@ -284,7 +288,7 @@ def test_the_tokens_are_those_of_the_steps_on_the_caller_s_arrays(
         tokens = results[rid].tokens
         assert len(tokens) == n
         assert tokens == bare_steps_greedy(model, prompt, n)
-        if i < 2:   # the harness compiles anew for every prompt's length
+        if i < 2:   # the harness's prefill compiles anew for every length
             assert tokens == teacher_forced_greedy(model, prompt, tokens)
 
 

@@ -18,7 +18,6 @@ from benchmarks.reference import trinity as reference
 from scaletorch_tpu.inference.decode import (
     counts_routing,
     make_fill_slots_step,
-    resolve_forward_cached,
 )
 from scaletorch_tpu.inference.kv_cache import (
     PagedKVIO,
@@ -33,6 +32,7 @@ from scaletorch_tpu.inference.kv_cache import (
 from scaletorch_tpu.models import afmoe, qwen3_moe
 from scaletorch_tpu.models.layers import rms_norm
 from scaletorch_tpu.models.presets import preset
+from tests.inference.compiled import compiled_forward_cached
 
 # the tiny preset: two periods of (three window layers, one full), a
 # window of 24 tokens, 2 leading dense layers, 8 of 16 routed experts
@@ -83,6 +83,12 @@ def _close(got, want, rtol=RTOL_OF_MAX):
         np.abs(got - want).max(), np.abs(want).max())
 
 
+# the uncached forward as one program: run operation by operation, the
+# period loop is traced and dispatched piece by piece
+_forward = jax.jit(afmoe.forward, static_argnums=2,
+                   static_argnames=("return_hidden",))
+
+
 def _ref_layer(params, index):
     return {k: v[index].astype(F32)
             for k, v in params["layers"]["block"].items()}
@@ -103,7 +109,6 @@ def test_the_program_builds_the_family_from_its_published_keys(model):
     assert cfg.shared_expert_intermediate_size == 32
     assert window_of(cfg) == 24 and not carries_state(cfg)
     assert counts_routing(cfg)
-    assert resolve_forward_cached(cfg) is afmoe.forward_cached
     assert "window-attention layers" in no_prefix_reason(cfg)
     n = sum(x.size for x in jax.tree.leaves(params))
     assert n == cfg.num_params()
@@ -370,7 +375,7 @@ def test_forward_against_the_reference(model):
     cfg, params = model
     tokens = _tokens((2, 64))
     rows = np.broadcast_to(np.arange(64), (2, 64))
-    _close(afmoe.forward(params, jnp.asarray(tokens), cfg),
+    _close(_forward(params, jnp.asarray(tokens), cfg),
            _reference_logits(params, tokens, rows))
 
 
@@ -420,11 +425,18 @@ def _paged(cfg, slots, max_seq):
     return cache, jnp.asarray(tables)
 
 
+def _cached(cfg):
+    """``afmoe.forward_cached`` compiled (one program a shape): a loop
+    of decode steps is one compile and as many calls, not the period
+    loop dispatched operation by operation."""
+    return compiled_forward_cached(afmoe.forward_cached, cfg)
+
+
 def _prefill(cfg, params, cache, tables, buf, lens, write=None):
     slots, width = buf.shape
     write = np.ones(slots, bool) if write is None else write
     rows = np.arange(width)[None]
-    logits, cache, counts = afmoe.forward_cached(
+    logits, cache, counts = _cached(cfg)(
         params, jnp.asarray(buf), cfg, tuple(cache),
         positions=jnp.broadcast_to(jnp.arange(width, dtype=jnp.int32),
                                    (slots, width)),
@@ -437,7 +449,7 @@ def _prefill(cfg, params, cache, tables, buf, lens, write=None):
 def _decode(cfg, params, cache, tables, feed, positions, active=None):
     slots = len(feed)
     active = np.ones(slots, bool) if active is None else active
-    logits, cache, counts = afmoe.forward_cached(
+    logits, cache, counts = _cached(cfg)(
         params, jnp.asarray(feed)[:, None], cfg, tuple(cache),
         positions=jnp.asarray(positions, jnp.int32)[:, None],
         write_mask=jnp.asarray(active), kv_io=PagedKVIO(tables, PAGE),
@@ -464,7 +476,7 @@ def test_prefill_then_decode_across_the_window_and_the_ring(model):
     lens = np.array([10, 30, 48], np.int32)
     depth, slots, width = 40, 3, 48
     tokens = _tokens((slots, width + depth), seed=3)
-    full = afmoe.forward(params, jnp.asarray(tokens), cfg)
+    full = _forward(params, jnp.asarray(tokens), cfg)
     rows = np.broadcast_to(np.arange(width + depth), tokens.shape)
     _close(full, _reference_logits(params, tokens, rows))
     cache, tables = _paged(cfg, slots, width + depth)
@@ -649,6 +661,6 @@ def test_the_output_norm_is_applied_before_the_residual(model):
         block[name] = jnp.zeros_like(block[name])
     muted = dict(params, layers=dict(params["layers"], block=block))
     tokens = jnp.asarray(_tokens((1, 16), seed=6))
-    got = afmoe.forward(muted, tokens, cfg, return_hidden=True)
+    got = _forward(muted, tokens, cfg, return_hidden=True)
     x = params["embed_tokens"][tokens] * 8.0          # sqrt(64)
     _close(got, rms_norm(x, params["norm"], cfg.rms_norm_eps), rtol=1e-6)

@@ -13,13 +13,9 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import jamba as reference
-from scaletorch_tpu.inference.decode import (
-    resolve_forward_cached,
-    teacher_forced_decode_paged,
-)
+from scaletorch_tpu.inference.decode import teacher_forced_decode_paged
 from scaletorch_tpu.inference.kv_cache import (
     HybridCache,
-    PagedKVIO,
     carries_state,
     init_paged_kv_cache,
     recurrent_state_bytes,
@@ -28,6 +24,7 @@ from scaletorch_tpu.models import jamba
 from scaletorch_tpu.models.jamba import ATTENTION, MAMBA, JambaConfig
 from scaletorch_tpu.models.olmo_hybrid import short_conv
 from scaletorch_tpu.ops.pallas import ssm_scan
+from tests.inference.compiled import compiled_forward_cached
 from tests.models.test_olmo_hybrid import _err_of_max, _paged_cache
 
 # the published key names at toy widths: two periods of (mamba,
@@ -91,21 +88,6 @@ def reference_logits(model, tokens):
     return logits(), logits
 
 
-def _jitted_forward(cfg, page_size, seq_limit):
-    """``forward_cached`` as one compiled program per shape, in the
-    harnesses' ``forward_fn`` form."""
-    @jax.jit
-    def run(params, toks, cache, positions, tables):
-        return jamba.forward_cached(
-            params, toks, cfg, cache, positions=positions,
-            kv_io=PagedKVIO(tables, page_size, seq_limit=seq_limit))
-
-    def fwd(params, toks, _cfg, cache, *, positions, kv_io=None):
-        return run(params, toks, cache, positions, kv_io.page_tables)
-
-    return fwd
-
-
 # ---- the dispatch ------------------------------------------------------------
 
 def test_published_keys_build_the_two_kinds_in_order(model):
@@ -129,7 +111,6 @@ def test_published_keys_build_the_two_kinds_in_order(model):
     assert "q_norm" not in attn
     n = sum(x.size for x in jax.tree.leaves(params))
     assert n == cfg.num_params()
-    assert resolve_forward_cached(cfg) is jamba.forward_cached
 
 
 def test_the_published_keys_put_attention_at_layers_7_and_21():
@@ -339,7 +320,7 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
     with jax.default_matmul_precision("highest"):
         paged = teacher_forced_decode_paged(
             params, cfg, tokens, page_size=8, prefill_len=prefill_len,
-            forward_fn=_jitted_forward(cfg, 8, tokens.shape[1]))
+            forward_fn=compiled_forward_cached(jamba.forward_cached, cfg))
     assert _err_of_max(paged, ref) < RTOL_OF_MAX
 
 

@@ -12,7 +12,6 @@ import pytest
 
 from benchmarks.reference import olmo_hybrid as reference
 from scaletorch_tpu.inference.decode import (
-    resolve_forward_cached,
     teacher_forced_decode,
     teacher_forced_decode_paged,
 )
@@ -26,6 +25,7 @@ from scaletorch_tpu.inference.kv_cache import (
 )
 from scaletorch_tpu.models import olmo_hybrid
 from scaletorch_tpu.models.olmo_hybrid import FULL, LINEAR, OlmoHybridConfig
+from tests.inference.compiled import compiled_forward_cached
 
 # the published key names at toy widths: two periods of (3 linear, 1
 # full); key width 8, value width 16 (the published 96 / 192 ratio)
@@ -90,24 +90,6 @@ def reference_logits(model, tokens):
     return logits(), logits
 
 
-def _jitted_forward(cfg, page_size=None, seq_limit=None):
-    """``forward_cached`` as one compiled program per shape, in the
-    harnesses' ``forward_fn`` form (run op by op, the layer loop's
-    closure compiles anew at every one of 150 positions)."""
-    @jax.jit
-    def run(params, toks, cache, positions, tables):
-        kv_io = None if tables is None else PagedKVIO(
-            tables, page_size, seq_limit=seq_limit)
-        return olmo_hybrid.forward_cached(
-            params, toks, cfg, cache, positions=positions, kv_io=kv_io)
-
-    def fwd(params, toks, _cfg, cache, *, positions, kv_io=None):
-        return run(params, toks, cache, positions,
-                   None if kv_io is None else kv_io.page_tables)
-
-    return fwd
-
-
 def _err_of_max(system, ref):
     return float(jnp.max(jnp.abs(system - ref)) / jnp.max(jnp.abs(ref)))
 
@@ -132,7 +114,6 @@ def test_published_keys_build_the_two_kinds_in_order(model):
     assert "input_layernorm" not in full     # the block norms its output
     n = sum(x.size for x in jax.tree.leaves(params))
     assert n == cfg.num_params()
-    assert resolve_forward_cached(cfg) is olmo_hybrid.forward_cached
     assert carries_state(cfg)
 
 
@@ -170,7 +151,8 @@ def test_any_period_of_both_kinds_is_served():
     with jax.default_matmul_precision("highest"):
         cached = teacher_forced_decode(
             params, cfg, toks, prefill_len=21,
-            forward_fn=_jitted_forward(cfg))
+            forward_fn=compiled_forward_cached(
+                olmo_hybrid.forward_cached, cfg))
     assert _err_of_max(cached, ref) < RTOL_OF_MAX
 
 
@@ -277,10 +259,12 @@ def test_prefill_then_decode_through_the_cache_is_the_reference(
     with jax.default_matmul_precision("highest"):
         dense = teacher_forced_decode(
             params, cfg, tokens, prefill_len=prefill_len,
-            forward_fn=_jitted_forward(cfg))
+            forward_fn=compiled_forward_cached(
+                olmo_hybrid.forward_cached, cfg))
         paged = teacher_forced_decode_paged(
             params, cfg, tokens, page_size=16, prefill_len=prefill_len,
-            forward_fn=_jitted_forward(cfg, 16, tokens.shape[1]))
+            forward_fn=compiled_forward_cached(
+                olmo_hybrid.forward_cached, cfg))
     assert _err_of_max(dense, ref) < RTOL_OF_MAX
     assert _err_of_max(paged, ref) < RTOL_OF_MAX
     np.testing.assert_allclose(paged, dense, atol=1e-5)

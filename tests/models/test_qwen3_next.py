@@ -17,7 +17,6 @@ import pytest
 from benchmarks.reference import qwen3_next as reference
 from scaletorch_tpu.inference.decode import (
     counts_routing,
-    resolve_forward_cached,
     teacher_forced_decode,
     teacher_forced_decode_paged,
 )
@@ -29,6 +28,7 @@ from scaletorch_tpu.inference.kv_cache import (
 from scaletorch_tpu.models import layers, olmo_hybrid, qwen3_moe, qwen3_next
 from scaletorch_tpu.models.presets import preset
 from scaletorch_tpu.ops.grouped_matmul import dropless_expert_mlp
+from tests.inference.compiled import compiled_forward_cached
 
 # the tiny preset: two periods, 2 key heads over 4 value heads, a
 # quarter-rotary 32-wide head, 8 of 16 routed experts held from id 4
@@ -96,26 +96,6 @@ def _forward(cfg, **kw):
     return run
 
 
-def _jitted_forward_cached(cfg, page_size=None, seq_limit=None):
-    """``forward_cached`` as one compiled program per shape, in the
-    harnesses' ``forward_fn`` form."""
-    from scaletorch_tpu.inference.kv_cache import PagedKVIO
-
-    @jax.jit
-    def run(params, toks, cache, positions, tables):
-        kv_io = None if tables is None else PagedKVIO(
-            tables, page_size, seq_limit=seq_limit)
-        with jax.default_matmul_precision("highest"):
-            return qwen3_next.forward_cached(
-                params, toks, cfg, cache, positions=positions, kv_io=kv_io)
-
-    def fwd(params, toks, _cfg, cache, *, positions, kv_io=None):
-        return run(params, toks, cache, positions,
-                   None if kv_io is None else kv_io.page_tables)
-
-    return fwd
-
-
 def _reference_logits(keys, params, tokens, wrong=None):
     rows = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None], tokens.shape)
     return reference.make_logits_fn(
@@ -156,7 +136,6 @@ def test_the_preset_is_the_published_pattern_and_a_share():
     assert tiny_config(WHOLE).holds_every_expert
     assert cfg.sparse_layer_ids() == tuple(range(8))
     assert carries_state(cfg) and counts_routing(cfg)
-    assert resolve_forward_cached(cfg) is qwen3_next.forward_cached
     assert cfg.recurrent_state_shapes(3) == ((6, 3, 4, 8, 16),
                                              (6, 3, 3, 2 * 16 + 64))
 
@@ -486,18 +465,22 @@ def test_the_chunked_forward_is_the_row_by_row_forward(model, tokens):
 def test_prefill_then_decode_through_the_cache_matches_the_reference(
         model, tokens, reference_logits, prefill_len):
     _, cfg, params = model
-    cached = teacher_forced_decode(
-        params, cfg, tokens, prefill_len=prefill_len,
-        forward_fn=_jitted_forward_cached(cfg))
+    with jax.default_matmul_precision("highest"):
+        cached = teacher_forced_decode(
+            params, cfg, tokens, prefill_len=prefill_len,
+            forward_fn=compiled_forward_cached(
+                qwen3_next.forward_cached, cfg))
     assert _err_of_max(cached, reference_logits) < RTOL_OF_MAX
 
 
 def test_the_paged_pool_matches_the_reference(model, tokens,
                                               reference_logits):
     _, cfg, params = model
-    paged = teacher_forced_decode_paged(
-        params, cfg, tokens, page_size=8, prefill_len=13,
-        forward_fn=_jitted_forward_cached(cfg, page_size=8, seq_limit=40))
+    with jax.default_matmul_precision("highest"):
+        paged = teacher_forced_decode_paged(
+            params, cfg, tokens, page_size=8, prefill_len=13,
+            forward_fn=compiled_forward_cached(
+                qwen3_next.forward_cached, cfg))
     assert _err_of_max(paged, reference_logits) < RTOL_OF_MAX
     pool = init_paged_kv_cache(cfg, 7, 8, dtype=jnp.float32, slots=3)
     assert isinstance(pool, HybridCache)

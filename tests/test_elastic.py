@@ -332,6 +332,14 @@ class TestElasticDrills:
                 # nobody relaunches anything in this drill: the parked
                 # host must give up in bounded time, not block the test
                 t.elastic.join_timeout = 3.0
+            # "no grow boundary ever admits it" is the drill's premise,
+            # not an accident of who is faster: under load the survivors
+            # may still be at a checkpoint boundary when host 2 wakes
+            # and parks, and an open boundary would lawfully readmit it
+            # (epoch 2, no abort). Every host skips the boundary alike
+            # (host 2 too, a member until it hangs), so the bus carries
+            # the same collectives on each.
+            t._maybe_elastic_grow = lambda: None
             t.coordinator = CoordinatedResilience(
                 t.resilience, bus=t.elastic.bus)
             t.train()
