@@ -395,6 +395,48 @@ def leg_kernels(dry_run: bool) -> dict:
                    write_case(8, write_pages, (write_pages // 2) * page)]
     log(f"paged-write parity: {json.dumps(paged_write)}")
 
+    # ---- the latent decode kernel vs the gathered form ------------------
+    # 128 heads on ONE cached head of 640 (512 + 64 in whole tiles), the
+    # value its first 512 columns: slots at position 0, at a page's
+    # edges and deep into the table
+    from scaletorch_tpu.ops.pallas.paged_attention import latent_attention
+
+    l_pages = 6 if dry_run else 216
+    l_heads, l_row, l_value = (8, 128, 64) if dry_run else (128, 640, 512)
+    l_pool = 0.5 * normal((2, 8 * l_pages + 1, 1, page, l_row))
+    l_q = 0.2 * normal((8, l_heads, l_row))
+    l_tables = jnp.asarray(1 + rng.permutation(8 * l_pages).reshape(
+        8, l_pages), jnp.int32)
+    last = l_pages * page - 1
+    l_positions = jnp.asarray(
+        [0, page - 1, page, last // 2, last // 2 + 1, last - page, last - 1,
+         last], jnp.int32)
+    l_kw = dict(layer=jnp.int32(1), value_width=l_value, scale=0.0722)
+    l_got = jax.jit(lambda *a: latent_attention(
+        *a, kernel=True, interpret=interpret, **l_kw))(
+            l_q, l_pool, l_tables, l_positions)
+    l_want = jax.jit(lambda *a: latent_attention(*a, kernel=False, **l_kw))(
+        l_q, l_pool, l_tables, l_positions)
+    latent = {"max_abs_err": max_abs(l_got - l_want),
+              "max_abs": max_abs(l_want)}
+    log(f"latent-decode parity: {json.dumps(latent)}")
+    check(latent["max_abs_err"] <= 0.02 * latent["max_abs"],
+          f"latent_decode differs from the gathered form: {latent}")
+
+    # ---- the flash forward at a value width of its own ------------------
+    from scaletorch_tpu.ops.flash_attention import prefill_self_attention
+
+    fq, fk = 0.3 * normal((1, hq, seq, 192)), 0.3 * normal((1, hq, seq, 192))
+    fv = 0.3 * normal((1, hq, seq, 128))
+    f_got = jax.jit(prefill_self_attention)(fq, fk, fv)
+    f_want = jax.jit(sdpa_attention)(fq, fk, fv)
+    flash_192_128 = {"max_abs_err": max_abs(f_got - f_want),
+                     "max_abs": max_abs(f_want)}
+    log(f"flash 192/128 parity: {json.dumps(flash_192_128)}")
+    check(flash_192_128["max_abs_err"] <= 0.02 * flash_192_128["max_abs"],
+          f"the flash forward at keys 192 / values 128 differs from SDPA: "
+          f"{flash_192_128}")
+
     # ---- the dispatchers pick the kernels iff the platform is tpu ------
     lowered = {
         "flash": jax.jit(flash_attention).lower(q, k, v).as_text(),
@@ -412,6 +454,7 @@ def leg_kernels(dry_run: bool) -> dict:
     return {"device": device, "flash": flash, "paged_decode": paged,
             "paged_decode_serving": paged_serving,
             "paged_write": paged_write,
+            "latent_decode": latent, "flash_192_128": flash_192_128,
             "memory_stats": {str(d.id): d.memory_stats()
                              for d in jax.devices()}}
 

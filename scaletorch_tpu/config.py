@@ -87,7 +87,7 @@ class ModelArguments:
         default="llama",
         metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
                           "olmo_hybrid | qwen3_next | afmoe | jamba | "
-                          "gpt_moe | lenet | mingpt"},
+                          "pangu_ultra_moe | gpt_moe | lenet | mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -129,10 +129,30 @@ class ModelArguments:
         default=None,
         metadata={"help": "Standard deviation the random initialiser "
                           "draws the token embedding at (qwen3_next, "
-                          "afmoe and jamba; unset: 0.02, HF's "
+                          "afmoe, jamba and pangu_ultra_moe; unset: "
+                          "0.02, HF's "
                           "initializer_range). "
                           "A property of random weights, not of the "
                           "model."},
+    )
+    routed_expert_init_scale: Optional[float] = field(
+        default=None,
+        metadata={"help": "Multiple of its fan-in bound that the random "
+                          "initialiser draws the held routed experts' "
+                          "down projection at (pangu_ultra_moe; unset: "
+                          "1). A property of random weights, not of "
+                          "the model: it sets how far one routed "
+                          "expert moves a token beside the shared one."},
+    )
+    query_init_scale: Optional[float] = field(
+        default=None,
+        metadata={"help": "Multiple of its fan-in bound that the random "
+                          "initialiser draws the query up-projection "
+                          "(q_b_proj) at (pangu_ultra_moe; unset: 1). A "
+                          "property of random weights, not of the "
+                          "model: at 1 random scores are flat (std "
+                          "0.33) and every token of a sequence gets "
+                          "the same vector from an attention block."},
     )
     # afmoe, by the published config.json names (layer_types above:
     # sliding_attention | full_attention; omitted = every
@@ -171,6 +191,27 @@ class ModelArguments:
     mamba_dt_rank: int = 160
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
+    # pangu_ultra_moe, by the published config.json names: latent
+    # attention's ranks and head widths (a head's query and key are
+    # qk_nope_head_dim + qk_rope_head_dim wide, its value v_head_dim;
+    # the cache keeps kv_lora_rank + qk_rope_head_dim a token), the
+    # leading layers with a dense MLP, the experts HELD here
+    # (n_routed_experts; the router's width is num_routed_experts where
+    # that is a chip's share), the ungated shared experts, the factor
+    # on the kept router weights, the four-norm block (sandwich_norm;
+    # false is refused) and the multi-token-prediction module's depth
+    # (carried: none is built)
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    first_k_dense_replace: int = 3
+    n_routed_experts: int = 256
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    sandwich_norm: bool = True
+    num_nextn_predict_layers: int = 1
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "

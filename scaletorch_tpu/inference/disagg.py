@@ -147,6 +147,7 @@ from scaletorch_tpu.inference.kv_cache import (  # noqa: E402
     carries_state,
     ceil_div,
     init_paged_kv_cache,
+    latent_of,
     window_of,
 )
 from scaletorch_tpu.telemetry.histogram import LogHistogram  # noqa: E402
@@ -340,6 +341,13 @@ class DisaggregatedEngine(InferenceEngine):
                 "ring; what is missing is the hand-off of a request's "
                 "rings from the prefill slice to the decode slice (the "
                 "channel moves the full-attention layers' pages only)")
+        if latent_of(cfg):
+            raise NotImplementedError(
+                f"DisaggregatedEngine: {type(cfg).__name__} has latent "
+                "attention; what is missing is the hand-off of latent "
+                "pages (one pool of [c | k_r] rows, where the channel "
+                "moves a K page and a V page) from the prefill slice to "
+                "the decode slice")
         devs = list(devices) if devices is not None else list(jax.devices())
         if isinstance(disagg_split, str):
             disagg_split = parse_disagg_spec(disagg_split)

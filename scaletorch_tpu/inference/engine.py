@@ -127,6 +127,7 @@ from scaletorch_tpu.inference.kv_cache import (
     ceil_div,
     init_paged_kv_cache,
     kv_cache_bytes,
+    latent_cache_bytes,
     no_prefix_reason,
     paged_kv_cache_shardings,
     recurrent_state_bytes,
@@ -328,6 +329,13 @@ class EngineMetrics:
     window_keys_attended: int = 0
     full_keys_attended: int = 0
     window_slot_reuse_mismatches: int = 0
+    # a model with latent attention (kv_cache.LatentCache): the bytes of
+    # its pool of latent rows (0: no such model, and neither is in the
+    # snapshot) and the cached rows one layer's decode kernel walked,
+    # summed over the decode slot-steps dispatched (from the positions:
+    # p + 1)
+    latent_cache_bytes: int = 0
+    latent_keys_attended: int = 0
 
     def record_ttft(self, ttft_s: float) -> None:
         self.hist["ttft"].observe(ttft_s)
@@ -395,6 +403,9 @@ class EngineMetrics:
                          "window_keys_attended", "full_keys_attended",
                          "window_slot_reuse_mismatches"):
                 snap[name] = getattr(self, name)
+        if self.latent_cache_bytes:
+            snap["latent_cache_bytes"] = self.latent_cache_bytes
+            snap["latent_keys_attended"] = self.latent_keys_attended
         return snap
 
 
@@ -801,7 +812,8 @@ class InferenceEngine:
             params_relaid_leaves=relaid_leaves,
             params_relaid_bytes=relaid_bytes,
             recurrent_state_bytes=recurrent_state_bytes(self.cache),
-            window_cache_bytes=window_cache_bytes(self.cache))
+            window_cache_bytes=window_cache_bytes(self.cache),
+            latent_cache_bytes=latent_cache_bytes(self.cache))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
         # the clock that is open, the last boundary; this tick's seconds
         # by phase name; when the previous tick ended, and whether it
@@ -1950,6 +1962,9 @@ class InferenceEngine:
             held = [i for i, _ in bound]
             if self._window is not None:
                 self._count_window_keys(positions[held])
+            if self.metrics.latent_cache_bytes:
+                self.metrics.latent_keys_attended += int(
+                    positions[held].astype(np.int64).sum()) + len(held)
             active[held] = True
             # positions and active stay numpy: the jitted call uploads
             # host operands itself, without the 0.25 ms of Python a

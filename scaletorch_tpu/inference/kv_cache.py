@@ -57,13 +57,32 @@ whose logical page ``t // page_size`` repeats the ring, so the same
 first page). What a slot's earlier request left in its ring is never
 read: a position inside a request's window was written by that request.
 
-MLA models cache only the low-rank latent (``MLACache``,
-[B, S_max, kv_rank]) and re-expand K/V per step — the trade the variant
-documents (models/attention/variants.py MultiHeadLatentAttention).
+A model with latent attention (pangu_ultra_moe: every layer) keeps
+ONE row a token and layer, ``[c | k_r]``: the normed latent
+(``kv_lora_rank`` wide: the key's first part AND the value) and the one
+rotary key every head shares, rotated when written. That is the SERVED
+latent cache, ``LatentCache``: one page pool ``[L, n_pages, 1,
+page_size, row]`` addressed through the engine's tables, the row
+``kv_lora_rank + qk_rope_head_dim`` padded with zeros to whole 128-lane
+tiles, which a Mosaic copy of a page needs (512 + 64 -> 640 numbers a
+token and layer as stored, 576 as written down: 1,280 B in bfloat16
+where 128 expanded heads would be 81,920). ``paged_write`` writes the
+row as it writes a K row; a decode step reads it in the absorbed form
+(``PagedKVIO.attend_latent`` -> ``paged_attention.latent_attention``:
+every query head against the one cached head, whose value is the row's
+first ``kv_lora_rank`` columns). It is addressed by page (no state, no
+ring), and refuses prefix sharing all the same (``no_prefix_reason``).
+
+``MLACache`` (``[B, S_max, kv_rank]``, dense, no decoupled rotary key,
+no norm on the latent) is the TEACHING variant's cache
+(models/attention/variants.py MultiHeadLatentAttention): it re-expands
+K/V per step, serves no model's forward, and the engine never builds
+one.
 """
 
 from __future__ import annotations
 
+import math
 from collections import deque
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -72,7 +91,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from scaletorch_tpu.ops.pallas.paged_attention import (
+    _LANES,
     TRASH_PAGE,
+    latent_attention,
     paged_attention,
     paged_write,
 )
@@ -92,7 +113,8 @@ class KVCache(NamedTuple):
 
 
 class MLACache(NamedTuple):
-    """Latent-only cache [B, S_max, kv_rank] for MLA attention."""
+    """Latent-only dense cache [B, S_max, kv_rank] of the teaching
+    variant (module docstring); the served one is ``LatentCache``."""
 
     latent: jax.Array
 
@@ -100,6 +122,11 @@ class MLACache(NamedTuple):
 def kv_cache_shape(cfg, batch: int, max_seq: int) -> Tuple[int, ...]:
     """[L, B, Hkv, S_max, D] for a Llama-family config, or
     [L, B, H, S_max, D] for GPT-MoE (full per-head K/V)."""
+    if latent_of(cfg):
+        raise TypeError(
+            f"{type(cfg).__name__} caches one latent row a token, no "
+            "head's K/V: its cache is kv_cache.LatentCache "
+            "(latent_cache_shape), served through the page pool only")
     if hasattr(cfg, "num_key_value_heads"):  # Llama / Qwen3 / Qwen3-MoE
         # a hybrid model keeps K/V for its full-attention layers only
         layers = getattr(cfg, "num_kv_cache_layers", cfg.num_hidden_layers)
@@ -119,12 +146,12 @@ def kv_cache_bytes(
     compiled programs to (ST1005)."""
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     dt = jnp.dtype(dtype or getattr(cfg, "dtype", jnp.bfloat16))
-    n = 1
-    for d in shape:
-        n *= d
-    return 2 * n * dt.itemsize
+    if latent_of(cfg):   # one pool, a row a token
+        return math.prod(
+            latent_cache_shape(cfg, num_pages, page_size)) * dt.itemsize
+    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
+    return 2 * math.prod(shape) * dt.itemsize
 
 
 def cache_nbytes(cache: Any) -> int:
@@ -228,6 +255,44 @@ class WindowCache(NamedTuple):
     wv: jax.Array
 
 
+class LatentCache(NamedTuple):
+    """The cache of a model with latent attention, one pytree that the
+    step programs donate and return: ``k`` ``[L, n_pages, 1, page_size,
+    row]`` holds a cached token's ``[c | k_r | 0...]`` (module
+    docstring): its key as the absorbed form reads it, and in the
+    first ``kv_lora_rank`` columns its value. The field keeps the page
+    pool's name because the engine's own reads (shape, dtype, placement)
+    go by it; nothing expanded is ever stored."""
+
+    k: jax.Array
+
+
+def latent_of(cfg) -> bool:
+    """Whether the model's attention is latent attention: its cache
+    holds one row ``[c | k_r]`` a token and layer and nothing per
+    head."""
+    return hasattr(cfg, "kv_lora_rank")
+
+
+def latent_row_width(cfg) -> int:
+    """A cached row as stored: ``kv_lora_rank + qk_rope_head_dim``
+    rounded up to whole 128-lane tiles (576 -> 640), which a Mosaic
+    copy of a page wants (``paged_attention.kernel_serves``)."""
+    return ceil_div(cfg.kv_lora_rank + cfg.qk_rope_head_dim, _LANES) * _LANES
+
+
+def latent_cache_shape(cfg, num_pages: int, page_size: int
+                       ) -> Tuple[int, ...]:
+    """The one pool of a ``LatentCache``."""
+    return (cfg.num_kv_cache_layers, num_pages, 1, page_size,
+            latent_row_width(cfg))
+
+
+def latent_cache_bytes(cache: Any) -> int:
+    """Bytes of a latent cache's pool; 0 for any other cache."""
+    return cache.k.nbytes if isinstance(cache, LatentCache) else 0
+
+
 # the fields of a cache whose axis 1 counts SLOTS (every other field's
 # counts pages): what a masked fill over slots touches
 SLOT_FIELDS = ("state", "conv")
@@ -257,6 +322,11 @@ def no_prefix_reason(cfg) -> Optional[str]:
                 "in a ring that holds a suffix of the slot's tokens; a "
                 "shared or transferred prefix page of the full-attention "
                 "layers has no window-layer K/V to go with it")
+    if latent_of(cfg):
+        return ("has latent attention, whose pages hold [c | k_r] and no "
+                "head's key or value; a shared prefix would have to be "
+                "expanded through W_ukv in the prefill; not written (a "
+                "prompt starts at 0 and attends to itself in key blocks)")
     return None
 
 
@@ -322,10 +392,19 @@ def init_paged_kv_cache(
     full-attention layers plus the zeroed state and convolution tail of
     ``slots`` slots (on one device, or replicated: sharding a recurrent
     state over heads is not written)."""
-    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
     sk, sv = (sharding.k, sharding.v) \
         if isinstance(sharding, PagedKVCache) else (sharding, sharding)
+    if latent_of(cfg):
+        if sk is not None and len(sk.device_set) > 1:
+            raise NotImplementedError(
+                "a latent cache over several devices is not written (its "
+                "one cached head has no axis to shard; a deployment runs "
+                "such attention data-parallel): serve this model on one "
+                "device")
+        return LatentCache(k=jnp.zeros(
+            latent_cache_shape(cfg, num_pages, page_size), dt, device=sk))
+    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     window = window_of(cfg)
     by_slot = window is not None or carries_state(cfg)
     if by_slot and slots is None:
@@ -637,6 +716,15 @@ class RadixPrefixCache:
         return freed
 
 
+def _latent_row(latent: jax.Array, rotary: jax.Array,
+                width: int) -> jax.Array:
+    """``[latent | rotary | 0...]`` along the last axis, ``width`` wide:
+    a cached row, or the query that reads it."""
+    row = jnp.concatenate([latent, rotary], axis=-1)
+    return jnp.pad(row, ((0, 0),) * (row.ndim - 1)
+                   + ((0, width - row.shape[-1]),))
+
+
 class PagedKVIO:
     """Paged-cache adapter for the models' cache-aware forwards.
 
@@ -683,6 +771,31 @@ class PagedKVIO:
             else self.kernel and q.shape[2] == 1,
             interpret=self.interpret,
         )
+
+
+    def write_latent(self, pool: jax.Array, layer: jax.Array, c: jax.Array,
+                     k_r: jax.Array, positions: jax.Array,
+                     write_mask: Optional[jax.Array]) -> jax.Array:
+        """A ``LatentCache``'s write: the normed latent ``c`` [B, 1, S,
+        kv_lora_rank] and the rotated ``k_r`` [B, 1, S, rope] as one row
+        ``[c | k_r | 0...]`` of the pool's width."""
+        return self.write(pool, layer, _latent_row(c, k_r, pool.shape[-1]),
+                          positions, write_mask)
+
+    def attend_latent(self, q_c: jax.Array, q_r: jax.Array,
+                      pool: jax.Array, layer: jax.Array,
+                      q_positions: jax.Array, *, scale: float) -> jax.Array:
+        """A ``LatentCache``'s one-token read in the absorbed form:
+        ``q_c`` [B, H, kv_lora_rank] and the rotated ``q_r`` [B, H,
+        rope] of every head, as one query row ``[q_c | q_r | 0...]``
+        against the one cached head; [B, H, kv_lora_rank] back
+        (``paged_attention.latent_attention``)."""
+        return latent_attention(
+            _latent_row(q_c, q_r, pool.shape[-1]), pool, self.page_tables,
+            q_positions, layer=layer,
+            value_width=q_c.shape[-1], scale=scale,
+            seq_limit=self.seq_limit, kernel=self.kernel,
+            interpret=self.interpret)
 
 
 class RingKVIO:

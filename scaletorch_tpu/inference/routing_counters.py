@@ -89,6 +89,7 @@ class RoutingCounters:
         self.num_experts, self.moe_layers = num_experts, moe_layers
         self._totals = np.zeros(len(ROUTING_COUNTERS), np.int64)
         self._read = np.zeros(len(ROUTING_COUNTERS), np.uint32)
+        self._read_call = 0     # the call whose accumulator was read last
         self._lock = threading.Lock()
         self._calls = 0
         zeros = np.zeros(len(ROUTING_COUNTERS), np.uint32)
@@ -96,10 +97,18 @@ class RoutingCounters:
             jax.device_put(zeros, sharding) if sharding is not None
             else jax.numpy.asarray(zeros))
 
-    def _read_device(self, accumulator) -> None:
+    def _read_device(self, accumulator, call: int) -> None:
+        """Add what the accumulator that call number ``call`` returned
+        holds over the last one read; an accumulator OLDER than that one
+        is left alone (``snapshot`` reads the newest that is ready, the
+        periodic read of ``run`` the one three calls back: read after a
+        newer one, its step back added 2**32 for good, one window in
+        eleven on the v5e: PERF.md, PR 51)."""
+        if call <= self._read_call:
+            return
         now = np.asarray(accumulator, np.uint32)
         self._totals += (now - self._read).astype(np.int64)  # mod 2**32
-        self._read = now
+        self._read, self._read_call = now, call
 
     def run(self, step: Callable, args) -> tuple:
         """One call of a counting step: the accumulator goes in last
@@ -110,7 +119,7 @@ class RoutingCounters:
             *results, self.accumulator = step(*args, self._previous)
             self._calls += 1
             if self._calls % READ_EVERY == 0:
-                self._read_device(self._settled)
+                self._read_device(self._settled, self._calls - 3)
         return tuple(results)
 
     def snapshot(self, decode_steps: int) -> Dict[str, float]:
@@ -120,10 +129,10 @@ class RoutingCounters:
         every call that has ended: the newest three as well once their
         results are ready, as they are whenever the engine is idle."""
         with self._lock:
-            self._read_device(next(
-                (acc for acc in (self.accumulator, self._previous,
-                                 self._older)
-                 if acc.is_ready()), self._settled))
+            self._read_device(*next(
+                ((acc, self._calls - back) for back, acc in enumerate(
+                    (self.accumulator, self._previous, self._older))
+                 if acc.is_ready()), (self._settled, self._calls - 3)))
             totals = dict(zip(ROUTING_COUNTERS, map(int, self._totals)))
         steps = decode_steps * self.moe_layers
         mean_load = totals["moe_prefill_assignments"] / self.num_experts

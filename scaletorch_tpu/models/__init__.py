@@ -24,6 +24,10 @@ from scaletorch_tpu.models.qwen3_next import (  # noqa: F401
 )
 from scaletorch_tpu.models.afmoe import Afmoe, AfmoeConfig  # noqa: F401
 from scaletorch_tpu.models.jamba import Jamba, JambaConfig  # noqa: F401
+from scaletorch_tpu.models.pangu_ultra_moe import (  # noqa: F401
+    PanguUltraMoE,
+    PanguUltraMoEConfig,
+)
 from scaletorch_tpu.models.gpt_moe import GPTMoE, GPTMoEConfig  # noqa: F401
 from scaletorch_tpu.models.lenet import LeNet, LeNetConfig  # noqa: F401
 from scaletorch_tpu.models.resnet import ResNetConfig  # noqa: F401

@@ -95,8 +95,14 @@ class MultiHeadLatentAttention(AttentionVariant):
     The KV cache (in inference) stores ONLY the latent: ``init_cache`` /
     ``prefill`` / ``decode`` keep a [B, S_max, kv_rank] buffer and
     re-expand K/V from it per step — per-token cache cost R floats
-    instead of 2·H·D (inference/kv_cache.MLACache wraps the buffer for
-    the engine-side bookkeeping)."""
+    instead of 2·H·D (inference/kv_cache.MLACache wraps the buffer).
+
+    The TEACHING variant: no decoupled rotary key, no norm on the
+    latent, a dense cache, K/V re-expanded per step; no model's forward
+    runs it and the engine never builds its cache. The latent attention
+    that is SERVED is models/pangu_ultra_moe.py on
+    inference/kv_cache.LatentCache (a paged pool of ``[c | k_r]`` rows,
+    read in the absorbed form)."""
 
     def init(self, key):
         cfg = self.cfg

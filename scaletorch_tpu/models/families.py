@@ -24,6 +24,7 @@ from scaletorch_tpu.models import (
     llama,
     olmo_hybrid,
     olmoe,
+    pangu_ultra_moe,
     qwen3,
     qwen3_moe,
     qwen3_next,
@@ -77,6 +78,16 @@ FAMILIES: Dict[str, Family] = {
             "rules (tp / cp / pp), and there is no loss wiring and no HF "
             "weight loading; the family is served (scripts/serve.py "
             "--preset jamba2-3b)")),
+    "pangu_ultra_moe": Family(
+        pangu_ultra_moe, pangu_ultra_moe.PanguUltraMoEConfig,
+        counts_routing=True,
+        untrained=(
+            "its latent projections (q_a / q_b / kv_a / kv_b) have no "
+            "sharding rules (tp / cp / pp), its experts no exchange "
+            "(ep), num_nextn_predict_layers names a multi-token "
+            "prediction module and loss that are not built, and there "
+            "is no HF weight loading; the family is served "
+            "(scripts/serve.py --preset openpangu-ultra-moe-718b)")),
     # served and tested through its config class; trains via its example
     "gpt_moe": Family(gpt_moe, gpt_moe.GPTMoEConfig),
 }
@@ -109,15 +120,18 @@ def build_model_config(args):
         raise ValueError(f"unknown model_type {args.model_type!r}")
     overrides = dict(dtype=_DTYPE[args.dtype],
                      param_dtype=_DTYPE[args.param_dtype])
-    if args.embed_init_std is not None:
-        # a property of random weights: a family whose initialiser reads
-        # it has the field
-        if "embed_init_std" not in row.config_cls.__dataclass_fields__:
+    for name in ("embed_init_std", "routed_expert_init_scale",
+                 "query_init_scale"):
+        # properties of random weights: a family whose initialiser reads
+        # one has the field
+        if getattr(args, name) is None:
+            continue
+        if name not in row.config_cls.__dataclass_fields__:
             raise NotImplementedError(
-                f"--embed_init_std with model_type {args.model_type!r}: "
+                f"--{name} with model_type {args.model_type!r}: "
                 "its config class has no such field (no initialiser of "
                 "the family reads it)")
-        overrides["embed_init_std"] = args.embed_init_std
+        overrides[name] = getattr(args, name)
     if args.model_name_or_path:
         if not row.loads_hf:
             raise NotImplementedError(

@@ -398,6 +398,76 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         mamba_conv_bias=True,
         mamba_proj_bias=False,
     ),
+    # openPangu-Ultra-MoE-718B (FreedomIntelligence/openPangu-Ultra-MoE-
+    # 718B config.json, model_type pangu_ultra_moe), as published: 61
+    # layers of latent attention (128 heads over a 512 + 64 latent row)
+    # under four norms, 3 leading dense layers, then 256 sigmoid-routed
+    # experts of width 2048 (top 8) and an ungated shared expert; 718 B
+    # parameters = 1.44 TB in bf16. One chip serves a share
+    # (benchmarks/configs/openpangu-ultra-moe-718b-serve.json):
+    # --num_hidden_layers 6 --first_k_dense_replace 1,
+    # --n_routed_experts 8 --num_routed_experts 256, an eighth of the
+    # vocabulary.
+    "openpangu-ultra-moe-718b": dict(
+        model_type="pangu_ultra_moe",
+        vocab_size=153600,
+        hidden_size=7680,
+        intermediate_size=18432,
+        num_hidden_layers=61,
+        num_attention_heads=128,
+        num_key_value_heads=128,
+        rope_theta=25600000.0,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=131072,
+        tie_word_embeddings=False,
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        first_k_dense_replace=3,
+        n_routed_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=2048,
+        n_shared_experts=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        sandwich_norm=True,
+        num_nextn_predict_layers=1,
+    ),
+    # The same family at a size the CPU tests serve: one dense layer and
+    # three sparse ones, 4 heads over a 32 + 8 latent row (stored 128
+    # wide), and a SHARE of the experts: 4 of 16 routed ones held here,
+    # from id 4.
+    "pangu-tiny": dict(
+        model_type="pangu_ultra_moe",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=4,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        rope_theta=1e4,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=4096,
+        tie_word_embeddings=False,
+        q_lora_rank=48,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        first_k_dense_replace=1,
+        n_routed_experts=4,
+        num_routed_experts=16,
+        first_expert_id=4,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        n_shared_experts=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        sandwich_norm=True,
+        num_nextn_predict_layers=1,
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

@@ -492,8 +492,11 @@ def dropless_mlp(
         if cfg.score_func == "sigmoid":
             with jax.named_scope("moe.router"):
                 probs = jax.nn.sigmoid(logits)
-                _, gate_idx = jax.lax.top_k(
-                    probs + layer["expert_bias"].astype(jnp.float32), k)
+                chosen_by = probs
+                if "expert_bias" in layer:   # pangu_ultra_moe has none
+                    chosen_by = probs + layer["expert_bias"].astype(
+                        jnp.float32)
+                _, gate_idx = jax.lax.top_k(chosen_by, k)
                 gate_w = jnp.take_along_axis(probs, gate_idx, axis=-1)
                 if cfg.norm_topk_prob:
                     gate_w = gate_w / (

@@ -69,7 +69,9 @@ def prefill_self_attention(
 ) -> jax.Array:
     """Causal attention of a prompt over itself, forward only: row i
     sees keys j <= i, and with ``window`` only those with ``i - j <
-    window``. [B, Hq, S, D] x [B, Hkv, S, D]^2 -> [B, Hq, S, D]. What a
+    window``. [B, Hq, S, D] x [B, Hkv, S, D] x [B, Hkv, S, Dv] -> [B, Hq,
+    S, Dv] (the value's width is its own: latent attention's expanded
+    heads are 192 / 128). What a
     serving prefill of a sequence that starts at position 0 computes,
     in key blocks (the flash forward: no [S, S] scores in HBM, and the
     blocks a window cannot see are no grid step); off the TPU the plain

@@ -31,6 +31,31 @@ from scaletorch_tpu.ops.flash_attention import _pallas_available
 # row tile of the Pallas form: M is padded up to a multiple of it
 _ROW_TILE = 512
 
+# The dropless expert layer gathers its (token, choice) rows sorted by
+# expert, ``[N k, H]``, and brings them back as ``f32[N, k, H]``: up to
+# _SORTED_ROWS_WHOLE bytes of sorted rows it does so for all N tokens
+# at once (every call this repo had compiled before a hidden size of
+# 7680 came: the largest, a prefill of 24,576 tokens x 8 choices at
+# hidden 2048, is 0.81 GB); past it the tokens go through in equal
+# blocks of at most _SORTED_ROWS_BLOCK bytes of sorted rows each
+# (24,576 x 8 x 7680 in bfloat16 is 3.02 GB of rows and 6.04 GB of what
+# comes back: twelve blocks of 2,048 tokens, 0.25 + 0.5 GB alive).
+_SORTED_ROWS_WHOLE = 1 << 30
+_SORTED_ROWS_BLOCK = 1 << 28
+
+
+def token_blocks(n: int, k: int, hidden: int, itemsize: int) -> int:
+    """In how many equal blocks of tokens ``dropless_expert_mlp`` runs
+    ``n`` tokens of ``k`` choices: 1 up to ``_SORTED_ROWS_WHOLE`` bytes
+    of sorted rows, else the fewest that divide ``n`` and keep a
+    block's rows within ``_SORTED_ROWS_BLOCK``. Static shapes in, one
+    integer out."""
+    row_bytes = k * hidden * itemsize
+    if n * row_bytes <= _SORTED_ROWS_WHOLE:
+        return 1
+    return next(b for b in range(2, n + 1)
+                if n % b == 0 and (n // b) * row_bytes <= _SORTED_ROWS_BLOCK)
+
 
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """(tm, tk, tn) of the megablox kernel for an [m, k] x [G, k, n]
@@ -115,13 +140,34 @@ def dropless_expert_mlp(
     of experts out of it (a Pallas call takes no fused slice; PERF.md,
     PR 27). ``matmul`` replaces ``grouped_matmul``
     (``tools/bench_dropless_moe.py`` times the forms through it).
+    Past ``_SORTED_ROWS_WHOLE`` bytes of sorted rows the tokens run in
+    ``token_blocks`` equal blocks, one after the other.
     Returns (y [N, H] in the compute dtype, rows per expert [E] int32).
     """
+    cdt = compute_dtype or x.dtype
+    blocks = token_blocks(x.shape[0], gate_idx.shape[-1], x.shape[1],
+                          jnp.dtype(cdt).itemsize)
+    if blocks > 1:
+        # dropless still: every block routes all its rows; only the
+        # sorted copies are bounded (``token_blocks``)
+        def one(block):
+            rows, idx, w, alive, here = block
+            return dropless_expert_mlp(
+                rows, idx, w, gate_proj, up_proj, down_proj, live=alive,
+                held=here, layer=layer, compute_dtype=compute_dtype,
+                matmul=matmul)
+
+        def cut(a):
+            return None if a is None else a.reshape(
+                (blocks, a.shape[0] // blocks) + a.shape[1:])
+
+        y, sizes = jax.lax.map(
+            one, tuple(cut(a) for a in (x, gate_idx, gate_w, live, held)))
+        return y.reshape(x.shape[0], -1), jnp.sum(sizes, axis=0)
     matmul = matmul or grouped_matmul
     n, hid = x.shape
     k = gate_idx.shape[-1]
     e = gate_proj.shape[-3]
-    cdt = compute_dtype or x.dtype
     with jax.named_scope("moe.sort"):
         ids = gate_idx.reshape(-1).astype(jnp.int32)
         computed = None if live is None else jnp.repeat(live, k)

@@ -289,8 +289,8 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None):
 
     q = q_ref[0, 0]  # [bq, D]
     k = k_ref[0, 0]  # [bkv, D]
-    v = v_ref[0, 0]
-    d = q.shape[-1]
+    v = v_ref[0, 0]  # [bkv, Dv]
+    d = v.shape[-1]
     s = _scores(q, k, i, j, scale=scale, masked=causal, bq=bq, bkv=bkv,
                 window=window)
     # m, l, corr: [bq, _LANES], every lane of a row the row's value
@@ -313,8 +313,13 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None):
 
 
 def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None):
+    """``v`` may be narrower or wider than ``q`` / ``k`` (latent
+    attention's expanded heads: keys 192, values 128): the output and
+    the accumulator take the value's width. The backward kernels know
+    one width."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
+    dv = v.shape[-1]
     n_rep = hq // hkv
     if window is not None and not causal:
         raise ValueError("a window is a causal mask's: causal=False "
@@ -336,19 +341,19 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_rows),
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
-            pl.BlockSpec((1, 1, bkv, d), kv_rows),
+            pl.BlockSpec((1, 1, bkv, dv), kv_rows),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), q_rows),
+            pl.BlockSpec((1, 1, bq, dv), q_rows),
             pl.BlockSpec((1, 1, 1, bq),
                          lambda b_, h, *g: (b_, h, 0, blocks(*g)[0])),
         ],
         out_shape=[
-            _struct((b, hq, sq, d), q.dtype, q),
+            _struct((b, hq, sq, dv), q.dtype, q),
             _struct((b, hq, 1, sq), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running sum
         ],
@@ -431,6 +436,10 @@ def _dkv_kernel(*refs, scale, causal, bq, bkv):
 def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
+    if v.shape[-1] != d:
+        raise NotImplementedError(
+            f"flash backward with values {v.shape[-1]} wide under keys "
+            f"{d} wide: only the forward takes a value width of its own")
     n_rep = hq // hkv
     nq, nkv = sq // bq, skv // bkv
     plan = causal_block_plan(sq, skv, bq, bkv) if causal else None

@@ -537,6 +537,201 @@ def pallas_paged_decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# the latent decode kernel
+# ---------------------------------------------------------------------------
+# keys one compute block of the latent kernel covers: every query head
+# shares the block (one cached head). The walk is bound by issuing one
+# copy a page (144 ns a page of 16 rows on the v5e, whatever the
+# block: 211 / 198 / 210 / 226 us a call of 22,000 rows at 128 / 256 /
+# 512 / 1,024 keys: PERF.md, PR 51), so the block only has to keep the
+# score tile [heads, keys] in float32 (128 KB at 128 heads) and the
+# landing zone (0.64 MB of rows double-buffered at 640 wide) small
+_LATENT_BLOCK_KEYS = 256
+
+
+def _latent_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, pool_hbm,
+                          o_ref, buf, sems, *, scale, page_size,
+                          pages_per_block, max_pages, value_width):
+    """``_paged_decode_kernel`` for a pool of latent rows: ONE cached
+    head, whose row is the key and whose first ``value_width`` columns
+    are the value, against every query head at once (the heads are the
+    rows of the score tile)."""
+    b = pl.program_id(0)   # slot
+    layer = layer_ref[0]
+    bk = pages_per_block * page_size
+    pos = pos_ref[b]
+    n_live = jnp.clip(pos // page_size + 1, 0, max_pages)
+    n_blocks = (n_live + pages_per_block - 1) // pages_per_block
+
+    def block_copies(i, slot):
+        """One async copy per page of block ``i``: the page's rows
+        ``[page_size, row]`` (contiguous in HBM) into their place in
+        landing buffer ``slot``; a page past the live length re-reads
+        the last live page and is masked. ``i`` None: the same copies
+        from page 0, to WAIT on (a wait reads its semaphore and the
+        copy's size, never its source: the table is looked up once a
+        page, not twice; the walk is bound by issuing copies)."""
+        out = []
+        for p in range(pages_per_block):
+            page = 0
+            if i is not None:
+                j = jnp.minimum(i * pages_per_block + p, n_live - 1)
+                page = pt_ref[b * max_pages + j]
+            out.append(pltpu.make_async_copy(
+                pool_hbm.at[layer, page, 0],
+                buf.at[slot, pl.ds(p * page_size, page_size), :],
+                sems.at[slot]))
+        return out
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in block_copies(0, 0):
+            c.start()
+
+    q = q_ref[0]   # [H, row]
+    heads = q.shape[0]
+    key_in_block = jax.lax.broadcasted_iota(jnp.int32, (heads, bk), 1)
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            for c in block_copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in block_copies(None, slot):
+            c.wait()
+        rows = buf[slot]   # [bk, row]
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, bk]
+        s = jnp.where(i * bk + key_in_block <= pos, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :value_width],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc
+
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full((heads, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, value_width), jnp.float32),
+    ))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def pallas_latent_decode_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    page_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    layer: jax.Array,
+    value_width: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """One-token latent attention in the absorbed form: q [B, H, row]
+    (``[q~ | q_r | 0...]`` of every head) against the latent page pool
+    [L, n_pages, 1, page_size, row] (``[c | k_r | 0...]`` a token: the
+    one cached head) at ``layer``; positions [B]: the query token's
+    (keys j <= position). Returns [B, H, value_width] float32-accumulated
+    ``sum_j p_h(j) c(j)``: a row's first ``value_width`` columns are its
+    value. The pool stays in HBM; each slot's step copies its live
+    pages once for ALL heads, ``_LATENT_BLOCK_KEYS`` keys a block,
+    double-buffered, and reduces flash-style: no expanded key or value
+    of any cached token exists anywhere."""
+    b, heads, row = q.shape
+    _, _, hkv, page_size, _ = pool.shape
+    if hkv != 1 or pool.shape[-1] != row:
+        raise ValueError(
+            f"a latent pool holds one head of the query's width: pool "
+            f"{pool.shape}, query {q.shape}")
+    if not interpret and not (kernel_serves(row)
+                              and kernel_serves(value_width)):
+        raise ValueError(
+            f"the latent decode kernel copies whole pages out of HBM and "
+            f"slices the value off the row at a {_LANES}-lane boundary; "
+            f"got row {row}, value {value_width}")
+    max_pages = page_tables.shape[1]
+    ppb = max(1, min(_LATENT_BLOCK_KEYS // page_size, max_pages))
+    if not interpret:
+        pool = pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, heads, row), lambda b_, *_: (b_, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, heads, value_width),
+                               lambda b_, *_: (b_, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb * page_size, row), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=scale,
+                          page_size=page_size, pages_per_block=ppb,
+                          max_pages=max_pages, value_width=value_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, heads, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(pltpu.PARALLEL,)),  # slots share no state
+        interpret=interpret,
+        name="latent_decode",
+    )(page_tables.astype(jnp.int32).reshape(-1),
+      positions.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q, pool)
+
+
+def latent_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    page_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    layer: jax.Array,
+    value_width: int,
+    scale: float,
+    seq_limit: Optional[int] = None,
+    kernel: Optional[bool] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """One-token attention against a latent page pool in the absorbed
+    form, kernel or fallback (``pallas_latent_decode_attention`` has the
+    shapes). ``kernel=None``: the Mosaic kernel when ``in_place_pair``
+    serves the row's width; elsewhere the same mathematics in plain XLA
+    over the slot's gathered rows ``pool[layer, page_tables]`` [B, S,
+    row] (the CPU path and the reference the kernel is held to): float32
+    scores and softmax, the value the rows' first columns."""
+    if kernel is None:
+        kernel = in_place_pair(pool.shape[-1])
+    if kernel:
+        return pallas_latent_decode_attention(
+            q, pool, page_tables, positions, layer=layer,
+            value_width=value_width, scale=scale, interpret=interpret)
+    rows = paged_gather_kv(pool, page_tables, layer)[:, 0]   # [B, S, row]
+    if seq_limit is not None and rows.shape[1] > seq_limit:
+        rows = rows[:, :seq_limit]
+    s = jnp.einsum("bhr,bsr->bhs", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
+        <= positions[:, None]
+    s = jnp.where(seen[:, None, :], s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhs,bsc->bhc", p, rows[..., :value_width])
+
+
+# ---------------------------------------------------------------------------
 # dispatchers: one predicate picks the pair
 # ---------------------------------------------------------------------------
 def paged_write(

@@ -167,6 +167,26 @@ def test_counters_survive_the_accumulators_wrap():
     assert snap["moe_routed_assignments"] > 2**32
 
 
+def test_a_snapshot_between_two_periodic_reads_adds_nothing_twice():
+    """``snapshot`` reads the newest accumulator that is ready, the
+    periodic read the one three calls back: after a snapshot that one
+    is the older of the two and is left alone (read, it stepped the
+    totals back mod 2**32: +4,294,967,296 in a window's counters)."""
+    counters = RoutingCounters(num_experts=8, moe_layers=2)
+
+    def step(accumulator):
+        return "out", accumulator + jnp.full(
+            len(ROUTING_COUNTERS), 7, jnp.uint32)
+
+    for call in range(1, 2 * READ_EVERY + 1):
+        counters.run(step, ())
+        if call % READ_EVERY == READ_EVERY - 1:
+            # one call before a periodic read: the newest is ready
+            assert counters.snapshot(1)["moe_routed_assignments"] == 7 * call
+    assert counters.snapshot(1)["moe_routed_assignments"] == (
+        7 * 2 * READ_EVERY)
+
+
 def _http(port, path, payload=None):
     data = None if payload is None else json.dumps(payload).encode()
     with urllib.request.urlopen(urllib.request.Request(

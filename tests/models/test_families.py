@@ -20,6 +20,7 @@ from scaletorch_tpu.models import (
     llama,
     olmo_hybrid,
     olmoe,
+    pangu_ultra_moe,
     qwen3,
     qwen3_moe,
     qwen3_next,
@@ -43,6 +44,8 @@ EXPECTED = {
     "qwen3_next": (qwen3_next, qwen3_next.forward_cached, True),
     "afmoe": (afmoe, afmoe.forward_cached, True),
     "jamba": (jamba, jamba.forward_cached, False),
+    "pangu_ultra_moe": (pangu_ultra_moe, pangu_ultra_moe.forward_cached,
+                        True),
     "gpt_moe": (gpt_moe, gpt_moe.forward_cached, False),
 }
 TRAINS = {"llama", "qwen3", "qwen3_moe", "olmoe", "gpt_moe"}
@@ -53,7 +56,7 @@ def built(name):
     return build_model_config(ScaleTorchTPUArguments(**preset(name)))
 
 
-def test_the_rows_are_the_nine_families():
+def test_the_rows_are_the_ten_families():
     assert set(FAMILIES) == set(EXPECTED)
     classes = [row.config_cls for row in FAMILIES.values()]
     assert len(set(classes)) == len(classes)
@@ -145,11 +148,28 @@ def test_one_refusal_of_hf_auto_fill_worded_from_the_row(model_type):
 def test_embed_init_std_is_read_where_the_class_has_the_field(model_type):
     has = "embed_init_std" in FAMILIES[
         model_type].config_cls.__dataclass_fields__
-    assert has == (model_type in ("qwen3_next", "afmoe", "jamba"))
+    assert has == (model_type in ("qwen3_next", "afmoe", "jamba",
+                                  "pangu_ultra_moe"))
     if not has:
         with pytest.raises(NotImplementedError, match="embed_init_std"):
             build_model_config(ScaleTorchTPUArguments(
                 model_type=model_type, embed_init_std=1.0))
+
+
+@pytest.mark.parametrize("name", ["routed_expert_init_scale",
+                                  "query_init_scale"])
+@pytest.mark.parametrize("model_type", sorted(EXPECTED))
+def test_the_draw_s_scales_are_read_where_the_class_has_the_field(
+        model_type, name):
+    """Two more properties of random weights a launch may set: the one
+    family whose initialiser reads them has the fields, every other
+    refuses each by name."""
+    has = name in FAMILIES[model_type].config_cls.__dataclass_fields__
+    assert has == (model_type == "pangu_ultra_moe")
+    if not has:
+        with pytest.raises(NotImplementedError, match=name):
+            build_model_config(ScaleTorchTPUArguments(
+                model_type=model_type, **{name: 0.5}))
 
 
 @pytest.mark.parametrize("model_type", sorted(set(EXPECTED) - TRAINS))

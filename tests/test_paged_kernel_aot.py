@@ -896,6 +896,158 @@ def test_jamba_steps_are_what_the_new_readers_look_for(one_chip):
                 + memory.temp_size_in_bytes) < 11e9, name
 
 
+def test_pangu_steps_are_what_the_new_readers_look_for(one_chip):
+    """openPangu-Ultra-MoE's two step programs at the cell's shapes (8
+    slots x 3,072-row prompts, ``max_seq`` 3,456, 6 latent-attention
+    layers of 128 heads, a 256-wide router over 8 held experts). **The
+    decode step holds no expanded key or value per cached token and no
+    gather of the pool**: its attention is ``latent_decode``, 2 calls in
+    the text (the unrolled dense layer's + the scanned sparse layers'),
+    the one 3-D Mosaic result ``bf16[8,128,512]``, and no array has a
+    cached token's 128 heads x 128 (or 192, or 256) numbers for every
+    position of a slot. The cached row is 640 wide (512 + 64 padded to
+    whole tiles), written by one ``paged_write`` a layer. **The prefill
+    call attends in key blocks, four groups of 32 heads one after the
+    other, keys 192 and values 128 wide** (the flash
+    forward's ``(bf16[8,32,3072,128], f32)``, the head count
+    ``costs/pangu_ultra_moe.prefill_head_groups`` charges: no ``slots x
+    heads x prefill_len x max_seq`` scores, which would be 43 GB, and no
+    array of all 128 heads' expanded queries, 1.21 GB) **and
+    bounds its sorted rows**: no array of ``slots x prefill_len x 8``
+    rows x 7,680 (3.02 GB a copy; its blocks are ``[16384, 7680]``).
+    Both fit the chip."""
+    decode, prefill, pool_shape = _programs_of(
+        one_chip, "openpangu-ultra-moe-718b-serve")
+    slots, heads, rows, max_seq = 8, 128, 3072, 3456
+    assert pool_shape == (6, slots * 216 + 1, 1, 16, 640)
+    texts = {"decode": decode.as_text(), "prefill": prefill.as_text()}
+    for name, text in texts.items():
+        assert _pool_shaped(text, pool_shape) == {}, name
+        writes = _named(_mosaic_calls(text), "paged_write")
+        assert len(writes) == 2, (name, len(writes))    # ONE row a token
+        for stack in ("bf16[5,8,7680,2048]", "bf16[8,7680,2048]",
+                      "bf16[40,7680,2048]", "bf16[5,8,2048,7680]",
+                      "bf16[8,2048,7680]", "bf16[40,2048,7680]"):
+            moved = [x for x in _top_level(text, stack)
+                     if x[0] not in _PLUMBING | {"bitcast"}
+                     and "tpu_custom_call" not in x[1]]
+            assert not moved, (name, moved[:3])
+
+    def sizes(text):
+        return {dims: math.prod(map(int, dims.split(",")))
+                for dims in set(_ARRAY.findall(text))}
+
+    # decode: nothing per cached token and head, nothing of a slot's
+    # whole gathered context
+    per_token_and_head = {slots * max_seq * heads * w
+                          for w in (128, 192, 256, 320, 512, 576, 640)}
+    gathered = {slots * max_seq * 640, slots * 216 * 16 * 640}
+    found = sizes(texts["decode"])
+    assert not [d for d, n in found.items()
+                if n in per_token_and_head | gathered]
+    assert f"f32[{slots},19200]" in texts["decode"]
+    # prefill: no scores over the whole buffer, no unbounded sorted rows
+    found = sizes(texts["prefill"])
+    scores = {slots * heads * rows * max_seq, slots * heads * rows * rows}
+    every_head = {slots * heads * rows * w for w in (192, 256)}
+    assert not [d for d, n in found.items() if n in scores | every_head]
+    unbounded = slots * rows * 8 * 7680
+    assert not [d for d, n in found.items() if n >= unbounded]
+    assert "bf16[16384,7680]" in texts["prefill"]       # a block's rows
+    assert f"f32[{slots},19200]" in texts["prefill"]    # last_logits
+
+    patterns = {name: _reader_patterns(name) for name in (
+        "serve_pangu_latent_attn_mxu_roofline",
+        "serve_pangu_latent_attn_hbm_roofline",
+        "serve_pangu_expert_mlp_roofline",
+        "serve_pangu_prefill_attn_roofline")}
+
+    def matched(program, reader):
+        return [n for n in _short_names(texts[program])
+                if any(re.search(p, n) for p in patterns[reader])]
+
+    for reader in ("serve_pangu_latent_attn_mxu_roofline",
+                   "serve_pangu_latent_attn_hbm_roofline"):
+        attn = matched("decode", reader)
+        assert len(attn) == 2 and all(
+            n.startswith("latent_decode")
+            and n.endswith("bf16[8,128,512]") for n in attn), attn
+        assert not matched("prefill", reader)
+    experts = matched("decode", "serve_pangu_expert_mlp_roofline")
+    assert len(experts) == 3 and all(n.startswith("gmm") for n in experts)
+    assert sorted(n.rsplit(" | ", 1)[1] for n in experts) == (
+        ["bf16[128,2048]"] * 2 + ["bf16[128,7680]"])
+    assert not matched("prefill", "serve_pangu_expert_mlp_roofline")
+    from benchmarks.costs import pangu_ultra_moe as costs
+
+    config = _serving_model("openpangu-ultra-moe-718b-serve")[0]
+    group = heads // costs.prefill_head_groups(config)
+    flash = matched("prefill", "serve_pangu_prefill_attn_roofline")
+    assert group == 32 and len(flash) == 2 and all(
+        n.startswith("flash_fwd") and n.endswith(
+            f"(bf16[8,{group},3072,128], f32[8,{group},1,3072])")
+        for n in flash), flash
+    assert not matched("decode", "serve_pangu_prefill_attn_roofline")
+    assert not _named(_mosaic_calls(texts["decode"]), "paged_decode")
+
+    cache_bytes = 2 * math.prod(pool_shape)
+    chip = 15.75 * 2 ** 30
+    for name, program, scratch in (("decode", decode, 0.1e9),
+                                   ("prefill", prefill, 4.2e9)):
+        memory = program.memory_analysis()
+        assert memory.alias_size_in_bytes >= cache_bytes, name
+        assert memory.temp_size_in_bytes < scratch, name
+        assert (memory.argument_size_in_bytes
+                + memory.temp_size_in_bytes) < 0.75 * chip, name
+
+
+def test_the_latent_kernel_compiles_for_a_longer_table(one_chip):
+    """``latent_decode`` alone at 128 heads over a 640-wide row with a
+    table of 8,192 pages (131,072 positions, the published context): one
+    Mosaic call, the pool an operand left in HBM."""
+    from scaletorch_tpu.ops.pallas.paged_attention import (
+        pallas_latent_decode_attention,
+    )
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = jax.jit(lambda q, pool, tables, pos: (
+        pallas_latent_decode_attention(
+            q, pool, tables, pos, layer=jnp.int32(1), value_width=512,
+            scale=192 ** -0.5))).lower(
+        arg((2, 128, 640), jnp.bfloat16),
+        arg((2, 16385, 1, 16, 640), jnp.bfloat16),
+        arg((2, 8192), jnp.int32), arg((2,), jnp.int32)).compile().as_text()
+    calls = _named(_mosaic_calls(text), "latent_decode")
+    assert len(calls) == 1, calls
+
+
+def test_the_flash_forward_takes_a_value_width_of_its_own(one_chip):
+    """Keys 192 and values 128 wide (latent attention's expanded heads):
+    one ``flash_fwd`` whose output and accumulator are the value's
+    width; a call with one width lowers to what it lowered to."""
+    from scaletorch_tpu.ops.pallas.flash import flash_forward_with_lse
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def lowered(dk, dv):
+        return jax.jit(lambda q, k, v: flash_forward_with_lse(
+            q, k, v, causal=True)).lower(
+            arg((1, 4, 1024, dk)), arg((1, 4, 1024, dk)),
+            arg((1, 4, 1024, dv)))
+
+    text = lowered(192, 128).compile().as_text()
+    names = [n for n in _short_names(text) if n.startswith("flash_fwd")]
+    assert len(names) == 1 and names[0].endswith(
+        "(bf16[1,4,1024,128], f32[1,4,1,1024])"), names
+    names = [n for n in _short_names(lowered(128, 128).compile().as_text())
+             if n.startswith("flash_fwd")]
+    assert len(names) == 1 and names[0].endswith(
+        "(bf16[1,4,1024,128], f32[1,4,1,1024])"), names
+
+
 @pytest.mark.parametrize("block_t", [128, 256])
 def test_ssm_scan_kernel_compiles_at_the_cell_s_shape(one_chip, block_t):
     """One Mamba layer's prefill call: 8 x 3,072 rows of 5,120 channels,
