@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Format, Layout
 
-from scaletorch_tpu.inference.kv_cache import carries_state
+from scaletorch_tpu.inference.kv_cache import carries_state, no_prefix_reason
 from scaletorch_tpu.inference.routing_counters import step_counts
 from scaletorch_tpu.inference.sampling import (
     SamplingParams,
@@ -199,7 +199,12 @@ def make_paged_prefill_step(
     write garbage into the slot's own later pages or the TRASH page —
     invisible, because the j <= p attention mask never reaches past the
     current position and decode overwrites position p before attending
-    to it. The first token samples from the logits at row
+    to it. Whether any admitted row has a prefix there is one predicate
+    a call (``PagedKVIO``'s ``prefix_hit``): without one every prompt
+    attends to itself in key blocks and no layer reads the pool; a
+    family that refuses prefix sharing (``kv_cache.no_prefix_reason``)
+    never has one, and its program holds no read of the pool. The
+    first token samples from the logits at row
     ``tail_len - 1`` with the slot's (seed, prompt_len - 1) key: the
     forward is told that row (``logit_rows``), so its final norm and
     head run on [B, 1, hidden] as the decode step's do and no
@@ -230,6 +235,8 @@ def make_paged_prefill_step(
     # place alike (one that cannot take ``row_mask``, or the
     # ``logit_rows`` every prefill names, fails at the trace)
     row_masked = carries_state(cfg)
+    # whether a row of a call can continue a prefix that lies in the pool
+    shares_prefixes = no_prefix_reason(cfg) is None
 
     def prefill(params, tokens, tail_lens, starts, write_mask,
                 page_tables, pool, base_keys, *routing):
@@ -239,7 +246,10 @@ def make_paged_prefill_step(
         b, p = tokens.shape
         rows = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
         positions = starts[:, None] + rows
-        kv_io = PagedKVIO(page_tables, page_size, seq_limit=seq_limit)
+        kv_io = PagedKVIO(
+            page_tables, page_size, seq_limit=seq_limit,
+            prefix_hit=shares_prefixes
+            and jnp.any(write_mask & (starts > 0)))
         counted = {}
         if routing_counts or row_masked:
             counted = dict(

@@ -607,6 +607,8 @@ class DisaggregatedEngine(InferenceEngine):
                 jnp.asarray(write_mask), jnp.asarray(tables),
                 self.prefill_cache, jnp.asarray(self._prefill_keys))
         self.metrics.prefill_calls += 1
+        # the slice holds no prefix: every row starts at position 0
+        self.metrics.prefill_calls_self_attended += 1
         self.metrics.prefill_positions_run += tokens.size
         self.metrics.prefill_positions_admitted += sum(
             len(req.prompt) for _, req, _ in admitted)

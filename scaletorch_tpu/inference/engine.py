@@ -252,6 +252,10 @@ class EngineMetrics:
     # ... of them, dispatched with a decode step still on the device
     # (behind it, the step not read first): all but the cold starts
     prefill_calls_behind_flight: int = 0
+    # ... of them, with no admitted row after a prefix in the pool:
+    # every prompt attended to itself in key blocks, no layer read the
+    # pool (``PagedKVIO``'s ``prefix_hit``)
+    prefill_calls_self_attended: int = 0
     # rows x length of the shape each prefill call ran at, and of them
     # the admitted prompts' tail tokens: run / admitted is what a call
     # pads (1 is none)
@@ -359,6 +363,7 @@ class EngineMetrics:
             "tokens_generated": self.tokens_generated,
             "prefill_calls": self.prefill_calls,
             "prefill_calls_behind_flight": self.prefill_calls_behind_flight,
+            "prefill_calls_self_attended": self.prefill_calls_self_attended,
             "prefill_positions_run": self.prefill_positions_run,
             "prefill_positions_admitted": self.prefill_positions_admitted,
             "decode_steps": self.decode_steps,
@@ -1641,15 +1646,18 @@ class InferenceEngine:
                 tail_lens[row] = len(tail)
                 starts[row] = shared
                 write_mask[row] = True
+        self_attended = not starts.any()
         t0 = time.monotonic()
         for i in admitted:
             self._req_event("b", self._slots[i].request, "req.prefill",
                             prefix_hit=self._slots[i].prefix_hit,
-                            rows=rows, length=length)
+                            rows=rows, length=length,
+                            self_attended=self_attended)
         with self._phase("engine.tick.prefill"):
             first, finite = self._call_prefill(
                 tokens, tail_lens, starts, write_mask, tables, base_keys)
         self.metrics.prefill_calls += 1
+        self.metrics.prefill_calls_self_attended += self_attended
         self.metrics.prefill_positions_run += rows * length
         self.metrics.prefill_positions_admitted += sum(tails)
         if self._by_slot:

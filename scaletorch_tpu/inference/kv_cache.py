@@ -743,17 +743,27 @@ class PagedKVIO:
     ``max_seq`` (the operand shapes of the contiguous reference);
     ``kernel`` forces the pair for both (None = auto, one predicate:
     ``paged_attention.in_place_pair``).
+
+    ``prefix_hit`` is what the caller knows of a multi-row call's rows:
+    whether any of them continues a prefix that lies in the pool
+    (``paged_attention``: a traced bool, the prefill step's ``starts``,
+    chooses on the device between the attention over the gathered view
+    and the prompts' attention to themselves in key blocks; False, a
+    family that refuses prefix sharing, leaves the gather out of the
+    program; None, not said: every call reads the pool).
     """
 
     def __init__(self, page_tables: jax.Array, page_size: int, *,
                  seq_limit: Optional[int] = None,
                  kernel: Optional[bool] = None,
-                 interpret: bool = False) -> None:
+                 interpret: bool = False,
+                 prefix_hit: Any = None) -> None:
         self.page_tables = page_tables
         self.page_size = page_size
         self.seq_limit = seq_limit
         self.kernel = kernel
         self.interpret = interpret
+        self.prefix_hit = prefix_hit
 
     def write(self, pool: jax.Array, layer: jax.Array, new: jax.Array,
               positions: jax.Array,
@@ -763,15 +773,20 @@ class PagedKVIO:
             layer=layer, kernel=self.kernel, interpret=self.interpret)
 
     def attend(self, q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-               layer: jax.Array, q_positions: jax.Array) -> jax.Array:
+               layer: jax.Array, q_positions: jax.Array,
+               own: Optional[Tuple[jax.Array, jax.Array]] = None
+               ) -> jax.Array:
+        """``q`` [B, Hq, S, D] against ``layer`` of the pool, which
+        already holds the call's own K/V; ``own`` is that K/V as the
+        call made it ([B, Hkv, S, D] each), for a multi-row call none
+        of whose rows has a prefix in the pool (``prefix_hit``)."""
         return paged_attention(
             q, pool_k, pool_v, self.page_tables, q_positions,
             page_size=self.page_size, layer=layer, seq_limit=self.seq_limit,
             kernel=None if self.kernel is None
             else self.kernel and q.shape[2] == 1,
-            interpret=self.interpret,
+            interpret=self.interpret, own=own, prefix_hit=self.prefix_hit,
         )
-
 
     def write_latent(self, pool: jax.Array, layer: jax.Array, c: jax.Array,
                      k_r: jax.Array, positions: jax.Array,

@@ -267,9 +267,10 @@ def forward_cached(
         def heads(t):
             return t.reshape(b, s, cfg.n_head, cfg.head_dim).transpose(0, 2, 1, 3)
 
-        ck = kv_io.write(ck, index, heads(k), positions, write_mask)
-        cv = kv_io.write(cv, index, heads(v), positions, write_mask)
-        o = kv_io.attend(heads(q), ck, cv, index, positions)
+        q, k, v = heads(q), heads(k), heads(v)
+        ck = kv_io.write(ck, index, k, positions, write_mask)
+        cv = kv_io.write(cv, index, v, positions, write_mask)
+        o = kv_io.attend(q, ck, cv, index, positions, own=(k, v))
         o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_embd)
         h = h + o @ layer["attn_proj"].astype(cdt)
 

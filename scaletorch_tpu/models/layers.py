@@ -353,7 +353,12 @@ class DenseKVIO:
         return jax.lax.dynamic_update_index_in_dim(cache, updated, layer, 0)
 
     def attend(self, q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
-               layer: jax.Array, q_positions: jax.Array) -> jax.Array:
+               layer: jax.Array, q_positions: jax.Array,
+               own: Optional[Tuple[jax.Array, jax.Array]] = None
+               ) -> jax.Array:
+        """``own`` (the call's K/V, which ``PagedKVIO.attend`` may
+        attend to in place of the pool) is not read: the reference
+        reads its cache at every call."""
         def at_layer(cache):
             return jax.lax.dynamic_index_in_dim(
                 cache, layer, 0, keepdims=False)

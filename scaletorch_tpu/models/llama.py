@@ -585,7 +585,7 @@ def attention_mix_cached(
         q, k = apply_rotary_pos_emb(q, k, cos, sin)
     cache_k = kv_io.write(cache_k, index, k, positions, write_mask)
     cache_v = kv_io.write(cache_v, index, v, positions, write_mask)
-    attn = kv_io.attend(q, cache_k, cache_v, index, positions)
+    attn = kv_io.attend(q, cache_k, cache_v, index, positions, own=(k, v))
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return attn @ layer["o_proj"].astype(cdt), cache_k, cache_v
 

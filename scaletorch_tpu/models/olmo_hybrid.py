@@ -601,7 +601,7 @@ class SelfKV:
     def write(self, cache, layer, new, positions, write_mask):
         return new
 
-    def attend(self, q, k, v, layer, positions):
+    def attend(self, q, k, v, layer, positions, own=None):
         return sdpa_attention(q, k, v, causal=True)
 
 

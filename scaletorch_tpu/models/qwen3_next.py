@@ -288,7 +288,7 @@ def gated_attention_mix_cached(
     q, k = apply_rotary_pos_emb(q, k, cos, sin)
     cache_k = kv_io.write(cache_k, index, k, positions, write_mask)
     cache_v = kv_io.write(cache_v, index, v, positions, write_mask)
-    attn = kv_io.attend(q, cache_k, cache_v, index, positions)
+    attn = kv_io.attend(q, cache_k, cache_v, index, positions, own=(k, v))
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
     with jax.named_scope("attn.output_gate"):
         attn = (attn.astype(F32) * jax.nn.sigmoid(

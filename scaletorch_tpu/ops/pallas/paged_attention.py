@@ -73,7 +73,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -773,6 +773,8 @@ def paged_attention(
     kernel: Optional[bool] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    own: Optional[Tuple[jax.Array, jax.Array]] = None,
+    prefix_hit: Any = None,
 ) -> jax.Array:
     """Attention against the paged cache, kernel or fallback.
 
@@ -782,18 +784,41 @@ def paged_attention(
     Pallas kernel for single-token decode when ``in_place_pair`` (the
     platform is ``tpu`` and ``kernel_serves`` the head_dim), the lax
     gather + ``cached_sdpa_attention`` everywhere else — other
-    platforms, narrow heads and every multi-row call of a family that
-    reads its prompt's prefix out of the pool (a family that refuses
-    prefix sharing attends a prompt to itself and never comes here with
-    S > 1: ``ops/flash_attention.prefill_self_attention``).
-    ``seq_limit`` crops the gathered view to the engine's ``max_seq``
-    so the fallback's reduction has the contiguous reference's operand
-    shapes. ``window``: a window layer's mask and first page
-    (``pallas_paged_decode_attention``), by position in the fallback.
+    platforms, narrow heads and a multi-row call that reads a prompt's
+    prefix out of the pool. ``seq_limit`` crops the gathered view to the
+    engine's ``max_seq`` so the fallback's reduction has the contiguous
+    reference's operand shapes. ``window``: a window layer's mask and
+    first page (``pallas_paged_decode_attention``), by position in the
+    fallback.
+
+    A multi-row call may bring ``own``, the K/V [B, Hkv, S, D] it made
+    (and has already written to the pool), and ``prefix_hit``, what its
+    caller knows of its rows: whether any of them continues a prefix
+    that lies in the pool. False (no row can: a family that refuses
+    prefix sharing): each prompt attends to itself in key blocks
+    (``ops/flash_attention.prefill_self_attention``: no score array)
+    and nothing here reads the pool. A traced bool (the prefill step's
+    ``starts``): one ``lax.cond`` between that and the fallback. Where
+    the Mosaic pair writes the pool (``in_place_pair``) the gather is
+    part of the fallback's branch and a call without a hit does not
+    pay for it; where the lax pair does, the gather stays outside the
+    choice and only the attention over the gathered view is chosen:
+    the scatter keeps such a pool pages-minor, and as an operand of a
+    branch it was copied whole, a layer (an AOT compile of a 64-wide
+    head's program showed it). None, or no ``own``: the fallback, as
+    for every call before.
     """
     from scaletorch_tpu.models.layers import cached_sdpa_attention
+    from scaletorch_tpu.ops.flash_attention import prefill_self_attention
 
     s = q.shape[2]
+    chooses = s > 1 and own is not None and prefix_hit is not None
+
+    def to_itself():
+        return prefill_self_attention(q, *own, scale=scale, window=window)
+
+    if chooses and prefix_hit is False:
+        return to_itself()
     use_kernel = kernel
     if use_kernel is None:
         use_kernel = s == 1 and in_place_pair(q.shape[3])
@@ -808,10 +833,25 @@ def paged_attention(
             layer=layer, scale=scale, interpret=interpret, window=window,
         )
         return out[:, :, None, :]
-    k = paged_gather_kv(pool_k, page_tables, layer)
-    v = paged_gather_kv(pool_v, page_tables, layer)
-    if seq_limit is not None and k.shape[2] > seq_limit:
-        k = k[:, :, :seq_limit, :]
-        v = v[:, :, :seq_limit, :]
-    return cached_sdpa_attention(q, k, v, q_positions, scale=scale,
-                                 window=window)
+
+    def gathered():
+        k = paged_gather_kv(pool_k, page_tables, layer)
+        v = paged_gather_kv(pool_v, page_tables, layer)
+        if seq_limit is not None and k.shape[2] > seq_limit:
+            return k[:, :, :seq_limit, :], v[:, :, :seq_limit, :]
+        return k, v
+
+    def from_pool(k, v):
+        return cached_sdpa_attention(q, k, v, q_positions, scale=scale,
+                                     window=window)
+
+    if not chooses:
+        return from_pool(*gathered())
+    if kernel is None and in_place_pair(q.shape[3]):
+        view = gathered         # in the branch: nothing read without a hit
+    else:
+        k_v = gathered()        # the lax pair's pool: outside the choice
+
+        def view():
+            return k_v
+    return jax.lax.cond(prefix_hit, lambda: from_pool(*view()), to_itself)

@@ -24,6 +24,7 @@ from scaletorch_tpu.inference.kv_cache import (
     PagedKVIO,
     carries_state,
     init_paged_kv_cache,
+    no_prefix_reason,
 )
 from scaletorch_tpu.inference.routing_counters import ROUTING_COUNTERS
 from scaletorch_tpu.models import gpt_moe, llama
@@ -91,10 +92,15 @@ def _all_rows_step(cfg, routing):
         kw = {}
         if routing or carries_state(cfg):
             kw["row_mask"] = write_mask[:, None] & (rows < tail_lens[:, None])
+        # the step's own choice of attention: it differs from the step
+        # in the rows its head multiplies alone
+        hit = (no_prefix_reason(cfg) is None
+               and jnp.any(write_mask & (starts > 0)))
         logits, new_pool = fwd(
             params, tokens, cfg, tuple(pool),
             positions=starts[:, None] + rows, write_mask=write_mask,
-            kv_io=PagedKVIO(tables, PAGE, seq_limit=SEQ), **kw)[:2]
+            kv_io=PagedKVIO(tables, PAGE, seq_limit=SEQ, prefix_hit=hit),
+            **kw)[:2]
         assert logits.shape == (SLOTS, PREFILL, cfg.vocab_size)
         last = jnp.take_along_axis(
             logits, (tail_lens - 1)[:, None, None], axis=1)[:, 0]
@@ -124,7 +130,10 @@ def stepped(request):
                jnp.zeros((SLOTS,), jnp.int32), jnp.arange(SLOTS) == hit,
                tables, pool, keys, *extra)
     pool = out[3]
-    args = (params, tokens, jnp.asarray(TAIL_LENS), jnp.asarray(STARTS),
+    # a family that refuses prefix sharing starts every row at 0 (its
+    # step holds no read of a prefix): the slot is one more cold tail
+    starts = STARTS * (no_prefix_reason(cfg) is None)
+    args = (params, tokens, jnp.asarray(TAIL_LENS), jnp.asarray(starts),
             jnp.asarray(WRITTEN), tables, pool)
     got = step(*args, keys, *extra)
     want_last, want_pool = _all_rows_step(cfg, routing)(*args)
