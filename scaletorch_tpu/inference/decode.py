@@ -48,7 +48,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Format, Layout
 
-from scaletorch_tpu.inference.kv_cache import carries_state, no_prefix_reason
+from scaletorch_tpu.inference.kv_cache import (
+    RING_FIELDS,
+    SLOT_FIELDS,
+    carries_state,
+    no_prefix_reason,
+)
 from scaletorch_tpu.inference.routing_counters import step_counts
 from scaletorch_tpu.inference.sampling import (
     SamplingParams,
@@ -78,6 +83,13 @@ def counts_routing(cfg) -> bool:
     (``return_routing``); such a step takes the row mask of a
     state-carrying model and the routing accumulator side by side."""
     return family_of(cfg).counts_routing
+
+
+def rows_name_slots(cfg) -> bool:
+    """Whether a prefill row of the config's model names its slot: its
+    cache carries state by slot and its family's column says the write
+    at a slot id is tested for it (``models/families.py``)."""
+    return carries_state(cfg) and family_of(cfg).rows_name_slots
 
 
 def prefill_shapes(max_slots: int,
@@ -132,11 +144,6 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
     """
 
     def fill_slots(cache, mask, value, slot_mask=None):
-        from scaletorch_tpu.inference.kv_cache import (
-            RING_FIELDS,
-            SLOT_FIELDS,
-        )
-
         vals = tuple(value) if isinstance(value, tuple) \
             else (value,) * len(cache)
         names = getattr(cache, "_fields", ("",) * len(cache))
@@ -226,6 +233,21 @@ def make_paged_prefill_step(
     pool the step donates and returns is that model's whole cache
     (``kv_cache.HybridCache``).
 
+    Where a row names its slot (``rows_name_slots``: the delta-rule
+    families) the step takes ``slot_ids [B] i32`` after ``base_keys``
+    (before the routing accumulator) and a call's rows are its admitted
+    prompts. No such family shares a prefix, so every row is at position
+    0 and begins from ``S = 0`` and an empty tail: nothing of the
+    ``[layers, slots, ...]`` buffers is read. The forward runs on
+    ``[layers, B, ...]`` of zeros, where a row is its own slot as ever,
+    and each written row's final state and tail land at ``[layer,
+    slot_ids[row]]`` of the donated cache in one scatter a buffer; a row
+    outside ``write_mask``, or one whose id is past the last slot,
+    writes nothing, and every other slot's state and tail pass through
+    bit for bit. What comes back is ``SlotRows`` around the jitted
+    program: handed ``slot_ids`` it is the program, handed today's
+    eight operands it serves any ``[B, P]`` with a row its own slot.
+
     ``param_orders`` (``chosen_orders``): the step takes the parameters
     as ``place_params`` stored them and reads them ``in_model_order``.
     """
@@ -237,6 +259,7 @@ def make_paged_prefill_step(
     row_masked = carries_state(cfg)
     # whether a row of a call can continue a prefix that lies in the pool
     shares_prefixes = no_prefix_reason(cfg) is None
+    by_id = rows_name_slots(cfg)
 
     def prefill(params, tokens, tail_lens, starts, write_mask,
                 page_tables, pool, base_keys, *routing):
@@ -244,6 +267,12 @@ def make_paged_prefill_step(
 
         params = in_model_order(params, param_orders)
         b, p = tokens.shape
+        if by_id:
+            slot_ids, *routing = routing
+            held = {name: getattr(pool, name) for name in SLOT_FIELDS}
+            pool = pool._replace(**{
+                name: jnp.zeros((buf.shape[0], b) + buf.shape[2:], buf.dtype)
+                for name, buf in held.items()})
         rows = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
         positions = starts[:, None] + rows
         kv_io = PagedKVIO(
@@ -261,18 +290,70 @@ def make_paged_prefill_step(
             positions=positions, write_mask=write_mask, kv_io=kv_io,
             logit_rows=tail_lens - 1, **counted,
         )
+        new_pool = type(pool)(*new_pool)
+        if by_id:
+            # past the last slot: dropped
+            at = jnp.where(write_mask, slot_ids, held["state"].shape[1])
+            new_pool = new_pool._replace(**{
+                name: buf.at[:, at].set(getattr(new_pool, name), mode="drop")
+                for name, buf in held.items()})
         last = logits[:, 0, :]
         keys = slot_keys(base_keys, starts + tail_lens - 1)
         first = sample(last, keys, sampling)
-        out = (first, last.astype(jnp.float32), finite_mask(last),
-               type(pool)(*new_pool))
+        out = (first, last.astype(jnp.float32), finite_mask(last), new_pool)
         if routing_counts:
             out += (routing[0] + step_counts(counts[0], prefill=True),)
         return out
 
-    return jax.jit(
+    step = jax.jit(
         prefill, donate_argnums=(6,) if _resolve_donate(donate_cache) else ()
     )
+    return SlotRows(step, int(routing_counts)) if by_id else step
+
+
+class SlotRows:
+    """The prefill step of a family whose rows name their slots
+    (``make_paged_prefill_step``), as an engine holds it: ONE jitted
+    program, whatever it is handed. Called with ``slot_ids`` after
+    ``base_keys`` it is that program (an admission: one row a call).
+    Called with the eight operands every family's step takes (the
+    benchmark's check, which hands ``[max_slots, prefill_len]`` with row
+    b = slot b) it serves any ``[B, P]`` by running the program once a
+    written row at ``[1, P]`` with the row as its slot id, the cache
+    (and an MoE model's accumulator) handed from call to call, and
+    joins the results ``[B]``; a row that is not written comes back as
+    zeros (not finite), as nothing reads it. Such a caller is idle:
+    reading its operands back to cut them by row waits for nothing.
+    Attributes (``_cache_size``, ``lower``) are the jitted
+    function's, so the compile count is the one program's."""
+
+    def __init__(self, step: Callable, trailing: int) -> None:
+        # ``trailing``: operands after ``slot_ids`` (the accumulator)
+        self._step, self._trailing = step, trailing
+
+    def __call__(self, params, tokens, tail_lens, starts, write_mask,
+                 page_tables, pool, base_keys, *rest):
+        if len(rest) > self._trailing:
+            return self._step(params, tokens, tail_lens, starts, write_mask,
+                              page_tables, pool, base_keys, *rest)
+        write_mask, base_keys = np.asarray(write_mask), np.asarray(base_keys)
+        lead = [np.asarray(a) for a in (
+            tokens, tail_lens, starts, write_mask, page_tables)]
+        done = {}
+        # (no row written: one masked call, for the results' shapes)
+        for row in np.flatnonzero(write_mask) if write_mask.any() else (0,):
+            at = slice(row, row + 1)
+            first, logits, finite, pool, *rest = self._step(
+                params, *(a[at] for a in lead), pool, base_keys[at],
+                np.array([row], np.int32), *rest)
+            done[row] = (first, logits, finite)
+        blank = [jnp.zeros_like(a) for a in next(iter(done.values()))]
+        joined = [jnp.concatenate(parts) for parts in zip(*(
+            done.get(row, blank) for row in range(len(write_mask))))]
+        return (*joined, pool, *rest)
+
+    def __getattr__(self, item):
+        return getattr(self._step, item)
 
 
 def make_paged_decode_step(

@@ -17,7 +17,11 @@ TPU execution discipline:
     admitted prompts' tails, so one short prompt does not pay for
     ``max_slots x prefill_len`` positions (``warm_prefill_shapes`` runs
     every listed shape once, before the first request: a server
-    compiles nothing under traffic);
+    compiles nothing under traffic). Where the cache keeps state by
+    slot and a prefill row NAMES its slot (the delta-rule families:
+    ``decode.rows_name_slots``) the one program is one row, an
+    admitted prompt a call; where a row IS its slot (jamba, the window
+    rings) the one call runs every slot;
   * the decode loop runs ONE STEP AHEAD of the host: with step n on the
     device a tick dispatches step n+1, fed n's sampled tokens as the
     device array they are, and only then reads n back and emits it —
@@ -112,6 +116,7 @@ from scaletorch_tpu.inference.decode import (
     orders_key,
     place_params,
     prefill_shapes,
+    rows_name_slots,
     store_orders,
 )
 from scaletorch_tpu.inference.routing_counters import (
@@ -455,16 +460,18 @@ class _InFlight(NamedTuple):
 
 
 class _Admission(NamedTuple):
-    """A prefill call on the device whose result the host has not
-    read: its first tokens ``[rows]`` and finite mask as the device
-    arrays they are, the admitted slots with the request each was bound
-    to, the row of the call each slot took, and when the call was
-    dispatched (``prefill_s`` runs from there to the readback)."""
+    """The prefill calls of one tick's admission, on the device, their
+    results not read by the host (one call; one an admitted prompt
+    where a row names its slot): each call's first tokens ``[rows]``
+    and finite mask as the device arrays they are, the admitted slots
+    with the request each was bound to, the (call, row) each slot took,
+    and when the first call was dispatched (``prefill_s`` runs from
+    there to the readback)."""
 
-    first: Any
-    finite: Any
+    first: List[Any]
+    finite: List[Any]
     bound: List[Tuple[int, Request]]
-    row_of: Dict[int, int]
+    row_of: Dict[int, Tuple[int, int]]
     dispatched_t: float
 
 
@@ -699,6 +706,9 @@ class InferenceEngine:
         self._stateful = carries_state(cfg)
         self._window = window_of(cfg)
         self._by_slot = self._stateful or self._window is not None
+        # whether a prefill row names its slot: a call's rows are then
+        # its admitted prompts (else, by slot, a row IS its slot)
+        self._rows_name_slots = rows_name_slots(cfg)
         # tokens one slot's ring holds in a window layer
         self._ring_tokens = (
             0 if self._window is None
@@ -754,11 +764,14 @@ class InferenceEngine:
                      forward_fn=forward_fn, donate_cache=donate_cache,
                      routing_counts=counts)
         # the (rows, length) a prefill call may take, fewest positions
-        # first. State, convolution tail and rings are indexed by the
-        # row itself: such a cache keeps the one call over every slot
-        # (a row subset there is a gather and scatter of state by slot)
+        # first. Where state, convolution tail or rings are indexed by
+        # the row itself the cache keeps the one call over every slot;
+        # where a row names its slot, ONE row: a second program is 3-6 s
+        # of trace and lowering in such a family, and ``setup_s`` pays
+        # for every listed shape (PERF.md, PRs 43 and 53)
         self.prefill_shapes = (
-            ((max_slots, self.prefill_len),) if self._by_slot
+            ((1, self.prefill_len),) if self._rows_name_slots
+            else ((max_slots, self.prefill_len),) if self._by_slot
             else prefill_shapes(max_slots, self.prefill_len))
         self._decode = make_paged_decode_step(cfg, sampling, **steps)
         orders = self._param_orders(params, steps)
@@ -1541,14 +1554,14 @@ class InferenceEngine:
                 and self._queue[0].request_id != self._page_starved
                 and not all(s.active for s in self._slots))
 
-    def _call_prefill(self, tokens, tail_lens, starts, write_mask, tables,
-                      base_keys, *, counted: bool = True):
-        """One call of the prefill step on host-built operands
-        ``[rows, ...]``, the cache donated and taken back: the one place
-        an admission and ``warm_prefill_shapes`` call it from, so that
-        both reach the same compiled program of a shape (the default
-        device is part of a jitted call's signature). ``counted`` False:
-        an MoE model's routing counters are left as they were."""
+    def _call_prefill(self, *operands, counted: bool = True):
+        """One call of the prefill step on host-built ``operands``
+        ``[rows, ...]`` (``_prefill_operands``), the cache donated and
+        taken back: the one place an admission and
+        ``warm_prefill_shapes`` call it from, so that both reach the
+        same compiled program of a shape (the default device is part of
+        a jitted call's signature). ``counted`` False: an MoE model's
+        routing counters are left as they were."""
         step = self._prefill if counted else getattr(
             self._prefill, "uncounted", self._prefill)
         with self.on_device():
@@ -1557,9 +1570,46 @@ class InferenceEngine:
             # and a call behind a step in flight has that step's time
             # to get itself and the next step dispatched
             first, _logits, finite, self.cache = step(
-                self.params, tokens, tail_lens, starts, write_mask, tables,
-                self.cache, base_keys)
+                self.params, *operands[:5], self.cache, *operands[5:])
         return first, finite
+
+    def _prefill_operands(self, row_slots: List[int], taken, shape=None):
+        """The host-built operands of one prefill call whose leading
+        rows are the slots ``row_slots``, at ``shape`` or the shape of
+        ``prefill_shapes`` with the fewest positions that holds them:
+        tokens, tail lengths, starts, write mask, page tables, base
+        keys and, where a row names its slot, the slot ids. ``taken``:
+        ``{slot: (the prompt's tail, tokens shared before it)}`` of the
+        admitted slots, whose rows are written; every other row is
+        masked and one token long, and a row past ``row_slots`` carries
+        a TRASH table (and the id past the last slot)."""
+        tails = [len(taken[i][0]) for i in row_slots if i in taken]
+        rows, length = shape or next(
+            shape for shape in self.prefill_shapes
+            if shape[0] >= len(row_slots) and shape[1] >= max(tails))
+        tokens = np.zeros((rows, length), np.int32)
+        tail_lens = np.ones(rows, np.int32)
+        starts = np.zeros(rows, np.int32)
+        write_mask = np.zeros(rows, bool)
+        tables = np.full((rows, self._pages_per_slot), TRASH_PAGE, np.int32)
+        tables[: len(row_slots)] = self._tables[row_slots]
+        # a slot's sampling key stays its own: a request's tokens do
+        # not depend on the shape that admitted it
+        base_keys = np.zeros((rows, 2), np.uint32)
+        base_keys[: len(row_slots)] = self._base_keys[row_slots]
+        for row, i in enumerate(row_slots):
+            if i in taken:
+                tail, shared = taken[i]
+                tokens[row, : len(tail)] = tail
+                tail_lens[row] = len(tail)
+                starts[row] = shared
+                write_mask[row] = True
+        operands = (tokens, tail_lens, starts, write_mask, tables, base_keys)
+        if self._rows_name_slots:
+            slot_ids = np.full(rows, self.max_slots, np.int32)
+            slot_ids[: len(row_slots)] = row_slots
+            operands += (slot_ids,)
+        return operands
 
     def warm_prefill_shapes(self) -> None:
         """Run the prefill step once at every shape of
@@ -1572,32 +1622,31 @@ class InferenceEngine:
         An engine that is not warmed compiles a shape at the first
         admission that takes it. Largest first and nothing waited for:
         the device runs the full shape while the host traces the next."""
-        for rows, length in reversed(self.prefill_shapes):
+        for shape in reversed(self.prefill_shapes):
             self._call_prefill(
-                np.zeros((rows, length), np.int32), np.ones(rows, np.int32),
-                np.zeros(rows, np.int32), np.zeros(rows, bool),
-                np.full((rows, self._pages_per_slot), TRASH_PAGE, np.int32),
-                np.zeros((rows, 2), np.uint32), counted=False)
+                *self._prefill_operands([], {}, shape), counted=False)
 
     def _admit(self) -> Optional[_Admission]:
         """Move queued requests into free slots while the page pool can
-        cover them, and DISPATCH their prefill: ONE batched prefill call
-        regardless of how many were admitted, at the shape of
-        ``prefill_shapes`` with the fewest positions that holds them. A
-        row of the call is an admitted slot, in admission order (every
-        slot in turn where the cache is by slot); rows past them are
+        cover them, and DISPATCH their prefill at the shape of
+        ``prefill_shapes`` with the fewest positions that holds them:
+        ONE batched call regardless of how many were admitted, a row
+        of which is an admitted slot, in admission order (every slot in
+        turn where the cache is by slot and a row is its slot); or,
+        where a row names its slot, one call of the one-row program an
+        admitted prompt, back to back. Rows past the admitted are
         padding: masked, one token, a TRASH table. Nothing is read
-        back: the call goes on the device behind the step in flight, if
+        back: the calls go on the device behind the step in flight, if
         there is one, which is not read first, and ``_read_admission``
-        takes the call's result once the step after it has been
+        takes their results once the step after them has been
         dispatched too. None when nothing was admitted."""
         with self._phase("engine.tick.admit"):
             if not self._admission_due():
                 return None
             free = [i for i, s in enumerate(self._slots) if not s.active]
             self._release_tokens()
-            # (slot, the prompt's tail to prefill, tokens shared before it)
-            taken: List[Tuple[int, Sequence[int], int]] = []
+            # slot: (the prompt's tail to prefill, tokens shared before it)
+            taken: Dict[int, Tuple[Sequence[int], int]] = {}
             for i in free:
                 if not self._queue:
                     break
@@ -1614,52 +1663,38 @@ class InferenceEngine:
                 self._tables[i, :] = TRASH_PAGE
                 self._tables[i, : len(pages)] = pages
                 self._tables_dev = None
-                taken.append((i, req.prompt[shared:], shared))
+                taken[i] = (req.prompt[shared:], shared)
                 if shared:
                     self.metrics.prefix_hits += 1
                     self.metrics.prefill_tokens_saved += shared
                     self._slots[i].prefix_hit = True
             if not taken:
                 return None
-            admitted = [i for i, _, _ in taken]
-            row_slots = (list(range(self.max_slots)) if self._by_slot
-                         else admitted)
-            row_of = {slot: row for row, slot in enumerate(row_slots)}
-            tails = [len(tail) for _, tail, _ in taken]
-            rows, length = next(
-                shape for shape in self.prefill_shapes
-                if shape[0] >= len(row_slots) and shape[1] >= max(tails))
-            tokens = np.zeros((rows, length), np.int32)
-            tail_lens = np.ones(rows, np.int32)
-            starts = np.zeros(rows, np.int32)
-            write_mask = np.zeros(rows, bool)
-            tables = np.full(
-                (rows, self._pages_per_slot), TRASH_PAGE, np.int32)
-            tables[: len(row_slots)] = self._tables[row_slots]
-            # a slot's sampling key stays its own: a request's tokens do
-            # not depend on the shape that admitted it
-            base_keys = np.zeros((rows, 2), np.uint32)
-            base_keys[: len(row_slots)] = self._base_keys[row_slots]
-            for i, tail, shared in taken:
-                row = row_of[i]
-                tokens[row, : len(tail)] = tail
-                tail_lens[row] = len(tail)
-                starts[row] = shared
-                write_mask[row] = True
-        self_attended = not starts.any()
+            admitted = list(taken)
+            calls = ([[i] for i in admitted] if self._rows_name_slots
+                     else [list(range(self.max_slots))] if self._by_slot
+                     else [admitted])
+            row_of = {slot: (call, row) for call, row_slots in enumerate(calls)
+                      for row, slot in enumerate(row_slots) if slot in taken}
+            operands = [self._prefill_operands(row_slots, taken)
+                        for row_slots in calls]
         t0 = time.monotonic()
         for i in admitted:
+            tokens, _, starts, *_ = operands[row_of[i][0]]
             self._req_event("b", self._slots[i].request, "req.prefill",
                             prefix_hit=self._slots[i].prefix_hit,
-                            rows=rows, length=length,
-                            self_attended=self_attended)
+                            rows=tokens.shape[0], length=tokens.shape[1],
+                            self_attended=not starts.any())
         with self._phase("engine.tick.prefill"):
-            first, finite = self._call_prefill(
-                tokens, tail_lens, starts, write_mask, tables, base_keys)
-        self.metrics.prefill_calls += 1
-        self.metrics.prefill_calls_self_attended += self_attended
-        self.metrics.prefill_positions_run += rows * length
-        self.metrics.prefill_positions_admitted += sum(tails)
+            first, finite = zip(*(self._call_prefill(*call)
+                                  for call in operands))
+        self.metrics.prefill_calls += len(calls)
+        self.metrics.prefill_calls_self_attended += sum(
+            not starts.any() for _, _, starts, *_ in operands)
+        self.metrics.prefill_positions_run += sum(
+            tokens.size for tokens, *_ in operands)
+        self.metrics.prefill_positions_admitted += sum(
+            len(tail) for tail, _ in taken.values())
         if self._by_slot:
             # the call started every admitted slot's state from zero
             # (or its rings from the prompt)
@@ -1672,8 +1707,8 @@ class InferenceEngine:
                 (self._slots[i].position - 1) // self._ring_tokens
                 for i in admitted)
         return _Admission(
-            first, finite, [(i, self._slots[i].request) for i in admitted],
-            row_of, t0)
+            list(first), list(finite),
+            [(i, self._slots[i].request) for i in admitted], row_of, t0)
 
     def _read_admission(self, admission: _Admission) -> None:
         """Read a dispatched prefill call back and emit its first
@@ -1685,20 +1720,23 @@ class InferenceEngine:
             # about to block, with work on the device: nothing that was
             # emitted (the step in flight's tokens) waits for the call
             self._release_tokens()
-            first, finite = jax.device_get(
-                (admission.first, admission.finite))
+            # by slot, from every call's arrays in one round trip
+            first, finite = (
+                {i: by_call[call][row]
+                 for i, (call, row) in admission.row_of.items()}
+                for by_call in jax.device_get(
+                    (admission.first, admission.finite)))
         with self._phase("engine.tick.emit"):
             now = time.monotonic()
-            row_of = admission.row_of
             admitted = [i for i, _ in admission.bound]
             self._note_prefill(admitted, now - admission.dispatched_t)
-            poisoned = [i for i in admitted if not finite[row_of[i]]]
+            poisoned = [i for i in admitted if not finite[i]]
             if poisoned:
                 # skip radix registration for poison prompts — their
                 # pages hold non-finite K/V and must never be shared
                 self._quarantine(poisoned, now, where="prefill")
             for i in admitted:
-                if not finite[row_of[i]]:
+                if not finite[i]:
                     continue
                 if self.radix is not None:
                     slot = self._slots[i]
@@ -1714,7 +1752,7 @@ class InferenceEngine:
                         # from here on — exempt from quarantine clears
                         # and shareable by later admissions
                         self._slot_frozen[i] = n
-                self._emit(i, int(first[row_of[i]]), now)
+                self._emit(i, int(first[i]), now)
             self._update_page_gauges()
             self.metrics.queue_depth = len(self._queue)
 
@@ -1873,7 +1911,7 @@ class InferenceEngine:
         anything is read."""
         admission = self._admit()
         if admission is not None and flight is not None:
-            self.metrics.prefill_calls_behind_flight += 1
+            self.metrics.prefill_calls_behind_flight += len(admission.first)
         if admission is None and flight is None:
             # slots that hold tokens and no step in flight: fed from
             # the host (an admission made by hand; nothing, when idle)
@@ -1950,13 +1988,15 @@ class InferenceEngine:
                 for i, _ in bound:
                     positions[i] = before.positions[i] + 1
             if fresh:
-                slot_of_row = np.full(
-                    admission.first.shape[0], self.max_slots, np.int32)
+                slot_of_row = [
+                    np.full(first.shape[0], self.max_slots, np.int32)
+                    for first in admission.first]
                 for i, _ in fresh:
-                    slot_of_row[admission.row_of[i]] = i
+                    call, row = admission.row_of[i]
+                    slot_of_row[call][row] = i
                     positions[i] = self._slots[i].position
-                tokens = self._merge_tokens(
-                    tokens, admission.first, slot_of_row)
+                for first, slots in zip(admission.first, slot_of_row):
+                    tokens = self._merge_tokens(tokens, first, slots)
                 bound = sorted(bound + fresh, key=lambda b: b[0])
             if not bound:
                 return None

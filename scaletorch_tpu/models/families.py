@@ -48,6 +48,18 @@ class Family:
     # HF auto-fill (the module's ``config_from_hf``) and weight loading
     # are written for it
     loads_hf: bool = False
+    # a row of a prefill call names its slot (``slot_ids``): a call's
+    # rows are its admitted prompts and the family's one prefill program
+    # is one row (``inference.decode.SlotRows``). Written and
+    # parity-tested for the two delta-rule families. The same write
+    # would serve jamba's state, and the two other by-slot shapes are a
+    # forward each (afmoe: ``RingKVIO``'s table from slot ids;
+    # pangu_ultra_moe: a page-addressed long-prompt shape), but their
+    # cells' cost functions charge ``max_slots`` rows to every prefill
+    # call they find, so a one-row call would read 237-348 % of a
+    # roofline there: the ``benchmark`` PR goes first (ROADMAP B1 (r)),
+    # then each is a flip, and the column goes with ROADMAP S2 (e)
+    rows_name_slots: bool = False
 
 
 FAMILIES: Dict[str, Family] = {
@@ -58,9 +70,10 @@ FAMILIES: Dict[str, Family] = {
     "olmoe": Family(olmoe, olmoe.OlmoeConfig, counts_routing=True,
                     loads_hf=True),
     "olmo_hybrid": Family(olmo_hybrid, olmo_hybrid.OlmoHybridConfig,
-                          untrained=_STATE_CARRYING),
+                          untrained=_STATE_CARRYING, rows_name_slots=True),
     "qwen3_next": Family(qwen3_next, qwen3_next.Qwen3NextConfig,
-                         counts_routing=True, untrained=_STATE_CARRYING),
+                         counts_routing=True, untrained=_STATE_CARRYING,
+                         rows_name_slots=True),
     "afmoe": Family(
         afmoe, afmoe.AfmoeConfig, counts_routing=True,
         untrained=(

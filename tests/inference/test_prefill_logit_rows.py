@@ -19,6 +19,7 @@ from scaletorch_tpu.inference.decode import (
     counts_routing,
     make_paged_prefill_step,
     resolve_forward_cached,
+    rows_name_slots,
 )
 from scaletorch_tpu.inference.kv_cache import (
     PagedKVIO,
@@ -145,6 +146,12 @@ def test_the_step_s_row_is_the_all_rows_forward_s(stepped, case):
     cfg, (first, last, finite, *_), want_last, _, _ = stepped
     slot = CASES.index(case)
     assert last.shape == (SLOTS, cfg.vocab_size) and last.dtype == jnp.float32
+    if rows_name_slots(cfg) and not WRITTEN[slot]:
+        # handed no slot ids the step runs its one-row program once a
+        # WRITTEN row (``decode.SlotRows``): the row nothing reads is
+        # blank and says so
+        assert not bool(finite[slot]) and not np.asarray(last[slot]).any()
+        return
     np.testing.assert_allclose(
         np.asarray(last[slot]), np.asarray(want_last[slot]), **TOL)
     assert int(first[slot]) == int(jnp.argmax(want_last[slot]))
@@ -159,11 +166,19 @@ def test_the_cache_it_returns_is_the_all_rows_forward_s(stepped):
     """Naming rows touches nothing before the final norm: the pool (and
     a recurrent state) come back as from the all-rows forward, and the
     slot outside ``write_mask`` keeps what it held."""
-    _, got, _, want_pool, before = stepped
+    cfg, got, _, want_pool, before = stepped
     new_pool = got[3]
     assert type(new_pool) is type(before)
-    for new, want in zip(new_pool, want_pool):
-        np.testing.assert_array_equal(np.asarray(new), np.asarray(want))
+    for name, new, want in zip(before._fields, new_pool, want_pool):
+        if rows_name_slots(cfg):
+            # one row a program, not five: equal to float32's last
+            # places, and the row that is not written is not run, so
+            # its K/V never reaches the TRASH page
+            keep = slice(None) if name in ("state", "conv") else slice(1, None)
+            np.testing.assert_allclose(
+                np.asarray(new[:, keep]), np.asarray(want[:, keep]), **TOL)
+        else:
+            np.testing.assert_array_equal(np.asarray(new), np.asarray(want))
     slot = CASES.index("outside_write_mask")
     for name, new, old in zip(before._fields, new_pool, before):
         if name in ("state", "conv"):

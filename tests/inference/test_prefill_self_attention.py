@@ -16,6 +16,7 @@ from scaletorch_tpu.inference import InferenceEngine, SamplingParams
 from scaletorch_tpu.inference.decode import (
     make_paged_prefill_step,
     resolve_forward_cached,
+    rows_name_slots,
 )
 from scaletorch_tpu.inference.kv_cache import (
     PagedKVIO,
@@ -97,6 +98,8 @@ def test_the_program_holds_the_gather_only_where_a_prefix_can_lie(name):
     score array over the whole cache is in no program of it."""
     cfg, params = family(name)
     args = operands(cfg)
+    if rows_name_slots(cfg):        # the program takes its rows' slot ids
+        args += (np.arange(SLOTS, dtype=np.int32),)
     text = step(cfg).lower(params, *args).as_text()
     parent = step(cfg, reads_the_pool(resolve_forward_cached(cfg))).lower(
         params, *args).as_text()

@@ -15,8 +15,14 @@ here, on the CPU in float32 at the tiny size of each family:
     slots' own and TRASH, padding rows included;
   * that a sampled request's tokens do not depend on the shape that
     admitted it (a slot's key stays its own);
-  * that a cache by slot (recurrent state, window rings) still makes
-    exactly the one fixed-shape call;
+  * that a cache by slot whose row IS its slot (jamba's state, window
+    rings) still makes exactly the one fixed-shape call;
+  * that where a row NAMES its slot (the delta-rule families) the one
+    program is one row: a call writes its slot's state and tail at
+    ``slot_ids`` and no other, as the by-row call of every slot did;
+    two admissions in a tick are two calls; and the step handed the
+    full shape without ids (the benchmark's check) runs that program
+    once a written row;
   * that ``scripts/serve.py``'s ``build_engine`` leaves every listed
     program compiled, nothing written, no counter moved, and that no
     admission compiles another;
@@ -33,9 +39,15 @@ import numpy as np
 import pytest
 
 from scaletorch_tpu.inference import InferenceEngine, SamplingParams
-from scaletorch_tpu.inference.decode import prefill_shapes
+from scaletorch_tpu.inference.decode import (
+    SlotRows,
+    make_paged_prefill_step,
+    prefill_shapes,
+    rows_name_slots,
+)
 from scaletorch_tpu.inference.engine import EngineMetrics
 from scaletorch_tpu.inference.kv_cache import TRASH_PAGE
+from scaletorch_tpu.inference.routing_counters import ROUTING_COUNTERS
 from tests.inference.oracle import assert_greedy
 from tests.inference.test_decode_parity import ATOL
 from tests.inference.test_paged_engine import family
@@ -72,6 +84,12 @@ def model(name):
         cfg = tiny_config()
         return cfg, jax.jit(afmoe.init_params, static_argnums=1)(
             jax.random.PRNGKey(3), cfg)
+    if name in ("qwen3_next", "jamba"):
+        import importlib
+
+        tiny = importlib.import_module(f"tests.models.test_{name}")
+        cfg = tiny.tiny_config()
+        return cfg, tiny.seeded_params(cfg)
     return family(name)
 
 
@@ -93,15 +111,19 @@ def prompt(n, seed):
     # addressed by page: one row of half the length before the full shape
     ("qwen3-1.7b-serve", ((1, 512), (16, 1024))),
     ("olmoe-1b-7b-serve", ((1, 512), (16, 1024))),
-    # state or rings by slot: the one fixed call
-    ("olmo-hybrid-7b-serve", ((16, 512),)),
-    ("qwen3-next-80b-a3b-serve", ((16, 512),)),
+    ("openpangu-ultra-moe-718b-serve", ((1, 1536), (8, 3072))),
+    # state by slot, a row names its slot: ONE row, one program
+    ("olmo-hybrid-7b-serve", ((1, 512),)),
+    ("qwen3-next-80b-a3b-serve", ((1, 512),)),
+    # state or rings by slot, a row IS its slot: the one fixed call
     ("trinity-mini-serve", ((8, 3072),)),
+    ("jamba2-3b-serve", ((8, 3072),)),
 ])
 def test_the_list_at_the_serving_cells_shapes(name, shapes):
     """What an engine built as the benchmark builds it would list, from
-    the two tests the engine makes (``carries_state``, ``window_of``):
-    never from a model's name."""
+    the three tests the engine makes (``rows_name_slots``: the family's
+    column; ``carries_state``, ``window_of``): never from a model's
+    name."""
     from benchmarks.lib import spec as spec_lib
     from benchmarks.lib.program import serving_model
     from scaletorch_tpu.inference.kv_cache import carries_state, window_of
@@ -111,7 +133,8 @@ def test_the_list_at_the_serving_cells_shapes(name, shapes):
     cfg, _ = serving_model(config, serve["dtype"])
     top = (serve["max_slots"], serve["prefill_len"])
     by_slot = carries_state(cfg) or window_of(cfg) is not None
-    assert ((top,) if by_slot else prefill_shapes(*top)) == shapes
+    assert (((1, top[1]),) if rows_name_slots(cfg)
+            else (top,) if by_slot else prefill_shapes(*top)) == shapes
     assert len(shapes) - 1 <= 6
 
 
@@ -142,7 +165,7 @@ class Recorder:
         self.step, self.calls = step, []
 
     def __call__(self, params, tokens, tail_lens, starts, write_mask,
-                 tables, cache, keys):
+                 tables, cache, keys, *slot_ids):
         rows = tokens.shape[0]
         assert (tail_lens.shape == starts.shape == write_mask.shape
                 == (rows,))
@@ -150,14 +173,15 @@ class Recorder:
         call = dict(shape=tokens.shape, tail_lens=np.asarray(tail_lens),
                     starts=np.asarray(starts),
                     write_mask=np.asarray(write_mask),
-                    tables=np.asarray(tables), keys=np.asarray(keys))
+                    tables=np.asarray(tables), keys=np.asarray(keys),
+                    slot_ids=[np.asarray(ids).tolist() for ids in slot_ids])
         self.calls.append(call)
         if self.step is None:       # no model: shapes only
             return (jnp.zeros(rows, jnp.int32), None,
                     jnp.ones(rows, bool), cache)
         first, logits, finite, cache = self.step(
             params, tokens, tail_lens, starts, write_mask, tables, cache,
-            keys)
+            keys, *slot_ids)
         call["logits"], call["first"] = np.asarray(logits), np.asarray(first)
         return first, logits, finite, cache
 
@@ -228,18 +252,21 @@ def check_the_call(engine, admitted, tail, shape):
     assert snap["prefill_positions_admitted"] == tail + (admitted - 1)
 
 
+BY_SLOT = dict(max_slots=3, max_seq=160, prefill_len=128, page_size=8)
+
+
 @pytest.mark.parametrize("name,kw", [
-    ("olmo_hybrid", dict(max_slots=3, max_seq=160, prefill_len=128,
-                         page_size=8)),
-    ("afmoe", dict(max_slots=2, max_seq=160, prefill_len=128, page_size=8)),
+    ("jamba", BY_SLOT),
+    ("afmoe", dict(BY_SLOT, max_slots=2)),
 ])
 def test_a_cache_by_slot_still_makes_exactly_the_fixed_shape_call(name, kw):
-    """State, convolution tail and rings are indexed by the row: the
-    list is the one full shape, a row is its slot whether admitted or
-    not, and a live slot's row carries its own table, masked."""
+    """Jamba's state and convolution tail and the window rings are
+    indexed by the row: the list is the one full shape, a row is its
+    slot whether admitted or not, and a live slot's row carries its own
+    table, masked."""
     engine = shape_engine(name, **kw)
     slots, length = kw["max_slots"], kw["prefill_len"]
-    assert engine._by_slot
+    assert engine._by_slot and not engine._rows_name_slots
     assert engine.prefill_shapes == ((slots, length),)
     engine.submit(prompt(5, 0), max_new_tokens=2)
     admit(engine)                 # slot 0 is live
@@ -254,6 +281,225 @@ def test_a_cache_by_slot_still_makes_exactly_the_fixed_shape_call(name, kw):
     assert (second["keys"] == engine._base_keys).all()
     assert engine.metrics.prefill_positions_run == 2 * slots * length
     assert engine.metrics.prefill_positions_admitted == 5 + 9
+
+
+def slot_rows(engine):
+    """The engine's prefill step if it is the one that takes slot ids
+    (under an MoE model's counting wrapper)."""
+    step = engine._prefill
+    if not isinstance(step, SlotRows):
+        step = getattr(step, "_step", None)
+    return step if isinstance(step, SlotRows) else None
+
+
+NAMED = ["olmo_hybrid", "qwen3_next"]
+
+
+# two compiled programs of one recurrence (one row, every row): the
+# delta rule carries float32's reduction-order noise (6e-7 a layer:
+# tests/inference/test_paged_cache.py) to 3e-5 on |logit| ~ 1.7 and on
+# the state; a wrong slot, row or tail moves either by >= 1e-2
+BY_ROW_TOL = dict(rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("name", NAMED)
+def test_a_row_that_names_its_slot_makes_one_call_a_prompt(name):
+    """The delta-rule families: the list is ONE row of the whole
+    length; an admission of two prompts in one tick is two calls back
+    to back, each row carrying its slot's table, key and id; a live
+    slot is in no call."""
+    engine = shape_engine(name, **BY_SLOT)
+    length = BY_SLOT["prefill_len"]
+    assert engine._by_slot and engine._rows_name_slots
+    assert engine.prefill_shapes == ((1, length),)
+    engine.submit(prompt(5, 0), max_new_tokens=2)
+    admit(engine)                 # slot 0 is live
+    engine.submit(prompt(9, 1), max_new_tokens=2, seed=7)
+    engine.submit(prompt(120, 2), max_new_tokens=2, seed=8)
+    admit(engine)                 # one tick admits two
+    assert [c["shape"] for c in engine._prefill.calls] == [(1, length)] * 3
+    for call, slot, tail in zip(engine._prefill.calls, (0, 1, 2),
+                                (5, 9, 120)):
+        assert call["slot_ids"] == [[slot]]
+        assert call["write_mask"].tolist() == [True]
+        assert call["tail_lens"].tolist() == [tail]
+        assert call["starts"].tolist() == [0]
+        assert (call["tables"] == engine._tables[[slot]]).all()
+        assert (call["keys"] == engine._base_keys[[slot]]).all()
+    snap = engine.metrics.snapshot()
+    assert snap["prefill_calls"] == snap["prefill_calls_self_attended"] == 3
+    assert snap["prefill_positions_run"] == 3 * length
+    assert snap["prefill_positions_admitted"] == 5 + 9 + 120
+    assert snap["recurrent_state_resets"] == 3
+    assert engine._state_owner == [0, 1, 2]
+
+
+def by_row_oracle(cfg, kw):
+    """The parent's program: every slot a row of ONE call, state and
+    tail read and written at the row (``forward_cached`` without slot
+    ids, through the step a family whose rows are its slots still
+    gets)."""
+    from scaletorch_tpu.inference import decode
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(decode, "rows_name_slots", lambda cfg: False)
+        step = make_paged_prefill_step(
+            cfg, GREEDY, page_size=kw["page_size"], seq_limit=kw["max_seq"],
+            routing_counts=decode.counts_routing(cfg))
+    assert not isinstance(step, SlotRows)
+    return step
+
+
+@pytest.mark.parametrize("name", NAMED)
+def test_a_one_row_call_writes_its_slot_and_no_other(name):
+    """Three slots hold noise. The one-row program with ``slot_ids =
+    [1]`` leaves slots 0 and 2 bit for bit (state, tail, pages) and
+    gives slot 1 what the by-row call over every slot gives it (the
+    oracle: the parent's semantics): state, tail, K/V, first token and
+    logits; a row that is masked, or whose id is past the last slot,
+    writes nothing at all."""
+    cfg, params = model(name)
+    kw = dict(BY_SLOT, max_seq=48, prefill_len=40)
+    engine = InferenceEngine(params, cfg, sampling=GREEDY,
+                             prefix_cache=False, **kw)
+    assert engine.prefill_shapes == ((1, 40),)
+    rng = np.random.default_rng(0)
+    noise = type(engine.cache)(*(
+        jnp.asarray(rng.normal(size=buf.shape), buf.dtype)
+        for buf in engine.cache))
+    slot, tail = 1, prompt(23, 4)
+    pps = engine._pages_per_slot
+    tables = (np.arange(3 * pps, dtype=np.int32) + 1).reshape(3, pps)
+    keys = np.asarray(jax.random.split(jax.random.PRNGKey(1), 3), np.uint32)
+    tokens = np.zeros((3, 40), np.int32)
+    tokens[slot, :23] = tail
+    tail_lens = np.array([1, 23, 1], np.int32)
+    starts = np.zeros(3, np.int32)
+    mask = np.arange(3) == slot
+    counts = () if not hasattr(engine._prefill, "uncounted") else (
+        engine.metrics.routing.accumulator,)
+    want = by_row_oracle(cfg, kw)(
+        engine.params, tokens, tail_lens, starts, mask, tables, noise,
+        keys, *counts)
+    step = slot_rows(engine)
+    at = slice(slot, slot + 1)
+
+    def one_row(written, ids):
+        return step(engine.params, tokens[at], tail_lens[at], starts[at],
+                    np.array([written]), tables[at], noise, keys[at],
+                    np.array(ids, np.int32), *counts)
+
+    got = one_row(True, [slot])
+    assert int(got[0][0]) == int(want[0][slot])
+    np.testing.assert_allclose(got[1][0], want[1][slot], **BY_ROW_TOL)
+    own = tables[slot]
+    for field, new, ref, old in zip(
+            type(noise)._fields, got[3], want[3], noise):
+        new, ref, old = (np.asarray(a, np.float32) for a in (new, ref, old))
+        if field in ("state", "conv"):
+            keep = [0, 2]
+            np.testing.assert_allclose(new[:, slot], ref[:, slot],
+                                       **BY_ROW_TOL, err_msg=field)
+            assert np.abs(new[:, slot] - old[:, slot]).max() > 0.1
+        else:
+            keep = [p for p in range(engine.num_pages)
+                    if p not in own and p != TRASH_PAGE]
+            np.testing.assert_allclose(new[:, own[:2]], ref[:, own[:2]],
+                                       **BY_ROW_TOL, err_msg=field)
+        assert (new[:, keep] == old[:, keep]).all(), field
+    for written, ids in ((False, [slot]), (True, [3])):
+        cache = one_row(written, ids)[3]
+        for field, new, old in zip(type(noise)._fields, cache, noise):
+            new, old = np.asarray(new), np.asarray(old)
+            if field in ("state", "conv"):
+                keep = slice(None)
+            else:       # K/V goes to the row's pages if written, or TRASH
+                keep = [p for p in range(engine.num_pages) if p != TRASH_PAGE
+                        and not (written and p in own)]
+            assert (new[:, keep] == old[:, keep]).all(), (field, ids)
+
+
+@pytest.mark.parametrize("name", NAMED)
+def test_the_full_shape_without_ids_runs_the_one_row_program(name):
+    """The benchmark's check hands ``engine._prefill`` eight operands at
+    ``[max_slots, prefill_len]``, row b = slot b: it gets ``[max_slots,
+    V]`` logits from the ONE program the admissions run (nothing else
+    is compiled), a written row's are the oracle's by-row call's, its
+    state lands at its row's slot, and an MoE model's counters count
+    the written rows."""
+    cfg, params = model(name)
+    kw = dict(BY_SLOT, max_seq=48, prefill_len=40)
+    engine = InferenceEngine(params, cfg, sampling=GREEDY,
+                             prefix_cache=False, **kw)
+    rid = engine.submit(prompt(7, 0), max_new_tokens=3)
+    engine.run()
+    assert engine.prefill_compile_count == 1
+    pps = engine._pages_per_slot
+    tables = (np.arange(3 * pps, dtype=np.int32) + 1).reshape(3, pps)
+    tokens = np.zeros((3, 40), np.int32)
+    lens = np.array([11, 1, 40], np.int32)
+    tokens[0, :11], tokens[2] = prompt(11, 5), prompt(40, 6)
+    mask = np.array([True, False, True])
+    operands = (jnp.asarray(tokens), jnp.asarray(lens),
+                jnp.zeros(3, jnp.int32), jnp.asarray(mask),
+                jnp.asarray(tables))
+    keys = jnp.zeros((3, 2), jnp.uint32)
+    counts = () if not hasattr(engine._prefill, "uncounted") else (
+        engine.metrics.routing.accumulator,)
+    want = by_row_oracle(cfg, kw)(
+        engine.params, *operands, engine.cache, keys, *counts)
+    before = engine.metrics.snapshot()
+    with engine.on_device():
+        first, logits, finite, engine.cache = engine._prefill(
+            engine.params, *operands, engine.cache, keys)
+    assert engine.prefill_compile_count == 1
+    assert logits.shape == (3, cfg.vocab_size) and first.shape == (3,)
+    assert np.asarray(finite).tolist() == mask.tolist()
+    for row in (0, 2):
+        assert int(first[row]) == int(want[0][row])
+        np.testing.assert_allclose(logits[row], want[1][row], **BY_ROW_TOL)
+        for field in ("state", "conv"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(engine.cache, field)[:, row], np.float32),
+                np.asarray(getattr(want[3], field)[:, row], np.float32),
+                **BY_ROW_TOL, err_msg=field)
+    if counts:      # the accumulator went from written row to written row
+        key = "moe_prefill_assignments"
+        want_moved = np.asarray(want[4] - counts[0])[
+            ROUTING_COUNTERS.index(key)]
+        assert engine.metrics.snapshot()[key] - before[key] == want_moved > 0
+    assert engine._results[rid].outcome == "ok"
+
+
+@pytest.mark.parametrize("name", NAMED)
+def test_a_successor_starts_from_zero_whatever_the_slot_held(name):
+    """A retired slot's state and tail are noise when the next prompt
+    takes the slot: its tokens are a fresh engine's, two admitted in
+    one tick are two calls of one program, and no step ran on another
+    request's state."""
+    cfg, params = model(name)
+    kw = dict(BY_SLOT, max_slots=2, max_seq=48, prefill_len=40)
+    asked = [prompt(13, 1), prompt(31, 2)]
+    tokens = []
+    for dirty in (False, True):
+        engine = InferenceEngine(params, cfg, sampling=GREEDY,
+                                 prefix_cache=False, **kw)
+        if dirty:
+            engine.cache = engine.cache._replace(
+                state=jnp.full_like(engine.cache.state, 3.0),
+                conv=jnp.full_like(engine.cache.conv, -2.0))
+        ids = [engine.submit(p, max_new_tokens=6) for p in asked]
+        done = engine.run()
+        tokens.append([done[i].tokens for i in ids])
+        snap = engine.metrics.snapshot()
+        assert snap["prefill_calls"] == 2
+        assert snap["prefill_calls_behind_flight"] == 0   # one cold tick
+        assert snap["recurrent_state_owner_mismatches"] == 0
+        assert engine.prefill_compile_count == 1
+        assert engine.decode_compile_count == 1
+    assert tokens[0] == tokens[1]
+    for p, got in zip(asked, tokens[0]):
+        assert_greedy(params, cfg, p, got)
 
 
 # ---- a call at any listed shape against the full one -------------------------
@@ -445,6 +691,8 @@ def _leaves(cache):
                "--page_size", "4"], 2),
     ("olmo_hybrid", ["--max_slots", "2", "--max_seq", "160",
                      "--prefill_len", "128", "--page_size", "8"], 1),
+    ("jamba", ["--max_slots", "2", "--max_seq", "160",
+               "--prefill_len", "128", "--page_size", "8"], 1),
 ])
 def test_build_engine_warms_every_shape_and_no_admission_compiles_another(
         backend_compiles, name, flags, count):
@@ -453,8 +701,9 @@ def test_build_engine_warms_every_shape_and_no_admission_compiles_another(
     engine exists before the first request, the cache is still zero but
     for the TRASH page, no counter has moved, and 50 mixed admissions
     hand the backend compiler nothing and add no entry to the prefill
-    step's cache. A cache by slot has the one shape and compiles it at
-    its first call, as before."""
+    step's cache. A cache by slot has the one shape (the full one, or
+    one row where a row names its slot) and compiles it at its first
+    call, as before."""
     serve = serve_module()
     cfg, params = model(name)
     args = serve.parse_args(flags)
@@ -491,15 +740,16 @@ def test_build_engine_warms_every_shape_and_no_admission_compiles_another(
     assert engine.decode_compile_count == 1
     snap = engine.metrics.snapshot()
     calls = snap["prefill_calls"]
+    rows, length = engine.prefill_shapes[-1]
+    assert (rows, length) == (
+        (1 if name == "olmo_hybrid" else engine.max_slots),
+        engine.prefill_len)
     assert 0 < snap["prefill_positions_admitted"] \
-        <= snap["prefill_positions_run"] \
-        <= calls * engine.max_slots * engine.prefill_len
+        <= snap["prefill_positions_run"] <= calls * rows * length
     if count > 1:                      # some calls were under the full shape
-        assert snap["prefill_positions_run"] < \
-            calls * engine.max_slots * engine.prefill_len
+        assert snap["prefill_positions_run"] < calls * rows * length
     else:
-        assert snap["prefill_positions_run"] == \
-            calls * engine.max_slots * engine.prefill_len
+        assert snap["prefill_positions_run"] == calls * rows * length
     assert snap.get("moe_dropped_assignments", 0) == 0
 
 

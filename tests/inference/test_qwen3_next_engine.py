@@ -104,6 +104,9 @@ def test_paged_prefill_and_decode_match_the_full_forward(checked):
     assert errors["prefill_max_abs_err"] > 0  # it did compare something
     assert isinstance(engine.cache, HybridCache)
     assert isinstance(engine._decode, CountedStep)
+    # a row names its slot: ONE one-row program, which the check's
+    # full-shape call ran once a prompt (``decode.SlotRows``)
+    assert engine.prefill_shapes == ((1, 40),)
     assert engine.decode_compile_count == 1
     assert engine.prefill_compile_count == 1
 
@@ -153,6 +156,10 @@ def test_mixed_lengths_equal_the_oracle_through_reused_slots(model):
     snap = eng.metrics.snapshot()
     assert snap["recurrent_state_resets"] == 7
     assert snap["recurrent_state_owner_mismatches"] == 0
+    # one call of the one-row program a prompt, three in the first tick
+    assert snap["prefill_calls"] == 7
+    assert snap["prefill_positions_run"] == 7 * 40
+    assert eng.prefill_compile_count == 1
     assert snap["recurrent_state_bytes"] == (
         eng.cache.state.nbytes + eng.cache.conv.nbytes)
     assert snap["moe_dropped_assignments"] == 0

@@ -186,7 +186,9 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
     by; kept under ``key``), both steps built to read them so; and the
     pool's shape. With ``prefill_rows`` the prefill step alone, at
     ``[prefill_rows, prefill_len]``: a row is a slot only through its
-    page table and its key, so the step takes any number of them."""
+    page table and its key, so the step takes any number of them.
+    Where a row names its slot (``decode.rows_name_slots``) the prefill
+    program is the engine's one: ONE row, with its slot id."""
     from jax.experimental.layout import Format
 
     from scaletorch_tpu.inference.decode import (
@@ -196,6 +198,7 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
         make_paged_decode_step,
         make_paged_prefill_step,
         place_params,
+        rows_name_slots,
     )
     from scaletorch_tpu.inference.kv_cache import init_paged_kv_cache
     from scaletorch_tpu.inference.routing_counters import ROUTING_COUNTERS
@@ -218,11 +221,11 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
                  routing_counts=counted)
     sampling = SamplingParams(temperature=0.0)
 
-    def operands(rows, *lead):
+    def operands(rows, *lead, slot_ids=False):
         ints = arg((rows,), jnp.int32)
         return (*lead, ints, ints, arg((rows,), jnp.bool_),
                 arg((rows, max_pages), jnp.int32), pool,
-                arg((rows, 2), jnp.uint32)) + (
+                arg((rows, 2), jnp.uint32)) + (ints,) * slot_ids + (
             (arg((len(ROUTING_COUNTERS),), jnp.uint32),) if counted else ())
 
     if key is None or key not in _CHOSEN:
@@ -241,10 +244,11 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
     build["param_orders"] = orders
     placed = on_chip(jax.eval_shape(
         lambda tree: place_params(tree, orders)[0], params))
-    rows = prefill_rows or slots
+    by_id = rows_name_slots(cfg)
+    rows = prefill_rows or (1 if by_id else slots)
     prefill = make_paged_prefill_step(cfg, sampling, **build).lower(
-        placed, *operands(rows, arg((rows, prefill_len), jnp.int32))
-    ).compile()
+        placed, *operands(rows, arg((rows, prefill_len), jnp.int32),
+                          slot_ids=by_id)).compile()
     if prefill_rows is not None:
         return None, prefill, pool.k.shape
     decode = make_paged_decode_step(cfg, sampling, **build).lower(
@@ -497,27 +501,36 @@ def test_decode_kernel_is_still_the_one_4d_call(serving_programs):
 # bf16 copy, 2.42 GB): the buffer assignment's heap peak is the parent's
 # to 4 KB (9,622,502,448 -> 9,622,506,624 B), its allocations 126 KB
 # smaller, and this counter reads 2.0 % MORE, so it is held to 2.5 %.
-# The hybrid's reads 3.493e9 since PR 52 took its 1.5 GB score array
-# out of the program (3.094e9 before): no array over 30 MB is new, the
-# scheduler's peak sits elsewhere once the scores no longer force it
-# (AOT, PR 52), so it is held to 7 %.
+# The two delta-rule families' program is ONE row since PR 53 (a row
+# names its slot): held to what it reads now, 131,150,336 and
+# 106,652,160 B (AOT, PR 53), with 5 % of room; the full ``(16, 512)``
+# program's scratch was 3.493e9 and 1.708e9.
 _PREFILL_TEMP_WITH_EVERY_ROW_S_LOGITS = {
     "qwen3-1.7b-serve": (8_973_132_288, 1.0),
     "olmoe-1b-7b-serve": (2_511_168_512, 1.025),
-    "olmo-hybrid-7b-serve": (3_301_462_528, 1.07),
-    "qwen3-next-80b-a3b-serve": (1_708_014_080, 1.0),
+    "olmo-hybrid-7b-serve": (131_150_336, 1.05),
+    "qwen3-next-80b-a3b-serve": (106_652_160, 1.05),
 }
 _ARRAY = re.compile(r"\b(?:pred|[a-z]+\d+)\[([\d,]+)\]")
 
 
+def _prefill_rows(cfg, slots):
+    """The rows of a configuration's largest prefill program: every
+    slot, or the one row of a family whose rows name their slots."""
+    from scaletorch_tpu.inference.decode import rows_name_slots
+
+    return 1 if rows_name_slots(cfg) else slots
+
+
 def test_prefill_program_runs_the_head_on_the_sampled_from_rows_only(
-        request, serving_programs):
+        request, serving_programs, serving_cfgs):
     """The prefill step names one row a slot (``logit_rows``) and the
     forward takes it before the final norm and the head: no array of
-    ``slots x prefill_len x vocab`` elements, of any type or layout, is
+    ``rows x prefill_len x vocab`` elements, of any type or layout, is
     left in the compiled program (Qwen3-1.7B's was 4.98 GB in bf16, five
-    instructions of it), the logits it does hold are the decode step's
-    ``[slots, vocab]``, and the program's scratch is smaller for it."""
+    instructions of it), the logits it does hold are ``[rows, vocab]``
+    (the decode step's ``[slots, vocab]``; one row where a row names
+    its slot), and the program's scratch is smaller for it."""
     name = request.node.callspec.params["serving_programs"]
     _, prefill, _ = serving_programs
     with open(os.path.join(REPO, "benchmarks", "configs",
@@ -525,12 +538,14 @@ def test_prefill_program_runs_the_head_on_the_sampled_from_rows_only(
         config = json.load(f)
     serve = config["serve"]
     slots, vocab = serve["max_slots"], config["vocab_size"]
-    every_row = slots * serve["prefill_len"] * vocab
+    rows = _prefill_rows(serving_cfgs[name], slots)
     text = prefill.as_text()
     sizes = {dims: math.prod(map(int, dims.split(",")))
              for dims in set(_ARRAY.findall(text))}
-    assert not [d for d, n in sizes.items() if n == every_row], name
-    assert f"f32[{slots},{vocab}]" in text       # last_logits
+    assert not [d for d, n in sizes.items()
+                if n in (slots * serve["prefill_len"] * vocab,
+                         rows * serve["prefill_len"] * vocab)], name
+    assert f"f32[{rows},{vocab}]" in text       # last_logits
     temp = prefill.memory_analysis().temp_size_in_bytes
     before, room = _PREFILL_TEMP_WITH_EVERY_ROW_S_LOGITS[name]
     assert temp < before * room, (
@@ -547,8 +562,10 @@ def test_the_listed_prefill_shapes_compile_at_their_own_size(
     has the full buffer's shape, no array of ``16 x 1024 x vocab``
     elements (or of ``512 x vocab``: one row is sampled from) exists,
     and its scratch is under a sixteenth of the full program's, which
-    holds 16 x 1024 rows of every layer's activations and scores. A
-    cache by slot lists the one full shape."""
+    holds 16 x 1024 rows of every layer's activations and scores. The
+    delta-rule families, whose rows name their slots, list ONE row of
+    the whole length and nothing else: the buffer is ``[1, 512]``, no
+    operand has sixteen rows of it, one row is sampled from."""
     from scaletorch_tpu.inference.decode import prefill_shapes
     from scaletorch_tpu.inference.kv_cache import carries_state
 
@@ -561,8 +578,15 @@ def test_the_listed_prefill_shapes_compile_at_their_own_size(
     *shorter, top = prefill_shapes(slots, length)
     assert top == (slots, length) and len(shorter) <= 6
     _, full, _ = serving_programs
-    if carries_state(request.getfixturevalue("serving_cfgs")[name]):
-        return       # by slot: the engine lists ``top`` alone
+    cfg = request.getfixturevalue("serving_cfgs")[name]
+    if carries_state(cfg):
+        assert _prefill_rows(cfg, slots) == 1
+        text = full.as_text()
+        assert f"s32[1,{length}]" in text, name
+        assert f"s32[{slots},{length}]" not in text, name
+        assert f"f32[1,{vocab}]" in text            # last_logits
+        assert f"f32[{slots},{vocab}]" not in text
+        return
     assert shorter == [(1, 512)]
     for rows, rung in shorter:
         _, program, _ = _programs_of(one_chip, name, (rows, rung))
@@ -618,17 +642,64 @@ def test_a_family_without_prefixes_holds_no_scores_over_the_cache(
         one_chip, name, heads, width):
     """``starts`` is 0 by construction there, so the choice is static:
     no ``conditional``, one flash forward in the text (the scanned
-    period's one full-attention layer), and no array of ``16 x heads x 512 x 1536`` elements of any
-    type (the parent's ``f32[16,30,512,1536]`` was 1.5 GB a layer)."""
+    period's one full-attention layer) over the program's ONE row, a
+    TUPLE result as in every prefill program (so
+    ``serve_paged_attn_roofline``, which takes a Mosaic call with one
+    4-D bf16 result for the decode kernel, does not count it), and no
+    array of ``heads x 512 x 1536`` elements a row of any type (the
+    ``f32[16,30,512,1536]`` of PR 51's full-shape program was 1.5 GB a
+    layer)."""
     _, prefill, _ = _programs_of(one_chip, name)
     text = prefill.as_text()
     assert " conditional(" not in text
     flash = _flash_forwards(text)
     assert len(flash) == 1 and flash[0].endswith(
-        f"(bf16[16,{heads},512,{width}], f32[16,{heads},1,512])"), flash
+        f"(bf16[1,{heads},512,{width}], f32[1,{heads},1,512])"), flash
     sizes = {dims: math.prod(map(int, dims.split(",")))
              for dims in set(_ARRAY.findall(text))}
-    assert not [d for d, n in sizes.items() if n == 16 * heads * 512 * 1536]
+    # (a row's element count is an expert stack's in qwen3-next: by shape)
+    assert not [d for d, n in sizes.items()
+                if n == 16 * heads * 512 * 1536 or d.endswith(",512,1536")]
+
+
+@pytest.mark.parametrize("name,state,tail", [
+    ("olmo-hybrid-7b-serve", "f32[12,16,30,96,192]", "bf16[12,16,3,11520]"),
+    ("qwen3-next-80b-a3b-serve", "f32[9,16,32,128,128]",
+     "bf16[9,16,3,8192]")])
+def test_the_one_row_prefill_program_holds_no_copy_of_the_state(
+        one_chip, name, state, tail):
+    """A row names its slot (PR 53): the ``(1, 512)`` program of the two
+    delta-rule families runs its recurrence on ``[layers, 1, ...]`` of
+    zeros and writes the row's final state and tail at its slot id. In
+    the program compiled for the v5e the whole state (and the whole
+    tail) is returned, beside plumbing, by ONE operation each, the
+    dynamic-update-slice (fused with its select, or alone) that writes
+    the slot's ``[layers, 1, ...]`` window in place into the donated
+    buffer (XLA turns the one-index scatter into it; every donated byte
+    is aliased), no operation returns a layer of either, the scan's
+    carry is the one-row state, and the program's whole scratch is
+    under half of the state: nowhere is there room for a copy of it."""
+    _, prefill, _ = _programs_of(one_chip, name)
+    text = prefill.as_text()
+    dims = {buf: [int(d) for d in buf[buf.index("[") + 1:-1].split(",")]
+            for buf in (state, tail)}
+    for buf in (state, tail):
+        whole = [(op, line) for op, line in _top_level(text, buf)
+                 if op not in _PLUMBING]
+        assert [op for op, _ in whole] in (
+            ["fusion"], ["dynamic-update-slice"]), whole
+        layer = buf.replace(f"[{dims[buf][0]},", "[")
+        assert not [x for x in _top_level(text, layer)
+                    if x[0] not in _PLUMBING], f"a layer of {buf} is copied"
+    assert re.search(r"ROOT %\S+ = " + re.escape(state)
+                     + r"\S* dynamic-update-slice\(", text), (
+        "the state's write is no dynamic-update-slice")
+    one_row = state.replace(f",{dims[state][1]},", ",1,", 1)
+    assert one_row in text                      # the scan's carry
+    memory = prefill.memory_analysis()
+    state_bytes = math.prod(dims[state]) * 4
+    assert memory.temp_size_in_bytes < state_bytes // 2
+    assert memory.alias_size_in_bytes >= state_bytes
 
 
 def test_no_decode_program_holds_a_choice_or_a_flash_call(serving_programs):
@@ -755,8 +826,8 @@ def test_qwen3_next_steps_are_what_the_new_readers_look_for(one_chip):
     state_bytes = 9 * 16 * 32 * 128 * 128 * 4
     assert memory.temp_size_in_bytes < state_bytes // 10
     assert memory.alias_size_in_bytes >= state_bytes
-    # the prefill program's scratch beside 11.3 GB of arguments
-    assert prefill.memory_analysis().temp_size_in_bytes < 2.2e9
+    # the one-row prefill program's scratch beside 11.3 GB of arguments
+    assert prefill.memory_analysis().temp_size_in_bytes < 0.12e9
     assert "InvertDiagBlocks" not in prefill.as_text()
 
 
