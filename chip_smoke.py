@@ -10,7 +10,10 @@ random weights and synthetic data — no checkpoint, no network:
            Pallas flash kernel (forward + grads) vs
            ``models.layers.sdpa_attention``, and the paged-decode
            kernel vs ``paged_gather_kv`` + ``cached_sdpa_attention``,
-           at the Qwen3 head geometry (16/8 heads x 128), bf16.
+           at the Qwen3 head geometry (16/8 heads x 128), bf16; the
+           page write, the latent decode kernel and the expert kernel
+           (megablox under both K / N tilings the served widths take)
+           against theirs.
   train    ``train.main(argv)``: seq 8192, micro-batch 1, bf16,
            gradient checkpointing, flash attention, 5 optimizer steps.
            Every step must be finite and applied (``update_skipped ==
@@ -437,6 +440,40 @@ def leg_kernels(dry_run: bool) -> dict:
           f"the flash forward at keys 192 / values 128 differs from SDPA: "
           f"{flash_192_128}")
 
+    # ---- the expert kernel vs XLA's ragged product -----------------------
+    # a decode step's rows (a few a group, one group empty, rows of no
+    # group at the end) at the two K / N tilings the served widths take:
+    # 1,024 (hidden 2,048) and 1,152 (hidden 2,304: up / gate on K, down
+    # on N); float32 results of bfloat16 operands, both forms summing
+    # bfloat16 products in float32
+    from scaletorch_tpu.ops.grouped_matmul import (
+        _gmm_tiling,
+        pallas_matmul,
+        ragged_matmul,
+    )
+
+    g_rows, g_groups = (96, 4) if dry_run else (256, 64)
+    g_sizes = rng.multinomial(g_rows - 16, np.ones(g_groups) / g_groups)
+    g_sizes[1] = 0
+    g_sizes = jnp.asarray(g_sizes, jnp.int32)
+    g_live = int(g_sizes.sum())
+    grouped = []
+    for g_k, g_n in ((2048, 768), (2304, 1024), (1024, 2304)):
+        g_x = normal((g_rows, g_k))
+        g_w = (g_k ** -0.5) * normal((g_groups, g_k, g_n))
+        g_got = jax.jit(lambda *a: pallas_matmul(
+            *a, out_dtype=jnp.float32, interpret=interpret))(
+                g_x, g_w, g_sizes)
+        g_want = jax.jit(lambda *a: ragged_matmul(
+            *a, out_dtype=jnp.float32))(g_x, g_w, g_sizes)
+        case = {"k": g_k, "n": g_n, "tiling": _gmm_tiling(g_rows, g_k, g_n),
+                "max_abs_err": max_abs(g_got[:g_live] - g_want[:g_live]),
+                "max_abs": max_abs(g_want[:g_live])}
+        check(case["max_abs_err"] <= 1e-3 * case["max_abs"],
+              f"the expert kernel differs from ragged_dot: {case}")
+        grouped.append(case)
+    log(f"grouped-matmul parity: {json.dumps(grouped)}")
+
     # ---- the dispatchers pick the kernels iff the platform is tpu ------
     lowered = {
         "flash": jax.jit(flash_attention).lower(q, k, v).as_text(),
@@ -455,6 +492,7 @@ def leg_kernels(dry_run: bool) -> dict:
             "paged_decode_serving": paged_serving,
             "paged_write": paged_write,
             "latent_decode": latent, "flash_192_128": flash_192_128,
+            "grouped_matmul": grouped,
             "memory_stats": {str(d.id): d.memory_stats()
                              for d in jax.devices()}}
 

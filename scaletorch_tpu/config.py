@@ -87,7 +87,8 @@ class ModelArguments:
         default="llama",
         metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
                           "olmo_hybrid | qwen3_next | afmoe | jamba | "
-                          "pangu_ultra_moe | gpt_moe | lenet | mingpt"},
+                          "pangu_ultra_moe | kimi_linear | gpt_moe | lenet | "
+                          "mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -129,7 +130,8 @@ class ModelArguments:
         default=None,
         metadata={"help": "Standard deviation the random initialiser "
                           "draws the token embedding at (qwen3_next, "
-                          "afmoe, jamba and pangu_ultra_moe; unset: "
+                          "afmoe, jamba, pangu_ultra_moe and "
+                          "kimi_linear; unset: "
                           "0.02, HF's "
                           "initializer_range). "
                           "A property of random weights, not of the "
@@ -139,7 +141,8 @@ class ModelArguments:
         default=None,
         metadata={"help": "Multiple of its fan-in bound that the random "
                           "initialiser draws the held routed experts' "
-                          "down projection at (pangu_ultra_moe; unset: "
+                          "down projection at (pangu_ultra_moe, "
+                          "kimi_linear; unset: "
                           "1). A property of random weights, not of "
                           "the model: it sets how far one routed "
                           "expert moves a token beside the shared one."},
@@ -148,7 +151,8 @@ class ModelArguments:
         default=None,
         metadata={"help": "Multiple of its fan-in bound that the random "
                           "initialiser draws the query up-projection "
-                          "(q_b_proj) at (pangu_ultra_moe; unset: 1). A "
+                          "(q_b_proj; kimi_linear's latent q_proj) at "
+                          "(pangu_ultra_moe, kimi_linear; unset: 1). A "
                           "property of random weights, not of the "
                           "model: at 1 random scores are flat (std "
                           "0.33) and every token of a sequence gets "
@@ -201,7 +205,7 @@ class ModelArguments:
     # on the kept router weights, the four-norm block (sandwich_norm;
     # false is refused) and the multi-token-prediction module's depth
     # (carried: none is built)
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536      # kimi_linear: null, no latent
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -212,6 +216,19 @@ class ModelArguments:
     routed_scaling_factor: float = 2.5
     sandwich_norm: bool = True
     num_nextn_predict_layers: int = 1
+    # kimi_linear, by the published config.json names (latent attention's
+    # widths, first_k_dense_replace, routed_scaling_factor and
+    # num_shared_experts above; num_experts counts the experts HELD
+    # here): which layers are Kimi Delta Attention and which latent
+    # attention (1-based lists) with the KDA heads' count and width and
+    # the convolution's, the experts a token takes, and whether their
+    # weights are divided by their sum. The published keys with one
+    # value written (mla_use_nope, moe_router_activation_func,
+    # num_expert_group, moe_layer_freq) are the family's constants, not
+    # arguments
+    linear_attn_config: Optional[Dict[str, Any]] = None
+    num_experts_per_token: int = 8
+    moe_renormalize: bool = True
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "

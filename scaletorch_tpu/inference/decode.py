@@ -156,6 +156,8 @@ def make_fill_slots_step(*, donate_cache: Optional[bool] = None) -> Callable:
                 jnp.zeros((1,), bool), jnp.repeat(slot_mask, ring)])
 
         def fill(buf, val, name):
+            if buf is None:     # a latent pool's absent ``v``
+                return None
             m = (slot_mask if name in SLOT_FIELDS
                  else over_rings(buf.shape[1]) if name in RING_FIELDS
                  else mask)

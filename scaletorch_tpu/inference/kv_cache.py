@@ -73,6 +73,16 @@ every query head against the one cached head, whose value is the row's
 first ``kv_lora_rank`` columns). It is addressed by page (no state, no
 ring), and refuses prefix sharing all the same (``no_prefix_reason``).
 
+A model with latent attention AND state-carrying layers (kimi_linear:
+Kimi Delta Attention layers between its latent layers) keeps both in
+ONE ``HybridCache`` whose ``k`` is the latent pool over the latent
+layers ``[latent layers, n_pages, 1, page_size, row]``, whose ``v`` is
+ABSENT (None: a latent row is key and value at once) and whose
+``state`` / ``conv`` are by slot as any state-carrying family's. No
+fifth cache kind: every reader of ``HybridCache`` goes by field name,
+and the two that walk all fields (the masked fill, the prefill step's
+scatter by slot id) skip an absent one.
+
 ``MLACache`` (``[B, S_max, kv_rank]``, dense, no decoupled rotary key,
 no norm on the latent) is the TEACHING variant's cache
 (models/attention/variants.py MultiHeadLatentAttention): it re-expands
@@ -221,7 +231,9 @@ class HybridCache(NamedTuple):
     """The cache of a model with full-attention AND state-carrying
     layers, one pytree that the step programs donate and return: the
     page pools ``k`` / ``v`` ``[full layers, n_pages, Hkv, page_size,
-    D]`` and, indexed by slot and not by page, the recurrent ``state``
+    D]`` (where the attention is latent attention, kimi_linear: ``k``
+    the latent pool ``[latent layers, n_pages, 1, page_size, row]`` and
+    ``v`` None) and, indexed by slot and not by page, the recurrent ``state``
     (float32) and the convolution tail ``conv`` (the serving dtype), in
     whatever shapes the family's ``recurrent_state_shapes(slots)``
     returns: each begins ``[state-carrying layers, slots]`` and may
@@ -236,7 +248,7 @@ class HybridCache(NamedTuple):
     and counts bytes."""
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     state: jax.Array
     conv: jax.Array
 
@@ -289,8 +301,12 @@ def latent_cache_shape(cfg, num_pages: int, page_size: int
 
 
 def latent_cache_bytes(cache: Any) -> int:
-    """Bytes of a latent cache's pool; 0 for any other cache."""
-    return cache.k.nbytes if isinstance(cache, LatentCache) else 0
+    """Bytes of a cache's latent pool (a ``LatentCache``'s, or the
+    ``k`` of a ``HybridCache`` without a ``v``); 0 for any other
+    cache."""
+    latent = isinstance(cache, LatentCache) or (
+        isinstance(cache, HybridCache) and cache.v is None)
+    return cache.k.nbytes if latent else 0
 
 
 # the fields of a cache whose axis 1 counts SLOTS (every other field's
@@ -316,7 +332,11 @@ def no_prefix_reason(cfg) -> Optional[str]:
         return ("has state-carrying layers, and what is missing is "
                 "snapshots of the recurrent state at page boundaries; "
                 "without them a shared or transferred prefix page has no "
-                "state to continue from")
+                "state to continue from"
+                + (" (and its attention is latent attention, whose pages "
+                   "hold [c | k_r]: a shared prefix would have to be "
+                   "expanded through W_ukv in the prefill as well)"
+                   if latent_of(cfg) else ""))
     if window_of(cfg) is not None:
         return ("has window-attention layers, whose K/V is kept by slot "
                 "in a ring that holds a suffix of the slot's tokens; a "
@@ -391,20 +411,11 @@ def init_paged_kv_cache(
     state-carrying layers gets a ``HybridCache``: the pool over its
     full-attention layers plus the zeroed state and convolution tail of
     ``slots`` slots (on one device, or replicated: sharding a recurrent
-    state over heads is not written)."""
+    state over heads is not written); where its attention is latent
+    attention the pool is the latent one and there is no ``v``."""
     dt = dtype or getattr(cfg, "dtype", jnp.bfloat16)
     sk, sv = (sharding.k, sharding.v) \
         if isinstance(sharding, PagedKVCache) else (sharding, sharding)
-    if latent_of(cfg):
-        if sk is not None and len(sk.device_set) > 1:
-            raise NotImplementedError(
-                "a latent cache over several devices is not written (its "
-                "one cached head has no axis to shard; a deployment runs "
-                "such attention data-parallel): serve this model on one "
-                "device")
-        return LatentCache(k=jnp.zeros(
-            latent_cache_shape(cfg, num_pages, page_size), dt, device=sk))
-    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     window = window_of(cfg)
     by_slot = window is not None or carries_state(cfg)
     if by_slot and slots is None:
@@ -412,6 +423,20 @@ def init_paged_kv_cache(
             f"{type(cfg).__name__} keeps memory by slot beside its pages "
             "(a recurrent state, or window layers' rings): its cache "
             "needs the number of slots beside the number of pages")
+    if latent_of(cfg):
+        if sk is not None and len(sk.device_set) > 1:
+            raise NotImplementedError(
+                "a latent cache over several devices is not written (its "
+                "one cached head has no axis to shard; a deployment runs "
+                "such attention data-parallel): serve this model on one "
+                "device")
+        pool = jnp.zeros(
+            latent_cache_shape(cfg, num_pages, page_size), dt, device=sk)
+        if not by_slot:
+            return LatentCache(k=pool)
+        return HybridCache(pool, None,
+                           *_zero_recurrent_state(cfg, slots, dt, sk))
+    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
     if by_slot and sk is not None and len(sk.device_set) > 1:
         # asked before the pools are made: a family with ONE K/V head
         # (Jamba) has no head axis a second device could take

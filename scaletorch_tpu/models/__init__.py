@@ -28,6 +28,10 @@ from scaletorch_tpu.models.pangu_ultra_moe import (  # noqa: F401
     PanguUltraMoE,
     PanguUltraMoEConfig,
 )
+from scaletorch_tpu.models.kimi_linear import (  # noqa: F401
+    KimiLinear,
+    KimiLinearConfig,
+)
 from scaletorch_tpu.models.gpt_moe import GPTMoE, GPTMoEConfig  # noqa: F401
 from scaletorch_tpu.models.lenet import LeNet, LeNetConfig  # noqa: F401
 from scaletorch_tpu.models.resnet import ResNetConfig  # noqa: F401

@@ -21,6 +21,7 @@ from scaletorch_tpu.models import (
     afmoe,
     gpt_moe,
     jamba,
+    kimi_linear,
     llama,
     olmo_hybrid,
     olmoe,
@@ -51,7 +52,8 @@ class Family:
     # a row of a prefill call names its slot (``slot_ids``): a call's
     # rows are its admitted prompts and the family's one prefill program
     # is one row (``inference.decode.SlotRows``). Written and
-    # parity-tested for the two delta-rule families. The same write
+    # parity-tested for the two delta-rule families and for kimi_linear
+    # (whose row also writes latent rows at its pages). The same write
     # would serve jamba's state, and the two other by-slot shapes are a
     # forward each (afmoe: ``RingKVIO``'s table from slot ids;
     # pangu_ultra_moe: a page-addressed long-prompt shape), but their
@@ -101,6 +103,15 @@ FAMILIES: Dict[str, Family] = {
             "prediction module and loss that are not built, and there "
             "is no HF weight loading; the family is served "
             "(scripts/serve.py --preset openpangu-ultra-moe-718b)")),
+    "kimi_linear": Family(
+        kimi_linear, kimi_linear.KimiLinearConfig, counts_routing=True,
+        rows_name_slots=True,
+        untrained=(
+            "its per-channel delta-rule scan has no backward kernel and "
+            "no loss wiring, its KDA and latent layers have no sharding "
+            "rules (tp / cp / pp), its experts no exchange (ep), and "
+            "there is no HF weight loading; the family is served "
+            "(scripts/serve.py --preset kimi-linear-48b-a3b)")),
     # served and tested through its config class; trains via its example
     "gpt_moe": Family(gpt_moe, gpt_moe.GPTMoEConfig),
 }

@@ -312,7 +312,17 @@ def gated_delta_step(q, k, v, log_alpha, beta, state):
     beta [B, H], state [B, H, d_k, d_v], all float32 -> (o [B, H, d_v],
     the new state). Products and sums on the state are elementwise: the
     step is bound by reading and writing it, and keeps float32 exactly
-    (a dot would round the state to bf16 passes on a TPU)."""
+    (a dot would round the state to bf16 passes on a TPU).
+
+    ``log_alpha`` [B, H, d_k] is a decay per KEY CHANNEL (Kimi Delta
+    Attention): ``S' = diag(alpha) S``, then the same rule on ``S'``."""
+    if log_alpha.ndim == k.ndim:
+        decayed = jnp.exp(log_alpha)[..., None] * state
+        s_k = jnp.sum(decayed * k[..., None], axis=-2)     # S'^T k
+        s_q = jnp.sum(decayed * q[..., None], axis=-2)     # S'^T q
+        u = beta[..., None] * (v - s_k)
+        new = decayed + k[..., None] * u[..., None, :]
+        return s_q + jnp.sum(k * q, axis=-1, keepdims=True) * u, new
     alpha = jnp.exp(log_alpha)[..., None]
     s_k = jnp.sum(state * k[..., None], axis=-2)           # S^T k
     s_q = jnp.sum(state * q[..., None], axis=-2)           # S^T q
@@ -325,7 +335,8 @@ def gated_delta_step(q, k, v, log_alpha, beta, state):
 def gated_delta_sequential(q, k, v, log_alpha, beta, state):
     """``gated_delta_step`` over time, one row after another: q, k
     [B, S, H, d_k], v [B, S, H, d_v], log_alpha, beta [B, S, H] ->
-    (o [B, S, H, d_v], the last state). What ``gated_delta_chunked``
+    (o [B, S, H, d_v], the last state); ``log_alpha`` [B, S, H, d_k]
+    where the decay is per key channel. What ``gated_delta_chunked``
     computes, written as the definition (the tests' oracle)."""
     def body(s, row):
         o, s = gated_delta_step(*row, s)
@@ -388,7 +399,13 @@ def gated_delta_chunked(q, k, v, log_alpha, beta, state, *,
     ``O = diag(G) Q S0 + (QK^T * G_r/G_i, i<=r) U``, ``S_C = G_C S0 +
     (diag(G_C/G) K)^T U``. Every ratio ``G_r/G_i`` has i <= r and is at
     most 1. Matrix products in float32 at ``highest`` precision: a few
-    per cent of a prefill call's time."""
+    per cent of a prefill call's time.
+
+    ``log_alpha`` [B, S, H, d_k], a decay per key channel, takes
+    ``_chunked_by_channel``: the ratios sit inside the contractions."""
+    if log_alpha.ndim == q.ndim:
+        return _chunked_by_channel(q, k, v, log_alpha, beta, state,
+                                   chunk=chunk)
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     pad = -s % chunk
@@ -432,6 +449,120 @@ def gated_delta_chunked(q, k, v, log_alpha, beta, state, *,
 
     state, o = jax.lax.scan(
         body, state, (q, u_v, w, qk, g_out, g_end, k_end))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2)          # [B, N, C, H, dv]
+    return o.reshape(b, n * chunk, h, dv)[:, :s], state
+
+
+# rows of one sub-block of a chunk where the decay is per key channel
+SUB_BLOCK = 16
+# elements of the diagonal sub-blocks' pairwise decays ``[chunks, B, H,
+# C / c, c, c, d_k]`` that are alive at once (float32: 256 MB): the
+# chunks of a long prompt go through in groups of this many
+_PAIRWISE_ELEMENTS = 1 << 26
+
+
+def _channel_chunk_parts(q, k, v, log_alpha, beta, block):
+    """What one chunk contributes whatever state it meets, the decay per
+    key channel: q, k, log_alpha [..., C, d_k], v [..., C, d_v], beta
+    [..., C] -> (``U_v`` [..., C, d_v], ``W`` [..., C, d_k], the decayed
+    ``Q K^T`` [..., C, C], ``diag(G) Q``, ``G_C`` [..., d_k],
+    ``diag(G_C / G) K``) with ``G_r = exp(g_r)`` the product of the
+    alphas up to row r, a VECTOR over the key channels. The ratio
+    ``G_r / G_i`` sits inside the contraction: ``A_ri = beta_r sum_d
+    k_rd k_id exp(g_rd - g_id)``, and ``(k_r e^{g_r}) . (k_i e^{-g_i})``
+    overflows float32 within one chunk (``-g`` reaches hundreds at a
+    decay of e^-30 a step). So the chunk is cut in sub-blocks of
+    ``block`` rows: on the diagonal sub-blocks the differences ``g_r -
+    g_i`` are taken pair by pair, exactly; off the diagonal both
+    factors are taken relative to the row block's first row b, ``exp(g_r
+    - g_b) <= 1`` and ``exp(g_b - g_i) <= 1`` (i lies before b), whose
+    product is the ratio and can only underflow where the ratio does."""
+    c = q.shape[-2]
+    nb = c // block
+    dv = v.shape[-1]
+    g = jnp.cumsum(log_alpha, axis=-2)                     # [..., C, dk]
+
+    def blocks(a):
+        return a.reshape(a.shape[:-2] + (nb, block, a.shape[-1]))
+
+    gb, kb, qb = blocks(g), blocks(k), blocks(q)
+    rows = jnp.arange(block)
+    pair = gb[..., :, None, :] - gb[..., None, :, :]       # g_r - g_i
+    pair = jnp.exp(jnp.where(
+        (rows[:, None] >= rows[None, :])[..., None], pair, -jnp.inf))
+    k_pair = kb[..., None, :, :] * pair
+    kk_diag = jnp.sum(kb[..., :, None, :] * k_pair, axis=-1)
+    qk_diag = jnp.sum(qb[..., :, None, :] * k_pair, axis=-1)
+    kk_rows, qk_rows = [], []
+    for r in range(nb):
+        parts_k, parts_q = [kk_diag[..., r, :, :]], [qk_diag[..., r, :, :]]
+        if r:
+            first = gb[..., r, :1, :]                      # g_b
+            shrink = jnp.exp(gb[..., r, :, :] - first)
+            before = k[..., :r * block, :] * jnp.exp(
+                first - g[..., :r * block, :])
+            parts_k.insert(0, jnp.einsum(
+                "...rd,...id->...ri", kb[..., r, :, :] * shrink, before,
+                precision=_HIGHEST))
+            parts_q.insert(0, jnp.einsum(
+                "...rd,...id->...ri", qb[..., r, :, :] * shrink, before,
+                precision=_HIGHEST))
+        after = jnp.zeros(q.shape[:-2] + (block, (nb - 1 - r) * block),
+                          q.dtype)
+        kk_rows.append(jnp.concatenate(parts_k + [after], axis=-1))
+        qk_rows.append(jnp.concatenate(parts_q + [after], axis=-1))
+    kk = jnp.concatenate(kk_rows, axis=-2)                 # [..., C, C]
+    qk = jnp.concatenate(qk_rows, axis=-2)
+    at = jnp.arange(c)
+    a = jnp.where(at[:, None] > at[None, :], beta[..., None] * kk, 0.0)
+    grown = jnp.exp(g)                                     # G_r
+    rhs = jnp.concatenate(
+        [beta[..., None] * v, beta[..., None] * grown * k], axis=-1)
+    solved = _mm(unit_lower_inverse(a, block), rhs)
+    return (solved[..., :dv], solved[..., dv:], qk, grown * q,
+            grown[..., -1, :], k * jnp.exp(g[..., -1:, :] - g))
+
+
+def _chunked_by_channel(q, k, v, log_alpha, beta, state, *, chunk: int,
+                        block: int = SUB_BLOCK):
+    """``gated_delta_chunked`` where ``log_alpha`` [B, S, H, d_k] is a
+    decay per key channel: ``S_r = diag(G_r) S0 + sum_{i<=r} diag(G_r /
+    G_i) k_i u_i^T``, the ``u`` solving ``(I + A) U = diag(beta) (V -
+    (diag(G) K) S0)`` (``_channel_chunk_parts``); a scan over the chunks
+    moves the state: ``O = (diag(G) Q) S0 + (decayed Q K^T) U``, ``S_C =
+    diag(G_C) S0 + (diag(G_C / G) K)^T U``."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -s % chunk
+    if pad:
+        q, k, v, log_alpha, beta = (
+            jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+            for a in (q, k, v, log_alpha, beta))
+    n = (s + pad) // chunk
+
+    def chunks(a):          # [B, S, H, ...] -> [N, B, H, C, ...]
+        a = a.reshape((b, n, chunk) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    rows = tuple(map(chunks, (q, k, v, log_alpha, beta)))
+    group = max(1, min(n, _PAIRWISE_ELEMENTS
+                       // (b * h * chunk * block * dk)))
+    while n % group:
+        group -= 1
+    parts = jax.lax.map(
+        lambda xs: _channel_chunk_parts(*xs, block),
+        tuple(a.reshape((n // group, group) + a.shape[1:]) for a in rows))
+    parts = tuple(a.reshape((n,) + a.shape[2:]) for a in parts)
+
+    def body(s0, xs):
+        u_v, w, qk, q_out, g_end, k_end = xs
+        u = u_v - _mm(w, s0)
+        o = _mm(q_out, s0) + _mm(qk, u)
+        s1 = g_end[..., None] * s0 + jnp.einsum(
+            "bhrk,bhrv->bhkv", k_end, u, precision=_HIGHEST)
+        return s1, o
+
+    state, o = jax.lax.scan(body, state, parts)
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2)          # [B, N, C, H, dv]
     return o.reshape(b, n * chunk, h, dv)[:, :s], state
 

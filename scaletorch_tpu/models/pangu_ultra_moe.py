@@ -323,7 +323,9 @@ def head_groups(rows: int, heads: int, width: int, itemsize: int) -> int:
 
 
 def _rotated(x: jax.Array, rope) -> jax.Array:
-    return apply_rotary_pos_emb(x, x, *rope)[0]
+    """``x`` under the rotary tables ``rope``; as it is where the model
+    has none (``None``: kimi_linear's latent layers, ``mla_use_nope``)."""
+    return x if rope is None else apply_rotary_pos_emb(x, x, *rope)[0]
 
 
 def _expanded_attention(c_q, c, k_r, w_uq, w_ukv, rope, cfg):
@@ -362,7 +364,8 @@ def latent_attention(
 ) -> Tuple[jax.Array, Any]:
     """The latent-attention mixer of the normed hidden states ``u``
     [B, S, H]: ``[c | k_r]`` written at ``index`` of the latent cache
-    ``pool`` through ``io``; a call of one row reads the cache in the
+    ``pool`` through ``io`` (``rope`` None: nothing is rotated; a layer
+    without ``q_a_proj`` has no query latent); a call of one row reads the cache in the
     absorbed form, a call of several rows attends to itself in the
     expanded form, ``head_groups`` groups of heads at a time (module
     docstring). Returns (the mixer's output before its norm, the
@@ -371,11 +374,17 @@ def latent_attention(
     heads, nope, rot = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                         cfg.qk_rope_head_dim)
     b, s, _ = u.shape
-    w_uq = layer["q_b_proj"].astype(cdt).reshape(-1, heads, nope + rot)
     w_ukv = layer["kv_b_proj"].astype(cdt)       # [rank, heads, nope + v]
-    with jax.named_scope("mla.q_latent"):
-        c_q = rms_norm(u @ layer["q_a_proj"].astype(cdt),
-                       layer["q_a_layernorm"], eps)
+    if "q_a_proj" in layer:
+        w_uq = layer["q_b_proj"].astype(cdt).reshape(-1, heads, nope + rot)
+        with jax.named_scope("mla.q_latent"):
+            c_q = rms_norm(u @ layer["q_a_proj"].astype(cdt),
+                           layer["q_a_layernorm"], eps)
+    else:
+        # no query latent (``q_lora_rank`` null, kimi_linear): the
+        # heads' queries come straight from the normed hidden states
+        w_uq = layer["q_proj"].astype(cdt).reshape(-1, heads, nope + rot)
+        c_q = u
     with jax.named_scope("mla.kv_latent"):
         kv_a = u @ layer["kv_a_proj_with_mqa"].astype(cdt)
         c = rms_norm(kv_a[..., :cfg.kv_lora_rank],

@@ -468,6 +468,79 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         sandwich_norm=True,
         num_nextn_predict_layers=1,
     ),
+    # Kimi-Linear-48B-A3B-Instruct (moonshotai config.json, model_type
+    # kimi_linear), as published: 27 layers, three Kimi Delta Attention
+    # layers (32 heads x 128, a decay per key channel) to one rope-free
+    # latent-attention layer (32 heads on a 512 + 64 row), one leading
+    # dense layer, then 256 sigmoid-routed experts of width 1024 (top 8)
+    # and an ungated shared expert; 48 B parameters = 96 GB in bf16. One
+    # chip serves a share (benchmarks/configs/
+    # kimi-linear-48b-a3b-serve.json): --num_hidden_layers 8 with the
+    # first eight entries of linear_attn_config, --num_experts 64
+    # --num_routed_experts 256, a quarter of the vocabulary.
+    "kimi-linear-48b-a3b": dict(
+        model_type="kimi_linear",
+        vocab_size=163840,
+        hidden_size=2304,
+        intermediate_size=9216,
+        num_hidden_layers=27,
+        num_attention_heads=32,
+        num_key_value_heads=32,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=1048576,
+        tie_word_embeddings=False,
+        linear_attn_config=dict(
+            kda_layers=[1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18,
+                        19, 21, 22, 23, 25, 26],
+            full_attn_layers=[4, 8, 12, 16, 20, 24, 27],
+            head_dim=128, num_heads=32, short_conv_kernel_size=4),
+        q_lora_rank=None,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        first_k_dense_replace=1,
+        num_experts=256,
+        num_experts_per_token=8,
+        moe_intermediate_size=1024,
+        num_shared_experts=1,
+        moe_renormalize=True,
+        routed_scaling_factor=2.446,
+    ),
+    # The same family at a size the CPU tests serve: a dense layer, two
+    # whole periods and the published list's short last one (11 layers:
+    # 8 KDA of 2 heads x 16, 3 latent of 4 heads over a 32 + 8 row), and
+    # a SHARE of the experts: 4 of 16 routed ones held here, from id 4.
+    "kimi-linear-tiny": dict(
+        model_type="kimi_linear",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=11,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=4096,
+        tie_word_embeddings=False,
+        linear_attn_config=dict(
+            kda_layers=[1, 2, 3, 5, 6, 7, 9, 10],
+            full_attn_layers=[4, 8, 11],
+            head_dim=16, num_heads=2, short_conv_kernel_size=4),
+        q_lora_rank=None,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        first_k_dense_replace=1,
+        num_experts=4,
+        num_routed_experts=16,
+        first_expert_id=4,
+        num_experts_per_token=3,
+        moe_intermediate_size=32,
+        num_shared_experts=1,
+        moe_renormalize=True,
+        routed_scaling_factor=2.446,
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

@@ -57,6 +57,26 @@ def token_blocks(n: int, k: int, hidden: int, itemsize: int) -> int:
                 if n % b == 0 and (n // b) * row_bytes <= _SORTED_ROWS_BLOCK)
 
 
+# the widest K / N tile taken where 1,024 does not divide the width
+_TILE_CAP = 1152
+
+
+def _width_tile(width: int) -> int:
+    """The K or N tile of a ``width``: 1,024 (the whole width below it),
+    which megablox masks the remainder of. Where that remainder leaves
+    over an eighth of the tiles' span empty (2,304 = 2.25 tiles: a
+    quarter of the three; 7,680 = 7.5 tiles: a sixteenth of the eight,
+    which stays as it is), the widest multiple of 128 up to
+    ``_TILE_CAP`` that divides the width (2,304: 1,152)."""
+    if width <= 1024:
+        return width
+    span = -(-width // 1024) * 1024
+    if 8 * (span - width) <= span:
+        return 1024
+    return max((t for t in range(128, _TILE_CAP + 1, 128)
+                if width % t == 0), default=1024)
+
+
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """(tm, tk, tn) of the megablox kernel for an [m, k] x [G, k, n]
     product. A decode step has at most a few rows a group, so its row
@@ -64,7 +84,7 @@ def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     VMEM takes: the call is weight streaming. A prefill call has
     hundreds of rows a group and takes the square-ish MXU tiles."""
     tm = min(_ROW_TILE, -(-m // 128) * 128)
-    return tm, min(k, 1024), min(n, 1024)
+    return tm, _width_tile(k), _width_tile(n)
 
 
 def ragged_matmul(rows, weights, group_sizes, *, out_dtype=None):
@@ -75,9 +95,11 @@ def ragged_matmul(rows, weights, group_sizes, *, out_dtype=None):
 
 
 def pallas_matmul(rows, weights, group_sizes, *, out_dtype=None,
-                  tiling: Optional[Tuple[int, int, int]] = None):
+                  tiling: Optional[Tuple[int, int, int]] = None,
+                  interpret: bool = False):
     """The Pallas form: megablox ``gmm`` under ``tiling`` (default: from
-    the shapes)."""
+    the shapes). ``interpret`` runs the kernel off the chip (the tests'
+    value check of a tiling)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     out_dtype = out_dtype or rows.dtype
@@ -90,7 +112,8 @@ def pallas_matmul(rows, weights, group_sizes, *, out_dtype=None,
         rows = jnp.pad(rows, ((0, padded - m), (0, 0)))
     with jax.named_scope("moe_grouped_matmul"):
         out = gmm(rows, weights, group_sizes,
-                  preferred_element_type=out_dtype, tiling=(tm, tk, tn))
+                  preferred_element_type=out_dtype, tiling=(tm, tk, tn),
+                  interpret=interpret)
     # row tiles no group covers are never visited, so never written
     return out[:m]
 

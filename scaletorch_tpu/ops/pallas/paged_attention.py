@@ -545,8 +545,19 @@ def pallas_paged_decode_attention(
 # block: 211 / 198 / 210 / 226 us a call of 22,000 rows at 128 / 256 /
 # 512 / 1,024 keys: PERF.md, PR 51), so the block only has to keep the
 # score tile [heads, keys] in float32 (128 KB at 128 heads) and the
-# landing zone (0.64 MB of rows double-buffered at 640 wide) small
+# landing zone (0.64 MB of rows double-buffered at 640 wide) small.
+# That is the block at 128 heads or more; fewer heads take as many more
+# keys as keep the score tile's size, up to four times (32 heads: 1,024
+# keys, a landing zone of 2.6 MB on a bfloat16 pool).
+# Alone on the v5e, 32 slots of 6,700 rows at 32 heads: 0.705 ms a call
+# at 256 keys, 0.559 at 512, 0.490 at 1,024 (PERF.md, PR 54)
 _LATENT_BLOCK_KEYS = 256
+
+
+def _latent_block_keys(heads: int) -> int:
+    """Keys of one compute block of the latent kernel for ``heads``
+    query heads."""
+    return _LATENT_BLOCK_KEYS * min(4, max(1, 128 // heads))
 
 
 def _latent_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, pool_hbm,
@@ -645,7 +656,7 @@ def pallas_latent_decode_attention(
     (keys j <= position). Returns [B, H, value_width] float32-accumulated
     ``sum_j p_h(j) c(j)``: a row's first ``value_width`` columns are its
     value. The pool stays in HBM; each slot's step copies its live
-    pages once for ALL heads, ``_LATENT_BLOCK_KEYS`` keys a block,
+    pages once for ALL heads, ``_latent_block_keys(heads)`` keys a block,
     double-buffered, and reduces flash-style: no expanded key or value
     of any cached token exists anywhere."""
     b, heads, row = q.shape
@@ -661,7 +672,7 @@ def pallas_latent_decode_attention(
             f"slices the value off the row at a {_LANES}-lane boundary; "
             f"got row {row}, value {value_width}")
     max_pages = page_tables.shape[1]
-    ppb = max(1, min(_LATENT_BLOCK_KEYS // page_size, max_pages))
+    ppb = max(1, min(_latent_block_keys(heads) // page_size, max_pages))
     if not interpret:
         pool = pltpu.with_memory_space_constraint(pool, pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(

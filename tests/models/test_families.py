@@ -17,6 +17,7 @@ from scaletorch_tpu.models import (
     afmoe,
     gpt_moe,
     jamba,
+    kimi_linear,
     llama,
     olmo_hybrid,
     olmoe,
@@ -46,6 +47,7 @@ EXPECTED = {
     "jamba": (jamba, jamba.forward_cached, False),
     "pangu_ultra_moe": (pangu_ultra_moe, pangu_ultra_moe.forward_cached,
                         True),
+    "kimi_linear": (kimi_linear, kimi_linear.forward_cached, True),
     "gpt_moe": (gpt_moe, gpt_moe.forward_cached, False),
 }
 TRAINS = {"llama", "qwen3", "qwen3_moe", "olmoe", "gpt_moe"}
@@ -56,7 +58,7 @@ def built(name):
     return build_model_config(ScaleTorchTPUArguments(**preset(name)))
 
 
-def test_the_rows_are_the_ten_families():
+def test_the_rows_are_the_eleven_families():
     assert set(FAMILIES) == set(EXPECTED)
     classes = [row.config_cls for row in FAMILIES.values()]
     assert len(set(classes)) == len(classes)
@@ -149,7 +151,7 @@ def test_embed_init_std_is_read_where_the_class_has_the_field(model_type):
     has = "embed_init_std" in FAMILIES[
         model_type].config_cls.__dataclass_fields__
     assert has == (model_type in ("qwen3_next", "afmoe", "jamba",
-                                  "pangu_ultra_moe"))
+                                  "pangu_ultra_moe", "kimi_linear"))
     if not has:
         with pytest.raises(NotImplementedError, match="embed_init_std"):
             build_model_config(ScaleTorchTPUArguments(
@@ -161,11 +163,11 @@ def test_embed_init_std_is_read_where_the_class_has_the_field(model_type):
 @pytest.mark.parametrize("model_type", sorted(EXPECTED))
 def test_the_draw_s_scales_are_read_where_the_class_has_the_field(
         model_type, name):
-    """Two more properties of random weights a launch may set: the one
-    family whose initialiser reads them has the fields, every other
+    """Two more properties of random weights a launch may set: the two
+    families whose initialisers read them have the fields, every other
     refuses each by name."""
     has = name in FAMILIES[model_type].config_cls.__dataclass_fields__
-    assert has == (model_type == "pangu_ultra_moe")
+    assert has == (model_type in ("pangu_ultra_moe", "kimi_linear"))
     if not has:
         with pytest.raises(NotImplementedError, match=name):
             build_model_config(ScaleTorchTPUArguments(

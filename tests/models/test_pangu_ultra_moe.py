@@ -356,7 +356,8 @@ def test_the_latent_kernel_in_interpret_mode_is_the_xla_form():
         q, pool, tables, positions, kernel=False, **kw)
     for keys in (16, 512):
         with pytest.MonkeyPatch.context() as patch:
-            patch.setattr(paged_attention, "_LATENT_BLOCK_KEYS", keys)
+            patch.setattr(paged_attention, "_latent_block_keys",
+                          lambda heads, keys=keys: keys)
             got = paged_attention.latent_attention(
                 q, pool, tables, positions, kernel=True, interpret=True,
                 **kw)

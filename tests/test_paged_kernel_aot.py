@@ -1136,6 +1136,94 @@ def test_pangu_steps_are_what_the_new_readers_look_for(one_chip):
                 + memory.temp_size_in_bytes) < 0.75 * chip, name
 
 
+def test_kimi_steps_are_what_the_new_readers_look_for(one_chip):
+    """Kimi-Linear's two step programs at the cell's shapes (32 slots,
+    8,192-row prompts, ``max_seq`` 9,728; 6 KDA + 2 latent layers, a
+    256-wide router over 64 held experts). **One cache, two kinds of
+    memory**: the latent pool ``[2, 32 x 608 + 1, 1, 16, 640]`` is
+    written by ONE ``paged_write`` a latent layer and returned by
+    nothing else, and the state ``f32[6,32,32,128,128]`` is written in
+    place (no copy returns it or a layer of it). **The decode step**
+    reads the pool through ``latent_decode`` at 32 heads
+    (``bf16[32,32,512]``, what ``serve_kimi_latent_attn_hbm_roofline``
+    matches) and its experts through 21 ``gmm`` calls told by
+    ``bf16[256,1024]`` / ``bf16[256,2304]`` (32 x 8 sorted rows:
+    ``serve_kimi_expert_mlp_roofline``). **The prefill program is one
+    row that names its slot**: ``s32[1,8192]`` tokens, its latent
+    attention the flash forward over the row's 32 expanded heads (keys
+    192, values 128 wide), no scores ``[heads, 8192, 8192]`` and none of
+    the full shape's ``32 x 8192`` rows. Both fit the chip."""
+    decode, prefill, pool_shape = _programs_of(
+        one_chip, "kimi-linear-48b-a3b-serve")
+    slots, rows = 32, 8192
+    assert pool_shape == (2, slots * 608 + 1, 1, 16, 640)
+    texts = {"decode": decode.as_text(), "prefill": prefill.as_text()}
+    for name, text in texts.items():
+        assert _pool_shaped(text, pool_shape) == {}, name
+        writes = _named(_mosaic_calls(text), "paged_write")
+        assert len(writes) == 2, (name, len(writes))   # one a latent layer
+        for state in ("f32[6,32,32,128,128]", "f32[32,32,128,128]",
+                      "f32[1,32,32,128,128]"):
+            copies = [x for x in _top_level(text, state)
+                      if x[0] in ("copy", "copy-start", "transpose")]
+            assert not copies, (name, copies[:3])
+        for stack in ("bf16[7,64,2304,1024]", "bf16[64,2304,1024]",
+                      "bf16[448,2304,1024]", "bf16[7,64,1024,2304]",
+                      "bf16[64,1024,2304]", "bf16[448,1024,2304]"):
+            moved = [x for x in _top_level(text, stack)
+                     if x[0] not in _PLUMBING | {"bitcast"}
+                     and "tpu_custom_call" not in x[1]]
+            assert not moved, (name, moved[:3])
+
+    def sizes(text):
+        return {dims: math.prod(map(int, dims.split(",")))
+                for dims in set(_ARRAY.findall(text))}
+
+    assert "s32[1,8192]" in texts["prefill"]
+    found = sizes(texts["prefill"])
+    scores = {32 * rows * rows, 32 * rows * 9728}
+    full_shape = {slots * rows * 2304, slots * rows * 4096}
+    assert not [d for d, n in found.items() if n in scores | full_shape]
+    assert f"f32[{slots},40960]" in texts["decode"]
+
+    patterns = {name: _reader_patterns(name) for name in (
+        "serve_kimi_latent_attn_hbm_roofline",
+        "serve_kimi_expert_mlp_roofline",
+        "serve_kimi_kda_state_update_roofline")}
+
+    def matched(program, reader):
+        return [n for n in _short_names(texts[program])
+                if any(re.search(p, n) for p in patterns[reader])]
+
+    attn = matched("decode", "serve_kimi_latent_attn_hbm_roofline")
+    assert len(attn) == 2 and all(
+        n.startswith("latent_decode") and n.endswith("bf16[32,32,512]")
+        for n in attn), attn
+    assert not matched("prefill", "serve_kimi_latent_attn_hbm_roofline")
+    experts = matched("decode", "serve_kimi_expert_mlp_roofline")
+    assert experts and all(n.startswith("gmm") for n in experts)
+    assert {n.rsplit(" | ", 1)[1] for n in experts} == {
+        "bf16[256,1024]", "bf16[256,2304]"}
+    assert not matched("prefill", "serve_kimi_expert_mlp_roofline")
+    updates = matched("decode", "serve_kimi_kda_state_update_roofline")
+    assert any("select_dynamic-update-slice_fusion" in n for n in updates)
+    assert any("(f32[32,32,128], f32[32,32,128])" in n for n in updates)
+    flash = _named(_mosaic_calls(texts["prefill"]), "flash_fwd")
+    assert flash and all("bf16[1,32,8192,128]" in c for c in flash), flash
+    assert not _named(_mosaic_calls(texts["decode"]), "paged_decode")
+
+    cache_bytes = 2 * math.prod(pool_shape) + 6 * 32 * (
+        32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    chip = 15.75 * 2 ** 30
+    for name, program, scratch in (("decode", decode, 0.1e9),
+                                   ("prefill", prefill, 2.4e9)):
+        memory = program.memory_analysis()
+        assert memory.alias_size_in_bytes >= cache_bytes, name
+        assert memory.temp_size_in_bytes < scratch, name
+        assert (memory.argument_size_in_bytes
+                + memory.temp_size_in_bytes) < 0.75 * chip, name
+
+
 def test_the_latent_kernel_compiles_for_a_longer_table(one_chip):
     """``latent_decode`` alone at 128 heads over a 640-wide row with a
     table of 8,192 pages (131,072 positions, the published context): one
