@@ -329,14 +329,20 @@ def leg_kernels(dry_run: bool) -> dict:
             *a, interpret=interpret))(qd, pool_k, pool_v, tables, positions)
         with jax.default_matmul_precision("highest"):
             out_t = gather_ref(jnp.float32)
+        # a dead slot (position -1: no key) is looked past by the walk
+        # and reads 0; the reference has no answer for it
+        live = (positions >= 0)[:, None, None]
         paged = {
             "shape": f"B{slots} Hq{hq} Hkv{hkv} D{d} page{page} "
                      f"max_pages{max_pages} bf16",
-            "max_abs_err_kernel": max_abs(out_k - out_t),
-            "max_abs_err_xla_bf16": max_abs(gather_ref(jnp.bfloat16) - out_t),
+            "max_abs_err_kernel": max_abs(jnp.where(live, out_k - out_t, 0)),
+            "max_abs_err_xla_bf16": max_abs(jnp.where(
+                live, gather_ref(jnp.bfloat16) - out_t, 0)),
         }
         check(bool(jnp.all(jnp.isfinite(out_k.astype(jnp.float32)))),
               f"paged decode output is not finite ({paged['shape']})")
+        check(bool(jnp.all(jnp.where(live, 0, out_k) == 0)),
+              f"a dead slot's output is not 0 ({paged['shape']})")
         check(paged["max_abs_err_kernel"] <= FWD_ATOL,
               f"paged decode off by {paged['max_abs_err_kernel']:.3g} "
               f"> {FWD_ATOL} ({paged['shape']})")
@@ -346,14 +352,15 @@ def leg_kernels(dry_run: bool) -> dict:
     max_pages = 8 if dry_run else 128          # max_seq 2048
     last = max_pages * page - 1
     paged, (qd, pool_k, pool_v, tables, positions) = paged_case(
-        8, max_pages, [0, page - 1, page, 5 * page + 3, last // 4,
+        9, max_pages, [0, page - 1, page, 5 * page + 3, -1, last // 4,
                        last // 2, last - 1, last])
     # the serving cell's shape (qwen3-1.7b-serve: 16 slots x 96 pages),
-    # ragged from 31 to the last position a slot can hold
+    # ragged from 31 to the last position a slot can hold, one slot dead
     serve_pages = 6 if dry_run else 96
-    paged_serving, _ = paged_case(
-        16, serve_pages,
-        np.linspace(31, serve_pages * page - 1, 16).astype(np.int32))
+    serve_positions = np.linspace(
+        31, serve_pages * page - 1, 16).astype(np.int32)
+    serve_positions[5] = -1
+    paged_serving, _ = paged_case(16, serve_pages, serve_positions)
 
     # ---- the page write, in place, vs the scatter: bit for bit ----------
     def write_case(slots, max_pages, rows, layers=3, layer=1):

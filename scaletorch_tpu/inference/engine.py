@@ -146,7 +146,10 @@ from scaletorch_tpu.inference.resilience import (
     ServingFaultInjector,
 )
 from scaletorch_tpu.inference.sampling import SamplingParams
-from scaletorch_tpu.ops.pallas.paged_attention import in_place_pair
+from scaletorch_tpu.ops.pallas.paged_attention import (
+    chained_first_blocks,
+    in_place_pair,
+)
 from scaletorch_tpu.telemetry.histogram import LogHistogram
 from scaletorch_tpu.telemetry.spans import span
 from scaletorch_tpu.utils.logger import get_logger
@@ -267,6 +270,15 @@ class EngineMetrics:
     prefill_positions_run: int = 0
     prefill_positions_admitted: int = 0
     decode_steps: int = 0           # decode steps dispatched
+    # slots the paged-decode kernel walked (a slot with a live page at
+    # the position its step was given; an inactive slot is given 0 and
+    # is walked too), summed over the kernel's calls, and of them the
+    # slots whose first block the slot before had already started
+    # (``paged_attention.chained_first_blocks`` of each dispatched
+    # step's positions x the layers that call the kernel; 0 where the
+    # lax pair or the latent kernel reads the pool)
+    paged_slot_walks: int = 0
+    paged_slot_walks_chained: int = 0
     # ... of them, dispatched while the step before, or the prefill
     # call before, had not been read back (the decode loop running one
     # step ahead, across an admission too), and slot-steps
@@ -374,6 +386,8 @@ class EngineMetrics:
             "decode_steps": self.decode_steps,
             "decode_steps_ahead": self.decode_steps_ahead,
             "decode_slot_steps_discarded": self.decode_slot_steps_discarded,
+            "paged_slot_walks": self.paged_slot_walks,
+            "paged_slot_walks_chained": self.paged_slot_walks_chained,
             "slow_ticks": self.slow_ticks,
             "queue_depth": self.queue_depth,
             "num_slots": self.num_slots,
@@ -832,6 +846,14 @@ class InferenceEngine:
             recurrent_state_bytes=recurrent_state_bytes(self.cache),
             window_cache_bytes=window_cache_bytes(self.cache),
             latent_cache_bytes=latent_cache_bytes(self.cache))
+        # calls of the paged-decode kernel in one decode step: a layer
+        # of the pool and a ring layer each make one (none where the lax
+        # pair reads the pool, or the latent kernel a latent one)
+        self._kernel_calls_a_step = (
+            0 if self.metrics.latent_cache_bytes
+            or not self.metrics.paged_pool_in_place
+            else self.cache.k.shape[0] + (
+                self.cache.wk.shape[0] if self._window is not None else 0))
         # phase clocks: cumulative seconds [STALL, DEVICE_WAIT, HOST],
         # the clock that is open, the last boundary; this tick's seconds
         # by phase name; when the previous tick ended, and whether it
@@ -2014,6 +2036,13 @@ class InferenceEngine:
                 self.metrics.latent_keys_attended += int(
                     positions[held].astype(np.int64).sum()) + len(held)
             active[held] = True
+            if self._kernel_calls_a_step:
+                walked, chained = chained_first_blocks(
+                    positions, self.page_size, self._pages_per_slot)
+                self.metrics.paged_slot_walks += (
+                    walked * self._kernel_calls_a_step)
+                self.metrics.paged_slot_walks_chained += (
+                    chained * self._kernel_calls_a_step)
             # positions and active stay numpy: the jitted call uploads
             # host operands itself, without the 0.25 ms of Python a
             # jnp.asarray each costs on a v5e's host

@@ -24,16 +24,29 @@ one of two pairs of a write and a read:
     layout XLA's scatter likes and converted back, whole, for every
     Mosaic read (PERF.md, PR 28).
     The decode kernel reads one query token per slot against its paged
-    cache. The grid is ``(B,)``, one step per slot. A step walks the
-    slot's *live* pages only, a block of ``_pages_per_block`` at a
-    time: one async copy per page brings ``pool.at[layer, page]``,
+    cache. The grid is ``(B,)``, one step per slot, in order. A step
+    walks the slot's *live* pages only, a block of ``_pages_per_block``
+    at a time: one async copy per page brings ``pool.at[layer, page]``,
     ``[Hkv, page_size, D]`` (all KV heads of a page, contiguous in the
     pool), into a double-buffered VMEM landing zone
     ``[2, Hkv, block, D]`` while the block before it is reduced
     flash-style, so the dense ``[B, Hkv, S_max, D]`` view is never
     materialised in HBM and a page past the slot's length costs
     nothing — no grid step, no DMA (the last block's dead pages
-    re-read the last live page and are masked). GQA reads grouped K/V
+    re-read the last live page and are masked). **The copy pipeline
+    does not stop at a slot's end**: while a slot's LAST block is
+    waited for and reduced, the copies of block 0 of the next slot that
+    has a live page (a slot at a negative position is looked past) are
+    already on their way into the other landing buffer, and that
+    slot's step finds them in flight; only the first walked slot of a
+    call starts its own first block in the open. What a slot hands the
+    next one, the buffer its walk starts on and "your first block is in
+    flight", lives in two SMEM words of scratch; the next slot's walk
+    comes from the positions and tables the kernel already holds, so
+    full layers and ``window`` layers chain alike. On the v5e, alone, a
+    live slot costs nothing over its blocks this way (0.75 us before:
+    PERF.md, PR 55). ``chained_first_blocks`` is the same rule on the
+    host, for the engine's counters. GQA reads grouped K/V
     unexpanded — one batched dot over the KV heads, the ``n_rep`` query
     heads of a KV head the rows of its ``[n_rep, block]`` score tile.
     ``_pages_per_block`` follows from shapes alone: enough pages for a
@@ -42,7 +55,7 @@ one of two pairs of a write and a read:
     pair serves a head_dim that is a multiple of 128
     (``kernel_serves``); narrower heads take the lax pair. What the
     kernel takes on the chip, and what the one-page-of-one-head grid it
-    replaced took, is in PERF.md (PR 25).
+    replaced took, is in PERF.md (PRs 25 and 55).
   * **The lax pair** (``paged_write_kv`` + ``paged_gather_kv`` and the
     models' shared ``cached_sdpa_attention``): a batched scatter at
     ``pool.at[layer, pages, :, offsets, :]`` and a whole-table gather
@@ -77,6 +90,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -351,48 +365,109 @@ def _pages_per_block(page_size: int, hkv: int, d: int, dtype,
     return max(1, min(fill_lanes, fit_budget, max_pages))
 
 
+def _slot_walk(pos, page_size, max_pages, window, xp=jnp):
+    """(first logical page, live pages) of the walk of a slot whose
+    query sits at ``pos``: no page for a negative position, never past
+    the table; under a ``window`` the walk starts at the page that holds
+    the window's first key. One rule for the kernel (traced scalars) and
+    for the host's count of what it does (``xp=np``)."""
+    n_live = xp.clip(pos // page_size + 1, 0, max_pages)
+    if window is None:
+        return 0, n_live
+    first = xp.maximum(pos - window + 1, 0) // page_size
+    return first, xp.maximum(n_live - first, 0)
+
+
+def _next_live_slot(pos_ref, b, n_slots, live_pages):
+    """The first slot after ``b`` with a page to walk (``live_pages`` of
+    its position over 0), ``n_slots`` where there is none: dead slots
+    are looked past. ``pos_ref`` is anything ``[slot]`` reads a
+    position from (the kernel's SMEM operand, an array in the tests)."""
+    def dead(s):
+        there = jnp.minimum(s, n_slots - 1)
+        return (s < n_slots) & (live_pages(pos_ref[there]) <= 0)
+
+    return jax.lax.while_loop(dead, lambda s: s + 1, b + 1)
+
+
+def chained_first_blocks(positions, page_size: int, max_pages: int,
+                         window: Optional[int] = None) -> Tuple[int, int]:
+    """What one call of the decode kernel does with these ``positions``
+    [slots], on the host (numpy): (slots walked, slots whose first block
+    the slot before them started). A slot is walked where ``_slot_walk``
+    gives it a live page; every walked slot starts the first block of
+    the next one, so all but the first are chained."""
+    _, n_live = _slot_walk(np.asarray(positions), page_size, max_pages,
+                           window, xp=np)
+    walked = int(np.count_nonzero(n_live > 0))
+    return walked, max(walked - 1, 0)
+
+
 def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                         o_ref, k_buf, v_buf, sems, *, scale, page_size,
-                         pages_per_block, max_pages, window=None):
+                         o_ref, k_buf, v_buf, sems, chain, *, scale,
+                         page_size, pages_per_block, max_pages, window=None):
     b = pl.program_id(0)   # slot
+    n_slots = pl.num_programs(0)
     layer = layer_ref[0]
     bk = pages_per_block * page_size
+
+    def walk(pos):
+        """(first page, live pages, blocks that hold them): a dead
+        block costs nothing"""
+        first, n_live = _slot_walk(pos, page_size, max_pages, window)
+        return first, n_live, (n_live + pages_per_block - 1) // pages_per_block
+
     pos = pos_ref[b]
-    # live pages of this slot (0 for a negative position, never past the
-    # table) and the blocks that hold them: a dead block costs nothing
-    n_live = jnp.clip(pos // page_size + 1, 0, max_pages)
-    if window is not None:
-        # a window layer: the walk starts at the page that holds the
-        # window's first key, ``first`` logical pages into the table
-        first = jnp.maximum(pos - window + 1, 0) // page_size
-        n_live = jnp.maximum(n_live - first, 0)
-    n_blocks = (n_live + pages_per_block - 1) // pages_per_block
+    first, n_live, n_blocks = walk(pos)
+    # the slot whose first block this one starts while its own last
+    # block is on its way: the next with a live page (none: n_slots; a
+    # dead slot starts nothing, so its search begins past the last slot)
+    nxt = _next_live_slot(pos_ref, jnp.where(n_blocks > 0, b, n_slots - 1),
+                          n_slots, lambda p: walk(p)[1])
+    nxt_first, nxt_live, _ = walk(pos_ref[jnp.minimum(nxt, n_slots - 1)])
 
-    def block_copies(i, buf):
-        """One async copy per page of block ``i`` and pool: page
-        ``[Hkv, page_size, D]`` (contiguous in HBM) into its rows of
-        landing buffer ``buf``. Pages of the last block past the live
-        length re-read the last live page: what reaches VMEM is always
-        this slot's own data, never TRASH or an unallocated page."""
-        out = []
+    # what a walked slot hands the next one (the grid is sequential):
+    # the landing buffer its walk starts on, and whether the copies of
+    # its first block are already in flight there
+    @pl.when(b == 0)
+    def _cold():
+        chain[0] = 0
+        chain[1] = 0
+    buf0 = chain[0]
+
+    def start_block(slot, first, n_live, i, buf):
+        """Start one async copy per page of block ``i`` of ``slot`` and
+        pool: page ``[Hkv, page_size, D]`` (contiguous in HBM) into its
+        rows of landing buffer ``buf``. Pages of the last block past the
+        live length re-read the last live page: what reaches VMEM is
+        always the slot's own data, never TRASH or an unallocated page."""
         for p in range(pages_per_block):
-            j = jnp.minimum(i * pages_per_block + p, n_live - 1)
-            if window is not None:
-                j += first
-            page = pt_ref[b * max_pages + j]
+            j = first + jnp.minimum(i * pages_per_block + p, n_live - 1)
+            page = pt_ref[slot * max_pages + j]
             rows = pl.ds(p * page_size, page_size)
-            out.append(pltpu.make_async_copy(
+            pltpu.make_async_copy(
                 k_hbm.at[layer, page], k_buf.at[buf, :, rows, :],
-                sems.at[0, buf]))
-            out.append(pltpu.make_async_copy(
+                sems.at[0, buf]).start()
+            pltpu.make_async_copy(
                 v_hbm.at[layer, page], v_buf.at[buf, :, rows, :],
-                sems.at[1, buf]))
-        return out
+                sems.at[1, buf]).start()
 
-    @pl.when(n_blocks > 0)
-    def _first():
-        for c in block_copies(0, 0):
-            c.start()
+    def wait_block(buf):
+        """Wait for the copies of the block in ``buf``, whoever started
+        them (a wait reads its semaphore and the copy's size, never its
+        source: the table is looked up once a page)."""
+        for p in range(pages_per_block):
+            rows = pl.ds(p * page_size, page_size)
+            pltpu.make_async_copy(
+                k_hbm.at[layer, TRASH_PAGE], k_buf.at[buf, :, rows, :],
+                sems.at[0, buf]).wait()
+            pltpu.make_async_copy(
+                v_hbm.at[layer, TRASH_PAGE], v_buf.at[buf, :, rows, :],
+                sems.at[1, buf]).wait()
+
+    @pl.when((n_blocks > 0) & (chain[1] == 0))
+    def _first():   # slot 0, or nobody walked before this slot
+        start_block(b, first, n_live, 0, buf0)
 
     q = q_ref[0]   # [Hkv, n_rep, D]
     hkv, nrep, d = q.shape
@@ -400,15 +475,20 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
     def block(i, carry):
         m_prev, l_prev, acc = carry
-        buf = i % 2
+        buf = (buf0 + i) % 2
+        last = i + 1 == n_blocks
 
-        @pl.when(i + 1 < n_blocks)
+        # the block after this one goes to the other buffer while this
+        # one is waited for and reduced: the slot's own next block, or,
+        # behind its last, block 0 of the next live slot
+        @pl.when(jnp.logical_not(last) | (nxt < n_slots))
         def _next():
-            for c in block_copies(i + 1, 1 - buf):
-                c.start()
+            start_block(jnp.where(last, nxt, b),
+                        jnp.where(last, nxt_first, first),
+                        jnp.where(last, nxt_live, n_live),
+                        jnp.where(last, 0, i + 1), 1 - buf)
 
-        for c in block_copies(i, buf):
-            c.wait()
+        wait_block(buf)
         k = k_buf[buf]   # [Hkv, bk, D]
         v = v_buf[buf]
         s = jax.lax.dot_general(
@@ -441,6 +521,11 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
     ))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
+    @pl.when(n_blocks > 0)
+    def _hand_on():
+        chain[0] = (buf0 + n_blocks) % 2
+        chain[1] = (nxt < n_slots).astype(jnp.int32)
+
 
 def pallas_paged_decode_attention(
     q: jax.Array,
@@ -472,7 +557,8 @@ def pallas_paged_decode_attention(
     scalar-prefetched, and each slot's step copies its live pages, a
     block of ``_pages_per_block`` at a time and all KV heads of a page
     at once, into a double-buffered VMEM landing zone while the block
-    before it is reduced flash-style.
+    before it is reduced flash-style; behind a slot's last block come
+    the copies of the next live slot's first (the module docstring).
     """
     if layer is None:   # one layer's pool is a pool of one layer
         pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
@@ -518,6 +604,7 @@ def pallas_paged_decode_attention(
             pltpu.VMEM((2, hkv, ppb * page_size, d), pool_k.dtype),
             pltpu.VMEM((2, hkv, ppb * page_size, d), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
@@ -527,7 +614,8 @@ def pallas_paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(pltpu.PARALLEL,)),  # slots share no state
+            # sequential: a slot starts the next one's first block
+            dimension_semantics=(pltpu.ARBITRARY,)),
         interpret=interpret,
         name="paged_decode",
     )(page_tables.astype(jnp.int32).reshape(-1),

@@ -29,7 +29,10 @@ from scaletorch_tpu.models import llama, qwen3
 from scaletorch_tpu.models.layers import cached_sdpa_attention, write_kv_cache
 from scaletorch_tpu.ops.pallas.paged_attention import (
     TRASH_PAGE,
+    _next_live_slot,
     _pages_per_block,
+    _slot_walk,
+    chained_first_blocks,
     paged_attention,
     paged_gather_kv,
     paged_write,
@@ -314,6 +317,50 @@ class TestPagedPrimitives:
                 q, pool_k, pool_k, tables, jnp.zeros((self.B,), jnp.int32))
 
 
+def _poisoned_case(hkv, n_rep, d, page_size, max_pages, pos, *, window=None,
+                   shared=None, seed=0):
+    """One slot a position of ``pos`` (-1: a slot with no key) over a
+    float32 pool whose TRASH page and every page no live key sits on are
+    all NaN; a table holds TRASH or such a page wherever the walk does
+    not go (past the live length, and before a ``window``'s first page).
+    ``shared`` (i, j): slot j's first two pages are slot i's. Returns
+    the kernel's inputs and the fallback's answer from the same pool
+    with the NaN zeroed."""
+    rng = np.random.default_rng(seed)
+    pos = np.asarray(pos)
+    b = len(pos)
+    n_live = np.clip(pos // page_size + 1, 0, max_pages)
+    first = np.zeros(b, int) if window is None else \
+        np.maximum(pos - window + 1, 0) // page_size
+    n_pages = b * max_pages + 1
+    tables = rng.permutation(np.arange(1, n_pages)).reshape(b, max_pages)
+    if shared is not None:
+        tables[shared[1], :2] = tables[shared[0], :2]
+    live = np.zeros(n_pages, bool)
+    for row, f, n in zip(tables, first, n_live):
+        live[row[f:n]] = True
+    for row, f, n in zip(tables, first, n_live):
+        off = np.r_[0:f, n:max_pages]
+        row[off] = np.where(rng.random(len(off)) < 0.5, TRASH_PAGE,
+                            rng.choice(np.flatnonzero(~live), len(off)))
+    shape = (n_pages, hkv, page_size, d)
+    pool_k = rng.standard_normal(shape, np.float32)
+    pool_v = rng.standard_normal(shape, np.float32)
+    q = jnp.asarray(rng.standard_normal((b, hkv * n_rep, d), np.float32))
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    want = cached_sdpa_attention(
+        q[:, :, None], paged_gather_kv(jnp.asarray(pool_k), tables),
+        paged_gather_kv(jnp.asarray(pool_v), tables),
+        pos[:, None], window=window)[:, :, 0]
+    # poisoned copies: jnp.asarray may alias the numpy buffer the
+    # oracle above is still reading
+    dead = ~live[:, None, None, None]
+    return (q, jnp.asarray(np.where(dead, np.nan, pool_k)),
+            jnp.asarray(np.where(dead, np.nan, pool_v)), tables, pos), \
+        np.asarray(want)
+
+
 class TestPagedDecodeKernelBlocks:
     """The kernel walks a slot's live pages a block of
     ``_pages_per_block`` at a time (all KV heads of a page in one copy):
@@ -323,46 +370,19 @@ class TestPagedDecodeKernelBlocks:
     HKV = 2
 
     def _case(self, n_rep, d, page_size, even, seed=0):
-        """Six slots, one at each edge of the block walk, over a pool
-        whose TRASH page and unallocated pages are all NaN. Returns the
-        kernel's inputs, the fallback's answer from the same pool with
-        the NaN zeroed, and the pages no live key sits on."""
-        rng = np.random.default_rng(seed)
+        """Six slots, one at each edge of the block walk (slots 4 and 5
+        share their first two pages), over a poisoned pool
+        (``_poisoned_case``). Returns the kernel's inputs, the
+        fallback's answer and the pages of a table past its last whole
+        block."""
         ppb = _pages_per_block(page_size, self.HKV, d, jnp.float32, 10 ** 6)
         max_pages = 2 * ppb if even else 2 * ppb - 3
         bk, top = ppb * page_size, max_pages * page_size - 1
-        pos = np.asarray([0, page_size - 1, page_size, bk - 1, bk, top])
-        n_live = pos // page_size + 1
-        b = len(pos)
-        n_pages = b * max_pages + 1
-        # a random permutation; slots 4 and 5 share their first two pages
-        tables = rng.permutation(np.arange(1, n_pages)).reshape(b, max_pages)
-        tables[5, :2] = tables[4, :2]
-        live = np.zeros(n_pages, bool)
-        for row, n in zip(tables, n_live):
-            live[row[:n]] = True
-        # past the live length a table holds TRASH or an unallocated page
-        for i, n in enumerate(n_live):
-            tables[i, n:] = np.where(
-                rng.random(max_pages - n) < 0.5, TRASH_PAGE,
-                rng.choice(np.flatnonzero(~live), max_pages - n))
-        shape = (n_pages, self.HKV, page_size, d)
-        pool_k = rng.standard_normal(shape, np.float32)
-        pool_v = rng.standard_normal(shape, np.float32)
-        q = jnp.asarray(rng.standard_normal(
-            (b, self.HKV * n_rep, d), np.float32))
-        tables = jnp.asarray(tables, jnp.int32)
-        pos = jnp.asarray(pos, jnp.int32)
-        want = cached_sdpa_attention(
-            q[:, :, None], paged_gather_kv(jnp.asarray(pool_k), tables),
-            paged_gather_kv(jnp.asarray(pool_v), tables),
-            pos[:, None])[:, :, 0]
-        # poisoned copies: jnp.asarray may alias the numpy buffer the
-        # oracle above is still reading
-        dead = ~live[:, None, None, None]
-        return (q, jnp.asarray(np.where(dead, np.nan, pool_k)),
-                jnp.asarray(np.where(dead, np.nan, pool_v)), tables, pos), \
-            np.asarray(want), max_pages % ppb
+        args, want = _poisoned_case(
+            self.HKV, n_rep, d, page_size, max_pages,
+            [0, page_size - 1, page_size, bk - 1, bk, top], shared=(4, 5),
+            seed=seed)
+        return args, want, max_pages % ppb
 
     @pytest.mark.parametrize("even", [True, False],
                              ids=["whole-blocks", "short-last-block"])
@@ -409,6 +429,115 @@ class TestPagedDecodeKernelBlocks:
         out = pallas_paged_decode_attention(
             q, pool_k, pool_v, tables, jnp.full_like(pos, -1), interpret=True)
         assert bool((out == 0).all())
+
+
+def _plain_chain(n_live):
+    """The walk one slot after another, as the kernel makes it: (slots
+    walked, slots that found their first block started, for each slot
+    the slot whose first block it starts or None)."""
+    walked = chained = 0
+    in_flight = False
+    starts = []
+    for b, n in enumerate(n_live):
+        if n <= 0:
+            starts.append(None)
+            continue
+        walked += 1
+        chained += in_flight
+        later = [s for s in range(b + 1, len(n_live)) if n_live[s] > 0]
+        starts.append(later[0] if later else None)
+        in_flight = bool(later)
+    return walked, chained, starts
+
+
+class TestDecodeKernelChain:
+    """The copy pipeline does not stop at a slot's end: behind its last
+    block a slot starts block 0 of the next slot that has a live page,
+    in the other landing buffer, and that slot does not start it again.
+    Parity with the fallback wherever the hand-over can go wrong: block
+    edges, dead slots looked past, the buffer parity after an odd walk,
+    a window's walk from mid-table; every page a walk must not touch is
+    NaN (``_poisoned_case``)."""
+
+    HKV = 2
+    PATTERNS = ["block-edges", "dead-slots", "one-live-slot",
+                "one-block-then-many", "window"]
+
+    @staticmethod
+    def _positions(pattern, bk, page_size):
+        """(positions, window, pages a table holds) of one pattern, in
+        keys ``bk`` a block."""
+        window = None
+        if pattern == "block-edges":    # ends on, short of, past an edge
+            pos = [bk - 1, bk - 2, bk, 2 * bk - 1, 2 * bk - 2, 2 * bk]
+        elif pattern == "dead-slots":   # first, alone between, two, last
+            pos = [-1, bk + 3, -1, 5, -1, -1, 2 * bk, -1]
+        elif pattern == "one-live-slot":
+            pos = [-1, -1, bk + 1, -1]
+        elif pattern == "one-block-then-many":   # 1, 2, 3, 1, 3, 1 blocks
+            pos = [3, 2 * bk - 1, 3 * bk - 5, 5, 2 * bk + 1, 9]
+        else:   # "window": up to two blocks of a walk from mid-table
+            window = bk + page_size + 3
+            pos = [2, window - 1, window, 2 * bk + 5, -1, 3 * bk - 3]
+        return pos, window, 3 * bk // page_size + 1
+
+    @pytest.mark.parametrize("pattern", PATTERNS)
+    @pytest.mark.parametrize("page_size", [8, 16])
+    @pytest.mark.parametrize("d", [128, 256])
+    @pytest.mark.parametrize("n_rep", [1, 2, 8])
+    def test_matches_fallback(self, n_rep, d, page_size, pattern):
+        ppb = _pages_per_block(page_size, self.HKV, d, jnp.float32, 10 ** 6)
+        pos, window, max_pages = self._positions(
+            pattern, ppb * page_size, page_size)
+        args, want = _poisoned_case(self.HKV, n_rep, d, page_size, max_pages,
+                                    pos, window=window)
+        assert bool(jnp.isnan(args[1][TRASH_PAGE]).all())
+        out = np.asarray(pallas_paged_decode_attention(
+            *args, interpret=True, window=window))
+        live = np.asarray(pos) >= 0
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out[live], want[live], atol=5e-6)
+        assert (out[~live] == 0).all()
+
+    @pytest.mark.parametrize("pattern", PATTERNS)
+    def test_every_started_copy_is_waited_for_once(self, pattern):
+        """Under the TPU interpreter a copy happens when it is WAITED
+        for, semaphores count and a buffer never written reads NaN: a
+        first block nobody started would wait for ever, one that landed
+        in the wrong buffer would reduce NaN."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        pos, window, max_pages = self._positions(pattern, 128, 16)
+        args, want = _poisoned_case(self.HKV, 2, 128, 16, max_pages, pos,
+                                    window=window)
+        out = np.asarray(pallas_paged_decode_attention(
+            *args, window=window, interpret=pltpu.InterpretParams(
+                dma_execution_mode="on_wait", uninitialized_memory="nan")))
+        live = np.asarray(pos) >= 0
+        np.testing.assert_allclose(out[live], want[live], atol=5e-6)
+
+    @pytest.mark.parametrize("window", [None, 40], ids=["full", "window"])
+    @pytest.mark.parametrize("pos", [
+        [5, 17, 200, 31], [-1, 5, -1, -1, 40, -1], [-1, -1, -1], [7],
+        [-1, 300, 9999, 0],
+    ], ids=["all-live", "dead-between", "all-dead", "one", "past-table"])
+    def test_the_counter_is_the_kernel_s_rule(self, pos, window):
+        """``chained_first_blocks`` (what the engine counts) against the
+        walk one slot after another, and the kernel's own search for the
+        slot it hands its pipeline to against the same walk."""
+        page_size, max_pages = 8, 32
+        _, n_live = _slot_walk(np.asarray(pos), page_size, max_pages, window,
+                               xp=np)
+        walked, chained, starts = _plain_chain(n_live)
+        assert chained_first_blocks(
+            pos, page_size, max_pages, window) == (walked, chained)
+        pos_ref, n = jnp.asarray(pos, jnp.int32), len(pos)
+        for b, want in enumerate(starts):
+            if n_live[b] > 0:
+                got = int(_next_live_slot(
+                    pos_ref, b, n,
+                    lambda p: _slot_walk(p, page_size, max_pages, window)[1]))
+                assert got == (n if want is None else want)
 
 
 class TestPageWriteInPlace:
@@ -641,6 +770,33 @@ class TestEngineSaysWhichPair:
             params, cfg, sampling=SamplingParams(temperature=0.0),
             max_slots=2, max_seq=16, page_size=4)
         assert engine.metrics.snapshot()["paged_pool_in_place"] == want
+
+    @pytest.mark.parametrize("kernel_reads", [False, True],
+                             ids=["lax-pair", "as-if-the-kernel"])
+    def test_the_snapshot_counts_the_kernel_s_walks(self, kernel_reads):
+        """``paged_slot_walks`` / ``paged_slot_walks_chained``: a decode
+        step hands the kernel every slot's position (an inactive slot's
+        is 0: one page), so each of a layer's calls walks every slot and
+        finds all but the first one's first block started; nothing is
+        counted where the lax pair reads the pool (this CPU)."""
+        from scaletorch_tpu.inference import InferenceEngine, SamplingParams
+
+        cfg = llama.LlamaConfig(**TINY)
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        engine = InferenceEngine(
+            params, cfg, sampling=SamplingParams(temperature=0.0),
+            max_slots=3, max_seq=16, page_size=4)
+        assert engine._kernel_calls_a_step == 0
+        if kernel_reads:
+            engine._kernel_calls_a_step = cfg.num_hidden_layers
+        for prompt in ([1, 2, 3], [4, 5, 6, 7, 8]):
+            engine.submit(prompt, max_new_tokens=5)
+        engine.run()
+        snap = engine.metrics.snapshot()
+        calls = snap["decode_steps"] * cfg.num_hidden_layers * kernel_reads
+        assert snap["decode_steps"] > 0
+        assert snap["paged_slot_walks"] == 3 * calls
+        assert snap["paged_slot_walks_chained"] == 2 * calls
 
 
 class TestPoolBytes:

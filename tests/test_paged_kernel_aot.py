@@ -10,6 +10,7 @@ second file that did would skip wherever two workers may not both hold
 libtpu. Quick tier, ~1 s a kernel case, 2-20 s a step program.
 """
 
+import hashlib
 import json
 import math
 import os
@@ -1244,6 +1245,47 @@ def test_the_latent_kernel_compiles_for_a_longer_table(one_chip):
         arg((2, 8192), jnp.int32), arg((2,), jnp.int32)).compile().as_text()
     calls = _named(_mosaic_calls(text), "latent_decode")
     assert len(calls) == 1, calls
+
+
+def _latent_kernel_digest(slots, heads, max_pages, layers):
+    """sha256 of ``latent_decode``'s traced program (the kernel's jaxpr,
+    its grid and its operands; no source location is in it) at a cell's
+    shape."""
+    from scaletorch_tpu.ops.pallas.paged_attention import (
+        pallas_latent_decode_attention,
+    )
+
+    arg = jax.ShapeDtypeStruct
+    traced = jax.make_jaxpr(lambda q, pool, tables, pos: (
+        pallas_latent_decode_attention(
+            q, pool, tables, pos, layer=jnp.int32(1), value_width=512,
+            scale=192 ** -0.5)))(
+        arg((slots, heads, 640), jnp.bfloat16),
+        arg((layers, slots * max_pages + 1, 1, 16, 640), jnp.bfloat16),
+        arg((slots, max_pages), jnp.int32), arg((slots,), jnp.int32))
+    return hashlib.sha256(str(traced).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("name,slots,heads,max_pages,layers,digest", [
+    ("openpangu-ultra-moe-718b-serve", 8, 128, 216, 6,
+     "957cbaedb8afdabafb1864902737c5cf485dd29ad0ae8e31c19e45b59fc7e713"),
+    ("kimi-linear-48b-a3b-serve", 32, 32, 608, 2,
+     "862da136927d7a76120782d05ab0594387b5e12f8ed4ed9e61655b175d78634b"),
+], ids=["openpangu", "kimi-linear"])
+def test_the_latent_cells_decode_with_the_kernel_they_were_measured_with(
+        one_chip, name, slots, heads, max_pages, layers, digest):
+    """PR 55 changed the dense decode kernel (its walk goes on into the
+    next slot) and left ``latent_decode``, the same shape of loop beside
+    it, alone: both latent cells' decode programs hold no
+    ``paged_decode`` call, and the latent kernel traces to the program
+    it traced to at the parent (`f9961ab`), at each cell's shape. A PR
+    that means to change the latent kernel measures both cells and
+    writes the new digests here."""
+    decode, _, pool_shape = _programs_of(one_chip, name)
+    assert pool_shape == (layers, slots * max_pages + 1, 1, 16, 640)
+    calls = _mosaic_calls(decode.as_text())
+    assert _named(calls, "latent_decode") and not _named(calls, "paged_decode")
+    assert _latent_kernel_digest(slots, heads, max_pages, layers) == digest
 
 
 def test_the_flash_forward_takes_a_value_width_of_its_own(one_chip):
