@@ -151,7 +151,11 @@ from scaletorch_tpu.ops.pallas.paged_attention import (
     in_place_pair,
 )
 from scaletorch_tpu.telemetry.histogram import LogHistogram
-from scaletorch_tpu.telemetry.spans import span
+from scaletorch_tpu.telemetry.spans import (
+    collection_counters,
+    observe_collections,
+    span,
+)
 from scaletorch_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -430,6 +434,9 @@ class EngineMetrics:
         if self.latent_cache_bytes:
             snap["latent_cache_bytes"] = self.latent_cache_bytes
             snap["latent_keys_attended"] = self.latent_keys_attended
+        # what the interpreter's collector has cost this process
+        # (``host_gc_*``: telemetry/spans.py, ``CollectionObserver``)
+        snap.update(collection_counters())
         return snap
 
 
@@ -685,6 +692,9 @@ class InferenceEngine:
         self.monitor = monitor
         self.monitor_every = monitor_every
         self.tracer = tracer
+        # the process's collections are timed from the first engine on,
+        # and a full one is a ``host.gc.full`` span beside the tick's
+        observe_collections(tracer)
         self.exporter = exporter
         self.queue_capacity = queue_capacity
         self.default_ttl_s = default_ttl_s

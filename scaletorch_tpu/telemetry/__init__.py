@@ -10,8 +10,8 @@ One layer shared by the trainer and the inference engine:
                      windows, SIGUSR1 live snapshots
   * ``stragglers`` — per-host step/data-fetch times riding the
                      CoordinatedResilience gather (zero new collectives)
-  * ``export``     — schema-versioned JSONL event stream + optional
-                     Prometheus text endpoint
+  * ``export``     — schema-versioned JSONL event stream + the
+                     Prometheus text rendering the gateway serves
 
 ``Telemetry`` is the per-process facade: built from config (enabled by
 ``--telemetry_dir`` / ``SCALETORCH_TPU_TELEMETRY_DIR``), it owns the
@@ -30,7 +30,6 @@ from typing import Any, Dict, List, Optional
 
 from scaletorch_tpu.telemetry.export import (
     SCHEMA_VERSION,
-    PrometheusEndpoint,
     TelemetryExporter,
     render_families,
     render_prometheus,
@@ -47,16 +46,23 @@ from scaletorch_tpu.telemetry.profiling import (
     SlowStepDetector,
     parse_profile_steps,
 )
-from scaletorch_tpu.telemetry.spans import SpanTracer, load_trace, span
+from scaletorch_tpu.telemetry.spans import (
+    SpanTracer,
+    collection_counters,
+    load_trace,
+    observe_collections,
+    span,
+)
 from scaletorch_tpu.telemetry.stragglers import StragglerDetector
 
 __all__ = [
     "Telemetry",
     "SpanTracer",
     "span",
+    "observe_collections",
+    "collection_counters",
     "load_trace",
     "TelemetryExporter",
-    "PrometheusEndpoint",
     "SCHEMA_VERSION",
     "BucketSchema",
     "DEFAULT_SCHEMA",

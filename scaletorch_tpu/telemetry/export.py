@@ -13,12 +13,9 @@ metrics they can *parse*, not console lines. Two surfaces:
     rest is the flat numeric record. Version policy: additive field
     changes keep ``v``; renames/removals/semantic changes bump it
     (docs/observability.md).
-  * ``PrometheusEndpoint`` — an optional stdlib-only HTTP endpoint
-    serving the text exposition format from a caller-supplied
-    ``metrics_fn`` (e.g. ``engine.metrics.snapshot``), so live
-    occupancy/TTFT is scrapeable without adding dependencies. Bind
-    port 0 for an ephemeral port (tests); the serving front door reads
-    ``endpoint.port`` after ``start()``.
+  * ``render_families`` / ``render_prometheus`` — the Prometheus text
+    exposition format of structured metric families or of a flat
+    numeric dict; the gateway's ``/metrics`` serves the first.
 
 Both are pure host-side I/O — nothing here touches a device value.
 """
@@ -30,10 +27,7 @@ import os
 import re
 import threading
 import time
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import IO, Any, Callable, Dict, Optional
-
-from scaletorch_tpu.utils.logger import get_logger
+from typing import IO, Any, Dict, Optional
 
 # Bump on renames/removals/semantic changes; additive fields keep it.
 SCHEMA_VERSION = 1
@@ -221,83 +215,3 @@ def render_prometheus(metrics: Dict[str, float],
          if not isinstance(metrics[key], bool)
          and isinstance(metrics[key], (int, float))),
         namespace=namespace)
-
-
-class PrometheusEndpoint:
-    """Minimal ``/metrics`` HTTP endpoint over a metrics callback.
-
-    ``metrics_fn`` is called per scrape on the server thread — it must
-    be cheap and sync-free (``EngineMetrics.snapshot`` and
-    ``MetricsLogger.history[-1]`` both qualify). Scrape errors return
-    500 and never propagate into the serving loop."""
-
-    def __init__(
-        self,
-        metrics_fn: Callable[[], Dict[str, float]],
-        *,
-        port: int = 0,
-        host: str = "127.0.0.1",
-        namespace: str = "scaletorch",
-    ) -> None:
-        self.metrics_fn = metrics_fn
-        self.namespace = namespace
-        self._host = host
-        self._requested_port = port
-        self._server: Optional[ThreadingHTTPServer] = None
-        self._thread: Optional[threading.Thread] = None
-        self.port: Optional[int] = None
-
-    def start(self) -> "PrometheusEndpoint":
-        endpoint = self
-
-        class Handler(BaseHTTPRequestHandler):
-            def do_GET(self) -> None:  # noqa: N802 (http.server contract)
-                if self.path.rstrip("/") not in ("", "/metrics"):
-                    self.send_error(404)
-                    return
-                try:
-                    body = render_prometheus(
-                        endpoint.metrics_fn(), namespace=endpoint.namespace
-                    ).encode()
-                except Exception as exc:  # scrape must not kill serving
-                    self.send_error(500, repr(exc))
-                    return
-                self.send_response(200)
-                self.send_header(
-                    "Content-Type", "text/plain; version=0.0.4")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-            def log_message(self, *args) -> None:  # quiet scrapes
-                return
-
-        self._server = ThreadingHTTPServer(
-            (self._host, self._requested_port), Handler)
-        self._server.daemon_threads = True
-        self.port = self._server.server_address[1]
-        self._thread = threading.Thread(
-            target=self._server.serve_forever,
-            name="scaletorch-prometheus", daemon=True,
-        )
-        self._thread.start()
-        get_logger().info(
-            f"prometheus endpoint serving on "
-            f"http://{self._host}:{self.port}/metrics"
-        )
-        return self
-
-    def stop(self) -> None:
-        if self._server is not None:
-            self._server.shutdown()
-            self._server.server_close()
-            self._server = None
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            self._thread = None
-
-    def __enter__(self) -> "PrometheusEndpoint":
-        return self.start()
-
-    def __exit__(self, *exc) -> None:
-        self.stop()

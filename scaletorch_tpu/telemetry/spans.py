@@ -43,6 +43,7 @@ the final timeline even when the trace file is unreachable.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -54,16 +55,21 @@ from typing import IO, Any, Dict, List, Optional
 _trace_annotation = None
 
 
-def _annotation(name: str, args: Dict[str, Any]):
-    """A ``jax.profiler.TraceAnnotation`` (imported on first use: this
-    package stays importable by tooling that runs without jax). The
-    annotation starts when it is built, so build it at the ``with``."""
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported on first use: this
+    package stays importable by tooling that runs without jax."""
     global _trace_annotation
     if _trace_annotation is None:
         from jax.profiler import TraceAnnotation
 
         _trace_annotation = TraceAnnotation
-    return _trace_annotation(name, **args)
+    return _trace_annotation
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    """A profiler annotation. It starts when it is built, so build it
+    at the ``with``."""
+    return _annotation_class()(name, **args)
 
 
 class _BothSinks:
@@ -131,8 +137,7 @@ class SpanTracer:
         loop's existing watchdog beat sites (``step_boundary`` /
         ``data_fetch`` / ``step_dispatch`` / ``checkpoint``) double as
         span boundaries and liveness + tracing share one vocabulary;
-      * ``instant(name)`` / ``counter(name, value)`` — point events and
-        counter tracks (``ph: "i"`` / ``"C"``).
+      * ``counter(name, value)`` — a counter track (``ph: "C"``).
 
     ``path=None`` keeps the tracer memory-only (tail still collected);
     ``enabled=False`` makes every method a single-branch no-op.
@@ -213,16 +218,6 @@ class SpanTracer:
             self._now_us() - self._phase_t0, self._phase_args))
         self._phase_name = None
         self._phase_args = None
-
-    def instant(self, name: str, **args: Any) -> None:
-        if not self.enabled:
-            return
-        self._emit({
-            "name": name, "ph": "i", "s": "p",
-            "ts": self._now_us(),
-            "pid": self.process_index, "tid": threading.get_native_id(),
-            "cat": "host", **({"args": args} if args else {}),
-        })
 
     def counter(self, name: str, value: float) -> None:
         if not self.enabled:
@@ -337,16 +332,21 @@ class SpanTracer:
             if self.events_written >= self.max_events:
                 self.events_dropped += 1
                 return
+            # one write an event, its separator with it: an allocation
+            # below may trip a full collection, whose ``host.gc.full``
+            # event comes through here on this thread (the lock is
+            # reentrant) and must land before or after this one whole
+            text = json.dumps(event)
             if self._file is None:
                 os.makedirs(os.path.dirname(self.path) or ".", exist_ok=True)
+                text = "[\n" + "".join(
+                    json.dumps(meta) + ",\n"
+                    for meta in self._metadata_events()) + text
                 self._file = open(self.path, "w")
-                self._file.write("[\n")
-                for meta in self._metadata_events():
-                    self._file.write(json.dumps(meta) + ",\n")
-            if not self._first_event:
-                self._file.write(",\n")
+            elif not self._first_event:
+                text = ",\n" + text
             self._first_event = False
-            self._file.write(json.dumps(event))
+            self._file.write(text)
             self.events_written += 1
 
     def _metadata_events(self) -> List[dict]:
@@ -364,6 +364,95 @@ class SpanTracer:
                          "clock": "perf_counter_us"},
             },
         ]
+
+
+class CollectionObserver:
+    """What the interpreter's collector costs the process, on
+    ``gc.callbacks``: a collection stops every Python thread behind the
+    interpreter lock, the engine's tick and the gateway's loop alike,
+    and its length follows the heap, not the model. Every collection is
+    counted and timed (``time.monotonic`` at ``start`` and at ``stop``,
+    both on the thread that tripped it); a collection of the oldest
+    generation is also a ``host.gc.full`` span, on the profiler's clock
+    through the annotation ``span()`` enters and as a complete event in
+    every ``SpanTracer`` handed to ``observe_collections``. Young
+    collections (1,000+ a second under load) are summed and never
+    spanned: a compare, two clock reads and two adds."""
+
+    __slots__ = ("collections", "pause_s", "full_collections",
+                 "full_pause_s", "tracers", "_t0", "_open")
+
+    def __init__(self) -> None:
+        self.collections = 0
+        self.pause_s = 0.0
+        self.full_collections = 0
+        self.full_pause_s = 0.0
+        self.tracers: List["SpanTracer"] = []
+        self._t0 = 0.0
+        self._open = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            if info["generation"] == OLDEST_GENERATION:
+                self._open = _annotation("host.gc.full", {})
+                self._open.__enter__()
+            self._t0 = time.monotonic()
+            return
+        if not self._t0:  # put on the list inside a collection
+            return
+        spent = time.monotonic() - self._t0
+        self.collections += 1
+        self.pause_s += spent
+        if info["generation"] == OLDEST_GENERATION:
+            self._full_stop(spent, info)
+
+    def _full_stop(self, spent: float, info: Dict[str, int]) -> None:
+        self.full_collections += 1
+        self.full_pause_s += spent
+        self._open.__exit__(None, None, None)
+        self._open = None
+        dur_us = int(spent * 1e6)
+        for tracer in self.tracers:
+            if tracer.enabled:
+                tracer._emit(tracer._complete_event(
+                    "host.gc.full", tracer._now_us() - dur_us, dur_us,
+                    {"collected": info["collected"]}))
+
+    def counters(self) -> Dict[str, float]:
+        """Cumulative over the process's life, as the engine's snapshot
+        carries them (``host_gc_*``)."""
+        return {
+            "host_gc_collections": self.collections,
+            "host_gc_pause_s": self.pause_s,
+            "host_gc_full_collections": self.full_collections,
+            "host_gc_full_pause_s": self.full_pause_s,
+        }
+
+
+OLDEST_GENERATION = len(gc.get_threshold()) - 1
+_collections = CollectionObserver()
+
+
+def observe_collections(
+        tracer: Optional["SpanTracer"] = None) -> CollectionObserver:
+    """The process's one ``CollectionObserver``, on ``gc.callbacks``
+    from the first call on (a second call registers nothing);
+    ``tracer`` gets the full collections' events from now until it is
+    closed."""
+    if _collections not in gc.callbacks:
+        _annotation_class()  # nothing is imported inside a collection
+        gc.callbacks.append(_collections)
+    _collections.tracers = [t for t in _collections.tracers if t.enabled]
+    if tracer is not None and tracer.enabled \
+            and tracer not in _collections.tracers:
+        _collections.tracers.append(tracer)
+    return _collections
+
+
+def collection_counters() -> Dict[str, float]:
+    """The observer's counters, all 0 until something installs it: a
+    reader (``EngineMetrics.snapshot``) installs nothing."""
+    return _collections.counters()
 
 
 def load_trace(path: str) -> List[dict]:

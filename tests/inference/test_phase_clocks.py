@@ -8,6 +8,7 @@ boundaries are ``engine.tick.*`` spans in any profiler trace, with no
 tracer and no telemetry directory configured.
 """
 
+import contextlib
 import glob
 import os
 import threading
@@ -68,20 +69,50 @@ class Collected:
         self.records.append((kind, record))
 
 
-def test_the_three_clocks_sum_to_the_decode_life(tiny_llama):
+class DeviceClock:
+    """``time.monotonic`` for the engine's thread, injected: a
+    microsecond a reading, and a millisecond more whenever a phase that
+    waits for the device ends (``waited``, in ``span``'s place). Host
+    phases take microseconds and device waits milliseconds, as on a
+    chip, whatever else the machine is doing."""
+
+    def __init__(self, start=5000.0):
+        self.t = start
+
+    def __call__(self):
+        self.t += 1e-6
+        return self.t
+
+    def waited(self, real_span):
+        @contextlib.contextmanager
+        def span(name, *args, **kw):
+            with real_span(name, *args, **kw) as opened:
+                yield opened
+                if name.endswith("_wait"):
+                    self.t += 1e-3
+        return span
+
+
+def test_the_three_clocks_sum_to_the_decode_life(tiny_llama, monkeypatch):
     """Two overlapping requests, the second admitted while the first
     decodes: for each, stall + device wait + host is its first token to
-    its last, and the first stood still for the second's admission."""
-    token_times = {}
+    its last, and the first stood still for the second's admission. On
+    an injected clock: the partition is arithmetic on the engine's own
+    readings, not a comparison of two wall clocks (ROADMAP D24)."""
+    emitted = {}
 
     def on_tokens(slot, request_id, tokens, emitted_t):
-        token_times.setdefault(request_id, []).append(time.monotonic())
+        emitted.setdefault(request_id, []).append(emitted_t)
 
     eng = make_engine(tiny_llama, on_tokens=on_tokens)
     # both steps compiled before anything is timed: a token reaches the
     # hook once the next step is dispatched, and a first dispatch compiles
     eng.submit([9, 9], max_new_tokens=3)
     eng.run()
+    clock = DeviceClock()
+    monkeypatch.setattr(engine_module.time, "monotonic", clock)
+    monkeypatch.setattr(engine_module, "span",
+                        clock.waited(engine_module.span))
     first = eng.submit([1, 2, 3], max_new_tokens=12)
     for _ in range(4):
         eng.step()
@@ -94,19 +125,21 @@ def test_the_three_clocks_sum_to_the_decode_life(tiny_llama):
         assert r.stall_s + r.device_wait_s + r.host_s == pytest.approx(
             life, abs=1e-9)
         assert min(r.stall_s, r.device_wait_s, r.host_s) >= 0
-        assert r.device_wait_s > 0
-        # the engine's token times are the ones the stream saw, to the
-        # host's part of a tick: the hook has a token once the next step
-        # is dispatched, or at once if it was the request's last
-        seen = token_times[rid]
-        assert life == pytest.approx(seen[-1] - seen[0], abs=5e-3)
+        # a wait for the device every decode step of its life
+        assert r.device_wait_s >= 1e-3 * (len(r.tokens) - 1)
+        # the engine's token times are the ones the stream is handed:
+        # the reading a step's tokens are stamped with is the reading
+        # its slots' clocks are closed at
+        seen = emitted[rid]
+        assert len(seen) == len(r.tokens) and seen == sorted(seen)
+        assert life == pytest.approx(seen[-1] - seen[0], abs=1e-9)
     # the first stream stood still while the second was admitted (the
     # admission built, its call dispatched and waited for: what the
     # host spent beside it on the two decode steps is not stall); the
     # second's own admission came before its first token
-    assert results[first].stall_s > 0
-    assert results[second].prefill_s > 0
-    assert results[second].stall_s < results[second].prefill_s
+    assert results[first].stall_s >= 1e-3
+    assert results[second].prefill_s >= 1e-3
+    assert results[second].stall_s < 1e-3 <= results[second].prefill_s
 
 
 def test_a_tick_that_admits_is_partitioned_and_times_its_call(

@@ -1,8 +1,11 @@
-"""A token's way from the engine's readback to its socket write: three
-stamps an event (``emitted_t`` at the engine, ``posted_t`` in
-``ServingGateway._post``, ``written_t`` in ``_write_tokens``), what the
-access record makes of them, and the ``gateway.deliver`` span. A fake
-clock and stub writers: every number below is worked out by hand."""
+"""A token's way from the engine's readback to its socket write: the
+stamps of an event (``emitted_t`` at the engine, ``posted_t`` in
+``ServingGateway._post``, ``drained_t`` / ``slept_s`` / ``place`` in
+``_drain_outbox``, ``written_t`` in ``_write_tokens``), what the access
+record makes of them (the five older fields; the four legs of a lag and
+what they add to the gaps a 95th percentile stands on), and the
+``gateway.deliver`` span. A fake clock and stub writers: every number
+below is worked out by hand."""
 
 import math
 import threading
@@ -21,6 +24,9 @@ from scaletorch_tpu.models import llama
 from scaletorch_tpu.serving import gateway as gateway_mod
 from scaletorch_tpu.serving.gateway import (
     HIST_METRICS,
+    PAUSE_COST_S,
+    PAUSE_SHARE,
+    SHOULDER_FIELDS,
     EngineWorker,
     ServingGateway,
     _delivery_fields,
@@ -113,12 +119,25 @@ def clock(monkeypatch):
     return clock
 
 
-@pytest.fixture
-def gw(tiny_llama):
-    gateway = ServingGateway(make_engine(tiny_llama), port=0,
-                             exporter=Records())
+@pytest.fixture(scope="module")
+def idle_engine(tiny_llama):
+    """One engine for the gateways below: none of them starts its
+    worker, so nothing ever runs on it."""
+    return make_engine(tiny_llama)
+
+
+def gateway_over(engine, **kw):
+    """A gateway whose (unstarted) worker takes the engine's hook over
+    from the gateway of the test before."""
+    engine.on_tokens = None
+    gateway = ServingGateway(engine, port=0, **kw)
     gateway._loop = FakeLoop()
     return gateway
+
+
+@pytest.fixture
+def gw(idle_engine):
+    return gateway_over(idle_engine, exporter=Records())
 
 
 def pending_request():
@@ -226,7 +245,8 @@ def test_the_access_record_carries_the_fields_and_the_histogram(gw, clock):
     pending = play(gw, clock, "outbox", emitted, posted, written)
     gw._record_outcome(pending, "ok", 200)
     (record,) = gw.exporter.access
-    assert {k: record[k] for k in (*FIELDS, "writes_queued")} \
+    assert {k: record[k] for k in (*FIELDS, *SHOULDER_FIELDS,
+                                   "writes_queued")} \
         == _delivery_fields(pending)
     assert record["tokens"] == 21
     assert "deliver_lag" in HIST_METRICS
@@ -243,7 +263,7 @@ def test_under_two_token_events_every_field_is_null(gw, clock, events):
     pending = play(gw, clock, "outbox", emitted[:events], posted[:events],
                    written[:events])
     got = _delivery_fields(pending)
-    assert [got[f] for f in FIELDS] == [None] * 5
+    assert [got[f] for f in (*FIELDS, *SHOULDER_FIELDS)] == [None] * 12
     assert got["writes_queued"] == 0
     gw._record_outcome(pending, "ok", 200)
     assert gw.exporter.access[0]["deliver_lag_p95_s"] is None
@@ -258,6 +278,9 @@ def test_a_worker_on_another_clock_leaves_its_three_fields_null(gw, clock):
     assert got["deliver_held_s_per_token"] is None
     assert got["deliver_lag_p95_s"] is None
     assert got["emit_gap_p95_s"] is None
+    # no emit gap, no shoulder: which gaps hold an admission is the
+    # engine's clock to say
+    assert [got[f] for f in SHOULDER_FIELDS] == [None] * 7
     assert got["deliver_loop_s_per_token"] == pytest.approx(0.016 / 21)
     assert got["write_gap_p95_s"] == pytest.approx(0.0155)
     assert len(pending.emitted_ts) == 0
@@ -285,28 +308,278 @@ def test_writes_queued_counts_the_handler_path_only(gw, clock, stream):
         assert len(writer.frames) == 8  # order kept: one socket
 
 
-def test_one_deliver_span_a_batch_with_its_four_arguments(
-        tiny_llama, clock):
+def test_one_deliver_span_a_batch_with_its_six_arguments(
+        idle_engine, clock, monkeypatch):
     tracer = SpanTracer()
-    gateway = ServingGateway(make_engine(tiny_llama), port=0, tracer=tracer)
-    gateway._loop = FakeLoop()
+    gateway = gateway_over(idle_engine, tracer=tracer)
+    monkeypatch.setattr(gateway_mod.time, "sleep", overshooting(clock))
     streams = [pending_request() for _ in range(4)]
     for p in streams:
         p.stream = FakeWriter()
     streams[3].stream.transport.buffered = 100
     clock.t = 1000.0
-    gateway._streamed_t = clock.t - 0.0031  # a tenth of 3.1 ms: 2 pauses
     gateway._post(streams[0], ("submitted", 0))
     for i, p in enumerate(streams):
         gateway._post(p, ("tokens", (i, [i], clock.t)))
+        clock.t += 0.00001
+    clock.t = 1000.0002  # the loop wakes 0.2 ms after the first post
+    afford(gateway, clock.t, 2)
     gateway._drain_outbox()
     spans = [e for e in tracer.tail() if e["name"] == "gateway.deliver"]
     assert len(spans) == 1 and spans[0]["ph"] == "X"
     # stream 0 queues behind its own 'submitted', stream 3 behind its
-    # socket; 1 and 2 are written, one pause between them of 2 afforded
+    # socket; 1 and 2 are written, one pause between them of 2
+    # afforded, which took 0.4 ms where 0.05 was asked for
     assert spans[0]["args"] == {"events": 5, "writes": 2, "queued": 2,
-                                "pauses": 1}
+                                "pauses": 1, "wake_us": 200,
+                                "slept_us": 400}
     assert spans[0]["tid"] == threading.get_native_id()
+
+
+def test_a_batch_with_no_token_event_waited_for_nothing(idle_engine, clock):
+    tracer = SpanTracer()
+    gateway = gateway_over(idle_engine, tracer=tracer)
+    gateway._post(pending_request(), ("submitted", 0))
+    clock.t += 0.003
+    gateway._drain_outbox()
+    (deliver,) = [e for e in tracer.tail() if e["name"] == "gateway.deliver"]
+    assert deliver["args"] == {"events": 1, "writes": 0, "queued": 0,
+                               "pauses": 0, "wake_us": 0, "slept_us": 0}
+
+
+# -- the four legs of a lag, event by event -----------------------------------
+
+SLEEP_S = 0.0004   # what a pause of 50 us takes in these scripts
+WRITE_S = 0.0002   # what one event's write keeps the loop
+
+
+def overshooting(clock, seconds=SLEEP_S):
+    """``time.sleep`` that takes ``seconds`` whatever it is asked for."""
+    def sleep(asked):
+        clock.t += seconds
+    return sleep
+
+
+def afford(gateway, now, pauses):
+    """The last batch of writes began just long enough ago for the
+    batch that starts ``now`` to afford ``pauses``."""
+    gateway._streamed_t = now - (pauses + 0.5) * PAUSE_COST_S / PAUSE_SHARE
+
+
+class CostlyWriter(FakeWriter):
+    """A write keeps the loop's thread ``WRITE_S``."""
+
+    def __init__(self, clock):
+        super().__init__()
+        self._clock = clock
+
+    def write(self, data):
+        super().write(data)
+        self._clock.t += WRITE_S
+
+
+def legs(pending):
+    """(held, wake, pauses, writes) of every token event."""
+    return [(p - e, d - p, s, w - d - s) for e, p, d, s, w in zip(
+        pending.emitted_ts, pending.posted_ts, pending.drained_ts,
+        pending.slept_ss, pending.written_ts)]
+
+
+def tick(gateway, clock, streams, k, *, emitted, pauses=0, held=0.001,
+         wake=0.0003):
+    """One engine step's tokens for ``streams`` (batch order), read
+    back at ``emitted``, posted ``held`` later, found by the loop
+    ``wake`` after that with ``pauses`` afforded."""
+    clock.t = emitted + held
+    for slot, pending in streams:
+        gateway._post(pending, ("tokens", (slot, [k], emitted)))
+    clock.t += wake
+    afford(gateway, clock.t, pauses)
+    gateway._drain_outbox()
+
+
+@pytest.fixture
+def paced(gw, clock, monkeypatch):
+    """The gateway on a clock that sleeps and writes take time on."""
+    monkeypatch.setattr(gateway_mod.time, "sleep", overshooting(clock))
+
+    def stream():
+        pending = pending_request()
+        pending.stream = CostlyWriter(clock)
+        return pending
+
+    return gw, clock, stream
+
+
+def assert_the_parts_sum(pending, got):
+    for (held, wake, pauses, writes), e, w in zip(
+            legs(pending), pending.emitted_ts, pending.written_ts):
+        assert held + wake + pauses + writes == pytest.approx(
+            w - e, abs=1e-9)
+    parts = sum(got[f"write_shoulder_{name}_s"] for name in (
+        "emit", "held", "wake", "pauses", "writes"))
+    assert parts == pytest.approx(got["write_shoulder_gap_s"], abs=1e-9)
+
+
+@pytest.mark.parametrize("path", ["outbox", "handler"])
+def test_the_four_legs_sum_to_the_lag_and_the_five_parts_to_the_gap(
+        gw, clock, path):
+    """The older script on both write paths: whatever a leg is called
+    where the handler wrote the event, the identity holds."""
+    emitted, posted, written = script((7,))
+    pending = play(gw, clock, path, emitted, posted, written)
+    got = _delivery_fields(pending)
+    assert_the_parts_sum(pending, got)
+    assert list(pending.posted_ts) == pytest.approx(posted, rel=1e-15)
+    if path == "outbox":
+        # a batch of one, drained when it is written: the loop's 0.5 ms
+        # (6 for event 9) is all wake-up
+        assert legs(pending)[9] == pytest.approx(
+            (0.001, 0.006, 0.0, 0.0), abs=1e-9)
+        assert set(pending.places) == {0}
+    else:
+        # one batch queued all 21 when the last was posted: they keep
+        # its stamps, and what they waited after it is the last leg
+        assert set(pending.drained_ts) == {posted[-1]}
+        assert list(pending.places) == [0] * 21
+
+
+def test_a_gap_that_holds_an_admission_is_outside_the_shoulder(gw, clock):
+    """20 gaps: 4.5, 9, fourteen of 10, 11, 13, 15.5 ms and one of
+    500.5 (the admission). The 90th percentile (rank 18) is 13: on or
+    over it and under two median emit gaps stand gap 12 (the tick's
+    own tail: 13 ms emitted, 13 written) and gap 8 (10 ms emitted,
+    15.5 written: event 9 waited 6 ms for the loop where 0.5)."""
+    emitted, posted, written = script((7,))
+    pending = play(gw, clock, "outbox", emitted, posted, written)
+    got = _delivery_fields(pending)
+    assert got["write_shoulder_gap_s"] == pytest.approx(0.01425)
+    assert got["write_shoulder_emit_s"] == pytest.approx(0.0115)
+    assert got["write_shoulder_wake_s"] == pytest.approx(0.00275)
+    assert got["write_shoulder_held_s"] == pytest.approx(0.0, abs=1e-9)
+    assert got["write_shoulder_pauses_s"] == 0.0
+    assert got["write_shoulder_writes_s"] == pytest.approx(0.0, abs=1e-9)
+    assert got["write_shoulder_place_moved_share"] == 0.0
+    # the existing percentile reads the same shoulder from above
+    assert got["write_gap_p95_s"] == pytest.approx(0.0155)
+
+
+def test_where_every_long_gap_holds_an_admission_there_is_no_shoulder(
+        gw, clock):
+    """Three events, an admission in one of two gaps: the 90th
+    percentile is the admission and nothing else reaches it."""
+    pending = play(gw, clock, "outbox", [2000.0, 2000.01, 2000.51],
+                   [2000.001, 2000.011, 2000.511],
+                   [2000.0015, 2000.0115, 2000.5115])
+    got = _delivery_fields(pending)
+    assert [got[f] for f in SHOULDER_FIELDS] == [None] * 7
+    assert got["write_gap_p95_s"] == pytest.approx(0.5)
+
+
+def test_a_pause_count_that_flips_shows_in_the_pauses_leg(paced):
+    """Four streams, twelve ticks of 10 ms, 3 pauses afforded in the
+    even ticks and 1 in the odd: the last stream's event is written
+    behind 1.2 or 0.4 ms of sleep, its write gaps read 10.8 and 9.2,
+    and its shoulder is the five gaps of 10.8."""
+    gw, clock, stream = paced
+    streams = [(slot, stream()) for slot in range(4)]
+    for k in range(12):
+        tick(gw, clock, streams, k, emitted=3000.0 + 0.010 * k,
+             pauses=3 if k % 2 == 0 else 1)
+    last = streams[3][1]
+    assert list(last.slept_ss) == pytest.approx(
+        [3 * SLEEP_S, SLEEP_S] * 6, abs=1e-9)
+    got = _delivery_fields(last)
+    assert got["write_shoulder_gap_s"] == pytest.approx(0.0108)
+    assert got["write_shoulder_emit_s"] == pytest.approx(0.010)
+    assert got["write_shoulder_pauses_s"] == pytest.approx(2 * SLEEP_S)
+    for leg in ("held", "wake", "writes"):
+        assert got[f"write_shoulder_{leg}_s"] == pytest.approx(0.0, abs=1e-9)
+    assert got["write_shoulder_place_moved_share"] == 0.0
+    assert_the_parts_sum(last, got)
+    # the first stream is written before any pause: its gaps are even
+    first = _delivery_fields(streams[0][1])
+    assert first["write_shoulder_pauses_s"] == 0.0
+    assert first["write_shoulder_gap_s"] == pytest.approx(0.010)
+
+
+def test_a_place_that_shifts_shows_in_the_writes_leg(paced):
+    """A stream behind three others, 21 ticks, no pause afforded: the
+    stream ahead of it in slot 0 is away in ticks 3-5, 9-11 and 15-17
+    (retired, then another admitted), so the watched event is written
+    behind three writes or two. Its 20 gaps: three of 9.8 ms (it moved
+    up), fourteen of 10, three of 10.2 (it moved back): rank 18 is
+    10.2, and the shoulder is the three gaps in which it moved back."""
+    gw, clock, stream = paced
+    watched = stream()
+    others = [(slot, stream()) for slot in range(3)]
+    for k in range(21):
+        ahead = others[1:] if (k // 3) % 2 else others
+        tick(gw, clock, [*ahead, (3, watched)], k,
+             emitted=3000.0 + 0.010 * k)
+    assert list(watched.places) == ([3] * 3 + [2] * 3) * 3 + [3] * 3
+    assert [writes for *_, writes in legs(watched)] == pytest.approx(
+        [WRITE_S * place for place in watched.places], abs=1e-9)
+    got = _delivery_fields(watched)
+    assert got["write_shoulder_gap_s"] == pytest.approx(0.0102)
+    assert got["write_shoulder_writes_s"] == pytest.approx(WRITE_S)
+    assert got["write_shoulder_place_moved_share"] == 1.0
+    for leg in ("held", "wake", "pauses"):
+        assert got[f"write_shoulder_{leg}_s"] == pytest.approx(0.0, abs=1e-9)
+    assert_the_parts_sum(watched, got)
+
+
+def test_slept_is_the_measured_sleep_not_the_budgets(paced):
+    """A pause asks for 50 us and is budgeted at 150; this clock's
+    takes 400: the third stream's event is written behind 800 us of
+    sleep, and the second's write (200 us) is not in it."""
+    gw, clock, stream = paced
+    streams = [(slot, stream()) for slot in range(3)]
+    tick(gw, clock, streams, 0, emitted=3000.0, pauses=2)
+    assert [list(p.slept_ss) for _, p in streams] == [
+        pytest.approx([k * SLEEP_S], abs=1e-9) for k in range(3)]
+    held, wake, pauses, writes = legs(streams[2][1])[0]
+    assert pauses == pytest.approx(0.0008, abs=1e-9)
+    assert writes == pytest.approx(2 * WRITE_S, abs=1e-9)
+    assert (held, wake) == pytest.approx((0.001, 0.0003), abs=1e-9)
+    # a queued event keeps the batch's stamps as they stood when it
+    # was passed on: its socket held bytes
+    late = stream()
+    late.stream.transport.buffered = 100
+    tick(gw, clock, [*streams, (3, late)], 1, emitted=3000.01, pauses=2)
+    (kind, payload), = [late.chan.get_nowait()]
+    assert kind == "tokens"
+    assert payload[4:] == (pytest.approx(3000.0113),
+                           pytest.approx(2 * SLEEP_S), 3)
+
+
+def test_the_stamps_cost_microseconds_an_event(gw):
+    """With the profiler off a token event's four more stamps are four
+    array appends, and a batch's pauses two clock reads each: a batch
+    of 16 events through ``_drain_outbox`` (no pause afforded, stub
+    writers, the real clock) stays under 20 us an event in the best of
+    seven rounds, 0.3 % of the shortest tick the ledger shows (6.75
+    ms); what the stamps add to it is under a microsecond. An absolute
+    ceiling: no second clock taken under load to compare with."""
+    import timeit
+
+    streams = [pending_request() for _ in range(16)]
+    for p in streams:
+        p.stream = FakeWriter()
+
+    def one_tick():
+        now = time.monotonic()
+        for slot, p in enumerate(streams):
+            gw._post(p, ("tokens", (slot, [1], now)))
+        gw._streamed_t = now  # affords no pause
+        gw._drain_outbox()
+        for p in streams:
+            p.stream.frames.clear()
+
+    one_tick()
+    per_event = min(timeit.repeat(one_tick, number=50, repeat=7)) / 50 / 16
+    assert per_event < 20e-6, f"{per_event * 1e6:.1f} us a token event"
+    assert len(streams[0].places) == len(streams[0].written_ts) == 351
 
 
 # -- the hook's new argument, worker by worker -------------------------------

@@ -21,14 +21,14 @@ annotation and a Chrome event too under a tracer; the overhead contract
 is the measured cost of a tick's whole span-and-clock set.
 """
 
+import gc
 import glob
 import json
 import logging
 import os
 import signal
+import threading
 import time
-import urllib.error
-import urllib.request
 
 import numpy as np
 import pytest
@@ -37,13 +37,14 @@ from scaletorch_tpu.telemetry import (
     SCHEMA_VERSION,
     AnomalyProfiler,
     LiveSnapshotter,
-    PrometheusEndpoint,
     SlowStepDetector,
     SpanTracer,
     StragglerDetector,
     Telemetry,
     TelemetryExporter,
+    collection_counters,
     load_trace,
+    observe_collections,
     parse_profile_steps,
     span,
 )
@@ -76,7 +77,6 @@ class TestSpanTracer:
         tr = SpanTracer(path, process_index=3)
         with tr.span("data_fetch", step=1):
             pass
-        tr.instant("note", detail="x")
         tr.counter("straggler_flags", 2)
         tr.close()
         events = json.load(open(path))  # valid JSON after close()
@@ -87,7 +87,6 @@ class TestSpanTracer:
         assert span["ph"] == "X" and span["dur"] >= 0
         assert span["pid"] == 3 and "tid" in span and "ts" in span
         assert span["args"] == {"step": 1}
-        assert by_name["note"]["ph"] == "i"
         assert by_name["straggler_flags"]["ph"] == "C"
         assert by_name["straggler_flags"]["args"]["value"] == 2
         assert by_name["process_name"]["ph"] == "M"
@@ -142,7 +141,7 @@ class TestSpanTracer:
         # reads tail() — which must not deadlock when the signal landed
         # while that same thread held the lock inside _emit.
         tr = SpanTracer(path=None)
-        tr.instant("x")
+        tr.counter("x", 1)
         with tr._lock:  # simulate: handler fires mid-_emit
             assert tr._lock.acquire(blocking=False), (
                 "tracer lock must be reentrant (SIGUSR1 handler reads "
@@ -163,7 +162,6 @@ class TestSpanTracer:
         with tr.span("x"):
             pass
         tr.phase("a")
-        tr.instant("b")
         tr.counter("c", 1)
         assert tr.tail() == []
 
@@ -246,24 +244,207 @@ class TestExport:
         assert "skip-me" not in body              # non-numeric skipped
         assert body.endswith("\n")
 
-    def test_prometheus_endpoint_serves_metrics(self):
-        with PrometheusEndpoint(lambda: {"queue_depth": 3}) as pe:
-            url = f"http://127.0.0.1:{pe.port}/metrics"
-            body = urllib.request.urlopen(url).read().decode()
-            assert "scaletorch_queue_depth 3.0" in body
-            with pytest.raises(urllib.error.HTTPError):
-                urllib.request.urlopen(
-                    f"http://127.0.0.1:{pe.port}/other")
 
-    def test_prometheus_scrape_error_returns_500(self):
-        def broken():
-            raise RuntimeError("boom")
+# ---------------------------------------------------------------------------
+# The interpreter's collections (telemetry/spans.py, CollectionObserver)
+# ---------------------------------------------------------------------------
 
-        with PrometheusEndpoint(broken) as pe:
-            with pytest.raises(urllib.error.HTTPError) as exc_info:
-                urllib.request.urlopen(
-                    f"http://127.0.0.1:{pe.port}/metrics")
-            assert exc_info.value.code == 500
+
+class StubAnnotation:
+    """In ``_annotation``'s place: what was opened and closed."""
+
+    def __init__(self, log, name, args):
+        self._log, self._name = log, name
+        assert not args
+
+    def __enter__(self):
+        self._log.append(("enter", self._name))
+        return self
+
+    def __exit__(self, *exc):
+        self._log.append(("exit", self._name))
+
+
+@pytest.fixture
+def collections(monkeypatch):
+    """The process's observer with the collector held still around it
+    (only a forced collection runs), a memory-only tracer on its list
+    and a stub in the profiler annotation's place."""
+    from scaletorch_tpu.telemetry import spans as spans_mod
+
+    log = []
+    monkeypatch.setattr(
+        spans_mod, "_annotation",
+        lambda name, args: StubAnnotation(log, name, args))
+    tracer = SpanTracer()
+    observer = observe_collections(tracer)
+    gc.collect()  # what the set-up left behind is not the test's
+    log.clear()
+    gc.disable()
+    try:
+        yield observer, tracer, log
+    finally:
+        gc.enable()
+        tracer.close()
+
+
+def full_events(tracer):
+    return [e for e in tracer.tail() if e["name"] == "host.gc.full"]
+
+
+class TestCollectionObserver:
+    def test_installing_twice_registers_one_callback(self):
+        tracer = SpanTracer()
+        first = observe_collections(tracer)
+        assert observe_collections(tracer) is first
+        assert observe_collections() is first
+        assert gc.callbacks.count(first) == 1
+        assert first.tracers.count(tracer) == 1
+        # a closed tracer leaves the list at the next call
+        tracer.close()
+        observe_collections()
+        assert tracer not in first.tracers
+
+    def test_a_forced_full_collection_is_counted_timed_and_spanned(
+            self, collections):
+        observer, tracer, log = collections
+        before, already = collection_counters(), len(full_events(tracer))
+        t0 = time.monotonic()
+        gc.collect()
+        wall = time.monotonic() - t0
+        after = collection_counters()
+        assert after["host_gc_full_collections"] \
+            == before["host_gc_full_collections"] + 1
+        assert after["host_gc_collections"] \
+            == before["host_gc_collections"] + 1
+        pause = after["host_gc_full_pause_s"] - before["host_gc_full_pause_s"]
+        assert 0 < pause <= wall
+        # all generations' pause holds the full one's
+        assert after["host_gc_pause_s"] - before["host_gc_pause_s"] \
+            == pytest.approx(pause, abs=1e-12)
+        # exactly one span, entered at start and left at stop, in the
+        # profiler's sink and in the tracer's
+        assert log == [("enter", "host.gc.full"), ("exit", "host.gc.full")]
+        (event,) = full_events(tracer)[already:]
+        assert event["ph"] == "X" and event["cat"] == "host"
+        assert event["dur"] == int(pause * 1e6)
+        assert set(event["args"]) == {"collected"}
+        assert event["tid"] == threading.get_native_id()
+
+    @pytest.mark.parametrize("generation", [0, 1])
+    def test_a_young_collection_is_summed_and_opens_no_span(
+            self, collections, generation):
+        observer, tracer, log = collections
+        before, already = collection_counters(), len(full_events(tracer))
+        gc.collect(generation)
+        after = collection_counters()
+        assert after["host_gc_collections"] \
+            == before["host_gc_collections"] + 1
+        assert after["host_gc_pause_s"] > before["host_gc_pause_s"]
+        assert after["host_gc_full_collections"] \
+            == before["host_gc_full_collections"]
+        assert after["host_gc_full_pause_s"] == before["host_gc_full_pause_s"]
+        assert log == [] and len(full_events(tracer)) == already
+
+    def test_a_disabled_tracer_gets_no_event(self, collections):
+        observer, tracer, log = collections
+        off = SpanTracer(enabled=False)
+        observe_collections(off)
+        assert off not in observer.tracers
+        gc.collect()
+        assert off.tail() == [] and len(full_events(tracer)) >= 1
+
+    def test_a_young_collections_callbacks_cost_a_few_microseconds_at_most(
+            self):
+        """A compare, two clock reads and two adds: the best of seven
+        rounds against a ceiling in absolute time (0.4 us on an idle
+        core; 1,000 a second is then 0.04 % of a thread)."""
+        import timeit
+
+        observer = observe_collections()
+        info = {"generation": 0, "collected": 0, "uncollectable": 0}
+
+        def one_collection():
+            observer("start", info)
+            observer("stop", info)
+
+        was = gc.isenabled()
+        gc.disable()
+        try:
+            before = collection_counters()
+            per = min(timeit.repeat(
+                one_collection, number=5_000, repeat=7)) / 5_000
+            after = collection_counters()
+        finally:
+            if was:
+                gc.enable()
+        assert per < 5e-6, f"{per * 1e6:.2f} us a young collection"
+        assert after["host_gc_collections"] \
+            == before["host_gc_collections"] + 35_000
+        assert after["host_gc_full_collections"] \
+            == before["host_gc_full_collections"]
+
+    def test_a_tracers_event_is_one_write_with_its_separator(self, tmp_path):
+        """A full collection tripped inside ``_emit`` sends its own
+        event through ``_emit`` on the same thread: each event reaches
+        the file in one write, so the array stays an array."""
+        path = str(tmp_path / "t.trace.json")
+        tracer = SpanTracer(path)
+        writes = []
+
+        class Recording:
+            def __init__(self, real):
+                self._real = real
+
+            def write(self, text):
+                writes.append(text)
+                return self._real.write(text)
+
+            def __getattr__(self, name):
+                return getattr(self._real, name)
+
+        tracer.counter("a", 1)
+        tracer._file = Recording(tracer._file)
+        tracer.counter("b", 2)
+        tracer.counter("c", 3)
+        assert len(writes) == 2 and all(w.startswith(",\n{") for w in writes)
+        tracer.close()
+        assert [e["name"] for e in load_trace(path)
+                if e["ph"] == "C"] == ["a", "b", "c"]
+
+    def test_the_engine_installs_it_and_its_snapshot_carries_the_four(self):
+        import jax
+        import jax.numpy as jnp
+
+        from scaletorch_tpu.inference import InferenceEngine, SamplingParams
+        from scaletorch_tpu.inference.engine import EngineMetrics
+        from scaletorch_tpu.models import llama
+
+        names = {"host_gc_collections", "host_gc_pause_s",
+                 "host_gc_full_collections", "host_gc_full_pause_s"}
+        assert names <= set(EngineMetrics().snapshot())
+        cfg = llama.LlamaConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, dtype=jnp.float32,
+        )
+        tracer = SpanTracer()
+        eng = InferenceEngine(
+            llama.init_params(jax.random.PRNGKey(0), cfg), cfg,
+            max_slots=2, max_seq=16, prefill_len=8, page_size=4,
+            sampling=SamplingParams(temperature=0.0), tracer=tracer)
+        observer = observe_collections()
+        assert gc.callbacks.count(observer) == 1
+        assert tracer in observer.tracers
+        before = eng.metrics.snapshot()
+        gc.collect()
+        after = eng.metrics.snapshot()
+        assert after["host_gc_full_collections"] \
+            >= before["host_gc_full_collections"] + 1
+        assert after["host_gc_full_pause_s"] > before["host_gc_full_pause_s"]
+        assert all(isinstance(after[name], (int, float)) for name in names)
+        assert full_events(tracer)
+        tracer.close()
 
 
 # ---------------------------------------------------------------------------
@@ -892,13 +1073,17 @@ class TestEndToEndTelemetry:
         assert per_tick < 0.01 * 8.9e-3, f"{per_tick * 1e6:.1f} us per tick"
         assert eng.metrics.slow_ticks == 0
 
-    def test_disabled_overhead_within_noise(self, tmp_path):
-        """Telemetry off: the instrumented loop's per-step telemetry
-        work is microsecond-scale (vs millisecond-scale steps), and
-        the full train() loop stays within a loose factor of driving
-        the bare step function directly."""
+    def test_disabled_overhead_within_noise(self, tmp_path, monkeypatch):
+        """Telemetry off: a step's telemetry work is two inactive
+        profiler annotations and a few branches. Held by counts taken
+        in this process (no event built, no tracer lock taken, nothing
+        exported over a real ``train()``) and by the hooks' best time
+        against a ceiling in absolute time: a toy step timed beside
+        five other workers is no yardstick (ROADMAP D24)."""
         # (a) the per-step hook cost when disabled: branches, and the
-        # two profiler annotations of Trainer.step
+        # two profiler annotations of Trainer.step. About 1 us on an
+        # idle core; the ceiling is 25 us, under 0.01 % of the
+        # shortest training step the ledger shows (0.67 s)
         tel = Telemetry.disabled()
         coordinator_counters = {}
 
@@ -915,29 +1100,40 @@ class TestEndToEndTelemetry:
 
         import timeit
 
-        per_call = timeit.timeit(per_step_hooks, number=20_000) / 20_000
-        assert per_call < 5e-6  # noise against a >= ms CPU toy step
+        per_call = min(timeit.repeat(
+            per_step_hooks, number=2_000, repeat=9)) / 2_000
+        assert per_call < 25e-6, f"{per_call * 1e6:.2f} us a step's hooks"
 
-        # (b) relate the hook cost to the real step: the disabled-path
-        # telemetry work must be < 5% of one measured toy step. (A full
-        # loop-vs-loop wall-clock comparison would be dominated by the
-        # loader / coordinator / metrics costs the loop pays with or
-        # without this PR — the marginal telemetry cost is the hooks.)
+        # (b) the real loop with telemetry off: every span site is the
+        # annotation alone (counted here), and the sinks a tracer or an
+        # exporter would feed are never reached
+        from scaletorch_tpu.telemetry import spans as spans_mod
+
+        calls = {"annotation": 0, "event": 0, "emit": 0, "export": 0}
+        real_annotation = spans_mod._annotation
+
+        def counting_annotation(name, args):
+            calls["annotation"] += 1
+            assert not args, f"{name}: arguments build a string a step"
+            return real_annotation(name, args)
+
+        def counted(key):
+            def hit(*args, **kwargs):
+                calls[key] += 1
+            return hit
+
+        monkeypatch.setattr(spans_mod, "_annotation", counting_annotation)
+        monkeypatch.setattr(spans_mod._Span, "__init__", counted("event"))
+        monkeypatch.setattr(SpanTracer, "_emit", counted("emit"))
+        monkeypatch.setattr(TelemetryExporter, "emit", counted("export"))
         cfg = e2e_cfg(None, total_train_steps=40, log_frequency=10_000,
                       sentinel_frequency=0, handle_preemption=False)
         t = TelemetryToyTrainer(cfg, e2e_tokens(128))
         assert not t.telemetry.enabled
-        t.train(num_steps=8)  # warm the jit cache; the loop runs clean
-        batch = next(iter(t.loader))
-        for _ in range(4):  # warm
-            t.step_fn(t.params, t.opt_state, batch)
-        t0 = time.perf_counter()
-        for _ in range(16):
-            t.params, t.opt_state, _ = t.step_fn(
-                t.params, t.opt_state, batch)
-        bare = (time.perf_counter() - t0) / 16
+        assert t.telemetry.tracer is None and t.telemetry.exporter is None
+        t.train(num_steps=8)
         t.close()
-        assert per_call < 0.05 * bare, (
-            f"disabled telemetry hooks cost {per_call * 1e6:.2f}us/step "
-            f"vs a {bare * 1e3:.3f}ms bare step (>= 5%)"
-        )
+        assert calls["event"] == calls["emit"] == calls["export"] == 0
+        # two annotations a step (place, dispatch) and nothing that
+        # grows faster than the steps do
+        assert 16 <= calls["annotation"] <= 16 + 8, calls
