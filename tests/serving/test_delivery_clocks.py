@@ -24,8 +24,6 @@ from scaletorch_tpu.models import llama
 from scaletorch_tpu.serving import gateway as gateway_mod
 from scaletorch_tpu.serving.gateway import (
     HIST_METRICS,
-    PAUSE_COST_S,
-    PAUSE_SHARE,
     SHOULDER_FIELDS,
     EngineWorker,
     ServingGateway,
@@ -37,6 +35,7 @@ from scaletorch_tpu.serving.remote import RemoteEngineWorker
 from scaletorch_tpu.telemetry.spans import SpanTracer
 
 from .fake_replica import FakeEngineWorker
+from .test_gateway_outbox import slept
 from .test_remote import ServerThread, make_req, run_request
 
 TINY = dict(
@@ -115,7 +114,7 @@ class Records:
 def clock(monkeypatch):
     clock = FakeClock()
     monkeypatch.setattr(gateway_mod.time, "monotonic", clock)
-    monkeypatch.setattr(gateway_mod.time, "sleep", lambda s: None)
+    monkeypatch.setattr(gateway_mod.time, "sleep", slept)
     return clock
 
 
@@ -308,11 +307,14 @@ def test_writes_queued_counts_the_handler_path_only(gw, clock, stream):
         assert len(writer.frames) == 8  # order kept: one socket
 
 
+def deliver_spans(tracer):
+    return [e for e in tracer.tail() if e["name"] == "gateway.deliver"]
+
+
 def test_one_deliver_span_a_batch_with_its_six_arguments(
-        idle_engine, clock, monkeypatch):
+        idle_engine, clock):
     tracer = SpanTracer()
     gateway = gateway_over(idle_engine, tracer=tracer)
-    monkeypatch.setattr(gateway_mod.time, "sleep", overshooting(clock))
     streams = [pending_request() for _ in range(4)]
     for p in streams:
         p.stream = FakeWriter()
@@ -323,17 +325,34 @@ def test_one_deliver_span_a_batch_with_its_six_arguments(
         gateway._post(p, ("tokens", (i, [i], clock.t)))
         clock.t += 0.00001
     clock.t = 1000.0002  # the loop wakes 0.2 ms after the first post
-    afford(gateway, clock.t, 2)
     gateway._drain_outbox()
-    spans = [e for e in tracer.tail() if e["name"] == "gateway.deliver"]
+    spans = deliver_spans(tracer)
     assert len(spans) == 1 and spans[0]["ph"] == "X"
     # stream 0 queues behind its own 'submitted', stream 3 behind its
-    # socket; 1 and 2 are written, one pause between them of 2
-    # afforded, which took 0.4 ms where 0.05 was asked for
+    # socket; 1 and 2 are written back to back: the two arguments that
+    # counted the write path's sleeps say there was none
     assert spans[0]["args"] == {"events": 5, "writes": 2, "queued": 2,
-                                "pauses": 1, "wake_us": 200,
-                                "slept_us": 400}
+                                "pauses": 0, "wake_us": 200,
+                                "slept_us": 0}
     assert spans[0]["tid"] == threading.get_native_id()
+
+
+@pytest.mark.parametrize("streams", [16, 32])
+def test_a_full_batchs_deliver_span_counts_no_pause(idle_engine, clock,
+                                                    streams):
+    """Two ticks 33 ms apart: a cadence at which every boundary between
+    two writes was afforded a pause while the write path had any."""
+    tracer = SpanTracer()
+    gateway = gateway_over(idle_engine, tracer=tracer)
+    for k in range(2):
+        for slot in range(streams):
+            pending = pending_request()
+            pending.stream = FakeWriter()
+            gateway._post(pending, ("tokens", (slot, [k], clock.t)))
+        clock.t += 0.033
+        gateway._drain_outbox()
+    assert [(e["args"]["writes"], e["args"]["pauses"], e["args"]["slept_us"])
+            for e in deliver_spans(tracer)] == [(streams, 0, 0)] * 2
 
 
 def test_a_batch_with_no_token_event_waited_for_nothing(idle_engine, clock):
@@ -342,28 +361,14 @@ def test_a_batch_with_no_token_event_waited_for_nothing(idle_engine, clock):
     gateway._post(pending_request(), ("submitted", 0))
     clock.t += 0.003
     gateway._drain_outbox()
-    (deliver,) = [e for e in tracer.tail() if e["name"] == "gateway.deliver"]
+    (deliver,) = deliver_spans(tracer)
     assert deliver["args"] == {"events": 1, "writes": 0, "queued": 0,
                                "pauses": 0, "wake_us": 0, "slept_us": 0}
 
 
 # -- the four legs of a lag, event by event -----------------------------------
 
-SLEEP_S = 0.0004   # what a pause of 50 us takes in these scripts
 WRITE_S = 0.0002   # what one event's write keeps the loop
-
-
-def overshooting(clock, seconds=SLEEP_S):
-    """``time.sleep`` that takes ``seconds`` whatever it is asked for."""
-    def sleep(asked):
-        clock.t += seconds
-    return sleep
-
-
-def afford(gateway, now, pauses):
-    """The last batch of writes began just long enough ago for the
-    batch that starts ``now`` to afford ``pauses``."""
-    gateway._streamed_t = now - (pauses + 0.5) * PAUSE_COST_S / PAUSE_SHARE
 
 
 class CostlyWriter(FakeWriter):
@@ -385,24 +390,21 @@ def legs(pending):
         pending.slept_ss, pending.written_ts)]
 
 
-def tick(gateway, clock, streams, k, *, emitted, pauses=0, held=0.001,
-         wake=0.0003):
+def tick(gateway, clock, streams, k, *, emitted, held=0.001, wake=0.0003):
     """One engine step's tokens for ``streams`` (batch order), read
     back at ``emitted``, posted ``held`` later, found by the loop
-    ``wake`` after that with ``pauses`` afforded."""
+    ``wake`` after that."""
     clock.t = emitted + held
     for slot, pending in streams:
         gateway._post(pending, ("tokens", (slot, [k], emitted)))
     clock.t += wake
-    afford(gateway, clock.t, pauses)
     gateway._drain_outbox()
 
 
 @pytest.fixture
-def paced(gw, clock, monkeypatch):
-    """The gateway on a clock that sleeps and writes take time on."""
-    monkeypatch.setattr(gateway_mod.time, "sleep", overshooting(clock))
-
+def paced(gw, clock):
+    """The gateway on a clock that writes take time on (a sleep, as
+    under every fake clock of this file, is a fault)."""
     def stream():
         pending = pending_request()
         pending.stream = CostlyWriter(clock)
@@ -476,37 +478,56 @@ def test_where_every_long_gap_holds_an_admission_there_is_no_shoulder(
     assert got["write_gap_p95_s"] == pytest.approx(0.5)
 
 
-def test_a_pause_count_that_flips_shows_in_the_pauses_leg(paced):
-    """Four streams, twelve ticks of 10 ms, 3 pauses afforded in the
-    even ticks and 1 in the odd: the last stream's event is written
-    behind 1.2 or 0.4 ms of sleep, its write gaps read 10.8 and 9.2,
-    and its shoulder is the five gaps of 10.8."""
+@pytest.mark.parametrize("streams", [4, 16, 32])
+def test_the_five_parts_sum_with_a_pauses_leg_of_zero(paced, streams):
+    """Twelve ticks, six 10 ms apart and six 33, the loop's wake-up 0.3
+    or 0.5 ms by the tick: every event's ``slept_s`` is 0.0, the last
+    stream's event is written behind ``streams - 1`` writes and
+    nothing else, and the five parts still sum to the gap with a
+    ``pauses`` part of exactly 0.0."""
     gw, clock, stream = paced
-    streams = [(slot, stream()) for slot in range(4)]
+    batch = [(slot, stream()) for slot in range(streams)]
+    emitted = 3000.0
     for k in range(12):
-        tick(gw, clock, streams, k, emitted=3000.0 + 0.010 * k,
-             pauses=3 if k % 2 == 0 else 1)
-    last = streams[3][1]
-    assert list(last.slept_ss) == pytest.approx(
-        [3 * SLEEP_S, SLEEP_S] * 6, abs=1e-9)
+        emitted += 0.010 if k < 6 else 0.033
+        tick(gw, clock, batch, k, emitted=emitted,
+             wake=0.0005 if k % 2 else 0.0003)
+    for slot, pending in batch:
+        assert list(pending.slept_ss) == [0.0] * 12
+        assert list(pending.places) == [slot] * 12
+    last = batch[-1][1]
+    assert [writes for *_, writes in legs(last)] == pytest.approx(
+        [WRITE_S * (streams - 1)] * 12, abs=1e-9)
     got = _delivery_fields(last)
-    assert got["write_shoulder_gap_s"] == pytest.approx(0.0108)
-    assert got["write_shoulder_emit_s"] == pytest.approx(0.010)
-    assert got["write_shoulder_pauses_s"] == pytest.approx(2 * SLEEP_S)
-    for leg in ("held", "wake", "writes"):
-        assert got[f"write_shoulder_{leg}_s"] == pytest.approx(0.0, abs=1e-9)
+    assert got["write_shoulder_pauses_s"] == 0.0
+    assert got["write_shoulder_writes_s"] == pytest.approx(0.0, abs=1e-9)
     assert got["write_shoulder_place_moved_share"] == 0.0
     assert_the_parts_sum(last, got)
-    # the first stream is written before any pause: its gaps are even
-    first = _delivery_fields(streams[0][1])
-    assert first["write_shoulder_pauses_s"] == 0.0
-    assert first["write_shoulder_gap_s"] == pytest.approx(0.010)
+
+
+def test_the_access_record_keeps_a_pauses_field_that_reads_zero(paced):
+    """``benchmarks/metrics/serve_write_shoulder_pauses_ms.json`` reads
+    the field: missing or null it would leave the metric off the
+    line, so it stays, a float, 0.0."""
+    gw, clock, stream = paced
+    batch = [(slot, stream()) for slot in range(4)]
+    for k in range(12):
+        tick(gw, clock, batch, k, emitted=3000.0 + 0.010 * k,
+             wake=0.0006 if k % 3 == 0 else 0.0003)
+    gw._record_outcome(batch[3][1], "ok", 200)
+    (record,) = gw.exporter.access
+    assert record["write_shoulder_pauses_s"] == 0.0
+    assert isinstance(record["write_shoulder_pauses_s"], float)
+    # the shoulder is the four gaps into a slow wake-up: 10.3 ms
+    assert record["write_shoulder_gap_s"] == pytest.approx(0.0103)
+    assert record["write_shoulder_wake_s"] == pytest.approx(0.0003)
+    assert all(record[f] is not None for f in SHOULDER_FIELDS)
 
 
 def test_a_place_that_shifts_shows_in_the_writes_leg(paced):
-    """A stream behind three others, 21 ticks, no pause afforded: the
-    stream ahead of it in slot 0 is away in ticks 3-5, 9-11 and 15-17
-    (retired, then another admitted), so the watched event is written
+    """A stream behind three others, 21 ticks: the stream ahead of it
+    in slot 0 is away in ticks 3-5, 9-11 and 15-17 (retired, then
+    another admitted), so the watched event is written
     behind three writes or two. Its 20 gaps: three of 9.8 ms (it moved
     up), fourteen of 10, three of 10.2 (it moved back): rank 18 is
     10.2, and the shoulder is the three gaps in which it moved back."""
@@ -529,41 +550,51 @@ def test_a_place_that_shifts_shows_in_the_writes_leg(paced):
     assert_the_parts_sum(watched, got)
 
 
-def test_slept_is_the_measured_sleep_not_the_budgets(paced):
-    """A pause asks for 50 us and is budgeted at 150; this clock's
-    takes 400: the third stream's event is written behind 800 us of
-    sleep, and the second's write (200 us) is not in it."""
+def test_an_event_is_written_behind_the_writes_ahead_of_it_and_no_sleep(
+        paced):
     gw, clock, stream = paced
     streams = [(slot, stream()) for slot in range(3)]
-    tick(gw, clock, streams, 0, emitted=3000.0, pauses=2)
-    assert [list(p.slept_ss) for _, p in streams] == [
-        pytest.approx([k * SLEEP_S], abs=1e-9) for k in range(3)]
+    tick(gw, clock, streams, 0, emitted=3000.0)
+    assert [list(p.slept_ss) for _, p in streams] == [[0.0]] * 3
     held, wake, pauses, writes = legs(streams[2][1])[0]
-    assert pauses == pytest.approx(0.0008, abs=1e-9)
+    assert pauses == 0.0
     assert writes == pytest.approx(2 * WRITE_S, abs=1e-9)
     assert (held, wake) == pytest.approx((0.001, 0.0003), abs=1e-9)
-    # a queued event keeps the batch's stamps as they stood when it
-    # was passed on: its socket held bytes
+
+
+def test_a_queued_event_keeps_its_batchs_stamps(paced):
+    """Its socket held bytes: the event goes to the request's handler
+    with the batch's stamps as they stood when it was passed on, and
+    the handler writes it with them."""
+    gw, clock, stream = paced
     late = stream()
     late.stream.transport.buffered = 100
-    tick(gw, clock, [*streams, (3, late)], 1, emitted=3000.01, pauses=2)
-    (kind, payload), = [late.chan.get_nowait()]
-    assert kind == "tokens"
-    assert payload[4:] == (pytest.approx(3000.0113),
-                           pytest.approx(2 * SLEEP_S), 3)
+    ahead = [(slot, stream()) for slot in range(3)]
+    tick(gw, clock, [*ahead, (3, late)], 0, emitted=3000.0)
+    event = late.chan.get_nowait()
+    assert event[0] == "tokens"
+    # drained at 3000.0013, behind three writes of 0.2 ms, no sleep
+    assert event[1][4:] == (pytest.approx(3000.0013), 0.0, 3)
+    late.chan.put_nowait(event)
+    late.stream.transport.buffered = 0
+    handler_drains(gw, late, late.stream)
+    assert late.writes_queued == 1
+    assert list(late.drained_ts) == pytest.approx([3000.0013])
+    assert (list(late.slept_ss), list(late.places)) == ([0.0], [3])
 
 
-def test_the_stamps_cost_microseconds_an_event(gw):
+@pytest.mark.parametrize("events", [16, 32])
+def test_the_stamps_cost_microseconds_an_event(gw, events):
     """With the profiler off a token event's four more stamps are four
-    array appends, and a batch's pauses two clock reads each: a batch
-    of 16 events through ``_drain_outbox`` (no pause afforded, stub
-    writers, the real clock) stays under 20 us an event in the best of
-    seven rounds, 0.3 % of the shortest tick the ledger shows (6.75
-    ms); what the stamps add to it is under a microsecond. An absolute
-    ceiling: no second clock taken under load to compare with."""
+    array appends: a batch of 16 or 32 events through
+    ``_drain_outbox`` (stub writers, the real clock) stays under 20 us
+    an event in the best of seven rounds, 0.3 % of the shortest tick
+    the ledger shows (6.75 ms); what the stamps add to it is under a
+    microsecond. An absolute ceiling: no second clock taken under load
+    to compare with."""
     import timeit
 
-    streams = [pending_request() for _ in range(16)]
+    streams = [pending_request() for _ in range(events)]
     for p in streams:
         p.stream = FakeWriter()
 
@@ -571,13 +602,13 @@ def test_the_stamps_cost_microseconds_an_event(gw):
         now = time.monotonic()
         for slot, p in enumerate(streams):
             gw._post(p, ("tokens", (slot, [1], now)))
-        gw._streamed_t = now  # affords no pause
         gw._drain_outbox()
         for p in streams:
             p.stream.frames.clear()
 
     one_tick()
-    per_event = min(timeit.repeat(one_tick, number=50, repeat=7)) / 50 / 16
+    per_event = min(
+        timeit.repeat(one_tick, number=50, repeat=7)) / 50 / events
     assert per_event < 20e-6, f"{per_event * 1e6:.1f} us a token event"
     assert len(streams[0].places) == len(streams[0].written_ts) == 351
 

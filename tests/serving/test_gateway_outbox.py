@@ -1,7 +1,8 @@
 """The worker -> event loop edge of the gateway: a tick's events ride one
 wake-up, a streaming response's tokens are written straight from the
-outbox, in order, paced; and the worker hands a tick's results over
-once the next decode step is on the device (PERF.md, PR 27)."""
+outbox, in slot order and back to back (PERF.md, PR 58); and the worker
+hands a tick's results over once the next decode step is on the device
+(PERF.md, PR 27)."""
 
 import threading
 
@@ -68,6 +69,11 @@ class FakeWriter:
         self.frames.append(data)
 
 
+def slept(seconds):
+    """``time.sleep`` on the gateway's write path: a fault."""
+    raise AssertionError(f"the write path slept {seconds} s")
+
+
 def pending_request():
     return _Pending(GenerateRequest(prompt=[1, 2], max_new_tokens=4),
                     deadline=None)
@@ -100,8 +106,7 @@ class TestOutbox:
 
     def test_stream_tokens_are_written_from_the_outbox_in_order(
             self, gw, monkeypatch):
-        pauses = []
-        monkeypatch.setattr(gateway_mod.time, "sleep", pauses.append)
+        monkeypatch.setattr(gateway_mod.time, "sleep", slept)
         streams = [pending_request() for _ in range(3)]
         for i, p in enumerate(streams):
             p.stream = FakeWriter()
@@ -115,38 +120,23 @@ class TestOutbox:
             assert events[0][1]["token_ids"] == [10 + i]
             assert p.request_id == i and p.token_count == 1
             assert p.first_token_t is not None
-        # the loop's thread gives up its CPU between two writes, not
-        # after the last
-        assert pauses == [gateway_mod.STREAM_WRITE_PAUSE_S] * 2
 
-    def post_stream_tokens(self, gw, n):
-        for i in range(n):
-            p = pending_request()
-            p.stream = FakeWriter()
-            gw._post(p, ("tokens", (i, [1], None)))
-
-    def test_pauses_are_capped(self, gw, monkeypatch):
-        pauses = []
-        monkeypatch.setattr(gateway_mod.time, "sleep", pauses.append)
-        monkeypatch.setattr(gateway_mod, "MAX_WRITE_PAUSES", 3)
-        self.post_stream_tokens(gw, 8)
-        gw._drain_outbox()
-        assert len(pauses) == 3
-
-    @pytest.mark.parametrize("since_last_s, want", [
-        (0.033, 7), (0.0014, 0), (0.0031, 2), (10.0, 7)])
-    def test_the_loop_sleeps_a_tenth_of_the_cadence_at_most(
-            self, gw, monkeypatch, since_last_s, want):
-        """A 33 ms tick affords all its pauses; an engine that ticks
-        every 1.4 ms none: its loop would do nothing but sleep."""
-        pauses = []
-        monkeypatch.setattr(gateway_mod.time, "sleep", pauses.append)
-        monkeypatch.setattr(gateway_mod.time, "monotonic", lambda: 100.0)
-        gw._streamed_t = 100.0 - since_last_s
-        self.post_stream_tokens(gw, 8)
-        gw._drain_outbox()
-        assert len(pauses) == want
-        assert gw._streamed_t == 100.0
+    @pytest.mark.parametrize("streams", [2, 8, 16, 32])
+    def test_a_batch_is_written_back_to_back_in_slot_order(
+            self, gw, monkeypatch, streams):
+        """Nothing on the write path may call ``time.sleep``, whatever
+        the batch's size and however long ago the batch before it was
+        (a first batch ever, then one right behind it)."""
+        monkeypatch.setattr(gateway_mod.time, "sleep", slept)
+        order = []
+        for _ in range(2):
+            for slot in range(streams):
+                p = pending_request()
+                p.stream = FakeWriter()
+                p.stream.write = lambda data, slot=slot: order.append(slot)
+                gw._post(p, ("tokens", (slot, [1], None)))
+            gw._drain_outbox()
+        assert order == list(range(streams)) * 2
 
     @pytest.mark.parametrize("case", [
         "behind_an_event", "socket_backed_up", "closing", "unary"])
