@@ -130,8 +130,8 @@ class ModelArguments:
         default=None,
         metadata={"help": "Standard deviation the random initialiser "
                           "draws the token embedding at (qwen3_next, "
-                          "afmoe, jamba, pangu_ultra_moe and "
-                          "kimi_linear; unset: "
+                          "afmoe, jamba, pangu_ultra_moe, kimi_linear "
+                          "and mimo_v2_flash; unset: "
                           "0.02, HF's "
                           "initializer_range). "
                           "A property of random weights, not of the "
@@ -142,7 +142,7 @@ class ModelArguments:
         metadata={"help": "Multiple of its fan-in bound that the random "
                           "initialiser draws the held routed experts' "
                           "down projection at (pangu_ultra_moe, "
-                          "kimi_linear; unset: "
+                          "kimi_linear, mimo_v2_flash; unset: "
                           "1). A property of random weights, not of "
                           "the model: it sets how far one routed "
                           "expert moves a token beside the shared one."},
@@ -151,8 +151,10 @@ class ModelArguments:
         default=None,
         metadata={"help": "Multiple of its fan-in bound that the random "
                           "initialiser draws the query up-projection "
-                          "(q_b_proj; kimi_linear's latent q_proj) at "
-                          "(pangu_ultra_moe, kimi_linear; unset: 1). A "
+                          "(q_b_proj; kimi_linear's latent q_proj; "
+                          "mimo_v2_flash's q_proj) at "
+                          "(pangu_ultra_moe, kimi_linear, "
+                          "mimo_v2_flash; unset: 1). A "
                           "property of random weights, not of the "
                           "model: at 1 random scores are flat (std "
                           "0.33) and every token of a sequence gets "
@@ -229,6 +231,38 @@ class ModelArguments:
     linear_attn_config: Optional[Dict[str, Any]] = None
     num_experts_per_token: int = 8
     moe_renormalize: bool = True
+    # mimo_v2_flash, by the published config.json names (head_dim,
+    # v_head_dim, partial_rotary_factor, sliding_window_size,
+    # n_routed_experts HELD here, n_shared_experts null,
+    # routed_scaling_factor null, n_group / topk_group, norm_topk_prob
+    # above): the kind of each layer (0 full attention, 1 window) and
+    # of its MLP (0 dense, 1 sparse) as the published lists, a window
+    # layer's K/V heads and rotary base, its head widths where a file
+    # repeats them (one that differs from the full layers' is refused),
+    # the factor on the value projection, the learned sink logit a
+    # head of the window (full: refused) layers, and the norm's epsilon
+    # under its published name. kimi_linear's file carries
+    # ``moe_layer_freq: 1``, a constant of that family that nothing
+    # reads
+    hybrid_layer_pattern: Optional[List[int]] = None
+    moe_layer_freq: Optional[List[int]] = None
+    swa_num_key_value_heads: int = 8
+    swa_rope_theta: float = 10000.0
+    swa_head_dim: Optional[int] = None
+    swa_v_head_dim: Optional[int] = None
+    attention_value_scale: float = 0.707
+    add_swa_attention_sink_bias: bool = True
+    add_full_attention_sink_bias: bool = False
+    layernorm_epsilon: Optional[float] = None
+    sink_init_mean: Optional[float] = field(
+        default=None,
+        metadata={"help": "Mean of the normal the random initialiser "
+                          "draws the window layers' sink logits around "
+                          "(mimo_v2_flash; unset: 0). A property of "
+                          "random weights, not of the model: it sets "
+                          "the share of a window row's mass the sink "
+                          "takes."},
+    )
     attention_backend: str = field(
         default="auto",
         metadata={"help": "auto | flash | flash_jax | ring | ulysses | "

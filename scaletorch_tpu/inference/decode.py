@@ -53,6 +53,7 @@ from scaletorch_tpu.inference.kv_cache import (
     SLOT_FIELDS,
     carries_state,
     no_prefix_reason,
+    window_of,
 )
 from scaletorch_tpu.inference.routing_counters import step_counts
 from scaletorch_tpu.inference.sampling import (
@@ -87,9 +88,11 @@ def counts_routing(cfg) -> bool:
 
 def rows_name_slots(cfg) -> bool:
     """Whether a prefill row of the config's model names its slot: its
-    cache carries state by slot and its family's column says the write
-    at a slot id is tested for it (``models/families.py``)."""
-    return carries_state(cfg) and family_of(cfg).rows_name_slots
+    cache keeps memory by slot (a recurrent state, or window layers'
+    rings) and its family's column says the write at a slot id is
+    tested for it (``models/families.py``)."""
+    return ((carries_state(cfg) or window_of(cfg) is not None)
+            and family_of(cfg).rows_name_slots)
 
 
 def prefill_shapes(max_slots: int,
@@ -262,6 +265,10 @@ def make_paged_prefill_step(
     # whether a row of a call can continue a prefix that lies in the pool
     shares_prefixes = no_prefix_reason(cfg) is None
     by_id = rows_name_slots(cfg)
+    # what a named slot addresses: the by-slot state buffers, scattered
+    # here, or the window layers' rings, whose tables the family's
+    # forward builds from the ids (``kv_cache.RingKVIO``)
+    state_by_id = by_id and carries_state(cfg)
 
     def prefill(params, tokens, tail_lens, starts, write_mask,
                 page_tables, pool, base_keys, *routing):
@@ -271,6 +278,7 @@ def make_paged_prefill_step(
         b, p = tokens.shape
         if by_id:
             slot_ids, *routing = routing
+        if state_by_id:
             held = {name: getattr(pool, name) for name in SLOT_FIELDS}
             pool = pool._replace(**{
                 name: jnp.zeros((buf.shape[0], b) + buf.shape[2:], buf.dtype)
@@ -287,13 +295,15 @@ def make_paged_prefill_step(
                 row_mask=write_mask[:, None] & (rows < tail_lens[:, None]))
         if routing_counts:
             counted["return_routing"] = True
+        if by_id and not state_by_id:
+            counted["slot_ids"] = slot_ids
         logits, new_pool, *counts = fwd(
             params, tokens, cfg, tuple(pool),
             positions=positions, write_mask=write_mask, kv_io=kv_io,
             logit_rows=tail_lens - 1, **counted,
         )
         new_pool = type(pool)(*new_pool)
-        if by_id:
+        if state_by_id:
             # past the last slot: dropped
             at = jnp.where(write_mask, slot_ids, held["state"].shape[1])
             new_pool = new_pool._replace(**{

@@ -342,18 +342,24 @@ class EngineMetrics:
     recurrent_state_resets: int = 0
     recurrent_state_owner_mismatches: int = 0
     # a model with window-attention layers (kv_cache.WindowCache): the
-    # bytes of its rings (0: no such model, and none of these five is
+    # bytes of its rings (0: no such model, and none of these six is
     # in the snapshot); times a slot's write position passed its ring's
     # end (a prompt longer than the ring has at admission); keys one
     # window layer and one full layer attended, summed over the decode
     # slot-steps dispatched (from the positions: min(p + 1, window) and
     # p + 1); decode slot-steps dispatched on a slot whose ring was
-    # last started by another request (0 or a fault)
+    # last started by another request (0 or a fault); pages of admitted
+    # prompts' window-layer K/V that the prefill call computed and wrote
+    # to TRASH because a ring keeps a prompt's newest ``ring_pages``
+    # (from the prompt's length: ``max(ceil(len / page) - ring_pages,
+    # 0)`` a window layer, summed over the window layers: writes that
+    # nothing reads, counted so that taking them out has a number)
     window_cache_bytes: int = 0
     window_ring_wraps: int = 0
     window_keys_attended: int = 0
     full_keys_attended: int = 0
     window_slot_reuse_mismatches: int = 0
+    window_pages_trashed: int = 0
     # a model with latent attention (kv_cache.LatentCache): the bytes of
     # its pool of latent rows (0: no such model, and neither is in the
     # snapshot) and the cached rows one layer's decode kernel walked,
@@ -429,7 +435,8 @@ class EngineMetrics:
         if self.window_cache_bytes:
             for name in ("window_cache_bytes", "window_ring_wraps",
                          "window_keys_attended", "full_keys_attended",
-                         "window_slot_reuse_mismatches"):
+                         "window_slot_reuse_mismatches",
+                         "window_pages_trashed"):
                 snap[name] = getattr(self, name)
         if self.latent_cache_bytes:
             snap["latent_cache_bytes"] = self.latent_cache_bytes
@@ -1738,6 +1745,10 @@ class InferenceEngine:
             self.metrics.window_ring_wraps += sum(
                 (self._slots[i].position - 1) // self._ring_tokens
                 for i in admitted)
+            ring_pages = self._ring_tokens // self.page_size
+            self.metrics.window_pages_trashed += self.cache.wk.shape[0] * sum(
+                max(ceil_div(len(tail), self.page_size) - ring_pages, 0)
+                for tail, _ in taken.values())
         return _Admission(
             list(first), list(finite),
             [(i, self._slots[i].request) for i in admitted], row_of, t0)

@@ -56,6 +56,14 @@ whose logical page ``t // page_size`` repeats the ring, so the same
 (the mask is by position, the kernel's walk starts at the window's
 first page). What a slot's earlier request left in its ring is never
 read: a position inside a request's window was written by that request.
+The pool and the rings each have their own ``(K/V heads, key width as
+stored, value width)`` (``kv_head_shapes``): afmoe's coincide; in
+mimo_v2_flash a full layer has 4 K/V heads and a window layer 8, and a
+key is 192 wide on a value of 128, stored at 256 (whole 128-lane tiles,
+the trailing 64 zeros: ``stored_key_width``) beside the value's 128.
+Where the family's prefill row names its slot (``rows_name_slots``)
+``RingKVIO`` is handed the rows' slot ids and a one-row call writes the
+rings of its slot.
 
 A model with latent attention (pangu_ultra_moe: every layer) keeps
 ONE row a token and layer, ``[c | k_r]``: the normed latent
@@ -140,8 +148,14 @@ def kv_cache_shape(cfg, batch: int, max_seq: int) -> Tuple[int, ...]:
     if hasattr(cfg, "num_key_value_heads"):  # Llama / Qwen3 / Qwen3-MoE
         # a hybrid model keeps K/V for its full-attention layers only
         layers = getattr(cfg, "num_kv_cache_layers", cfg.num_hidden_layers)
-        return (layers, batch, cfg.num_key_value_heads,
-                max_seq, cfg.actual_head_dim)
+        (heads, d_k, d_v), _ = kv_head_shapes(cfg)
+        if d_k != d_v:
+            raise TypeError(
+                f"{type(cfg).__name__} stores keys {d_k} wide beside "
+                f"values {d_v} wide: its K and V buffers differ in shape "
+                "(paged_kv_cache_shapes); a contiguous cache for it is not "
+                "written")
+        return (layers, batch, heads, max_seq, d_k)
     if hasattr(cfg, "n_layer"):  # GPTMoEConfig
         return (cfg.n_layer, batch, cfg.n_head, max_seq, cfg.head_dim)
     raise TypeError(f"no KV-cache layout known for config {type(cfg).__name__}")
@@ -160,8 +174,8 @@ def kv_cache_bytes(
     if latent_of(cfg):   # one pool, a row a token
         return math.prod(
             latent_cache_shape(cfg, num_pages, page_size)) * dt.itemsize
-    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
-    return 2 * math.prod(shape) * dt.itemsize
+    return sum(math.prod(shape) for shape in paged_kv_cache_shapes(
+        cfg, num_pages, page_size)) * dt.itemsize
 
 
 def cache_nbytes(cache: Any) -> int:
@@ -259,7 +273,10 @@ class WindowCache(NamedTuple):
     ``k`` / ``v`` ``[full layers, n_pages, Hkv, page_size, D]`` (pages
     by request) and the rings ``wk`` / ``wv`` ``[window layers, 1 +
     slots * ring_pages, Hkv, page_size, D]`` (page 0 TRASH, then each
-    slot's ring; module docstring)."""
+    slot's ring; module docstring). ``Hkv`` and ``D`` are the pool's in
+    ``k`` / ``v`` and the rings' in ``wk`` / ``wv``, and a K buffer's
+    ``D`` (the key as stored) need not be its V buffer's
+    (``kv_head_shapes``)."""
 
     k: jax.Array
     v: jax.Array
@@ -321,6 +338,27 @@ def window_of(cfg) -> Optional[int]:
     """The window of a model's window-attention layers; None for a model
     without any (its cache is the page pool alone)."""
     return cfg.sliding_window if hasattr(cfg, "num_window_layers") else None
+
+
+def stored_key_width(width: int) -> int:
+    """A key ``width`` wide as a pool stores it: whole 128-lane tiles
+    (192 -> 256, the trailing numbers zeros), which a Mosaic copy of a
+    page needs (``paged_attention.kernel_serves``). One layout on every
+    platform: the CPU's scatter and gather read the same rows."""
+    return ceil_div(width, _LANES) * _LANES
+
+
+def kv_head_shapes(cfg) -> Tuple[Tuple[int, int, int], Tuple[int, int, int]]:
+    """``(K/V heads, key width as stored, value width)`` of the page
+    pool's layers and of the window layers' rings: the family's own
+    where its config says so (mimo_v2_flash: ``(4, 256, 128)`` and
+    ``(8, 256, 128)``), else one head count and one width for all."""
+    own = getattr(cfg, "kv_head_shapes", None)
+    if own is not None:
+        return own
+    one = (cfg.num_key_value_heads, cfg.actual_head_dim,
+           cfg.actual_head_dim)
+    return one, one
 
 
 def no_prefix_reason(cfg) -> Optional[str]:
@@ -396,6 +434,19 @@ def paged_kv_cache_shape(cfg, num_pages: int, page_size: int
     return (l, num_pages, h, page_size, d)
 
 
+def paged_kv_cache_shapes(cfg, num_pages: int, page_size: int
+                          ) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The K pool's and the V pool's shape: ``paged_kv_cache_shape``
+    twice, except where a key is stored wider than a value
+    (``kv_head_shapes``)."""
+    if not hasattr(cfg, "kv_head_shapes"):
+        shape = paged_kv_cache_shape(cfg, num_pages, page_size)
+        return shape, shape
+    (heads, d_k, d_v), _ = kv_head_shapes(cfg)
+    lead = (cfg.num_kv_cache_layers, num_pages, heads, page_size)
+    return lead + (d_k,), lead + (d_v,)
+
+
 def init_paged_kv_cache(
     cfg,
     num_pages: int,
@@ -436,7 +487,7 @@ def init_paged_kv_cache(
             return LatentCache(k=pool)
         return HybridCache(pool, None,
                            *_zero_recurrent_state(cfg, slots, dt, sk))
-    shape = paged_kv_cache_shape(cfg, num_pages, page_size)
+    k_shape, v_shape = paged_kv_cache_shapes(cfg, num_pages, page_size)
     if by_slot and sk is not None and len(sk.device_set) > 1:
         # asked before the pools are made: a family with ONE K/V head
         # (Jamba) has no head axis a second device could take
@@ -445,14 +496,17 @@ def init_paged_kv_cache(
             "devices (tensor parallelism over such layers) is not "
             "written: serve this model on one device")
     # device=: allocated on the shards, never whole on the default device
-    k, v = jnp.zeros(shape, dt, device=sk), jnp.zeros(shape, dt, device=sv)
+    k = jnp.zeros(k_shape, dt, device=sk)
+    v = jnp.zeros(v_shape, dt, device=sv)
     if not by_slot:
         return PagedKVCache(k=k, v=v)
     if window is not None:
+        _, (heads, d_k, d_v) = kv_head_shapes(cfg)
         ring = (cfg.num_window_layers,
-                1 + slots * window_ring_pages(window, page_size)) + shape[2:]
-        return WindowCache(k, v, jnp.zeros(ring, dt, device=sk),
-                           jnp.zeros(ring, dt, device=sv))
+                1 + slots * window_ring_pages(window, page_size),
+                heads, page_size)
+        return WindowCache(k, v, jnp.zeros(ring + (d_k,), dt, device=sk),
+                           jnp.zeros(ring + (d_v,), dt, device=sv))
     return HybridCache(k, v, *_zero_recurrent_state(cfg, slots, dt, sk))
 
 
@@ -799,18 +853,23 @@ class PagedKVIO:
 
     def attend(self, q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                layer: jax.Array, q_positions: jax.Array,
-               own: Optional[Tuple[jax.Array, jax.Array]] = None
-               ) -> jax.Array:
+               own: Optional[Tuple[jax.Array, jax.Array]] = None,
+               *, scale: Optional[float] = None,
+               sink: Optional[jax.Array] = None) -> jax.Array:
         """``q`` [B, Hq, S, D] against ``layer`` of the pool, which
         already holds the call's own K/V; ``own`` is that K/V as the
         call made it ([B, Hkv, S, D] each), for a multi-row call none
-        of whose rows has a prefix in the pool (``prefix_hit``)."""
+        of whose rows has a prefix in the pool (``prefix_hit``).
+        ``scale``: the scores' factor where it is not ``D ** -0.5`` (a
+        query padded to the stored key's width); ``sink`` [Hq]: a logit
+        a query head in every softmax (``paged_attention``)."""
         return paged_attention(
             q, pool_k, pool_v, self.page_tables, q_positions,
             page_size=self.page_size, layer=layer, seq_limit=self.seq_limit,
-            kernel=None if self.kernel is None
+            scale=scale, kernel=None if self.kernel is None
             else self.kernel and q.shape[2] == 1,
             interpret=self.interpret, own=own, prefix_hit=self.prefix_hit,
+            sink=sink,
         )
 
     def write_latent(self, pool: jax.Array, layer: jax.Array, c: jax.Array,
@@ -856,16 +915,22 @@ class RingKVIO:
     go to TRASH, since an older page of a prompt longer than the ring
     would land on a newer one's place, and so would the rows a fixed-
     shape prefill buffer holds past the prompt's end.
-    ``attend`` serves the one-token read; a multi-row call of such a
-    family attends to itself and does not come here."""
+    ``slots`` [B]: the slot each row of the call is (None: row b is
+    slot b, a decode step's rows and afmoe's prefill call over every
+    slot; a family whose prefill row names its slot hands its ids).
+    ``attend`` serves the one-token read (``scale`` and ``sink`` as
+    ``paged_attention`` has them); a multi-row call of such a family
+    attends to itself and does not come here."""
 
     def __init__(self, paged: "PagedKVIO", window: int, first: jax.Array,
-                 live: jax.Array) -> None:
+                 live: jax.Array, slots: Optional[jax.Array] = None) -> None:
         self.paged, self.window = paged, window
-        slots, width = paged.page_tables.shape
+        rows, width = paged.page_tables.shape
         ring = window_ring_pages(window, paged.page_size)
         logical = jnp.arange(width, dtype=jnp.int32)[None, :]
-        own = 1 + ring * jnp.arange(slots, dtype=jnp.int32)[:, None]
+        if slots is None:
+            slots = jnp.arange(rows, dtype=jnp.int32)
+        own = 1 + ring * slots.astype(jnp.int32)[:, None]
         self.tables = own + logical % ring
         last = ((first + live - 1) // paged.page_size)[:, None]
         self.write_tables = jnp.where(
@@ -881,7 +946,9 @@ class RingKVIO:
             interpret=self.paged.interpret)
 
     def attend(self, q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-               layer: jax.Array, q_positions: jax.Array) -> jax.Array:
+               layer: jax.Array, q_positions: jax.Array, *,
+               scale: Optional[float] = None,
+               sink: Optional[jax.Array] = None) -> jax.Array:
         if q.shape[2] != 1:
             raise ValueError(
                 "a window layer's ring serves one-token reads; a "
@@ -889,5 +956,6 @@ class RingKVIO:
         return paged_attention(
             q, pool_k, pool_v, self.tables, q_positions,
             page_size=self.paged.page_size, layer=layer,
-            seq_limit=self.paged.seq_limit, kernel=self.paged.kernel,
-            interpret=self.paged.interpret, window=self.window)
+            seq_limit=self.paged.seq_limit, scale=scale,
+            kernel=self.paged.kernel, interpret=self.paged.interpret,
+            window=self.window, sink=sink)

@@ -32,6 +32,10 @@ from scaletorch_tpu.models.kimi_linear import (  # noqa: F401
     KimiLinear,
     KimiLinearConfig,
 )
+from scaletorch_tpu.models.mimo_v2_flash import (  # noqa: F401
+    MimoV2Flash,
+    MimoV2FlashConfig,
+)
 from scaletorch_tpu.models.gpt_moe import GPTMoE, GPTMoEConfig  # noqa: F401
 from scaletorch_tpu.models.lenet import LeNet, LeNetConfig  # noqa: F401
 from scaletorch_tpu.models.resnet import ResNetConfig  # noqa: F401

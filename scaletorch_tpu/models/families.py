@@ -23,6 +23,7 @@ from scaletorch_tpu.models import (
     jamba,
     kimi_linear,
     llama,
+    mimo_v2_flash,
     olmo_hybrid,
     olmoe,
     pangu_ultra_moe,
@@ -52,8 +53,10 @@ class Family:
     # a row of a prefill call names its slot (``slot_ids``): a call's
     # rows are its admitted prompts and the family's one prefill program
     # is one row (``inference.decode.SlotRows``). Written and
-    # parity-tested for the two delta-rule families and for kimi_linear
-    # (whose row also writes latent rows at its pages). The same write
+    # parity-tested for the two delta-rule families, for kimi_linear
+    # (whose row also writes latent rows at its pages) and for
+    # mimo_v2_flash (``RingKVIO``'s table from slot ids: its row writes
+    # the pool at its pages and the rings at its slot). The same write
     # would serve jamba's state, and the two other by-slot shapes are a
     # forward each (afmoe: ``RingKVIO``'s table from slot ids;
     # pangu_ultra_moe: a page-addressed long-prompt shape), but their
@@ -112,6 +115,16 @@ FAMILIES: Dict[str, Family] = {
             "rules (tp / cp / pp), its experts no exchange (ep), and "
             "there is no HF weight loading; the family is served "
             "(scripts/serve.py --preset kimi-linear-48b-a3b)")),
+    "mimo_v2_flash": Family(
+        mimo_v2_flash, mimo_v2_flash.MimoV2FlashConfig, counts_routing=True,
+        rows_name_slots=True,
+        untrained=(
+            "the flash backward knows neither a window nor a sink nor a "
+            "value narrower than its key, its two kinds of layer have no "
+            "sharding rules (tp / cp / pp), its experts no exchange (ep), "
+            "its multi-token-prediction layers and their loss are not "
+            "built, and there is no HF weight loading; the family is "
+            "served (scripts/serve.py --preset mimo-v2-flash)")),
     # served and tested through its config class; trains via its example
     "gpt_moe": Family(gpt_moe, gpt_moe.GPTMoEConfig),
 }
@@ -145,7 +158,7 @@ def build_model_config(args):
     overrides = dict(dtype=_DTYPE[args.dtype],
                      param_dtype=_DTYPE[args.param_dtype])
     for name in ("embed_init_std", "routed_expert_init_scale",
-                 "query_init_scale"):
+                 "query_init_scale", "sink_init_mean"):
         # properties of random weights: a family whose initialiser reads
         # one has the field
         if getattr(args, name) is None:

@@ -209,6 +209,23 @@ def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     return k.reshape(b, h_kv * n_rep, s, d)
 
 
+def softmax_with_sink(scores: jax.Array,
+                      sink: Optional[jax.Array] = None) -> jax.Array:
+    """Softmax over the last axis of ``scores`` [B, H, Sq, Sk] float32;
+    with ``sink`` [H] (a learned logit a query head) the sink is one
+    more column of every row's softmax that carries no value: it takes
+    its share of the mass and the column is dropped (``exp(s - m) /
+    (exp(sink - m) + sum exp(s - m))``)."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    with jax.named_scope("attn.sink"):
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            scores.shape[:-1] + (1,))
+        return jax.nn.softmax(
+            jnp.concatenate([scores, column], axis=-1), axis=-1)[..., :-1]
+
+
 def sdpa_attention(
     q: jax.Array,
     k: jax.Array,
@@ -217,10 +234,12 @@ def sdpa_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     bias: Optional[jax.Array] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Plain XLA scaled-dot-product attention with fp32 softmax.
 
-    q: [B, Hq, S, D]; k/v: [B, Hkv, Skv, D] (GQA expanded here).
+    q: [B, Hq, S, D]; k/v: [B, Hkv, Skv, D] (GQA expanded here; the
+    value's width may be its own). ``sink``: ``softmax_with_sink``.
     The default/portable backend (reference 'sdpa', attention_utils.py:130-152).
     """
     if scale is None:
@@ -235,7 +254,7 @@ def sdpa_attention(
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     if bias is not None:
         scores = scores + bias.astype(jnp.float32)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = softmax_with_sink(scores, sink).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
@@ -279,6 +298,7 @@ def cached_sdpa_attention(
     *,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """SDPA against a fixed-size KV cache with absolute-position masking.
 
@@ -294,7 +314,9 @@ def cached_sdpa_attention(
     the full-sequence training forward to float tolerance. With
     ``window`` the query at p sees only the entries with ``p - j <
     window`` (a window layer's ring, where index j holds the newest
-    position that is j modulo the ring's length).
+    position that is j modulo the ring's length). ``sink``:
+    ``softmax_with_sink``; the value cache may be narrower than the key
+    cache.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -307,7 +329,7 @@ def cached_sdpa_attention(
     if window is not None:
         mask &= q_positions[:, :, None] - key_idx[None, None, :] < window
     scores = jnp.where(mask[:, None], scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = softmax_with_sink(scores, sink).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 

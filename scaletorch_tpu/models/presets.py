@@ -541,6 +541,84 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         moe_renormalize=True,
         routed_scaling_factor=2.446,
     ),
+    # XiaomiMiMo/MiMo-V2-Flash (309B-A15B), config.json as published:
+    # 48 layers, five 128-key window layers (8 K/V heads, a learned sink
+    # a head, rotary base 1e4) to one full layer (4 K/V heads, base
+    # 5e6), keys 192 wide on values 128, the rotary embedding on a
+    # head's first 64 dims, a leading dense layer and 47 of 256
+    # sigmoid-routed experts, top 8, no shared expert. Far past one
+    # chip: the benchmark serves a chip's share
+    # (benchmarks/configs/mimo-v2-flash-serve.json):
+    # --num_hidden_layers 7 with the two lists' first seven entries,
+    # --n_routed_experts 16 --num_routed_experts 256, an eighth of the
+    # vocabulary.
+    "mimo-v2-flash": dict(
+        model_type="mimo_v2_flash",
+        vocab_size=152576,
+        hidden_size=4096,
+        intermediate_size=16384,
+        num_hidden_layers=48,
+        num_attention_heads=64,
+        num_key_value_heads=4,
+        swa_num_key_value_heads=8,
+        head_dim=192,
+        v_head_dim=128,
+        rope_theta=5000000.0,
+        swa_rope_theta=10000.0,
+        partial_rotary_factor=0.334,
+        layernorm_epsilon=1e-5,
+        max_position_embeddings=262144,
+        tie_word_embeddings=False,
+        sliding_window_size=128,
+        attention_value_scale=0.707,
+        hybrid_layer_pattern=[0, 1, 1, 1, 1, 0] + [1, 1, 1, 1, 1, 0] * 7,
+        moe_layer_freq=[0] + [1] * 47,
+        add_swa_attention_sink_bias=True,
+        add_full_attention_sink_bias=False,
+        n_routed_experts=256,
+        n_shared_experts=None,
+        num_experts_per_tok=8,
+        moe_intermediate_size=2048,
+        norm_topk_prob=True,
+        routed_scaling_factor=None,
+    ),
+    # The same family at a size the CPU tests serve: the published
+    # pattern's first seven layers (a dense full layer, then 5 window +
+    # 1 full sparse layers), keys 24 wide on values 16 with 8 dims
+    # turned, a window of 20 tokens (a ring of 4 pages of 8), 2 and 4
+    # K/V heads, and a SHARE of the experts: 4 of 16 routed ones held
+    # here, from id 4.
+    "mimo-v2-flash-tiny": dict(
+        model_type="mimo_v2_flash",
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_hidden_layers=7,
+        num_attention_heads=8,
+        num_key_value_heads=2,
+        swa_num_key_value_heads=4,
+        head_dim=24,
+        v_head_dim=16,
+        rope_theta=5000000.0,
+        swa_rope_theta=10000.0,
+        partial_rotary_factor=0.334,
+        layernorm_epsilon=1e-5,
+        max_position_embeddings=4096,
+        tie_word_embeddings=False,
+        sliding_window_size=20,
+        attention_value_scale=0.707,
+        hybrid_layer_pattern=[0, 1, 1, 1, 1, 0, 1],
+        moe_layer_freq=[0, 1, 1, 1, 1, 1, 1],
+        n_routed_experts=4,
+        num_routed_experts=16,
+        first_expert_id=4,
+        n_shared_experts=None,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        norm_topk_prob=True,
+        routed_scaling_factor=None,
+        sink_init_mean=2.5,
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

@@ -66,10 +66,12 @@ def prefill_self_attention(
     *,
     window: Optional[int] = None,
     scale: Optional[float] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Causal attention of a prompt over itself, forward only: row i
     sees keys j <= i, and with ``window`` only those with ``i - j <
-    window``. [B, Hq, S, D] x [B, Hkv, S, D] x [B, Hkv, S, Dv] -> [B, Hq,
+    window``; with ``sink`` [Hq] float32 a logit a query head joins
+    every row's softmax and carries no value. [B, Hq, S, D] x [B, Hkv, S, D] x [B, Hkv, S, Dv] -> [B, Hq,
     S, Dv] (the value's width is its own: latent attention's expanded
     heads are 192 / 128). What a
     serving prefill of a sequence that starts at position 0 computes,
@@ -82,14 +84,15 @@ def prefill_self_attention(
         from scaletorch_tpu.ops.pallas.flash import flash_forward_with_lse
 
         return flash_forward_with_lse(
-            q, k, v, causal=True, scale=scale, window=window)[0]
+            q, k, v, causal=True, scale=scale, window=window, sink=sink)[0]
     bias = None
     if window is not None:
         rows = jnp.arange(q.shape[2])[:, None]
         cols = jnp.arange(k.shape[2])[None, :]
         bias = jnp.where(rows - cols >= window,
                          jnp.finfo(jnp.float32).min, 0.0)
-    return sdpa_attention(q, k, v, causal=True, scale=scale, bias=bias)
+    return sdpa_attention(q, k, v, causal=True, scale=scale, bias=bias,
+                          sink=sink)
 
 
 register_attention_backend("flash", flash_attention)

@@ -277,15 +277,22 @@ def _lanes(stat, width):
     return jnp.tile(stat, (1, -(-width // _LANES)))[:, :width]
 
 
-def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None):
+def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None, sink=False):
     (i, j), first, last, refs = _grid_step(causal, refs, 2)
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
+    q_ref, k_ref, v_ref, *sink_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc = refs
 
     @pl.when(first)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+        if sink:
+            # the head's sink logit, lane-replicated [1, _LANES]: one more
+            # column of every row's softmax that carries no value, so the
+            # running maximum starts at it and the running sum at 1
+            m_sc[:] = jnp.broadcast_to(sink_ref[0][0], m_sc.shape)
+            l_sc[:] = jnp.ones_like(l_sc)
+        else:
+            m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+            l_sc[:] = jnp.zeros_like(l_sc)
 
     q = q_ref[0, 0]  # [bq, D]
     k = k_ref[0, 0]  # [bkv, D]
@@ -312,11 +319,15 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None):
         lse_ref[0, 0] = (m_sc[:, 0] + jnp.log(l[:, 0]))[None, :]
 
 
-def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None):
+def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None,
+                   sink=None):
     """``v`` may be narrower or wider than ``q`` / ``k`` (latent
     attention's expanded heads: keys 192, values 128): the output and
     the accumulator take the value's width. The backward kernels know
-    one width."""
+    one width. ``sink`` [Hq] float32: a logit a query head in every
+    row's softmax, with no value (``lse`` then counts it); a call
+    without one compiles to what it compiled to before there was
+    one."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     dv = v.shape[-1]
@@ -334,14 +345,21 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None):
     def kv_rows(b_, h, *g):
         return b_, h // n_rep, blocks(*g)[1], 0
 
+    sinks, sink_specs = (), []
+    if sink is not None:
+        sinks = (jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (hq, 1, _LANES)),)
+        sink_specs = [pl.BlockSpec((1, 1, _LANES),
+                                   lambda b_, h, *g: (h, 0, 0))]
     out, lse = _call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
-                          bkv=bkv, window=window),
+                          bkv=bkv, window=window, sink=sink is not None),
         "flash_fwd", tables, (b, hq, sq // bq, skv // bkv), interpret,
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_rows),
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
             pl.BlockSpec((1, 1, bkv, dv), kv_rows),
+            *sink_specs,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, dv), q_rows),
@@ -357,7 +375,7 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None):
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running sum
         ],
-    )(*tables, q, k, v)
+    )(*tables, q, k, v, *sinks)
     return out, lse[:, :, 0, :]
 
 
@@ -588,12 +606,22 @@ def flash_forward_with_lse(
     block_kv: int | None = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ):
     """Raw kernel forward returning ``(out, lse)``; with ``window`` each
     row sees the ``window`` keys that end at its own
     (``causal_block_plan(window=)``: blocks wholly outside are no grid
-    step, the rest carry the band in their mask). A serving prefill's
-    entry: the backward kernels know no window.
+    step, the rest carry the band in their mask). Under a window
+    narrower than a block (128 keys in 512 x 512 blocks) a query block
+    visits two key blocks, and narrower key blocks buy nothing: on the
+    v5e a window layer's call over one 8,192-token row at 64 heads reads
+    5.45 ms at 512 x 512, 5.45 at 512 x 256, 5.46 at 512 x 128, 5.33 at
+    256 x 128 or 256 x 256 and 6.40 at 1,024 x 128 (PERF.md, PR 59):
+    with two steps a query block, what a query block costs once (its
+    accumulator zeroed, its division and its stores) is most of the
+    call, so the blocks stay as they are. ``sink`` [Hq]: a logit a query
+    head in every softmax, with no value. A serving prefill's entry: the
+    backward kernels know neither a window nor a sink.
 
     NOT differentiable — the caller owns the VJP (ring attention merges
     per-block (out, lse) partials across ``ppermute`` steps and drives the
@@ -610,7 +638,7 @@ def flash_forward_with_lse(
     bq = _pick_block(q.shape[2], block_q)
     bkv = _pick_block(k.shape[2], block_kv)
     return _flash_forward(q, k, v, causal, scale, bq, bkv, interpret,
-                          window)
+                          window, sink)
 
 
 def flash_block_backward(

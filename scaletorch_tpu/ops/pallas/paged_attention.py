@@ -352,16 +352,18 @@ def pallas_paged_write(
 # the decode kernel
 # ---------------------------------------------------------------------------
 def _pages_per_block(page_size: int, hkv: int, d: int, dtype,
-                     max_pages: int) -> int:
+                     max_pages: int, d_v: Optional[int] = None) -> int:
     """Pages one compute block covers: enough for a score tile whose
     last dimension fills the 128 lanes (8 pages at page 16), capped by
     the VMEM budget of the double-buffered landing zone (2 buffers x 2
-    pools x one block of pages) and by the table's length. Shapes in,
-    one integer out — nothing to configure.
+    pools x one block of pages; ``d_v``: the V pool's width where it is
+    not the K pool's ``d``) and by the table's length. Shapes in, one
+    integer out — nothing to configure.
     """
-    page_bytes = hkv * page_size * d * jnp.dtype(dtype).itemsize
+    row_bytes = hkv * page_size * jnp.dtype(dtype).itemsize
+    page_bytes = row_bytes * (d + (d if d_v is None else d_v))
     fill_lanes = -(-_LANES // page_size)
-    fit_budget = _KV_VMEM_BUDGET // (4 * page_bytes)
+    fit_budget = _KV_VMEM_BUDGET // (2 * page_bytes)
     return max(1, min(fill_lanes, fit_budget, max_pages))
 
 
@@ -403,9 +405,17 @@ def chained_first_blocks(positions, page_size: int, max_pages: int,
     return walked, max(walked - 1, 0)
 
 
-def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                         o_ref, k_buf, v_buf, sems, chain, *, scale,
-                         page_size, pages_per_block, max_pages, window=None):
+def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, *refs, scale,
+                         page_size, pages_per_block, max_pages, window=None,
+                         sink=False):
+    """``refs``: with ``sink`` the per-head sink logits ``[Hkv, n_rep,
+    1]`` float32 come first (a column of the softmax that carries no
+    value: the running maximum starts at the sink and the denominator at
+    1, where without one they start at -inf and 0); then the K and V
+    pools, the output and the scratch. The value's width is ``v_buf``'s
+    and the output's, the key's ``q``'s and ``k_buf``'s."""
+    sink_ref, refs = (refs[0], refs[1:]) if sink else (None, refs)
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, chain = refs
     b = pl.program_id(0)   # slot
     n_slots = pl.num_programs(0)
     layer = layer_ref[0]
@@ -470,7 +480,8 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
         start_block(b, first, n_live, 0, buf0)
 
     q = q_ref[0]   # [Hkv, n_rep, D]
-    hkv, nrep, d = q.shape
+    hkv, nrep, _ = q.shape
+    d = v_buf.shape[-1]
     key_in_block = jax.lax.broadcasted_iota(jnp.int32, (hkv, nrep, bk), 2)
 
     def block(i, carry):
@@ -514,11 +525,14 @@ def _paged_decode_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
         )
         return m_new, l_new, acc
 
+    if sink_ref is None:
+        m0 = jnp.full((hkv, nrep, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((hkv, nrep, 1), jnp.float32)
+    else:
+        m0 = sink_ref[...]
+        l0 = jnp.ones((hkv, nrep, 1), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
-        jnp.full((hkv, nrep, 1), _NEG_INF, jnp.float32),
-        jnp.zeros((hkv, nrep, 1), jnp.float32),
-        jnp.zeros((hkv, nrep, d), jnp.float32),
-    ))
+        m0, l0, jnp.zeros((hkv, nrep, d), jnp.float32)))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
     @pl.when(n_blocks > 0)
@@ -538,6 +552,7 @@ def pallas_paged_decode_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """One-token paged attention: q [B, Hq, D] against the page pool.
 
@@ -551,7 +566,13 @@ def pallas_paged_decode_attention(
     of the window's first key: the pages before it cost no DMA, so a
     table whose logical pages repeat a ring of ``ceil(window /
     page_size) + 1`` physical ones serves a window layer).
-    Returns [B, Hq, D].
+    The V pool's last dimension may be narrower or wider than the K
+    pool's (a key 192 wide stored at 256 beside a value of 128): the
+    landing zone, the accumulator and the result take the value's.
+    ``sink`` [Hq] float32: a logit a query head that joins every
+    softmax and carries no value (``p = exp(s - m) / (exp(sink - m) +
+    sum exp(s - m))``).
+    Returns [B, Hq, D of V].
 
     The pools stay in HBM; the page table and positions are
     scalar-prefetched, and each slot's step copies its live pages, a
@@ -564,19 +585,25 @@ def pallas_paged_decode_attention(
         pool_k, pool_v, layer = pool_k[None], pool_v[None], 0
     b, hq, d = q.shape
     _, n_pages, hkv, page_size, _ = pool_k.shape
+    d_v = pool_v.shape[-1]
     if hq % hkv:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
+    if pool_k.shape[-1] != d:
+        raise ValueError(
+            f"a query {d} wide against keys stored {pool_k.shape[-1]} wide: "
+            "pad the query to the stored key's width (zeros) and give the "
+            "scale of the key as written")
     n_rep = hq // hkv
     max_pages = page_tables.shape[1]
-    if not interpret and not kernel_serves(d):
+    if not interpret and not (kernel_serves(d) and kernel_serves(d_v)):
         raise ValueError(
             f"the paged-decode kernel copies whole pages out of HBM, which "
             f"Mosaic allows only for a head_dim that fills the {_LANES} "
-            f"lanes; got {d} (paged_attention() sends it to the gather "
-            f"fallback)")
+            f"lanes; got {d} / {d_v} (paged_attention() sends it to the "
+            f"gather fallback)")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    ppb = _pages_per_block(page_size, hkv, d, pool_k.dtype, max_pages)
+    ppb = _pages_per_block(page_size, hkv, d, pool_k.dtype, max_pages, d_v)
     if not interpret:
         # "the pools stay in HBM", said to XLA too (inside a jitted
         # program: the constraint is no eager operation). A Mosaic
@@ -591,18 +618,23 @@ def pallas_paged_decode_attention(
     def q_idx(b_, *_):
         return (b_, 0, 0, 0)
 
+    sinks, sink_specs = (), []
+    if sink is not None:   # every slot reads the same [Hkv, n_rep, 1]
+        sinks = (sink.astype(jnp.float32).reshape(hkv, n_rep, 1),)
+        sink_specs = [pl.BlockSpec((hkv, n_rep, 1), lambda *_: (0, 0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, hkv, n_rep, d), q_idx),
+            *sink_specs,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hkv, n_rep, d), q_idx),
+        out_specs=pl.BlockSpec((1, hkv, n_rep, d_v), q_idx),
         scratch_shapes=[
             pltpu.VMEM((2, hkv, ppb * page_size, d), pool_k.dtype),
-            pltpu.VMEM((2, hkv, ppb * page_size, d), pool_v.dtype),
+            pltpu.VMEM((2, hkv, ppb * page_size, d_v), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
         ],
@@ -610,9 +642,10 @@ def pallas_paged_decode_attention(
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale,
                           page_size=page_size, pages_per_block=ppb,
-                          max_pages=max_pages, window=window),
+                          max_pages=max_pages, window=window,
+                          sink=sink is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d_v), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # sequential: a slot starts the next one's first block
             dimension_semantics=(pltpu.ARBITRARY,)),
@@ -620,8 +653,8 @@ def pallas_paged_decode_attention(
         name="paged_decode",
     )(page_tables.astype(jnp.int32).reshape(-1),
       positions.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      q.reshape(b, hkv, n_rep, d), pool_k, pool_v)
-    return out.reshape(b, hq, d)
+      q.reshape(b, hkv, n_rep, d), *sinks, pool_k, pool_v)
+    return out.reshape(b, hq, d_v)
 
 
 # ---------------------------------------------------------------------------
@@ -874,6 +907,7 @@ def paged_attention(
     window: Optional[int] = None,
     own: Optional[Tuple[jax.Array, jax.Array]] = None,
     prefix_hit: Any = None,
+    sink: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention against the paged cache, kernel or fallback.
 
@@ -888,7 +922,10 @@ def paged_attention(
     engine's ``max_seq`` so the fallback's reduction has the contiguous
     reference's operand shapes. ``window``: a window layer's mask and
     first page (``pallas_paged_decode_attention``), by position in the
-    fallback.
+    fallback. ``sink`` [Hq]: a logit a query head in every softmax that
+    carries no value, in the kernel and in the fallback alike. The V
+    pool may be narrower than the K pool (a key stored padded): the
+    result takes the value's width; ``in_place_pair`` is asked of both.
 
     A multi-row call may bring ``own``, the K/V [B, Hkv, S, D] it made
     (and has already written to the pool), and ``prefix_hit``, what its
@@ -914,13 +951,15 @@ def paged_attention(
     chooses = s > 1 and own is not None and prefix_hit is not None
 
     def to_itself():
-        return prefill_self_attention(q, *own, scale=scale, window=window)
+        return prefill_self_attention(q, *own, scale=scale, window=window,
+                                      sink=sink)
 
     if chooses and prefix_hit is False:
         return to_itself()
     use_kernel = kernel
     if use_kernel is None:
-        use_kernel = s == 1 and in_place_pair(q.shape[3])
+        use_kernel = (s == 1 and in_place_pair(q.shape[3])
+                      and in_place_pair(pool_v.shape[-1]))
     if use_kernel:
         if s != 1:
             raise ValueError(
@@ -930,6 +969,7 @@ def paged_attention(
         out = pallas_paged_decode_attention(
             q[:, :, 0, :], pool_k, pool_v, page_tables, q_positions[:, 0],
             layer=layer, scale=scale, interpret=interpret, window=window,
+            sink=sink,
         )
         return out[:, :, None, :]
 
@@ -942,7 +982,7 @@ def paged_attention(
 
     def from_pool(k, v):
         return cached_sdpa_attention(q, k, v, q_positions, scale=scale,
-                                     window=window)
+                                     window=window, sink=sink)
 
     if not chooses:
         return from_pool(*gathered())

@@ -19,6 +19,7 @@ from scaletorch_tpu.models import (
     jamba,
     kimi_linear,
     llama,
+    mimo_v2_flash,
     olmo_hybrid,
     olmoe,
     pangu_ultra_moe,
@@ -48,6 +49,7 @@ EXPECTED = {
     "pangu_ultra_moe": (pangu_ultra_moe, pangu_ultra_moe.forward_cached,
                         True),
     "kimi_linear": (kimi_linear, kimi_linear.forward_cached, True),
+    "mimo_v2_flash": (mimo_v2_flash, mimo_v2_flash.forward_cached, True),
     "gpt_moe": (gpt_moe, gpt_moe.forward_cached, False),
 }
 TRAINS = {"llama", "qwen3", "qwen3_moe", "olmoe", "gpt_moe"}
@@ -58,7 +60,7 @@ def built(name):
     return build_model_config(ScaleTorchTPUArguments(**preset(name)))
 
 
-def test_the_rows_are_the_eleven_families():
+def test_the_rows_are_the_twelve_families():
     assert set(FAMILIES) == set(EXPECTED)
     classes = [row.config_cls for row in FAMILIES.values()]
     assert len(set(classes)) == len(classes)
@@ -151,7 +153,8 @@ def test_embed_init_std_is_read_where_the_class_has_the_field(model_type):
     has = "embed_init_std" in FAMILIES[
         model_type].config_cls.__dataclass_fields__
     assert has == (model_type in ("qwen3_next", "afmoe", "jamba",
-                                  "pangu_ultra_moe", "kimi_linear"))
+                                  "pangu_ultra_moe", "kimi_linear",
+                                  "mimo_v2_flash"))
     if not has:
         with pytest.raises(NotImplementedError, match="embed_init_std"):
             build_model_config(ScaleTorchTPUArguments(
@@ -159,15 +162,18 @@ def test_embed_init_std_is_read_where_the_class_has_the_field(model_type):
 
 
 @pytest.mark.parametrize("name", ["routed_expert_init_scale",
-                                  "query_init_scale"])
+                                  "query_init_scale", "sink_init_mean"])
 @pytest.mark.parametrize("model_type", sorted(EXPECTED))
 def test_the_draw_s_scales_are_read_where_the_class_has_the_field(
         model_type, name):
-    """Two more properties of random weights a launch may set: the two
-    families whose initialisers read them have the fields, every other
-    refuses each by name."""
+    """Three more properties of random weights a launch may set: the
+    families whose initialisers read one have its field (the sinks'
+    draw: the one family with sinks), every other refuses each by
+    name."""
     has = name in FAMILIES[model_type].config_cls.__dataclass_fields__
-    assert has == (model_type in ("pangu_ultra_moe", "kimi_linear"))
+    reads = {"sink_init_mean": ("mimo_v2_flash",)}.get(
+        name, ("pangu_ultra_moe", "kimi_linear", "mimo_v2_flash"))
+    assert has == (model_type in reads)
     if not has:
         with pytest.raises(NotImplementedError, match=name):
             build_model_config(ScaleTorchTPUArguments(

@@ -1225,6 +1225,113 @@ def test_kimi_steps_are_what_the_new_readers_look_for(one_chip):
                 + memory.temp_size_in_bytes) < 0.75 * chip, name
 
 
+@pytest.mark.parametrize("hkv,layers,pages,window", [
+    (4, 2, 32 * 608 + 1, None), (8, 5, 32 * 9 + 1, 128)],
+    ids=["full", "window-sink"])
+def test_decode_kernel_compiles_at_two_widths_and_with_a_sink(
+        one_chip, hkv, layers, pages, window):
+    """mimo-v2-flash-serve's two calls alone: 64 query heads (padded to
+    the stored key's 256) over 4 or 8 K/V heads, a K buffer 256 wide
+    beside a V buffer of 128, the window layers' under a band of 128 and
+    a float32 sink a head. One Mosaic call each whose one 4-D result has
+    the VALUE's width."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    sink = None if window is None else arg((64,), jnp.float32)
+    text = jax.jit(
+        lambda q, k, v, tables, pos, layer, sink: (
+            pallas_paged_decode_attention(
+                q, k, v, tables, pos, layer=layer, window=window,
+                scale=192 ** -0.5, sink=sink))
+    ).lower(
+        arg((32, 64, 256), jnp.bfloat16),
+        arg((layers, pages, hkv, 16, 256), jnp.bfloat16),
+        arg((layers, pages, hkv, 16, 128), jnp.bfloat16),
+        arg((32, 608), jnp.int32), arg((32,), jnp.int32),
+        arg((), jnp.int32), sink,
+    ).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 1, calls
+    assert re.search(
+        rf"%paged_decode\S* = bf16\[32,{hkv},{64 // hkv},128\]", calls[0]), calls
+
+
+def _traced_digest(fn, *shapes):
+    """sha256 of a function's traced program (a kernel's jaxpr, its grid
+    and its operands; no source location is in it)."""
+    return hashlib.sha256(str(jax.make_jaxpr(fn)(*(
+        jax.ShapeDtypeStruct(shape, dt) for shape, dt in shapes
+    ))).encode()).hexdigest()
+
+
+def _decode_digest(slots, hq, hkv, d, max_pages, layers, window=None,
+                   ring=None):
+    pool = ((layers, 1 + slots * (ring or max_pages), hkv, 16, d),
+            jnp.bfloat16)
+    return _traced_digest(
+        lambda q, k, v, t, p: pallas_paged_decode_attention(
+            q, k, v, t, p, layer=jnp.int32(1), window=window),
+        ((slots, hq, d), jnp.bfloat16), pool, pool,
+        ((slots, max_pages), jnp.int32), ((slots,), jnp.int32))
+
+
+def _flash_digest(b, hq, hkv, s, dk, dv, window=None):
+    from scaletorch_tpu.ops.pallas.flash import flash_forward_with_lse
+
+    return _traced_digest(
+        lambda q, k, v: flash_forward_with_lse(
+            q, k, v, causal=True, window=window),
+        ((b, hq, s, dk), jnp.bfloat16), ((b, hkv, s, dk), jnp.bfloat16),
+        ((b, hkv, s, dv), jnp.bfloat16))
+
+
+def _flash_training_digest():
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: pallas_flash_attention(
+            q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    return _traced_digest(
+        grads, ((1, 16, 8192, 128), jnp.bfloat16),
+        ((1, 8, 8192, 128), jnp.bfloat16), ((1, 8, 8192, 128), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("digest,want", [
+    (lambda: _decode_digest(16, 16, 8, 128, 96, 28),
+     "046d94fb0dfc6142a1326c878c3d69afcb1d6c9b2e5d69e3d105efd4ed69a36f"),
+    (lambda: _decode_digest(16, 16, 2, 256, 96, 12),
+     "ba7e362e411dca4be7a27145e8976705f59d86e7a3afe02e486c93bfdb28e155"),
+    (lambda: _decode_digest(8, 32, 4, 128, 216, 4),
+     "0d72caf1a79f0f5a610f4aedaf3bbbe6bceef7b405b1c78502a285a691c36a73"),
+    (lambda: _decode_digest(8, 32, 4, 128, 216, 12, window=2048, ring=129),
+     "50dd7211c900f3421087126cd90a1fc511882ac33c99322b3b8d0f959ee9b184"),
+    (_flash_training_digest,
+     "08e50f14919a8d4b4b16d06f14ea01868ee69173b8eaed03455f9073dffb3760"),
+    (lambda: _flash_digest(8, 32, 4, 3072, 128, 128, window=2048),
+     "b38cb64712e0d11977e7af01c6820523ad3869c25db1c63ad9502649371e2dde"),
+    (lambda: _flash_digest(8, 128, 128, 3072, 192, 128),
+     "3bbf465cd93715673f249ffd8859cbdde974915a56df64eda4929b7f8f31d6c8"),
+    (lambda: _flash_digest(1, 16, 8, 512, 128, 128),
+     "550b2429ad94ca8d6e5b811ea603580c20c7971f73b724c36b9cef44820e8485"),
+], ids=["decode-longgen", "decode-qwen3-next", "decode-trinity-full",
+        "decode-trinity-window", "flash-training-fwd-bwd",
+        "flash-trinity-window", "flash-openpangu-192-128",
+        "flash-longgen-row"])
+def test_callers_without_a_sink_at_one_width_trace_to_what_they_did(
+        digest, want):
+    """PR 59 gave the decode kernel and the flash forward a value width
+    of their own and an optional sink. A caller with neither must get
+    the program it had: each traced program below (the decode kernel at
+    three cells' shapes and under Trinity-Mini's window, the flash
+    forward and backward at the training cell's shape, the flash forward
+    under Trinity-Mini's window, at openPangu's 192 / 128 heads and at
+    the one-row call of the dense cells) hashes to what it hashed to at
+    the parent (`7c85100`, computed there with the same functions). A PR
+    that means to change one of these kernels measures the cells that
+    run it and writes the new digests here."""
+    assert digest() == want
+
+
 def test_the_latent_kernel_compiles_for_a_longer_table(one_chip):
     """``latent_decode`` alone at 128 heads over a 640-wide row with a
     table of 8,192 pages (131,072 positions, the published context): one
