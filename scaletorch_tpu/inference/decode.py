@@ -26,8 +26,12 @@ the layout of every parameter left to the compiler, each leaf it reads
 in another order of dimensions is stored once, transposed into that
 order, as a new array (the caller's arrays stay as they were), and both
 steps are built to read the placed tree through a transposition back
-that costs nothing (``param_orders``). No family has code for it: the
-rule asks about each model's own decode program.
+that costs nothing (``param_orders``). Such a leaf that the decode
+program reads ONLY one static layer at a time (``a[index]`` in an
+unrolled forward) is stored as its layers, each an array and a program
+parameter of its own (``ByLayer``): sliced out of one stored stack, a
+layer was copied from HBM to HBM every step. No family has code for it:
+the rule asks about each model's own decode program.
 
 Both lower onto the models' cache-aware forwards
 (models/llama.py forward_cached & family), resolved per config by
@@ -455,25 +459,94 @@ def compile_decode_for_layouts(decode_step: Callable, params, operands, *,
     ``params`` left to the compiler (``Format(Layout.AUTO, <the leaf's
     sharding>)``; the other ``operands`` as a call hands them over, the
     pool donated as the step donates it): the executable, whose
-    ``input_formats`` say how the program wants each weight to lie.
+    ``input_formats`` say how the program wants each weight to lie, and
+    the jaxpr it was compiled from, which says how the step reads each
+    weight (``chosen_orders`` takes both).
     Nothing runs and nothing is placed: ``params`` and ``operands`` may
     be arrays or shapes with shardings (``abstract``). The jitted step
     itself is what is compiled, inside a jit that names the layouts, so
     where nothing is then moved its trace is the one its first call
     finds again."""
     free = jax.tree.map(lambda x: Format(Layout.AUTO, x.sharding), params)
-    return jax.jit(
+    traced = jax.jit(
         decode_step,
         in_shardings=(free,) + (None,) * len(operands),
         donate_argnums=(5,) if _resolve_donate(donate_cache) else (),
-    ).lower(abstract(params), *operands).compile()
+    ).trace(abstract(params), *operands)
+    return traced.lower().compile(), traced.jaxpr
+
+
+class ByLayer(tuple):
+    """An order of dimensions (``chosen_orders``) for a stack that is
+    stored as its layers: ``shape[0]`` arrays, each in this order
+    without the leading axis."""
+
+    @property
+    def of_a_layer(self) -> Tuple[int, ...]:
+        return tuple(d - 1 for d in self if d)
+
+
+class Layers:
+    """In a step, in the place of a stack stored as its layers
+    (``ByLayer``): ``[index]`` with a static index is that layer, a
+    program parameter of its own, which is how the decode program was
+    seen to read the stack. Indexed in any other way, or handed to a
+    ``jnp`` function (another program of the same forward might), it is
+    the stack put together again: the same values, and a copy."""
+
+    def __init__(self, layers):
+        self.layers = tuple(layers)
+
+    @property
+    def shape(self):
+        return (len(self.layers),) + self.layers[0].shape
+
+    @property
+    def dtype(self):
+        return self.layers[0].dtype
+
+    def __jax_array__(self):
+        return jnp.stack(self.layers)
+
+    def __getitem__(self, index):
+        if isinstance(index, (int, np.integer)):
+            return self.layers[index]
+        return self.__jax_array__()[index]
+
+
+def _read_by_layer(jaxpr, var) -> bool:
+    """Whether ``jaxpr`` reads ``var`` ONLY as slices of extent 1 along
+    axis 0 at static indices (what ``a[index]`` with a Python int traces
+    to), looking into a plain ``jit`` equation's body the same way. Any
+    other reader (a ``scan`` over the stack, a ``dynamic_slice`` under a
+    loop's counter, a kernel handed the whole stack) says no, and so
+    does a stack nothing reads."""
+    if any(out is var for out in jaxpr.outvars):
+        return False
+    read = False
+    for eqn in jaxpr.eqns:
+        for at, operand in enumerate(eqn.invars):
+            if operand is not var:
+                continue
+            read = True
+            if eqn.primitive.name == "slice":
+                if (eqn.params["limit_indices"][0]
+                        - eqn.params["start_indices"][0]) != 1:
+                    return False
+            elif eqn.primitive.name == "jit":
+                body = eqn.params["jaxpr"].jaxpr
+                if not _read_by_layer(body, body.invars[at]):
+                    return False
+            else:
+                return False
+    return read
 
 
 def _is_order(x) -> bool:
     return isinstance(x, tuple)
 
 
-def chosen_orders(params, executable):
+def chosen_orders(params, executable, jaxpr=None):
     """What ``executable`` (``compile_decode_for_layouts``) asks to be
     moved, as a tree like ``params``: for a leaf the program reads in
     another order of dimensions than the leaf lies in, that order
@@ -484,8 +557,16 @@ def chosen_orders(params, executable):
     Orders are compared with dimensions of 1 left out (they lie
     anywhere) and tilings apart (a few small vectors). A leaf asked for
     in the row-major order stays too: nothing a transposition stores
-    differs from what the device already keeps."""
-    def asked(leaf, chosen):
+    differs from what the device already keeps.
+
+    With the program's ``jaxpr`` (its first operands the leaves of
+    ``params``), a leaf that is asked for AND that the program reads
+    only one static layer at a time (``_read_by_layer``) gets its order
+    as ``ByLayer``. A leaf that is not moved anyway stays whole (no byte
+    is added, and the compiler slices a stack it reads as it lies
+    inside the matmul's own fusion), and so does one that lies across
+    several devices."""
+    def asked(leaf, chosen, var):
         if chosen.layout is None:       # a leaf the program does not read
             return ()
         own = leaf.format.layout
@@ -498,9 +579,18 @@ def chosen_orders(params, executable):
 
         if lies(order) == lies(own) or lies(order) == sorted(lies(order)):
             return ()
+        if (var is not None and len(leaf.sharding.device_set) == 1
+                and _read_by_layer(jaxpr.jaxpr, var)):
+            return ByLayer(order)
         return order
 
-    orders = jax.tree.map(asked, params, executable.input_formats[0][0])
+    leaves, tree = jax.tree.flatten(params)
+    reads = ([None] * len(leaves) if jaxpr is None
+             else jaxpr.jaxpr.invars[:len(leaves)])
+    orders = tree.unflatten([
+        asked(leaf, chosen, var) for leaf, chosen, var in zip(
+            leaves, tree.flatten_up_to(executable.input_formats[0][0]),
+            reads)])
     return orders if any(jax.tree.leaves(orders, is_leaf=_is_order)) else None
 
 
@@ -550,9 +640,11 @@ def load_orders(key: Optional[str], params) -> Tuple[bool, Any]:
         return False, None
     try:
         with open(path) as f:
-            moved = {leaf: tuple(order)
-                     for leaf, order in json.load(f)["moved"].items()}
-    except (OSError, ValueError, KeyError, AttributeError):
+            kept = json.load(f)
+        by_layer = set(kept["by_layer"])
+        moved = {leaf: (ByLayer if leaf in by_layer else tuple)(order)
+                 for leaf, order in kept["moved"].items()}
+    except (OSError, ValueError, KeyError, TypeError, AttributeError):
         return False, None
     if not moved:
         return True, None
@@ -562,7 +654,9 @@ def load_orders(key: Optional[str], params) -> Tuple[bool, Any]:
 
 def store_orders(key: Optional[str], orders) -> None:
     """Keep ``orders`` under ``key`` for the processes that come after
-    (written beside and renamed: a reader sees a whole file or none)."""
+    (written beside and renamed: a reader sees a whole file or none):
+    ``moved`` the order of each leaf that has one, ``by_layer`` those of
+    them that are stored as their layers."""
     path = _orders_file(key)
     if path is None:
         return
@@ -573,18 +667,24 @@ def store_orders(key: Optional[str], orders) -> None:
     os.makedirs(os.path.dirname(path), exist_ok=True)
     scratch = f"{path}.{os.getpid()}"
     with open(scratch, "w") as f:
-        json.dump({"moved": moved}, f)
+        json.dump({"moved": moved, "by_layer": sorted(
+            leaf for leaf, order in moved.items()
+            if isinstance(order, ByLayer))}, f)
     os.replace(scratch, path)
 
 
-def place_params(params, orders) -> Tuple[Any, int, int]:
+def place_params(params, orders) -> Tuple[Any, dict]:
     """``params`` as the decode program reads them (``chosen_orders``):
     a leaf that is asked for in another order of dimensions is stored
     ONCE, transposed into that order, as a new array in the device's
-    default layout, which is the chosen layout of the leaf's own shape;
-    every other leaf is handed on as it is. The caller's arrays are not
-    donated and stay as they were. Returns the placed tree, the leaves
-    moved and their bytes.
+    default layout, which is the chosen layout of the leaf's own shape
+    (``ByLayer``: as a tuple of such arrays, one a layer, the same
+    bytes); every other leaf is handed on as it is. The caller's arrays
+    are not donated and stay as they were. Returns the placed tree and
+    what moved, under the engine's counters' names:
+    ``params_relaid_leaves`` / ``_bytes`` (every leaf stored anew) and
+    ``params_layered_leaves`` / ``_bytes`` (those of them stored as
+    their layers).
 
     The steps built with the same ``orders`` (``param_orders``) read the
     placed tree through ``in_model_order``, a transposition back that
@@ -596,30 +696,46 @@ def place_params(params, orders) -> Tuple[Any, int, int]:
     back from the persistent compile cache as the default-layout
     program on the v5e and read the re-laid weights as garbage
     (PERF.md, PR 48): every program that runs has default layouts."""
-    if orders is None:
-        return params, 0, 0
-    moved = []
+    relaid, layered = [], []
 
     def place(order, leaf):
         if not order:
             return leaf
-        moved.append(leaf.nbytes)
-        return jnp.transpose(leaf, order)
+        relaid.append(leaf.nbytes)
+        if not isinstance(order, ByLayer):
+            return jnp.transpose(leaf, order)
+        layered.append(leaf.nbytes)
+        return tuple(jnp.transpose(leaf[index], order.of_a_layer)
+                     for index in range(leaf.shape[0]))
 
-    placed = jax.tree.map(place, orders, params, is_leaf=_is_order)
-    return placed, len(moved), sum(moved)
+    placed = params if orders is None else jax.tree.map(
+        place, orders, params, is_leaf=_is_order)
+    return placed, {
+        "params_relaid_leaves": len(relaid),
+        "params_relaid_bytes": sum(relaid),
+        "params_layered_leaves": len(layered),
+        "params_layered_bytes": sum(layered)}
 
 
 def in_model_order(params, orders):
     """Inside a step: the placed tree (``place_params``) as the model's
     forward indexes it. A transposition of a program's parameter, which
-    the compiler turns into the layout of what reads it: no copy."""
+    the compiler turns into the layout of what reads it: no copy. A
+    stack stored as its layers comes as ``Layers``, whose ``[index]`` is
+    one of them transposed back: no slice of a stack is in the
+    program."""
     if orders is None:
         return params
-    return jax.tree.map(
-        lambda order, leaf: (jnp.transpose(leaf, np.argsort(order))
-                             if order else leaf),
-        orders, params, is_leaf=_is_order)
+
+    def back(order, leaf):
+        if not order:
+            return leaf
+        if not isinstance(order, ByLayer):
+            return jnp.transpose(leaf, np.argsort(order))
+        return Layers(jnp.transpose(layer, np.argsort(order.of_a_layer))
+                      for layer in leaf)
+
+    return jax.tree.map(back, orders, params, is_leaf=_is_order)
 
 
 def teacher_forced_decode_paged(

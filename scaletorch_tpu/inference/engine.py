@@ -59,7 +59,10 @@ TPU execution discipline:
     (``decode.place_params``; the caller's arrays are not donated and
     stay as they were), and ``engine.params`` is the placed tree, which
     both jitted steps are built to read (``params_relaid_leaves`` /
-    ``params_relaid_bytes`` in the snapshot; 0 / 0 on a CPU). A new
+    ``params_relaid_bytes`` in the snapshot; 0 / 0 on a CPU). Such a
+    leaf that the decode program reads only one static layer at a time
+    is stored as its layers, each a program parameter of its own
+    (``params_layered_leaves`` / ``params_layered_bytes``). A new
     family needs nothing for it.
 
 Serving-grade fault tolerance (inference/resilience.py) rides the same
@@ -320,6 +323,13 @@ class EngineMetrics:
     # where the program reads them as they lie (every CPU)
     params_relaid_leaves: int = 0
     params_relaid_bytes: int = 0
+    # those of them that are stacks the decode program reads ONLY one
+    # static layer at a time (an unrolled forward's ``a[index]``),
+    # stored as their layers and not as one stack, out of which the
+    # program copied each layer every step; 0 / 0 where every stack is
+    # scanned
+    params_layered_leaves: int = 0
+    params_layered_bytes: int = 0
     outcomes: Dict[str, int] = field(
         default_factory=lambda: {o: 0 for o in TERMINAL_OUTCOMES})
     # request-scoped latency distributions (telemetry/histogram.py):
@@ -422,6 +432,8 @@ class EngineMetrics:
             "paged_pool_in_place": self.paged_pool_in_place,
             "params_relaid_leaves": self.params_relaid_leaves,
             "params_relaid_bytes": self.params_relaid_bytes,
+            "params_layered_leaves": self.params_layered_leaves,
+            "params_layered_bytes": self.params_layered_bytes,
         }
         for outcome, count in self.outcomes.items():
             snap[f"requests_{outcome}"] = count
@@ -544,7 +556,8 @@ class InferenceEngine:
         feeds this directly). ``engine.params`` is that tree as the
         decode program reads it: a leaf the compiler asks for in
         another order of dimensions is a new array, TRANSPOSED into
-        that order (only the engine's own steps read such a tree),
+        that order, or a tuple of such arrays, one a layer (only the
+        engine's own steps read such a tree),
         every other leaf is the caller's own array, and nothing of the
         caller's is donated or changed (``_param_orders``).
     max_slots : decode batch size B (fixed).
@@ -806,8 +819,7 @@ class InferenceEngine:
             else prefill_shapes(max_slots, self.prefill_len))
         self._decode = make_paged_decode_step(cfg, sampling, **steps)
         orders = self._param_orders(params, steps)
-        self.params, relaid_leaves, relaid_bytes = place_params(
-            params, orders)
+        self.params, moved = place_params(params, orders)
         if orders is not None:
             self._decode = make_paged_decode_step(
                 cfg, sampling, param_orders=orders, **steps)
@@ -816,8 +828,13 @@ class InferenceEngine:
         logger.info(
             "inference engine: params_relaid_leaves %d, "
             "params_relaid_bytes %d (%.1f MiB): what the decode program "
-            "reads in another order of dimensions, stored so once",
-            relaid_leaves, relaid_bytes, relaid_bytes / 2**20)
+            "reads in another order of dimensions, stored so once; "
+            "params_layered_leaves %d, params_layered_bytes %d of them "
+            "stacks it reads a static layer at a time, stored as their "
+            "layers",
+            moved["params_relaid_leaves"], moved["params_relaid_bytes"],
+            moved["params_relaid_bytes"] / 2**20,
+            moved["params_layered_leaves"], moved["params_layered_bytes"])
         if counts:
             routing = RoutingCounters(
                 cfg.num_experts, len(cfg.sparse_layer_ids()),
@@ -858,8 +875,7 @@ class InferenceEngine:
         self.metrics = EngineMetrics(
             num_slots=max_slots, routing=routing,
             paged_pool_in_place=int(in_place_pair(self.cache.k.shape[-1])),
-            params_relaid_leaves=relaid_leaves,
-            params_relaid_bytes=relaid_bytes,
+            **moved,
             recurrent_state_bytes=recurrent_state_bytes(self.cache),
             window_cache_bytes=window_cache_bytes(self.cache),
             latent_cache_bytes=latent_cache_bytes(self.cache))
@@ -918,7 +934,7 @@ class InferenceEngine:
             jax.tree.map(lambda x: x.format, params), operands)
         found, orders = load_orders(key, params)
         if not found:
-            orders = chosen_orders(params, compile_decode_for_layouts(
+            orders = chosen_orders(params, *compile_decode_for_layouts(
                 self._decode, params, operands,
                 donate_cache=steps["donate_cache"]))
             store_orders(key, orders)

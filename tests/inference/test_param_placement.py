@@ -12,6 +12,12 @@ to read it (one decode program, one prefill program a shape), and the
 tokens are those of the bare jitted steps and of the teacher-forced
 paged harness on the caller's own arrays. What the v5e's compiler asks
 for at the cells' shapes is in ``tests/test_paged_kernel_aot.py``.
+
+A stack that is asked for AND that the program reads only one static
+layer at a time is stored as its layers (PERF.md, PR 60): the rule reads
+the traced program, so toy steps that index, scan and mix the two say
+which qualify, and a tiny ``mimo_v2_flash`` engine (an unrolled forward)
+serves from such a tree.
 """
 
 import functools
@@ -26,13 +32,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from scaletorch_tpu.inference import InferenceEngine, SamplingParams
 from scaletorch_tpu.inference import engine as engine_module
 from scaletorch_tpu.inference.decode import (
+    ByLayer,
     abstract,
     chosen_orders,
     compile_decode_for_layouts,
+    in_model_order,
+    load_orders,
     make_paged_decode_step,
     make_paged_prefill_step,
     place_params,
     resolve_forward_cached,
+    store_orders,
     teacher_forced_decode_paged,
 )
 from scaletorch_tpu.inference.kv_cache import (
@@ -40,7 +50,9 @@ from scaletorch_tpu.inference.kv_cache import (
     init_paged_kv_cache,
 )
 from scaletorch_tpu.models import llama
+from scaletorch_tpu.models import mimo_v2_flash as mimo
 from tests.inference.compiled import compiled_forward_cached
+from tests.models.test_mimo_v2_flash import tiny_config as tiny_mimo_config
 from tests.models.test_olmo_hybrid import seeded_params, tiny_config
 
 GREEDY = SamplingParams(temperature=0.0)
@@ -77,6 +89,13 @@ class StandIn:
             (jax.tree_util.tree_map_with_path(ask, params), *rest), kwargs)
 
 
+def stand_in(program, moved):
+    """``compile_decode_for_layouts``' answer with the stand-in for its
+    executable and the program's own jaxpr."""
+    executable, jaxpr = program
+    return StandIn(executable, moved), jaxpr
+
+
 def forward_fn(*args, **kwargs):
     """A ``forward_fn`` in the llama forward's place."""
     return llama.forward_cached(*args, **kwargs)
@@ -107,7 +126,7 @@ def moving(monkeypatch):
     real = engine_module.compile_decode_for_layouts
     monkeypatch.setattr(
         engine_module, "compile_decode_for_layouts",
-        lambda *a, **kw: StandIn(real(*a, **kw), MOVED))
+        lambda *a, **kw: stand_in(real(*a, **kw), MOVED))
     # whatever an earlier process of this checkout kept is not asked
     monkeypatch.setattr(engine_module, "load_orders",
                         lambda key, params: (False, None))
@@ -183,7 +202,7 @@ PROMPTS = [([5, 9, 2, 41, 7], 9), ([11, 3, 3, 60, 1, 8, 22, 4, 13], 7),
 
 # ---- the rule -----------------------------------------------------------------
 
-def decode_executable(model):
+def decode_program(model):
     cfg, params, fwd = model
     engine = make_engine(model)
     step = make_paged_decode_step(
@@ -201,10 +220,10 @@ def decode_executable(model):
 def test_on_a_cpu_the_compiler_asks_for_nothing(model):
     """Every leaf is handed on by identity: 0 leaves, 0 bytes."""
     _, params, _ = model
-    orders = chosen_orders(params, decode_executable(model))
+    orders = chosen_orders(params, *decode_program(model))
     assert orders is None
-    placed, leaves, moved_bytes = place_params(params, orders)
-    assert (leaves, moved_bytes) == (0, 0)
+    placed, moved = place_params(params, orders)
+    assert set(moved.values()) == {0}
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(placed)):
         assert a is b
 
@@ -217,10 +236,13 @@ def test_the_rule_moves_the_leaf_the_executable_names_and_no_other(dense):
     _, params, _ = dense
     before = jax.tree.map(np.asarray, params)
     orders = chosen_orders(
-        params, StandIn(decode_executable(dense), ("['q_proj']",)))
-    placed, leaves, moved_bytes = place_params(params, orders)
+        params, *stand_in(decode_program(dense), ("['q_proj']",)))
+    placed, moved = place_params(params, orders)
     q = params["layers"]["q_proj"]
-    assert (leaves, moved_bytes) == (1, q.nbytes)
+    # the llama forward scans its layers: a stack stays a stack
+    assert moved == {
+        "params_relaid_leaves": 1, "params_relaid_bytes": q.nbytes,
+        "params_layered_leaves": 0, "params_layered_bytes": 0}
     assert orders["layers"]["q_proj"] == (0, 2, 1)
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
     for (path, leaf), new in zip(flat, jax.tree.leaves(placed)):
@@ -262,6 +284,219 @@ def test_what_counts_as_asked_for(shape, own, asked, want):
         sharding=Format(Layout(major_to_minor=own), sharding))
     orders = chosen_orders({"w": leaf}, Executable)
     assert (orders or {"w": ()})["w"] == want
+
+
+# ---- a stack stored as its layers ----------------------------------------------
+
+def _matmuls(x, layers):
+    for w in layers:
+        x = jnp.tanh(x @ w)
+    return x
+
+
+def _unrolled(params, x):
+    return _matmuls(x, (params["w"][i] for i in range(3)))
+
+
+def _unrolled_in_a_jit(params, x):
+    return jax.jit(_unrolled)(params, x)
+
+
+def _counted_back(params, x):
+    """A negative index traces to a ``dynamic_slice`` of a computed
+    start."""
+    return _matmuls(x, (params["w"][-1 - i] for i in range(3)))
+
+
+def _scanned(params, x):
+    return jax.lax.scan(
+        lambda x, w: (jnp.tanh(x @ w), None), x, params["w"])[0]
+
+
+def _mixed(params, x):
+    """A layer by a static index and one under a traced index (a period
+    loop's counter, ``layer_of``)."""
+    at = jnp.argmax(x[0, :3])
+    return (x @ params["w"][0]) @ jax.lax.dynamic_index_in_dim(
+        params["w"], at, 0, keepdims=False)
+
+
+def _under_remat(params, x):
+    return jax.checkpoint(_unrolled)(params, x)
+
+
+def _two_layers_at_once(params, x):
+    return _matmuls(x, params["w"][0:2])
+
+
+def _handed_on(params, x):
+    return _unrolled(params, x), params["w"]
+
+
+TOY = {"w": jnp.arange(3 * 8 * 8, dtype=jnp.float32).reshape(3, 8, 8) / 200,
+       "unasked": jnp.ones((3, 8, 8))}
+
+
+def toy_orders(step, asked=("['w']",)):
+    program = compile_decode_for_layouts(
+        jax.jit(step), TOY, (jax.ShapeDtypeStruct((2, 8), jnp.float32),))
+    return chosen_orders(TOY, *stand_in(program, asked))
+
+
+@pytest.mark.parametrize("step,layered", [
+    (_unrolled, True), (_unrolled_in_a_jit, True), (_counted_back, False),
+    (_scanned, False), (_mixed, False), (_under_remat, False),
+    (_two_layers_at_once, False), (_handed_on, False),
+], ids=lambda x: getattr(x, "__name__", None))
+def test_a_stack_read_only_one_static_layer_at_a_time_is_layered(
+        step, layered):
+    """The program says so, not the family: every equation that reads
+    the leaf is a ``slice`` of extent 1 along axis 0 at a static index,
+    through plain ``jit`` equations. A scan over the stack, a traced
+    or computed index anywhere, a ``remat`` around the readers, two
+    layers in one slice or the stack handed on whole keep it a stack,
+    moved as it was."""
+    orders = toy_orders(step)
+    assert orders == {"w": (0, 2, 1), "unasked": ()}
+    assert isinstance(orders["w"], ByLayer) == layered
+    assert ByLayer((0, 2, 1)).of_a_layer == (1, 0)
+    assert ByLayer((1, 0, 3, 2)).of_a_layer == (0, 2, 1)
+
+
+def test_a_stack_that_is_not_moved_anyway_stays_whole():
+    """(a): the rule adds no byte. What the program reads as it lies is
+    handed on by identity however it is indexed."""
+    def step(params, x):
+        return _scanned({"w": params["unasked"]}, _unrolled(params, x))
+
+    assert toy_orders(step, asked=()) is None
+    orders = toy_orders(step, asked=("['unasked']",))
+    assert orders == {"w": (), "unasked": (0, 2, 1)}
+    assert not isinstance(orders["unasked"], ByLayer)
+
+
+def test_a_leaf_across_several_devices_stays_whole():
+    """A mesh engine's stack is moved shard by shard as it was at PR 48
+    and not cut into layers."""
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    sharded = {**TOY, "w": jax.device_put(
+        TOY["w"], NamedSharding(mesh, P(None, None, "tp")))}
+    program = compile_decode_for_layouts(
+        jax.jit(_unrolled), sharded,
+        (jax.ShapeDtypeStruct((2, 8), jnp.float32),))
+    orders = chosen_orders(sharded, *stand_in(program, ("['w']",)))
+    assert orders["w"] == (0, 2, 1) and not isinstance(orders["w"], ByLayer)
+
+
+def test_the_layers_come_back_bit_for_bit_and_the_counters_say_so():
+    """``place_params`` stores ``shape[0]`` arrays, each the layer in the
+    asked order; ``in_model_order`` hands the forward something whose
+    ``[index]`` is the layer as the model laid it, and that still reads
+    as the stack where a program wants that."""
+    orders = toy_orders(_unrolled)
+    before = np.asarray(TOY["w"])
+    placed, moved = place_params(TOY, orders)
+    assert placed["unasked"] is TOY["unasked"]
+    assert isinstance(placed["w"], tuple) and len(placed["w"]) == 3
+    for index, layer in enumerate(placed["w"]):
+        np.testing.assert_array_equal(np.asarray(layer), before[index].T)
+    assert moved == {
+        "params_relaid_leaves": 1, "params_relaid_bytes": before.nbytes,
+        "params_layered_leaves": 1, "params_layered_bytes": before.nbytes}
+    back = in_model_order(placed, orders)
+    assert back["unasked"] is TOY["unasked"]
+    assert back["w"].shape == before.shape and back["w"].dtype == before.dtype
+    for index in range(-3, 3):
+        np.testing.assert_array_equal(np.asarray(back["w"][index]),
+                                      before[index])
+    np.testing.assert_array_equal(np.asarray(back["w"][1:]), before[1:])
+    np.testing.assert_array_equal(np.asarray(jnp.asarray(back["w"])), before)
+    np.testing.assert_array_equal(np.asarray(TOY["w"]), before)
+    x = jnp.ones((2, 8))
+    for step in (_unrolled, _two_layers_at_once):
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(lambda tree, x: step(
+                in_model_order(tree, orders), x))(placed, x)),
+            np.asarray(jax.jit(step)(TOY, x)))
+
+
+def test_the_kept_answer_says_which_leaves_go_by_layer(
+        monkeypatch, tmp_path):
+    """``store_orders`` -> ``load_orders``: an order comes back as the
+    type it went in as; a file that cannot be read, or one of the form
+    kept before PR 60 (no ``by_layer``), counts as none."""
+    from scaletorch_tpu import env
+
+    monkeypatch.setattr(env, "compile_cache_dir", lambda: str(tmp_path))
+    orders = {"w": ByLayer((0, 2, 1)), "unasked": (0, 2, 1),
+              "norm": ()}
+    tree = {"w": 0, "unasked": 0, "norm": 0}
+    store_orders("a-key", orders)
+    found, kept = load_orders("a-key", tree)
+    assert found and kept == orders
+    assert type(kept["w"]) is ByLayer and type(kept["unasked"]) is tuple
+    store_orders("nothing-moves", None)
+    assert load_orders("nothing-moves", tree) == (True, None)
+    assert load_orders("no-such-key", tree) == (False, None)
+    for text in ("not json", '{"moved": {"[\'w\']": [0, 2, 1]}}',
+                 '{"moved": {}, "by_layer": 3}'):
+        (tmp_path / "param_orders-a-key.json").write_text(text)
+        assert load_orders("a-key", tree) == (False, None)
+
+
+MIMO_MOVED = ("['q_proj']", "['k_proj']", "['v_proj']")
+
+
+@pytest.fixture(scope="module")
+def tiny_mimo():
+    cfg = tiny_mimo_config()
+    return cfg, jax.jit(mimo.init_params, static_argnums=1)(
+        jax.random.PRNGKey(3), cfg), None
+
+
+def test_an_unrolled_family_serves_from_its_layers(monkeypatch, tiny_mimo):
+    """A tiny ``mimo_v2_flash`` engine whose compiler (the stand-in)
+    asks for the attention projections contraction-minor, as the v5e's
+    does: ``q_proj`` and both kinds' ``k_proj`` / ``v_proj`` are tuples
+    of layers in ``engine.params``, the one-row prefill program and the
+    decode program read them, and the tokens are those of the
+    teacher-forced paged harness on the caller's own arrays, which are
+    as they were afterwards."""
+    cfg, params, _ = tiny_mimo
+    before = jax.tree.map(np.asarray, params)
+    real = engine_module.compile_decode_for_layouts
+    monkeypatch.setattr(
+        engine_module, "compile_decode_for_layouts",
+        lambda *a, **kw: stand_in(real(*a, **kw), MIMO_MOVED))
+    monkeypatch.setattr(engine_module, "load_orders",
+                        lambda key, params: (False, None))
+    monkeypatch.setattr(engine_module, "store_orders", lambda *a: None)
+    engine = make_engine(tiny_mimo)
+    layers, placed = params["layers"], engine.params["layers"]
+    layered = [(layers[part][name], placed[part][name])
+               for part, name in (("block", "q_proj"), ("full", "k_proj"),
+                                  ("full", "v_proj"), ("window", "k_proj"),
+                                  ("window", "v_proj"))]
+    for own, stored in layered:
+        assert isinstance(stored, tuple) and len(stored) == own.shape[0]
+        assert stored[0].shape == own.shape[:0:-1]
+    assert placed["block"]["o_proj"] is layers["block"]["o_proj"]
+    snap = engine.metrics.snapshot()
+    nbytes = sum(own.nbytes for own, _ in layered)
+    assert (snap["params_layered_leaves"], snap["params_layered_bytes"],
+            snap["params_relaid_leaves"], snap["params_relaid_bytes"]
+            ) == (5, nbytes, 5, nbytes)
+    ids = [engine.submit(p, max_new_tokens=n) for p, n in PROMPTS]
+    results = engine.run()
+    assert engine.decode_compile_count == 1
+    assert engine.prefill_compile_count == len(engine.prefill_shapes) == 1
+    for (prompt, n), rid in zip(PROMPTS[:2], ids):
+        tokens = results[rid].tokens
+        assert len(tokens) == n
+        assert tokens == teacher_forced_greedy(tiny_mimo, prompt, tokens)
+    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(params)):
+        assert not b.is_deleted()
+        np.testing.assert_array_equal(a, np.asarray(b))
 
 
 # ---- an engine built through it -----------------------------------------------
@@ -330,6 +565,8 @@ def test_the_snapshot_says_what_was_moved(request, dense, placement, leaves):
     _, params, _ = dense
     snap = make_engine(dense).metrics.snapshot()
     assert snap["params_relaid_leaves"] == leaves
+    # the llama forward scans its layers: no stack is cut into them
+    assert snap["params_layered_leaves"] == snap["params_layered_bytes"] == 0
     assert snap["params_relaid_bytes"] == (leaves and sum(
         params["layers"][name].nbytes for name in ("q_proj", "o_proj")))
 
@@ -348,9 +585,9 @@ def test_the_answer_is_kept_for_the_next_process(
 
     def compile_for_layouts(*a, **kw):
         asked.append(1)
-        executable = real(*a, **kw)
-        return (StandIn(executable, MOVED) if placement == "two-leaves-moved"
-                else executable)
+        program = real(*a, **kw)
+        return (stand_in(program, MOVED) if placement == "two-leaves-moved"
+                else program)
 
     monkeypatch.setattr(engine_module, "compile_decode_for_layouts",
                         compile_for_layouts)

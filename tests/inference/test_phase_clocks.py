@@ -74,10 +74,14 @@ class DeviceClock:
     microsecond a reading, and a millisecond more whenever a phase that
     waits for the device ends (``waited``, in ``span``'s place). Host
     phases take microseconds and device waits milliseconds, as on a
-    chip, whatever else the machine is doing."""
+    chip, whatever else the machine is doing. It starts at the real
+    clock's reading: the engine's cumulative clocks were opened on that
+    one, and a jump at the hand-over (to 5000.0, until PR 60) went into
+    them as a term of the host's uptime, whose rounding (1e-9 once
+    ``time.monotonic()`` passed 2**18 s) the partition then carried."""
 
-    def __init__(self, start=5000.0):
-        self.t = start
+    def __init__(self):
+        self.t = time.monotonic()
 
     def __call__(self):
         self.t += 1e-6

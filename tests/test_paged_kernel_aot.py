@@ -238,7 +238,7 @@ def _serving_steps(one_chip, cfg, init, *, slots, max_seq, prefill_len,
             params,
             jax.jit(lambda tree: tree).lower(params).compile(
             ).input_formats[0][0])
-        _CHOSEN[key] = chosen_orders(lying, compile_decode_for_layouts(
+        _CHOSEN[key] = chosen_orders(lying, *compile_decode_for_layouts(
             make_paged_decode_step(cfg, sampling, **build), lying,
             operands(slots), donate_cache=True))
     orders = _CHOSEN[key]
@@ -344,37 +344,130 @@ def test_no_step_program_moves_the_pool(serving_programs):
 _COPY = re.compile(
     r"= bf16\[(?P<dims>[\d,]+)\](?P<layout>\S*) copy\(%(?P<operand>[^\s,)]+)")
 _RESULT = re.compile(r"^\s*(?:ROOT )?%(?P<name>\S+) = \w+\[[\d,]*\](?P<layout>\S*) ")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>\S+) \(.*\) -> .* \{$")
+_ARRAY_OF = re.compile(r"\w+\[(?P<dims>[\d,]*)\](?P<layout>\{[^}]*\})?")
+# what moves an array and computes nothing
+_MOVES = {"parameter", "slice", "dynamic-slice", "bitcast", "copy",
+          "transpose", "reshape", "tuple", "get-tuple-element"}
+_WEIGHT_SIZED = 2 ** 20
+
+
+def _arrays(hlo_type):
+    """(dtype[dims] text, dims, layout) of each array of an
+    instruction's result type, a tuple's elements in turn."""
+    return [(m[0], [int(d) for d in m["dims"].split(",") if d],
+             m["layout"] or "") for m in _ARRAY_OF.finditer(hlo_type)]
 
 
 def _weight_copies(text, params):
-    """The ``copy`` instructions of a compiled program that RE-LAY a
-    weight: the result a bf16 array of 1 Mi elements or more with a
-    weight's shape (a leaf of ``params`` whole, or its trailing
-    dimensions: a layer of a stack, a period's slice; in any order, a
-    copy being how a matrix is turned contraction-minor; dimensions of 1
-    apart), in another layout than its operand has. A copy that keeps
-    the layout and changes the memory space (``S(1)``: XLA fetching a
-    stack into fast memory ahead of its use) re-lays nothing."""
+    """The instructions of a compiled program that copy a weight from
+    HBM to HBM, each a line's first 120 characters.
+
+    A ``copy`` that RE-LAYS a weight: the result a bf16 array of 1 Mi
+    elements or more with a weight's shape (a leaf of ``params`` whole,
+    or its trailing dimensions: a layer of a stack, a period's slice; in
+    any order, a copy being how a matrix is turned contraction-minor;
+    dimensions of 1 apart), in another layout than its operand has. A
+    copy that keeps the layout and changes the memory space (``S(1)``:
+    XLA fetching a stack into fast memory ahead of its use) re-lays
+    nothing.
+
+    A SLICE of a weight materialised (PR 60: MiMo-V2-Flash's decode
+    program copied 0.81 GB of layers out of its re-laid stacks every
+    step, ``fusion.519``): a ``slice`` / ``dynamic-slice`` that stands
+    on its own in the program, or a loop fusion all of whose weight-sized
+    instructions move and compute nothing, with such a result, or such
+    an element of a tuple result, NOT in fast memory: landing in
+    ``S(1)`` the slice is the weight's one reading, in HBM it is a copy
+    that the matmul reads once more. A slice INSIDE a fusion that
+    computes (the matmul that reads ``o_proj[index]``) is no
+    instruction of the program's own."""
     def key(dims):
         return tuple(sorted(d for d in dims if d != 1))
 
     def laid(layout):
         return re.sub(r"S\(\d+\)", "", layout)
 
+    def weight_sized(arrays):
+        return [(array, dims, layout) for array, dims, layout in arrays
+                if math.prod(dims) >= _WEIGHT_SIZED]
+
     weights = {key(leaf.shape[i:]) for leaf in jax.tree.leaves(params)
                for i in range(leaf.ndim)}
+    lines = text.splitlines()
     layouts = {m["name"]: laid(m["layout"])
-               for m in map(_RESULT.match, text.splitlines()) if m}
-    found = []
-    for line in text.splitlines():
+               for m in map(_RESULT.match, lines) if m}
+    # {computation: whether its weight-sized instructions only move},
+    # and the computations that are fusions' bodies
+    moves, inside, bodies = {}, None, set()
+    for line in lines:
+        header, m = _COMPUTATION.match(line), _INSTRUCTION.match(line)
+        if header:
+            inside = header["name"]
+            moves[inside] = True
+        elif m and weight_sized(_arrays(m["type"])):
+            moves[inside] = moves.get(inside, True) and m["op"] in _MOVES
+        bodies.update(re.findall(r"\bfusion\(.*calls=%([^\s,]+)", line))
+    found, inside = [], None
+    for line in lines:
+        header = _COMPUTATION.match(line)
+        if header:
+            inside = header["name"]
         m = _COPY.search(line)
-        if m is None:
+        if m is not None:
+            dims = [int(d) for d in m["dims"].split(",")]
+            if (math.prod(dims) >= _WEIGHT_SIZED and key(dims) in weights
+                    and laid(m["layout"]) != layouts.get(m["operand"])):
+                found.append(line.strip()[:120])
             continue
-        dims = [int(d) for d in m["dims"].split(",")]
-        if (math.prod(dims) >= 2 ** 20 and key(dims) in weights
-                and laid(m["layout"]) != layouts.get(m["operand"])):
+        m = _INSTRUCTION.match(line)
+        if m is None or inside in bodies:
+            continue
+        if m["op"] == "fusion":
+            body = re.search(r"calls=%([^\s,]+)", line)
+            if "kind=kLoop" not in line or not moves.get(
+                    body and body[1], True):
+                continue
+        elif m["op"] not in ("slice", "dynamic-slice"):
+            continue
+        if any(array.startswith("bf16[") and key(dims) in weights
+               and "S(1)" not in layout
+               for array, dims, layout in weight_sized(_arrays(m["type"]))):
             found.append(line.strip()[:120])
     return found
+
+
+_TILED = "{1,2,0:T(8,128)(2,1)}"
+_FAST = "{1,2,0:T(8,128)(2,1)S(1)}"
+# MiMo-V2-Flash's decode program at the parent of PR 60: seven static
+# slices of the re-laid ``q_proj`` stack in one loop fusion, six results
+# in HBM and one in fast memory (two and one here)
+_MIMO_Q_LAYERS = f"""
+%fused_computation.845 (param_0.2778: bf16[7,4096,12288]) -> (bf16[1,4096,12288], bf16[1,4096,12288], bf16[1,4096,12288]) {{
+  %param_0.2778 = bf16[7,4096,12288]{_TILED} parameter(0)
+  %slice.622 = bf16[1,4096,12288]{_TILED} slice(%param_0.2778), slice={{[2:3], [0:4096], [0:12288]}}
+  %slice.623 = bf16[1,4096,12288]{_FAST} slice(%param_0.2778), slice={{[1:2], [0:4096], [0:12288]}}
+  %slice.624 = bf16[1,4096,12288]{_TILED} slice(%param_0.2778), slice={{[0:1], [0:4096], [0:12288]}}
+  ROOT %tuple.105 = (bf16[1,4096,12288]{_TILED}, bf16[1,4096,12288]{_FAST}, bf16[1,4096,12288]{_TILED}) tuple(%slice.622, %slice.623, %slice.624)
+}}
+ENTRY %main.143 (p: bf16[7,4096,12288]) -> bf16[32,19072] {{
+  %fusion.519 = (bf16[1,4096,12288]{_TILED}, bf16[1,4096,12288]{_FAST}, bf16[1,4096,12288]{_TILED}) fusion(%bitcast.6), kind=kLoop, calls=%fused_computation.845, metadata={{op_name="jit(decode)/slice" stack_frame_id=53}}
+}}"""
+# a layer of ``o_proj`` sliced inside the fusion of the matmul that
+# reads it, and a residual sum with a weight's shape: nothing is copied
+_NO_COPIES = f"""
+%fused_computation.7 (param_0.1: bf16[28,2048,2048], param_1.1: bf16[16,2048]) -> bf16[16,2048] {{
+  %param_0.1 = bf16[28,2048,2048]{_TILED} parameter(0)
+  %slice.583 = bf16[1,2048,2048]{_TILED} slice(%param_0.1), slice={{[3:4], [0:2048], [0:2048]}}
+  ROOT %convolution.1 = bf16[16,2048]{{1,0:T(8,128)(2,1)}} convolution(%param_1.1, %slice.583), dim_labels=bf_io->bf
+}}
+%fused_computation.8 (param_0.2: bf16[1,2048,4096], param_1.2: bf16[1,2048,4096]) -> bf16[1,2048,4096] {{
+  ROOT %add.1 = bf16[1,2048,4096]{_TILED} add(%param_0.2, %param_1.2)
+}}
+ENTRY %main.9 (p: bf16[28,2048,2048]) -> bf16[16,2048] {{
+  %fusion.7 = bf16[16,2048]{{1,0:T(8,128)(2,1)}} fusion(%p.1, %x.1), kind=kOutput, calls=%fused_computation.7
+  %fusion.8 = bf16[1,2048,4096]{_TILED} fusion(%x.2, %x.3), kind=kLoop, calls=%fused_computation.8
+}}"""
 
 
 @pytest.mark.parametrize("line,found", [
@@ -388,12 +481,30 @@ def _weight_copies(text, params):
     # jamba's x_proj stack, fetched into fast memory as it lies (the
     # parent does it too): a move, not a re-laying
     ("  %copy.95 = bf16[16,2048,4096]{2,1,0:T(8,128)(2,1)S(1)} copy(%p.1)", 0),
+    # slices of a weight materialised (ISSUE 60): MiMo's seven layers of
+    # ``q_proj`` a step; the one of them that lands in fast memory, alone;
+    # Qwen3-1.7B's layer of ``o_proj`` under the loop's counter, the
+    # weight's one reading; a slice on its own into HBM; what computes
+    (_MIMO_Q_LAYERS, 1),
+    (f"  %fusion.9 = bf16[1,4096,12288]{_FAST} fusion(%bitcast.6), "
+     "kind=kLoop, calls=%fused_computation.9", 0),
+    (f"  %constant_dynamic-slice_fusion.11 = bf16[1,2048,2048]{_FAST} "
+     "fusion(%get-tuple-element.766, %get-tuple-element.728), kind=kLoop, "
+     "calls=%fused_computation.69.clone.clone.clone", 0),
+    (f"  %dynamic-slice.4 = bf16[1,2048,4096]{_TILED} dynamic-slice(%p.1, "
+     "%i.1, %c.0, %c.0), dynamic_slice_sizes={1,2048,4096}", 1),
+    (f"  %slice.5 = bf16[1,2048,128]{_TILED} slice(%p.2), "
+     "slice={[3:4], [0:2048], [0:128]}", 0),
+    (_NO_COPIES, 0),
 ], ids=["a-stack", "a-layer", "a-layer-transposed", "no-weight", "small",
-        "as-it-lies"])
+        "as-it-lies", "mimo-q-proj-layers", "a-layer-into-fast-memory",
+        "qwen3-o-proj-into-fast-memory", "a-slice-on-its-own",
+        "a-small-slice", "inside-a-matmul-and-a-sum"])
 def test_the_guard_finds_the_copies_the_parent_made(line, found):
     weights = {"q_proj": jax.ShapeDtypeStruct((16, 2048, 4096), jnp.bfloat16),
                "o_proj": jax.ShapeDtypeStruct((28, 2048, 2048), jnp.bfloat16),
-               "norm": jax.ShapeDtypeStruct((28, 2048, 128), jnp.bfloat16)}
+               "norm": jax.ShapeDtypeStruct((28, 2048, 128), jnp.bfloat16),
+               "mimo": jax.ShapeDtypeStruct((7, 4096, 12288), jnp.bfloat16)}
     text = "\n".join([
         "  %p.1 = bf16[16,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)",
         "  %f.2 = bf16[1,2048,2048]{2,1,0:T(8,128)(2,1)S(1)} fusion(%p.1)",
@@ -410,19 +521,60 @@ def test_the_guard_finds_the_copies_the_parent_made(line, found):
 # contraction-minor where the decode program, which the rule asks, reads
 # it as it lies (0.68 GB, ~1.7 ms of a 1.2 s call). The hybrid's gates'
 # projections ``[4,3,3840,30]`` are stored ``[4,3,30,3840]`` as asked and
-# tiled again for the matmul (2.7 MB each)
+# tiled again for the matmul (2.7 MB each).
+#
+# What the search for materialised SLICES (PR 60) finds besides, none of
+# it a stack the rule may cut into layers (``decode.chosen_orders``: a
+# leaf the decode program re-lays AND reads only at static indices).
+# A layer picked under a loop's counter (``dynamic-slice``: the index is
+# traced, so the layer is no parameter of its own) that is too large for
+# fast memory and lands in HBM: Trinity-Mini's period loop, three
+# ``q_proj`` / ``k_proj`` / ``v_proj`` layers an iteration
+# (``constant_dynamic-slice_fusion.46`` - ``.48``; 0.156 s of a 7.95 s
+# window: ledger, PR 59), and the unrolled last period's reading of the
+# same stacks (``fusion.1064`` - ``.1066``: static, but the loop reads
+# the stacks too, and the rule says ONLY); in PREFILL programs, which
+# the rule does not ask, jamba's ``[1,1,2560,2560]`` (13 MB), qwen3-next's
+# attention projections (38 MB a full layer) and openPangu's ``q_b_proj``
+# ``[1,1536,24576]`` / ``kv_b_proj`` (75 + 34 MB a layer, and the
+# ``kv_b_proj`` stack re-laid whole, 201 MB, in a 1.2 s call).
+# Kimi-Linear indexes its unrolled layers with
+# ``lax.dynamic_index_in_dim`` at a Python int (``afmoe._layer_of``),
+# which traces to a ``dynamic_slice`` and not to a ``slice``: its latent
+# layers' ``q_proj`` ``[1,2304,6144]`` (28 MB) and a ``kv_b_proj`` layer
+# (8 MB) are copied out of their re-laid stacks in both programs, ~0.05
+# ms of an 11.1 ms step (PERF.md section 7: the next of this kind)
 _STILL_RE_LAID = {
     ("jamba2-3b-serve", "decode"): ["bf16[2,13,5120,192]"],
-    ("jamba2-3b-serve", "prefill"): ["bf16[2,13,5120,2560]"],
+    ("jamba2-3b-serve", "prefill"): ["bf16[1,1,2560,2560]",
+                                     "bf16[2,13,5120,2560]"],
     ("olmo-hybrid-7b-serve", "decode"): ["bf16[4,3,30,3840]"] * 2,
     ("olmo-hybrid-7b-serve", "prefill"): ["bf16[4,3,30,3840]"] * 2,
+    ("qwen3-next-80b-a3b-serve", "prefill"): [
+        "bf16[1,1,2048,512]", "bf16[1,1,2048,512]", "bf16[1,1,2048,8192]"],
+    ("trinity-mini-serve", "decode"): [
+        "bf16[1,2048,512]", "bf16[1,2048,4096]", "bf16[1,2048,512]"] * 2,
+    ("trinity-mini-serve", "prefill"): [
+        "bf16[1,2048,512]", "bf16[1,2048,512]", "bf16[1,2048,4096]"] * 2,
+    ("kimi-linear-48b-a3b-serve", "decode"): [
+        "bf16[1,2304,6144]", "bf16[512,32,256]"],
+    ("kimi-linear-48b-a3b-serve", "prefill"): [
+        "bf16[2,32,512,256]", "bf16[1,2304,6144]"],
+    ("openpangu-ultra-moe-718b-serve", "prefill"): [
+        "bf16[1,1536,24576]", "bf16[512,128,256]", "bf16[6,128,512,256]",
+        "bf16[512,128,256]", "bf16[1,1536,24576]"],
 }
 
 
-@pytest.mark.parametrize("name", [
-    "qwen3-1.7b-serve", "olmoe-1b-7b-serve", "olmo-hybrid-7b-serve",
-    "qwen3-next-80b-a3b-serve", "trinity-mini-serve", "jamba2-3b-serve"])
-def test_no_step_program_copies_a_weight(one_chip, name):
+@pytest.mark.parametrize("name,every_shape", [
+    pytest.param(name, every_shape, id=name) for name, every_shape in (
+        ("qwen3-1.7b-serve", True), ("olmoe-1b-7b-serve", True),
+        ("olmo-hybrid-7b-serve", True), ("qwen3-next-80b-a3b-serve", True),
+        ("trinity-mini-serve", True), ("jamba2-3b-serve", True),
+        # the two programs other tests of this file compile anyway
+        ("openpangu-ultra-moe-718b-serve", False),
+        ("kimi-linear-48b-a3b-serve", False))])
+def test_no_step_program_copies_a_weight(one_chip, name, every_shape):
     """What an engine runs once its parameters are stored in the orders
     of dimensions the decode program reads them in
     (``decode.place_params``): no step re-lays a weight. Compiled against the default layouts the decode programs
@@ -434,15 +586,17 @@ def test_no_step_program_copies_a_weight(one_chip, name):
     the hybrid, ``bf16[1,1,2048,8192]`` in qwen3-next,
     ``bf16[1,1,2560,2560]`` in jamba (PERF.md, PR 48). Every prefill
     shape the engine lists is compiled against the SAME placed
-    weights and has none either. One copy is left, in the parent and
-    here (``_STILL_RE_LAID``)."""
+    weights and has none either. What is left, in the parent and
+    here, is listed by name (``_STILL_RE_LAID``); MiMo-V2-Flash's two
+    programs are in ``tests/test_mimo_step_programs_aot.py``."""
     from scaletorch_tpu.inference.decode import prefill_shapes
     from scaletorch_tpu.inference.kv_cache import carries_state, window_of
 
     config, cfg, init = _serving_model(name)
     decode, prefill, _ = _programs_of(one_chip, name)
     programs = {"decode": decode, "prefill": prefill}
-    if not (carries_state(cfg) or window_of(cfg) is not None):
+    if every_shape and not (carries_state(cfg)
+                            or window_of(cfg) is not None):
         serve = config["serve"]
         for shape in prefill_shapes(
                 serve["max_slots"], serve["prefill_len"])[:-1]:
