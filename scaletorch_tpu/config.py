@@ -87,8 +87,8 @@ class ModelArguments:
         default="llama",
         metadata={"help": "llama | qwen3 | qwen3_moe | olmoe | "
                           "olmo_hybrid | qwen3_next | afmoe | jamba | "
-                          "pangu_ultra_moe | kimi_linear | gpt_moe | lenet | "
-                          "mingpt"},
+                          "pangu_ultra_moe | kimi_linear | mimo_v2_flash | "
+                          "granitemoehybrid | gpt_moe | lenet | mingpt"},
     )
     # Architecture overrides (used when model_name_or_path is unset).
     hidden_size: int = 2048
@@ -154,7 +154,8 @@ class ModelArguments:
                           "(q_b_proj; kimi_linear's latent q_proj; "
                           "mimo_v2_flash's q_proj) at "
                           "(pangu_ultra_moe, kimi_linear, "
-                          "mimo_v2_flash; unset: 1). A "
+                          "mimo_v2_flash; granitemoehybrid's q_proj; "
+                          "unset: 1). A "
                           "property of random weights, not of the "
                           "model: at 1 random scores are flat (std "
                           "0.33) and every token of a sequence gets "
@@ -262,6 +263,40 @@ class ModelArguments:
                           "random weights, not of the model: it sets "
                           "the share of a window row's mass the sink "
                           "takes."},
+    )
+    # granitemoehybrid, by the published config.json names (layer_types
+    # above: mamba | attention; mamba_d_state, mamba_d_conv,
+    # mamba_expand, mamba_conv_bias, mamba_proj_bias as jamba's;
+    # intermediate_size is ONE routed expert's width,
+    # num_experts_per_tok the experts a token takes): the Mamba-2
+    # layers' heads, a head's channels, the groups of B / C (1: more is
+    # refused) and the rows of one chunk of a prompt's scan; the experts
+    # HELD here (num_routed_experts the router's width where that is a
+    # chip's share) and the ungated shared expert's width; the four muP
+    # multipliers (the embedding's, the attention scores' in place of
+    # head_dim ** -0.5, the two residual branches', and the divisor of
+    # the logits); and the positional embedding's kind (nope: a rotary
+    # one is refused, and rope_theta read by nothing)
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    num_local_experts: int = 72
+    shared_intermediate_size: int = 1536
+    embedding_multiplier: float = 12.0
+    attention_multiplier: float = 0.0078125
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 16.0
+    position_embedding_type: str = "nope"
+    ssm_decay_init_scale: Optional[float] = field(
+        default=None,
+        metadata={"help": "Multiple of its published range, U(1, 16), "
+                          "that the random initialiser draws a Mamba-2 "
+                          "head's decay rate A at (granitemoehybrid; "
+                          "unset: 1). A property of random weights, not "
+                          "of the model: a head remembers ~1 / (dt A) "
+                          "tokens, and at 1 the heads that carry a "
+                          "random model's signal forget within a few."},
     )
     attention_backend: str = field(
         default="auto",

@@ -377,6 +377,19 @@ class EngineMetrics:
     # p + 1)
     latent_cache_bytes: int = 0
     latent_keys_attended: int = 0
+    # a model with Mamba-2 layers (a config with ``mamba_chunk_size``:
+    # a state matrix a head): how many it has (0: no such model, and
+    # none of these is in the snapshot); slot x layer states a decode
+    # step advanced, summed over the steps dispatched (live slots x
+    # layers: x the bytes of one slot's state of one layer, what the
+    # updates had to read and write); chunks of ``mamba_chunk_size``
+    # rows a prompt's scan ran, summed over the prefill calls (rows x
+    # ceil(length / chunk) of the shape each call took x layers: a
+    # padded row's chunks are run too); and ``full_keys_attended``
+    # above, p + 1 a live slot and step, for its attention layers
+    ssd_layers: int = 0
+    ssd_state_slot_updates: int = 0
+    ssd_prefill_chunks: int = 0
 
     def record_ttft(self, ttft_s: float) -> None:
         self.hist["ttft"].observe(ttft_s)
@@ -453,6 +466,10 @@ class EngineMetrics:
         if self.latent_cache_bytes:
             snap["latent_cache_bytes"] = self.latent_cache_bytes
             snap["latent_keys_attended"] = self.latent_keys_attended
+        if self.ssd_layers:
+            for name in ("ssd_state_slot_updates", "ssd_prefill_chunks",
+                         "full_keys_attended"):
+                snap[name] = getattr(self, name)
         # what the interpreter's collector has cost this process
         # (``host_gc_*``: telemetry/spans.py, ``CollectionObserver``)
         snap.update(collection_counters())
@@ -878,7 +895,9 @@ class InferenceEngine:
             **moved,
             recurrent_state_bytes=recurrent_state_bytes(self.cache),
             window_cache_bytes=window_cache_bytes(self.cache),
-            latent_cache_bytes=latent_cache_bytes(self.cache))
+            latent_cache_bytes=latent_cache_bytes(self.cache),
+            ssd_layers=(cfg.num_mamba_layers
+                        if hasattr(cfg, "mamba_chunk_size") else 0))
         # calls of the paged-decode kernel in one decode step: a layer
         # of the pool and a ring layer each make one (none where the lax
         # pair reads the pool, or the latent kernel a latent one)
@@ -1757,6 +1776,11 @@ class InferenceEngine:
                 self._state_owner[i] = self._slots[i].request.request_id
         if self._stateful:
             self.metrics.recurrent_state_resets += len(admitted)
+        if self.metrics.ssd_layers:
+            self.metrics.ssd_prefill_chunks += self.metrics.ssd_layers * sum(
+                tokens.shape[0] * ceil_div(tokens.shape[1],
+                                           self.cfg.mamba_chunk_size)
+                for tokens, *_ in operands)
         if self._window is not None:
             self.metrics.window_ring_wraps += sum(
                 (self._slots[i].position - 1) // self._ring_tokens
@@ -2069,9 +2093,16 @@ class InferenceEngine:
             held = [i for i, _ in bound]
             if self._window is not None:
                 self._count_window_keys(positions[held])
-            if self.metrics.latent_cache_bytes:
-                self.metrics.latent_keys_attended += int(
-                    positions[held].astype(np.int64).sum()) + len(held)
+            if self.metrics.latent_cache_bytes or self.metrics.ssd_layers:
+                # p + 1 a live slot: what one layer's pool walk reads
+                keys = int(positions[held].astype(np.int64).sum()) + len(
+                    held)
+                if self.metrics.latent_cache_bytes:
+                    self.metrics.latent_keys_attended += keys
+                if self.metrics.ssd_layers:
+                    self.metrics.full_keys_attended += keys
+                    self.metrics.ssd_state_slot_updates += (
+                        len(held) * self.metrics.ssd_layers)
             active[held] = True
             if self._kernel_calls_a_step:
                 walked, chained = chained_first_blocks(

@@ -40,7 +40,12 @@ slot's state has no pages, no table and no snapshots: it is whatever the
 slot's request has read so far, started from zero by its prefill. A
 state-space model (Jamba: Mamba-1 layers between two attention layers)
 keeps the same pytree with a state of another shape, ``f32[mamba
-layers, slots, N, R, 128]``: the family's ``recurrent_state_shapes``
+layers, slots, N, R, 128]``, and a Mamba-2 model (granitemoehybrid:
+nine Mamba-2 layers to one attention layer) a third, ``f32[mamba
+layers, slots, N, H P]``: a head's ``[P, N]`` state matrix transposed
+and the heads side by side, so that a decode step advances every slot
+of a layer in one in-place pass over the donated buffer
+(``ops/pallas/ssd_update.py``). The family's ``recurrent_state_shapes``
 says which, and only ``[layers, slots]`` is common to them.
 
 A model whose layers mix window and full attention (afmoe: three
@@ -256,7 +261,11 @@ class HybridCache(NamedTuple):
     d_v]``, and ``[linear layers, slots, kernel - 1, channels]``;
     Mamba-1's (Jamba): elementwise per channel with the channels on the
     lanes, ``[mamba layers, slots, N, R, 128]`` (``channels = R *
-    128``), and ``[mamba layers, slots, d_conv - 1, channels]``. Nothing
+    128``), and ``[mamba layers, slots, d_conv - 1, channels]``;
+    Mamba-2's (granitemoehybrid): a matrix per head, transposed and the
+    heads side by side, ``[mamba layers, slots, N, H P]``, and ``[mamba
+    layers, slots, d_conv - 1, H P + 2 N]`` (the convolution runs over
+    ``x``, ``B`` and ``C``). Nothing
     but the family's own forward reads past the first two axes: the
     engine fills by slot (``decode.make_fill_slots_step`` masks axis 1)
     and counts bytes."""

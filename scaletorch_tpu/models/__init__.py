@@ -36,6 +36,10 @@ from scaletorch_tpu.models.mimo_v2_flash import (  # noqa: F401
     MimoV2Flash,
     MimoV2FlashConfig,
 )
+from scaletorch_tpu.models.granite_moe_hybrid import (  # noqa: F401
+    GraniteMoeHybrid,
+    GraniteMoeHybridConfig,
+)
 from scaletorch_tpu.models.gpt_moe import GPTMoE, GPTMoEConfig  # noqa: F401
 from scaletorch_tpu.models.lenet import LeNet, LeNetConfig  # noqa: F401
 from scaletorch_tpu.models.resnet import ResNetConfig  # noqa: F401

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from scaletorch_tpu.models import (
     afmoe,
     gpt_moe,
+    granite_moe_hybrid,
     jamba,
     kimi_linear,
     llama,
@@ -56,7 +57,8 @@ class Family:
     # parity-tested for the two delta-rule families, for kimi_linear
     # (whose row also writes latent rows at its pages) and for
     # mimo_v2_flash (``RingKVIO``'s table from slot ids: its row writes
-    # the pool at its pages and the rings at its slot). The same write
+    # the pool at its pages and the rings at its slot) and for
+    # granitemoehybrid (its Mamba-2 state and tail). The same write
     # would serve jamba's state, and the two other by-slot shapes are a
     # forward each (afmoe: ``RingKVIO``'s table from slot ids;
     # pangu_ultra_moe: a page-addressed long-prompt shape), but their
@@ -125,6 +127,16 @@ FAMILIES: Dict[str, Family] = {
             "its multi-token-prediction layers and their loss are not "
             "built, and there is no HF weight loading; the family is "
             "served (scripts/serve.py --preset mimo-v2-flash)")),
+    "granitemoehybrid": Family(
+        granite_moe_hybrid, granite_moe_hybrid.GraniteMoeHybridConfig,
+        counts_routing=True, rows_name_slots=True,
+        untrained=(
+            "its Mamba-2 scan has no backward (the chunked form under "
+            "jax.grad keeps every chunk's decay matrices), its Mamba-2 "
+            "layers have no sharding rules (tp / cp / pp), its experts no "
+            "exchange (ep), and there is no loss wiring and no HF weight "
+            "loading; the family is served (scripts/serve.py --preset "
+            "granite-4.0-h-small)")),
     # served and tested through its config class; trains via its example
     "gpt_moe": Family(gpt_moe, gpt_moe.GPTMoEConfig),
 }
@@ -158,7 +170,8 @@ def build_model_config(args):
     overrides = dict(dtype=_DTYPE[args.dtype],
                      param_dtype=_DTYPE[args.param_dtype])
     for name in ("embed_init_std", "routed_expert_init_scale",
-                 "query_init_scale", "sink_init_mean"):
+                 "query_init_scale", "sink_init_mean",
+                 "ssm_decay_init_scale"):
         # properties of random weights: a family whose initialiser reads
         # one has the field
         if getattr(args, name) is None:

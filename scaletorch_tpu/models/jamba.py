@@ -467,13 +467,16 @@ def attention_mix(
     cfg: JambaConfig,
     io: Any,
     write_mask: Optional[jax.Array],
+    *,
+    scale: Optional[float] = None,
 ) -> Tuple[jax.Array, Any, Any]:
     """The attention mixer of the normed hidden states ``x`` [B, S, H]:
     no q/k norm, no rotary embedding; K/V written at ``index`` of the
     pool through ``io``; a call of one row reads the pool, a call of
     several rows is a prompt from its first token and attends to itself
-    (module docstring). Returns (the mixer's output, cache_k,
-    cache_v)."""
+    (module docstring). ``scale``: the scores' factor where it is not
+    ``head_dim ** -0.5`` (granitemoehybrid's ``attention_multiplier``).
+    Returns (the mixer's output, cache_k, cache_v)."""
     from scaletorch_tpu.ops.flash_attention import prefill_self_attention
 
     cdt = cfg.dtype
@@ -487,9 +490,9 @@ def attention_mix(
     cache_k = io.write(cache_k, index, k, positions, write_mask)
     cache_v = io.write(cache_v, index, v, positions, write_mask)
     if s == 1:
-        attn = io.attend(q, cache_k, cache_v, index, positions)
+        attn = io.attend(q, cache_k, cache_v, index, positions, scale=scale)
     else:
-        attn = prefill_self_attention(q, k, v)
+        attn = prefill_self_attention(q, k, v, scale=scale)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return attn @ layer["o_proj"].astype(cdt), cache_k, cache_v
 

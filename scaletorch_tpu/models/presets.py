@@ -619,6 +619,83 @@ MODEL_PRESETS: Dict[str, Dict[str, Any]] = {
         routed_scaling_factor=None,
         sink_init_mean=2.5,
     ),
+    # ibm-granite/granite-4.0-h-small (32B-A9B), config.json as
+    # published (model_type granitemoehybrid): 40 layers, nine Mamba-2
+    # layers (128 heads x 64 channels, a [64, 128] float32 state a head,
+    # one group of B / C, chunks of 256) to one rope-free attention layer
+    # (32 heads on 8 K/V heads of 128, scores x 1/128), every layer 72
+    # softmax-routed experts of width 768 (top 10) beside an ungated
+    # shared expert of 1,536, the four muP multipliers; 32 B parameters
+    # = 64 GB in bf16. One chip serves a share
+    # (benchmarks/configs/granite-4.0-h-small-serve.json):
+    # --num_hidden_layers 10 (one whole period), --num_local_experts 36
+    # --num_routed_experts 72, half the vocabulary.
+    "granite-4.0-h-small": dict(
+        model_type="granitemoehybrid",
+        vocab_size=100352,
+        hidden_size=4096,
+        intermediate_size=768,
+        num_hidden_layers=40,
+        num_attention_heads=32,
+        num_key_value_heads=8,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=131072,
+        tie_word_embeddings=True,
+        mamba_n_heads=128,
+        mamba_d_head=64,
+        mamba_d_state=128,
+        mamba_n_groups=1,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_chunk_size=256,
+        mamba_conv_bias=True,
+        mamba_proj_bias=False,
+        num_local_experts=72,
+        num_experts_per_tok=10,
+        shared_intermediate_size=1536,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.0078125,
+        residual_multiplier=0.22,
+        logits_scaling=16.0,
+        position_embedding_type="nope",
+    ),
+    # The same family at a size the CPU tests serve: one period cut to
+    # five layers (m m a m m: 4 Mamba-2 layers of 4 heads x 16 channels
+    # on a state of 8, chunks of 8; one attention layer of 4 heads on 2
+    # K/V heads), and a SHARE of the experts: 4 of 8 routed ones held
+    # here, from id 4, top 3.
+    "granite-moe-hybrid-tiny": dict(
+        model_type="granitemoehybrid",
+        vocab_size=128,
+        hidden_size=32,
+        intermediate_size=16,
+        num_hidden_layers=5,
+        layer_types=["mamba", "mamba", "attention", "mamba", "mamba"],
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        rms_norm_eps=1e-5,
+        max_position_embeddings=4096,
+        tie_word_embeddings=True,
+        mamba_n_heads=4,
+        mamba_d_head=16,
+        mamba_d_state=8,
+        mamba_n_groups=1,
+        mamba_d_conv=4,
+        mamba_expand=2,
+        mamba_chunk_size=8,
+        mamba_conv_bias=True,
+        mamba_proj_bias=False,
+        num_local_experts=4,
+        num_routed_experts=8,
+        first_expert_id=4,
+        num_experts_per_tok=3,
+        shared_intermediate_size=24,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.125,
+        residual_multiplier=0.22,
+        logits_scaling=16.0,
+        position_embedding_type="nope",
+    ),
     # Downscaled dense model for 8-chip correctness/system sweeps.
     "dense-tiny": dict(
         model_type="qwen3",

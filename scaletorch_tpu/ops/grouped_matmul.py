@@ -30,6 +30,17 @@ from scaletorch_tpu.ops.flash_attention import _pallas_available
 
 # row tile of the Pallas form: M is padded up to a multiple of it
 _ROW_TILE = 512
+# A decode step's M is slots x choices: up to _DECODE_ROWS rows, a few a
+# group. megablox multiplies a whole row tile for every group the tile
+# meets, 2 tm K N operations for the group's 2 K N bytes of weights, so
+# such a call is bound by the MXU and not by HBM once tm passes ~240
+# rows on a v5e (197 TFLOP/s over 819 GB/s). Up to _STREAMING_ROW_TILE
+# the tile is the whole padded M (every decode call this repo had before
+# 64 slots x 10 choices came: 64 to 256 rows); past it a decode call
+# takes 128-row tiles (640 rows of 36 groups x [4096, 768]: 0.69 ms a
+# call at 512, 0.40 at 128, the same values; PERF.md, PR 61)
+_DECODE_ROWS = 1024
+_STREAMING_ROW_TILE = 256
 
 # The dropless expert layer gathers its (token, choice) rows sorted by
 # expert, ``[N k, H]``, and brings them back as ``f32[N, k, H]``: up to
@@ -80,10 +91,13 @@ def _width_tile(width: int) -> int:
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """(tm, tk, tn) of the megablox kernel for an [m, k] x [G, k, n]
     product. A decode step has at most a few rows a group, so its row
-    tile is the whole (padded) M and the K/N tiles are as large as
+    tile is the whole (padded) M, or 128 rows where that would pass
+    ``_STREAMING_ROW_TILE``, and the K/N tiles are as large as
     VMEM takes: the call is weight streaming. A prefill call has
     hundreds of rows a group and takes the square-ish MXU tiles."""
     tm = min(_ROW_TILE, -(-m // 128) * 128)
+    if _STREAMING_ROW_TILE < tm and m <= _DECODE_ROWS:
+        tm = 128
     return tm, _width_tile(k), _width_tile(n)
 
 

@@ -3,8 +3,9 @@
 No cache of any kind and none of the engine's code: every token is the
 argmax of the full-sequence training forward (``llama.forward`` /
 ``qwen3_moe.forward`` / ``gpt_moe.forward`` / ``afmoe.forward`` /
-``olmo_hybrid.forward``, ``qwen3_next.forward``, ``kimi_linear.forward``
-and ``jamba.forward`` with the recurrence row after row) over the
+``olmo_hybrid.forward``, ``qwen3_next.forward``, ``kimi_linear.forward``,
+``granite_moe_hybrid.forward`` and ``jamba.forward`` with the recurrence
+row after row) over the
 whole sequence so far. The engine's page pool, page tables, prefix sharing, cached
 forwards and sampling are all on the other side of the comparison.
 
@@ -21,7 +22,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from scaletorch_tpu.models import jamba, kimi_linear, olmo_hybrid, qwen3_next
+from scaletorch_tpu.models import (
+    granite_moe_hybrid,
+    jamba,
+    kimi_linear,
+    olmo_hybrid,
+    qwen3_next,
+)
 from scaletorch_tpu.models.families import family_of
 
 # of the largest |logit| of the step: float32 order-of-summation noise
@@ -34,6 +41,7 @@ AS_ITS_DEFINITION = {
     qwen3_next: {"sequential": True},
     olmo_hybrid: {"sequential": True},
     kimi_linear: {"sequential": True},
+    granite_moe_hybrid: {"sequential": True},
     jamba: {"scan": "sequential"},
 }
 

@@ -16,6 +16,7 @@ from scaletorch_tpu.inference.decode import (
 from scaletorch_tpu.models import (
     afmoe,
     gpt_moe,
+    granite_moe_hybrid,
     jamba,
     kimi_linear,
     llama,
@@ -50,6 +51,8 @@ EXPECTED = {
                         True),
     "kimi_linear": (kimi_linear, kimi_linear.forward_cached, True),
     "mimo_v2_flash": (mimo_v2_flash, mimo_v2_flash.forward_cached, True),
+    "granitemoehybrid": (granite_moe_hybrid,
+                         granite_moe_hybrid.forward_cached, True),
     "gpt_moe": (gpt_moe, gpt_moe.forward_cached, False),
 }
 TRAINS = {"llama", "qwen3", "qwen3_moe", "olmoe", "gpt_moe"}
@@ -60,7 +63,7 @@ def built(name):
     return build_model_config(ScaleTorchTPUArguments(**preset(name)))
 
 
-def test_the_rows_are_the_twelve_families():
+def test_the_rows_are_the_thirteen_families():
     assert set(FAMILIES) == set(EXPECTED)
     classes = [row.config_cls for row in FAMILIES.values()]
     assert len(set(classes)) == len(classes)
@@ -162,17 +165,21 @@ def test_embed_init_std_is_read_where_the_class_has_the_field(model_type):
 
 
 @pytest.mark.parametrize("name", ["routed_expert_init_scale",
-                                  "query_init_scale", "sink_init_mean"])
+                                  "query_init_scale", "sink_init_mean",
+                                  "ssm_decay_init_scale"])
 @pytest.mark.parametrize("model_type", sorted(EXPECTED))
 def test_the_draw_s_scales_are_read_where_the_class_has_the_field(
         model_type, name):
-    """Three more properties of random weights a launch may set: the
+    """Four more properties of random weights a launch may set: the
     families whose initialisers read one have its field (the sinks'
-    draw: the one family with sinks), every other refuses each by
-    name."""
+    draw: the one family with sinks; the decay's: the one with
+    Mamba-2's), every other refuses each by name."""
     has = name in FAMILIES[model_type].config_cls.__dataclass_fields__
-    reads = {"sink_init_mean": ("mimo_v2_flash",)}.get(
-        name, ("pangu_ultra_moe", "kimi_linear", "mimo_v2_flash"))
+    share = ("pangu_ultra_moe", "kimi_linear", "mimo_v2_flash")
+    reads = {"sink_init_mean": ("mimo_v2_flash",),
+             "ssm_decay_init_scale": ("granitemoehybrid",),
+             "routed_expert_init_scale": share,
+             "query_init_scale": share + ("granitemoehybrid",)}[name]
     assert has == (model_type in reads)
     if not has:
         with pytest.raises(NotImplementedError, match=name):

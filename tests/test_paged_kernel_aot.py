@@ -1764,3 +1764,53 @@ def test_flash_compiles_for_its_other_callers_shapes(
     if kw.get("block_q"):
         assert causal_block_plan(s, s, 128, 128).live == 65341
         assert 65341 <= MAX_CAUSAL_STEPS < 362 * 363 // 2
+
+
+# ---- the Mamba-2 decode state update (PR 61) -------------------------------------
+
+def test_a_mamba2_decode_layer_advances_its_state_in_one_pass(one_chip):
+    """One Mamba-2 layer of granite-4.0-h-small-serve's decode step at
+    the cell's shapes (64 slots, a ``[128, 8192]`` float32 state a slot,
+    nine layers in the donated buffer), compiled for the v5e: the state
+    is advanced by ONE Mosaic call (``ops/pallas/ssd_update.py``) that
+    the whole buffer goes into and comes out of (aliased: no copy of
+    it), ``y`` comes out of the same call, and no other instruction of
+    the program, fused ones included, has a result of the buffer's or of
+    one layer's shape: no ``[slots, 128, 8192]`` temporary beside it.
+    Written in XLA the update compiles to two fusions that each read the
+    state (PERF.md, PR 61)."""
+    from scaletorch_tpu.models import granite_moe_hybrid as granite
+
+    config, cfg, init = _serving_model("granite-4.0-h-small-serve")
+    slots = config["serve"]["max_slots"]
+    state_shape, tail_shape = cfg.recurrent_state_shapes(slots)
+    assert state_shape == (9, 64, 128, 8192)
+    assert granite.update_kernel_serves(cfg)    # FORCE_PALLAS: the fixture
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
+    layer = jax.tree.map(lambda a: arg(a.shape[1:], a.dtype),
+                         params["layers"]["mamba"])
+
+    def step(u, layer, states, tail, fresh, written):
+        return granite.mamba2_decode(u, layer, cfg, states, 4, tail, fresh,
+                                     written, row_mask=written[:, None])
+
+    flags = arg((slots,), jnp.bool_)
+    compiled = jax.jit(step, donate_argnums=2).lower(
+        arg((slots, 1, cfg.hidden_size), cfg.dtype), layer,
+        arg(state_shape, jnp.float32), arg(tail_shape[1:], cfg.dtype),
+        flags, flags).compile()
+    text = compiled.as_text()
+    calls = _named(_mosaic_calls(text), "ssd_state_update")
+    assert len(calls) == 1, calls
+    # (``_pool_shaped`` asks of any ``[layers, ...]`` buffer)
+    assert _pool_shaped(text, state_shape) == {}
+    memory = compiled.memory_analysis()
+    state_bytes = 9 * 64 * 128 * 8192 * 4
+    assert memory.alias_size_in_bytes == state_bytes
+    # the projections' activations and the kernel's small operands: far
+    # under one layer's 268 MB of state
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
