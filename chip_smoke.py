@@ -239,7 +239,10 @@ def leg_kernels(dry_run: bool) -> dict:
         sdpa_attention,
     )
     from scaletorch_tpu.ops.flash_attention import flash_attention
-    from scaletorch_tpu.ops.pallas.flash import pallas_flash_attention
+    from scaletorch_tpu.ops.pallas.flash import (
+        flash_blocks,
+        pallas_flash_attention,
+    )
     from scaletorch_tpu.ops.pallas.paged_attention import (
         paged_attention,
         paged_gather_kv,
@@ -287,6 +290,9 @@ def leg_kernels(dry_run: bool) -> dict:
             *(x.astype(jnp.float32) for x in (q, k, v)))
     flash = {
         "shape": f"B1 Hq{hq} Hkv{hkv} S{seq} D{d} bf16 causal",
+        # (bq, bkv) each kernel takes at this shape: the rule's answer
+        "blocks": {kind: flash_blocks(kind, seq, seq)
+                   for kind in ("fwd", "dq", "dkv")},
         "fwd_max_abs_err_kernel": max_abs(out_k - out_t),
         "fwd_max_abs_err_xla_bf16": max_abs(out_x - out_t),
     }

@@ -17,6 +17,10 @@ def _as_bool(v: str) -> bool:
     return v.lower() in ("1", "true", "yes", "on")
 
 
+def _as_optional_int(v: str) -> int | None:
+    return int(v) if v else None
+
+
 def register_env(name: str, default: str, parser: Callable[[str], Any] = str) -> None:
     """Declare an environment variable the framework reads."""
     _REGISTRY[name] = (default, parser)
@@ -82,14 +86,15 @@ register_env("SCALETORCH_TPU_CE_CHUNK", "1024", int)
 # Default OFF until measured faster than the batched einsum on real
 # chips (the einsum is already MXU-dense; the win is the padding skip).
 register_env("SCALETORCH_TPU_GROUPED_MLP_KERNEL", "0", _as_bool)
-# Flash-kernel tile sizes (ops/pallas/flash.py), halved until they divide
-# the sequence. The defaults are sound for d=64..128 on v5e VMEM and are
-# what every chip number of PERF.md was taken at; the blocks a causal call
-# skips follow from them and the two lengths alone
-# (flash.causal_block_plan), so they are no lever for that.
-# tools/optimize_mfu.py --flash-blocks can sweep them; no chip run has.
-register_env("SCALETORCH_TPU_FLASH_BLOCK_Q", "512", int)
-register_env("SCALETORCH_TPU_FLASH_BLOCK_KV", "512", int)
+# Flash-kernel tile sizes (ops/pallas/flash.py). Unset (the default):
+# each of the three kernels takes its (bq, bkv) from the call's shapes
+# (flash.flash_blocks, a sweep on the v5e: PERF.md, PR 62). Set by hand,
+# a value overrides the rule for ALL THREE kernels, halved until it
+# divides the sequence: how tools/optimize_mfu.py --flash-blocks reads a
+# whole step at one uniform pair. An override, not a tuning surface:
+# deleting the pair is ROADMAP D5's.
+register_env("SCALETORCH_TPU_FLASH_BLOCK_Q", "", _as_optional_int)
+register_env("SCALETORCH_TPU_FLASH_BLOCK_KV", "", _as_optional_int)
 # Paged-decode attention (ops/pallas/paged_attention.py): 1 (default)
 # lets single-token decode on a TPU backend take the Pallas kernel; 0
 # forces the lax gather fallback everywhere (the bit-parity oracle).

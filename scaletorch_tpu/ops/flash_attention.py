@@ -52,9 +52,10 @@ def flash_attention(
     if _pallas_available():
         from scaletorch_tpu.ops.pallas.flash import pallas_flash_attention
 
-        # tile sizes resolve from SCALETORCH_TPU_FLASH_BLOCK_Q/KV inside
-        # the kernel entry (pallas/flash.py _resolve_blocks), shared with
-        # the ring-attention composition path
+        # each kernel takes its tile sizes from the shapes inside the
+        # kernel entry (pallas/flash.py flash_blocks, which
+        # SCALETORCH_TPU_FLASH_BLOCK_Q/KV override when set), as on the
+        # ring-attention composition path
         return pallas_flash_attention(q, k, v, causal=causal, scale=scale)
     return sdpa_attention(q, k, v, causal=causal, scale=scale)
 

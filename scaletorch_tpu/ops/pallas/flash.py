@@ -46,6 +46,26 @@ Design points:
     ``[1, bq]`` rows of lse and delta: ``flash_dq`` fed from two such
     scratches was slower (2.87 -> 3.00 ms, same PR).
 
+  * **Blocks from shapes** — each of the three kernels takes its
+    ``(bq, bkv)`` from a pure function of the call's static shapes
+    (``flash_blocks``: the two sides, the mask, which kernel), where
+    all three ran every call in 512 x 512. What a sweep
+    of each kernel alone on the v5e said (PERF.md, PR 62): at the
+    training cell's ``[1, 16 / 8, 8192, 128]`` ``flash_dkv`` reads 4.14
+    ms at 512 x 512 and 3.50 at 1,024 x 1,024, ``flash_dq`` 2.87 and
+    2.72 at 1,024 x 512, and the forward 2.44 and 2.41-2.44 at every
+    shape from 512 x 512 to 1,024 x 1,024: halving its grid steps buys
+    it nothing, so its time is the score elements it touches and not a
+    cost a step, and the serving prefills (sides of 3,072: larger blocks
+    3-9 % slower; 8,192 at keys 192: -2 to +5 %) keep their blocks.
+    ``lse`` and ``delta`` are ``[B, Hq, 1, S]`` whatever the blocks, so
+    forward, ``dq`` and ``dkv`` need not agree; a caller's ``block_q=``
+    / ``block_kv=`` (tests, a sweep) and a hand-set
+    SCALETORCH_TPU_FLASH_BLOCK_Q / _KV override all three. Past Mosaic's
+    16 MiB of scoped VMEM a call carries a ``vmem_limit_bytes`` computed
+    from its blocks (``_vmem_limit``); no shape the rule picks is (1,024
+    x 1,024 compiles under 9 MiB).
+
 Backward follows FlashAttention-2: delta = rowsum(dO * O) precomputed in
 XLA, then a dq kernel (a query block at a time, reducing its key blocks)
 and a dkv kernel (a key block at a time, reducing its query blocks and, at
@@ -64,18 +84,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-def _resolve_blocks(block_q, block_kv):
-    """None -> the SCALETORCH_TPU_FLASH_BLOCK_Q/KV env registry values.
-    Resolved HERE so every entry point — the attention backend, the ring
-    attention's forward/backward composition — honours the tuned tiles."""
-    if block_q is None or block_kv is None:
-        from scaletorch_tpu.env import get_env
-
-        block_q = block_q or get_env("SCALETORCH_TPU_FLASH_BLOCK_Q")
-        block_kv = block_kv or get_env("SCALETORCH_TPU_FLASH_BLOCK_KV")
-    return block_q, block_kv
-
-
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps masked rows NaN-free
 _LANES = 128  # of a vector register: the forward holds its statistics that wide
 
@@ -91,6 +99,126 @@ def _pick_block(seq: int, preferred: int) -> int:
     while seq % block:
         block //= 2
     return max(block, 1)
+
+
+# ---------------------------------------------------------------------------
+# blocks from shapes
+# ---------------------------------------------------------------------------
+def flash_blocks(kind: str, sq: int, skv: int, *, causal: bool = True,
+                 window: Optional[int] = None) -> tuple[int, int]:
+    """``(bq, bkv)`` of one kernel (``kind``: ``"fwd"``, ``"dq"`` or
+    ``"dkv"``) from the call's static shapes: the two sides and the
+    mask. Pure, so a caller or a test can ask what a call will run in.
+    The thresholds are a sweep's on the v5e, each kernel ALONE, bf16,
+    16 / 8 heads x 128 unless said, ms a call against 512 x 512
+    (PERF.md section 6, PR 62, has every shape tried). The head's width
+    is no argument: 64- and 256-wide heads and keys 192 on values 128
+    chose as 128 does, or read flat.
+
+    * ``flash_dkv``: 1,024 x 1,024 from a side of 2,048. Causal: 0.295
+      against 0.309 at 2,048, 0.978 against 1.102 at 4,096, **3.50
+      against 4.14 at 8,192** (the training cell's call), 13.20 against
+      16.05 at 16,384, 25.4 against 31.0 at 32,768. Unmasked (the ring's
+      other hops): 0.380 against 0.467, 1.51 against 1.85, 6.00 against
+      7.40 at 2,048 / 4,096 / 8,192. 512 x 1,024 and 1,024 x 512 read
+      2-3 % over it, 2,048 either way 8-10 % (causal) or within 2 %.
+    * ``flash_dq``: causal, 1,024 x 512 from a side of 8,192 (**2.72
+      against 2.87**; 10.28 against 11.12 at 16,384; at 4,096 0.761
+      against 0.766: stays): the same key blocks in the same order, so
+      ``dq`` bit for bit. Unmasked, 1,024 x 1,024 from 2,048 (0.282
+      against 0.324, 1.12 against 1.28, 4.44 against 5.11).
+    * ``flash_fwd``: causal, NO shape earns 2 % under a side of 16,384:
+      at 8,192 the best reads 2.41 against 2.44 (16 / 8 x 128), 12.55
+      against 12.36 (64 / 4 heads, keys 192 on values 128) and 6.32
+      against 6.43 (32 / 32, 192 / 128); at 3,072 (8 rows x 32 heads:
+      full, under a window of 2,048, at 192 / 128) every larger block
+      is 3-9 % SLOWER, the triangle's waste (14 % more score elements
+      touched in 1,024-wide blocks) unpaid. Halving the grid's steps
+      buys nothing: the forward's time follows the score elements it
+      touches, not a cost a step, and every serving prefill keeps the
+      blocks it had. From 16,384, with no window, 1,024 x 1,024 (8.78
+      against 9.01; 16.6 against 17.3 at 32,768; 23.0 against 24.1 at
+      192 / 128). Under a window narrower than a block nothing helps
+      (PR 59). Unmasked, 1,024 x 512 from 2,048 (0.298 against 0.311,
+      1.07 against 1.12, 4.03 against 4.22): no triangle, no waste.
+
+    A size is halved until it divides its side (``_pick_block``)."""
+    if kind not in ("fwd", "dq", "dkv"):
+        raise ValueError(f"flash kernel {kind!r}: one of fwd, dq, dkv")
+    side = min(sq, skv)
+    bq = bkv = 512
+    if kind == "dkv" or (kind == "dq" and not causal):
+        if side >= 2048:
+            bq = bkv = 1024
+    elif kind == "dq":
+        if side >= 8192:
+            bq = 1024
+    elif not causal:
+        if side >= 2048:
+            bq = 1024
+    elif side >= 16384 and window is None:
+        bq = bkv = 1024
+    return _pick_block(sq, bq), _pick_block(skv, bkv)
+
+
+def _blocks(kind, q, k, causal, window, block_q, block_kv):
+    """One kernel's ``(bq, bkv)``: the caller's ``block_q`` / ``block_kv``
+    (the tests, the ring, a sweep), else SCALETORCH_TPU_FLASH_BLOCK_Q / _KV
+    where set by hand (``tools/optimize_mfu.py --flash-blocks``: one pair
+    for all three kernels), else ``flash_blocks``. A given size is halved
+    until it divides its side. Resolved HERE so every entry point — the
+    attention backend, the ring's forward / backward composition —
+    takes the same blocks."""
+    sq, skv = q.shape[2], k.shape[2]
+    if block_q is None or block_kv is None:
+        from scaletorch_tpu.env import get_env
+
+        rule_q, rule_kv = flash_blocks(kind, sq, skv, causal=causal,
+                                       window=window)
+        block_q = (block_q or get_env("SCALETORCH_TPU_FLASH_BLOCK_Q")
+                   or rule_q)
+        block_kv = (block_kv or get_env("SCALETORCH_TPU_FLASH_BLOCK_KV")
+                    or rule_kv)
+    return _pick_block(sq, block_q), _pick_block(skv, block_kv)
+
+
+# Mosaic's scoped VMEM on the v5e when a call asks for nothing
+# (``xla_tpu_scoped_vmem_limit_kib`` = 16,384) and what the core has.
+_SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_PHYSICAL = 128 * 2 ** 20
+
+
+def _vmem_limit(blocks, scratch_shapes):
+    """``vmem_limit_bytes`` of one call, or None where the working set
+    of a grid step stays under Mosaic's default. ``blocks``: the
+    ``(block shape, dtype)`` of every operand and result, ``q``'s and
+    ``k``'s first (all three kernels'); ``scratch_shapes`` the call's::
+
+        2 x (operand and result blocks)      the pipeline's two buffers
+        + the float32 scratch                accumulators, statistics
+        + 1.5 x 4 x bq x bkv                 the body's score-shaped values
+
+    The body names four to six float32 ``[bq, bkv]`` values (s, p, dp,
+    ds, the mask's iotas), but Mosaic streams the elementwise chain
+    through registers and keeps one of them and a matmul's bf16 feed:
+    compiled for the v5e under a bisected limit (AOT, PR 62), what is
+    left after blocks and scratch is 1.1-1.5 of ``4 bq bkv`` for every
+    kernel from 512 x 512 (3 MiB in all) to 2,048 x 2,048 (25 / 25 / 30
+    MiB for fwd / dq / dkv against this sum's 31 / 30 / 32). So 1,024 x
+    1,024 (8 / 9 / 9 MiB) fits the default; the first shapes past it are
+    ``flash_dkv`` at 1,024 x 2,048 (17 MiB) and everything at 2,048 x
+    2,048. Past the default the call asks for the sum and a quarter, in
+    whole MiB."""
+    def nbytes(shape, dtype):
+        return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+    (q_block, _), (k_block, _) = blocks[:2]
+    need = (2 * sum(nbytes(*block) for block in blocks)
+            + sum(nbytes(s.shape, s.dtype) for s in scratch_shapes)
+            + 6 * q_block[2] * k_block[2])
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return min(-(-need * 5 // 4 // 2 ** 20) * 2 ** 20, _VMEM_PHYSICAL)
 
 
 # ---------------------------------------------------------------------------
@@ -234,36 +362,47 @@ def _scores(q, k, i, j, *, scale, masked, bq, bkv, window=None):
     return s
 
 
-def _semantics(*dims):
+def _semantics(*dims, vmem_limit_bytes=None):
     """Mosaic grid dimension semantics: 'p' = parallel (no cross-iteration
     carry — megacore-partitionable on 2-core chips), 'a' = arbitrary (the
     sequential reduction dims that carry scratch accumulators). Declaring
     them lets Mosaic schedule DMAs/compute across iterations instead of
-    assuming every dim may carry state."""
+    assuming every dim may carry state. ``vmem_limit_bytes``: what
+    ``_vmem_limit`` computed from the blocks, None (Mosaic's default)
+    where the working set fits it."""
     m = {"p": pltpu.PARALLEL, "a": pltpu.ARBITRARY}
     return pltpu.CompilerParams(
-        dimension_semantics=tuple(m[d] for d in dims))
+        dimension_semantics=tuple(m[d] for d in dims),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
-def _call(kernel, name, tables, grid, interpret, out_shape, **specs):
-    """The ``pallas_call`` of one kernel. ``grid`` is the rectangular
-    grid: (batch, head, outer block) are parallel, the rest carry the
-    accumulators. With ``tables`` (a causal walk) the two outermost
-    block dimensions fold into one sequential one, a step a table
-    entry."""
+def _call(kernel, name, tables, grid, interpret, out_shape, operands, *,
+          in_specs, out_specs, scratch_shapes):
+    """One kernel's ``pallas_call`` on ``operands``. ``grid`` is the
+    rectangular grid: (batch, head, outer block) are parallel, the rest
+    carry the accumulators. With ``tables`` (a causal walk) the two
+    outermost block dimensions fold into one sequential one, a step a
+    table entry. Past Mosaic's default the call carries the
+    ``vmem_limit_bytes`` its blocks and scratch come to."""
     parallel = 3
     if tables:
         grid, parallel = grid[:2] + (len(tables[0]),) + grid[4:], 2
+    results = zip(*(x if isinstance(x, list) else [x]
+                    for x in (out_specs, out_shape)))
+    blocks = [(spec.block_shape, x.dtype)
+              for spec, x in [*zip(in_specs, operands), *results]]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(tables), grid=grid, **specs),
+            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
         compiler_params=_semantics(
-            *["p"] * parallel, *["a"] * (len(grid) - parallel)),
+            *["p"] * parallel, *["a"] * (len(grid) - parallel),
+            vmem_limit_bytes=_vmem_limit(blocks, scratch_shapes)),
         out_shape=out_shape,
         interpret=interpret,
         name=name,
-    )
+    )(*tables, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -319,9 +458,10 @@ def _fwd_kernel(*refs, scale, causal, bq, bkv, window=None, sink=False):
         lse_ref[0, 0] = (m_sc[:, 0] + jnp.log(l[:, 0]))[None, :]
 
 
-def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None,
-                   sink=None):
-    """``v`` may be narrower or wider than ``q`` / ``k`` (latent
+def _flash_forward(q, k, v, causal, scale, block_q, block_kv, interpret,
+                   window=None, sink=None):
+    """``block_q`` / ``block_kv``: the caller's, or None for ``_blocks``'
+    choice. ``v`` may be narrower or wider than ``q`` / ``k`` (latent
     attention's expanded heads: keys 192, values 128): the output and
     the accumulator take the value's width. The backward kernels know
     one width. ``sink`` [Hq] float32: a logit a query head in every
@@ -335,6 +475,7 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None,
     if window is not None and not causal:
         raise ValueError("a window is a causal mask's: causal=False "
                          f"with window={window}")
+    bq, bkv = _blocks("fwd", q, k, causal, window, block_q, block_kv)
     tables = (causal_block_plan(sq, skv, bq, bkv, window).by_query
               if causal else ())
     blocks = _blocks_of(causal, 2)
@@ -355,6 +496,9 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None,
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
                           bkv=bkv, window=window, sink=sink is not None),
         "flash_fwd", tables, (b, hq, sq // bq, skv // bkv), interpret,
+        [_struct((b, hq, sq, dv), q.dtype, q),
+         _struct((b, hq, 1, sq), jnp.float32, q)],
+        (q, k, v, *sinks),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_rows),
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
@@ -366,16 +510,12 @@ def _flash_forward(q, k, v, causal, scale, bq, bkv, interpret, window=None,
             pl.BlockSpec((1, 1, 1, bq),
                          lambda b_, h, *g: (b_, h, 0, blocks(*g)[0])),
         ],
-        out_shape=[
-            _struct((b, hq, sq, dv), q.dtype, q),
-            _struct((b, hq, 1, sq), jnp.float32, q),
-        ],
         scratch_shapes=[
             pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running sum
         ],
-    )(*tables, q, k, v, *sinks)
+    )
     return out, lse[:, :, 0, :]
 
 
@@ -451,7 +591,11 @@ def _dkv_kernel(*refs, scale, causal, bq, bkv):
         dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
+def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_kv,
+                    interpret):
+    """``flash_dq`` and ``flash_dkv`` each in blocks of its own
+    (``_blocks``): ``lse`` and ``delta`` are ``[B, Hq, 1, S]`` whatever
+    blocks the forward ran in, so the three need not agree."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     if v.shape[-1] != d:
@@ -459,15 +603,14 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
             f"flash backward with values {v.shape[-1]} wide under keys "
             f"{d} wide: only the forward takes a value width of its own")
     n_rep = hq // hkv
-    nq, nkv = sq // bq, skv // bkv
-    plan = causal_block_plan(sq, skv, bq, bkv) if causal else None
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse4 = lse[:, :, None, :]      # [B, Hq, 1, S]
     delta4 = delta[:, :, None, :]
 
     # dq: a query block at a time, over its key blocks
-    tables = plan.by_query if causal else ()
+    bq, bkv = _blocks("dq", q, k, causal, None, block_q, block_kv)
+    tables = causal_block_plan(sq, skv, bq, bkv).by_query if causal else ()
     blocks = _blocks_of(causal, 2)
 
     def q_rows(b_, h, *g_):
@@ -481,7 +624,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
 
     dq = _call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv),
-        "flash_dq", tables, (b, hq, nq, nkv), interpret,
+        "flash_dq", tables, (b, hq, sq // bq, skv // bkv), interpret,
+        _struct((b, hq, sq, d), q.dtype, q), (q, k, v, g, lse4, delta4),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_rows),
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
@@ -491,13 +635,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
             pl.BlockSpec((1, 1, 1, bq), q_stats),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), q_rows),
-        out_shape=_struct((b, hq, sq, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-    )(*tables, q, k, v, g, lse4, delta4)
+    )
 
     # dk/dv: a key block at a time, over its query blocks and, at each,
     # the n_rep query heads of the kv head
-    tables = plan.by_key if causal else ()
+    bq, bkv = _blocks("dkv", q, k, causal, None, block_q, block_kv)
+    tables = causal_block_plan(sq, skv, bq, bkv).by_key if causal else ()
     blocks = _blocks_of(causal, 3)
 
     def q_rows(b_, hk, *g_):
@@ -513,7 +657,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
 
     dk, dv = _call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq, bkv=bkv),
-        "flash_dkv", tables, (b, hkv, nkv, nq, n_rep), interpret,
+        "flash_dkv", tables, (b, hkv, skv // bkv, sq // bq, n_rep), interpret,
+        [_struct((b, hkv, skv, d), k.dtype, k),
+         _struct((b, hkv, skv, d), v.dtype, v)],
+        (q, k, v, g, lse4, delta4),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), q_rows),
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
@@ -526,15 +673,11 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
             pl.BlockSpec((1, 1, bkv, d), kv_rows),
         ],
-        out_shape=[
-            _struct((b, hkv, skv, d), k.dtype, k),
-            _struct((b, hkv, skv, d), v.dtype, v),
-        ],
         scratch_shapes=[
             pltpu.VMEM((bkv, d), jnp.float32),
             pltpu.VMEM((bkv, d), jnp.float32),
         ],
-    )(*tables, q, k, v, g, lse4, delta4)
+    )
     return dq, dk, dv
 
 
@@ -542,13 +685,15 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret):
 # public op with custom VJP
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, bq, bkv, interpret):
-    out, _ = _flash_forward(q, k, v, causal, scale, bq, bkv, interpret)
+def _flash(q, k, v, causal, scale, block_q, block_kv, interpret):
+    out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_kv,
+                            interpret)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, bq, bkv, interpret):
-    out, lse = _flash_forward(q, k, v, causal, scale, bq, bkv, interpret)
+def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret):
+    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_kv,
+                              interpret)
     # Under jax.checkpoint the 'save_attn' policy keeps these two named
     # residuals, so the backward kernels run off the SAVED (out, lse)
     # instead of recomputing the whole flash forward inside the layer
@@ -560,9 +705,10 @@ def _flash_fwd(q, k, v, causal, scale, bq, bkv, interpret):
     return out, (q, k, v, out_r, lse_r)
 
 
-def _flash_bwd(causal, scale, bq, bkv, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_kv, interpret, res, g):
     q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, g, causal, scale, bq, bkv, interpret)
+    return _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
+                           block_kv, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -579,17 +725,16 @@ def pallas_flash_attention(
     block_kv: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: [B, Hq, S, D]; k/v: [B, Hkv, Skv, D]; Hq % Hkv == 0 (GQA)."""
-    b, hq, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
+    """q: [B, Hq, S, D]; k/v: [B, Hkv, Skv, D]; Hq % Hkv == 0 (GQA).
+    ``block_q`` / ``block_kv`` given: all three kernels take them;
+    left None: each takes its own from the shapes (``flash_blocks``)."""
+    hq, d = q.shape[1], q.shape[-1]
+    hkv = k.shape[1]
     if hq % hkv:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    block_q, block_kv = _resolve_blocks(block_q, block_kv)
-    bq = _pick_block(sq, block_q)
-    bkv = _pick_block(skv, block_kv)
-    return _flash(q, k, v, causal, scale, bq, bkv, interpret)
+    return _flash(q, k, v, causal, scale, block_q, block_kv, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -634,11 +779,8 @@ def flash_forward_with_lse(
         )
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    block_q, block_kv = _resolve_blocks(block_q, block_kv)
-    bq = _pick_block(q.shape[2], block_q)
-    bkv = _pick_block(k.shape[2], block_kv)
-    return _flash_forward(q, k, v, causal, scale, bq, bkv, interpret,
-                          window, sink)
+    return _flash_forward(q, k, v, causal, scale, block_q, block_kv,
+                          interpret, window, sink)
 
 
 def flash_block_backward(
@@ -670,8 +812,5 @@ def flash_block_backward(
         )
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    block_q, block_kv = _resolve_blocks(block_q, block_kv)
-    bq = _pick_block(q.shape[2], block_q)
-    bkv = _pick_block(k.shape[2], block_kv)
-    return _flash_backward(q, k, v, out, lse, dout, causal, scale, bq, bkv,
-                           interpret)
+    return _flash_backward(q, k, v, out, lse, dout, causal, scale, block_q,
+                           block_kv, interpret)

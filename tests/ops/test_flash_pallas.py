@@ -15,6 +15,7 @@ from scaletorch_tpu.ops.pallas.flash import (
     MAX_CAUSAL_STEPS,
     causal_block_plan,
     flash_block_backward,
+    flash_blocks,
     flash_forward_with_lse,
     pallas_flash_attention,
 )
@@ -135,10 +136,11 @@ PLAN_IDS = ["x".join(map(str, shape)) for shape, _ in PLAN_SHAPES]
 
 
 def _visible(shape, i, j):
-    """Block (i, j) of the triangle the kernels' mask draws."""
-    sq, skv, bq, bkv = shape
-    tril = np.arange(sq)[:, None] >= np.arange(skv)[None, :]
-    return tril[i * bq:(i + 1) * bq, j * bkv:(j + 1) * bkv]
+    """Block (i, j) of the triangle the kernels' mask draws (the block
+    alone: the whole 8,192 x 8,192 triangle a call was 17 s of a test)."""
+    _, _, bq, bkv = shape
+    return (np.arange(i * bq, (i + 1) * bq)[:, None]
+            >= np.arange(j * bkv, (j + 1) * bkv)[None, :])
 
 
 @pytest.mark.parametrize("shape,counts", PLAN_SHAPES, ids=PLAN_IDS)
@@ -374,3 +376,159 @@ def test_lane_replicated_statistics_at_every_width(
         argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gp, gr):
         assert jnp.max(jnp.abs(a - b)) < 1e-4
+
+
+# ---------------------------------------------------------------------------
+# blocks from shapes (PR 62): each kernel takes its own (bq, bkv)
+# ---------------------------------------------------------------------------
+RULE_ROWS = [
+    # id, (sq, skv), causal, window, fwd, dq, dkv
+    ("row-8192-training-cell-mimo-kimi-linear", (8192, 8192), True, None,
+     (512, 512), (1024, 512), (1024, 1024)),
+    ("rows-3072-trinity-mini-openpangu-jamba2", (3072, 3072), True, None,
+     (512, 512), (512, 512), (1024, 1024)),
+    ("trinity-mini-window-2048", (3072, 3072), True, 2048,
+     (512, 512), (512, 512), (1024, 1024)),
+    ("one-row-512", (512, 512), True, None,
+     (512, 512), (512, 512), (512, 512)),
+    ("ring-diagonal-hop-2k", (2048, 2048), True, None,
+     (512, 512), (512, 512), (1024, 1024)),
+    ("ring-unmasked-hop-4k", (4096, 4096), False, None,
+     (1024, 512), (1024, 1024), (1024, 1024)),
+    ("ring-unmasked-hop-1k", (1024, 1024), False, None,
+     (512, 512), (512, 512), (512, 512)),
+    ("ulysses-128k", (131072, 131072), True, None,
+     (1024, 1024), (1024, 512), (1024, 1024)),
+    ("window-narrower-than-a-block", (16384, 16384), True, 128,
+     (512, 512), (1024, 512), (1024, 1024)),
+    ("long-row-16k", (16384, 16384), True, None,
+     (1024, 1024), (1024, 512), (1024, 1024)),
+    ("side-1024-does-not-divide", (8704, 8704), True, None,
+     (512, 512), (512, 512), (512, 512)),
+    ("rows-shorter-than-keys", (2048, 8192), True, None,
+     (512, 512), (512, 512), (1024, 1024)),
+    ("a-cpu-test-s-shape", (192, 128), True, None,
+     (192, 128), (192, 128), (192, 128)),
+]
+
+
+@pytest.mark.parametrize("shape,causal,window,fwd,dq,dkv",
+                         [row[1:] for row in RULE_ROWS],
+                         ids=[row[0] for row in RULE_ROWS])
+def test_the_rule_s_blocks_by_shape(shape, causal, window, fwd, dq, dkv):
+    """``flash_blocks`` at the training cell's sides (MiMo-V2-Flash's
+    and Kimi-Linear's 8,192-token rows have the same: the head's width
+    is no argument), at the sides the other serving prefills, the ring
+    and Ulysses hand the kernels, under a window narrower than a block
+    and at a side 1,024 does not divide: the three pairs a sweep on the
+    v5e chose (PERF.md, PR 62). Every pair divides its sides."""
+    got = {kind: flash_blocks(kind, *shape, causal=causal, window=window)
+           for kind in ("fwd", "dq", "dkv")}
+    assert got == {"fwd": fwd, "dq": dq, "dkv": dkv}
+    sq, skv = shape[:2]
+    assert all(sq % bq == 0 and skv % bkv == 0 for bq, bkv in got.values())
+
+
+def test_the_rule_knows_three_kernels_and_a_hand_set_pair_overrides_all(
+        monkeypatch):
+    with pytest.raises(ValueError, match="one of fwd, dq, dkv"):
+        flash_blocks("bwd", 8192, 8192)
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16)
+
+    def blocks():
+        # a new function a call: the pair is read when a call is traced
+        grad = jax.grad(lambda q, k, v: pallas_flash_attention(
+            q, k, v, interpret=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))
+        return flash_call_blocks(jax.make_jaxpr(grad)(q, k, k).jaxpr)
+
+    assert blocks() == {"flash_fwd": (512, 512), "flash_dq": (1024, 512),
+                        "flash_dkv": (1024, 1024)}
+    monkeypatch.setenv("SCALETORCH_TPU_FLASH_BLOCK_Q", "2048")
+    assert blocks() == {"flash_fwd": (2048, 512), "flash_dq": (2048, 512),
+                        "flash_dkv": (2048, 1024)}
+    monkeypatch.setenv("SCALETORCH_TPU_FLASH_BLOCK_KV", "256")
+    assert set(blocks().values()) == {(2048, 256)}
+    # an empty value is an unset one
+    monkeypatch.setenv("SCALETORCH_TPU_FLASH_BLOCK_Q", "")
+    monkeypatch.setenv("SCALETORCH_TPU_FLASH_BLOCK_KV", "")
+    assert blocks()["flash_dkv"] == (1024, 1024)
+
+
+THREE_PAIRS_CASES = [
+    # hq, hkv, sq, skv, d, causal, fwd, dq, dkv
+    (4, 2, 128, 128, 32, True, (64, 32), (32, 128), (128, 64)),
+    (2, 1, 64, 128, 32, True, (32, 64), (64, 128), (16, 32)),
+    (2, 2, 128, 64, 32, False, (128, 32), (32, 64), (64, 16)),
+]
+
+
+@pytest.mark.parametrize(
+    "hq,hkv,sq,skv,d,causal,fwd,dq,dkv", THREE_PAIRS_CASES,
+    ids=[f"h{c[0]}-{c[1]}_s{c[2]}-{c[3]}_" + ("causal" if c[5] else "full")
+         for c in THREE_PAIRS_CASES])
+def test_three_kernels_in_three_different_blocks_in_one_grad(
+        monkeypatch, hq, hkv, sq, skv, d, causal, fwd, dq, dkv):
+    """One ``jax.grad`` whose forward, ``flash_dq`` and ``flash_dkv`` each
+    run another rectangular pair (the rule's answer, stood in for here:
+    at these sizes the real one says one pair for all three): ``lse`` is
+    ``[B, Hq, 1, S]`` whatever the forward's blocks, so the backward
+    kernels read it in theirs. Against plain softmax, GQA / MQA / MHA,
+    ``sq != skv`` both ways, causal and full."""
+    from scaletorch_tpu.ops.pallas import flash as flash_module
+
+    asked = []
+
+    def rule(kind, *shape, **mask):
+        asked.append((kind, shape, mask))
+        return {"fwd": fwd, "dq": dq, "dkv": dkv}[kind]
+
+    monkeypatch.setattr(flash_module, "flash_blocks", rule)
+    kq, kk, kv = jax.random.split(jax.random.key(sq + skv + d), 3)
+    q = jax.random.normal(kq, (1, hq, sq, d))
+    k = jax.random.normal(kk, (1, hkv, skv, d))
+    v = jax.random.normal(kv, (1, hkv, skv, d))
+
+    def flash(q, k, v):
+        return pallas_flash_attention(q, k, v, causal=causal, interpret=True)
+
+    def ref(q, k, v):
+        return _softmax_reference(q, k, v, causal)[0]
+
+    traced = jax.make_jaxpr(jax.grad(_sq_loss(flash), argnums=(0, 1, 2)))(
+        q, k, v)
+    assert flash_call_blocks(traced.jaxpr) == {
+        "flash_fwd": fwd, "flash_dq": dq, "flash_dkv": dkv}
+    assert {kind for kind, _, _ in asked} == {"fwd", "dq", "dkv"}
+    assert all(shape == (sq, skv) and mask == dict(
+        causal=causal, window=None) for _, shape, mask in asked)
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    want, want_vjp = jax.vjp(ref, q, k, v)
+    assert jnp.max(jnp.abs(out - want)) < 1e-5
+    for a, b in zip(vjp(2 * out), want_vjp(2 * want)):
+        assert jnp.max(jnp.abs(a - b)) < 1e-4
+
+
+def pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, the ones inside a
+    ``custom_vjp`` or a ``jit`` too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from pallas_calls(inner)
+
+
+def flash_call_blocks(jaxpr):
+    """``{kernel name: (bq, bkv)}`` of the flash calls traced under
+    ``jaxpr``: the rows of the ``q`` and of the ``k`` block, the first
+    two operands of all three kernels."""
+    return {
+        call.params["name"]: tuple(
+            mapping.block_shape[2].block_size
+            for mapping in call.params["grid_mapping"].block_mappings[:2])
+        for call in pallas_calls(jaxpr)}

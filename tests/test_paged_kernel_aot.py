@@ -22,9 +22,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks.lib.trace import short_name
+from tests.ops.test_flash_pallas import flash_call_blocks, pallas_calls
 from scaletorch_tpu.ops.pallas.flash import (
     MAX_CAUSAL_STEPS,
     causal_block_plan,
+    flash_blocks,
     pallas_flash_attention,
 )
 from scaletorch_tpu.ops.pallas.paged_attention import (
@@ -1460,17 +1462,26 @@ def _flash_training_digest():
     (lambda: _decode_digest(8, 32, 4, 128, 216, 12, window=2048, ring=129),
      "50dd7211c900f3421087126cd90a1fc511882ac33c99322b3b8d0f959ee9b184"),
     (_flash_training_digest,
-     "08e50f14919a8d4b4b16d06f14ea01868ee69173b8eaed03455f9073dffb3760"),
+     "e74c658a1a00225f889938675350843f602ddc170bccd408ee464ae149fab54a"),
     (lambda: _flash_digest(8, 32, 4, 3072, 128, 128, window=2048),
      "b38cb64712e0d11977e7af01c6820523ad3869c25db1c63ad9502649371e2dde"),
     (lambda: _flash_digest(8, 128, 128, 3072, 192, 128),
      "3bbf465cd93715673f249ffd8859cbdde974915a56df64eda4929b7f8f31d6c8"),
     (lambda: _flash_digest(1, 16, 8, 512, 128, 128),
      "550b2429ad94ca8d6e5b811ea603580c20c7971f73b724c36b9cef44820e8485"),
+    # PR 62, computed at its parent `e199970`: the 8,192-token rows, the
+    # only serving forwards long enough for the rule to have moved them
+    (lambda: _flash_digest(1, 64, 4, 8192, 192, 128),
+     "d11a365e000e0411a630ea6fb241202bc770304a3188e43f4dbaaf075cac487b"),
+    (lambda: _flash_digest(1, 32, 32, 8192, 192, 128),
+     "18733b9d3f97db2dcd0a5ef3f4ca3dbde4213b588d0ec7b57a01758a10b624ce"),
+    (lambda: _flash_digest(1, 16, 8, 8192, 128, 128),
+     "0d17ee40655e127933224a6a397f422069d362211fd77b9f5b2cc9a2e3aca671"),
 ], ids=["decode-longgen", "decode-qwen3-next", "decode-trinity-full",
         "decode-trinity-window", "flash-training-fwd-bwd",
         "flash-trinity-window", "flash-openpangu-192-128",
-        "flash-longgen-row"])
+        "flash-longgen-row", "flash-mimo-full-row", "flash-kimi-linear-row",
+        "flash-training-fwd"])
 def test_callers_without_a_sink_at_one_width_trace_to_what_they_did(
         digest, want):
     """PR 59 gave the decode kernel and the flash forward a value width
@@ -1482,7 +1493,10 @@ def test_callers_without_a_sink_at_one_width_trace_to_what_they_did(
     the one-row call of the dense cells) hashes to what it hashed to at
     the parent (`7c85100`, computed there with the same functions). A PR
     that means to change one of these kernels measures the cells that
-    run it and writes the new digests here."""
+    run it and writes the new digests here. PR 62 did for the training
+    cell's forward and backward: ``flash_dq`` runs in 1,024 x 512 blocks
+    and ``flash_dkv`` in 1,024 x 1,024 there (``flash_blocks``), the
+    forward and every serving caller in the blocks they had."""
     assert digest() == want
 
 
@@ -1670,6 +1684,12 @@ def _flash_kinds(calls):
     return sorted({_flash_kernel(name) for name in calls})
 
 
+# what ``flash_blocks`` says at qwen3-0.6b-train's shape, written out: a
+# later edit that sends the training call back to 512 x 512 fails here
+TRAINING_BLOCKS = {"flash_fwd": (512, 512), "flash_dq": (1024, 512),
+                   "flash_dkv": (1024, 1024)}
+
+
 def test_training_shape_is_three_kernels_the_roofline_can_find(one_chip):
     """qwen3-0.6b-train at seq 8192: 1 x 16 / 8 heads x 8192 x 128, bf16,
     causal. Forward and gradient compile; the program holds the three
@@ -1699,9 +1719,52 @@ def test_training_shape_is_three_kernels_the_roofline_can_find(one_chip):
         share = json.load(f)["reducer"]["patterns"]
     assert [n for n in calls if any(re.search(p, n) for p in share)] == calls
 
-    plan = causal_block_plan(8192, 8192, 512, 512)
-    assert (plan.live, plan.dead) == (136, 0)  # of 256: no dead grid step
-    assert len(plan.by_query[0]) == len(plan.by_key[0]) == 136
+    # the blocks are the rule's (``flash_blocks``: PERF.md, PR 62), a pair
+    # a kernel, and no plan at them holds a dead grid step
+    traced = jax.jit(_flash_grad()).trace(
+        *_flash_args(one_chip, 16, 8, 8192, 128))
+    blocks = flash_call_blocks(traced.jaxpr.jaxpr)
+    assert blocks == {"flash_" + kind: flash_blocks(kind, 8192, 8192)
+                      for kind in ("fwd", "dq", "dkv")}
+    assert blocks == TRAINING_BLOCKS, "the rule's answer at the cell's shape"
+    grids = {call.params["name"]: call.params["grid_mapping"].grid
+             for call in pallas_calls(traced.jaxpr.jaxpr)}
+    for name, (bq, bkv) in blocks.items():
+        plan = causal_block_plan(8192, 8192, bq, bkv)
+        # a query block sees the key blocks up to its own last row
+        assert plan.live == sum(
+            -(-(i + 1) * bq // bkv) for i in range(8192 // bq))
+        assert plan.dead == 0
+        assert len(plan.by_query[0]) == len(plan.by_key[0]) == plan.live
+        heads, rep = ((8, (2,)) if name == "flash_dkv" else (16, ()))
+        assert grids[name] == (1, heads, plan.live, *rep), grids
+
+
+@pytest.mark.parametrize("blocks", [None, (1024, 2048)],
+                         ids=["the-rule", "1024x2048"])
+def test_flash_dkv_carries_the_vmem_limit_its_blocks_compute(
+        one_chip, blocks):
+    """``flash_dkv`` at the cell's shape: the lowered call carries what
+    ``_vmem_limit`` computes from its blocks and the backward compiles
+    to its two Mosaic calls. In the rule's blocks that is None, Mosaic's
+    16 MiB default (1,024 x 1,024 needs 9). At 1,024 x 2,048 the call
+    needs 17 MiB (AOT, PR 62, bisected) and does not compile without
+    the limit; the sum asks for 24."""
+    from scaletorch_tpu.ops.pallas.flash import flash_block_backward
+
+    q, k, v = _flash_args(one_chip, 16, 8, 8192, 128)
+    lse = jax.ShapeDtypeStruct((1, 16, 8192), jnp.float32, sharding=one_chip)
+    kw = dict(block_q=blocks[0], block_kv=blocks[1]) if blocks else {}
+    traced = jax.jit(lambda q, k, v, out, lse, g: flash_block_backward(
+        q, k, v, out, lse, g, causal=True, **kw)).trace(q, k, v, q, lse, q)
+    bq, bkv = flash_call_blocks(traced.jaxpr.jaxpr)["flash_dkv"]
+    assert (bq, bkv) == (blocks or flash_blocks("dkv", 8192, 8192))
+    (limit,) = [call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+                for call in pallas_calls(traced.jaxpr.jaxpr)
+                if call.params["name"] == "flash_dkv"]
+    assert limit == (24 * 2 ** 20 if blocks else None)
+    calls = _mosaic_calls(traced.lower().compile().as_text())
+    assert len(calls) == 2 and _named(calls, "flash_dkv"), calls
 
 
 def test_flash_forward_alone_is_one_call(one_chip):
@@ -1709,18 +1772,6 @@ def test_flash_forward_alone_is_one_call(one_chip):
         one_chip, lambda q, k, v: pallas_flash_attention(q, k, v),
         16, 8, 8192, 128)
     assert _flash_kinds(calls) == ["flash_fwd"] and len(calls) == 1, calls
-
-
-def _pallas_calls(jaxpr):
-    """Every ``pallas_call`` equation under ``jaxpr``, the ones inside a
-    ``custom_vjp`` or a ``jit`` too."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for param in eqn.params.values():
-            inner = getattr(param, "jaxpr", param)
-            if hasattr(inner, "eqns"):
-                yield from _pallas_calls(inner)
 
 
 @pytest.mark.parametrize("d", [128, 64, 256])
@@ -1732,14 +1783,15 @@ def test_flash_forward_holds_its_statistics_a_register_wide(one_chip, d):
     PR 38). A later edit that narrows them fails here, on a CPU."""
     traced = jax.jit(lambda q, k, v: pallas_flash_attention(q, k, v)).trace(
         *_flash_args(one_chip, 16, 8, 8192, d))
-    (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+    (call,) = pallas_calls(traced.jaxpr.jaxpr)
     assert call.params["name"] == "flash_fwd"
+    bq, _ = flash_blocks("fwd", 8192, 8192)
     scratch = call.params["grid_mapping"].scratch_avals
     assert [(str(ref.memory_space), ref.shape, ref.dtype)
             for ref in scratch] == [
-        ("vmem", (512, d), jnp.float32),     # the output's accumulator
-        ("vmem", (512, 128), jnp.float32),   # running max
-        ("vmem", (512, 128), jnp.float32),   # running sum
+        ("vmem", (bq, d), jnp.float32),     # the output's accumulator
+        ("vmem", (bq, 128), jnp.float32),   # running max
+        ("vmem", (bq, 128), jnp.float32),   # running sum
     ]
     assert len(_mosaic_calls(traced.lower().compile().as_text())) == 1
 

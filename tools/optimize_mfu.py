@@ -148,9 +148,12 @@ def main() -> None:
                          "default: detect from the attached device")
     ap.add_argument("--flash-blocks", nargs="*", default=None,
                     metavar="BQxBKV",
-                    help="also sweep flash tile sizes on the best "
-                         "gc/batch point, e.g. 256x512 512x512 512x1024 "
-                         "(sets SCALETORCH_TPU_FLASH_BLOCK_Q/KV per run)")
+                    help="also run the best gc/batch point with all three "
+                         "flash kernels at one uniform pair, e.g. 512x512 "
+                         "1024x1024 (sets SCALETORCH_TPU_FLASH_BLOCK_Q/KV "
+                         "per run: they override flash.flash_blocks, the "
+                         "rule that picks a pair a kernel from the "
+                         "shapes when they are unset)")
     args = ap.parse_args()
 
     # Validate BEFORE the expensive sweeps: a typo'd spec must not crash
@@ -203,8 +206,9 @@ def main() -> None:
 
     ok = [r for r in results if "mfu" in r]
     if ok and flash_blocks:
-        # Tile-size sweep on the winning shape: the kernel reads the env
-        # registry at trace time, so each variant re-jits with its tiles.
+        # Tile-size sweep on the winning shape: the kernels read the env
+        # registry at trace time (set, it overrides their rule), so each
+        # variant re-jits with its tiles.
         best_label = max(ok, key=lambda r: r["mfu"])["label"]
         best_shape = next(v for label, v in variants if label == best_label)
         for bq, bkv in flash_blocks:
