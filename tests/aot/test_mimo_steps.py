@@ -5,9 +5,9 @@ their layers, each a program parameter, and neither program copies a
 weight-sized slice of a stack from HBM to HBM (the parent's decode
 program copied 0.81 GB of them every step, ``fusion.519`` - ``.523``).
 
-In a file of its own, with ``tests/test_paged_kernel_aot.py``'s fixture
-and helpers: ``--dist loadfile`` gives a file to one worker, that file
-is the run's longest, and these two programs are ~55 s of compiling.
+In a file of its own, over this folder's fixture and helpers
+(``conftest.one_chip``, ``programs.py``): ``--dist loadfile`` gives a
+file to one worker, and these two programs are ~55 s of compiling.
 Nothing at import time touches the TPU compiler.
 """
 
@@ -17,12 +17,11 @@ import jax
 import pytest
 
 from scaletorch_tpu.inference.decode import ByLayer
-from tests.test_paged_kernel_aot import (  # noqa: F401  (one_chip: a fixture)
+from tests.aot.programs import (
     _CHOSEN,
     _programs_of,
     _serving_model,
     _weight_copies,
-    one_chip,
 )
 
 NAME = "mimo-v2-flash-serve"
@@ -37,7 +36,7 @@ LAYERED = {
 
 
 @pytest.fixture(scope="module")
-def programs(one_chip):  # noqa: F811
+def programs(one_chip):
     decode, prefill, _ = _programs_of(one_chip, NAME)
     return {"decode": decode.as_text(), "prefill": prefill.as_text()}
 

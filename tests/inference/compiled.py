@@ -3,7 +3,14 @@ that call it in a loop.
 
 Run operation by operation, a cached forward traces its layer loop anew
 at every call: a decode loop of 40 steps pays 40 traces for one shape.
-A test that calls a forward in a loop calls a compiled one.
+A test that calls a forward in a loop calls a compiled one, and a test
+that needs a compiled program finds it in the run's compile cache: a
+temporary directory that ``tests/conftest.py`` makes (or the caller's
+``JAX_COMPILATION_CACHE_DIR``) and every worker and subprocess of the
+run shares, so the second engine over a program, in whatever file or
+process, reads what the first compiled and the layouts it asked for
+(``decode.load_orders``). The process that made the directory removes
+it at the end of its session.
 """
 
 import functools

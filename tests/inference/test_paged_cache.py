@@ -3,8 +3,10 @@ randomized admit/retire/quarantine schedules, radix-tree prefix
 correctness (longest match, page-boundary splits, refcount-gated
 eviction), the page scatter/gather pair against the dense cache ops,
 the Pallas paged-decode kernel in interpret mode against the lax
-fallback oracle, the paged teacher-forced parity harness, and the
-pool's ``kv_cache_bytes``. Quick tier, CPU.
+fallback oracle (one case here; its block walk, its walk from slot to
+slot and the page write are ``tests/ops/test_paged_decode_blocks.py`` and
+``test_paged_decode_chain.py``), the paged teacher-forced parity harness,
+and the pool's ``kv_cache_bytes``. Quick tier, CPU.
 """
 
 import random
@@ -29,16 +31,10 @@ from scaletorch_tpu.models import llama, qwen3
 from scaletorch_tpu.models.layers import cached_sdpa_attention, write_kv_cache
 from scaletorch_tpu.ops.pallas.paged_attention import (
     TRASH_PAGE,
-    _next_live_slot,
-    _pages_per_block,
-    _slot_walk,
-    chained_first_blocks,
     paged_attention,
     paged_gather_kv,
-    paged_write,
     paged_write_kv,
     pallas_paged_decode_attention,
-    pallas_paged_write,
 )
 from tests.inference.compiled import compiled_forward_cached
 
@@ -317,331 +313,6 @@ class TestPagedPrimitives:
                 q, pool_k, pool_k, tables, jnp.zeros((self.B,), jnp.int32))
 
 
-def _poisoned_case(hkv, n_rep, d, page_size, max_pages, pos, *, window=None,
-                   shared=None, seed=0):
-    """One slot a position of ``pos`` (-1: a slot with no key) over a
-    float32 pool whose TRASH page and every page no live key sits on are
-    all NaN; a table holds TRASH or such a page wherever the walk does
-    not go (past the live length, and before a ``window``'s first page).
-    ``shared`` (i, j): slot j's first two pages are slot i's. Returns
-    the kernel's inputs and the fallback's answer from the same pool
-    with the NaN zeroed."""
-    rng = np.random.default_rng(seed)
-    pos = np.asarray(pos)
-    b = len(pos)
-    n_live = np.clip(pos // page_size + 1, 0, max_pages)
-    first = np.zeros(b, int) if window is None else \
-        np.maximum(pos - window + 1, 0) // page_size
-    n_pages = b * max_pages + 1
-    tables = rng.permutation(np.arange(1, n_pages)).reshape(b, max_pages)
-    if shared is not None:
-        tables[shared[1], :2] = tables[shared[0], :2]
-    live = np.zeros(n_pages, bool)
-    for row, f, n in zip(tables, first, n_live):
-        live[row[f:n]] = True
-    for row, f, n in zip(tables, first, n_live):
-        off = np.r_[0:f, n:max_pages]
-        row[off] = np.where(rng.random(len(off)) < 0.5, TRASH_PAGE,
-                            rng.choice(np.flatnonzero(~live), len(off)))
-    shape = (n_pages, hkv, page_size, d)
-    pool_k = rng.standard_normal(shape, np.float32)
-    pool_v = rng.standard_normal(shape, np.float32)
-    q = jnp.asarray(rng.standard_normal((b, hkv * n_rep, d), np.float32))
-    tables = jnp.asarray(tables, jnp.int32)
-    pos = jnp.asarray(pos, jnp.int32)
-    want = cached_sdpa_attention(
-        q[:, :, None], paged_gather_kv(jnp.asarray(pool_k), tables),
-        paged_gather_kv(jnp.asarray(pool_v), tables),
-        pos[:, None], window=window)[:, :, 0]
-    # poisoned copies: jnp.asarray may alias the numpy buffer the
-    # oracle above is still reading
-    dead = ~live[:, None, None, None]
-    return (q, jnp.asarray(np.where(dead, np.nan, pool_k)),
-            jnp.asarray(np.where(dead, np.nan, pool_v)), tables, pos), \
-        np.asarray(want)
-
-
-class TestPagedDecodeKernelBlocks:
-    """The kernel walks a slot's live pages a block of
-    ``_pages_per_block`` at a time (all KV heads of a page in one copy):
-    parity with the gather fallback where blocks begin, end and are
-    ragged, over the head layouts and page sizes the models use."""
-
-    HKV = 2
-
-    def _case(self, n_rep, d, page_size, even, seed=0):
-        """Six slots, one at each edge of the block walk (slots 4 and 5
-        share their first two pages), over a poisoned pool
-        (``_poisoned_case``). Returns the kernel's inputs, the
-        fallback's answer and the pages of a table past its last whole
-        block."""
-        ppb = _pages_per_block(page_size, self.HKV, d, jnp.float32, 10 ** 6)
-        max_pages = 2 * ppb if even else 2 * ppb - 3
-        bk, top = ppb * page_size, max_pages * page_size - 1
-        args, want = _poisoned_case(
-            self.HKV, n_rep, d, page_size, max_pages,
-            [0, page_size - 1, page_size, bk - 1, bk, top], shared=(4, 5),
-            seed=seed)
-        return args, want, max_pages % ppb
-
-    @pytest.mark.parametrize("even", [True, False],
-                             ids=["whole-blocks", "short-last-block"])
-    @pytest.mark.parametrize("page_size", [8, 16])
-    @pytest.mark.parametrize("d", [64, 128])
-    @pytest.mark.parametrize("n_rep", [1, 2, 4, 8])
-    def test_block_edges_match_fallback(self, n_rep, d, page_size, even):
-        args, want, remainder = self._case(n_rep, d, page_size, even)
-        assert (remainder == 0) == even
-        out = np.asarray(pallas_paged_decode_attention(*args, interpret=True))
-        assert np.isfinite(out).all()
-        np.testing.assert_allclose(out, want, atol=5e-6)
-
-    def test_dead_pages_never_reach_the_result(self):
-        """Every page no live key sits on is NaN (TRASH included): one
-        fetch of any of them, or one unmasked dead key, and the output
-        is NaN. The fallback itself cannot take this pool (0 x NaN in
-        its value product), which is why the oracle reads it zeroed."""
-        (q, pool_k, pool_v, tables, pos), want, _ = self._case(
-            2, 128, 16, False, seed=1)
-        assert bool(jnp.isnan(pool_k[TRASH_PAGE]).all())
-        assert bool(jnp.isnan(pool_v).any(axis=(1, 2, 3)).sum() > len(pos))
-        out = np.asarray(pallas_paged_decode_attention(
-            q, pool_k, pool_v, tables, pos, interpret=True))
-        assert np.isfinite(out).all()
-        np.testing.assert_allclose(out, want, atol=5e-6)
-
-    @pytest.mark.parametrize("page_size,hkv,d,dtype,max_pages,want", [
-        (16, 8, 128, jnp.bfloat16, 96, 8),    # the serving cell: 1 MiB
-        (8, 8, 128, jnp.bfloat16, 96, 16),    # 128 lanes at page 8
-        (32, 8, 128, jnp.bfloat16, 48, 4),
-        (16, 1, 128, jnp.bfloat16, 96, 8),    # one KV head of a tp shard
-        (16, 8, 128, jnp.bfloat16, 5, 5),     # a table shorter than a block
-        (16, 32, 256, jnp.float32, 96, 1),    # 512 KiB a page: the budget caps
-        (4, 2, 8, jnp.float32, 4, 4),
-    ])
-    def test_pages_per_block_follows_shapes(self, page_size, hkv, d, dtype,
-                                            max_pages, want):
-        assert _pages_per_block(page_size, hkv, d, dtype, max_pages) == want
-
-    def test_negative_position_reads_nothing(self):
-        # a slot with no key at all (position -1) walks zero blocks
-        (q, pool_k, pool_v, tables, pos), _, _ = self._case(2, 128, 16, True)
-        out = pallas_paged_decode_attention(
-            q, pool_k, pool_v, tables, jnp.full_like(pos, -1), interpret=True)
-        assert bool((out == 0).all())
-
-
-def _plain_chain(n_live):
-    """The walk one slot after another, as the kernel makes it: (slots
-    walked, slots that found their first block started, for each slot
-    the slot whose first block it starts or None)."""
-    walked = chained = 0
-    in_flight = False
-    starts = []
-    for b, n in enumerate(n_live):
-        if n <= 0:
-            starts.append(None)
-            continue
-        walked += 1
-        chained += in_flight
-        later = [s for s in range(b + 1, len(n_live)) if n_live[s] > 0]
-        starts.append(later[0] if later else None)
-        in_flight = bool(later)
-    return walked, chained, starts
-
-
-class TestDecodeKernelChain:
-    """The copy pipeline does not stop at a slot's end: behind its last
-    block a slot starts block 0 of the next slot that has a live page,
-    in the other landing buffer, and that slot does not start it again.
-    Parity with the fallback wherever the hand-over can go wrong: block
-    edges, dead slots looked past, the buffer parity after an odd walk,
-    a window's walk from mid-table; every page a walk must not touch is
-    NaN (``_poisoned_case``)."""
-
-    HKV = 2
-    PATTERNS = ["block-edges", "dead-slots", "one-live-slot",
-                "one-block-then-many", "window"]
-
-    @staticmethod
-    def _positions(pattern, bk, page_size):
-        """(positions, window, pages a table holds) of one pattern, in
-        keys ``bk`` a block."""
-        window = None
-        if pattern == "block-edges":    # ends on, short of, past an edge
-            pos = [bk - 1, bk - 2, bk, 2 * bk - 1, 2 * bk - 2, 2 * bk]
-        elif pattern == "dead-slots":   # first, alone between, two, last
-            pos = [-1, bk + 3, -1, 5, -1, -1, 2 * bk, -1]
-        elif pattern == "one-live-slot":
-            pos = [-1, -1, bk + 1, -1]
-        elif pattern == "one-block-then-many":   # 1, 2, 3, 1, 3, 1 blocks
-            pos = [3, 2 * bk - 1, 3 * bk - 5, 5, 2 * bk + 1, 9]
-        else:   # "window": up to two blocks of a walk from mid-table
-            window = bk + page_size + 3
-            pos = [2, window - 1, window, 2 * bk + 5, -1, 3 * bk - 3]
-        return pos, window, 3 * bk // page_size + 1
-
-    @pytest.mark.parametrize("pattern", PATTERNS)
-    @pytest.mark.parametrize("page_size", [8, 16])
-    @pytest.mark.parametrize("d", [128, 256])
-    @pytest.mark.parametrize("n_rep", [1, 2, 8])
-    def test_matches_fallback(self, n_rep, d, page_size, pattern):
-        ppb = _pages_per_block(page_size, self.HKV, d, jnp.float32, 10 ** 6)
-        pos, window, max_pages = self._positions(
-            pattern, ppb * page_size, page_size)
-        args, want = _poisoned_case(self.HKV, n_rep, d, page_size, max_pages,
-                                    pos, window=window)
-        assert bool(jnp.isnan(args[1][TRASH_PAGE]).all())
-        out = np.asarray(pallas_paged_decode_attention(
-            *args, interpret=True, window=window))
-        live = np.asarray(pos) >= 0
-        assert np.isfinite(out).all()
-        np.testing.assert_allclose(out[live], want[live], atol=5e-6)
-        assert (out[~live] == 0).all()
-
-    @pytest.mark.parametrize("pattern", PATTERNS)
-    def test_every_started_copy_is_waited_for_once(self, pattern):
-        """Under the TPU interpreter a copy happens when it is WAITED
-        for, semaphores count and a buffer never written reads NaN: a
-        first block nobody started would wait for ever, one that landed
-        in the wrong buffer would reduce NaN."""
-        from jax.experimental.pallas import tpu as pltpu
-
-        pos, window, max_pages = self._positions(pattern, 128, 16)
-        args, want = _poisoned_case(self.HKV, 2, 128, 16, max_pages, pos,
-                                    window=window)
-        out = np.asarray(pallas_paged_decode_attention(
-            *args, window=window, interpret=pltpu.InterpretParams(
-                dma_execution_mode="on_wait", uninitialized_memory="nan")))
-        live = np.asarray(pos) >= 0
-        np.testing.assert_allclose(out[live], want[live], atol=5e-6)
-
-    @pytest.mark.parametrize("window", [None, 40], ids=["full", "window"])
-    @pytest.mark.parametrize("pos", [
-        [5, 17, 200, 31], [-1, 5, -1, -1, 40, -1], [-1, -1, -1], [7],
-        [-1, 300, 9999, 0],
-    ], ids=["all-live", "dead-between", "all-dead", "one", "past-table"])
-    def test_the_counter_is_the_kernel_s_rule(self, pos, window):
-        """``chained_first_blocks`` (what the engine counts) against the
-        walk one slot after another, and the kernel's own search for the
-        slot it hands its pipeline to against the same walk."""
-        page_size, max_pages = 8, 32
-        _, n_live = _slot_walk(np.asarray(pos), page_size, max_pages, window,
-                               xp=np)
-        walked, chained, starts = _plain_chain(n_live)
-        assert chained_first_blocks(
-            pos, page_size, max_pages, window) == (walked, chained)
-        pos_ref, n = jnp.asarray(pos, jnp.int32), len(pos)
-        for b, want in enumerate(starts):
-            if n_live[b] > 0:
-                got = int(_next_live_slot(
-                    pos_ref, b, n,
-                    lambda p: _slot_walk(p, page_size, max_pages, window)[1]))
-                assert got == (n if want is None else want)
-
-
-class TestPageWriteInPlace:
-    """``paged_write`` (the Mosaic page write, interpret mode) against
-    ``paged_write_kv`` into one layer of the whole pool, bit for bit."""
-
-    PS, MP, D = 4, 4, 8
-
-    def _case(self, rows, heads, starts, mask, layers, layer, seed=0):
-        rng = np.random.default_rng(seed)
-        slots = len(starts)
-        pool = jnp.asarray(rng.standard_normal(
-            (layers, slots * self.MP + 1, heads, self.PS, self.D)),
-            jnp.float32)
-        tables = jnp.asarray(rng.permutation(
-            np.arange(1, slots * self.MP + 1)).reshape(slots, self.MP),
-            jnp.int32)
-        new = jnp.asarray(rng.standard_normal(
-            (slots, heads, rows, self.D)), jnp.float32)
-        positions = jnp.asarray(
-            np.asarray(starts)[:, None] + np.arange(rows), jnp.int32)
-        mask = None if mask is None else jnp.asarray(mask)
-        return pool, new, positions, tables, mask, layer
-
-    @pytest.mark.parametrize("rows,heads,starts,mask,layers,layer,trash", [
-        # S = 1 (decode): any offset in the page
-        (1, 2, [0, 5, 11, 7], None, 1, 0, "same"),
-        (1, 4, [3, 14, 9], None, 3, 2, "same"),         # MHA-sized, layer 2
-        (1, 2, [0, 5, 11, 7], [True, False, True, True], 3, 1, "same"),
-        (1, 2, [0, 5, 11, 7], [True, False, True, False], 3, 1, "shared"),
-        (1, 2, [0, 5, 16, 40], None, 2, 1, "shared"),   # past the table
-        # S = several pages (prefill): page-aligned starts
-        (12, 2, [0, 4, 0], None, 1, 0, "same"),
-        (12, 4, [0, 4, 0], None, 3, 1, "same"),         # layer 1 of 3
-        (10, 2, [0, 4, 0], None, 2, 1, "same"),         # a partly filled page
-        (3, 2, [0, 8, 12], None, 2, 0, "same"),         # less than one page
-        (10, 2, [0, 4, 0], [True, False, True], 2, 1, "shared"),
-        (10, 2, [0, 4, 0], [False, False, True], 2, 1, "shared"),
-        (12, 2, [0, 8, 12], None, 2, 1, "shared"),      # runs off the table
-    ], ids=["row", "row-mha-layer2", "row-one-masked", "row-two-masked",
-            "row-past-table", "pages", "pages-mha-layer1", "pages-ragged",
-            "pages-short", "pages-one-masked", "pages-two-masked",
-            "pages-past-table"])
-    def test_bit_identical_to_the_scatter(self, rows, heads, starts, mask,
-                                          layers, layer, trash):
-        pool, new, positions, tables, mask, layer = self._case(
-            rows, heads, starts, mask, layers, layer)
-        want = np.asarray(paged_write_kv(
-            pool, new, positions, tables, self.PS, mask, layer=layer))
-        got = np.asarray(pallas_paged_write(
-            pool, new, positions, tables, mask, layer=layer, interpret=True))
-        # every page a slot owns, in every layer: the written layer equal
-        # to the scatter's, every other layer untouched
-        assert np.array_equal(got[:, 1:], want[:, 1:])
-        others = [i for i in range(layers) if i != layer]
-        assert np.array_equal(got[others], np.asarray(pool)[others])
-        if trash == "same":     # at most one row for TRASH: same bytes
-            assert np.array_equal(got[:, TRASH_PAGE], want[:, TRASH_PAGE])
-        else:   # TRASH holds some writer's rows: garbage by contract
-            assert np.isfinite(got[:, TRASH_PAGE]).all()
-            assert not np.array_equal(got[layer, TRASH_PAGE],
-                                      np.asarray(pool)[layer, TRASH_PAGE])
-
-    def test_dispatcher_takes_the_scatter_off_the_chip(self):
-        pool, new, positions, tables, mask, layer = self._case(
-            1, 2, [0, 5, 11, 7], [True, False, True, True], 3, 1)
-        want = paged_write_kv(pool, new, positions, tables, self.PS, mask,
-                              layer=layer)
-        got = paged_write(pool, new, positions, tables, mask, layer=layer)
-        assert jnp.array_equal(got, want)
-        forced = paged_write(pool, new, positions, tables, mask, layer=layer,
-                             kernel=True, interpret=True)
-        assert jnp.array_equal(forced[:, 1:], want[:, 1:])
-
-    @pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 4)], ids=["gqa", "mha"])
-    def test_kernel_reads_its_layer_of_the_whole_pool(self, hq, hkv):
-        """The 5-D decode kernel at a non-zero layer against the gather
-        fallback on that layer, and against the kernel on the layer
-        sliced out."""
-        rng = np.random.default_rng(3)
-        slots, layers, layer = 3, 3, 2
-        shape = (layers, slots * self.MP + 1, hkv, self.PS, self.D)
-        pool_k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-        pool_v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-        tables = jnp.asarray(rng.permutation(
-            np.arange(1, slots * self.MP + 1)).reshape(slots, self.MP),
-            jnp.int32)
-        q = jnp.asarray(rng.standard_normal((slots, hq, self.D)), jnp.float32)
-        pos = jnp.asarray([2, 15, 9], jnp.int32)
-        out = pallas_paged_decode_attention(
-            q, pool_k, pool_v, tables, pos, layer=layer, interpret=True)
-        fallback = paged_attention(
-            q[:, :, None], pool_k, pool_v, tables, pos[:, None],
-            page_size=self.PS, layer=layer, kernel=False)[:, :, 0]
-        np.testing.assert_allclose(np.asarray(out), np.asarray(fallback),
-                                   atol=2e-6)
-        sliced = pallas_paged_decode_attention(
-            q, pool_k[layer], pool_v[layer], tables, pos, interpret=True)
-        assert jnp.array_equal(out, sliced)
-        assert jnp.array_equal(
-            paged_gather_kv(pool_k, tables, layer),
-            paged_gather_kv(pool_k[layer], tables))
-
-
 # fp32 logits of the paged and the dense path: both sum the same terms
 # over the same operand shapes (seq_limit crop), but they are two
 # compiled programs (gather/scatter vs dynamic-update-slice) and XLA is
@@ -855,3 +526,4 @@ class TestPoolBytes:
             )
             assert cache_nbytes(engine.cache) == kv_cache_bytes(
                 cfg, engine.num_pages, 4, cfg.dtype)
+

@@ -11,7 +11,7 @@ here: the leaf is stored transposed as a new array, the steps are built
 to read it (one decode program, one prefill program a shape), and the
 tokens are those of the bare jitted steps and of the teacher-forced
 paged harness on the caller's own arrays. What the v5e's compiler asks
-for at the cells' shapes is in ``tests/test_paged_kernel_aot.py``.
+for at the cells' shapes is in ``tests/aot/``.
 
 A stack that is asked for AND that the program reads only one static
 layer at a time is stored as its layers (PERF.md, PR 60): the rule reads
@@ -51,6 +51,7 @@ from scaletorch_tpu.inference.kv_cache import (
 )
 from scaletorch_tpu.models import llama
 from scaletorch_tpu.models import mimo_v2_flash as mimo
+from tests.conftest import compile_cache_at
 from tests.inference.compiled import compiled_forward_cached
 from tests.models.test_mimo_v2_flash import tiny_config as tiny_mimo_config
 from tests.models.test_olmo_hybrid import seeded_params, tiny_config
@@ -591,14 +592,11 @@ def test_the_answer_is_kept_for_the_next_process(
 
     monkeypatch.setattr(engine_module, "compile_decode_for_layouts",
                         compile_for_layouts)
-    make_engine(model)
-    make_engine(model)
-    assert len(asked) == 2 and not list(tmp_path.iterdir())   # no cache
-    from scaletorch_tpu.env import compile_cache_dir
-
-    before = compile_cache_dir()
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-    try:
+    with compile_cache_at(None):
+        make_engine(model)
+        make_engine(model)
+        assert len(asked) == 2 and not list(tmp_path.iterdir())
+    with compile_cache_at(str(tmp_path)):
         first = make_engine(model)
         kept = list(tmp_path.glob("param_orders-*.json"))
         assert len(asked) == 3 and len(kept) == 1
@@ -622,11 +620,6 @@ def test_the_answer_is_kept_for_the_next_process(
         rid = second.submit([1, 2, 3], max_new_tokens=5)
         assert second.run()[rid].tokens == bare_steps_greedy(
             dense, [1, 2, 3], 5)
-    finally:
-        from jax.experimental.compilation_cache import compilation_cache
-
-        jax.config.update("jax_compilation_cache_dir", before)
-        compilation_cache.reset_cache()     # forget the directory that goes
 
 
 @pytest.mark.parametrize("placement", ["as-they-come", "two-leaves-moved"])

@@ -513,10 +513,13 @@ class TestDisconnectReleasesPages:
             sse_disconnect_after_first_token(
                 gw.port, {"prompt": [1, 2, 3, 4, 5],
                           "max_new_tokens": 1000, "stream": True})
+            # the engine's thread counts the outcome and THEN drops the
+            # slot's page references (``_retire_slot``): wait for both
             deadline = time.monotonic() + 30
             while time.monotonic() < deadline:
                 if gw.metrics.outcomes["aborted"] == 1 \
-                        and engine.metrics.outcomes["aborted"] == 1:
+                        and engine.metrics.outcomes["aborted"] == 1 \
+                        and not any(engine._slot_pages):
                     break
                 time.sleep(0.02)
             assert gw.metrics.outcomes["aborted"] == 1
