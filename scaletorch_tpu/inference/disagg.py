@@ -312,6 +312,12 @@ class DisaggregatedEngine(InferenceEngine):
     compiler chooses for the decode program) is not asked for; no
     benchmark cell runs this engine."""
 
+    # ``_timed_decode`` below waits for every step it dispatches: a
+    # readback finds the decode slice idle, so a step's tokens wait for
+    # the next dispatch, whose wait gives their consumers the
+    # interpreter, and this thread never stands aside for them
+    _DISPATCH_BLOCKS = True
+
     def _param_orders(self, params, steps):
         return None
 
@@ -655,6 +661,7 @@ class DisaggregatedEngine(InferenceEngine):
         self.metrics.tokens_generated += 1
         self.metrics.record_ttft(now - req.submit_time)
         if self.on_tokens is not None:
+            self.metrics.tokens_handed_at_readback += 1
             try:
                 self.on_tokens(-1, req.request_id, [token], now)
             except Exception:

@@ -294,6 +294,13 @@ class EngineMetrics:
     # or a TTL is learnt one step late; an end by length never is)
     decode_steps_ahead: int = 0
     decode_slot_steps_discarded: int = 0
+    # tokens handed to ``on_tokens`` at the readback of the step or the
+    # prefill call that made them, and tokens that waited for the next
+    # dispatch or their request's result (an engine whose dispatch
+    # blocks for its step): the two sum to ``tokens_generated`` while a
+    # hook is attached
+    tokens_handed_at_readback: int = 0
+    tokens_handed_later: int = 0
     slow_ticks: int = 0             # ticks over SLOW_TICK_S (gap included)
     queue_depth: int = 0
     active_slots: int = 0
@@ -419,6 +426,8 @@ class EngineMetrics:
             "decode_steps": self.decode_steps,
             "decode_steps_ahead": self.decode_steps_ahead,
             "decode_slot_steps_discarded": self.decode_slot_steps_discarded,
+            "tokens_handed_at_readback": self.tokens_handed_at_readback,
+            "tokens_handed_later": self.tokens_handed_later,
             "paged_slot_walks": self.paged_slot_walks,
             "paged_slot_walks_chained": self.paged_slot_walks_chained,
             "slow_ticks": self.slow_ticks,
@@ -650,14 +659,14 @@ class InferenceEngine:
         tokens. ``emitted_t`` is the ``time.monotonic()`` the engine
         read when the step's tokens were back on the host (one reading
         a step, the one the TPOT histogram is fed from): the first
-        stamp of the delivery leg, which the consumer carries on. The
-        tokens a tick emits are handed over right after the NEXT
-        dispatch of a decode step (or before an admission builds its
-        prefill call):
-        the moment the engine thread is about to block on the device,
-        so that whatever the consumer wakes (the gateway's event loop
-        shares this interpreter) gets the interpreter then and not
-        while the thread dispatches; a request that reaches its
+        stamp of the delivery leg, which the consumer carries on. A
+        step's tokens (or a prefill call's first tokens) are handed
+        over at its readback, all of them and before any slot's
+        retirement is booked: the decode loop runs one step ahead, so
+        the device has the next step to run while the consumers work
+        (an engine whose dispatch blocks for its step,
+        ``_DISPATCH_BLOCKS``, hands them over after its next dispatch
+        instead); a request that reaches its
         terminal result has its tokens handed over first. Host-side
         only: the hook sees tokens after the device->host transfer the
         engine already performs, so attaching it adds zero retraces
@@ -667,10 +676,23 @@ class InferenceEngine:
 
     ``on_dispatched`` (an attribute, None by default) is called with no
     argument on the ticking thread each time a decode step has been put
-    on the device, after the held tokens were handed over: the place
-    for host work that should run beside a step (the serving bridge
-    delivers the last tick's terminal results there).
+    on the device: the place for host work that should run beside a
+    step (the serving bridge delivers the last tick's terminal results
+    there).
+    ``on_handed_over`` (the same kind of attribute) is called after a
+    readback's tokens went to ``on_tokens``: the place to let their
+    consumers run (the serving bridge gives the interpreter to its
+    event loop there). An engine whose dispatch blocks never calls it.
     """
+
+    # Whether ``_dispatch`` returns only when the step it dispatched has
+    # run. Here it returns at once and the loop runs one step ahead, so
+    # a readback always has a step (or nothing left to run) behind it
+    # and its tokens are handed over there. An engine that blocks in its
+    # dispatch (DisaggregatedEngine) reads a step with the device idle:
+    # it keeps the tokens for its next dispatch, which lets go of the
+    # interpreter for a whole step (``_emit``, ``_dispatch``).
+    _DISPATCH_BLOCKS = False
 
     def __init__(
         self,
@@ -744,6 +766,7 @@ class InferenceEngine:
         # handed to on_tokens: see _release_tokens
         self._held_tokens: List[Tuple[int, int, List[int], float]] = []
         self.on_dispatched: Optional[Callable[[], None]] = None
+        self.on_handed_over: Optional[Callable[[], None]] = None
 
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
@@ -1718,7 +1741,6 @@ class InferenceEngine:
             if not self._admission_due():
                 return None
             free = [i for i, s in enumerate(self._slots) if not s.active]
-            self._release_tokens()
             # slot: (the prompt's tail to prefill, tokens shared before it)
             taken: Dict[int, Tuple[Sequence[int], int]] = {}
             for i in free:
@@ -1800,9 +1822,6 @@ class InferenceEngine:
         call already runs for it is thrown away when that step is read;
         the other admitted slots proceed."""
         with self._phase("engine.tick.prefill_wait"):
-            # about to block, with work on the device: nothing that was
-            # emitted (the step in flight's tokens) waits for the call
-            self._release_tokens()
             # by slot, from every call's arrays in one round trip
             first, finite = (
                 {i: by_call[call][row]
@@ -1818,9 +1837,8 @@ class InferenceEngine:
                 # skip radix registration for poison prompts — their
                 # pages hold non-finite K/V and must never be shared
                 self._quarantine(poisoned, now, where="prefill")
-            for i in admitted:
-                if not finite[i]:
-                    continue
+            healthy = [i for i in admitted if finite[i]]
+            for i in healthy:
                 if self.radix is not None:
                     slot = self._slots[i]
                     plen = len(slot.request.prompt)
@@ -1835,22 +1853,35 @@ class InferenceEngine:
                         # from here on — exempt from quarantine clears
                         # and shareable by later admissions
                         self._slot_frozen[i] = n
-                self._emit(i, int(first[i]), now)
+            self._emit([(i, int(first[i])) for i in healthy], now)
             self._update_page_gauges()
             self.metrics.queue_depth = len(self._queue)
 
-    def _release_tokens(self, request_id: Optional[int] = None) -> None:
-        """Hand the tokens emitted since the last release to
-        ``on_tokens``: all of them, or one request's. All of them once
-        the decode step is on the device, so that the hook's consumers
-        run beside it (pushed straight from the emit loop, 16 streams'
-        writes on the gateway's event loop took the interpreter from
-        this thread between two steps: 1.7 ms of a 36 ms tick on a
-        v5e), or before an admission builds its prefill call, which no
-        token waits for; one request's before its terminal result is
-        recorded, the other streams keeping their cadence. A raising
+    def _release_tokens(self, request_id: Optional[int] = None, *,
+                        at_readback: bool = False) -> None:
+        """Hand the held tokens to ``on_tokens``: all of them, or one
+        request's. All of them ``at_readback``: ``_emit`` calls this as
+        soon as a step's (or a prefill call's) tokens are recorded,
+        before any retirement is booked. The consumers' writes (16
+        streams' on the gateway's event loop, which shares the
+        interpreter) then run while this thread has a whole step of
+        slack before the device wants the next dispatch: the decode
+        loop runs one step ahead (``step()``). Until it did, the tokens
+        waited for the next dispatch so that those writes would not run
+        between two steps with the device idle (1.7 ms of a 36 ms tick
+        on a v5e, PERF.md, PR 27), and a tick that retired a slot held
+        every other stream's tokens for the retirement and the page
+        tables' upload (1.2-1.4 ms on one tick in ~32: the 95th
+        percentile of the inter-token gaps stood on those, PERF.md,
+        PR 64). An engine whose dispatch blocks for its step
+        (``_DISPATCH_BLOCKS``) is still where PR 27 found this one: it
+        hands all of them over after its next dispatch, and one
+        request's before that request's terminal result is recorded,
+        so the stream sees every token, then the result. A raising
         hook is disarmed (logged), never fatal: one bad consumer must
-        not take the whole decode batch down."""
+        not take the whole decode batch down; the batch it raised in is
+        dropped with it, no token of it is held or handed over
+        again."""
         if not self._held_tokens:
             return
         if request_id is None:
@@ -1861,6 +1892,10 @@ class InferenceEngine:
                 return
             self._held_tokens = [
                 h for h in self._held_tokens if h[1] != request_id]
+        if at_readback:
+            self.metrics.tokens_handed_at_readback += len(held)
+        else:
+            self.metrics.tokens_handed_later += len(held)
         hook = self.on_tokens
         if hook is None:
             return
@@ -1871,9 +1906,9 @@ class InferenceEngine:
             logger.exception("on_tokens hook raised; disarming the hook")
             self.on_tokens = None
 
-    def _emit(self, i: int, token: int, now: float) -> None:
-        """Record one generated token for slot i; retire the slot when a
-        stop condition hits."""
+    def _record_token(self, i: int, token: int, now: float) -> Optional[str]:
+        """Book one generated token to slot i and hold it for
+        ``on_tokens``; the stop condition it hits, if any."""
         slot = self._slots[i]
         req = slot.request
         slot.tokens.append(token)
@@ -1891,17 +1926,34 @@ class InferenceEngine:
         slot.last_token_t = now
         if self.on_tokens is not None:
             self._held_tokens.append((i, req.request_id, [token], now))
-
-        reason = None
         if req.eos_id is not None and token == req.eos_id:
-            reason = "eos"
-        elif slot.generated >= req.max_new_tokens:
-            reason = "length"
-        elif slot.position + slot.generated >= self.max_seq:
+            return "eos"
+        if slot.generated >= req.max_new_tokens:
+            return "length"
+        if slot.position + slot.generated >= self.max_seq:
             # continuing would feed a token at position >= max_seq —
             # past the end of the cache
-            reason = "max_seq"
-        if reason is not None:
+            return "max_seq"
+        return None
+
+    def _emit(self, rows: List[Tuple[int, int]], now: float) -> None:
+        """Emit what a step or a prefill call just read gave: one token
+        for each ``(slot, token)`` of ``rows``, all stamped ``now``.
+        Every token is recorded first, then they are all handed over
+        (``_release_tokens``) and ``on_handed_over`` runs; only after
+        that are the slots a stop condition hit retired, so no stream's
+        token waits for another's result, page release or radix
+        bookkeeping. A retiring stream's own last token went out with
+        the rest, so ``_finalize`` finds nothing of it held and the
+        stream still sees every token, then the result. An engine
+        whose dispatch blocks keeps them for that dispatch."""
+        ended = [(i, reason) for i, token in rows
+                 if (reason := self._record_token(i, token, now)) is not None]
+        if self._held_tokens and not self._DISPATCH_BLOCKS:
+            self._release_tokens(at_readback=True)
+            if self.on_handed_over is not None:
+                self.on_handed_over()
+        for i, reason in ended:
             self._retire_slot(i, "ok", reason=reason, now=now)
 
     def step(self) -> List[RequestResult]:
@@ -2034,10 +2086,9 @@ class InferenceEngine:
     def _dispatch(self, before: Optional[_InFlight],
                   admission: Optional[_Admission] = None
                   ) -> Optional[_InFlight]:
-        """Put the next decode step on the device, then hand over what
-        the host held back for that moment. With ``before`` None the
-        step is fed from the host: every active slot's last token at
-        its position. With ``before`` still on the device it is the
+        """Put the next decode step on the device. With ``before``
+        None the step is fed from the host: every active slot's last
+        token at its position. With ``before`` still on the device it is the
         step after it: the slots of ``before`` that cannot end at it
         by length, one position on, fed ITS sampled tokens without
         their leaving the device. With ``admission`` (a prefill call
@@ -2134,9 +2185,12 @@ class InferenceEngine:
         if before is not None or admission is not None:
             self.metrics.decode_steps_ahead += 1
         with self._phase("engine.tick.decode_wait"):
-            # the device has work: now whatever the consumers of the
-            # held tokens and results wake runs beside it
-            self._release_tokens()
+            if self._DISPATCH_BLOCKS:
+                # the step has run: what the last readback held goes
+                # out, and its consumers run when the next dispatch
+                # blocks
+                self._release_tokens()
+            # what the consumers of the results wake runs beside the step
             if self.on_dispatched is not None:
                 self.on_dispatched()
         return _InFlight(number, nxt, finite, positions, bound, stall)
@@ -2163,10 +2217,6 @@ class InferenceEngine:
         if not live:
             return
         with self._phase("engine.tick.decode_wait"):
-            # about to block: nothing that was emitted waits for it (a
-            # tick whose streams all end at this step dispatched none
-            # after it, and so handed nothing over)
-            self._release_tokens()
             if flight.stall > 0:
                 # an injected slow decode is booked where a real one
                 # would be: the host waiting on the step
@@ -2179,9 +2229,7 @@ class InferenceEngine:
             poisoned = [i for i, _ in live if not finite[i]]
             if poisoned:
                 self._quarantine(poisoned, now, where="decode")
-            for i, _ in live:
-                if finite[i]:
-                    self._emit(i, int(nxt[i]), now)
+            self._emit([(i, int(nxt[i])) for i, _ in live if finite[i]], now)
 
     def _close_tick(self, tick: int, tick_t0: float) -> None:
         """A tick that took over ``SLOW_TICK_S``, the time since the

@@ -148,18 +148,49 @@ class EngineWorker:
     gateway uses one to wake its dispatcher); callbacks run ON THE
     WORKER THREAD and must trampoline themselves onto the event loop.
 
-    What a tick leaves for the event loop (its terminal results, the
-    listeners' wake-up) is handed over right after the NEXT dispatch of
-    a decode step (``engine.on_dispatched``), as the engine does with
-    the tokens: the loop shares this interpreter, and it gets it when
-    this thread is about to block on the device, with a step (the
-    engine's decode loop runs one ahead) to work beside: the loop
-    writes the tick's frames one after another and sleeps between
-    none of them, so it is done well inside the step. A tick that
+    A step's tokens leave at its readback (``InferenceEngine._emit``:
+    all of them, before any retirement is booked), and the thread then
+    STANDS ASIDE for the consumers' event loop, which shares this
+    interpreter and writes nothing until it holds it
+    (``engine.on_handed_over`` -> ``_stand_aside``): it waits on
+    ``drained``, an event that a consumer clears when it posts to a
+    loop of this interpreter and sets when that loop has taken
+    everything posted (``ServingGateway._post`` / ``_drain_outbox`` put
+    their own event here), for at most half the time since it last
+    stood aside, which in a running loop is half a decode step. The
+    engine's decode loop runs one step ahead, so the next dispatch has
+    a whole step to happen in: half of it for the loop's back-to-back
+    writes (~1 ms for 16 streams on a 6.8 ms step, ~4 for 64 on a 25 ms
+    one), the other half for this thread's own ~1 ms of host work.
+    Until PR 64 the tokens waited for the next dispatch, where this
+    thread is about to block on the device (PERF.md, PR 27: the loop's
+    writes between two steps cost 1.7 ms of a 36 ms tick when no step
+    was queued ahead); the tick that retired a slot then held every
+    other stream's tokens 1.2-1.4 ms longer, and the 95th percentile of
+    the inter-token gaps stood on those ticks. An engine whose dispatch
+    blocks for its step (``DisaggregatedEngine``) has no step queued at
+    a readback: it keeps the old order and never calls the hook. A
+    consumer that never clears ``drained`` (the replica child's wire
+    server, serving/remote.py) is not waited for: its tokens are posted
+    at the readback, and its loop runs where this thread next blocks
+    (docs/serving_gateway.md: what a supervised replica gets of this).
+
+    What else a tick leaves for the event loop (its terminal results,
+    the listeners' wake-up) is handed over right after the NEXT
+    dispatch of a decode step (``engine.on_dispatched``): a result is
+    on no other stream's inter-token path, and the loop gets the
+    interpreter when this thread blocks on the device. A tick that
     leaves no slot decoding hands over at once, and a tick that freed a
     slot or a place in the engine's queue wakes the listeners at once
     (the dispatcher's backlog does not wait).
     """
+
+    # the thread stands aside for at most this share of the time since
+    # it last did (a running loop: of a decode step), and never longer
+    # than ``idle_wait_s``, its wait for work when idle (after an idle
+    # spell the interval says nothing): a loop that is slower, stopped
+    # or gone is not waited for beyond
+    STAND_ASIDE_SHARE = 0.5
 
     def __init__(self, engine: InferenceEngine, *, replica_id: str = "r0",
                  idle_wait_s: float = 0.01,
@@ -173,6 +204,10 @@ class EngineWorker:
         self.max_drain_ticks = max_drain_ticks
         engine.on_tokens = self._hook_tokens
         engine.on_dispatched = self._after_tick
+        engine.on_handed_over = self._stand_aside
+        self.drained = threading.Event()  # see the class docstring
+        self.drained.set()
+        self._stood_aside_t = 0.0
         self._finished: List[RequestResult] = []  # returned, not delivered
         self._unannounced = False                 # a tick no listener heard
         self._inbox: "queue.SimpleQueue[Callable[[], None]]" = \
@@ -373,6 +408,20 @@ class EngineWorker:
         handlers = self._handlers.get(request_id)
         if handlers is not None:
             handlers.on_tokens(request_id, list(token_ids), emitted_t)
+
+    def _stand_aside(self) -> None:
+        """A readback's tokens are posted: give the interpreter to the
+        loop that writes them until it has taken them all, or for the
+        bound the class states. Blocking on the event is what lets go
+        of the interpreter (the switch interval is 5 ms: a loop that
+        is only woken waits for this thread's next blocking call)."""
+        now = time.monotonic()
+        bound = min((now - self._stood_aside_t) * self.STAND_ASIDE_SHARE,
+                    self.idle_wait_s)
+        self._stood_aside_t = now
+        if not self.drained.is_set():
+            with span("engine.tick_loop.stand_aside", self.engine.tracer):
+                self.drained.wait(bound)
 
     def _deliver(self, result: RequestResult) -> None:
         handlers = self._handlers.pop(result.request_id, None)
@@ -851,6 +900,14 @@ class ServingGateway:
                 self._owned_workers.append(worker)
             else:
                 self.workers[rid] = eng
+        # set while the outbox is empty: a worker thread of this
+        # interpreter stands aside on it after a readback's tokens
+        # (EngineWorker.drained; see _post and _drain_outbox)
+        self._outbox_drained = threading.Event()
+        self._outbox_drained.set()
+        for worker in self.workers.values():
+            if isinstance(worker, EngineWorker):
+                worker.drained = self._outbox_drained
         page_size = next(
             (w.page_size for w in self.workers.values()
              if getattr(w, "page_size", None)), 16)
@@ -1360,23 +1417,30 @@ class ServingGateway:
         event posted since the loop last drained the outbox wakes the
         loop; the rest of a tick's events (16 streams' tokens handed
         over in one go) ride that wake-up. The worker thread holds the
-        interpreter until it blocks on its step, so the loop finds the
-        whole batch and writes it back to back, sleeping nowhere
-        (``_drain_outbox``): one wake-up per stream made the loop take
-        the interpreter somewhere inside the batch, elsewhere in every
-        tick, and the streams' cadence jittered with it. A ``tokens``
-        event leaves with ``posted_t``, the second stamp of its
-        delivery leg, behind the worker's ``emitted_t``."""
+        interpreter until the whole batch is posted and then stands
+        aside for the loop (``EngineWorker._stand_aside``, on
+        ``_outbox_drained``: cleared here, set when the loop has taken
+        the batch), so the loop finds the whole batch and writes it
+        back to back, sleeping nowhere (``_drain_outbox``): one wake-up
+        per stream made the loop take the interpreter somewhere inside
+        the batch, elsewhere in every tick, and the streams' cadence
+        jittered with it. A ``tokens`` event leaves with ``posted_t``,
+        the second stamp of its delivery leg, behind the worker's
+        ``emitted_t``."""
         if event[0] == "tokens":
             event = ("tokens", (*event[1], time.monotonic()))
         with self._outbox_lock:
             first = not self._outbox
             self._outbox.append((pending, event))
+            if first:
+                self._outbox_drained.clear()
         if first:
             try:
                 self._loop.call_soon_threadsafe(self._drain_outbox)
             except RuntimeError:
-                pass  # loop closed: the clients are gone anyway
+                # loop closed: the clients are gone anyway, and nobody
+                # will drain: no worker stands aside for it
+                self._outbox_drained.set()
 
     def _drain_outbox(self) -> None:
         """On the loop: a token event of a streaming response is
@@ -1396,7 +1460,9 @@ class ServingGateway:
         events it found, the token events it wrote itself and those it
         queued, how long its oldest token event waited for the loop
         (0 with no token event), and ``pauses`` / ``slept_us``, which
-        read 0."""
+        read 0. With the batch written and nothing posted since,
+        ``_outbox_drained`` is set: the worker thread that stood aside
+        for these writes goes on (``_post``)."""
         with self._outbox_lock:
             batch, self._outbox = self._outbox, []
         with span("gateway.deliver", self.tracer,
@@ -1424,6 +1490,11 @@ class ServingGateway:
                         ("tokens", (*event[1], now, 0.0, writes)))
             delivery.set_metadata(writes=writes, queued=queued, pauses=0,
                                   wake_us=round(wake * 1e6), slept_us=0)
+        with self._outbox_lock:
+            # (a post since the batch was taken has asked for a drain
+            # of its own, which sets it)
+            if not self._outbox:
+                self._outbox_drained.set()
 
     def _write_tokens(self, pending: _Pending, writer: asyncio.StreamWriter,
                       rid: int, token_ids: List[int],

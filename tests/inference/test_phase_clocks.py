@@ -187,9 +187,11 @@ def test_a_tick_that_admits_is_partitioned_and_times_its_call(
         "engine.tick", "engine.tick.sweep", "engine.tick.admit",
         "engine.tick.prefill",                          # the call, behind n
         "engine.tick.feed", "engine.tick.decode",       # n+1, behind it
-        "engine.tick.decode_wait",                      # the hand-over
-        "engine.tick.decode_wait", "engine.tick.emit",  # n read
-        "engine.tick.prefill_wait", "engine.tick.emit",  # the call read
+        "engine.tick.decode_wait",       # on_dispatched (the results)
+        # n read; its tokens handed over inside the emit
+        "engine.tick.decode_wait", "engine.tick.emit",
+        # the call read; its first tokens handed over inside the emit
+        "engine.tick.prefill_wait", "engine.tick.emit",
         "engine.tick.export"]
     assert eng.metrics.prefill_calls_behind_flight == 1
     spent = [c - c0 for c, c0 in zip(eng._clocks, before)]
@@ -210,8 +212,9 @@ def test_the_clocks_partition_the_wall_time_with_a_step_in_flight(
     """The loop one step ahead: every instant of the ticking thread is
     still booked to one clock, the wait for the step in flight is
     device wait, and a plain tick's phases come in the order
-    docs/observability.md gives: the dispatch of the step ahead, the
-    hand-over, then the read of the step before it."""
+    docs/observability.md gives: the dispatch of the step ahead, what
+    runs beside it (``on_dispatched``), then the read of the step
+    before it, whose tokens are handed over inside its emit."""
     eng = make_engine(tiny_llama)
     eng.submit([1, 2, 3], max_new_tokens=24)
     eng.step()
